@@ -187,9 +187,9 @@ _GLOBAL_FLAGS = {
     # Only reached when the flat sweep itself is on (fuse=True /
     # FLAGS_fuse_optimizer). See docs/kernels.md.
     "FLAGS_fuse_optimizer_pallas": None,
-    # persistent XLA compilation cache directory ('' = disabled). When set,
-    # repeated processes compiling the same program hit the on-disk cache
-    # instead of paying the cold XLA compile (jax_compilation_cache_dir).
+    # persistent XLA compilation cache directory. Yields to the
+    # JAX_COMPILATION_CACHE_DIR environment variable; '' = the fixed
+    # in-checkout default (see ensure_compile_cache below).
     "FLAGS_compile_cache_dir": _os.environ.get("FLAGS_compile_cache_dir", ""),
     # program-report JSONL sink ('' = disabled): every compiled executable
     # writes one cost/memory introspection record under this directory
@@ -228,7 +228,7 @@ _GLOBAL_FLAGS = {
 def set_flags(flags: dict):
     for k, v in flags.items():
         _GLOBAL_FLAGS[k] = v
-    if flags.get("FLAGS_compile_cache_dir"):
+    if "FLAGS_compile_cache_dir" in flags:
         ensure_compile_cache()
 
 
@@ -248,12 +248,22 @@ def flags_snapshot() -> dict:
 
 
 # ---------------------------------------------------------------------------
-# Persistent XLA compilation cache (FLAGS_compile_cache_dir). The reference
-# pays every XLA compile from scratch per process; jax's on-disk cache
-# (jax_compilation_cache_dir) makes the second process a deserialize instead
-# of a compile. Hit/miss counters come from jax.monitoring events so the
-# Executor can log and RecordEvent whether a compile was served from disk.
+# Persistent XLA compilation cache. Every process that compiles (Executor,
+# make_train_step, DecodeEngine.warmup, the bench entry points) calls
+# ensure_compile_cache(); `import paddle_tpu` does not. Where the cache
+# lives, first match wins:
+#   1. JAX_COMPILATION_CACHE_DIR — jax reads it itself; no directory is set
+#      in code, so whoever launches the process places the cache;
+#   2. FLAGS_compile_cache_dir (set_flags / env);
+#   3. <checkout>/.jax_cache — a fixed path: the path is part of the cache
+#      key, so a temp name, pid or timestamp would never hit.
+# Hit/miss counters come from jax.monitoring events so the Executor can log
+# and RecordEvent whether a compile was served from disk.
 # ---------------------------------------------------------------------------
+
+_DEFAULT_COMPILE_CACHE_DIR = _os.path.join(
+    _os.path.dirname(_os.path.dirname(_os.path.dirname(
+        _os.path.abspath(__file__)))), ".jax_cache")
 
 _compile_cache_state = {"dir": None, "hits": 0, "misses": 0, "listener": False}
 
@@ -265,27 +275,33 @@ def _compile_cache_listener(event, **kwargs):
         _compile_cache_state["misses"] += 1
 
 
-def ensure_compile_cache() -> bool:
-    """Point jax's persistent compilation cache at FLAGS_compile_cache_dir.
+def compile_cache_dir() -> str:
+    """The directory the persistent compile cache uses (see above)."""
+    return (_os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or _GLOBAL_FLAGS.get("FLAGS_compile_cache_dir")
+            or _DEFAULT_COMPILE_CACHE_DIR)
 
-    Idempotent; returns True when the cache is active. The size thresholds
-    are dropped to zero so even small programs (which this framework compiles
-    per (program, feed-sig, fetch) key) are cached across processes.
+
+def ensure_compile_cache() -> str:
+    """Turn jax's persistent compilation cache on at compile_cache_dir().
+
+    Idempotent; returns the directory. The size thresholds are dropped to
+    zero so even small programs (which this framework compiles per
+    (program, feed-sig, fetch) key) are cached across processes.
     """
-    d = _GLOBAL_FLAGS.get("FLAGS_compile_cache_dir")
-    if not d:
-        return False
+    d = compile_cache_dir()
     if _compile_cache_state["dir"] != d:
         if not _compile_cache_state["listener"]:
             from jax import monitoring
 
             monitoring.register_event_listener(_compile_cache_listener)
             _compile_cache_state["listener"] = True
-        jax.config.update("jax_compilation_cache_dir", str(d))
+        if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update("jax_compilation_cache_dir", d)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         _compile_cache_state["dir"] = d
-    return True
+    return d
 
 
 def compile_cache_counters():

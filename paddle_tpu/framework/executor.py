@@ -441,32 +441,21 @@ class _CompiledBlock:
     # -- explicit AOT compile: one compile serves dispatch + introspection --
     def _aot_compile(self, mutable, const, feeds, rng_key) -> None:
         """Lower + compile the block explicitly and keep the executable.
-        On any failure the block permanently falls back to implicit jit
-        dispatch (AOT is an optimization + introspection surface, never a
-        correctness requirement)."""
-        watch = bool(get_flag("FLAGS_compile_cache_dir"))
-        if watch:
-            h0, m0 = compile_cache_counters()
+        A compile the backend refuses raises here: compiling the same
+        program a second time under implicit jit would only hide it."""
+        h0, m0 = compile_cache_counters()
         t0 = time.perf_counter_ns()
-        try:
-            # a first-call XLA compile can legitimately run for minutes:
-            # pause the hang-watchdog clock for its duration, and charge
-            # the wall time to the ledger's compile category
-            with _health().suspend(), _gp.timer("compile"), \
-                    _spans.span(f"compile/{self.report_name}"):
-                lowered = self._jitted.lower(mutable, const, feeds, rng_key)
-                executable = lowered.compile()
-        except Exception as e:
-            self._aot_failed = True
-            logger.info("AOT compile unavailable for %s (%s: %s); "
-                        "falling back to implicit jit dispatch",
-                        self.report_name, type(e).__name__, e)
-            return
+        # a first-call XLA compile can legitimately run for minutes:
+        # pause the hang-watchdog clock for its duration, and charge
+        # the wall time to the ledger's compile category
+        with _health().suspend(), _gp.timer("compile"), \
+                _spans.span(f"compile/{self.report_name}"):
+            lowered = self._jitted.lower(mutable, const, feeds, rng_key)
+            executable = lowered.compile()
         self.compile_ms = (time.perf_counter_ns() - t0) / 1e6
-        if watch:
-            h1, m1 = compile_cache_counters()
-            self.cache_verdict = ("hit" if h1 > h0
-                                  else "cold" if m1 > m0 else None)
+        h1, m1 = compile_cache_counters()
+        self.cache_verdict = ("hit" if h1 > h0
+                              else "cold" if m1 > m0 else None)
         self._executable = executable
         # input avals summarized BEFORE the first call: donation will
         # invalidate the mutable buffers
@@ -814,8 +803,7 @@ class Executor:
         self._step += 1
         # the XLA compile happens lazily at the first execution; when the
         # persistent cache is on, attribute it as served-from-disk vs cold
-        watch_cache = newly_built and bool(get_flag("FLAGS_compile_cache_dir"))
-        if watch_cache:
+        if newly_built:
             hits0, misses0 = compile_cache_counters()
             t0 = time.perf_counter_ns()
         _m_dispatch_slow.inc()
@@ -828,7 +816,7 @@ class Executor:
         if _spans.tracing_enabled():
             _spans.record("executor/step", t_run0, t_run1 - t_run0,
                           attrs={"path": "slow"})
-        if watch_cache:
+        if newly_built:
             hits1, misses1 = compile_cache_counters()
             if hits1 > hits0 or misses1 > misses0:
                 verdict = "hit" if hits1 > hits0 else "cold"

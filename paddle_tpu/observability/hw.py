@@ -35,51 +35,47 @@ HBM_CAPACITY_BYTES = {
     "v2": 16e9,
 }
 
-_FALLBACK_FLOPS = 1e12    # CPU / unknown accelerator
-_FALLBACK_HBM_BPS = 50e9  # DDR-class fallback so CPU rooflines stay finite
+# The CPU lane's placeholders: the CPU tests need finite roofline
+# arithmetic, and nothing divided by these is a device metric. An
+# accelerator whose kind is in no table is an error, never a default.
+_CPU_PLACEHOLDER_FLOPS = 1e12
+_CPU_PLACEHOLDER_HBM_BPS = 50e9
 
 
-def peak_bf16_flops(device=None) -> float:
-    """Peak *bf16* FLOP/s for a jax device (or the default device)."""
+def _lookup(table, device, what, cpu_value):
     if device is None:
         import jax
 
         device = jax.devices()[0]
     kind = str(getattr(device, "device_kind", "cpu")).lower()
-    for k, v in PEAK_BF16_FLOPS.items():
+    for k, v in table.items():
         if k in kind:
             return v
-    return _FALLBACK_FLOPS
+    if getattr(device, "platform", "cpu") == "cpu":
+        return cpu_value
+    raise ValueError(
+        f"no {what} on record for device kind {kind!r} — add the published "
+        "figure to paddle_tpu/observability/hw.py")
+
+
+def peak_bf16_flops(device=None) -> float:
+    """Peak *bf16* FLOP/s for a jax device (or the default device)."""
+    return _lookup(PEAK_BF16_FLOPS, device, "peak bf16 FLOP/s",
+                   _CPU_PLACEHOLDER_FLOPS)
 
 
 def peak_hbm_bytes_per_s(device=None) -> float:
     """Peak HBM bandwidth (bytes/s) for a jax device — the roofline's
     memory axis, shared by attribution.py the same way the flops table is
     shared by bench/monitor."""
-    if device is None:
-        import jax
-
-        device = jax.devices()[0]
-    kind = str(getattr(device, "device_kind", "cpu")).lower()
-    for k, v in PEAK_HBM_BYTES_PER_S.items():
-        if k in kind:
-            return v
-    return _FALLBACK_HBM_BPS
+    return _lookup(PEAK_HBM_BYTES_PER_S, device, "peak HBM bandwidth",
+                   _CPU_PLACEHOLDER_HBM_BPS)
 
 
 def hbm_capacity_bytes(device=None):
-    """On-chip HBM capacity in bytes, or ``None`` when the device has no
-    fixed budget in the table (CPU / unknown accelerator — host memory is
+    """On-chip HBM capacity in bytes; ``None`` on the CPU (host memory is
     not the scarce resource the tuner prunes against)."""
-    if device is None:
-        import jax
-
-        device = jax.devices()[0]
-    kind = str(getattr(device, "device_kind", "cpu")).lower()
-    for k, v in HBM_CAPACITY_BYTES.items():
-        if k in kind:
-            return v
-    return None
+    return _lookup(HBM_CAPACITY_BYTES, device, "HBM capacity", None)
 
 
 def ridge_intensity(device=None) -> float:

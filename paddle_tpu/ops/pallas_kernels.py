@@ -43,14 +43,19 @@ from jax.experimental.pallas import tpu as pltpu
 NUM_LANES = 128
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions; take
-# whichever this jax ships
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
+_CompilerParams = pltpu.CompilerParams
+
+
+def _on_tpu() -> bool:
+    """The module's one backend question: Mosaic kernels (and the Pallas
+    auto-switches below) are chosen when the process's backend is a TPU.
+    A compile for a described, unattached chip runs under the CPU backend,
+    so tests/test_chip_compile.py patches this one function to True."""
+    return jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return not _on_tpu()
 
 
 def _bcast_lanes(x, n):
@@ -730,7 +735,7 @@ def chunked_lm_loss(x, head, labels, bias=None, valid=None,
     vmask = None if valid is None else valid.reshape(-1)
     vc = max(1, min(int(vocab_chunk) or v, v))
     if use_pallas is None:
-        use_pallas = head_layout == "dv" and jax.default_backend() == "tpu"
+        use_pallas = head_layout == "dv" and _on_tpu()
 
     # pad the vocab axis to a chunk multiple (masked to -inf in-chunk; the
     # pad's transpose slices the head cotangent back automatically)
@@ -957,8 +962,8 @@ def _ln_bwd_kernel(*refs, has_dsx, has_mask, inv_keep):
     if dx_ref is not None:
         dx = ds * mask_ref[...].astype(jnp.float32) * inv_keep
         dx_ref[...] = dx.astype(dx_ref.dtype)
-    # per-grid-block partial reductions; the host sums the (ngrid, D)
-    # partials so the row grid stays embarrassingly parallel
+    # per-grid-block partial reductions; the caller sums the
+    # (ngrid, 1, D) partials so the row grid stays embarrassingly parallel
     dscale_ref[...] = jnp.sum(dy * xhat, axis=0, keepdims=True)
     dbias_ref[...] = jnp.sum(dy, axis=0, keepdims=True)
 
@@ -1059,7 +1064,11 @@ def _fused_ln_vjp_bwd(eps, keep, return_residual, block_rows, res, ct):
     row_spec = pl.BlockSpec((br, d), lambda i: (i, 0))
     vec_spec = pl.BlockSpec((1, d), lambda i: (0, 0))
     stat_spec = pl.BlockSpec((br, NUM_LANES), lambda i: (i, 0))
-    part_spec = pl.BlockSpec((1, d), lambda i: (i, 0))
+    # (ng, 1, d) partials: a (1, d) block of an (ng, d) array has a
+    # second-minor dim Mosaic refuses (neither a multiple of 8 nor the
+    # array's own); with the grid axis leading and squeezed, the block's
+    # last two dims ARE the array's
+    part_spec = pl.BlockSpec((None, 1, d), lambda i: (i, 0, 0))
     args = [sx, mu, rstd, scale.reshape(1, d), _ln_pad_rows(dy, rp)]
     in_specs = [row_spec, stat_spec, stat_spec, vec_spec, row_spec]
     if dsx is not None:
@@ -1074,7 +1083,7 @@ def _fused_ln_vjp_bwd(eps, keep, return_residual, block_rows, res, ct):
         out_specs.append(row_spec)
         out_shape.append(jax.ShapeDtypeStruct((rp, d), sx.dtype))
     out_specs += [part_spec, part_spec]
-    out_shape += [jax.ShapeDtypeStruct((ng, d), jnp.float32)] * 2
+    out_shape += [jax.ShapeDtypeStruct((ng, 1, d), jnp.float32)] * 2
     kern = functools.partial(
         _ln_bwd_kernel, has_dsx=dsx is not None, has_mask=has_mask,
         inv_keep=1.0 / keep)
@@ -1093,8 +1102,8 @@ def _fused_ln_vjp_bwd(eps, keep, return_residual, block_rows, res, ct):
         ds_p, dscale_p, dbias_p = outs
         dx = ds_p[:r]
     ds = ds_p[:r]
-    dscale = jnp.sum(dscale_p, axis=0).astype(scale.dtype)
-    dbias = jnp.sum(dbias_p, axis=0).astype(bias_tag.dtype)
+    dscale = jnp.sum(dscale_p[:, 0], axis=0).astype(scale.dtype)
+    dbias = jnp.sum(dbias_p[:, 0], axis=0).astype(bias_tag.dtype)
     dres = None if res_tag is None else ds.astype(res_tag.dtype)
     dbadd = None if badd_tag is None \
         else jnp.sum(ds, axis=0).astype(badd_tag.dtype)
@@ -1318,7 +1327,7 @@ def use_opt_megakernel(override=None) -> bool:
     mode would only slow the CPU lane down)."""
     if override is not None:
         return bool(override)
-    return jax.default_backend() == "tpu"
+    return _on_tpu()
 
 
 # ---------------------------------------------------------------------------
@@ -1335,43 +1344,90 @@ def use_opt_megakernel(override=None) -> bool:
 # layernorm + LM-head projection. Behind EngineConfig(fused_decode=True).
 
 
+# Block shapes the chip's compiler takes (tests/test_chip_compile.py): the
+# caches are [.., rows, nh, hd], so a block keeps ALL heads — its last two
+# dims are then the array's own, which Mosaic accepts at any nh/hd — and
+# the row axis is cut into chunks (slab) or pages (paged) along the grid's
+# last, sequential axis, with flash-decoding's running max / sum /
+# accumulator in VMEM scratch. One query row per head makes the matmuls
+# M=1, so scores and the weighted sum run on the VPU as broadcast
+# multiply + reduce in exact f32, heads on sublanes and hd on lanes —
+# no in-kernel transpose of the head axis.
+
+
+def _decode_chunk(q_ref, k_ref, v_ref, nk, nv, pos, sub, c, chunk,
+                  m_scr, l_scr, acc_scr, *, sm_scale):
+    """Fold cache rows [c*chunk, (c+1)*chunk) of one slot into the running
+    softmax. ``sub`` (scalar bool) substitutes row ``pos`` with the new
+    token's (nk, nv) — already rounded through the cache dtype, so
+    attention sees exactly the row value that lands in the cache."""
+    @pl.when(c == 0)
+    def _init():
+        m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    @pl.when(c * chunk <= pos)        # later chunks are fully masked
+    def _fold():
+        kf = k_ref[...].astype(jnp.float32)              # (chunk, nh, hd)
+        vf = v_ref[...].astype(jnp.float32)
+        rows = c * chunk + jax.lax.broadcasted_iota(jnp.int32, kf.shape, 0)
+        sel = jnp.logical_and(rows == pos, sub)
+        kf = jnp.where(sel, nk[None], kf)
+        vf = jnp.where(sel, nv[None], vf)
+        qf = q_ref[...].astype(jnp.float32)              # (nh, hd)
+        s = jnp.sum(qf[None] * kf, axis=2, keepdims=True) * sm_scale
+        valid = c * chunk + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 0) < pos + 1             # (chunk, nh, 1)
+        s = jnp.where(valid, s, -jnp.inf)
+        # same masked-softmax guards as ops/decode_attention.py
+        m_prev = m_scr[...]                              # (nh, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        alpha = jnp.exp(m_prev - m_safe)
+        e = jnp.where(valid, jnp.exp(s - m_safe[None]), 0.0)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(e, axis=0)
+        acc_scr[...] = alpha * acc_scr[...] + jnp.sum(e * vf, axis=0)
+        m_scr[...] = m_new
+
+
+def _decode_finish(o_ref, l_scr, acc_scr):
+    o_ref[...] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+                  ).astype(o_ref.dtype)
+
+
+def _decode_scratch(nh, hd):
+    return [pltpu.VMEM((nh, 1), jnp.float32),
+            pltpu.VMEM((nh, 1), jnp.float32),
+            pltpu.VMEM((nh, hd), jnp.float32)]
+
+
 def _decode_slab_kernel(pos_ref, act_ref, q_ref, k_ref, v_ref, nk_ref,
-                        nv_ref, o_ref, ko_ref, vo_ref, *, sm_scale,
-                        seq_len):
+                        nv_ref, o_ref, ko_ref, vo_ref, m_scr, l_scr,
+                        acc_scr, *, sm_scale, chunk, num_chunks):
     b = pl.program_id(0)
+    c = pl.program_id(1)
     pos = pos_ref[b]
     act = act_ref[b] != 0
-    k2 = k_ref[0, :, 0, :]                           # (S, hd)
-    v2 = v_ref[0, :, 0, :]
-    # write-guard: inactive lanes keep the row that was already there
-    # (cache_update's masked-lane semantics), and attention sees exactly
-    # the row value that lands in the cache
-    old_k = k_ref[0, pl.ds(pos, 1), 0, :]            # (1, hd)
-    old_v = v_ref[0, pl.ds(pos, 1), 0, :]
-    row_k = jnp.where(act, nk_ref[0].astype(k2.dtype), old_k)
-    row_v = jnp.where(act, nv_ref[0].astype(v2.dtype), old_v)
-    ko_ref[0, :, 0, :] = row_k
-    vo_ref[0, :, 0, :] = row_v
+    nk = nk_ref[...].astype(k_ref.dtype)                 # (nh, hd)
+    nv = nv_ref[...].astype(v_ref.dtype)
 
-    sel = jax.lax.broadcasted_iota(jnp.int32, (seq_len, 1), 0) == pos
-    kf = jnp.where(sel, row_k, k2).astype(jnp.float32)
-    vf = jnp.where(sel, row_v, v2).astype(jnp.float32)
-    qf = q_ref[0].astype(jnp.float32)                # (1, hd)
-    s = jax.lax.dot_general(
-        qf, kf, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale       # (1, S)
-    valid = jax.lax.broadcasted_iota(
-        jnp.int32, (1, seq_len), 1) < pos + 1
-    s = jnp.where(valid, s, -jnp.inf)
-    # same masked-softmax guards as ops/decode_attention.py
-    mx = jnp.max(s, axis=1, keepdims=True)
-    mx = jnp.where(jnp.isfinite(mx), mx, 0.0)
-    e = jnp.where(valid, jnp.exp(s - mx), 0.0)
-    probs = e / jnp.maximum(jnp.sum(e, axis=1, keepdims=True), 1e-30)
-    o = jax.lax.dot_general(
-        probs, vf, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                  # (1, hd)
-    o_ref[0] = o.astype(o_ref.dtype)
+    @pl.when(c == pos // chunk)
+    def _write_row():
+        # write-guard: inactive lanes keep the row that was already there
+        # (cache_update's masked-lane semantics). The (1, nh, hd) out block
+        # sits at row pos for every c and is flushed when b advances.
+        r = pos - c * chunk
+        ko_ref[...] = jnp.where(act, nk[None], k_ref[pl.ds(r, 1)])
+        vo_ref[...] = jnp.where(act, nv[None], v_ref[pl.ds(r, 1)])
+
+    _decode_chunk(q_ref, k_ref, v_ref, nk.astype(jnp.float32),
+                  nv.astype(jnp.float32), pos, act, c, chunk,
+                  m_scr, l_scr, acc_scr, sm_scale=sm_scale)
+
+    @pl.when(c == num_chunks - 1)
+    def _finish():
+        _decode_finish(o_ref, l_scr, acc_scr)
 
 
 def fused_decode_attention(q, k_cache, v_cache, new_k, new_v, positions,
@@ -1381,9 +1437,10 @@ def fused_decode_attention(q, k_cache, v_cache, new_k, new_v, positions,
     decode_attention per layer when ``EngineConfig.fused_decode``.
 
     q/new_k/new_v: [B, nh, hd]; k_cache/v_cache: [B, S, nh, hd];
-    positions: [B] int32 (write row; attention covers positions+1 rows —
-    the engine's lengths); active: [B] optional write mask — inactive
-    lanes keep their cached row (the masked-lane no-write guard).
+    positions: [B] int32 in [0, S) (write row; attention covers
+    positions+1 rows — the engine's lengths); active: [B] optional write
+    mask — inactive lanes keep their cached row (the masked-lane
+    no-write guard).
 
     Returns (out [B, nh, hd], k_cache', v_cache'); the caches are
     aliased in place — only row positions[b] of slot b is touched.
@@ -1394,25 +1451,22 @@ def fused_decode_attention(q, k_cache, v_cache, new_k, new_v, positions,
     if active is None:
         active = jnp.ones((B,), jnp.int32)
     _count_launch("decode_slab")
-    row4 = pl.BlockSpec((1, 1, 1, hd), lambda b, h, p, a: (b, p[b], h, 0))
+    chunk = next((c for c in (256, 128, 64, 32, 16, 8) if S % c == 0), S)
+    nc = S // chunk
+    row3 = pl.BlockSpec((None, nh, hd), lambda b, c, p, a: (b, 0, 0))
+    slab = pl.BlockSpec((None, chunk, nh, hd),
+                        lambda b, c, p, a: (b, c, 0, 0))
+    row4 = pl.BlockSpec((None, 1, nh, hd),
+                        lambda b, c, p, a: (b, p[b], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(B, nh),
-        in_specs=[
-            pl.BlockSpec((1, 1, hd), lambda b, h, p, a: (b, h, 0)),
-            pl.BlockSpec((1, S, 1, hd), lambda b, h, p, a: (b, 0, h, 0)),
-            pl.BlockSpec((1, S, 1, hd), lambda b, h, p, a: (b, 0, h, 0)),
-            pl.BlockSpec((1, 1, hd), lambda b, h, p, a: (b, h, 0)),
-            pl.BlockSpec((1, 1, hd), lambda b, h, p, a: (b, h, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, hd), lambda b, h, p, a: (b, h, 0)),
-            row4,
-            row4,
-        ])
+        num_scalar_prefetch=2, grid=(B, nc),
+        in_specs=[row3, slab, slab, row3, row3],
+        out_specs=[row3, row4, row4],
+        scratch_shapes=_decode_scratch(nh, hd))
     with jax.named_scope("fused_decode_attention"):
         o, kc, vc = pl.pallas_call(
             functools.partial(_decode_slab_kernel, sm_scale=sm_scale,
-                              seq_len=S),
+                              chunk=chunk, num_chunks=nc),
             grid_spec=grid_spec,
             out_shape=[
                 jax.ShapeDtypeStruct((B, nh, hd), q.dtype),
@@ -1421,7 +1475,7 @@ def fused_decode_attention(q, k_cache, v_cache, new_k, new_v, positions,
             ],
             input_output_aliases={3: 1, 4: 2},
             compiler_params=_CompilerParams(
-                dimension_semantics=("parallel", "parallel")),
+                dimension_semantics=("parallel", "arbitrary")),
             interpret=_interpret(),
         )(positions.astype(jnp.int32), active.astype(jnp.int32),
           q, k_cache, v_cache, new_k, new_v)
@@ -1429,63 +1483,46 @@ def fused_decode_attention(q, k_cache, v_cache, new_k, new_v, positions,
 
 
 def _decode_paged_kernel(tbl_ref, pos_ref, q_ref, kp_ref, vp_ref, nk_ref,
-                         nv_ref, o_ref, ko_ref, vo_ref, k_scr, v_scr, *,
-                         sm_scale, page, num_pages):
+                         nv_ref, o_ref, ko_ref, vo_ref, m_scr, l_scr,
+                         acc_scr, *, sm_scale, page, num_pages):
+    del tbl_ref                       # consumed by the index maps
     b = pl.program_id(0)
-    m = pl.program_id(2)
+    m = pl.program_id(1)
     pos = pos_ref[b]
-    # stream this slot's pages into the gathered scratch view (the
-    # in-kernel paged_gather): page m covers logical rows [m*ps, (m+1)*ps)
-    pl.store(k_scr, (pl.ds(m * page, page), slice(None)),
-             kp_ref[0, :, 0, :].astype(jnp.float32))
-    pl.store(v_scr, (pl.ds(m * page, page), slice(None)),
-             vp_ref[0, :, 0, :].astype(jnp.float32))
+    # the current token's row rounds through the pool dtype, as the
+    # unfused path does by scattering first and gathering it back
+    nk = nk_ref[...].astype(kp_ref.dtype)
+    nv = nv_ref[...].astype(vp_ref.dtype)
 
     @pl.when(m == 0)
     def _write_row():
         # the out row block maps to (tables[b, pos//ps], pos%ps) for every
         # m — dead lanes' all-zero tables land it on the scratch page,
         # which is never read back (the unfused scratch-page guard)
-        ko_ref[0, :, 0, :] = nk_ref[0].astype(ko_ref.dtype)
-        vo_ref[0, :, 0, :] = nv_ref[0].astype(vo_ref.dtype)
+        ko_ref[...] = nk[None]
+        vo_ref[...] = nv[None]
+
+    # page m covers logical rows [m*ps, (m+1)*ps): the in-kernel
+    # paged_gather is the kp/vp index map walking the page table
+    _decode_chunk(q_ref, kp_ref, vp_ref, nk.astype(jnp.float32),
+                  nv.astype(jnp.float32), pos, True, m, page,
+                  m_scr, l_scr, acc_scr, sm_scale=sm_scale)
 
     @pl.when(m == num_pages - 1)
-    def _attend():
-        S = num_pages * page
-        # substitute the current token's row: the unfused path scatters
-        # first and gathers it back, rounding through the pool dtype
-        sel = jax.lax.broadcasted_iota(jnp.int32, (S, 1), 0) == pos
-        nk = nk_ref[0].astype(ko_ref.dtype).astype(jnp.float32)
-        nv = nv_ref[0].astype(vo_ref.dtype).astype(jnp.float32)
-        kf = jnp.where(sel, nk, k_scr[...])
-        vf = jnp.where(sel, nv, v_scr[...])
-        qf = q_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            qf, kf, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        valid = jax.lax.broadcasted_iota(jnp.int32, (1, S), 1) < pos + 1
-        s = jnp.where(valid, s, -jnp.inf)
-        mx = jnp.max(s, axis=1, keepdims=True)
-        mx = jnp.where(jnp.isfinite(mx), mx, 0.0)
-        e = jnp.where(valid, jnp.exp(s - mx), 0.0)
-        probs = e / jnp.maximum(jnp.sum(e, axis=1, keepdims=True), 1e-30)
-        o = jax.lax.dot_general(
-            probs, vf, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        o_ref[0] = o.astype(o_ref.dtype)
+    def _finish():
+        _decode_finish(o_ref, l_scr, acc_scr)
 
 
 def fused_paged_decode_attention(q, k_pool, v_pool, new_k, new_v, tables,
                                  positions, sm_scale=None):
     """Paged twin of :func:`fused_decode_attention`: page-table gather +
     row scatter + masked one-token attention in ONE launch (subsumes
-    paged_gather + paged_cache_update). The gathered view is staged in
-    VMEM scratch page-by-page, so the softmax runs single-pass in the
-    same reduction order as the unfused gathered attention.
+    paged_gather + paged_cache_update). The slot's pages stream through
+    VMEM one grid step each; the softmax folds them online.
 
     q/new_k/new_v [B, nh, hd]; k_pool/v_pool [P, page, nh, hd];
     tables [B, M] int32 (all-zero rows = dead lanes writing the
-    scratch page); positions [B] int32.
+    scratch page); positions [B] int32 in [0, M*page).
 
     Returns (out [B, nh, hd], k_pool', v_pool'), pools aliased in place.
     """
@@ -1494,31 +1531,17 @@ def fused_paged_decode_attention(q, k_pool, v_pool, new_k, new_v, tables,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     _count_launch("decode_paged")
-    S = M * page
-
-    def row_idx(b, h, m, t, p):
-        return (t[b, p[b] // page], p[b] % page, h, 0)
-
+    row3 = pl.BlockSpec((None, nh, hd), lambda b, m, t, p: (b, 0, 0))
+    page_spec = pl.BlockSpec((None, page, nh, hd),
+                             lambda b, m, t, p: (t[b, m], 0, 0, 0))
+    row4 = pl.BlockSpec(
+        (None, 1, nh, hd),
+        lambda b, m, t, p: (t[b, p[b] // page], p[b] % page, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(B, nh, M),
-        in_specs=[
-            pl.BlockSpec((1, 1, hd), lambda b, h, m, t, p: (b, h, 0)),
-            pl.BlockSpec((1, page, 1, hd),
-                         lambda b, h, m, t, p: (t[b, m], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, hd),
-                         lambda b, h, m, t, p: (t[b, m], 0, h, 0)),
-            pl.BlockSpec((1, 1, hd), lambda b, h, m, t, p: (b, h, 0)),
-            pl.BlockSpec((1, 1, hd), lambda b, h, m, t, p: (b, h, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, hd), lambda b, h, m, t, p: (b, h, 0)),
-            pl.BlockSpec((1, 1, 1, hd), row_idx),
-            pl.BlockSpec((1, 1, 1, hd), row_idx),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((S, hd), jnp.float32),
-            pltpu.VMEM((S, hd), jnp.float32),
-        ])
+        num_scalar_prefetch=2, grid=(B, M),
+        in_specs=[row3, page_spec, page_spec, row3, row3],
+        out_specs=[row3, row4, row4],
+        scratch_shapes=_decode_scratch(nh, hd))
     with jax.named_scope("fused_decode_attention_paged"):
         o, kp, vp = pl.pallas_call(
             functools.partial(_decode_paged_kernel, sm_scale=sm_scale,
@@ -1533,8 +1556,7 @@ def fused_paged_decode_attention(q, k_pool, v_pool, new_k, new_v, tables,
             # b sequential: dead lanes' scratch-page writes collide
             # (benign — never read back — but kept ordered on TPU)
             compiler_params=_CompilerParams(
-                dimension_semantics=("arbitrary", "parallel",
-                                     "arbitrary")),
+                dimension_semantics=("arbitrary", "arbitrary")),
             interpret=_interpret(),
         )(tables.astype(jnp.int32), positions.astype(jnp.int32),
           q, k_pool, v_pool, new_k, new_v)

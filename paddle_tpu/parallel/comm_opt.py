@@ -63,13 +63,8 @@ _m_wire_bytes = _obs_metrics.default_registry().counter(
 
 
 def axis_size(name) -> int:
-    """Static size of a named mesh axis inside shard_map (jax 0.4.x:
-    ``jax.core.axis_frame`` returns the size directly; newer jax returns a
-    frame object)."""
-    from jax.core import axis_frame
-
-    fr = axis_frame(name)
-    return int(getattr(fr, "size", fr))
+    """Static size of a named mesh axis inside ``shard_map``."""
+    return int(lax.axis_size(name))
 
 
 def _axes_size(axes) -> int:
@@ -438,10 +433,13 @@ def quantized_reduce_scatter_op(x, axis, comm_dtype, quant_chunk: int = 256,
 # Overlap measurement (profiler capture -> achieved comm/compute overlap)
 # ---------------------------------------------------------------------------
 
+# HLO opcode spellings, plus the jax primitive names the installed jax gives
+# the instructions themselves (`%psum.7 = ... all-reduce(...)`: traces carry
+# the instruction name, on the CPU client and in the compiled TPU HLO alike)
 _COLLECTIVE_HLO_MARKERS = (
     "all-reduce", "all-gather", "reduce-scatter", "all-to-all",
     "collective-permute", "all_reduce", "all_gather", "reduce_scatter",
-    "all_to_all", "collective_permute",
+    "all_to_all", "collective_permute", "psum", "ppermute",
 )
 
 

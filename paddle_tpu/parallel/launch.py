@@ -379,9 +379,9 @@ def launch(training_script: str, script_args: Optional[List[str]] = None,
                 # rings here (flight.maybe_attach_from_env)
                 env[_flight.ENV_DIR] = flight_dir
             if perf_flags:
-                # comm/compute-overlap preset into each worker's XLA_FLAGS
-                # BEFORE its backend init (no-op unless the worker env
-                # targets a TPU — the platform gate in sysconfig)
+                # comm/compute-overlap preset into each worker's
+                # LIBTPU_INIT_ARGS BEFORE its backend init (read by libtpu
+                # only: harmless for a worker that loads none)
                 tpu_perf_flags(env=env)
             # append mode: a restarted worker's log continues the file
             out = (open(os.path.join(log_dir, f"worker.{rank}.log"), "a")

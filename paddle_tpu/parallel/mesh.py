@@ -110,20 +110,11 @@ def feed_aval(shape, dt) -> jax.ShapeDtypeStruct:
 
 def jit_shard_map(per_rank, mesh: Mesh, in_specs, out_specs,
                   donate_argnums=()):
-    """shard_map + jit with the replication-check kwarg spelled for the
-    running jax version (check_vma on current, check_rep on older). The
-    single wrapping point for the executor / pipeline / grad-merge
-    per-rank executables."""
-    try:
-        from jax import shard_map as _shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        wrapped = _shard_map(per_rank, **kwargs, check_vma=False)
-    except TypeError:  # older jax spells it check_rep
-        wrapped = _shard_map(per_rank, **kwargs, check_rep=False)
+    """shard_map (no varying-manual-axes check) + jit. The single
+    wrapping point for the executor / pipeline / grad-merge per-rank
+    executables."""
+    wrapped = jax.shard_map(per_rank, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
     return jax.jit(wrapped, donate_argnums=donate_argnums)
 
 

@@ -39,11 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from . import comm_opt
 from . import health as _health
 from . import mesh as mesh_mod
@@ -71,12 +66,11 @@ def _named_collective(kind: str):
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_vma=False)
-    except TypeError:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_rep=False)
+    """``jax.shard_map`` without the varying-manual-axes check: the
+    per-rank bodies here psum by hand and return replicated values the
+    checker cannot prove."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -909,68 +903,60 @@ def make_train_step(cfg: GPTConfig, pcfg: ParallelConfig, mesh: Mesh,
 def _wrap_step_with_report(step, pcfg: ParallelConfig, report_name: str,
                            extra_mode: str):
     # Program-report capture (observability/program_report.py): the first
-    # invocation lowers + compiles explicitly, keeps the executable as the
-    # dispatch target, and records cost/memory analysis, compile wall-ms
-    # and the donation map — the same introspection surface Executor.run's
-    # compiled blocks get. Any AOT failure reverts to implicit jit
-    # dispatch permanently (never a correctness dependency).
+    # invocation with each (tokens, labels) signature lowers + compiles
+    # explicitly, keeps the executable as the dispatch target, and records
+    # cost/memory analysis, compile wall-ms and the donation map — the same
+    # introspection surface Executor.run's compiled blocks get. A compile
+    # the backend refuses raises: there is no second try under implicit jit.
+    from ..framework.core import ensure_compile_cache
+    from ..observability import goodput as _goodput
     from ..observability import program_report as _prep
 
-    aot = {"exec": None, "failed": False}
+    aot = {}            # (tokens, labels) aval signature -> executable
 
     def step_with_report(params, opt_state, tokens, labels):
         # hang-watchdog progress stamp (docs/health.md): one tuple store
         _health.progress("train_step")
-        if aot["exec"] is None and not aot["failed"]:
+        sig = (tokens.shape, str(tokens.dtype), labels.shape,
+               str(labels.dtype))
+        compiled = aot.get(sig)
+        if compiled is None:
             import time as _time
 
+            ensure_compile_cache()
             t0 = _time.perf_counter_ns()
-            try:
-                # first-call XLA compile can run for minutes: pause the
-                # hang-watchdog deadline clock for its duration
-                with _health.suspend():
-                    lowered = step.lower(params, opt_state, tokens, labels)
-                    aot["exec"] = lowered.compile()
-            except Exception:
-                aot["failed"] = True
-            else:
-                _prep.capture(
-                    report_name, compiled=aot["exec"],
-                    compile_ms=(_time.perf_counter_ns() - t0) / 1e6,
-                    donated=["params", "opt_state"],
-                    inputs=(params, opt_state, tokens, labels),
-                    extra={"mode": extra_mode,
-                           "mesh": {a: int(s) for a, s in
-                                    zip(pcfg.axis_names,
-                                        (pcfg.dp, pcfg.pp, pcfg.tp))}})
-        from ..observability import goodput as _goodput
-
-        if aot["exec"] is not None:
-            try:
-                with _goodput.timer("productive_step"):
-                    return aot["exec"](params, opt_state, tokens, labels)
-            except TypeError:
-                # arg-signature drift (raised before execution, nothing
-                # donated yet): revert to jit dispatch for good
-                aot["exec"] = None
-                aot["failed"] = True
+            # first-call XLA compile can run for minutes: pause the
+            # hang-watchdog deadline clock for its duration
+            with _health.suspend():
+                compiled = step.lower(params, opt_state, tokens,
+                                      labels).compile()
+            aot[sig] = compiled
+            _prep.capture(
+                report_name, compiled=compiled,
+                compile_ms=(_time.perf_counter_ns() - t0) / 1e6,
+                donated=["params", "opt_state"],
+                inputs=(params, opt_state, tokens, labels),
+                extra={"mode": extra_mode,
+                       "mesh": {a: int(s) for a, s in
+                                zip(pcfg.axis_names,
+                                    (pcfg.dp, pcfg.pp, pcfg.tp))}})
         with _goodput.timer("productive_step"):
-            return step(params, opt_state, tokens, labels)
+            return compiled(params, opt_state, tokens, labels)
 
     def _hlo_text():
-        # optimized HLO of the kept AOT executable (None before the first
-        # call / after an AOT fallback) — the roofline attribution
+        # optimized HLO of the newest kept executable (None before the
+        # first call) — the roofline attribution
         # (observability/attribution.py) joins its per-instruction static
         # costs with the measured device trace
-        if aot["exec"] is None:
+        if not aot:
             return None
-        try:
-            return aot["exec"].as_text()
-        except Exception:
-            return None
+        return list(aot.values())[-1].as_text()
 
     step_with_report.report_name = report_name
     step_with_report.hlo_text = _hlo_text
+    # the jitted step's own lower(): a compile for a described, unattached
+    # chip passes shapes, which the dispatch wrapper above cannot run
+    step_with_report.lower = step.lower
     return step_with_report
 
 

@@ -693,27 +693,27 @@ class DecodeEngine:
             self._exec[name] = exe
         return exe
 
+    def _decode_program(self):
+        """(fn, example args) of the decode tick — what _decode_exec
+        compiles; tests/test_chip_compile.py lowers the same pair for a
+        described chip from the example's shapes."""
+        B = self.ecfg.max_batch
+        zeros_b = np.zeros((B,), np.int32)
+        if self.paged:
+            M = self.cache.max_pages_per_slot
+            return self._decode_fn_paged, (
+                self.qparams, self.cache.k, self.cache.v, zeros_b, zeros_b,
+                np.zeros((B, M), np.int32), *self._samp_batch_examples())
+        return self._decode_fn, (
+            self.qparams, self.cache.k, self.cache.v, zeros_b, zeros_b,
+            zeros_b, *self._samp_batch_examples())
+
     def _decode_exec(self):
         exe = self._exec.get("decode")
         if exe is None:
-            B = self.ecfg.max_batch
-            if self.paged:
-                M = self.cache.max_pages_per_slot
-                example = (self.qparams, self.cache.k, self.cache.v,
-                           np.zeros((B,), np.int32),
-                           np.zeros((B,), np.int32),
-                           np.zeros((B, M), np.int32),
-                           *self._samp_batch_examples())
-                exe = self._compile("decode", self._decode_fn_paged,
-                                    example, donate_argnums=(1, 2))
-            else:
-                example = (self.qparams, self.cache.k, self.cache.v,
-                           np.zeros((B,), np.int32),
-                           np.zeros((B,), np.int32),
-                           np.zeros((B,), np.int32),
-                           *self._samp_batch_examples())
-                exe = self._compile("decode", self._decode_fn, example,
-                                    donate_argnums=(1, 2))
+            fn, example = self._decode_program()
+            exe = self._compile("decode", fn, example,
+                                donate_argnums=(1, 2))
             self._exec["decode"] = exe
         return exe
 
@@ -751,6 +751,9 @@ class DecodeEngine:
         configured) and run each once so the first real request pays no
         compile and no first-dispatch cost. Returns {executable_name:
         wall ms per warm call}."""
+        from ..framework.core import ensure_compile_cache
+
+        ensure_compile_cache()
         timings: Dict[str, float] = {}
         B = self.ecfg.max_batch
         zeros_b = np.zeros((B,), np.int32)

@@ -44,9 +44,10 @@ same contracts:
   still idempotent under the request-id contract.
 
 TPU caveat: replicas are separate processes — on a TPU host each must be
-pinned to its own chip subset (``TPU_VISIBLE_DEVICES`` per replica, see
-tools/run_tpu_session8.sh); the committed bench lanes are the CPU smoke
-surface.
+pinned to its own chip subset (``TPU_VISIBLE_DEVICES`` per replica). The
+spawn below hands every child the parent's whole device set, so
+per-replica chip assignment is unproven; the committed bench lanes are
+the CPU smoke surface.
 """
 from __future__ import annotations
 

@@ -31,7 +31,8 @@ from typing import Any, Callable, Dict, List, Optional
 
 from .space import Candidate, parse_disagg_ratio
 
-__all__ = ["DeviceInfo", "device_info", "hw_fingerprint", "ProbeTiming",
+__all__ = ["DeviceInfo", "device_info", "require_tpu",
+           "hw_fingerprint", "ProbeTiming",
            "timed_loop", "TrainProbeGeometry", "run_train_probe",
            "ServeProbeGeometry", "run_serve_probe"]
 
@@ -63,6 +64,23 @@ def device_info() -> DeviceInfo:
         on_acc=on_acc,
         degraded=d.platform != "tpu",
         device=d)
+
+
+def require_tpu(tool: str, smoke: bool = False) -> DeviceInfo:
+    """The measuring entry points' first act on jax (chip_smoke.py,
+    bench.py, tools/serve_bench.py, profile_step.py, autotune.py,
+    comm_bench.py): a backend that is not a TPU is refused — a timing from
+    the CPU is not a measurement. The tools' ``--smoke`` lanes (``smoke=
+    True``) are CPU correctness runs and say so in what they write
+    (``degraded``/``cpu_smoke``)."""
+    di = device_info()
+    if not smoke and di.platform != "tpu":
+        raise SystemExit(
+            f"{tool}: backend is {di.platform!r}, not a TPU — nothing was "
+            "measured (run it through the chip tool"
+            + ("; --smoke runs the CPU correctness lane)"
+               if tool.startswith("tools/") else ")"))
+    return di
 
 
 def hw_fingerprint(di: Optional[DeviceInfo] = None) -> Dict[str, Any]:

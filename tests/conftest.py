@@ -5,13 +5,19 @@ Must run before jax import.
 
 Exception: PADDLE_TPU_NATIVE=1 leaves the platform alone so the tests/tpu
 lane (reference check_output_with_place runs every registered place) can
-exercise the REAL chip: `PADDLE_TPU_NATIVE=1 python -m pytest tests/tpu`.
+exercise the REAL chip, one command through the chip tool:
+`chiprun -- env PADDLE_TPU_NATIVE=1 python -m pytest tests/tpu -q`.
 """
 import os
 
 _TPU_LANE = os.environ.get("PADDLE_TPU_NATIVE") == "1"
 if not _TPU_LANE:
     os.environ["JAX_PLATFORMS"] = "cpu"
+    # hermetic CPU lane: the library turns the persistent compile cache on
+    # wherever it compiles (framework.core.ensure_compile_cache); neither
+    # the tests nor the child processes they spawn (which inherit this) read
+    # a stale entry, fill <checkout>/.jax_cache or pay its writes
+    os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     flags = os.environ.get("XLA_FLAGS", "")
     if "host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
@@ -24,6 +30,7 @@ import jax
 
 if not _TPU_LANE:
     jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_enable_compilation_cache", False)
 
 import numpy as np
 import pytest
@@ -34,8 +41,18 @@ def pytest_configure(config):
         "markers", "slow: excluded from the tier-1 `-m 'not slow'` run")
 
 
+# Minutes of TPU-compiler and full-path work on every core they are given.
+# Run last, they leave the xdist schedule of every other file as it was
+# before they existed: test_dgc_halfasync's two async trainer processes stop
+# converging when such a neighbour starves them (their margin is thin).
+_RUN_LAST = ("test_chip_compile.py", "test_chip_smoke.py")
+
+
 def pytest_collection_modifyitems(config, items):
     if not _TPU_LANE:
+        # stable and deterministic: every xdist worker collects this order
+        items.sort(key=lambda it: os.path.basename(str(it.fspath))
+                   in _RUN_LAST)
         return
     # the TPU lane runs on the real (single-chip) backend: everything
     # outside tests/tpu assumes the 8-virtual-device CPU mesh — skip it

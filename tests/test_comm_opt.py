@@ -234,22 +234,39 @@ def test_measure_overlap_fraction_from_trace(tmp_path):
     assert res["source"] in ("device_plane", "cpu_thread_emulation")
 
 
-def test_tpu_perf_flags_gated_off_tpu():
+def test_axis_size_under_shard_map():
+    """comm_opt.axis_size is jax.lax.axis_size: a static python int inside
+    shard_map, per axis and as a product over several."""
+    mesh = PZ.build_mesh(PZ.ParallelConfig(dp=4, pp=1, tp=2))
+    seen = {}
+
+    def f(x):
+        seen["dp"] = comm_opt.axis_size("dp")
+        seen["tp"] = comm_opt.axis_size("tp")
+        seen["both"] = comm_opt._axes_size(("dp", "tp"))
+        return x * seen["both"]
+
+    out = _shard_map(f, mesh, P("dp"), P("dp"))(jnp.ones((8,)))
+    assert seen == {"dp": 4, "tp": 2, "both": 8}
+    assert all(type(v) is int for v in seen.values())
+    np.testing.assert_array_equal(np.asarray(out), np.full((8,), 8.0))
+
+
+def test_tpu_perf_flags_use_libtpu_channel():
     from paddle_tpu.sysconfig import TPU_PERF_XLA_FLAGS, tpu_perf_flags
 
-    env = {"JAX_PLATFORMS": "cpu"}
+    env = {"XLA_FLAGS": "--existing=1", "LIBTPU_INIT_ARGS": "--mine=2"}
     preset = tpu_perf_flags(env=env)
     assert "latency_hiding_scheduler" in preset
-    assert "XLA_FLAGS" not in env  # CPU target: not applied
-    env = {"JAX_PLATFORMS": "tpu", "XLA_FLAGS": "--existing=1"}
-    tpu_perf_flags(env=env)
+    # jaxlib's own parser aborts on --xla_tpu_* flags: XLA_FLAGS untouched
+    assert env["XLA_FLAGS"] == "--existing=1"
     for f in TPU_PERF_XLA_FLAGS:
-        assert f in env["XLA_FLAGS"]
-    assert "--existing=1" in env["XLA_FLAGS"]
+        assert f in env["LIBTPU_INIT_ARGS"]
+    assert "--mine=2" in env["LIBTPU_INIT_ARGS"]
     # idempotent: re-applying does not duplicate
-    once = env["XLA_FLAGS"]
+    once = env["LIBTPU_INIT_ARGS"]
     tpu_perf_flags(env=env)
-    assert env["XLA_FLAGS"] == once
+    assert env["LIBTPU_INIT_ARGS"] == once
 
 
 def test_named_scope_buckets_lowered():

@@ -263,7 +263,10 @@ def test_persistent_compile_cache_across_processes(tmp_path):
     """Second process compiling the same program must be served from the
     FLAGS_compile_cache_dir on-disk cache (and log the hit)."""
     cache_dir = str(tmp_path / "xla_cache")
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    # conftest turns the cache off for the lane and its children; this
+    # test is about the cache
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_ENABLE_COMPILATION_CACHE="true")
 
     def run_once():
         return subprocess.run(
@@ -285,3 +288,43 @@ def test_persistent_compile_cache_across_processes(tmp_path):
     assert "persistent compile cache hit" in r2.stderr
     # both processes computed the same thing
     assert r1.stdout.split("loss=")[1] == r2.stdout.split("loss=")[1]
+
+
+def test_compile_cache_placement(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and no directory is then set in
+    code; FLAGS_compile_cache_dir yields to it; the default is one fixed
+    path inside the checkout (the path is part of the cache key)."""
+    import jax
+
+    from paddle_tpu.framework import core
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    thresholds = (jax.config.jax_persistent_cache_min_compile_time_secs,
+                  jax.config.jax_persistent_cache_min_entry_size_bytes)
+    monkeypatch.setitem(core._compile_cache_state, "dir", None)
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setitem(core._GLOBAL_FLAGS, "FLAGS_compile_cache_dir", "")
+        d1, d2 = core.ensure_compile_cache(), core.ensure_compile_cache()
+        assert d1 == d2 == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == d1
+        assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
+
+        monkeypatch.setitem(core._GLOBAL_FLAGS, "FLAGS_compile_cache_dir",
+                            "/flag/dir")
+        assert core.ensure_compile_cache() == "/flag/dir"
+        assert jax.config.jax_compilation_cache_dir == "/flag/dir"
+
+        # the environment places the cache: jax read the variable itself,
+        # the code must not overwrite what jax holds
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/env/dir")
+        jax.config.update("jax_compilation_cache_dir", "sentinel")
+        assert core.ensure_compile_cache() == "/env/dir"
+        assert jax.config.jax_compilation_cache_dir == "sentinel"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          thresholds[0])
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                          thresholds[1])

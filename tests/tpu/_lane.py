@@ -1,9 +1,10 @@
-"""Shared TPU-lane helpers: the TPU_LANE.json round-artifact recorder."""
+"""Shared TPU-lane helpers: records what the lane measured under
+chiprun_out/ (the one directory the chip tool brings back)."""
 import json
 import os
 
 _PATH = os.path.join(os.path.dirname(__file__), "..", "..",
-                     "TPU_LANE.json")
+                     "chiprun_out", "TPU_LANE.json")
 
 
 def record(key, value):
@@ -12,5 +13,6 @@ def record(key, value):
         with open(_PATH) as f:
             data = json.load(f)
     data[key] = value
+    os.makedirs(os.path.dirname(_PATH), exist_ok=True)
     with open(_PATH, "w") as f:
         json.dump(data, f, indent=1)

@@ -45,8 +45,9 @@ def main(argv=None):
     ap.add_argument("--space", default="all",
                     choices=("train", "serve", "all"))
     ap.add_argument("--smoke", action="store_true",
-                    help="tiny geometry + trimmed serve axes (CPU-lane "
-                         "end-to-end in minutes)")
+                    help="tiny geometry + trimmed serve axes: the CPU "
+                         "correctness lane, the only one that runs "
+                         "without a TPU")
     ap.add_argument("--out", default=os.path.join(REPO, "TUNED.json"))
     ap.add_argument("--log", default=None,
                     help="probe-log JSONL (default <out>.probes.jsonl); "
@@ -82,7 +83,7 @@ def main(argv=None):
     from paddle_tpu.tuning import driver, probe, space, static_cost
     from paddle_tpu.tuning import tuned as tuned_mod
 
-    di = probe.device_info()
+    di = probe.require_tpu("tools/autotune.py", args.smoke)
     fp = probe.hw_fingerprint(di)
     print(f"[autotune] device: {di.platform}/{di.device_kind} "
           f"x{di.n_devices} degraded={di.degraded} "

@@ -15,12 +15,14 @@ Per config it measures:
   * the 5-step loss trajectory, and for the f32 reduce-scatter config a
     bit-parity check against the psum baseline.
 
-Defaults run the 8-virtual-device CPU mesh (dp=8) end to end; the TPU lane
-re-runs the same matrix via tools/run_tpu_session5.sh. Emits one JSON row
-per config on stdout and writes COMM_BENCH.json.
+``--smoke`` runs the 8-virtual-device CPU mesh (dp=8) end to end: a
+correctness lane for bytes and bit parity, labelled ``degraded``. Without
+``--smoke`` the bench measures a real mesh and refuses a backend that is
+not a TPU. Emits one JSON row per config on stdout and writes
+COMM_BENCH.json.
 
-  JAX_PLATFORMS=cpu python tools/comm_bench.py --out COMM_BENCH.json
-  python tools/comm_bench.py --dp 4 --steps 8 --profile-overlap
+  python tools/comm_bench.py --smoke --out COMM_BENCH.json
+  python tools/comm_bench.py --dp 4 --steps 8 --profile-overlap   # 4 chips
 """
 import argparse
 import json
@@ -32,17 +34,24 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-# must precede the first jax import: the CPU mesh needs 8 virtual devices
-if "JAX_PLATFORMS" not in os.environ:
-    os.environ["JAX_PLATFORMS"] = "cpu"
-if os.environ.get("JAX_PLATFORMS") == "cpu" and \
-        "host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=8")
 
-from paddle_tpu.sysconfig import tpu_perf_flags  # noqa: E402
 
-tpu_perf_flags()  # no-op off-TPU (platform gate); must precede backend init
+def _prepare_backend(smoke: bool) -> None:
+    """Before the first jax import: the smoke lane's CPU mesh needs 8
+    virtual devices; the measuring lane needs the overlap preset in
+    LIBTPU_INIT_ARGS before libtpu loads."""
+    if smoke:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        if "host_platform_device_count" not in os.environ.get(
+                "XLA_FLAGS", ""):
+            os.environ["XLA_FLAGS"] = (
+                os.environ.get("XLA_FLAGS", "")
+                + " --xla_force_host_platform_device_count=8")
+    else:
+        from paddle_tpu.sysconfig import tpu_perf_flags
+
+        tpu_perf_flags()
+
 
 CONFIGS = (
     # (name, make_train_step kwargs)
@@ -171,6 +180,9 @@ def run_config(name, kw, cfg, pcfg, mesh, tokens, labels, steps,
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=os.path.join(REPO, "COMM_BENCH.json"))
+    ap.add_argument("--smoke", action="store_true",
+                    help="CPU correctness lane on 8 virtual devices (the "
+                         "only lane that runs without a TPU)")
     ap.add_argument("--dp", type=int, default=8)
     ap.add_argument("--pp", type=int, default=1)
     ap.add_argument("--tp", type=int, default=1)
@@ -191,15 +203,19 @@ def main():
     ap.add_argument("--monitor", default=None,
                     help="also write TrainMonitor JSONL rows per config")
     args = ap.parse_args()
+    _prepare_backend(args.smoke)
 
     import numpy as np
     import jax
     import jax.numpy as jnp
 
+    from paddle_tpu.framework.core import ensure_compile_cache
     from paddle_tpu.models import gpt as G
     from paddle_tpu.parallel import parallelize as PZ
+    from paddle_tpu.tuning.probe import require_tpu
 
-    dev = jax.devices()[0]
+    dev = require_tpu("tools/comm_bench.py", args.smoke).device
+    ensure_compile_cache()
     on_acc = dev.platform != "cpu"
     if args.profile_overlap is None:
         args.profile_overlap = True  # cheap at this scale; honest 0 on CPU

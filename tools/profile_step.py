@@ -444,6 +444,13 @@ def compare_attributions(path_a: str, path_b: str, out=sys.stdout):
 
 
 def main():
+    if "--compare" not in sys.argv:
+        from paddle_tpu.framework.core import ensure_compile_cache
+        from paddle_tpu.tuning.probe import require_tpu
+
+        require_tpu("tools/profile_step.py",
+                                 "--smoke" in sys.argv)
+        ensure_compile_cache()
     trace_dir = _flag("--dir", "/tmp/gpt-trace")
     attr_out = _flag("--attr-out")
     tuned = _flag("--tuned") or next(

@@ -21,9 +21,10 @@ measured p99 TTFT breaks the SLO — ``max_sustainable_rps`` makes "how
 many chips for N users" a measured number (chips x max_rps / per-user
 rate).
 
-CPU lane (default sizes) is labeled ``cpu_smoke`` — dispatch-bound, it
-validates the mechanism and the zero-recompile contract, not absolute
-throughput. The TPU lane is queued in tools/run_tpu_session7.sh.
+``--smoke`` is the CPU correctness lane, labeled ``cpu_smoke`` —
+dispatch-bound, it validates the mechanism and the zero-recompile
+contract, not throughput. Without ``--smoke`` the bench measures, and
+refuses a backend that is not a TPU.
 
   JAX_PLATFORMS=cpu python tools/serve_bench.py --smoke --out SERVE_BENCH.json
 """
@@ -530,7 +531,8 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=os.path.join(REPO, "SERVE_BENCH.json"))
     ap.add_argument("--smoke", action="store_true",
-                    help="short CPU-sized run")
+                    help="short CPU-sized correctness run (the only "
+                         "lane that runs without a TPU)")
     ap.add_argument("--d", type=int, default=64)
     ap.add_argument("--layers", type=int, default=4)
     ap.add_argument("--nh", type=int, default=4)
@@ -577,8 +579,12 @@ def main(argv=None):
 
     import jax
 
+    from paddle_tpu.framework.core import ensure_compile_cache
     from paddle_tpu.models import gpt
+    from paddle_tpu.tuning.probe import require_tpu
 
+    require_tpu("tools/serve_bench.py", args.smoke)
+    ensure_compile_cache()
     if args.smoke:
         args.rates, args.requests = "16,64", 24
         args.eval_len = 24
