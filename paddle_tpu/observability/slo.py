@@ -364,16 +364,28 @@ class SLOEngine:
         obj = next(o for o in self.objectives if o.name == objective)
         offenders = [s for s in recent if obj.is_bad(s)]
         worst = offenders[-1] if offenders else None
-        spans = []
+        spans, ticks = [], []
         if worst and worst.get("trace_id") is not None:
-            spans = _spans.default_tracer().trace_spans(
-                worst["trace_id"])
+            tracer = _spans.default_tracer()
+            spans = tracer.trace_spans(worst["trace_id"])
+            # the request's own trace holds no record per token: the
+            # ticks it rode are the loop's, found by step
+            for root in spans:
+                at = root.get("attrs") or {}
+                if (root["name"] == "serve/request"
+                        and at.get("first_step") is not None
+                        and at.get("last_step") is not None):
+                    ticks = [t for t in tracer.attr_range(
+                        "serve/decode_tick", "step", at["first_step"],
+                        at["last_step"])
+                        if at["request_id"] in t["attrs"]["riders"]]
         payload = {
             "kind": "slo_breach",
             "objective": objective,
             "status": status["objectives"].get(objective),
             "worst_request": worst,
             "trace_spans": spans,
+            "ticks_ridden": ticks,
         }
         if self.state_fn is not None:
             try:

@@ -14,22 +14,36 @@ is one timed operation with identity:
   checkpoint async-save writer) attaches the submitting thread's context
   with :meth:`SpanTracer.context` and its spans parent correctly instead
   of orphaning;
-- timestamps are ``time.perf_counter_ns`` — the SAME clock profiler.py
-  host events use, so spans drop into the merged chrome trace
-  (trace_merge.py) as their own plane with no cross-clock alignment.
+- timestamps are :data:`clock_ns` (``time.perf_counter_ns``) — the SAME
+  clock profiler.py host events use, so spans drop into the merged chrome
+  trace (trace_merge.py) as their own plane with no cross-clock
+  alignment.  Code that holds a ``time.monotonic()`` instant (a request's
+  stamps, a benchmark's window) turns it into these units with
+  :func:`monotonic_to_ns` and never assumes the two clocks are one;
+- a span opened with :meth:`SpanTracer.span` is also a
+  ``jax.profiler.TraceAnnotation`` called ``paddle/<name>`` while it is
+  open.  Without a profiler session that is a no-op inside jax; with one
+  (profiler.py, a benchmark's traced run, an operator's capture) the span
+  lies on the xplane's host plane, on the profiler's clock, beside the
+  device operations.  A process that has not loaded jax (the stub replica
+  worker) is never made to.
 
 Cost model (the dispatch-overhead gate in tools/dispatch_bench.py holds
 tracing to <5% of the fast path): a disabled tracer is one global read;
-an enabled :func:`record` is two dict builds and a deque append; the
-:meth:`span` context manager adds two ``perf_counter_ns`` calls.  Spans
-land in a bounded ring (old spans fall off) and, when a JSONL sink is
-set, one flushed line per span.
+an enabled :func:`record` builds one tuple and appends it under a lock;
+the :meth:`span` context manager adds two clock reads and the
+annotation.  Records land in a bounded ring of :data:`RING` — old ones
+fall off and ``dropped`` counts them, so a reader can tell a window it
+still holds whole from one it does not — and, when a JSONL sink is set,
+one flushed line per span.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import itertools
 import json
+import sys
 import threading
 import time
 from typing import Any, Dict, IO, List, Optional, Tuple, Union
@@ -38,8 +52,43 @@ __all__ = [
     "SpanTracer", "default_tracer", "span", "record", "current_context",
     "gen_id", "set_tracing_enabled", "tracing_enabled",
     "WIRE_KEY", "inject", "extract", "attach_process_sink",
-    "process_sink_path",
+    "process_sink_path", "clock_ns", "monotonic_to_ns", "RING",
+    "ANNOTATION_PREFIX",
 ]
+
+# the one clock every record is stamped with
+clock_ns = time.perf_counter_ns
+RING = 65536
+ANNOTATION_PREFIX = "paddle/"
+
+_monotonic_offset_ns = None
+
+
+def monotonic_to_ns(t: float) -> int:
+    """A ``time.monotonic()`` instant in :data:`clock_ns` units.  The
+    offset between the two clocks is read once (zero where both are
+    CLOCK_MONOTONIC, which no caller should count on)."""
+    global _monotonic_offset_ns
+    if _monotonic_offset_ns is None:
+        a = clock_ns()
+        m = time.monotonic()
+        b = clock_ns()
+        _monotonic_offset_ns = (a + b) // 2 - int(m * 1e9)
+    return int(t * 1e9) + _monotonic_offset_ns
+
+
+_annotation = None
+
+
+def _annotation_class():
+    """``jax.profiler.TraceAnnotation`` once this process has loaded jax,
+    else None: tracing never imports it."""
+    global _annotation
+    if _annotation is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
 
 # process-wide kill switch, mirroring metrics.set_metrics_enabled — the
 # tracing on/off A/B in tools/dispatch_bench.py throws this
@@ -132,20 +181,50 @@ def attach_process_sink(trace_dir: str, role: str = "proc") -> str:
     return path
 
 
+# a finished span in the ring: a plain tuple in this order, turned into the
+# documented dict only where it is handed out (spans(), the JSONL sink)
+_FIELDS = ("name", "trace", "span", "parent", "start_ns", "dur_ns", "tid",
+           "thread", "attrs")
+_NAME, _TRACE, _START, _DUR, _ATTRS = 0, 1, 4, 5, 8
+
+
+def _as_dict(rec: tuple) -> dict:
+    d = dict(zip(_FIELDS, rec))
+    if not d["attrs"]:
+        del d["attrs"]
+    return d
+
+
 class _OpenSpan:
     __slots__ = ("tracer", "name", "trace", "span_id", "parent", "attrs",
-                 "t0")
+                 "prev", "t0", "ann")
 
-    def __init__(self, tracer, name, trace, span_id, parent, attrs):
+    def __init__(self, tracer, name, trace, span_id, parent, attrs, prev):
         self.tracer = tracer
         self.name = name
         self.trace = trace
         self.span_id = span_id
         self.parent = parent
         self.attrs = attrs
+        self.prev = prev            # the thread's context to go back to
 
     def __enter__(self):
-        self.t0 = time.perf_counter_ns()
+        cls = _annotation_class()
+        if cls is not None:
+            self.ann = cls(ANNOTATION_PREFIX + self.name)
+            self.ann.__enter__()
+        else:
+            self.ann = None
+        self.t0 = clock_ns()
+        if self.tracer._sink is not None:
+            # announce the open span (dur 0, attrs.open; the full record
+            # supersedes it, tools/trace_assemble.py): children flush at
+            # their own end, before this one's, and a process SIGKILLed
+            # in between must leave their parent on disk
+            self.tracer._to_sink((
+                self.name, self.trace, self.span_id, self.parent, self.t0,
+                0, threading.get_ident(), threading.current_thread().name,
+                {"open": True}))
         return self
 
     def set_attr(self, key: str, value) -> None:
@@ -154,19 +233,16 @@ class _OpenSpan:
         self.attrs[key] = value
 
     def __exit__(self, exc_type, exc, tb):
-        t1 = time.perf_counter_ns()
+        t1 = clock_ns()
+        if self.ann is not None:
+            self.ann.__exit__(exc_type, exc, tb)
         tr = self.tracer
-        tls = tr._tls
-        tls.ctx = (self.trace, self.parent) if self.parent else None
+        tr._tls.ctx = self.prev
         if exc_type is not None:
             self.set_attr("error", exc_type.__name__)
-        tr._append({
-            "name": self.name, "trace": self.trace, "span": self.span_id,
-            "parent": self.parent, "start_ns": self.t0,
-            "dur_ns": t1 - self.t0, "tid": threading.get_ident(),
-            "thread": threading.current_thread().name,
-            **({"attrs": self.attrs} if self.attrs else {}),
-        })
+        tr._append((self.name, self.trace, self.span_id, self.parent,
+                    self.t0, t1 - self.t0, threading.get_ident(),
+                    threading.current_thread().name, self.attrs or None))
         return False
 
 
@@ -191,11 +267,14 @@ _NULL = _NullSpan()
 class SpanTracer:
     """Bounded-ring span recorder with thread-local context propagation."""
 
-    def __init__(self, ring: int = 4096,
+    def __init__(self, ring: int = RING,
                  sink: Optional[Union[str, IO]] = None):
-        import collections
-
         self._ring = collections.deque(maxlen=int(ring))
+        self._ring_lock = threading.Lock()
+        # records that fell off the ring's old end since the tracer was
+        # made: a reader whose window may reach back past the oldest
+        # record left must not take what remains for the whole
+        self.dropped = 0
         self._tls = threading.local()
         self._sink: Optional[IO] = None
         self._own_sink = False
@@ -224,22 +303,27 @@ class SpanTracer:
 
     # -- recording --------------------------------------------------------
     def span(self, name: str, trace: Optional[int] = None,
-             attrs: Optional[Dict[str, Any]] = None):
+             attrs: Optional[Dict[str, Any]] = None,
+             parent: Optional[int] = None):
         """Context manager timing one span.  Inherits trace + parent from
-        the thread-local context unless ``trace`` starts a new one."""
+        the thread-local context unless ``trace`` starts a new one (or,
+        with ``parent``, names the span of that trace to hang under).
+        Leaving it puts the thread back in the context it was opened
+        in, whatever trace that was."""
         if not _ENABLED:
             return _NULL
         ctx = getattr(self._tls, "ctx", None)
         if trace is not None:
-            trace_id, parent = trace, (ctx[1] if ctx and ctx[0] == trace
-                                       else None)
+            trace_id = trace
+            if parent is None and ctx and ctx[0] == trace:
+                parent = ctx[1]
         elif ctx is not None:
             trace_id, parent = ctx
         else:
             trace_id, parent = gen_id(), None
         span_id = gen_id()
         self._tls.ctx = (trace_id, span_id)
-        return _OpenSpan(self, name, trace_id, span_id, parent, attrs)
+        return _OpenSpan(self, name, trace_id, span_id, parent, attrs, ctx)
 
     def record(self, name: str, start_ns: int, dur_ns: int,
                trace: Optional[int] = None, parent: Optional[int] = None,
@@ -263,21 +347,25 @@ class SpanTracer:
                 trace = gen_id()
         if span_id is None:
             span_id = gen_id()
-        self._append({
-            "name": name, "trace": trace, "span": span_id,
-            "parent": parent, "start_ns": int(start_ns),
-            "dur_ns": int(dur_ns), "tid": threading.get_ident(),
-            "thread": threading.current_thread().name,
-            **({"attrs": attrs} if attrs else {}),
-        })
+        self._append((name, trace, span_id, parent, int(start_ns),
+                      int(dur_ns), threading.get_ident(),
+                      threading.current_thread().name, attrs or None))
         return span_id
 
-    def _append(self, rec: dict) -> None:
-        self._ring.append(rec)
-        sink = self._sink
-        if sink is not None:
-            with self._sink_lock:
-                sink.write(json.dumps(rec) + "\n")
+    def _append(self, rec: tuple) -> None:
+        ring = self._ring
+        with self._ring_lock:
+            if len(ring) == ring.maxlen:
+                self.dropped += 1
+            ring.append(rec)
+        if self._sink is not None:
+            self._to_sink(rec)
+
+    def _to_sink(self, rec: tuple) -> None:
+        with self._sink_lock:
+            sink = self._sink
+            if sink is not None:
+                sink.write(json.dumps(_as_dict(rec)) + "\n")
                 sink.flush()
 
     # -- sinks / introspection --------------------------------------------
@@ -297,7 +385,7 @@ class SpanTracer:
 
     def spans(self) -> List[dict]:
         """Snapshot of the ring, oldest first."""
-        return list(self._ring)
+        return [_as_dict(r) for r in list(self._ring)]
 
     def clear(self) -> None:
         self._ring.clear()
@@ -306,8 +394,8 @@ class SpanTracer:
         """Per-name percentile rollup over the ring:
         {name: {count, total_ms, p50_ms, p90_ms, p99_ms, max_ms}}."""
         by_name: Dict[str, List[float]] = {}
-        for s in list(self._ring):
-            by_name.setdefault(s["name"], []).append(s["dur_ns"] / 1e6)
+        for r in list(self._ring):
+            by_name.setdefault(r[_NAME], []).append(r[_DUR] / 1e6)
         out: Dict[str, dict] = {}
         for name, vals in sorted(by_name.items()):
             vals.sort()
@@ -327,9 +415,21 @@ class SpanTracer:
     def trace_spans(self, trace_id: int) -> List[dict]:
         """Every ring span of one trace, in start order (the p99->cause
         walk: feed it the trace id stamped on a slow request)."""
-        return sorted((s for s in list(self._ring)
-                       if s["trace"] == trace_id),
-                      key=lambda s: s["start_ns"])
+        return [_as_dict(r) for r in sorted(
+            (r for r in list(self._ring) if r[_TRACE] == trace_id),
+            key=lambda r: r[_START])]
+
+    def attr_range(self, name: str, key: str, lo, hi) -> List[dict]:
+        """Ring spans called ``name`` whose ``attrs[key]`` lies in
+        [lo, hi], in start order: from a request's ``first_step`` and
+        ``last_step`` to the ``serve/decode_tick`` records it rode."""
+        out = []
+        for r in list(self._ring):
+            if r[_NAME] == name and r[_ATTRS] is not None:
+                v = r[_ATTRS].get(key)
+                if v is not None and lo <= v <= hi:
+                    out.append(r)
+        return [_as_dict(r) for r in sorted(out, key=lambda r: r[_START])]
 
 
 _default = SpanTracer()
@@ -340,9 +440,10 @@ def default_tracer() -> SpanTracer:
 
 
 def span(name: str, trace: Optional[int] = None,
-         attrs: Optional[Dict[str, Any]] = None):
+         attrs: Optional[Dict[str, Any]] = None,
+         parent: Optional[int] = None):
     """Module-level :meth:`SpanTracer.span` on the default tracer."""
-    return _default.span(name, trace=trace, attrs=attrs)
+    return _default.span(name, trace=trace, attrs=attrs, parent=parent)
 
 
 def record(name: str, start_ns: int, dur_ns: int, **kw) -> Optional[int]:

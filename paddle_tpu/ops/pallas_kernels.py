@@ -178,27 +178,29 @@ def _fwd(q, k, v, bias, causal, sm_scale, block_q, block_k):
     if bias is not None:
         in_specs.append(_bias_spec(bias, bh, block_q, block_k))
         args.append(bias)
-    o, lse = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, hd), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_q, NUM_LANES), lambda b, qi, ki: (b, qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t, hd), q.dtype),
-            jax.ShapeDtypeStruct((bh, t, NUM_LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, NUM_LANES), jnp.float32),
-            pltpu.VMEM((block_q, NUM_LANES), jnp.float32),
-            pltpu.VMEM((block_q, hd), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
-    )(*args)
+    with jax.named_scope("flash_attention"):
+        o, lse = pl.pallas_call(
+            kern,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, block_q, hd), lambda b, qi, ki: (b, qi, 0)),
+                pl.BlockSpec((1, block_q, NUM_LANES), lambda b, qi, ki: (b, qi, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, t, hd), q.dtype),
+                jax.ShapeDtypeStruct((bh, t, NUM_LANES), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, NUM_LANES), jnp.float32),
+                pltpu.VMEM((block_q, NUM_LANES), jnp.float32),
+                pltpu.VMEM((block_q, hd), jnp.float32),
+            ],
+            compiler_params=_CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=_interpret(),
+            name="flash_fwd",
+        )(*args)
     return o, lse
 
 
@@ -351,17 +353,19 @@ def _bwd(q, k, v, o, lse, do, bias, causal, sm_scale, block_q, block_k):
     if has_bias:
         in_specs.append(_bias_spec(bias, bh, block_q, block_k))
         args.append(bias)
-    dq = pl.pallas_call(
-        dq_kern,
-        grid=(bh, nq, nk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_q, hd), lambda b, qi, ki: (b, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, t, hd), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
-    )(*args)
+    with jax.named_scope("flash_attention"):
+        dq = pl.pallas_call(
+            dq_kern,
+            grid=(bh, nq, nk),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, block_q, hd), lambda b, qi, ki: (b, qi, 0)),
+            out_shape=jax.ShapeDtypeStruct((bh, t, hd), q.dtype),
+            scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
+            compiler_params=_CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=_interpret(),
+            name="flash_bwd_dq",
+        )(*args)
 
     dkv_kern = functools.partial(
         _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
@@ -383,26 +387,28 @@ def _bwd(q, k, v, o, lse, do, bias, causal, sm_scale, block_q, block_k):
 
         in_specs2.append(pl.BlockSpec(bspec.block_shape, idx2))
         args2.append(bias)
-    dk, dv = pl.pallas_call(
-        dkv_kern,
-        grid=(bh, nk, nq),
-        in_specs=in_specs2,
-        out_specs=[
-            pl.BlockSpec((1, block_k, hd), lambda b, ki, qi: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, hd), lambda b, ki, qi: (b, ki, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, tk, hd), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk, hd), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, hd), jnp.float32),
-            pltpu.VMEM((block_k, hd), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
-    )(*args2)
+    with jax.named_scope("flash_attention"):
+        dk, dv = pl.pallas_call(
+            dkv_kern,
+            grid=(bh, nk, nq),
+            in_specs=in_specs2,
+            out_specs=[
+                pl.BlockSpec((1, block_k, hd), lambda b, ki, qi: (b, ki, 0)),
+                pl.BlockSpec((1, block_k, hd), lambda b, ki, qi: (b, ki, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, tk, hd), k.dtype),
+                jax.ShapeDtypeStruct((bh, tk, hd), v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, hd), jnp.float32),
+                pltpu.VMEM((block_k, hd), jnp.float32),
+            ],
+            compiler_params=_CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=_interpret(),
+            name="flash_bwd_dkv",
+        )(*args2)
     return dq, dk, dv
 
 

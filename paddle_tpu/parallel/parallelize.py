@@ -30,6 +30,7 @@ is batch-partial over dp.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
@@ -911,37 +912,46 @@ def _wrap_step_with_report(step, pcfg: ParallelConfig, report_name: str,
     from ..framework.core import ensure_compile_cache
     from ..observability import goodput as _goodput
     from ..observability import program_report as _prep
+    from ..observability import spans as _spans
 
     aot = {}            # (tokens, labels) aval signature -> executable
+    calls = itertools.count()
 
     def step_with_report(params, opt_state, tokens, labels):
         # hang-watchdog progress stamp (docs/health.md): one tuple store
         _health.progress("train_step")
-        sig = (tokens.shape, str(tokens.dtype), labels.shape,
-               str(labels.dtype))
-        compiled = aot.get(sig)
-        if compiled is None:
-            import time as _time
+        # the host's part of a step: the dispatch of the compiled step
+        # (the loss fetch is the caller's), numbered by call
+        with _spans.span("train/step", attrs={"seq": next(calls)}):
+            sig = (tokens.shape, str(tokens.dtype), labels.shape,
+                   str(labels.dtype))
+            compiled = aot.get(sig)
+            if compiled is None:
+                compiled = aot[sig] = _compile(params, opt_state, tokens,
+                                               labels)
+            with _goodput.timer("productive_step"):
+                return compiled(params, opt_state, tokens, labels)
 
-            ensure_compile_cache()
-            t0 = _time.perf_counter_ns()
-            # first-call XLA compile can run for minutes: pause the
-            # hang-watchdog deadline clock for its duration
-            with _health.suspend():
-                compiled = step.lower(params, opt_state, tokens,
-                                      labels).compile()
-            aot[sig] = compiled
-            _prep.capture(
-                report_name, compiled=compiled,
-                compile_ms=(_time.perf_counter_ns() - t0) / 1e6,
-                donated=["params", "opt_state"],
-                inputs=(params, opt_state, tokens, labels),
-                extra={"mode": extra_mode,
-                       "mesh": {a: int(s) for a, s in
-                                zip(pcfg.axis_names,
-                                    (pcfg.dp, pcfg.pp, pcfg.tp))}})
-        with _goodput.timer("productive_step"):
-            return compiled(params, opt_state, tokens, labels)
+    def _compile(params, opt_state, tokens, labels):
+        import time as _time
+
+        ensure_compile_cache()
+        t0 = _time.perf_counter_ns()
+        # first-call XLA compile can run for minutes: pause the
+        # hang-watchdog deadline clock for its duration
+        with _spans.span("train/compile"), _health.suspend():
+            compiled = step.lower(params, opt_state, tokens,
+                                  labels).compile()
+        _prep.capture(
+            report_name, compiled=compiled,
+            compile_ms=(_time.perf_counter_ns() - t0) / 1e6,
+            donated=["params", "opt_state"],
+            inputs=(params, opt_state, tokens, labels),
+            extra={"mode": extra_mode,
+                   "mesh": {a: int(s) for a, s in
+                            zip(pcfg.axis_names,
+                                (pcfg.dp, pcfg.pp, pcfg.tp))}})
+        return compiled
 
     def _hlo_text():
         # optimized HLO of the newest kept executable (None before the

@@ -208,6 +208,9 @@ class DecodeEngine:
         # (the slabs are invalidated by donation, so no later call can be
         # trusted) — every serving entrypoint refuses from then on
         self.poisoned: Optional[str] = None
+        # Scheduler.steps of the step now running, stamped by the
+        # scheduler so that serve/prefill names the step it ran in
+        self.sched_step: Optional[int] = None
         # optional persistent prefix store (serving/prefix_store.py):
         # published pages survive restarts — attach_prefix_store()
         self.prefix_store = None
@@ -888,74 +891,76 @@ class DecodeEngine:
         sp_scalars = (np.float32(params.temperature),
                       np.int32(params.top_k), np.float32(params.top_p),
                       np.int32(np.uint32(params.seed)))
-        if self.paged:
-            return self._start_paged(tokens, n, sp_scalars)
-        bucket = self.bucket_for(n)
-        exe = self._prefill_exec(bucket)
-        slot = self.cache.alloc(length=n)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :n] = np.asarray(tokens, np.int32)
-        t0 = time.perf_counter_ns()
-        try:
-            ck, cv, logits, tok = exe(
-                self.qparams, self.cache.k, self.cache.v, padded,
-                np.int32(n), np.int32(slot), *sp_scalars)
-            logits = np.asarray(logits)
-            tok = int(tok)
-        except Exception as e:
-            self._poison_on_donation_failure(f"prefill_b{bucket}", e)
-            self.cache.free(slot)
-            raise
-        t1 = time.perf_counter_ns()
-        smetrics.m_prefill_ms.observe((t1 - t0) / 1e6)
-        smetrics.m_prefill_tokens.inc(n)
-        # inherits the scheduler's per-request span context (the admit
-        # path wraps this call in the request's trace)
-        _spans.record("serve/prefill", t0, t1 - t0,
-                      attrs={"bucket": bucket, "prompt_len": n,
-                             "slot": slot})
-        self.cache.k, self.cache.v = ck, cv
-        return slot, logits, tok
-
-    def _start_paged(self, tokens, n: int, sp_scalars):
-        prefix_len, prefix_pages = 0, ()
-        if self.prefix is not None:
-            prefix_len, prefix_pages = self.prefix.lookup(tokens)
-            prefix_len, prefix_pages = self._trim_prefix(
-                n, prefix_len, tuple(prefix_pages))
-        suffix = list(tokens[prefix_len:])
-        bucket = self.bucket_for(len(suffix))
-        exe = self._prefill_exec(bucket)
-        slot = self.cache.alloc(length=n, prefix_pages=prefix_pages)
-        table_row = self.cache.table_row(slot)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :len(suffix)] = np.asarray(suffix, np.int32)
-        t0 = time.perf_counter_ns()
-        try:
-            kp, vp, logits, tok = exe(
-                self.qparams, self.cache.k, self.cache.v, padded,
-                np.int32(len(suffix)), np.int32(prefix_len), table_row,
+        # a real open span, under the scheduler's per-request context
+        # (the admit path wraps this call in the request's trace); its
+        # four phases are its children
+        attrs = {"prompt_len": n, "step": self.sched_step}
+        with _spans.span("serve/prefill", attrs=attrs):
+            if self.paged:
+                return self._start_paged(tokens, n, sp_scalars, attrs)
+            with _spans.span("prefill/prep"):
+                bucket = self.bucket_for(n)
+                exe = self._prefill_exec(bucket)
+                slot = self.cache.alloc(length=n)
+                padded = np.zeros((1, bucket), np.int32)
+                padded[0, :n] = np.asarray(tokens, np.int32)
+                attrs.update(bucket=bucket, prefix_len=0, slot=slot)
+            ck, cv, logits, tok = self._run_prefill(
+                exe, bucket, slot, n, padded, np.int32(n), np.int32(slot),
                 *sp_scalars)
-            logits = np.asarray(logits)
-            tok = int(tok)
+            with _spans.span("prefill/publish"):
+                self.cache.k, self.cache.v = ck, cv
+            return slot, logits, tok
+
+    def _run_prefill(self, exe, bucket: int, slot: int, n_tokens: int,
+                     *args):
+        """The prefill executable's call: ``prefill/run`` until the
+        sampled token is on the host (that waits for the program), then
+        ``prefill/fetch_logits``, the transfer of the logits alone."""
+        t0 = time.perf_counter_ns()
+        try:
+            with _spans.span("prefill/run"):
+                k, v, logits, tok = exe(
+                    self.qparams, self.cache.k, self.cache.v, *args)
+                tok = int(tok)
+            with _spans.span("prefill/fetch_logits"):
+                logits = np.asarray(logits)
         except Exception as e:
             self._poison_on_donation_failure(f"prefill_b{bucket}", e)
             self.cache.free(slot)
             raise
-        t1 = time.perf_counter_ns()
-        smetrics.m_prefill_ms.observe((t1 - t0) / 1e6)
-        smetrics.m_prefill_tokens.inc(len(suffix))
-        _spans.record("serve/prefill", t0, t1 - t0,
-                      attrs={"bucket": bucket, "prompt_len": n,
-                             "prefix_len": prefix_len, "slot": slot})
-        self.cache.k, self.cache.v = kp, vp
-        if self.prefix is not None:
-            added = self.prefix.insert(tokens, table_row)
-            if added and self.prefix_store is not None:
-                # persist at publish time: the pages just written are the
-                # ones a recycled replica restores (async, CRC-committed)
-                self.prefix_store.maybe_publish(tokens, table_row,
-                                                self.cache)
+        smetrics.m_prefill_ms.observe((time.perf_counter_ns() - t0) / 1e6)
+        smetrics.m_prefill_tokens.inc(n_tokens)
+        return k, v, logits, tok
+
+    def _start_paged(self, tokens, n: int, sp_scalars, attrs):
+        with _spans.span("prefill/prep"):
+            prefix_len, prefix_pages = 0, ()
+            if self.prefix is not None:
+                prefix_len, prefix_pages = self.prefix.lookup(tokens)
+                prefix_len, prefix_pages = self._trim_prefix(
+                    n, prefix_len, tuple(prefix_pages))
+            suffix = list(tokens[prefix_len:])
+            bucket = self.bucket_for(len(suffix))
+            exe = self._prefill_exec(bucket)
+            slot = self.cache.alloc(length=n, prefix_pages=prefix_pages)
+            table_row = self.cache.table_row(slot)
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, :len(suffix)] = np.asarray(suffix, np.int32)
+            attrs.update(bucket=bucket, prefix_len=prefix_len, slot=slot)
+        kp, vp, logits, tok = self._run_prefill(
+            exe, bucket, slot, len(suffix), padded, np.int32(len(suffix)),
+            np.int32(prefix_len), table_row, *sp_scalars)
+        with _spans.span("prefill/publish"):
+            self.cache.k, self.cache.v = kp, vp
+            if self.prefix is not None:
+                added = self.prefix.insert(tokens, table_row)
+                if added and self.prefix_store is not None:
+                    # persist at publish time: the pages just written are
+                    # the ones a recycled replica restores (async,
+                    # CRC-committed)
+                    self.prefix_store.maybe_publish(tokens, table_row,
+                                                    self.cache)
         return slot, logits, tok
 
     def resume_sequence_sampled(
@@ -974,10 +979,15 @@ class DecodeEngine:
         head = list(tokens[:self.buckets[-1]])
         slot, _logits, _tok = self.start_sequence_sampled(head, params)
         try:
-            for i in range(len(head), n - 1):
-                self.decode_step_sampled({slot: int(tokens[i])}, None)
-            out = self.decode_step_sampled(
-                {slot: int(tokens[n - 1])}, {slot: params})
+            # the replayed tail is prefill work too: a second
+            # serve/prefill beside the head's, the decode calls under it
+            with _spans.span("serve/prefill", attrs={
+                    "prompt_len": n, "replayed": n - len(head),
+                    "slot": slot, "step": self.sched_step}):
+                for i in range(len(head), n - 1):
+                    self.decode_step_sampled({slot: int(tokens[i])}, None)
+                out = self.decode_step_sampled(
+                    {slot: int(tokens[n - 1])}, {slot: params})
         except Exception:
             if self.poisoned is None and self.cache.is_live(slot):
                 self.cache.free(slot)
@@ -1038,42 +1048,46 @@ class DecodeEngine:
         if not slot_tokens:
             return {}
         self._check_poisoned()
-        tokens, positions = self._decode_feed(slot_tokens)
-        sp = samp.batch_arrays(params_by_slot or {}, self.ecfg.max_batch)
-        exe = self._decode_exec()
-        t0 = time.perf_counter_ns()
-        try:
+        # four phases, each a span under the scheduler's serve/decode_tick:
+        # feed (host arrays), run (the call until the sampled tokens are
+        # on the host: the small array first, it waits for the program),
+        # fetch_logits (the transfer alone), commit (host bookkeeping)
+        with _spans.span("decode/feed"):
+            tokens, positions = self._decode_feed(slot_tokens)
+            sp = samp.batch_arrays(params_by_slot or {},
+                                   self.ecfg.max_batch)
+            exe = self._decode_exec()
+            t0 = time.perf_counter_ns()
             if self.paged:
                 for slot in slot_tokens:
                     if not self.ensure_decode_capacity(slot):
                         raise PagePoolFullError(
                             f"slot {slot}: no free page for position "
                             f"{self.cache.length(slot)}")
-                tables = self._masked_tables(slot_tokens)
-                ck, cv, logits, toks = exe(
-                    self.qparams, self.cache.k, self.cache.v, tokens,
-                    positions, tables, *sp)
+                lanes = self._masked_tables(slot_tokens)
             else:
-                actives = np.zeros((self.ecfg.max_batch,), np.int32)
+                lanes = np.zeros((self.ecfg.max_batch,), np.int32)
                 for slot in slot_tokens:
-                    actives[slot] = 1
+                    lanes[slot] = 1
+        try:
+            with _spans.span("decode/run"):
                 ck, cv, logits, toks = exe(
                     self.qparams, self.cache.k, self.cache.v, tokens,
-                    positions, actives, *sp)
-            logits = np.asarray(logits)
-            toks = np.asarray(toks)
-        except PagePoolFullError:
-            raise                      # host-side: nothing was donated
+                    positions, lanes, *sp)
+                toks = np.asarray(toks)
+            with _spans.span("decode/fetch_logits"):
+                logits = np.asarray(logits)
         except Exception as e:
             self._poison_on_donation_failure("decode", e)
             raise
         smetrics.m_decode_ms.observe((time.perf_counter_ns() - t0) / 1e6)
-        self.cache.k, self.cache.v = ck, cv
-        out: Dict[int, Tuple[int, np.ndarray]] = {}
-        for slot in slot_tokens:
-            self.cache.set_length(slot, self.cache.length(slot) + 1)
-            out[slot] = (int(toks[slot]), logits[slot])
-        self.note_tokens(len(slot_tokens))
+        with _spans.span("decode/commit"):
+            self.cache.k, self.cache.v = ck, cv
+            out: Dict[int, Tuple[int, np.ndarray]] = {}
+            for slot in slot_tokens:
+                self.cache.set_length(slot, self.cache.length(slot) + 1)
+                out[slot] = (int(toks[slot]), logits[slot])
+            self.note_tokens(len(slot_tokens))
         return out
 
     def generate_step(

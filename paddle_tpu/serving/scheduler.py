@@ -103,9 +103,12 @@ class Request:
     finished: threading.Event = dataclasses.field(
         default_factory=threading.Event)
     # span identity (docs/observability.md): every lifecycle span of this
-    # request — queue wait, prefill, each decode tick, eviction — carries
-    # trace_id, parented under root_span ("serve/request"), so a slow p99
-    # walks straight back to the tick that caused it
+    # request — queue wait, prefill, eviction — carries trace_id, parented
+    # under root_span ("serve/request").  The ticks it rode are the loop's
+    # records, one a tick: first_step..last_step (Scheduler.steps at its
+    # prefill and at its eviction) is the range of serve/decode_tick
+    # records to look through, each naming its riders, so a slow p99
+    # still walks straight back to the tick that caused it
     trace_id: int = dataclasses.field(default_factory=_spans.gen_id)
     root_span: int = dataclasses.field(default_factory=_spans.gen_id)
     # cross-process propagation (ISSUE 18): a request arriving with wire
@@ -114,8 +117,14 @@ class Request:
     # fresh trace — one request stays ONE trace across router, prefill
     # replica, KV transfer, decode replica, and every failover retry
     parent_span: Optional[int] = None
-    submit_ns: int = dataclasses.field(
-        default_factory=time.perf_counter_ns)
+    # ``submitted`` on the tracer's clock: one stamp, two units
+    submit_ns: Optional[int] = None
+    first_step: Optional[int] = None
+    last_step: Optional[int] = None
+
+    def __post_init__(self):
+        if self.submit_ns is None:
+            self.submit_ns = _spans.monotonic_to_ns(self.submitted)
 
     def wait(self, timeout: Optional[float] = None) -> bool:
         return self.finished.wait(timeout)
@@ -154,6 +163,10 @@ class Scheduler:
         self._next_token: Dict[int, int] = {}     # slot -> token to feed
         self._admit_order: List[int] = []         # slots, oldest first
         self._lock = threading.Lock()
+        # every span the loop thread opens for its own work (serve/step
+        # and what lies under it) belongs to this one trace and carries
+        # the step's number; a request's own spans stay on its trace
+        self.loop_trace = _spans.gen_id()
         self._draining = False
         # set by abort_all(refuse_new=True) — the fail-fast path for a
         # poisoned engine: later submits get a clean error instead of
@@ -310,17 +323,24 @@ class Scheduler:
     def step(self) -> bool:
         """One serving tick: evict -> admit -> decode. Returns True when
         any work happened (False = idle, the loop may sleep)."""
-        now = time.monotonic()
-        self._expire_queued(now)
-        ingested = self._ingest_handoffs(now)
-        admitted = self._admit(now)
-        decoded = self._decode(now)
-        self.steps += 1
-        occ = self.engine.cache.occupancy
-        self.occupancy_sum += occ
-        smetrics.m_occupancy.set(occ)
-        smetrics.m_active.set(len(self._active))
-        return bool(ingested or admitted or decoded)
+        attrs = {"step": self.steps}
+        # the engine's spans (serve/prefill) name the step they ran in
+        self.engine.sched_step = self.steps
+        with _spans.span("serve/step", trace=self.loop_trace, attrs=attrs):
+            now = time.monotonic()
+            self._expire_queued(now)
+            ingested = self._ingest_handoffs(now)
+            admitted = self._admit(now)
+            decoded = self._decode(now)
+            self.steps += 1
+            occ = self.engine.cache.occupancy
+            self.occupancy_sum += occ
+            smetrics.m_occupancy.set(occ)
+            smetrics.m_active.set(len(self._active))
+            worked = bool(ingested or admitted or decoded)
+            attrs.update(worked=worked, prefills=admitted,
+                         active=len(self._active))
+        return worked
 
     def _ingest_handoffs(self, now: float) -> int:
         """Adopt migrated requests' KV payloads into the cache — at the
@@ -354,6 +374,7 @@ class Scheduler:
             req.handoff = None
             req.state = ACTIVE
             req.slot = slot
+            req.first_step = self.steps
             self._active[slot] = req
             self._next_token[slot] = req.tokens[-1]
             self._admit_order.append(slot)
@@ -505,12 +526,20 @@ class Scheduler:
     def _admit(self, now: float) -> int:
         """Prefill queued requests into free slots — FIFO with the
         head-of-line bypass above."""
+        if not self._queue:
+            return 0
+        attrs = {"admitted": 0}
+        with _spans.span("serve/admit", attrs=attrs):
+            attrs["admitted"] = admitted = self._admit_queued()
+        return admitted
+
+    def _admit_queued(self) -> int:
         admitted = 0
         while self.engine.cache.free_slot_count() > 0:
             req = self._pop_admissible()
             if req is None:
                 break
-            t_admit = time.perf_counter_ns()
+            t_admit = _spans.clock_ns()
             if req.prefix_blob is not None:
                 # gang-shared prefix record: adopt into the local pool
                 # first so the prefill below hits instead of recomputing.
@@ -554,6 +583,8 @@ class Scheduler:
             t = time.monotonic()
             req.state = ACTIVE
             req.slot = slot
+            if req.first_step is None:
+                req.first_step = self.steps
             resumed = req.preempted
             req.preempted = False
             if not resumed:
@@ -658,29 +689,33 @@ class Scheduler:
         feed = {slot: self._next_token[slot] for slot in self._active}
         params = {slot: self._active[slot].sampling
                   for slot in self._active}
-        t_tick0 = time.perf_counter_ns()
-        out = self.engine.generate_step(feed, params)
-        tick_ns = time.perf_counter_ns() - t_tick0
+        # ONE record a tick on the loop's trace: the whole batch shares
+        # one executable call, so the tick names its riders and a request
+        # finds its ticks by step (first_step..last_step), not the other
+        # way round
+        cache = self.engine.cache
+        with _spans.span("serve/decode_tick", attrs={
+                "step": self.steps, "batch": len(feed),
+                "riders": [r.id for r in self._active.values()],
+                "cached_tokens": sum(cache.length(s) for s in feed)}):
+            out = self.engine.generate_step(feed, params)
+        attrs = {"emitted": 0, "finished": 0}
+        with _spans.span("serve/emit", attrs=attrs):
+            self._emit(out, attrs)
+        return True
+
+    def _emit(self, out, counts) -> None:
+        """Hand a tick's tokens to their requests; finish and evict."""
         t = time.monotonic()
-        trace_on = _spans.tracing_enabled()
         for slot, emitted in out.items():
             req = self._active.get(slot)
             if req is None:
                 continue
-            if trace_on:
-                # per-tick decode span on the request's trace: the whole
-                # batch shares one executable call, so every rider gets
-                # the tick's wall time (batch size + emitted count in
-                # the attrs — speculative ticks emit several)
-                _spans.record("serve/decode_tick", t_tick0, tick_ns,
-                              trace=req.trace_id, parent=req.root_span,
-                              attrs={"batch": len(out),
-                                     "emitted": len(emitted),
-                                     "token_index": len(req.tokens)})
             finished = False
             for tok in emitted:
                 tok = int(tok)
                 req.tokens.append(tok)
+                counts["emitted"] += 1
                 if req.token_times:
                     self._tpot_hist.observe(
                         (t - req.token_times[-1]) * 1e3)
@@ -694,7 +729,8 @@ class Scheduler:
                     self.engine, "min_headroom", 1):
                 self._evict(slot, DONE, "max_seq reached",
                             reason="max_seq")
-        return True
+                finished = True
+            counts["finished"] += finished
 
     def _should_finish(self, req: Request, last_token: int) -> bool:
         eos = self.engine.ecfg.eos_id
@@ -711,13 +747,13 @@ class Scheduler:
         self._next_token.pop(slot, None)
         if slot in self._admit_order:
             self._admit_order.remove(slot)
-        t0 = time.perf_counter_ns()
-        self.engine.free_sequence(slot)
         reason = reason or self._EVICT_REASONS.get(state, state)
+        req.last_step = self.steps
+        with _spans.span("serve/evict", trace=req.trace_id,
+                         parent=req.root_span,
+                         attrs={"reason": reason, "slot": slot}):
+            self.engine.free_sequence(slot)
         smetrics.m_evictions.labels(reason).inc()
-        _spans.record("serve/evict", t0, time.perf_counter_ns() - t0,
-                      trace=req.trace_id, parent=req.root_span,
-                      attrs={"reason": reason, "slot": slot})
         self._finish(req, state, detail)
 
     def _finish(self, req: Request, state: str,
@@ -733,10 +769,12 @@ class Scheduler:
         # explicit span_id is what the lifecycle children parented to;
         # parent_span (when the request arrived with wire trace context)
         # links this process's subtree under the sender's span.
-        end = time.perf_counter_ns()
+        end = _spans.clock_ns()
         _spans.record("serve/request", req.submit_ns,
                       end - req.submit_ns, trace=req.trace_id,
                       parent=req.parent_span, span_id=req.root_span,
                       attrs={"state": state, "tokens": len(req.tokens),
-                             "request_id": req.id})
+                             "request_id": req.id,
+                             "first_step": req.first_step,
+                             "last_step": req.last_step})
         req.finished.set()

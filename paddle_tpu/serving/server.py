@@ -119,12 +119,16 @@ class EngineLoop:
     def _run(self) -> None:
         from ..parallel import health as _health
 
+        idle = None     # the open serve/loop_idle span, while parked
         while not self._stop.is_set():
             _health.progress("serve/tick")
             if self._check_poisoned():
-                return
+                break
             worked = False
             if self.scheduler.pending():
+                if idle is not None:
+                    idle.__exit__(None, None, None)
+                    idle = None
                 try:
                     worked = self.scheduler.step()
                 except Exception as e:
@@ -136,10 +140,20 @@ class EngineLoop:
                     except Exception:
                         pass  # never let cleanup kill the loop either
                     if self._check_poisoned():
-                        return
+                        break
             if not worked:
+                # one span for the whole stretch with nothing to do, not
+                # one a park: an empty server must not wash its requests'
+                # records out of the ring
+                if idle is None:
+                    idle = _ospans.span(
+                        "serve/loop_idle",
+                        trace=getattr(self.scheduler, "loop_trace", None))
+                    idle.__enter__()
                 self._wake.wait(timeout=self.idle_sleep_s)
                 self._wake.clear()
+        if idle is not None:
+            idle.__exit__(None, None, None)
 
     def _check_poisoned(self) -> bool:
         """Fail-fast on a poisoned engine: abort + refuse, fire

@@ -140,6 +140,14 @@ class SpecDecodeEngine:
         return self.target.poisoned or self.draft.poisoned
 
     @property
+    def sched_step(self):
+        return self.target.sched_step
+
+    @sched_step.setter
+    def sched_step(self, step):
+        self.target.sched_step = self.draft.sched_step = step
+
+    @property
     def compiles(self):
         return self.target.compiles + self.draft.compiles
 

@@ -404,7 +404,9 @@ def test_serving_request_spans_isolated(tiny_serving):
         fam = [s for s in ss if s["trace"] == req.trace_id]
         names = {s["name"] for s in fam}
         assert {"serve/request", "serve/queue_wait", "serve/prefill",
-                "serve/decode_tick", "serve/evict"} <= names, names
+                "serve/evict"} <= names, names
+        # a request gets no record a token: the ticks are the loop's
+        assert "serve/decode_tick" not in names
         # the dur-0 open sentinel (flushed at admission for crash
         # stitchability, ISSUE 18) shares the root's span id; the final
         # record is the one without attrs.open
@@ -418,9 +420,26 @@ def test_serving_request_spans_isolated(tiny_serving):
                 assert s["parent"] in own, (req.id, s)
         # no leakage: nothing from the other request's trace
         assert root["attrs"]["state"] == "done"
-    # decode ticks carry the batch size so a slow tick names its riders
-    tick = next(s for s in ss if s["name"] == "serve/decode_tick")
-    assert tick["attrs"]["batch"] >= 1
+        # from a request to the ticks it rode: the root's first_step ..
+        # last_step is a range of serve/decode_tick records, one a tick,
+        # each naming its riders
+        first, last = (root["attrs"]["first_step"],
+                       root["attrs"]["last_step"])
+        assert (first, last) == (req.first_step, req.last_step)
+        rode = [t for t in tr.attr_range("serve/decode_tick", "step",
+                                         first, last)
+                if req.id in t["attrs"]["riders"]]
+        # the prefill gives the first token, every later one is a tick
+        assert len(rode) == len(req.tokens) - 1
+        assert [t["attrs"]["step"] for t in rode] == list(
+            range(first, last + 1))
+    # exactly one record a tick, on the loop's one trace, with its batch
+    ticks = [s for s in ss if s["name"] == "serve/decode_tick"]
+    steps = [t["attrs"]["step"] for t in ticks]
+    assert len(set(steps)) == len(steps)
+    assert {t["trace"] for t in ticks} == {sched.loop_trace}
+    assert all(t["attrs"]["batch"] == len(t["attrs"]["riders"]) >= 1
+               for t in ticks)
     # loop-thread context never sticks: after the ticks the loop thread's
     # ambient context is clean (a fresh span starts a fresh trace)
     with tr.span("after") as sp:
@@ -444,8 +463,10 @@ def test_engine_loop_thread_spans_and_health_rollups(tiny_serving):
         assert r.wait(timeout=30) and r.state == "done"
         fam = [s for s in tr.spans() if s["trace"] == r.trace_id]
         names = {s["name"] for s in fam}
-        assert {"serve/request", "serve/prefill",
-                "serve/decode_tick"} <= names, names
+        assert {"serve/request", "serve/prefill"} <= names, names
+        ticks = [s for s in tr.spans() if s["name"] == "serve/decode_tick"
+                 and r.id in s["attrs"]["riders"]]
+        assert ticks and ticks[0]["thread"] == "serve-engine-loop"
         loop_side = [s for s in fam if s["name"] == "serve/prefill"]
         assert loop_side[0]["thread"] == "serve-engine-loop"
         health = front.health()

@@ -1,0 +1,437 @@
+"""ISSUE 26: the program's one tracer as the serving loop's and the train
+step's own clock: compact records in a ring that counts what falls off,
+open spans that are profiler annotations, one record a tick, the phases of
+a prefill and of a tick as children, and the walk from a request to the
+ticks it rode."""
+import glob
+import io
+import json
+import os
+import subprocess
+import sys
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from paddle_tpu import serving
+from paddle_tpu.models import gpt
+from paddle_tpu.observability import spans
+from paddle_tpu.parallel import parallelize as PZ
+from paddle_tpu.serving.server import EngineLoop
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture()
+def tracer():
+    tr = spans.default_tracer()
+    tr.clear()
+    yield tr
+    spans.set_tracing_enabled(True)
+
+
+@pytest.fixture(scope="module")
+def paged_engine():
+    cfg = gpt.GPT_TINY.scaled(num_layers=2, max_seq_len=64)
+    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    eng = serving.DecodeEngine(params, cfg, serving.EngineConfig(
+        max_batch=4, max_seq=32, prefill_buckets=(8, 16),
+        kv_layout="paged", page_size=8))
+    eng.warmup()
+    return eng
+
+
+def _serve(engine, prompts, max_new=4):
+    sched = serving.Scheduler(engine)
+    reqs = [sched.submit(p, max_new_tokens=max_new) for p in prompts]
+    for _ in range(64):
+        sched.step()
+        if all(r.finished.is_set() for r in reqs):
+            break
+    assert all(r.state == "done" for r in reqs)
+    return sched, reqs
+
+
+# ---------------------------------------------------------------------------
+# the tracer
+# ---------------------------------------------------------------------------
+
+def test_ring_counts_what_falls_off():
+    tr = spans.SpanTracer(ring=8)
+    assert spans.default_tracer()._ring.maxlen >= 65536
+    for i in range(8):
+        tr.record("r", i, 1, trace=1)
+    assert tr.dropped == 0 and len(tr.spans()) == 8
+    for i in range(5):
+        tr.record("r", 8 + i, 1, trace=1)
+    assert tr.dropped == 5
+    assert [s["start_ns"] for s in tr.spans()] == list(range(5, 13))
+
+
+def test_records_are_compact_and_handed_out_as_the_documented_dict(tracer):
+    with spans.span("outer", attrs={"k": 1}) as sp:
+        sp.set_attr("late", 2)
+        spans.record("timed_elsewhere", 10, 5)
+    assert all(type(r) is tuple for r in tracer._ring)
+    inner, outer = tracer.spans()
+    assert set(outer) == {"name", "trace", "span", "parent", "start_ns",
+                          "dur_ns", "tid", "thread", "attrs"}
+    assert outer["attrs"] == {"k": 1, "late": 2}
+    assert "attrs" not in inner and inner["parent"] == outer["span"]
+    assert tracer.summary()["outer"]["count"] == 1
+    assert [s["name"] for s in tracer.trace_spans(outer["trace"])] == [
+        "timed_elsewhere", "outer"]
+
+
+def test_a_span_of_another_trace_gives_the_thread_its_context_back(tracer):
+    with spans.span("loop", trace=1234) as loop:
+        with spans.span("theirs", trace=77, parent=5):
+            assert spans.current_context()[0] == 77
+        assert spans.current_context() == (1234, loop.span_id)
+        with spans.span("child"):
+            pass
+    assert spans.current_context() is None
+    by = {s["name"]: s for s in tracer.spans()}
+    assert (by["theirs"]["trace"], by["theirs"]["parent"]) == (77, 5)
+    assert by["child"]["parent"] == by["loop"]["span"]
+
+
+def test_one_clock_said_once():
+    assert spans.clock_ns is time.perf_counter_ns
+    m, ns = time.monotonic(), spans.clock_ns()
+    assert abs(spans.monotonic_to_ns(m) - ns) < 1e6
+    req = serving.scheduler.Request(prompt=[1], max_new_tokens=1,
+                                    deadline=0.0)
+    assert req.submit_ns == spans.monotonic_to_ns(req.submitted)
+
+
+def test_nothing_recorded_or_annotated_while_tracing_is_off(tracer,
+                                                            monkeypatch):
+    made = []
+    real = spans._annotation_class()
+    assert real is jax.profiler.TraceAnnotation
+    monkeypatch.setattr(spans, "_annotation",
+                        lambda name: made.append(name) or real(name))
+    spans.set_tracing_enabled(False)
+    with spans.span("off") as sp:
+        sp.set_attr("a", 1)
+    assert spans.record("off", 0, 1) is None
+    assert not made and not tracer.spans()
+    spans.set_tracing_enabled(True)
+    with spans.span("on"):
+        pass
+    assert made == ["paddle/on"]
+    assert [s["name"] for s in tracer.spans()] == ["on"]
+
+
+def test_tracing_loads_no_jax_into_a_process_that_has_none():
+    code = (
+        "import importlib.util, sys\n"
+        "spec = importlib.util.spec_from_file_location('spans', sys.argv[1])\n"
+        "m = importlib.util.module_from_spec(spec)\n"
+        "sys.modules['spans'] = m\n"
+        "spec.loader.exec_module(m)\n"
+        "with m.span('stub'):\n"
+        "    pass\n"
+        "assert m.default_tracer().spans()[0]['name'] == 'stub'\n"
+        "assert 'jax' not in sys.modules, 'tracing imported jax'\n")
+    path = os.path.join(REPO, "paddle_tpu", "observability", "spans.py")
+    proc = subprocess.run([sys.executable, "-c", code, path],
+                          capture_output=True, text=True, timeout=60)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+def test_a_sink_hears_of_an_open_span_before_its_children_end():
+    # a process SIGKILLed inside a span has flushed the children that
+    # ended; their parent must be on disk too (tools/trace_assemble.py
+    # counts a child without one as an orphan)
+    sink = io.StringIO()
+    tr = spans.SpanTracer(sink=sink)
+    with tr.span("parent"):
+        with tr.span("child"):
+            pass
+        lines = [json.loads(x) for x in sink.getvalue().splitlines()]
+        child = next(x for x in lines if x["name"] == "child"
+                     and not x.get("attrs"))
+        announced = [x for x in lines if x["name"] == "parent"]
+        assert len(announced) == 1 and announced[0]["attrs"] == {
+            "open": True} and announced[0]["dur_ns"] == 0
+        assert child["parent"] == announced[0]["span"]
+    final = [json.loads(x) for x in sink.getvalue().splitlines()][-1]
+    assert final["name"] == "parent" and "attrs" not in final
+    assert final["span"] == announced[0]["span"]
+    # the ring holds finished spans only
+    assert [s["name"] for s in tr.spans()] == ["child", "parent"]
+
+
+# ---------------------------------------------------------------------------
+# the serving loop's span tree
+# ---------------------------------------------------------------------------
+
+STEP_CHILDREN = {"serve/admit", "serve/decode_tick", "serve/emit"}
+TICK_PHASES = ["decode/feed", "decode/run", "decode/fetch_logits",
+               "decode/commit"]
+PREFILL_PHASES = ["prefill/prep", "prefill/run", "prefill/fetch_logits",
+                  "prefill/publish"]
+
+
+def test_span_tree_of_a_step_on_a_paged_engine(tracer, paged_engine,
+                                               monkeypatch):
+    # a tick of the length a real model's has (the tiny one's is under a
+    # millisecond, of which the spans' own cost is a few per cent)
+    exe = paged_engine._decode_exec()
+
+    def slow(*args):
+        time.sleep(0.02)
+        return exe(*args)
+
+    monkeypatch.setattr(paged_engine, "_decode_exec", lambda: slow)
+    sched, (r1, r2) = _serve(paged_engine, [[1, 2, 3, 4, 5], [6, 7, 8]],
+                             max_new=5)
+    ss = tracer.spans()
+    by_id = {s["span"]: s for s in ss}
+    loop = [s for s in ss if s["trace"] == sched.loop_trace]
+    steps = [s for s in loop if s["name"] == "serve/step"]
+    # every step is a root of the loop's one trace, numbered in order
+    assert [s["attrs"]["step"] for s in steps] == list(range(sched.steps))
+    assert all(s["parent"] is None for s in steps)
+    assert {s["name"] for s in loop} == (
+        {"serve/step"} | STEP_CHILDREN | set(TICK_PHASES))
+    for s in loop:
+        if s["name"] in STEP_CHILDREN:
+            assert by_id[s["parent"]]["name"] == "serve/step"
+        if s["name"] in TICK_PHASES:
+            assert by_id[s["parent"]]["name"] == "serve/decode_tick"
+    # the first step admitted both and ticked once
+    first = steps[0]
+    assert first["attrs"] == {"step": 0, "worked": True, "prefills": 2,
+                              "active": 2}
+    admit = next(s for s in loop if s["name"] == "serve/admit")
+    assert admit["parent"] == first["span"]
+    assert admit["attrs"] == {"admitted": 2}
+    # exactly one serve/decode_tick a tick, naming its riders
+    ticks = [s for s in loop if s["name"] == "serve/decode_tick"]
+    assert len(ticks) == len({t["attrs"]["step"] for t in ticks}) == 4
+    assert ticks[0]["attrs"] == {"step": 0, "batch": 2,
+                                 "riders": [r1.id, r2.id],
+                                 "cached_tokens": 5 + 3}
+    # a tick's four phases, in order, and they account for the tick
+    shares = []
+    for t in ticks:
+        kids = [s for s in ss if s["parent"] == t["span"]]
+        assert [k["name"] for k in kids] == TICK_PHASES
+        assert all(k["start_ns"] >= t["start_ns"] for k in kids)
+        shares.append(sum(k["dur_ns"] for k in kids) / t["dur_ns"])
+    assert all(x <= 1.0 for x in shares)
+    assert float(np.median(shares)) > 0.99, shares
+    # after each tick its tokens are handed out under serve/emit
+    emits = [s for s in loop if s["name"] == "serve/emit"]
+    assert [e["attrs"]["emitted"] for e in emits] == [2, 2, 2, 2]
+    assert [e["attrs"]["finished"] for e in emits] == [0, 0, 0, 2]
+    # a request's own trace: no record a token; its prefill is a real span
+    # under its root with the four phases as children, and names its step
+    for req in (r1, r2):
+        fam = tracer.trace_spans(req.trace_id)
+        assert sorted({s["name"] for s in fam}) == sorted(
+            ["serve/request", "serve/queue_wait", "serve/prefill",
+             "serve/evict"] + PREFILL_PHASES)
+        pre = next(s for s in fam if s["name"] == "serve/prefill")
+        assert pre["parent"] == req.root_span
+        assert pre["attrs"] == {"prompt_len": len(req.prompt), "step": 0,
+                                "bucket": 8, "prefix_len": 0,
+                                "slot": req.slot}
+        kids = [s for s in fam if s["parent"] == pre["span"]]
+        assert [k["name"] for k in kids] == PREFILL_PHASES
+        assert sum(k["dur_ns"] for k in kids) <= pre["dur_ns"]
+        # inside the step's serve/admit in time, though on its own trace
+        assert admit["start_ns"] <= pre["start_ns"] and (
+            pre["start_ns"] + pre["dur_ns"]
+            <= admit["start_ns"] + admit["dur_ns"])
+        evict = next(s for s in fam if s["name"] == "serve/evict")
+        assert evict["parent"] == req.root_span
+        assert evict["attrs"]["reason"] == "done"
+    # at most 12 records a tick from the loop, prefills and requests aside
+    assert len(loop) / len(ticks) <= 12
+
+
+def test_from_a_slow_request_to_the_ticks_it_rode(tracer, paged_engine):
+    sched = serving.Scheduler(paged_engine)
+    long = sched.submit([1, 2, 3], max_new_tokens=6)
+    sched.step()
+    sched.step()
+    late = sched.submit([4, 5, 6, 7], max_new_tokens=2)
+    for _ in range(16):
+        sched.step()
+    assert long.state == late.state == "done"
+    roots = {s["attrs"]["request_id"]: s for s in tracer.spans()
+             if s["name"] == "serve/request"
+             and not s["attrs"].get("open")}
+    for req, steps in ((long, [0, 1, 2, 3, 4]), (late, [2])):
+        root = roots[req.id]["attrs"]
+        assert (root["first_step"], root["last_step"]) == (
+            steps[0], steps[-1]) == (req.first_step, req.last_step)
+        rode = [t for t in tracer.attr_range(
+            "serve/decode_tick", "step", root["first_step"],
+            root["last_step"]) if req.id in t["attrs"]["riders"]]
+        assert [t["attrs"]["step"] for t in rode] == steps
+        assert len(rode) == len(req.tokens) - 1
+    # the tick both rode says so
+    shared = tracer.attr_range("serve/decode_tick", "step", 2, 2)[0]
+    assert shared["attrs"]["riders"] == [long.id, late.id]
+    assert shared["attrs"]["batch"] == 2
+    # a request that never ran has no steps to show
+    sched2 = serving.Scheduler(paged_engine)
+    gone = sched2.submit([1, 2], max_new_tokens=2)
+    assert sched2.cancel(gone)
+    root = [s for s in tracer.trace_spans(gone.trace_id)
+            if not s["attrs"].get("open")][0]
+    assert root["attrs"]["first_step"] is None
+    assert root["attrs"]["last_step"] is None
+
+
+def test_slo_forensic_dump_lists_the_ticks_the_worst_request_rode(
+        tracer, paged_engine, tmp_path):
+    from paddle_tpu.observability.slo import (DEFAULT_OBJECTIVES,
+                                              ForensicDir, SLOEngine)
+
+    _, (req, other) = _serve(paged_engine, [[1, 2, 3], [4, 5]], max_new=3)
+    fdir = ForensicDir(str(tmp_path / "forensics"), keep=4)
+    eng = SLOEngine(forensics=fdir, min_events=8)
+    target = next(o for o in DEFAULT_OBJECTIVES
+                  if o.name == "ttft_p99").target
+    for i in range(20):
+        eng.note_request(ttft_ms=target * 10, tpot_ms=1.0, code=200,
+                         trace_id=req.trace_id, request_id=str(req.id),
+                         t=1000.0 + i * 0.1)
+    assert eng.evaluate(1020.0)["ok"] is False
+    (name,) = fdir.files()
+    with open(os.path.join(fdir.dirname, name)) as f:
+        dump = json.load(f)
+    assert {s["name"] for s in dump["trace_spans"]} >= {
+        "serve/request", "serve/queue_wait", "serve/prefill", "serve/evict"}
+    ticks = dump["ticks_ridden"]
+    assert [t["attrs"]["step"] for t in ticks] == list(
+        range(req.first_step, req.last_step + 1))
+    assert all(t["name"] == "serve/decode_tick"
+               and req.id in t["attrs"]["riders"] for t in ticks)
+
+
+def test_engine_loop_parks_under_one_idle_span(tracer, paged_engine):
+    sched = serving.Scheduler(paged_engine)
+    loop = EngineLoop(sched, idle_sleep_s=0.001).start()
+    try:
+        time.sleep(0.05)                 # some fifty parks
+        r = sched.submit([1, 2, 3], max_new_tokens=2)
+        loop.wake()
+        assert r.wait(timeout=30) and r.state == "done"
+        time.sleep(0.02)
+    finally:
+        loop.stop()
+    assert not loop.alive
+    idle = [s for s in tracer.spans() if s["name"] == "serve/loop_idle"]
+    # one span a stretch with nothing to do (before the request, after
+    # it), not one a park: an idle server leaves the ring alone
+    assert 1 <= len(idle) <= 3
+    assert idle[0]["dur_ns"] >= 0.04e9
+    assert all(s["trace"] == sched.loop_trace and s["parent"] is None
+               and s["thread"] == "serve-engine-loop" for s in idle)
+    steps = [s for s in tracer.spans() if s["name"] == "serve/step"]
+    assert all(s["start_ns"] >= idle[0]["start_ns"] + idle[0]["dur_ns"]
+               for s in steps)
+
+
+# ---------------------------------------------------------------------------
+# the train step
+# ---------------------------------------------------------------------------
+
+class _Trainer:
+    """A tiny ``make_train_step`` with its (donated) state threaded on."""
+
+    def __init__(self):
+        cfg = gpt.GPT_TINY.scaled(num_layers=1, max_seq_len=16)
+        pcfg = PZ.ParallelConfig(dp=1, pp=1, tp=1)
+        mesh = PZ.build_mesh(pcfg, devices=jax.devices()[:1])
+        self.params, self.opt = PZ.init_sharded(
+            jax.random.PRNGKey(0), cfg, pcfg, mesh)
+        self.step = PZ.make_train_step(cfg, pcfg, mesh, lr=1e-3)
+        self.tokens = np.zeros((1, 2, 16), np.int32)
+
+    def run(self, n):
+        for _ in range(n):
+            self.params, self.opt, loss, _ = self.step(
+                self.params, self.opt, self.tokens, self.tokens)
+        return float(loss)
+
+
+@pytest.fixture(scope="module")
+def trainer():
+    return _Trainer()
+
+
+def test_train_step_spans(tracer, trainer):
+    trainer.run(3)
+    ss = tracer.spans()
+    steps = [s for s in ss if s["name"] == "train/step"]
+    seqs = [s["attrs"]["seq"] for s in steps]
+    assert seqs == list(range(seqs[0], seqs[0] + 3))
+    assert len({s["trace"] for s in steps}) == 3      # a trace a step
+    compiles = [s for s in ss if s["name"] == "train/compile"]
+    # the first call with a signature compiles, inside its train/step
+    assert len(compiles) <= 1
+    for c in compiles:
+        assert c["parent"] == steps[0]["span"]
+    # one record a step once compiled
+    assert len([s for s in ss if s["trace"] == steps[-1]["trace"]]) == 1
+
+
+# ---------------------------------------------------------------------------
+# on the profiler's clock
+# ---------------------------------------------------------------------------
+
+def _host_events(trace_dir, prefix):
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(prefix):
+                    out.append((ev.name, int(ev.start_ns),
+                                int(ev.duration_ns)))
+    return sorted(out, key=lambda e: e[1])
+
+
+def test_a_profiler_capture_holds_the_spans_where_the_ring_has_them(
+        tracer, paged_engine, trainer, tmp_path):
+    trainer.run(1)                                   # compiled
+    tracer.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _serve(paged_engine, [[1, 2, 3], [4, 5, 6, 7]], max_new=4)
+        trainer.run(2)
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(str(tmp_path), spans.ANNOTATION_PREFIX)
+    names = {e[0] for e in events}
+    assert {"paddle/serve/step", "paddle/serve/decode_tick",
+            "paddle/decode/run", "paddle/serve/prefill",
+            "paddle/prefill/run", "paddle/serve/emit", "paddle/serve/evict",
+            "paddle/train/step"} <= names, names
+    ring = sorted(tracer.spans(), key=lambda s: s["start_ns"])
+    # one offset puts every annotation on its record: start and length
+    # agree to a millisecond
+    first_tick = next(e for e in events
+                      if e[0] == "paddle/serve/decode_tick")
+    offset = first_tick[1] - next(
+        s for s in ring if s["name"] == "serve/decode_tick")["start_ns"]
+    for name in ("serve/decode_tick", "train/step", "serve/prefill"):
+        got = [e for e in events if e[0] == "paddle/" + name]
+        want = [s for s in ring if s["name"] == name]
+        assert len(got) == len(want) >= 2
+        for (_, start, dur), rec in zip(got, want):
+            assert abs(start - offset - rec["start_ns"]) < 1e6, name
+            assert abs(dur - rec["dur_ns"]) < 1e6, name

@@ -591,7 +591,8 @@ def _run_check_inner(out_dir: str) -> dict:
     from paddle_tpu.observability import spans as ospans
 
     ring = ospans.default_tracer().spans()
-    roots = [s for s in ring if s["name"] == "serve/request"]
+    roots = [s for s in ring if s["name"] == "serve/request"
+             and not s["attrs"].get("open")]
     assert len(roots) >= 20, f"only {len(roots)} serve/request spans"
     by_trace = {}
     for s in ring:
@@ -600,7 +601,14 @@ def _run_check_inner(out_dir: str) -> dict:
         fam = by_trace[root["trace"]]
         names = {s["name"] for s in fam}
         assert {"serve/queue_wait", "serve/prefill",
-                "serve/decode_tick", "serve/evict"} <= names, names
+                "serve/evict"} <= names, names
+        # one serve/decode_tick record a tick, on the loop's trace: the
+        # request's first_step..last_step is the range it rode
+        rode = [t for t in ospans.default_tracer().attr_range(
+            "serve/decode_tick", "step", root["attrs"]["first_step"],
+            root["attrs"]["last_step"])
+            if root["attrs"]["request_id"] in t["attrs"]["riders"]]
+        assert len(rode) == root["attrs"]["tokens"] - 1, (root, rode)
         for s in fam:
             if s["name"] == "serve/request":
                 continue
