@@ -94,51 +94,73 @@ def decode_attention(q, k_cache, v_cache, lengths,
     return out.astype(q.dtype)
 
 
-def paged_gather(pool, tables):
+def paged_gather(pool, tables, layer=None):
     """Materialize per-slot contiguous cache views from a paged pool.
 
-    pool:   [P, page, nh, hd]  (one layer's K or V page pool)
+    pool:   [P, page, nh, hd]  (one layer's K or V page pool), or with
+            ``layer`` the engine's whole [L, P, page, nh, hd] pool: the
+            gather then indexes (layer, page) at once and no layer is
+            sliced out of the pool first
     tables: [B, M] int32       (physical page per logical page per slot;
                                 unmapped entries point at the reserved
                                 scratch page — positions there are always
                                 masked by the caller's lengths)
+    layer:  int32 scalar       (traced: the layer loop's variable)
 
     Returns [B, M*page, nh, hd] — the slot-major layout every attention
     helper here already consumes, so the paged variants are gather +
     the existing masked-softmax kernels (one fused gather under XLA).
-    The Pallas gather-attention fusion this docstring used to promise
-    landed as ``pallas_kernels.fused_paged_decode_attention`` — the
-    one-launch decode step behind ``EngineConfig(fused_decode=True)``
-    walks the table in-kernel and skips the [B, S] round-trip entirely
-    (docs/kernels.md); this materializing path stays the default off-TPU
-    and the parity reference."""
+    On a TPU the decode tick does not come here:
+    ``pallas_kernels.paged_decode_attention`` reads the live pages
+    through the table in-kernel (docs/kernels.md); this materializing
+    path is the lowering off-TPU and under a mesh, and the parity
+    reference."""
     B, M = tables.shape
-    g = pool[tables]                       # [B, M, page, nh, hd]
-    return g.reshape(B, M * pool.shape[1], pool.shape[2], pool.shape[3])
+    g = pool[tables] if layer is None else pool[layer, tables]
+    return g.reshape((B, M * g.shape[2]) + g.shape[3:])
 
 
-def paged_cache_update(pool, new, phys_pages, rows):
+def _never_one_index(new, *index):
+    """XLA rewrites a scatter of ONE index as a dynamic-update-slice and
+    then lays the whole operand out to suit the update: on the TPU a
+    transposed copy of both carried KV pools every layer (850 ms for a
+    16-token prefill at 1.3B; my chip run, PR 27). Writing the one row or
+    page twice keeps it a scatter, in place."""
+    if new.shape[0] != 1:
+        return (new,) + index
+    return tuple(jnp.concatenate([a, a]) for a in (new,) + index)
+
+
+def paged_cache_update(pool, new, phys_pages, rows, layer=None):
     """Write one new row per sequence into the page pool.
 
-    pool:       [P, page, nh, hd]
+    pool:       [P, page, nh, hd], or [L, P, page, nh, hd] with ``layer``
     new:        [B, nh, hd]
     phys_pages: [B] int32   (physical page per slot — scratch for dead lanes)
     rows:       [B] int32   (row within the page)
 
-    Batch scatter with fixed shapes — donation makes it an in-place HBM
-    write. Colliding indices only occur on the scratch page, which is
-    never read back."""
-    return pool.at[phys_pages, rows].set(new.astype(pool.dtype))
+    Batch scatter with fixed shapes — donation (or a loop's carry) makes
+    it an in-place HBM write of B rows. Colliding indices only occur on
+    the scratch page, which is never read back."""
+    new, phys_pages, rows = _never_one_index(new.astype(pool.dtype),
+                                             phys_pages, rows)
+    if layer is None:
+        return pool.at[phys_pages, rows].set(new)
+    return pool.at[layer, phys_pages, rows].set(new)
 
 
-def paged_page_write(pool, pages_data, phys_pages):
+def paged_page_write(pool, pages_data, phys_pages, layer=None):
     """Write whole pages into the pool (the prefill path).
 
-    pool:       [P, page, nh, hd]
+    pool:       [P, page, nh, hd], or [L, P, page, nh, hd] with ``layer``
     pages_data: [n, page, nh, hd]  (suffix K/V reshaped to page granularity)
     phys_pages: [n] int32
     """
-    return pool.at[phys_pages].set(pages_data.astype(pool.dtype))
+    pages_data, phys_pages = _never_one_index(
+        pages_data.astype(pool.dtype), phys_pages)
+    if layer is None:
+        return pool.at[phys_pages].set(pages_data)
+    return pool.at[layer, phys_pages].set(pages_data)
 
 
 def paged_prefill_attention(q, k_all, v_all, prefix_len,

@@ -1344,35 +1344,59 @@ def use_opt_megakernel(override=None) -> bool:
 # cache row scatter (cache_update / paged_cache_update), the paged-view
 # gather, and the masked one-token softmax each lower as separate
 # fusions with their own HBM round trips over the [B, S, nh, hd] slabs.
-# These kernels collapse a decode tick to one launch per layer
-# (write-guarded row update + masked attention, the paged variant
-# subsuming the page-table gather) plus one launch for the final
-# layernorm + LM-head projection. Behind EngineConfig(fused_decode=True).
+# These kernels collapse a decode tick to one launch per layer (slab:
+# write-guarded row update + masked attention; paged: attention read
+# through the page table, the row write a scatter before it) plus one
+# launch for the final layernorm + LM-head projection. The slab kernel
+# and the logits head sit behind EngineConfig(fused_decode=True); the
+# paged kernel is what a paged engine runs on a TPU (engine.kv_path).
 
 
 # Block shapes the chip's compiler takes (tests/test_chip_compile.py): the
 # caches are [.., rows, nh, hd], so a block keeps ALL heads — its last two
 # dims are then the array's own, which Mosaic accepts at any nh/hd — and
-# the row axis is cut into chunks (slab) or pages (paged) along the grid's
-# last, sequential axis, with flash-decoding's running max / sum /
-# accumulator in VMEM scratch. One query row per head makes the matmuls
-# M=1, so scores and the weighted sum run on the VPU as broadcast
-# multiply + reduce in exact f32, heads on sublanes and hd on lanes —
-# no in-kernel transpose of the head axis.
+# the row axis is cut into chunks: along the grid's last, sequential axis
+# (slab) or by the kernel's own copies of the live pages (paged), with
+# flash-decoding's running max / sum / accumulator in VMEM scratch. One
+# query row per head makes the matmuls M=1, so scores and the weighted
+# sum run on the VPU as broadcast multiply + reduce in exact f32, heads
+# on sublanes and hd on lanes — no in-kernel transpose of the head axis.
 
 
-def _decode_chunk(q_ref, k_ref, v_ref, nk, nv, pos, sub, c, chunk,
-                  m_scr, l_scr, acc_scr, *, sm_scale):
-    """Fold cache rows [c*chunk, (c+1)*chunk) of one slot into the running
-    softmax. ``sub`` (scalar bool) substitutes row ``pos`` with the new
-    token's (nk, nv) — already rounded through the cache dtype, so
-    attention sees exactly the row value that lands in the cache."""
+def _fold_rows(qf, kf, vf, pos, c, rows, m_scr, l_scr, acc_scr, *,
+               sm_scale):
+    """Fold rows [c*rows, (c+1)*rows) of one slot into the running
+    softmax. ``qf`` (nh, hd), ``kf``/``vf`` (rows, nh, hd), all float32;
+    the guards are those of ops/decode_attention.py. Rows past ``pos``
+    are masked in K AND in V: the tail of a paged chunk may be VMEM that
+    no copy has written."""
     @pl.when(c == 0)
     def _init():
         m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
+    s = jnp.sum(qf[None] * kf, axis=2, keepdims=True) * sm_scale
+    valid = c * rows + jax.lax.broadcasted_iota(
+        jnp.int32, s.shape, 0) < pos + 1                 # (rows, nh, 1)
+    s = jnp.where(valid, s, -jnp.inf)
+    m_prev = m_scr[...]                                  # (nh, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+    m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+    alpha = jnp.exp(m_prev - m_safe)
+    e = jnp.where(valid, jnp.exp(s - m_safe[None]), 0.0)
+    vf = jnp.where(valid, vf, 0.0)
+    l_scr[...] = alpha * l_scr[...] + jnp.sum(e, axis=0)
+    acc_scr[...] = alpha * acc_scr[...] + jnp.sum(e * vf, axis=0)
+    m_scr[...] = m_new
+
+
+def _decode_chunk(q_ref, k_ref, v_ref, nk, nv, pos, sub, c, chunk,
+                  m_scr, l_scr, acc_scr, *, sm_scale):
+    """Fold cache rows [c*chunk, (c+1)*chunk) of one slab slot into the
+    running softmax. ``sub`` (scalar bool) substitutes row ``pos`` with
+    the new token's (nk, nv) — already rounded through the cache dtype, so
+    attention sees exactly the row value that lands in the cache."""
     @pl.when(c * chunk <= pos)        # later chunks are fully masked
     def _fold():
         kf = k_ref[...].astype(jnp.float32)              # (chunk, nh, hd)
@@ -1381,20 +1405,8 @@ def _decode_chunk(q_ref, k_ref, v_ref, nk, nv, pos, sub, c, chunk,
         sel = jnp.logical_and(rows == pos, sub)
         kf = jnp.where(sel, nk[None], kf)
         vf = jnp.where(sel, nv[None], vf)
-        qf = q_ref[...].astype(jnp.float32)              # (nh, hd)
-        s = jnp.sum(qf[None] * kf, axis=2, keepdims=True) * sm_scale
-        valid = c * chunk + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0) < pos + 1             # (chunk, nh, 1)
-        s = jnp.where(valid, s, -jnp.inf)
-        # same masked-softmax guards as ops/decode_attention.py
-        m_prev = m_scr[...]                              # (nh, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        alpha = jnp.exp(m_prev - m_safe)
-        e = jnp.where(valid, jnp.exp(s - m_safe[None]), 0.0)
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(e, axis=0)
-        acc_scr[...] = alpha * acc_scr[...] + jnp.sum(e * vf, axis=0)
-        m_scr[...] = m_new
+        _fold_rows(q_ref[...].astype(jnp.float32), kf, vf, pos, c, chunk,
+                   m_scr, l_scr, acc_scr, sm_scale=sm_scale)
 
 
 def _decode_finish(o_ref, l_scr, acc_scr):
@@ -1488,85 +1500,166 @@ def fused_decode_attention(q, k_cache, v_cache, new_k, new_v, positions,
     return o, kc, vc
 
 
-def _decode_paged_kernel(tbl_ref, pos_ref, q_ref, kp_ref, vp_ref, nk_ref,
-                         nv_ref, o_ref, ko_ref, vo_ref, m_scr, l_scr,
-                         acc_scr, *, sm_scale, page, num_pages):
-    del tbl_ref                       # consumed by the index maps
-    b = pl.program_id(0)
-    m = pl.program_id(1)
-    pos = pos_ref[b]
-    # the current token's row rounds through the pool dtype, as the
-    # unfused path does by scattering first and gathering it back
-    nk = nk_ref[...].astype(kp_ref.dtype)
-    nv = nv_ref[...].astype(vp_ref.dtype)
-
-    @pl.when(m == 0)
-    def _write_row():
-        # the out row block maps to (tables[b, pos//ps], pos%ps) for every
-        # m — dead lanes' all-zero tables land it on the scratch page,
-        # which is never read back (the unfused scratch-page guard)
-        ko_ref[...] = nk[None]
-        vo_ref[...] = nv[None]
-
-    # page m covers logical rows [m*ps, (m+1)*ps): the in-kernel
-    # paged_gather is the kp/vp index map walking the page table
-    _decode_chunk(q_ref, kp_ref, vp_ref, nk.astype(jnp.float32),
-                  nv.astype(jnp.float32), pos, True, m, page,
-                  m_scr, l_scr, acc_scr, sm_scale=sm_scale)
-
-    @pl.when(m == num_pages - 1)
-    def _finish():
-        _decode_finish(o_ref, l_scr, acc_scr)
+# the paged kernel reads the pool where it lies: the whole
+# ``[L, P, page, nh, hd]`` pool stays in HBM (``pl.ANY``), the layer
+# index, the page tables and the positions are scalar prefetch, and the
+# kernel copies just the pages below each slot's length into VMEM, ``G``
+# pages a chunk, double-buffered across chunks AND slots (one flat
+# sequence of work items: while chunk w folds, chunk w+1 is in flight).
+# The work of a tick is then the live pages' and nothing else's: no grid
+# step, copy or convert exists for a page no token lives on.
+_PAGED_CHUNK_BYTES = 1 << 20         # float32 bytes of K a chunk folds
 
 
-def fused_paged_decode_attention(q, k_pool, v_pool, new_k, new_v, tables,
-                                 positions, sm_scale=None):
-    """Paged twin of :func:`fused_decode_attention`: page-table gather +
-    row scatter + masked one-token attention in ONE launch (subsumes
-    paged_gather + paged_cache_update). The slot's pages stream through
-    VMEM one grid step each; the softmax folds them online.
+def paged_decode_tiles(num_heads: int, head_dim: int) -> bool:
+    """Whether Mosaic takes the paged kernel's page copies: a page lands
+    in VMEM as (page, nh, hd) with (nh, hd) on sublanes and lanes, and a
+    copy's slice must be whole tiles (tests/test_chip_compile.py; heads
+    of 64, or 12 of them, are refused). The engine asks before it
+    chooses the kernel; interpret mode takes any shape."""
+    return head_dim % NUM_LANES == 0 and num_heads % 8 == 0
 
-    q/new_k/new_v [B, nh, hd]; k_pool/v_pool [P, page, nh, hd];
-    tables [B, M] int32 (all-zero rows = dead lanes writing the
-    scratch page); positions [B] int32 in [0, M*page).
 
-    Returns (out [B, nh, hd], k_pool', v_pool'), pools aliased in place.
-    """
+def _decode_paged_kernel(layer_ref, tbl_ref, pos_ref, q_ref, kp_hbm, vp_hbm,
+                         o_ref, kbuf, vbuf, sems, m_scr, l_scr, acc_scr,
+                         *, sm_scale, page, pages_per_chunk, batch):
+    G = pages_per_chunk
+    layer = layer_ref[0]
+
+    def live_pages(b):               # pages holding rows [0, pos[b]]
+        return pos_ref[b] // page + 1
+
+    def copies(b, c, buf, go):
+        """Start (``go``) or wait for the copies of chunk ``c`` of slot
+        ``b`` into buffer ``buf``: one per live page, K and V."""
+        n = live_pages(b)
+        for i in range(G):
+            pg = c * G + i
+
+            @pl.when(pg < n)
+            def _():
+                phys = tbl_ref[b, pg]
+                for hbm, vmem, kv in ((kp_hbm, kbuf, 0), (vp_hbm, vbuf, 1)):
+                    cp = pltpu.make_async_copy(
+                        hbm.at[layer, phys], vmem.at[buf, i],
+                        sems.at[kv, buf])
+                    if go:
+                        cp.start()
+                    else:
+                        cp.wait()
+
+    copies(0, 0, 0, True)
+
+    def slot(b, w):
+        pos = pos_ref[b]
+        nc = (live_pages(b) + G - 1) // G
+
+        def chunk(c, w):
+            buf = w % 2
+            # the next work item: this slot's next chunk, or the next
+            # slot's first
+            last = c + 1 == nc
+            nb = jnp.where(last, b + 1, b)
+            nxt = jnp.where(last, 0, c + 1)
+
+            @pl.when(nb < batch)
+            def _prefetch():
+                copies(nb, nxt, 1 - buf, True)
+
+            copies(b, c, buf, False)
+            R = G * page
+            k = kbuf[buf].reshape((R,) + kbuf.shape[3:])
+            v = vbuf[buf].reshape((R,) + vbuf.shape[3:])
+            _fold_rows(q_ref[b].astype(jnp.float32),
+                       k.astype(jnp.float32), v.astype(jnp.float32), pos,
+                       c, R, m_scr, l_scr, acc_scr, sm_scale=sm_scale)
+            return w + 1
+
+        w = jax.lax.fori_loop(0, nc, chunk, w)
+        _decode_finish(o_ref.at[b], l_scr, acc_scr)
+        return w
+
+    jax.lax.fori_loop(0, batch, slot, 0)
+
+
+def paged_decode_attention(q, k_pool, v_pool, layer, tables, positions,
+                           sm_scale=None):
+    """One-token attention read through the page table: the pool is not
+    sliced, gathered or converted outside the kernel.
+
+    q [B, nh, hd]; k_pool/v_pool [L, P, page, nh, hd] (the engine's stored
+    layout, this step's rows already written); layer: int32 scalar
+    (traced: the layer loop's variable); tables [B, M] int32; positions
+    [B] int32 in [0, M*page): slot b attends rows [0, positions[b]] of
+    its pages ``tables[b, :positions[b]//page + 1]`` and touches no
+    other. A dead lane (all-zero table, position 0) reads one row of the
+    scratch page. Returns [B, nh, hd] in q's dtype."""
     B, M = tables.shape
-    P, page, nh, hd = k_pool.shape
+    L, P, page, nh, hd = k_pool.shape
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     _count_launch("decode_paged")
-    row3 = pl.BlockSpec((None, nh, hd), lambda b, m, t, p: (b, 0, 0))
-    page_spec = pl.BlockSpec((None, page, nh, hd),
-                             lambda b, m, t, p: (t[b, m], 0, 0, 0))
-    row4 = pl.BlockSpec(
-        (None, 1, nh, hd),
-        lambda b, m, t, p: (t[b, p[b] // page], p[b] % page, 0, 0))
+    # pages a chunk: 128 rows at 16 heads of 128, fewer rows for more
+    # heads, so that a chunk's float32 K (and V, and their product) stays
+    # a megabyte of VMEM
+    G = max(1, min(M, _PAGED_CHUNK_BYTES // (4 * nh * hd * page)))
+    rows_spec = pl.BlockSpec((B, nh, hd), lambda i, *_: (0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(B, M),
-        in_specs=[row3, page_spec, page_spec, row3, row3],
-        out_specs=[row3, row4, row4],
-        scratch_shapes=_decode_scratch(nh, hd))
-    with jax.named_scope("fused_decode_attention_paged"):
-        o, kp, vp = pl.pallas_call(
+        num_scalar_prefetch=3, grid=(1,),
+        in_specs=[rows_spec,
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=rows_spec,
+        scratch_shapes=[pltpu.VMEM((2, G, page, nh, hd), k_pool.dtype),
+                        pltpu.VMEM((2, G, page, nh, hd), v_pool.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2))]
+        + _decode_scratch(nh, hd))
+    with jax.named_scope("paged_decode_attention"):
+        return pl.pallas_call(
             functools.partial(_decode_paged_kernel, sm_scale=sm_scale,
-                              page=page, num_pages=M),
+                              page=page, pages_per_chunk=G, batch=B),
             grid_spec=grid_spec,
-            out_shape=[
-                jax.ShapeDtypeStruct((B, nh, hd), q.dtype),
-                jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-                jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
-            ],
-            input_output_aliases={3: 1, 4: 2},
-            # b sequential: dead lanes' scratch-page writes collide
-            # (benign — never read back — but kept ordered on TPU)
+            out_shape=jax.ShapeDtypeStruct((B, nh, hd), q.dtype),
             compiler_params=_CompilerParams(
-                dimension_semantics=("arbitrary", "arbitrary")),
+                dimension_semantics=("arbitrary",)),
             interpret=_interpret(),
-        )(tables.astype(jnp.int32), positions.astype(jnp.int32),
-          q, k_pool, v_pool, new_k, new_v)
-    return o, kp, vp
+            name="paged_decode_attention",
+        )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+          tables.astype(jnp.int32), positions.astype(jnp.int32),
+          q, k_pool, v_pool)
+
+
+def fused_paged_decode_attention(q, k_pool, v_pool, new_k, new_v, tables,
+                                 positions, layer=None, sm_scale=None):
+    """The paged decode step of one layer: row write through the page
+    table + :func:`paged_decode_attention` (subsumes paged_cache_update +
+    paged_gather + decode_attention).
+
+    q/new_k/new_v [B, nh, hd]; k_pool/v_pool [L, P, page, nh, hd] with
+    ``layer`` (the engine's carried pools), or one layer's
+    [P, page, nh, hd] without; tables [B, M] int32 (all-zero rows = dead
+    lanes writing the scratch page); positions [B] int32 in [0, M*page).
+
+    Returns (out [B, nh, hd], k_pool', v_pool'): the row write is a
+    scatter of B rows on the pool, in place under donation or as a
+    loop's carry; the kernel only reads.
+    """
+    from .decode_attention import paged_cache_update
+
+    one_layer = layer is None
+    if one_layer:
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
+    page = k_pool.shape[2]
+    phys = jnp.take_along_axis(
+        tables, (positions // page)[:, None], axis=1)[:, 0]
+    rows = positions % page
+    k_pool = paged_cache_update(k_pool, new_k, phys, rows, layer=layer)
+    v_pool = paged_cache_update(v_pool, new_v, phys, rows, layer=layer)
+    out = paged_decode_attention(q, k_pool, v_pool, layer, tables,
+                                 positions, sm_scale=sm_scale)
+    if one_layer:
+        k_pool, v_pool = k_pool[0], v_pool[0]
+    return out, k_pool, v_pool
 
 
 def _logits_head_kernel(x_ref, scale_ref, bias_ref, w_ref, o_ref, *, eps):

@@ -19,6 +19,10 @@ module assembles them into the serving shape:
   (``serving/paged_kv.py``: a ``[L, num_pages, page_size, nh, hd]`` pool
   + per-slot page tables fed as device arrays, prefix-cache capable).
   Both are threaded through every executable with buffer donation on TPU.
+  The paged programs carry both pools through the layer loop in place
+  (``_layers_over_pools``) and touch only the rows and pages they index;
+  on a TPU the decode tick reads the live pages through the page table
+  in a Pallas kernel (``kv_path``).
 - **Sampling inside the executables** (``serving/sampling.py``):
   per-slot temperature/top-k/top-p/seed ride as batch inputs — changing
   them never changes a shape. ``temperature=0`` is bit-exact greedy.
@@ -52,6 +56,7 @@ from ..models import gpt as gpt_mod
 from ..models.gpt import GPTConfig
 from ..observability import program_report as _prep
 from ..observability import spans as _spans
+from ..ops import pallas_kernels as _pk
 from ..ops.decode_attention import (cache_update, decode_attention,
                                     paged_cache_update, paged_gather,
                                     paged_page_write,
@@ -62,7 +67,8 @@ from . import metrics as smetrics
 from . import sampling as samp
 from .kv_cache import KVCache
 from .paged_kv import PagedKVCache, PagePoolFullError, PrefixCache
-from .quant import dequantize_params, quantize_params, quantized_nbytes
+from .quant import (QuantizedLeaf, dequantize_params, quantize_params,
+                    quantized_nbytes)
 from .sampling import GREEDY, SamplingParams
 
 __all__ = ["EngineConfig", "DecodeEngine", "PromptTooLongError",
@@ -113,13 +119,19 @@ class EngineConfig:
     # -- speculative decoding (serving/spec_decode.py) ------------------
     verify_window: int = 0           # W>0 compiles the verify executable
     # -- fused decode step (ops/pallas_kernels.py, docs/kernels.md) -----
-    # one Pallas launch per layer for cache-row write + masked one-token
-    # attention (the paged variant subsumes the page-table gather) plus
-    # one launch for the final layernorm + LM-head projection — replaces
-    # the decode tick's scatter/gather/attention small-fusion residue
-    # ranked by ATTRIBUTION_DECODE.json. Opt-in: interpret-mode Pallas
-    # is slower than XLA off-TPU. Masked-lane / scratch-page write-guard
-    # semantics are preserved (tests/test_pallas_fused.py).
+    # Pallas launches in place of the decode tick's small-fusion residue
+    # ranked by ATTRIBUTION_DECODE.json: fused_ln for the tick's
+    # layernorms, one launch for the final layernorm + LM-head
+    # projection, and on a slab one launch a layer for the write-guarded
+    # cache-row write + masked one-token attention. Opt-in:
+    # interpret-mode Pallas is slower than XLA off-TPU and no cell has
+    # measured these on the chip. NOT a switch of the paged attention:
+    # a paged engine on a TPU reads its cache through the page-table
+    # kernel either way (``DecodeEngine.kv_path``, decided from platform
+    # and layout); off the TPU this flag also asks for that kernel, in
+    # interpret mode, which is how the CPU lane drives it. Masked-lane /
+    # scratch-page write-guard semantics are preserved
+    # (tests/test_pallas_fused.py).
     fused_decode: bool = False
 
     def resolved_buckets(self) -> Tuple[int, ...]:
@@ -133,6 +145,37 @@ class EngineConfig:
                 f"largest prefill bucket {buckets[-1]} exceeds max_seq "
                 f"{self.max_seq}")
         return buckets
+
+
+def _embed_rows(qparams, tokens, positions, dt):
+    """``wte[tokens] + wpe[positions]`` as ``dt``, summed in float32. The
+    rows are gathered from the tables as they are stored and widened
+    after: widening first has XLA write the whole float32 table (412 MB
+    at 50257 x 2048) on every call before it gathers a few rows of it."""
+    def rows(table, idx):
+        if isinstance(table, QuantizedLeaf):     # int8: chunked, flat
+            return dequantize_params(table)[idx]
+        return table[idx].astype(jnp.float32)
+
+    return (rows(qparams["wte"], tokens)
+            + rows(qparams["wpe"], positions)).astype(dt)
+
+
+def _layers_over_pools(body, x, kp, vp, blocks):
+    """Run ``body(h, layer_p, l, kp, vp) -> (h, kp, vp)`` over the stacked
+    ``blocks`` with both KV pools as the loop's CARRY, in their stored
+    ``[L, P, page, nh, hd]`` layout, and the layer index ``l`` a loop
+    variable. A scan's ``xs``/``ys`` would slice a layer out of each pool
+    and re-stack it into a new buffer every iteration; a carry is updated
+    in place, so the donated pools alias the outputs and a program
+    touches only the rows and pages it indexes at ``[l, page, row]``."""
+    def step(carry, xs):
+        layer_p, l = xs
+        return body(carry[0], layer_p, l, carry[1], carry[2]), None
+
+    layers = jnp.arange(kp.shape[0], dtype=jnp.int32)
+    (x, kp, vp), _ = jax.lax.scan(step, (x, kp, vp), (blocks, layers))
+    return x, kp, vp
 
 
 class DecodeEngine:
@@ -193,6 +236,22 @@ class DecodeEngine:
         if self._cache_sh is not None:
             self.cache.k = jax.device_put(self.cache.k, self._cache_sh)
             self.cache.v = jax.device_put(self.cache.v, self._cache_sh)
+        # how the decode tick reads the cache (docs/serving.md): a paged
+        # engine on a TPU reads the live pages through the page table in
+        # a Pallas kernel, where Mosaic takes its page shape; under a
+        # mesh (a Pallas call there needs shard_map) and off the TPU
+        # (interpret-mode Pallas is slower than XLA) it gathers the
+        # padded view. Off the TPU fused_decode asks for the kernel all
+        # the same: the CPU lane's way to drive it.
+        if not self.paged:
+            self.kv_path = "slab"
+        elif self._mesh is not None:
+            self.kv_path = "xla_gather"
+        elif (_pk.paged_decode_tiles(cfg.num_heads, cfg.head_dim)
+              if _pk._on_tpu() else ecfg.fused_decode):
+            self.kv_path = "pallas_paged"
+        else:
+            self.kv_path = "xla_gather"
         self._exec: Dict[str, Any] = {}
         self._sig_history: Dict[str, List[dict]] = {}
         self.compiles = 0
@@ -297,9 +356,8 @@ class DecodeEngine:
         """The decode tick's layernorm: the fused Pallas block kernel
         under ``EngineConfig.fused_decode``, else the XLA reference."""
         if self.ecfg.fused_decode:
-            from ..ops.pallas_kernels import fused_ln as _fln
-
-            return lambda x, scale, bias: _fln(x, scale, bias, eps=1e-5)
+            return lambda x, scale, bias: _pk.fused_ln(x, scale, bias,
+                                                       eps=1e-5)
         return gpt_mod._layer_norm
 
     def _block_tail(self, h, a, layer_p, dt, ln, bt: str):
@@ -374,30 +432,27 @@ class DecodeEngine:
         T = tokens.shape[1]
         n_pages = T // ps
         positions = prefix_len + jnp.arange(T)
-        x = (params["wte"][tokens]
-             + params["wpe"][positions][None]).astype(dt)    # [1, T, D]
+        x = _embed_rows(qparams, tokens, positions[None], dt)  # [1, T, D]
         suffix_pages = jax.lax.dynamic_slice(
             table_row, (prefix_len // ps,), (n_pages,))
 
-        def body(h, xs):
-            layer_p, kp_l, vp_l = xs
+        def body(h, layer_p, l, kp, vp):
             h1 = ln(h, layer_p["ln1_scale"], layer_p["ln1_bias"])
             qkv = jnp.einsum("btd,dcnh->btcnh", h1,
                              layer_p["w_qkv"].astype(dt))
             qkv = qkv + layer_p["b_qkv"].astype(dt)
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
             nh, hd = k.shape[2], k.shape[3]
-            kp_l = paged_page_write(
-                kp_l, k[0].reshape(n_pages, ps, nh, hd), suffix_pages)
-            vp_l = paged_page_write(
-                vp_l, v[0].reshape(n_pages, ps, nh, hd), suffix_pages)
-            k_all = paged_gather(kp_l, table_row[None])  # [1, S, nh, hd]
-            v_all = paged_gather(vp_l, table_row[None])
+            kp = paged_page_write(
+                kp, k[0].reshape(n_pages, ps, nh, hd), suffix_pages, l)
+            vp = paged_page_write(
+                vp, v[0].reshape(n_pages, ps, nh, hd), suffix_pages, l)
+            k_all = paged_gather(kp, table_row[None], l)  # [1, S, nh, hd]
+            v_all = paged_gather(vp, table_row[None], l)
             a = paged_prefill_attention(q, k_all, v_all, prefix_len)
-            h = self._block_tail(h, a, layer_p, dt, ln, "bt")
-            return h, (kp_l, vp_l)
+            return self._block_tail(h, a, layer_p, dt, ln, "bt"), kp, vp
 
-        x, (kp, vp) = jax.lax.scan(body, x, (params["blocks"], kp, vp))
+        x, kp, vp = _layers_over_pools(body, x, kp, vp, params["blocks"])
         h_last = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0,
                                               keepdims=False)
         h_last = ln(h_last, params["ln_f_scale"], params["ln_f_bias"])
@@ -424,10 +479,7 @@ class DecodeEngine:
         dt = cfg.dtype
         fused = self.ecfg.fused_decode
         ln = self._decode_ln()
-        if fused:
-            from ..ops.pallas_kernels import (fused_decode_attention,
-                                              fused_logits_head)
-        x = (params["wte"][tokens] + params["wpe"][positions]).astype(dt)
+        x = _embed_rows(qparams, tokens, positions, dt)
 
         def body(h, xs):
             layer_p, ck_l, cv_l = xs
@@ -438,7 +490,7 @@ class DecodeEngine:
             q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]      # [B, nh, hd]
             if fused:
                 # one launch: write-guarded row update + masked attention
-                a, ck_l, cv_l = fused_decode_attention(
+                a, ck_l, cv_l = _pk.fused_decode_attention(
                     q, ck_l, cv_l, k, v, positions, active=actives)
             else:
                 ck_l = cache_update(ck_l, k, positions, active=actives)
@@ -450,7 +502,7 @@ class DecodeEngine:
         x, (ck, cv) = jax.lax.scan(body, x,
                                    (params["blocks"], ck, cv))
         if fused:
-            logits = fused_logits_head(
+            logits = _pk.fused_logits_head(
                 x, params["ln_f_scale"], params["ln_f_bias"],
                 params["lm_head"].astype(dt))
         else:
@@ -465,48 +517,50 @@ class DecodeEngine:
     def _decode_fn_paged(self, qparams, kp, vp, tokens, positions,
                          tables, temps, top_ks, top_ps, seeds):
         """Paged twin of :meth:`_decode_fn`: per-slot page tables
-        [B, max_pages] route the one-row write (scatter) and the
-        attention read (gather) through the shared pool. Lanes whose
-        table row is all-zero write into the scratch page."""
+        [B, max_pages] route the one-row write (a scatter on the carried
+        pool) and the attention read through the shared pool. Lanes whose
+        table row is all-zero write into the scratch page. The read has
+        two lowerings of one algorithm (:attr:`kv_path`): the Pallas
+        kernel that fetches only the live pages, or gather + masked
+        softmax over the padded view."""
         cfg = self.cfg
         params = self._dequant(qparams)
         dt = cfg.dtype
         fused = self.ecfg.fused_decode
         ln = self._decode_ln()
-        if fused:
-            from ..ops.pallas_kernels import (fused_logits_head,
-                                              fused_paged_decode_attention)
         ps = self.ecfg.page_size
-        x = (params["wte"][tokens] + params["wpe"][positions]).astype(dt)
-        phys = jnp.take_along_axis(
-            tables, (positions // ps)[:, None], axis=1)[:, 0]
-        rows = positions % ps
+        x = _embed_rows(qparams, tokens, positions, dt)
+        if self.kv_path == "pallas_paged":
+            def write_and_attend(q, k, v, kp, vp, l):
+                return _pk.fused_paged_decode_attention(
+                    q, kp, vp, k, v, tables, positions, layer=l)
+        else:
+            phys = jnp.take_along_axis(
+                tables, (positions // ps)[:, None], axis=1)[:, 0]
+            rows = positions % ps
 
-        def body(h, xs):
-            layer_p, kp_l, vp_l = xs
+            def write_and_attend(q, k, v, kp, vp, l):
+                kp = paged_cache_update(kp, k, phys, rows, l)
+                vp = paged_cache_update(vp, v, phys, rows, l)
+                a = decode_attention(q, paged_gather(kp, tables, l),
+                                     paged_gather(vp, tables, l),
+                                     positions + 1)
+                return a, kp, vp
+
+        def body(h, layer_p, l, kp, vp):
             h1 = ln(h, layer_p["ln1_scale"], layer_p["ln1_bias"])
             qkv = jnp.einsum("bd,dcnh->bcnh", h1,
                              layer_p["w_qkv"].astype(dt))
             qkv = qkv + layer_p["b_qkv"].astype(dt)
-            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
-            if fused:
-                # one launch: row scatter + page gather + masked attention
-                # (dead lanes' all-zero tables land the write on the
-                # scratch page, same as paged_cache_update)
-                a, kp_l, vp_l = fused_paged_decode_attention(
-                    q, kp_l, vp_l, k, v, tables, positions)
-            else:
-                kp_l = paged_cache_update(kp_l, k, phys, rows)
-                vp_l = paged_cache_update(vp_l, v, phys, rows)
-                k_all = paged_gather(kp_l, tables)      # [B, S, nh, hd]
-                v_all = paged_gather(vp_l, tables)
-                a = decode_attention(q, k_all, v_all, positions + 1)
-            h = self._block_tail(h, a, layer_p, dt, ln, "b")
-            return h, (kp_l, vp_l)
+            # dead lanes' all-zero tables land the write on the scratch
+            # page, which no live slot reads
+            a, kp, vp = write_and_attend(qkv[:, 0], qkv[:, 1], qkv[:, 2],
+                                         kp, vp, l)
+            return self._block_tail(h, a, layer_p, dt, ln, "b"), kp, vp
 
-        x, (kp, vp) = jax.lax.scan(body, x, (params["blocks"], kp, vp))
+        x, kp, vp = _layers_over_pools(body, x, kp, vp, params["blocks"])
         if fused:
-            logits = fused_logits_head(
+            logits = _pk.fused_logits_head(
                 x, params["ln_f_scale"], params["ln_f_bias"],
                 params["lm_head"].astype(dt))
         else:
@@ -530,7 +584,7 @@ class DecodeEngine:
         ln = gpt_mod._layer_norm
         W = tokens.shape[1]
         positions = starts[:, None] + jnp.arange(W)      # [B, W]
-        x = (params["wte"][tokens] + params["wpe"][positions]).astype(dt)
+        x = _embed_rows(qparams, tokens, positions, dt)
 
         def body(h, xs):
             layer_p, ck_l, cv_l = xs
@@ -567,31 +621,28 @@ class DecodeEngine:
         ps = self.ecfg.page_size
         B, W = tokens.shape
         positions = starts[:, None] + jnp.arange(W)      # [B, W]
-        x = (params["wte"][tokens] + params["wpe"][positions]).astype(dt)
+        x = _embed_rows(qparams, tokens, positions, dt)
         phys = jnp.take_along_axis(tables, positions // ps, axis=1)
         rows = positions % ps
 
-        def body(h, xs):
-            layer_p, kp_l, vp_l = xs
+        def body(h, layer_p, l, kp, vp):
             h1 = ln(h, layer_p["ln1_scale"], layer_p["ln1_bias"])
             qkv = jnp.einsum("bwd,dcnh->bwcnh", h1,
                              layer_p["w_qkv"].astype(dt))
             qkv = qkv + layer_p["b_qkv"].astype(dt)
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
             nh, hd = k.shape[2], k.shape[3]
-            kp_l = paged_cache_update(
-                kp_l, k.reshape(B * W, nh, hd),
-                phys.reshape(-1), rows.reshape(-1))
-            vp_l = paged_cache_update(
-                vp_l, v.reshape(B * W, nh, hd),
-                phys.reshape(-1), rows.reshape(-1))
-            k_all = paged_gather(kp_l, tables)
-            v_all = paged_gather(vp_l, tables)
-            a = window_attention(q, k_all, v_all, starts)
-            h = self._block_tail(h, a, layer_p, dt, ln, "bw")
-            return h, (kp_l, vp_l)
+            kp = paged_cache_update(
+                kp, k.reshape(B * W, nh, hd),
+                phys.reshape(-1), rows.reshape(-1), l)
+            vp = paged_cache_update(
+                vp, v.reshape(B * W, nh, hd),
+                phys.reshape(-1), rows.reshape(-1), l)
+            a = window_attention(q, paged_gather(kp, tables, l),
+                                 paged_gather(vp, tables, l), starts)
+            return self._block_tail(h, a, layer_p, dt, ln, "bw"), kp, vp
 
-        x, (kp, vp) = jax.lax.scan(body, x, (params["blocks"], kp, vp))
+        x, kp, vp = _layers_over_pools(body, x, kp, vp, params["blocks"])
         x = ln(x, params["ln_f_scale"], params["ln_f_bias"])
         logits = jnp.einsum("bwd,dv->bwv", x,
                             params["lm_head"].astype(dt))
@@ -1004,6 +1055,14 @@ class DecodeEngine:
             return True
         return self.cache.ensure_capacity(
             slot, self.cache.length(slot) + extra)
+
+    def live_pages(self, slots) -> int:
+        """Pages a decode tick over ``slots`` reads: those holding each
+        slot's rows up to the one the tick writes (0 on a slab)."""
+        if not self.paged:
+            return 0
+        return sum(self.cache.pages_for(self.cache.length(s) + 1)
+                   for s in slots)
 
     def _decode_feed(self, slot_tokens: Dict[int, int]):
         B = self.ecfg.max_batch

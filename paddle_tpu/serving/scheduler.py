@@ -693,12 +693,15 @@ class Scheduler:
         # one executable call, so the tick names its riders and a request
         # finds its ticks by step (first_step..last_step), not the other
         # way round
-        cache = self.engine.cache
+        eng = self.engine
         with _spans.span("serve/decode_tick", attrs={
                 "step": self.steps, "batch": len(feed),
                 "riders": [r.id for r in self._active.values()],
-                "cached_tokens": sum(cache.length(s) for s in feed)}):
-            out = self.engine.generate_step(feed, params)
+                "cached_tokens": sum(eng.cache.length(s) for s in feed),
+                # how the tick reads the cache, and how many pages of it
+                "kv_path": eng.kv_path,
+                "live_pages": eng.live_pages(feed)}):
+            out = eng.generate_step(feed, params)
         attrs = {"emitted": 0, "finished": 0}
         with _spans.span("serve/emit", attrs=attrs):
             self._emit(out, attrs)

@@ -620,6 +620,7 @@ class FrontDoor:
             out["max_batch"] = self.scheduler.engine.ecfg.max_batch
             out["buckets"] = list(self.scheduler.engine.buckets)
             out["weight_dtype"] = self.scheduler.engine.ecfg.weight_dtype
+            out["kv_path"] = getattr(self.scheduler.engine, "kv_path", None)
             if self.loop is not None:
                 out["loop_alive"] = self.loop.alive
                 out["loop_faults"] = self.loop.faults

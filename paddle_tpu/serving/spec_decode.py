@@ -136,6 +136,13 @@ class SpecDecodeEngine:
         return self.target.paged
 
     @property
+    def kv_path(self):
+        return self.target.kv_path
+
+    def live_pages(self, slots) -> int:
+        return self.target.live_pages(slots)
+
+    @property
     def poisoned(self):
         return self.target.poisoned or self.draft.poisoned
 

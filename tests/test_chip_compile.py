@@ -14,6 +14,7 @@ only one process may hold libtpu, and every xdist worker imports this file.
 Keep these tests in this one file for the same reason.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +28,9 @@ from paddle_tpu.ops import pallas_kernels as PK
 # gpt_wide (bench.gpt_wide_config): b=16, T=1024, 16 heads of 128, d=2048
 B, T, NH, HD, D, V = 16, 1024, 16, 128, 2048, 50304
 SERVE_B, SERVE_S, PAGE = 8, 1024, 16
+# serve_cgpt1p3b_closed14 (benchmark/configs/cerebras-gpt-1.3b.json): 16
+# slots of 2048 tokens over 1793 pages; depth 2 keeps the layer loop real
+CELL_B, CELL_S, CELL_PAGES, CELL_L = 16, 2048, 1793, 2
 BF16, F32 = jnp.bfloat16, jnp.float32
 
 
@@ -147,6 +151,17 @@ def test_fused_decode_paged(one_chip):
     pool = ((1 + SERVE_B * m, PAGE, NH, HD), BF16)
     _compile(PK.fused_paged_decode_attention, one_chip, ROW, pool, pool,
              ROW, ROW, ((SERVE_B, m), jnp.int32), ((SERVE_B,), jnp.int32))
+
+
+def test_paged_decode_attention_layer_indexed(one_chip):
+    """The kernel of the paged tick at the serving cell's widths: the
+    whole [L, P, page, nh, hd] pool in HBM, layer index, page tables and
+    positions as scalar prefetch."""
+    pool = ((CELL_L, CELL_PAGES, PAGE, NH, HD), BF16)
+    row = ((CELL_B, NH, HD), BF16)
+    _compile(PK.paged_decode_attention, one_chip, row, pool, pool,
+             ((), jnp.int32), ((CELL_B, CELL_S // PAGE), jnp.int32),
+             ((CELL_B,), jnp.int32))
 
 
 def test_fused_logits_head(one_chip):
@@ -273,28 +288,156 @@ def test_ernie_base_pretrain_step_fits(one_chip):
             < 14 * 2**30)
 
 
-@pytest.mark.parametrize("kv_layout", ["slab", "paged"])
-@pytest.mark.parametrize("fused", [False, True], ids=["xla", "fused"])
-def test_gpt_wide_decode_tick(one_chip, kv_layout, fused):
-    """The serving engine's decode tick at gpt_wide widths (depth 1: the
-    layers are one scanned body), default and fused_decode paths."""
+def _engine(num_layers, **ecfg):
+    """A bf16 serving engine at gpt_wide widths over calloc'd weights."""
     from paddle_tpu import serving
     from paddle_tpu.models import gpt as G
 
-    cfg = _gpt_wide().scaled(num_layers=1)
+    cfg = _gpt_wide().scaled(num_layers=num_layers, max_seq_len=CELL_S)
     shapes = jax.eval_shape(
         lambda: G.init_params(jax.random.PRNGKey(0), cfg))
     params = jax.tree_util.tree_map(      # calloc'd: never touched
         lambda a: np.zeros(a.shape, a.dtype), shapes)
-    eng = serving.DecodeEngine(params, cfg, serving.EngineConfig(
-        max_seq=SERVE_S, max_batch=SERVE_B, kv_layout=kv_layout,
-        page_size=PAGE, weight_dtype="bf16", fused_decode=fused))
-    fn, example = eng._decode_program()
+    return serving.DecodeEngine(params, cfg, serving.EngineConfig(
+        page_size=PAGE, weight_dtype="bf16", **ecfg))
+
+
+def _lower_donated(fn, example, sharding):
+    """``fn`` lowered for the described chip from its example arguments'
+    shapes, the cache arguments (1 and 2) donated as the engine does."""
     args = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype
                                        if not hasattr(a, "dtype")
-                                       else a.dtype, sharding=one_chip),
+                                       else a.dtype, sharding=sharding),
         example)
-    lowered = jax.jit(fn, donate_argnums=(1, 2)).lower(*args)
-    assert ("tpu_custom_call" in lowered.as_text()) == fused
+    return jax.jit(fn, donate_argnums=(1, 2)).lower(*args)
+
+
+@pytest.mark.parametrize("kv_layout", ["slab", "paged"])
+@pytest.mark.parametrize("fused", [False, True], ids=["xla", "fused"])
+def test_gpt_wide_decode_tick(one_chip, kv_layout, fused):
+    """The serving engine's decode tick at gpt_wide widths (depth 1),
+    default and fused_decode paths. A paged engine reads its cache
+    through the Pallas kernel on a TPU whatever fused_decode says."""
+    eng = _engine(1, max_seq=SERVE_S, max_batch=SERVE_B,
+                  kv_layout=kv_layout, fused_decode=fused)
+    assert eng.kv_path == ("pallas_paged" if kv_layout == "paged"
+                           else "slab")
+    lowered = _lower_donated(*eng._decode_program(), one_chip)
+    assert ("tpu_custom_call" in lowered.as_text()) == (
+        fused or kv_layout == "paged")
     lowered.compile()
+
+
+def test_paged_engine_gathers_where_mosaic_refuses_the_page(one_chip):
+    """Heads of 16 (GPT_TINY): Mosaic refuses the kernel's page copies
+    (a slice must be whole (8, 128) tiles), the engine sees it from the
+    shapes (``paged_decode_tiles``) and its tick gathers instead."""
+    from paddle_tpu import serving
+    from paddle_tpu.models import gpt as G
+
+    cfg = G.GPT_TINY.scaled(num_layers=2, max_seq_len=64, dtype=BF16)
+    assert not PK.paged_decode_tiles(cfg.num_heads, cfg.head_dim)
+    assert PK.paged_decode_tiles(NH, HD)
+    eng = serving.DecodeEngine(
+        G.init_params(jax.random.PRNGKey(0), cfg), cfg,
+        serving.EngineConfig(max_batch=4, max_seq=32, kv_layout="paged",
+                             prefill_buckets=(8, 16), page_size=8,
+                             weight_dtype="bf16"))
+    assert eng.kv_path == "xla_gather"
+    lowered = _lower_donated(*eng._decode_program(), one_chip)
+    assert "tpu_custom_call" not in lowered.as_text()
+    lowered.compile()
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compile(PK.paged_decode_attention, one_chip,
+                 ((4, 4, 16), BF16), ((2, 17, 8, 4, 16), BF16),
+                 ((2, 17, 8, 4, 16), BF16), ((), jnp.int32),
+                 ((4, 4), jnp.int32), ((4,), jnp.int32))
+
+
+_POOL_SIZED = ("copy", "convert", "dynamic-slice", "dynamic-update-slice")
+
+
+def _elements(shape_text):
+    """Elements of the largest array in an HLO result type such as
+    ``bf16[2,1793,16,16,128]{...}`` or a tuple of them."""
+    return max((int(np.prod([int(d) for d in dims.split(",")]))
+                for dims in re.findall(r"\w+\[([\d,]+)\]", shape_text)),
+               default=0)
+
+
+def _pool_sized_moves(hlo, at_least):
+    """Instructions of the compiled module that materialise an array of
+    ``at_least`` elements or more by a copy, a convert, a dynamic-slice or
+    a dynamic-update-slice: such an instruction on its own, or a fusion
+    whose result is that large and whose body holds one that large (the
+    body of a fusion with a small result materialises nothing)."""
+    line_re = re.compile(
+        r"^\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\((.*)$")
+    calls_re = re.compile(r"calls=%([\w.-]+)")
+    bodies, current, fused = {}, None, set()
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) \(.*\) -> .* \{$", line)
+        if head:
+            current = head.group(1)
+            bodies[current] = []
+            continue
+        m = line_re.match(line)
+        if m and current is not None:
+            name, shape, op, rest = m.groups()
+            bodies[current].append((name, shape, op, rest))
+            if op == "fusion":
+                fused.add(calls_re.search(rest).group(1))
+
+    def big(body):
+        return [f"{n} = {sh} {op}" for n, sh, op, _ in bodies.get(body, ())
+                if op in _POOL_SIZED and _elements(sh) >= at_least]
+
+    found = []
+    for body, instrs in bodies.items():
+        if body in fused:
+            continue
+        found += big(body)
+        for name, shape, op, rest in instrs:
+            if op == "fusion" and _elements(shape) >= at_least:
+                inner = big(calls_re.search(rest).group(1))
+                found += [f"{name} = {shape} fusion of {i}" for i in inner]
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_b16",
+                                     "prefill_b512"])
+def test_paged_programs_touch_only_live_pages(one_chip, program):
+    """The serving cell's paged decode tick and two prefill rungs, the
+    one-page rung (XLA rewrites a one-index scatter as a
+    dynamic-update-slice and re-lays the pool for it) and a common one
+    (16 slots, max_seq 2048, 1793 pages of 16 tokens, 16 heads of 128;
+    depth 2):
+    the KV pools are the layer loop's carry, updated in place. The
+    donated pools alias the outputs, the program's temporaries are
+    smaller than one pool (a scan's xs/ys held a second copy of both),
+    and nothing of a layer's pool size is copied, converted, sliced out
+    or written back."""
+    eng = _engine(CELL_L, max_seq=CELL_S, max_batch=CELL_B,
+                  kv_layout="paged", num_pages=CELL_PAGES)
+    if program == "decode":
+        fn, example = eng._decode_program()
+    else:
+        bucket = int(program.split("_b")[1])
+        fn, example = eng._prefill_fn_paged, (
+            eng.qparams, eng.cache.k, eng.cache.v,
+            np.zeros((1, bucket), np.int32), np.int32(1), np.int32(0),
+            np.zeros((eng.cache.max_pages_per_slot,), np.int32),
+            *eng._samp_scalar_examples())
+    compiled = _lower_donated(fn, example, one_chip).compile()
+    pool = eng.cache.k.size * eng.cache.k.dtype.itemsize
+    mem = compiled.memory_analysis()
+    print(f"{program}: arguments {mem.argument_size_in_bytes / 2**20:.0f} "
+          f"MiB, aliased {mem.alias_size_in_bytes / 2**20:.0f}, "
+          f"temporaries {mem.temp_size_in_bytes / 2**20:.0f}, one pool "
+          f"{pool / 2**20:.0f}")
+    assert mem.alias_size_in_bytes >= 2 * pool
+    assert mem.temp_size_in_bytes < pool
+    moves = _pool_sized_moves(compiled.as_text(),
+                              at_least=eng.cache.k[0].size)
+    assert not moves, "\n".join(moves)

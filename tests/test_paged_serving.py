@@ -663,3 +663,159 @@ def test_spec_paged_target(tiny_model, slab_eng):
         got.extend(out[slot])
     spec.free_sequence(slot)
     assert got[:9] == want
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 27: the KV pools are the layer loop's carry, and the decode tick has
+# two lowerings of one algorithm (Pallas through the page table / gather)
+# ---------------------------------------------------------------------------
+
+def _xs_ys_layers(body, x, kp, vp, blocks):
+    """The parent's data flow for the same per-layer arithmetic: each
+    layer's pool is sliced out as the scan's ``xs`` and re-stacked as its
+    ``ys``."""
+    import jax.numpy as jnp
+
+    def step(h, xs):
+        layer_p, kp_l, vp_l = xs
+        h, kp_l, vp_l = body(h, layer_p, jnp.int32(0), kp_l[None],
+                             vp_l[None])
+        return h, (kp_l[0], vp_l[0])
+
+    x, (kp, vp) = jax.lax.scan(step, x, (blocks, kp, vp))
+    return x, kp, vp
+
+
+def _seeded_pools(eng, seed):
+    rng = np.random.default_rng(seed)
+    shape = eng.cache.k.shape
+    return (jax.numpy.asarray(rng.standard_normal(shape), eng.cache.dtype),
+            jax.numpy.asarray(rng.standard_normal(shape), eng.cache.dtype))
+
+
+def _paged_program(eng, program, rng):
+    """(fn, args after the pools) of one paged program over a cache in
+    which slots 0 and 2 are live (7 and 16 rows: mid-page, page edge),
+    slot 1 is a dead lane."""
+    B, M = eng.ecfg.max_batch, eng.cache.max_pages_per_slot
+    V = eng.cfg.vocab_size
+    tables = np.zeros((B, M), np.int32)
+    tables[0] = 1 + np.arange(M)
+    tables[2] = 1 + M + np.arange(M)
+    if program == "decode":
+        return eng._decode_fn_paged, (
+            rng.integers(0, V, (B,)).astype(np.int32),
+            np.asarray([7, 0, 16, 0], np.int32), tables,
+            *eng._samp_batch_examples())
+    if program == "prefill":
+        # a 8-token suffix behind a cached 8-token prefix, 5 valid
+        return eng._prefill_fn_paged, (
+            rng.integers(0, V, (1, 8)).astype(np.int32), np.int32(5),
+            np.int32(8), tables[2], *eng._samp_scalar_examples())
+    W = eng.ecfg.verify_window
+    return eng._verify_fn_paged, (
+        rng.integers(0, V, (B, W)).astype(np.int32),
+        np.asarray([7, 0, 14, 0], np.int32), tables,
+        *eng._samp_batch_examples())
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "verify"])
+def test_carried_pools_match_xs_ys_scan(tiny_model, monkeypatch, program):
+    """Part 1 changes where the pools live in the layer loop, not a
+    number: each paged program over the carried pools returns the pools,
+    logits and tokens that the scan over per-layer slices returned."""
+    from paddle_tpu.serving import engine as engine_mod
+
+    eng = make_engine(tiny_model, kv_layout="paged", page_size=8,
+                      verify_window=3)
+    assert eng.kv_path == "xla_gather"
+    fn, args = _paged_program(eng, program, np.random.default_rng(5))
+    kp, vp = _seeded_pools(eng, 6)
+    new = jax.jit(fn)(eng.qparams, kp, vp, *args)
+    monkeypatch.setattr(engine_mod, "_layers_over_pools", _xs_ys_layers)
+    old = jax.jit(fn)(eng.qparams, kp, vp, *args)
+    for got, want in zip(new, old):
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+    # the program wrote the rows it was given and no others
+    changed = np.flatnonzero(
+        (np.asarray(new[0], np.float32)
+         != np.asarray(kp, np.float32)).any(axis=(0, 2, 3, 4)))
+    M = eng.cache.max_pages_per_slot
+    allowed = {"decode": {0, 1 + 0, 1 + M + 2},
+               "prefill": {1 + M + 1},
+               "verify": {0, 1 + 0, 1 + 1, 1 + M + 1, 1 + M + 2}}[program]
+    assert set(changed.tolist()) <= allowed, changed
+
+
+def test_kernel_and_gather_ticks_agree_over_a_run(tiny_model):
+    """The two lowerings of the paged decode tick (the Pallas kernel, in
+    interpret mode here, and gather + masked softmax) through a
+    multi-tick, multi-slot engine run: the same greedy tokens, logits and
+    pools to float rounding. The test steers the engine's choice (the CPU
+    lane would take the gather), as tests/test_chip_compile.py steers the
+    backend question."""
+    gather = make_engine(tiny_model, kv_layout="paged", page_size=8)
+    kernel = make_engine(tiny_model, kv_layout="paged", page_size=8)
+    kernel.kv_path = "pallas_paged"          # before anything compiles
+    assert gather.kv_path == "xla_gather"
+    rng = np.random.RandomState(3)
+    prompts = [rng.randint(0, gather.cfg.vocab_size, size=n).tolist()
+               for n in (1, 8, 13)]          # 1 token; a page edge; ragged
+    runs = []
+    for eng in (gather, kernel):
+        slots, toks = [], {}
+        for p in prompts:
+            slot, logits = eng.start_sequence(p)
+            slots.append(slot)
+            toks[slot] = int(np.argmax(logits))
+        trail = []
+        for tick in range(10):
+            feed = dict(toks)
+            if tick == 4:                    # one slot sits a tick out
+                feed.pop(slots[1])
+            out = eng.decode_step(feed)
+            for slot, logits in out.items():
+                toks[slot] = int(np.argmax(logits))
+                trail.append((tick, slot, logits))
+        runs.append((trail, np.asarray(eng.cache.k, np.float32),
+                     np.asarray(eng.cache.v, np.float32)))
+    (t_g, k_g, v_g), (t_k, k_k, v_k) = runs
+    assert len(t_g) == len(t_k) == 29
+    for (tick, slot, lg), (_, slot_k, lk) in zip(t_g, t_k):
+        assert slot == slot_k
+        assert int(np.argmax(lg)) == int(np.argmax(lk)), (tick, slot)
+        np.testing.assert_allclose(lk, lg, atol=2e-5, rtol=2e-5)
+    # page 0 is the scratch page: dead lanes write it, nothing reads it
+    np.testing.assert_allclose(k_k[:, 1:], k_g[:, 1:], atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(v_k[:, 1:], v_g[:, 1:], atol=2e-5, rtol=2e-5)
+
+
+def test_tick_record_names_kv_path_and_live_pages(tiny_model):
+    """serve/decode_tick says how the tick read the cache and how many
+    pages its riders hold; /health says the engine's path."""
+    from paddle_tpu.observability import spans
+    from paddle_tpu.serving.server import FrontDoor
+
+    eng = make_engine(tiny_model, kv_layout="paged", page_size=8)
+    eng.warmup()
+    sched = serving.Scheduler(eng, serving.SchedulerConfig())
+    tracer = spans.default_tracer()
+    before = len(tracer.spans())
+    reqs = [sched.submit([1, 2, 3], max_new_tokens=3),
+            sched.submit(list(range(1, 10)), max_new_tokens=3)]
+    for _ in range(8):
+        sched.step()
+    assert all(r.state == "done" for r in reqs)
+    ticks = [s for s in tracer.spans()[before:]
+             if s["name"] == "serve/decode_tick"]
+    assert ticks
+    assert {t["attrs"]["kv_path"] for t in ticks} == {"xla_gather"}
+    # 3 and 9 prompt tokens (+ the first generated): 1 page and 2 pages
+    assert ticks[0]["attrs"]["live_pages"] == 3
+    assert make_engine(tiny_model).kv_path == "slab"
+    front = FrontDoor(scheduler=sched, port=0)
+    try:
+        assert front.health()["kv_path"] == "xla_gather"
+    finally:
+        front.httpd.server_close()

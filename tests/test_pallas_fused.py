@@ -583,6 +583,66 @@ def test_fused_paged_decode_parity(cdt):
                                atol=2e-6, rtol=2e-6)
 
 
+@pytest.mark.parametrize("cdt", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_paged_decode_layer_indexed_parity(cdt):
+    """The layer-indexed kernel over the engine's whole
+    [L, P, page, nh, hd] pool against paged_gather + decode_attention, at
+    every layer, with ragged lengths: a slot of one token, a slot ending
+    on a page edge, one a row past it, a full 2048-token slot, and dead
+    lanes (all-zero tables, position 0). The pools come back bit for bit
+    the reference's: the written rows and nothing else."""
+    rng = np.random.default_rng(4)
+    L, B, M, page, nh, hd = 3, 6, 128, 16, 2, 64
+    own = [1, 2, 3, M]                       # pages each live slot owns
+    P = 1 + sum(own)                         # page 0 = scratch
+    kp = jnp.asarray(rng.standard_normal((L, P, page, nh, hd)), cdt)
+    vp = jnp.asarray(rng.standard_normal((L, P, page, nh, hd)), cdt)
+    q = jnp.asarray(rng.standard_normal((B, nh, hd)), jnp.float32)
+    nk = jnp.asarray(rng.standard_normal((B, nh, hd)), jnp.float32)
+    nv = jnp.asarray(rng.standard_normal((B, nh, hd)), jnp.float32)
+    tables = np.zeros((B, M), np.int32)      # slots 1 and 4: dead lanes
+    first = 1
+    for slot, n in zip((0, 2, 3, 5), own):
+        tables[slot, :n] = first + np.arange(n)
+        first += n
+    tables = jnp.asarray(tables)
+    positions = jnp.asarray([0, 0, 2 * page - 1, 2 * page, 0,
+                             M * page - 1], jnp.int32)
+    live = [0, 2, 3, 5]
+
+    @jax.jit
+    def ref(q, kp, vp, nk, nv, layer):
+        phys = tables[jnp.arange(B), positions // page]
+        rows = positions % page
+        kp2 = DA.paged_cache_update(kp, nk, phys, rows, layer=layer)
+        vp2 = DA.paged_cache_update(vp, nv, phys, rows, layer=layer)
+        gk = DA.paged_gather(kp2, tables, layer=layer)
+        gv = DA.paged_gather(vp2, tables, layer=layer)
+        return (DA.decode_attention(q, gk, gv, positions + 1), kp2, vp2,
+                phys, rows)
+
+    fused = jax.jit(lambda q, kp, vp, nk, nv, layer:
+                    PK.fused_paged_decode_attention(
+                        q, kp, vp, nk, nv, tables, positions, layer=layer))
+    for layer in range(L):
+        out, kp2, vp2 = fused(q, kp, vp, nk, nv, jnp.int32(layer))
+        r_out, r_kp, r_vp, phys, rows = ref(q, kp, vp, nk, nv,
+                                            jnp.int32(layer))
+        np.testing.assert_allclose(np.asarray(out)[live],
+                                   np.asarray(r_out)[live],
+                                   atol=3e-6, rtol=3e-6)
+        for got, want, before in ((kp2, r_kp, kp), (vp2, r_vp, vp)):
+            got, want, before = (np.asarray(a, np.float32)
+                                 for a in (got, want, before))
+            np.testing.assert_array_equal(got, want)
+            # outside the written rows and the scratch page: untouched
+            mask = np.ones(got.shape[:3], bool)
+            mask[:, 0] = False
+            mask[layer, np.asarray(phys), np.asarray(rows)] = False
+            np.testing.assert_array_equal(got[mask], before[mask])
+
+
 def test_fused_logits_head_parity():
     rng = np.random.default_rng(3)
     B, d, V = 4, 64, 300                     # V not a multiple of block_v

@@ -216,7 +216,8 @@ def test_span_tree_of_a_step_on_a_paged_engine(tracer, paged_engine,
     assert len(ticks) == len({t["attrs"]["step"] for t in ticks}) == 4
     assert ticks[0]["attrs"] == {"step": 0, "batch": 2,
                                  "riders": [r1.id, r2.id],
-                                 "cached_tokens": 5 + 3}
+                                 "cached_tokens": 5 + 3,
+                                 "kv_path": "xla_gather", "live_pages": 2}
     # a tick's four phases, in order, and they account for the tick
     shares = []
     for t in ticks:

@@ -104,41 +104,52 @@ def test_fused_decode_slab_matches_xla_on_tpu(cdt):
                                atol=tol, rtol=tol)
 
 
+@pytest.mark.parametrize("layers", [None, 3], ids=["one_layer", "pool5d"])
 @pytest.mark.parametrize("cdt", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
-def test_fused_decode_paged_matches_xla_on_tpu(cdt):
+def test_fused_decode_paged_matches_xla_on_tpu(cdt, layers):
+    """The paged decode step against the unfused XLA expressions, over one
+    layer's [P, page, nh, hd] pool and over the engine's whole
+    [L, P, page, nh, hd] pool with a traced layer index (the tick's
+    carried pools). Ragged lengths; slot 6 is a dead lane (all-zero
+    table, position 0: it writes the scratch page)."""
     rng = np.random.default_rng(2)
     M = S // PAGE
     P = 1 + B * M                            # page 0 = scratch
-    kp = jnp.asarray(rng.standard_normal((P, PAGE, NH, HD)), cdt)
-    vp = jnp.asarray(rng.standard_normal((P, PAGE, NH, HD)), cdt)
+    lead = () if layers is None else (layers,)
+    kp = jnp.asarray(rng.standard_normal(lead + (P, PAGE, NH, HD)), cdt)
+    vp = jnp.asarray(rng.standard_normal(lead + (P, PAGE, NH, HD)), cdt)
     q, nk, nv = (jnp.asarray(rng.standard_normal((B, NH, HD)), cdt)
                  for _ in range(3))
     perm = rng.permutation(np.arange(1, P)).reshape(B, M)   # disjoint
+    perm[6] = 0
     tables = jnp.asarray(perm, jnp.int32)
-    positions = jnp.asarray([0, 5, 255, 256, 700, 1023, 17, 512], jnp.int32)
+    positions = jnp.asarray([0, 5, 255, 256, 700, 1023, 0, 512], jnp.int32)
+    layer = None if layers is None else jnp.int32(1)
+    live = np.arange(B) != 6
 
     @jax.jit
-    def ref(q, kp, vp, nk, nv):
+    def ref(q, kp, vp, nk, nv, layer):
         phys = tables[jnp.arange(B), positions // PAGE]
         rows = positions % PAGE
-        kp2 = DA.paged_cache_update(kp, nk, phys, rows)
-        vp2 = DA.paged_cache_update(vp, nv, phys, rows)
-        gk = DA.paged_gather(kp2, tables)
-        gv = DA.paged_gather(vp2, tables)
+        kp2 = DA.paged_cache_update(kp, nk, phys, rows, layer=layer)
+        vp2 = DA.paged_cache_update(vp, nv, phys, rows, layer=layer)
+        gk = DA.paged_gather(kp2, tables, layer=layer)
+        gv = DA.paged_gather(vp2, tables, layer=layer)
         return DA.decode_attention(q, gk, gv, positions + 1), kp2, vp2
 
-    fused = jax.jit(lambda q, kp, vp, nk, nv:
+    fused = jax.jit(lambda q, kp, vp, nk, nv, layer:
                     PK.fused_paged_decode_attention(
-                        q, kp, vp, nk, nv, tables, positions))
-    assert "tpu_custom_call" in fused.lower(q, kp, vp, nk, nv).as_text()
-    out, kp2, vp2 = fused(q, kp, vp, nk, nv)
-    r_out, r_kp, r_vp = ref(q, kp, vp, nk, nv)
+                        q, kp, vp, nk, nv, tables, positions, layer=layer))
+    assert "tpu_custom_call" in fused.lower(q, kp, vp, nk, nv,
+                                            layer).as_text()
+    out, kp2, vp2 = fused(q, kp, vp, nk, nv, layer)
+    r_out, r_kp, r_vp = ref(q, kp, vp, nk, nv, layer)
     np.testing.assert_array_equal(np.asarray(kp2, np.float32),
                                   np.asarray(r_kp, np.float32))
     np.testing.assert_array_equal(np.asarray(vp2, np.float32),
                                   np.asarray(r_vp, np.float32))
     tol = 2e-2 if cdt == jnp.bfloat16 else 2e-3
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(r_out, np.float32),
+    np.testing.assert_allclose(np.asarray(out, np.float32)[live],
+                               np.asarray(r_out, np.float32)[live],
                                atol=tol, rtol=tol)
