@@ -29,6 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 
 MODES = ("train", "serve")
+# the configuration keys that are widths: ``reduced`` may name none of them
+WIDTH_KEYS = ("n_embd", "n_inner", "n_head")
 LN_EPS = 1e-5
 # leaves stacked on a leading layer axis, in the published names
 BLOCK_LEAVES = ("ln_1_g", "ln_1_b", "c_attn_w", "c_attn_b", "c_proj_w",
@@ -320,11 +322,6 @@ class ServeProgram:
         total = sum(s["value"] for s in snap.get(
             "paddle_recompiles_total", {}).get("series", []))
         return total + self.engine.steady_state_recompiles
-
-    def live_lengths(self):
-        cache = self.engine.cache
-        return [cache.length(s) for s in range(self.engine.ecfg.max_batch)
-                if cache.is_live(s)]
 
     def free(self):
         """Let go of weights, cache and executables, whoever still holds

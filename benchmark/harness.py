@@ -11,6 +11,7 @@ own that the harness finds by that name:
     benchmark/traffic_kinds/<kind>.py      the generator and the window
     benchmark/layer_metrics/<metric>.py    one reader of spans/counters/trace
 
+``benchmark/checks.py`` holds what has to be true of all of them together.
 ``main`` is the measuring path and refuses anything but a TPU. ``rehearse``
 runs the same code at the tiny sizes a cell's file gives under
 ``rehearsal``, on whatever backend there is, and stamps the device it ran on.
@@ -29,7 +30,8 @@ from benchmark import trace_reduce
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
-RESULT_KEYS = ("correct", "attempted", "failed", "metrics", "device")
+RESULT_KEYS = ("correct", "attempted", "failed", "metrics", "device",
+               "checks")
 
 
 class Refused(Exception):
@@ -173,7 +175,10 @@ class Run:
         self.marks = []
         self.setup_s = None
         self.window = None           # (t0, t1), time.monotonic
-        self.trace_dir = os.path.join(cell.root, ".bench_trace", cell.name)
+        # a directory of this process's own: two runs of one cell at once
+        # (the tests' workers) must not empty each other's trace
+        self.trace_dir = os.path.join(cell.root, ".bench_trace",
+                                      f"{cell.name}.{os.getpid()}")
         self.trace_window = None
         self.profile = None          # trace_reduce.Profile after the run
         self.counters = {}
@@ -315,10 +320,11 @@ def run_cell(root, workload, seed, seconds, trace, rehearsal=False,
 
     cell.kind.run(run)
 
-    for name, value, limit in run.checks:
-        print(f"[bench] check {name}: {value:.6g} (limit {limit:.6g}) "
-              f"{'ok' if value <= limit else 'FAILED'}", file=out,
-              flush=True)
+    check_lines = "".join(
+        f"[bench] check {name}: {value:.6g} (limit {limit:.6g}) "
+        f"{'ok' if value <= limit else 'FAILED'}\n"
+        for name, value, limit in run.checks)
+    print(check_lines, end="", file=out, flush=True)
     correct = bool(run.checks) and all(v <= lim for _, v, lim in run.checks)
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices),
@@ -346,7 +352,12 @@ def run_cell(root, workload, seed, seconds, trace, rehearsal=False,
                                   "unit": m["unit"]}
     result["metrics"] = metrics
     result["device"] = device
+    # every number compared beside its limit: last in the line, and the
+    # last lines on standard error
+    result["checks"] = {name: {"value": value, "limit": limit}
+                        for name, value, limit in run.checks}
     print(json.dumps(result), file=out, flush=True)
+    print(check_lines, end="", file=sys.stderr, flush=True)
     return result
 
 

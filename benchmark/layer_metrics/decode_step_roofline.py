@@ -2,10 +2,12 @@
 bound by memory: the least time is the bytes it has to read (every
 multiplied weight once, and the keys and values of the live slots up to
 their lengths at that tick: the family's count from the shapes) over the
-HBM bandwidth of ``benchmark/peaks.json``, averaged over the traced ticks.
-The time is the mean device duration of the decode step's program in the
-trace (the "XLA Modules" line, by the jitted function's name)."""
-from benchmark import trace_reduce
+HBM bandwidth of ``benchmark/peaks.json``, averaged over the traced ticks:
+``cached_tokens`` of the program's ``serve/decode_tick`` records
+(``Scheduler._decode``) that lie inside the traced window. The time is the
+mean device duration of the decode step's program in the trace (the "XLA
+Modules" line, by the jitted function's name)."""
+from benchmark import program_spans
 
 META = {"name": "decode_step_roofline", "layer": "kernels", "unit": "%",
         "share_of_peak": True, "better": "higher", "source": "device_trace",
@@ -19,10 +21,8 @@ def read(run):
         return None
     runs = [d for evs in run.profile.modules.values()
             for name, _, d in evs if PROGRAM in name]
-    t0, t1 = run.trace_window
-    ticks = [attrs["cached_tokens"]
-             for _, a, b, attrs in run.spans.named("decode_tick")
-             if t0 <= a and b <= t1]
+    ticks = [r["attrs"]["cached_tokens"] for r in program_spans.named(
+        run, "serve/decode_tick", window=run.trace_window) or []]
     if not runs or not ticks:
         return None
     sv = run.cell.config["serving"]

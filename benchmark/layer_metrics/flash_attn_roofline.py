@@ -3,27 +3,27 @@ the chip could take for the causal attention of the traced steps (the
 family's count, forward plus twice that for the backward, over the bf16
 peak: the kernel is compute-bound at these shapes, its bytes take a tenth
 of that time) over the summed device time of the flash forward and
-backward kernels in the trace. The trace does not carry a Pallas kernel's
-name, so the kernels are told by what they are: Mosaic custom calls
-(``tpu_custom_call``) whose result has the attention operands' shape
-[batch * heads, T, head size]. A forward pass recomputed by the remat
-policy is time the kernels took and no operation the algorithm requires."""
+backward kernels in the trace. The kernels are told by their name:
+``pl.pallas_call(name="flash_fwd" | "flash_bwd_dq" | "flash_bwd_dkv")`` is
+the HLO instruction's own ``%name`` in the trace, so the Mosaic custom
+calls (``tpu_custom_call``) whose short name starts with ``flash_``;
+another family's kernel with the same result shape is not attention. A
+forward pass recomputed by the remat policy is time the kernels took and no
+operation the algorithm requires."""
 from benchmark import trace_reduce
 
 META = {"name": "flash_attn_roofline", "layer": "kernels", "unit": "%",
         "share_of_peak": True, "better": "higher", "source": "device_trace",
         "moves": "train_tokens_per_s"}
-KERNEL = "custom-call/tpu_custom_call"
+KERNEL, NAME_HEAD = "custom-call/tpu_custom_call", "flash_"
 
 
 def read(run):
     if run.profile is None or not run.profile.devices or run.peaks is None:
         return None
     c = run.counters
-    d = run.cell.family.dims(run.cell.config)
-    shape = f"[{c['batch'] * d['H']},{c['seq_len']},{d['hd']}]"
     seconds, events = trace_reduce.seconds_matching(run.profile, KERNEL,
-                                                    shape)
+                                                    head=NAME_HEAD)
     steps = len([s for s in run.profile.spans if s[0] == "train_step"])
     if not events or not steps:
         return None

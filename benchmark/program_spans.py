@@ -6,30 +6,26 @@ clock, and while a span is open it is a profiler annotation ``paddle/<name>``
 on the profiler's clock. Two things are read here, once a run:
 
 - ``named(run, name)``: the ring's records of that name that lie inside
-  ``run.window``. The window is ``time.monotonic()``; the tracer's own
-  helper turns it into the ring's units. Where the ring may have lost a
+  ``run.window`` (or inside another window of the same clock, such as
+  ``run.trace_window``). The window is ``time.monotonic()``; the tracer's
+  own helper turns it into the ring's units. Where the ring may have lost a
   record of the window (its ``dropped`` is up and its oldest record is no
   older than the window), nothing is handed out: no number beats a median
   over what happened to be left.
-- ``traced(run)``: a ``trace_reduce.Profile`` of the traced window whose
-  spans are the ``paddle/`` annotations, read from the newest
-  ``*.xplane.pb`` under ``run.trace_dir`` (``trace_reduce.load_xplane``
-  keeps the benchmark's own ``bench/`` spans only); the device planes are
-  those the harness has loaded. From it: the idle gaps by the program span
-  the host was in (printed once, ``[bench] idle by program span: ...``),
-  the host's part of each decode tick, and the share of the idle time that
-  no leaf span of the program covers.
+- ``traced(run)``: the ``trace_reduce.Profile`` the harness loaded from the
+  run's xplane, where it holds a device plane and any span
+  (``trace_reduce.load_xplane`` keeps the ``paddle/`` annotations). From
+  it: the idle time by the span the host was in (printed once, ``[bench]
+  idle by program span: ...``, the same division the result line's
+  ``breakdown.idle_gaps`` carries), the host's part of each decode tick,
+  and the share of the idle time that no leaf span of the program covers.
 
 A program from before its tracer could be read (no ``dropped``, no clock
 helper, no annotation) gives ``None`` everywhere, and a reader that gets
 ``None`` reports nothing.
 """
-import glob
-import os
-
 from benchmark import trace_reduce
 
-PREFIX = "paddle/"
 # the spans with no span of the program inside them: idle time under one of
 # these has a name; under their parents alone (serve/step, serve/admit,
 # serve/decode_tick, serve/prefill) it has not
@@ -60,30 +56,47 @@ def window_records(spans_module, window):
                     records[0]["start_ns"] + records[0]["dur_ns"] >= w0):
         return None
     by_name = {}
-    for r in records:
-        if w0 <= r["start_ns"] and r["start_ns"] + r["dur_ns"] <= w1:
-            by_name.setdefault(r["name"], []).append(r)
+    for r in _within(records, w0, w1):
+        by_name.setdefault(r["name"], []).append(r)
     return by_name
+
+
+def _within(records, w0, w1):
+    return [r for r in records
+            if w0 <= r["start_ns"] and r["start_ns"] + r["dur_ns"] <= w1]
+
+
+def _spans_module():
+    from paddle_tpu.observability import spans
+
+    return spans
 
 
 def _read_once(run):
     cached = getattr(run, "_program_spans", None)
     if cached is None:
-        from paddle_tpu.observability import spans as spans_module
-
-        ring = window_records(spans_module, run.window)
-        profile = _load_traced(run)
-        if profile is not None:
+        ring = window_records(_spans_module(), run.window)
+        profile = getattr(run, "profile", None)
+        if profile is None or not profile.devices or not profile.spans:
+            profile = None
+        else:
             print("[bench] " + idle_line(profile), flush=True)
         cached = run._program_spans = (ring, profile)
     return cached
 
 
-def named(run, name):
-    """The ring's records called ``name`` inside the run's window, oldest
-    first; None where the ring cannot be read."""
+def named(run, name, window=None):
+    """The ring's records called ``name`` inside the run's window (and
+    inside ``window`` too, ``time.monotonic()`` seconds, where one is
+    given), oldest first; None where the ring cannot be read."""
     ring = _read_once(run)[0]
-    return None if ring is None else ring.get(name, [])
+    if ring is None:
+        return None
+    records = ring.get(name, [])
+    if window is not None:
+        to_ns = _spans_module().monotonic_to_ns
+        records = _within(records, to_ns(window[0]), to_ns(window[1]))
+    return records
 
 
 def ms(records):
@@ -102,56 +115,16 @@ def by_step(records):
 # the profiler's trace
 # ---------------------------------------------------------------------------
 
-def annotations(path):
-    """[(name without the prefix, start_ns, dur_ns)] of the ``paddle/``
-    host events of an ``.xplane.pb``, by start."""
-    from jax.profiler import ProfileData
-
-    out = []
-    for plane in ProfileData.from_file(path).planes:
-        if plane.name.startswith(trace_reduce.DEVICE_PLANE_PREFIX):
-            continue
-        for line in plane.lines:
-            for ev in line.events:
-                if ev.name.startswith(PREFIX):
-                    out.append((ev.name[len(PREFIX):], int(ev.start_ns),
-                                int(ev.duration_ns)))
-    out.sort(key=lambda e: e[1])
-    return out
-
-
-def _load_traced(run):
-    base = getattr(run, "profile", None)
-    if base is None or not base.devices:
-        return None
-    found = sorted(glob.glob(os.path.join(
-        run.trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
-    if not found:
-        return None
-    spans = annotations(found[-1])
-    if not spans:
-        return None
-    return trace_reduce.Profile(base.devices, base.modules, spans)
-
-
 def traced(run):
-    """The traced window with the program's annotations as its spans, or
-    None: no device plane (a rehearsal on the CPU), or no annotation."""
+    """The traced window with the host's spans on the device's clock, or
+    None: no device plane (a rehearsal on the CPU), or no span."""
     return _read_once(run)[1]
-
-
-def idle_by_span(profile):
-    """[(span name, idle seconds)] over the traced window, the innermost
-    program span at each gap's middle, most first."""
-    out = {}
-    for name, _, d in trace_reduce.idle_gaps(profile):
-        out[name] = out.get(name, 0) + d / 1e9
-    return sorted(out.items(), key=lambda kv: -kv[1])
 
 
 def idle_line(profile):
     return "idle by program span: " + ", ".join(
-        f"{name} {seconds:.6f}" for name, seconds in idle_by_span(profile))
+        f"{name} {seconds:.6f}"
+        for name, seconds in trace_reduce.idle_by_span(profile))
 
 
 def host_gaps_ms(profile, name):
@@ -170,8 +143,8 @@ def host_gaps_ms(profile, name):
 
 
 def unattributed_idle_share(profile):
-    """Share (%) of the traced window's device idle time whose gap has its
-    middle under no leaf span of the program."""
+    """Share (%) of the traced window's device idle time that lies under
+    no leaf span of the program."""
     gaps = trace_reduce.idle_gaps(profile)
     total = sum(d for _, _, d in gaps)
     if not total:

@@ -1,25 +1,28 @@
 """From the profiler's trace to numbers: the union of device-busy
-intervals, durations by operation name, and idle gaps attributed to the
-benchmark span the host was in.
+intervals, durations by operation name, and idle gaps divided over the
+spans the host was in.
 
 The reduction works on a ``Profile``: per device plane the leaf operations
-as ``(name, start_ns, dur_ns)``, and the benchmark's own spans (host
-``TraceAnnotation`` events whose name starts with ``SPAN_PREFIX``) on the
-same clock. ``load_xplane`` makes one from the ``.xplane.pb`` the JAX
-profiler writes; ``Profile.from_json`` from the compact recording kept
-under ``benchmark/fixtures/``.
+as ``(name, start_ns, dur_ns)``, and the host's spans on the same clock:
+the program's own (``TraceAnnotation`` events ``paddle/<name>``, written by
+its tracer while a span is open) beside the few a traffic kind still puts
+round its own calls (``bench/<name>``). ``load_xplane`` makes one from the
+``.xplane.pb`` the JAX profiler writes; ``Profile.from_json`` from the
+compact recording kept under ``benchmark/fixtures/``.
 """
 import gzip
+import heapq
 import json
 import re
 
-SPAN_PREFIX = "bench/"
+SPAN_PREFIX = "bench/"              # what ``harness.Spans`` writes
+PROGRAM_PREFIX = "paddle/"          # what the program's tracer writes
 DEVICE_PLANE_PREFIX = "/device:TPU:"
 # the line of a TPU plane that holds the leaf operations (one event per
 # executed HLO instruction or fusion); "XLA Modules" holds whole programs
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-OUTSIDE = "outside_benchmark_spans"
+OUTSIDE = "outside_every_span"
 
 
 class Profile:
@@ -83,8 +86,8 @@ def load_xplane(path):
         else:
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name.startswith(SPAN_PREFIX):
-                        spans.append((ev.name[len(SPAN_PREFIX):],
+                    if ev.name.startswith((SPAN_PREFIX, PROGRAM_PREFIX)):
+                        spans.append((ev.name.split("/", 1)[1],
                                       int(ev.start_ns),
                                       int(ev.duration_ns)))
     spans.sort(key=lambda e: e[1])
@@ -172,44 +175,84 @@ def durations_by_name(profile):
     return {k: v / n / 1e9 for k, v in out.items()}
 
 
-def seconds_matching(profile, *needles):
-    """Summed device seconds of the operations whose name holds every one
-    of ``needles``, averaged over planes, and how many events a plane."""
+def seconds_matching(profile, *needles, head=""):
+    """Summed device seconds of the operations whose name starts with
+    ``head`` and holds every one of ``needles``, averaged over planes, and
+    how many events a plane."""
     total, count = 0, 0
     for evs in profile.devices.values():
         for name, d in self_times(evs):
-            if all(n in name for n in needles):
+            if name.startswith(head) and all(n in name for n in needles):
                 total += d
                 count += 1
     n = max(len(profile.devices), 1)
     return total / n / 1e9, count // n
 
 
+def innermost_timeline(spans):
+    """``[(start, end, name)]``, disjoint and by start: over every stretch
+    that any span covers, the innermost span there (the shortest of those
+    that cover it, as nested spans go)."""
+    edges = sorted({x for _, s, d in spans if d > 0 for x in (s, s + d)})
+    starts = sorted((s, d, name) for name, s, d in spans if d > 0)
+    out, live, k = [], [], 0         # live: heap of (dur, end, name)
+    for a, b in zip(edges, edges[1:]):
+        while k < len(starts) and starts[k][0] <= a:
+            s, d, name = starts[k]
+            heapq.heappush(live, (d, s + d, name))
+            k += 1
+        while live and live[0][1] <= a:
+            heapq.heappop(live)
+        if not live:
+            continue
+        name = live[0][2]
+        if out and out[-1][2] == name and out[-1][1] == a:
+            out[-1] = (out[-1][0], b, name)
+        else:
+            out.append((a, b, name))
+    return out
+
+
 def idle_gaps(profile):
     """Every gap between busy intervals inside the window, on the first
-    device plane, with the benchmark span the host was in at the middle of
-    the gap, the innermost where spans nest:
-    [(span name, start_ns, dur_ns)]."""
+    device plane, divided over the innermost spans that cover it, each
+    getting the part it covers (what no span covers goes to ``OUTSIDE``):
+    [(span name, start_ns, dur_ns)], by start."""
     w0, w1 = window(profile)
     plane = sorted(profile.devices)[0]
     busy = busy_intervals(profile, plane)
     edges = [w0] + [x for iv in busy for x in iv] + [w1]
     gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
             if edges[i + 1] > edges[i]]
-    out = []
+    line = innermost_timeline(profile.spans)
+    out, k = [], 0
     for g0, g1 in gaps:
-        mid = (g0 + g1) / 2
-        inside = [(d, name) for name, s, d in profile.spans
-                  if s <= mid < s + d]
-        out.append((min(inside)[1] if inside else OUTSIDE, g0, g1 - g0))
+        at = g0
+        while k < len(line) and line[k][1] <= g0:
+            k += 1
+        j = k
+        while j < len(line) and line[j][0] < g1:
+            a, b, name = line[j]
+            a, b = max(a, g0), min(b, g1)
+            if a > at:
+                out.append((OUTSIDE, at, a - at))
+            out.append((name, a, b - a))
+            at = b
+            j += 1
+        if g1 > at:
+            out.append((OUTSIDE, at, g1 - at))
     return out
+
+
+def idle_by_span(profile):
+    """[(span name, idle seconds)] over the traced window, most first."""
+    by_span = {}
+    for name, _, d in idle_gaps(profile):
+        by_span[name] = by_span.get(name, 0) + d / 1e9
+    return sorted(by_span.items(), key=lambda kv: -kv[1])
 
 
 def breakdown(profile, top=10):
     ops = sorted(durations_by_name(profile).items(), key=lambda kv: -kv[1])
-    by_span = {}
-    for name, _, d in idle_gaps(profile):
-        by_span[name] = by_span.get(name, 0) + d / 1e9
-    gaps = sorted(by_span.items(), key=lambda kv: -kv[1])
     return {"device_ops": [[k, v] for k, v in ops[:top]],
-            "idle_gaps": [[k, v] for k, v in gaps[:top]]}
+            "idle_gaps": [[k, v] for k, v in idle_by_span(profile)[:top]]}

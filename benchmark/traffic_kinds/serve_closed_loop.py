@@ -118,49 +118,6 @@ def summarise(records, t0, t1, vocab_size):
             "gap_ms": gaps}
 
 
-def install_spans(run, program, inflight):
-    """Wrap the calls into each layer (traced runs only): the scheduler's
-    step, the engine's prefill entries and its decode tick."""
-    spans, sched, eng = run.spans, program.scheduler, program.engine
-    state = {"prefills": 0, "step_t0": 0.0}
-    queue_ms, admitted = [], set()
-    inner_step = sched.step
-
-    def step():
-        state["prefills"] = 0
-        state["step_t0"] = time.monotonic()
-        with spans.span("sched_step") as attrs:
-            worked = inner_step()
-            attrs["prefills"] = state["prefills"]
-            attrs["worked"] = bool(worked)
-        for req, _ in list(inflight.values()):
-            if req.id not in admitted and req.state != "queued":
-                admitted.add(req.id)
-                queue_ms.append((state["step_t0"] - req.submitted) * 1e3)
-        return worked
-
-    def wrap_prefill(inner):
-        def call(tokens, params):
-            state["prefills"] += 1
-            with spans.span("prefill", prompt_len=len(tokens)):
-                return inner(tokens, params)
-        return call
-
-    inner_decode = eng.decode_step_sampled
-
-    def decode(slot_tokens, params_by_slot):
-        lengths = program.live_lengths()
-        with spans.span("decode_tick", live=len(slot_tokens),
-                        cached_tokens=sum(lengths)):
-            return inner_decode(slot_tokens, params_by_slot)
-
-    sched.step = step
-    eng.start_sequence_sampled = wrap_prefill(eng.start_sequence_sampled)
-    eng.resume_sequence_sampled = wrap_prefill(eng.resume_sequence_sampled)
-    eng.decode_step_sampled = decode
-    run.counters["queue_ms"] = queue_ms
-
-
 def tick_histogram(records, t0, t1):
     """{ms between consecutive token stamps in the window, to 10 ms:
     how often}: the ticks as the clients saw them."""
@@ -328,8 +285,6 @@ def run(run):
             program.vocab_size, tr["logit_columns"], replace=False)))
     inflight = {}                   # client -> (Request, tokens asked for)
     records = []                    # the same pairs, once finished
-    if run.trace:
-        install_spans(run, program, inflight)
 
     def send(client):
         prompt, n_out = plan.next()

@@ -1,8 +1,11 @@
-"""BENCHMARK.json against its contract, and every name in it against the
-file it stands for."""
+"""BENCHMARK.json against its contract, every name in it against the file
+it stands for, and every configuration against its own record of what its
+source publishes. The checks are functions of a root directory
+(``benchmark/checks.py``): here they run on the repo, in
+``test_benchmark_extend.py`` on a copy with a cell of another family."""
 import json
 import os
-import re
+import shutil
 import sys
 
 import pytest
@@ -11,150 +14,139 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from benchmark import harness  # noqa: E402
+from benchmark import checks, harness  # noqa: E402
 
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
-SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-
-
-@pytest.fixture(scope="module")
-def manifest():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
-
-
-def test_top_level_keys(manifest):
-    assert set(manifest) == {"command", "paths", "run_seconds", "configs",
-                             "workloads", "end_to_end", "per_layer"}
-    assert manifest["command"] == ["python3", "benchmark/run.py"]
-    assert manifest["paths"] == ["benchmark", "tests/benchmark_harness"]
-    assert all(PATH.match(p) for p in manifest["paths"])
-    assert isinstance(manifest["run_seconds"], int)
-    assert 1 <= manifest["run_seconds"] <= 51
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 64 * 1024
+# what the sources publish, written down here and not read from the files:
+# the general check holds a file to its own ``published``, this one holds
+# ``published`` to the source
+PUBLISHED = {
+    "cerebras-gpt-1.3b": {"n_embd": 2048, "n_head": 16, "n_inner": 8192,
+                          "vocab_size": 50257, "n_positions": 2048,
+                          "n_layer": 24},
+    "cerebras-gpt-1.3b-l6": {"n_embd": 2048, "n_head": 16, "n_inner": 8192,
+                             "vocab_size": 50257, "n_positions": 2048,
+                             "n_layer": 24},
+}
 
 
-def test_run_seconds_fits_a_full_check_of_24_cells(manifest):
-    runs = 2 + 14 * 24
-    total = runs * (manifest["run_seconds"] + 60) + 24 * 2 * 90 + 1200
-    assert total <= 43200
+@pytest.mark.parametrize("check", checks.MANIFEST_CHECKS,
+                         ids=lambda f: f.__name__)
+def test_manifest(check):
+    check(ROOT)
 
 
-def _one_line(text, limit=200):
-    return 1 <= len(text) <= limit and "\n" not in text and "\t" not in text
+@pytest.mark.parametrize("name", sorted(PUBLISHED))
+def test_published_is_the_sources_own(name):
+    (entry,) = [c for c in checks.manifest(ROOT)["configs"]
+                if c["name"] == name]
+    doc = harness.load_json(os.path.join(ROOT, entry["file"]))
+    assert doc["published"] == PUBLISHED[name]
+    # only the depth of the training one is cut, and only deployment sizes
+    # of the serving one
+    assert set(entry["reduced"]) & set(PUBLISHED[name]) == (
+        {"n_layer"} if name.endswith("-l6") else set())
 
 
-def test_configs(manifest):
-    names = [c["name"] for c in manifest["configs"]]
-    assert len(set(names)) == len(names) and 1 <= len(names) <= 24
-    files = [c["file"] for c in manifest["configs"]]
-    assert len(set(files)) == len(files)
-    used = {w["config"] for w in manifest["workloads"]}
+def test_the_pinned_configurations_are_in_the_manifest():
+    # a later configuration brings a pinned record of its own, in a test
+    # file of its own: this one holds the two that are here to theirs
+    assert set(PUBLISHED) <= {c["name"]
+                              for c in checks.manifest(ROOT)["configs"]}
+
+
+# ---------------------------------------------------------------------------
+# the general check can fail
+# ---------------------------------------------------------------------------
+
+@pytest.fixture()
+def copy(tmp_path):
+    """The manifest and its configurations and families, to break."""
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    for sub in ("configs", "families"):
+        shutil.copytree(os.path.join(ROOT, "benchmark", sub),
+                        tmp_path / "benchmark" / sub,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    return tmp_path
+
+
+def _edit(root, config, change):
+    path = root / "benchmark" / "configs" / (config + ".json")
+    doc = json.loads(path.read_text())
+    change(doc)
+    path.write_text(json.dumps(doc))
+    manifest = json.loads((root / "BENCHMARK.json").read_text())
     for c in manifest["configs"]:
-        assert set(c) == {"name", "source", "file", "reduced", "why"}
-        assert NAME.match(c["name"]) and c["name"] in used
-        assert _one_line(c["source"]) and _one_line(c["why"])
-        assert c["file"].startswith("benchmark/configs/")
-        assert len(c["reduced"]) <= 16
-        doc = harness.load_json(os.path.join(ROOT, c["file"]))
-        assert doc["name"] == c["name"] and doc["source"] == c["source"]
-        assert doc["reduced"] == c["reduced"]
-        for key in c["reduced"]:
-            assert NAME.match(key) and key in doc
-            # never a width
-            assert not re.search(r"(_dim|_rank)$", key)
-            assert key not in ("n_embd", "n_inner", "n_head")
-        assert os.path.isfile(os.path.join(
-            ROOT, "benchmark", "families", doc["family"] + ".py"))
+        if c["name"] == config:
+            c["reduced"] = doc["reduced"]
+    (root / "BENCHMARK.json").write_text(json.dumps(manifest))
 
 
-def test_published_widths_are_kept(manifest):
-    """Both configurations carry Cerebras-GPT-1.3B's published sizes; only
-    the depth of the training one is cut."""
-    published = {"n_embd": 2048, "n_head": 16, "n_inner": 8192,
-                 "vocab_size": 50257, "n_positions": 2048, "n_layer": 24}
-    for c in manifest["configs"]:
-        doc = harness.load_json(os.path.join(ROOT, c["file"]))
-        for key, value in published.items():
-            if key in c["reduced"]:
-                assert doc[key] != value
-                assert doc["reduced_from"][key] == value
-            else:
-                assert doc[key] == value, (c["name"], key)
-        # every cut says what it was cut from, the deployment's too
-        assert set(doc.get("reduced_from", {})) == set(c["reduced"])
+def _cut_a_width(doc):
+    doc["n_inner"] = 4096
+    doc["reduced"].append("n_inner")
+    doc["reduced_from"]["n_inner"] = 8192
 
 
-def test_workloads(manifest):
-    cells = manifest["workloads"]
-    names = [w["name"] for w in cells]
-    assert len(set(names)) == len(names) and 1 <= len(names) <= 24
-    pairs = [(w["config"], w["traffic"]) for w in cells]
-    assert len(set(pairs)) == len(pairs)
-    four = [w for w in cells if w["chips"] == 4]
-    assert len(four) <= max(1, len(cells) // 4)
-    for w in cells:
-        assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert all(NAME.match(w[k]) for k in ("name", "config", "traffic"))
-        assert w["chips"] in (1, 4) and _one_line(w["why"])
+def _cut_a_latent_rank(doc):
+    doc["published"]["kv_lora_rank"] = 512
+    doc["kv_lora_rank"] = 128
+    doc["reduced"].append("kv_lora_rank")
+    doc["reduced_from"]["kv_lora_rank"] = 512
 
 
-@pytest.mark.parametrize("rehearsal", [False, True])
-def test_every_cell_resolves_by_name(manifest, rehearsal):
-    for w in manifest["workloads"]:
-        cell = harness.Cell(ROOT, w["name"], rehearsal=rehearsal)
-        assert cell.spec["name"] == w["name"]
-        assert cell.spec["config"] == w["config"]
-        assert cell.spec["traffic"] == w["traffic"]
-        assert cell.spec["chips"] == w["chips"]
-        assert hasattr(cell.kind, "run")
-        for fn in ("build", "reference", "flops_per_token",
-                   "bytes_per_decode_step"):
-            assert hasattr(cell.family, fn)
-        e2e = [m["name"] for m in cell.metrics("end_to_end")]
-        assert "setup_s" in e2e and len(e2e) >= 2
-        assert cell.metrics("per_layer")
+def _cut_unlisted(doc):
+    doc["vocab_size"] = 32768
 
 
-def test_metrics(manifest):
-    e2e, layer = manifest["end_to_end"], manifest["per_layer"]
-    assert 1 <= len(e2e) <= 16 and 1 <= len(layer) <= 128
-    names = [m["name"] for m in e2e + layer]
-    assert len(set(names)) == len(names)
-    cells = {w["name"] for w in manifest["workloads"]}
-    assert "setup_s" in [m["name"] for m in e2e]
-    for m in e2e:
-        assert set(m) - {"workloads"} == {"name", "unit", "better",
-                                          "bound", "source"}
-        assert m["source"] in ("host_clock", "device_trace")
-        assert 0.01 <= m["bound"] <= 0.1
-    for m in layer:
-        assert set(m) - {"workloads"} == {"name", "unit", "better",
-                                          "source", "layer", "moves"}
-        assert m["source"] in SOURCES and _one_line(m["layer"])
-        assert m["moves"] in [e["name"] for e in e2e]
-    for m in e2e + layer:
-        assert NAME.match(m["name"]) and UNIT.match(m["unit"])
-        assert m["better"] in ("lower", "higher")
-        assert set(m.get("workloads", [])) <= cells
-    reporters = {e["name"]: set(e.get("workloads", cells)) for e in e2e}
-    for m in layer:    # a layer metric's cells report what it moves
-        assert set(m.get("workloads", cells)) <= reporters[m["moves"]]
+def _cut_without_its_origin(doc):
+    del doc["reduced_from"]["n_layer"]
 
 
-def test_layer_metric_files_agree_with_the_manifest(manifest):
-    for m in manifest["per_layer"]:
-        reader = harness.load_module(os.path.join(
-            ROOT, "benchmark", "layer_metrics", m["name"] + ".py"))
-        for key in ("name", "layer", "unit", "better", "source", "moves"):
-            assert reader.META[key] == m[key], (m["name"], key)
-        assert callable(reader.read)
-        # a share of a peak says so in its own file: the harness refuses a
-        # reading over 100 % by that key, whatever the metric is called
-        if "roofline" in m["name"] or "mfu" in m["name"]:
-            assert reader.META["share_of_peak"] is True and m["unit"] == "%"
+def _cut_from_another_value(doc):
+    doc["reduced_from"]["n_layer"] = 32
+
+
+def _cut_that_cuts_nothing(doc):
+    doc["n_layer"] = 24
+
+
+def _origin_of_no_cut(doc):
+    doc["reduced_from"]["n_positions"] = 4096
+
+
+def _no_record(doc):
+    del doc["published"]
+
+
+def _record_without_a_width(doc):
+    del doc["published"]["n_head"]
+
+
+@pytest.mark.parametrize("change", [
+    _cut_a_width, _cut_a_latent_rank, _cut_unlisted, _cut_without_its_origin,
+    _cut_from_another_value, _cut_that_cuts_nothing, _origin_of_no_cut,
+    _no_record, _record_without_a_width], ids=lambda f: f.__name__)
+def test_a_configuration_that_leaves_its_record_is_refused(copy, change):
+    checks.configurations_keep_what_their_source_publishes(str(copy))
+    _edit(copy, "cerebras-gpt-1.3b-l6", change)
+    with pytest.raises((AssertionError, KeyError)):
+        checks.configurations_keep_what_their_source_publishes(str(copy))
+
+
+def test_a_reader_whose_metric_went_is_refused(tmp_path):
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    shutil.copytree(os.path.join(ROOT, "benchmark", "layer_metrics"),
+                    tmp_path / "benchmark" / "layer_metrics",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    checks.layer_metric_files_agree_with_the_manifest(str(tmp_path))
+    manifest = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    gone = manifest["per_layer"].pop()
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(manifest))
+    with pytest.raises(AssertionError):
+        checks.layer_metric_files_agree_with_the_manifest(str(tmp_path))
+    os.remove(tmp_path / "benchmark" / "layer_metrics" /
+              (gone["name"] + ".py"))
+    checks.layer_metric_files_agree_with_the_manifest(str(tmp_path))
 
 
 def test_a_share_of_a_peak_over_100_is_refused_whatever_its_name(
@@ -179,15 +171,6 @@ def test_a_share_of_a_peak_over_100_is_refused_whatever_its_name(
     monkeypatch.setattr(harness, "load_module", lambda path: reader(False,
                                                                     101.0))
     assert harness.read_layer_metrics(run)["busy_part"]["value"] == 101.0
-
-
-def test_files_under_paths_are_named_from_name_characters(manifest):
-    for top in manifest["paths"]:
-        for dirpath, dirnames, filenames in os.walk(os.path.join(ROOT, top)):
-            dirnames[:] = [d for d in dirnames if d != "__pycache__"]
-            for f in filenames:
-                rel = os.path.relpath(os.path.join(dirpath, f), ROOT)
-                assert PATH.match(rel), rel
 
 
 def test_peaks_table_knows_the_v5e_and_refuses_the_unknown():

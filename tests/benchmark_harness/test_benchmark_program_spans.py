@@ -2,9 +2,9 @@
 the refusal where the ring may have lost part of it), the ``paddle/``
 annotations of a trace with planted gaps, and each reader on the rehearsal
 entry, traced."""
-import io
 import json
 import os
+import shutil
 import sys
 import time
 import types
@@ -15,14 +15,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from benchmark import harness, program_spans as PS  # noqa: E402
+from benchmark import checks, harness, program_spans as PS  # noqa: E402
 from benchmark import trace_reduce as T  # noqa: E402
 
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
     MANIFEST = json.load(_f)
-NEW = ["serve_queue_wait_ms", "serve_sched_self_ms", "serve_tick_ms",
-       "serve_logits_fetch_ms", "serve_prefill_share", "serve_host_gap_ms",
-       "serve_idle_unattributed", "train_dispatch_ms"]
+NEW = sorted(checks.PROGRAM_SPAN_READERS)
 
 
 def _reader(name):
@@ -30,16 +28,38 @@ def _reader(name):
         ROOT, "benchmark", "layer_metrics", name + ".py"))
 
 
-def test_the_eight_readers_are_the_manifests_last_eight():
-    assert [m["name"] for m in MANIFEST["per_layer"][-8:]] == NEW
-    cells = {w["name"]: w for w in MANIFEST["workloads"]}
-    for m in MANIFEST["per_layer"][-8:]:
-        (cell,) = m["workloads"]
-        assert cells[cell]["traffic"].startswith(
-            "train" if m["name"].startswith("train") else "closed")
-        assert m["source"] == ("device_trace" if m["name"] in (
-            "serve_host_gap_ms", "serve_idle_unattributed")
-            else "program_span")
+def test_the_eight_readers_are_in_the_manifest_by_name():
+    assert len(NEW) == 8
+    checks.program_span_readers_are_in_the_manifest_by_name(ROOT)
+    # the two cells of today, each under the metrics of its own path
+    listed = {m["name"]: m["workloads"] for m in MANIFEST["per_layer"]}
+    assert "serve_cgpt1p3b_closed14" in listed["serve_tick_ms"]
+    assert "train_cgpt1p3b_l6_1chip" in listed["train_dispatch_ms"]
+
+
+def test_a_cell_listed_under_another_paths_metric_is_refused(tmp_path):
+    manifest = json.loads(json.dumps(MANIFEST))
+    for sub in ("traffic", "layer_metrics"):
+        shutil.copytree(os.path.join(ROOT, "benchmark", sub),
+                        tmp_path / "benchmark" / sub,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(manifest))
+    checks.program_span_readers_are_in_the_manifest_by_name(str(tmp_path))
+    for m in manifest["per_layer"]:
+        if m["name"] == "serve_tick_ms":
+            m["workloads"].append("train_cgpt1p3b_l6_1chip")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(manifest))
+    with pytest.raises(AssertionError):
+        checks.program_span_readers_are_in_the_manifest_by_name(
+            str(tmp_path))
+    # and one of the eight gone from the list altogether
+    manifest = json.loads(json.dumps(MANIFEST))
+    manifest["per_layer"] = [m for m in manifest["per_layer"]
+                             if m["name"] != "serve_host_gap_ms"]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(manifest))
+    with pytest.raises(AssertionError):
+        checks.program_span_readers_are_in_the_manifest_by_name(
+            str(tmp_path))
 
 
 # ---------------------------------------------------------------------------
@@ -107,8 +127,8 @@ def _planted(fetch2=1860):
     tick 100..900 with feed 100..150, run 150..820, fetch 820..880, commit
     880..900, then emit 905..950; the device is busy 150..800. Tick 2 the
     same 1000 later, but its device program ends at 1700, its run at 1710,
-    and the host dawdles under serve/decode_tick alone (the benchmark's
-    wrappers, say) until the fetch begins at ``fetch2``."""
+    and the host dawdles under serve/decode_tick alone until the fetch
+    begins at ``fetch2``."""
     us = 1000
     ops = [("fusion.1 fusion", 150 * us, 650 * us),
            ("fusion.1 fusion", 1150 * us, 550 * us)]
@@ -135,74 +155,125 @@ def test_host_gap_is_a_ticks_length_less_the_device_time_inside_it():
     assert PS.host_gaps_ms(p, "no/such") == []
 
 
-def test_idle_goes_to_the_innermost_program_span_at_the_gaps_middle():
+def test_idle_is_divided_over_the_innermost_spans_that_cover_it():
     p = _planted()
-    # gaps: 0..150 (middle 75: serve/step alone), 800..1150 (middle 975:
-    # serve/step, past serve/emit's end at 950), 1700..2000 (middle 1850:
-    # serve/decode_tick alone, between decode/run's end and the fetch)
-    assert dict(PS.idle_by_span(p)) == pytest.approx(
-        {"serve/step": 500e-6, "serve/decode_tick": 300e-6})
-    assert PS.idle_line(p) == ("idle by program span: serve/step "
-                               "0.000500, serve/decode_tick 0.000300")
-    assert PS.unattributed_idle_share(p) == pytest.approx(100.0)
+    # gap 0..150: serve/step 0..100, decode/feed 100..150. Gap 800..1150:
+    # the end of decode/run 20, the fetch 60, the commit 20, serve/step 5,
+    # serve/emit 45, serve/step 50 + 100 into the next step, its feed 50.
+    # Gap 1700..2000: decode/run 10, serve/decode_tick alone 150 (between
+    # the run's end and the fetch), fetch 20, commit 20, serve/step 5,
+    # emit 45, serve/step 50.
+    want = {"serve/step": 310e-6, "serve/decode_tick": 150e-6,
+            "decode/feed": 100e-6, "serve/emit": 90e-6,
+            "decode/fetch_logits": 80e-6, "decode/commit": 40e-6,
+            "decode/run": 30e-6}
+    assert dict(T.idle_by_span(p)) == pytest.approx(want)
+    assert [n for n, _ in T.idle_by_span(p)] == list(want)   # most first
+    assert PS.idle_line(p).startswith(
+        "idle by program span: serve/step 0.000310, serve/decode_tick "
+        "0.000150, decode/feed 0.000100")
+    assert PS.unattributed_idle_share(p) == pytest.approx(
+        100.0 * (310 + 150) / 800)
+    # the ledger's breakdown is the same division
+    assert T.breakdown(p)["idle_gaps"] == [
+        [n, pytest.approx(v)] for n, v in want.items()]
     # with the host's work after the tick under a leaf span (the fetch
     # begins where the run ends), that idle has a name
     q = _planted(fetch2=1710)
-    assert dict(PS.idle_by_span(q)) == pytest.approx(
-        {"serve/step": 500e-6, "decode/fetch_logits": 300e-6})
-    assert PS.unattributed_idle_share(q) == pytest.approx(100.0 * 500 / 800)
+    got = dict(T.idle_by_span(q))
+    assert "serve/decode_tick" not in got
+    assert got["decode/fetch_logits"] == pytest.approx(230e-6)
+    assert PS.unattributed_idle_share(q) == pytest.approx(100.0 * 310 / 800)
     assert PS.is_leaf("prefill/run") and PS.is_leaf("serve/loop_idle")
     assert not PS.is_leaf("serve/decode_tick")
     assert not PS.is_leaf(T.OUTSIDE)
 
 
-def test_the_two_trace_readers_on_a_run_with_a_planted_trace(
-        tmp_path, monkeypatch, capsys):
+def test_the_two_trace_readers_on_a_run_with_a_planted_trace(capsys):
     planted = _planted()
-    trace_dir = tmp_path / "trace"
-    (trace_dir / "plugins" / "profile" / "t0").mkdir(parents=True)
-    (trace_dir / "plugins" / "profile" / "t0" / "h.xplane.pb").write_bytes(
-        b"")
-    monkeypatch.setattr(PS, "annotations", lambda path: planted.spans)
-    run = types.SimpleNamespace(
-        window=None, trace_dir=str(trace_dir),
-        profile=T.Profile(planted.devices, planted.modules, []))
+    run = types.SimpleNamespace(window=None, profile=planted)
     assert _reader("serve_host_gap_ms").read(run) == pytest.approx(
         (0.150 + 0.250) / 2)
     assert _reader("serve_idle_unattributed").read(run) == pytest.approx(
-        100.0)
+        57.5)
     # the line the ledger's notes can carry, once a run
     out = capsys.readouterr().out
     assert out.count("[bench] idle by program span: serve/step") == 1
-    # no device plane (a rehearsal on the CPU), or no annotation of the
-    # program's: nothing is reported
-    for profile, spans in ((T.Profile({}, {}, []), planted.spans),
-                           (run.profile, [])):
-        monkeypatch.setattr(PS, "annotations", lambda path, s=spans: s)
-        bare = types.SimpleNamespace(window=None, profile=profile,
-                                     trace_dir=str(trace_dir))
+    # no device plane (a rehearsal on the CPU), no span, or spans of
+    # another path alone: nothing is reported
+    for profile in (T.Profile({}, {}, planted.spans),
+                    T.Profile(planted.devices, planted.modules, [])):
+        bare = types.SimpleNamespace(window=None, profile=profile)
         assert _reader("serve_host_gap_ms").read(bare) is None
         assert _reader("serve_idle_unattributed").read(bare) is None
+    train = types.SimpleNamespace(window=None, profile=T.Profile(
+        planted.devices, planted.modules, [("train_step", 0, 2000000)]))
+    assert _reader("serve_host_gap_ms").read(train) is None
 
 
-def test_annotations_keeps_the_programs_spans_of_a_real_capture(tmp_path):
+def test_decode_step_roofline_reads_the_programs_tick_records():
+    """``cached_tokens`` comes from the program's ``serve/decode_tick``
+    records inside the traced window, no wrapper's span."""
+    family = types.SimpleNamespace(
+        bytes_per_decode_step=lambda config, lengths, weight_bytes:
+        1000 * weight_bytes + 10 * sum(lengths))
+    cell = types.SimpleNamespace(
+        family=family,
+        config={"serving": {"engine": {"weight_dtype": "bf16"}}})
+
+    def tick(start_ns, cached):
+        return {"name": "serve/decode_tick", "start_ns": start_ns,
+                "dur_ns": 100, "attrs": {"cached_tokens": cached}}
+
+    from paddle_tpu.observability import spans
+
+    at = spans.monotonic_to_ns
+    ring = {"serve/decode_tick": [tick(at(1.0), 100), tick(at(2.0), 300),
+                                  tick(at(3.0) - 50, 500)]}
+    run = types.SimpleNamespace(
+        cell=cell, window=(0.0, 4.0), trace_window=(1.5, 3.0),
+        peaks={"hbm_bytes_per_s": 1e9},
+        profile=T.Profile({}, {"/device:TPU:0": [
+            ("jit__decode_fn_paged(1)", 0, 10000),
+            ("jit__prefill_fn(2)", 0, 99999)]}, []))
+    run._program_spans = (ring, None)
+    # one tick inside the traced window: 2000 + 3000 bytes at 1 GB/s is
+    # 5 us; the program took 10 us
+    assert _reader("decode_step_roofline").read(run) == pytest.approx(50.0)
+    run.trace_window = (0.5, 2.5)                 # two ticks: mean 4000
+    assert _reader("decode_step_roofline").read(run) == pytest.approx(40.0)
+    run.trace_window = (5.0, 6.0)                 # none
+    assert _reader("decode_step_roofline").read(run) is None
+    run._program_spans = (None, None)             # a ring that lost records
+    run.trace_window = (0.5, 2.5)
+    assert _reader("decode_step_roofline").read(run) is None
+
+
+def test_load_xplane_keeps_the_programs_spans_of_a_real_capture(tmp_path):
     import jax
 
     from paddle_tpu.observability import spans
 
     jax.profiler.start_trace(str(tmp_path))
     try:
-        with jax.profiler.TraceAnnotation("bench/not_ours"):
-            with spans.span("serve/step"):
-                with spans.span("decode/run"):
-                    time.sleep(0.002)
+        with jax.profiler.TraceAnnotation("bench/loss_fetch"):
+            with jax.profiler.TraceAnnotation("somebody/elses"):
+                with spans.span("serve/step"):
+                    with spans.span("decode/run"):
+                        time.sleep(0.002)
     finally:
         jax.profiler.stop_trace()
     found = sorted((tmp_path / "plugins" / "profile").glob("*/*.xplane.pb"))
-    got = PS.annotations(str(found[-1]))
-    assert [n for n, _, _ in got] == ["serve/step", "decode/run"]
-    (_, s0, d0), (_, s1, d1) = got
+    got = T.load_xplane(str(found[-1])).spans
+    assert [n for n, _, _ in got] == ["loss_fetch", "serve/step",
+                                      "decode/run"]
+    _, (_, s0, d0), (_, s1, d1) = got
     assert s0 <= s1 and s1 + d1 <= s0 + d0 and d1 >= 2e6
+    # and the innermost of them owns the stretch it covers
+    line = T.innermost_timeline(got)
+    assert [n for _, _, n in line] == ["loss_fetch", "serve/step",
+                                       "decode/run", "serve/step",
+                                       "loss_fetch"]
 
 
 # ---------------------------------------------------------------------------
@@ -211,30 +282,12 @@ def test_annotations_keeps_the_programs_spans_of_a_real_capture(tmp_path):
 
 @pytest.mark.parametrize("cell", [w["name"] for w in MANIFEST["workloads"]])
 def test_rehearsal_traced_reports_the_program_span_readers(cell):
-    out = io.StringIO()
-    result = harness.run_cell(ROOT, cell, 2 ** 31 + 11, 1.0, 1,
-                              rehearsal=True, out=out)
-    got = result["metrics"]
-    want = [m["name"] for m in MANIFEST["per_layer"][-8:]
-            if cell in m["workloads"] and m["source"] == "program_span"]
-    assert want and all(got[name]["value"] > 0 or name ==
-                        "serve_prefill_share" for name in want), got
-    # the CPU has no device plane: the two readers of the trace report
-    # nothing there
-    assert "serve_host_gap_ms" not in got
-    assert "serve_idle_unattributed" not in got
-    if "serve_tick_ms" in got:
-        # the program's tick and its scheduler's own time make up the
-        # benchmark's span round the same step
-        inner = (got["serve_tick_ms"]["value"]
-                 + got["serve_sched_self_ms"]["value"])
-        assert inner == pytest.approx(got["decode_tick_ms"]["value"],
-                                      rel=0.5)
-        assert got["serve_queue_wait_ms"]["value"] == pytest.approx(
-            got["sched_queue_ms"]["value"], rel=0.5)
-        assert 0 < got["serve_prefill_share"]["value"] < 100
-        assert got["serve_logits_fetch_ms"]["value"] < \
-            got["serve_tick_ms"]["value"]
-    else:
-        assert got["train_dispatch_ms"]["value"] <= \
-            got["train_step_ms"]["value"] * 1.5
+    got = checks.traced_rehearsal_reports_the_program_span_readers(ROOT,
+                                                                   cell)
+    # today's two cells, by name, report what they did when the eight
+    # arrived; a later cell is held to the lists it put itself on
+    pinned = {"serve_cgpt1p3b_closed14": {
+        "serve_queue_wait_ms", "serve_sched_self_ms", "serve_tick_ms",
+        "serve_logits_fetch_ms", "serve_prefill_share"},
+        "train_cgpt1p3b_l6_1chip": {"train_dispatch_ms", "train_step_ms"}}
+    assert pinned.get(cell, set()) <= set(got)

@@ -30,6 +30,7 @@ def _check_line(result, cell, trace):
     assert set(result) - {"breakdown"} == set(harness.RESULT_KEYS)
     assert result["correct"] is True
     assert result["attempted"] > 0 and result["failed"] == 0
+    assert list(result)[-1] == "checks"
     assert result["device"]["platform"] == "cpu"       # stamped, no record
     assert set(result["device"]) >= {"platform", "kind", "count",
                                      "memory_peak_bytes"}
@@ -58,10 +59,18 @@ def test_rehearsal_in_process(cell, trace):
                               rehearsal=True, out=out)
     assert _last_line(out.getvalue()) == result
     _check_line(result, cell, trace)
-    # every number compared is printed beside its limit
+    # every number compared is printed beside its limit, and is in the
+    # result line under the key that comes last there
     checks = [x for x in out.getvalue().splitlines() if "check " in x]
     assert checks and all("(limit" in x for x in checks)
-    assert not os.path.exists(os.path.join(ROOT, ".bench_trace", cell))
+    assert list(result)[-1] == "checks"
+    assert len(result["checks"]) == len(checks)
+    assert all(set(c) == {"value", "limit"} and c["value"] <= c["limit"]
+               for c in result["checks"].values())
+    # the trace directory is this process's own (another worker may be
+    # tracing the same cell in the same checkout), and it is gone
+    assert not os.path.exists(os.path.join(
+        ROOT, ".bench_trace", f"{cell}.{os.getpid()}"))
 
 
 def test_rehearsal_command_last_line_has_exactly_the_contracts_keys():

@@ -64,17 +64,60 @@ def test_self_times_take_a_loops_body_out_of_the_loop():
         (pytest.approx(35e-9), 2)
 
 
-def test_idle_gaps_go_to_the_span_that_covers_most_of_them():
+def test_idle_gaps_are_divided_over_the_spans_that_cover_them():
     gaps = T.idle_gaps(_profile())
     # 0..10 under step; 50..70: 5 under step, 15 under fetch; 80..100 fetch
-    assert gaps == [("step", 0, 10), ("fetch", 50, 20), ("fetch", 80, 20)]
+    assert gaps == [("step", 0, 10), ("step", 50, 5), ("fetch", 55, 15),
+                    ("fetch", 80, 20)]
     b = T.breakdown(_profile())
-    assert b["idle_gaps"] == [["fetch", pytest.approx(40e-9)],
-                              ["step", pytest.approx(10e-9)]]
+    assert b["idle_gaps"] == [["fetch", pytest.approx(35e-9)],
+                              ["step", pytest.approx(15e-9)]]
     assert b["device_ops"][0] == ["fusion.2 fusion", pytest.approx(20e-9)]
     no_span = T.Profile({"/device:TPU:0": [("a", 0, 1), ("b", 5, 1)]},
                         {}, [])
     assert T.idle_gaps(no_span) == [(T.OUTSIDE, 1, 4)]
+    # a span that covers the middle of a gap leaves both ends outside
+    part = T.Profile({"/device:TPU:0": [("a", 0, 1), ("b", 9, 1)]}, {},
+                     [("s", 3, 4)])
+    assert T.idle_gaps(part) == [(T.OUTSIDE, 1, 2), ("s", 3, 4),
+                                 (T.OUTSIDE, 7, 2)]
+
+
+@pytest.mark.parametrize("spans,want", [
+    # nested: the inner one owns its stretch, the outer one the rest
+    ([("outer", 0, 100), ("inner", 20, 30)],
+     [(0, 20, "outer"), (20, 50, "inner"), (50, 100, "outer")]),
+    # three deep, the innermost ending with its parent
+    ([("a", 0, 100), ("b", 10, 80), ("c", 40, 50)],
+     [(0, 10, "a"), (10, 40, "b"), (40, 90, "c"), (90, 100, "a")]),
+    # two threads' spans that overlap without nesting: the shorter wins
+    # where both cover
+    ([("long", 0, 60), ("short", 50, 30)],
+     [(0, 50, "long"), (50, 80, "short")]),
+    # neighbours of one name that touch are one stretch; a hole stays one
+    ([("t", 0, 10), ("t", 10, 10), ("u", 30, 5)],
+     [(0, 20, "t"), (30, 35, "u")]),
+    ([("empty", 5, 0)], []),
+], ids=["nested", "three_deep", "overlap", "neighbours", "empty"])
+def test_innermost_timeline(spans, want):
+    assert T.innermost_timeline(spans) == want
+
+
+def test_seconds_matching_by_the_head_of_the_name():
+    ops = [("flash_fwd.17 custom-call/tpu_custom_call bf16[128,2048,128]",
+            0, 10),
+           ("scan_kernel.3 custom-call/tpu_custom_call bf16[128,2048,128]",
+            10, 7),
+           ("flash_bwd_dq.2 custom-call/tpu_custom_call bf16[128,2048,128]",
+            20, 5),
+           ("not_flash_.1 fusion bf16[8]", 30, 1)]
+    p = T.Profile({"/device:TPU:0": ops}, {}, [])
+    call = "custom-call/tpu_custom_call"
+    assert T.seconds_matching(p, call) == (pytest.approx(22e-9), 3)
+    assert T.seconds_matching(p, call, head="flash_") == (
+        pytest.approx(15e-9), 2)
+    assert T.seconds_matching(p, head="flash_bwd") == (
+        pytest.approx(5e-9), 1)
 
 
 def test_busy_over_the_window_is_refused():
@@ -105,5 +148,7 @@ def test_recorded_v5e_trace_reproduces_the_values_beside_it():
     assert [m[2] / 1e9 for m in p.modules["/device:TPU:0"]] == \
         pytest.approx(want["module_seconds"])
     assert len(p.devices["/device:TPU:0"]) == want["events"]
-    gaps = T.breakdown(p)["idle_gaps"]
-    assert [g[0] for g in gaps] == [g[0] for g in want["idle_gaps_by_span"]]
+    # no gap of this recording straddles two spans, so the division over
+    # the spans that cover a gap gives what the span at its middle got
+    assert T.breakdown(p)["idle_gaps"] == [
+        [name, pytest.approx(s)] for name, s in want["idle_gaps_by_span"]]
