@@ -75,9 +75,20 @@ def decode_attention(q, k_cache, v_cache, lengths,
     cache dtype (softmax stability at bf16 caches), positions >= length are
     masked to -inf, and empty slots (length 0 — inactive batch lanes in the
     continuous-batching decode step) produce zeros instead of NaNs.
+
+    Grouped-query heads: a cache of ``kvh < nh`` heads (``kvh`` divides
+    ``nh``) is read once and shared by each group of ``nh / kvh`` queries.
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    if k_cache.shape[2] != q.shape[1]:
+        B, nh, hd = q.shape
+        kvh = k_cache.shape[2]
+        out = _grouped_attention(
+            q.reshape(B, 1, kvh, nh // kvh, hd), k_cache, v_cache,
+            (jnp.arange(k_cache.shape[1])[None, None, :]
+             < lengths[:, None, None])[:, None, None], sm_scale)
+        return out.reshape(B, nh, hd)
     S = k_cache.shape[1]
     scores = jnp.einsum("bnh,bsnh->bns", q.astype(jnp.float32),
                         k_cache.astype(jnp.float32)) * sm_scale
@@ -91,6 +102,22 @@ def decode_attention(q, k_cache, v_cache, lengths,
     probs = e / jnp.maximum(denom, 1e-30)
     out = jnp.einsum("bns,bsnh->bnh", probs,
                      v_cache.astype(jnp.float32))
+    return out.astype(q.dtype)
+
+
+def _grouped_attention(q, k, v, mask, sm_scale):
+    """q [B, T, kvh, g, hd] against k, v [B, S, kvh, hd] under ``mask``
+    (broadcastable to [B, kvh, g, T, S]) -> [B, T, kvh, g, hd]: each
+    key/value head serves its group of ``g`` query heads, float32
+    inside, all-masked rows give zeros."""
+    scores = jnp.einsum("btkgh,bskh->bkgts", q.astype(jnp.float32),
+                        k.astype(jnp.float32)) * sm_scale
+    scores = jnp.where(mask, scores, -jnp.inf)
+    m = jnp.max(scores, axis=-1, keepdims=True)
+    m = jnp.where(jnp.isfinite(m), m, 0.0)
+    e = jnp.where(mask, jnp.exp(scores - m), 0.0)
+    probs = e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
+    out = jnp.einsum("bkgts,bskh->btkgh", probs, v.astype(jnp.float32))
     return out.astype(q.dtype)
 
 
@@ -255,13 +282,21 @@ def window_attention(q, k_cache, v_cache, starts,
 
 def prefill_attention(q, k, v, sm_scale: Optional[float] = None):
     """Causal self-attention for the prefill pass: [B, T, nh, hd] all
-    around. Numerically the same contraction order as decode_attention so
+    around (k and v may hold ``kvh < nh`` heads: grouped-query). Numerically
+    the same contraction order as decode_attention so
     prefill logits and a later decode replay of the same positions agree
     to float rounding (the parity bar tests/test_serving_engine.py holds
     the engine to)."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     T = q.shape[1]
+    if k.shape[2] != q.shape[2]:
+        B, _, nh, hd = q.shape
+        kvh = k.shape[2]
+        out = _grouped_attention(
+            q.reshape(B, T, kvh, nh // kvh, hd), k, v,
+            jnp.tril(jnp.ones((T, T), jnp.bool_)), sm_scale)
+        return out.reshape(B, T, nh, hd)
     scores = jnp.einsum("bqnh,bknh->bnqk", q.astype(jnp.float32),
                         k.astype(jnp.float32)) * sm_scale
     mask = jnp.tril(jnp.ones((T, T), jnp.bool_))[None, None]

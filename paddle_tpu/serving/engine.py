@@ -38,8 +38,11 @@ module assembles them into the serving shape:
   take f32/bf16.)
 
 The engine is single-threaded by contract: exactly one scheduler loop
-calls it (serving/scheduler.py). It is GPT-first (models/gpt.py param
-tree); other decoder families plug in by matching the param-tree layout.
+calls it (serving/scheduler.py). The paged prefill and decode programs
+take their layers from a *model description* (``serving/model.py``: the
+GPT block, or ``models/jamba.py``'s hybrid of Mamba and attention layers
+with per-slot recurrent state); the slab and verify programs are still
+written for the GPT block (ROADMAP D2).
 """
 from __future__ import annotations
 
@@ -53,22 +56,21 @@ import jax
 import jax.numpy as jnp
 
 from ..models import gpt as gpt_mod
-from ..models.gpt import GPTConfig
 from ..observability import program_report as _prep
 from ..observability import spans as _spans
 from ..ops import pallas_kernels as _pk
 from ..ops.decode_attention import (cache_update, decode_attention,
                                     paged_cache_update, paged_gather,
-                                    paged_page_write,
-                                    paged_prefill_attention,
                                     prefill_attention, window_attention,
                                     window_cache_update)
 from . import metrics as smetrics
+from . import model as _model
 from . import sampling as samp
 from .kv_cache import KVCache
+from .model import (block_tail as _block_tail, embed_rows as _embed_rows,
+                    layers_over_pools as _layers_over_pools)
 from .paged_kv import PagedKVCache, PagePoolFullError, PrefixCache
-from .quant import (QuantizedLeaf, dequantize_params, quantize_params,
-                    quantized_nbytes)
+from .quant import dequantize_params, quantized_nbytes
 from .sampling import GREEDY, SamplingParams
 
 __all__ = ["EngineConfig", "DecodeEngine", "PromptTooLongError",
@@ -147,45 +149,20 @@ class EngineConfig:
         return buckets
 
 
-def _embed_rows(qparams, tokens, positions, dt):
-    """``wte[tokens] + wpe[positions]`` as ``dt``, summed in float32. The
-    rows are gathered from the tables as they are stored and widened
-    after: widening first has XLA write the whole float32 table (412 MB
-    at 50257 x 2048) on every call before it gathers a few rows of it."""
-    def rows(table, idx):
-        if isinstance(table, QuantizedLeaf):     # int8: chunked, flat
-            return dequantize_params(table)[idx]
-        return table[idx].astype(jnp.float32)
-
-    return (rows(qparams["wte"], tokens)
-            + rows(qparams["wpe"], positions)).astype(dt)
-
-
-def _layers_over_pools(body, x, kp, vp, blocks):
-    """Run ``body(h, layer_p, l, kp, vp) -> (h, kp, vp)`` over the stacked
-    ``blocks`` with both KV pools as the loop's CARRY, in their stored
-    ``[L, P, page, nh, hd]`` layout, and the layer index ``l`` a loop
-    variable. A scan's ``xs``/``ys`` would slice a layer out of each pool
-    and re-stack it into a new buffer every iteration; a carry is updated
-    in place, so the donated pools alias the outputs and a program
-    touches only the rows and pages it indexes at ``[l, page, row]``."""
-    def step(carry, xs):
-        layer_p, l = xs
-        return body(carry[0], layer_p, l, carry[1], carry[2]), None
-
-    layers = jnp.arange(kp.shape[0], dtype=jnp.int32)
-    (x, kp, vp), _ = jax.lax.scan(step, (x, kp, vp), (blocks, layers))
-    return x, kp, vp
-
-
 class DecodeEngine:
-    def __init__(self, params, cfg: GPTConfig, ecfg: EngineConfig):
-        if ecfg.max_seq > cfg.max_seq_len:
+    def __init__(self, params, cfg, ecfg: EngineConfig):
+        """``cfg``: a model description (``serving/model.py``) or the
+        config of a family that has one (``GPTConfig``, ``JambaConfig``)."""
+        self.model = _model.describe(cfg)
+        cfg = self.cfg = self.model.cfg
+        if (self.model.max_positions is not None
+                and ecfg.max_seq > self.model.max_positions):
             raise ValueError(
                 f"EngineConfig.max_seq {ecfg.max_seq} exceeds the model's "
-                f"positional table {cfg.max_seq_len}")
-        self.cfg = cfg
+                f"positional table {self.model.max_positions}")
         self.ecfg = ecfg
+        if self.model.recurrent:
+            self._refuse_what_cannot_carry_state(ecfg)
         self.buckets = ecfg.resolved_buckets()
         self.paged = ecfg.kv_layout == "paged"
         if ecfg.kv_layout not in ("slab", "paged"):
@@ -211,26 +188,30 @@ class DecodeEngine:
         if ecfg.sharding not in (None, "tp"):
             raise ValueError(f"sharding {ecfg.sharding!r}: expected None "
                              "or 'tp'")
-        qparams = quantize_params(params, ecfg.weight_dtype,
+        qparams = self.model.hold(params, ecfg.weight_dtype,
                                   ecfg.quant_chunk)
         if ecfg.sharding == "tp":
             self._init_tp(qparams)
         self.qparams = jax.device_put(qparams, self._param_sh)
         self.weight_nbytes = quantized_nbytes(self.qparams)
         cache_dtype = ecfg.cache_dtype or cfg.dtype
+        kv_layers, kv_heads, kv_head_dim = self.model.kv_geometry
         if self.paged:
+            # one manager for both kinds of cache: pages for the attention
+            # layers, a state row a slot for the recurrent ones
             self.cache = PagedKVCache(
-                cfg.num_layers, ecfg.max_batch, ecfg.max_seq,
-                cfg.num_heads, cfg.head_dim, dtype=cache_dtype,
-                page_size=ecfg.page_size, num_pages=ecfg.num_pages)
+                kv_layers, ecfg.max_batch, ecfg.max_seq,
+                kv_heads, kv_head_dim, dtype=cache_dtype,
+                page_size=ecfg.page_size, num_pages=ecfg.num_pages,
+                state=self.model.state_geometry)
             self.prefix = (PrefixCache(self.cache,
                                        ecfg.prefix_cache_pages)
                            if ecfg.prefix_cache else None)
             if self.prefix is not None:
                 self.cache.reclaimer = self.prefix.reclaim
         else:
-            self.cache = KVCache(cfg.num_layers, ecfg.max_batch,
-                                 ecfg.max_seq, cfg.num_heads, cfg.head_dim,
+            self.cache = KVCache(kv_layers, ecfg.max_batch,
+                                 ecfg.max_seq, kv_heads, kv_head_dim,
                                  dtype=cache_dtype)
             self.prefix = None
         if self._cache_sh is not None:
@@ -247,8 +228,9 @@ class DecodeEngine:
             self.kv_path = "slab"
         elif self._mesh is not None:
             self.kv_path = "xla_gather"
-        elif (_pk.paged_decode_tiles(cfg.num_heads, cfg.head_dim)
-              if _pk._on_tpu() else ecfg.fused_decode):
+        elif self.model.paged_kernel and (
+                _pk.paged_decode_tiles(kv_heads, kv_head_dim)
+                if _pk._on_tpu() else ecfg.fused_decode):
             self.kv_path = "pallas_paged"
         else:
             self.kv_path = "xla_gather"
@@ -275,6 +257,47 @@ class DecodeEngine:
         self.prefix_store = None
         self._tokens_window: List[Tuple[float, int]] = []  # (t, n) samples
 
+    def _refuse_what_cannot_carry_state(self, ecfg: EngineConfig) -> None:
+        """The one place the rule is stated: a model with recurrent layers
+        keeps, beside its pages, a state a slot that is advanced token by
+        token and cannot be cut at a page boundary, rolled back, exported
+        or split over chips by anything built so far. Each mechanism that
+        would have to carry it is refused by name."""
+        def refuse(mechanism, why):
+            raise ValueError(
+                f"{type(self.model).__name__} has recurrent layers: "
+                f"{mechanism} cannot carry recurrent state ({why})")
+
+        if ecfg.kv_layout != "paged":
+            refuse("the slab KV layout (kv_layout='slab')",
+                   "per-slot state lives in the paged manager; use "
+                   "kv_layout='paged'")
+        if ecfg.prefix_cache:
+            refuse("the prefix cache (prefix_cache=True)",
+                   "a cached page holds keys and values, not the state at "
+                   "its boundary; pass prefix_cache=False")
+        if ecfg.verify_window:
+            refuse("the verify window (verify_window > 0, speculative "
+                   "decoding)", "a rejected draft token cannot be taken "
+                   "back out of the state")
+        if ecfg.sharding is not None:
+            refuse("the tensor-parallel engine (sharding='tp')",
+                   "no plan shards the scan's channels")
+        if ecfg.weight_dtype not in ("bf16", "f32"):
+            refuse(f"weight_dtype {ecfg.weight_dtype!r} (int8)",
+                   "the quantiser's flat chunks are dequantised by the "
+                   "GPT block's programs alone; use 'bf16' or 'f32'")
+        if ecfg.role != "colocated":
+            refuse(f"role {ecfg.role!r} (phase disaggregation, "
+                   "kv_transfer)", "a hand-off ships pages only")
+
+    def _refuse_kv_transfer(self) -> None:
+        if self.model.recurrent:
+            raise ValueError(
+                f"{type(self.model).__name__} has recurrent layers: "
+                "kv_transfer (export_request_kv / adopt_request_kv) ships "
+                "pages of keys and values and cannot carry recurrent state")
+
     def attach_prefix_store(self, store) -> int:
         """Arm warm restart (docs/serving.md "Resilience"): restore the
         store's committed prefix records into the pool + prefix cache
@@ -300,6 +323,7 @@ class DecodeEngine:
         live until the caller frees it."""
         from .kv_transfer import export_slot
 
+        self._refuse_kv_transfer()
         return export_slot(self, slot, tokens=tokens)
 
     def adopt_request_kv(self, handoff: dict) -> int:
@@ -309,6 +333,7 @@ class DecodeEngine:
         the cache arrays between executable calls."""
         from .kv_transfer import adopt_into_engine
 
+        self._refuse_kv_transfer()
         return adopt_into_engine(self, handoff)
 
     def _init_tp(self, qparams) -> None:
@@ -352,27 +377,6 @@ class DecodeEngine:
     def _dequant(self, qparams):
         return dequantize_params(qparams)
 
-    def _decode_ln(self):
-        """The decode tick's layernorm: the fused Pallas block kernel
-        under ``EngineConfig.fused_decode``, else the XLA reference."""
-        if self.ecfg.fused_decode:
-            return lambda x, scale, bias: _pk.fused_ln(x, scale, bias,
-                                                       eps=1e-5)
-        return gpt_mod._layer_norm
-
-    def _block_tail(self, h, a, layer_p, dt, ln, bt: str):
-        """Shared post-attention half of a transformer block: projection,
-        residual, MLP. ``bt`` is the einsum batch prefix ("b" for decode
-        rows, "bt"/"bw" for prefill/verify)."""
-        o = jnp.einsum(f"{bt}nh,nhd->{bt}d", a,
-                       layer_p["w_proj"].astype(dt))
-        h = h + o + layer_p["b_proj"].astype(dt)
-        h2 = ln(h, layer_p["ln2_scale"], layer_p["ln2_bias"])
-        f = jnp.einsum(f"{bt}d,df->{bt}f", h2, layer_p["w_fc"].astype(dt))
-        f = jax.nn.gelu(f + layer_p["b_fc"].astype(dt), approximate=True)
-        o2 = jnp.einsum(f"{bt}f,fd->{bt}d", f, layer_p["w_out"].astype(dt))
-        return h + o2 + layer_p["b_out"].astype(dt)
-
     def _prefill_fn(self, qparams, ck, cv, tokens, length, slot,
                     temp, top_k, top_p, seed):
         """tokens [1, T] int32, length/slot + sampling scalars ->
@@ -397,7 +401,7 @@ class DecodeEngine:
             qkv = qkv + layer_p["b_qkv"].astype(dt)
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
             a = prefill_attention(q, k, v)
-            h = self._block_tail(h, a, layer_p, dt, ln, "bt")
+            h = _block_tail(h, a, layer_p, dt, ln, "bt")
             return h, (k, v)
 
         x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
@@ -416,52 +420,30 @@ class DecodeEngine:
                                 length - 1)
         return ck, cv, logits, tok
 
-    def _prefill_fn_paged(self, qparams, kp, vp, tokens, length,
-                          prefix_len, table_row, temp, top_k, top_p,
+    def _prefill_fn_paged(self, qparams, caches, tokens, length,
+                          prefix_len, table_row, slot, temp, top_k, top_p,
                           seed):
         """Paged (prefix-cache capable) prefill: tokens [1, T] is the
-        SUFFIX after ``prefix_len`` cached tokens; suffix K/V scatter
-        into the slot's own pages, attention runs over the gathered full
-        view (cached prefix + suffix). prefix_len == 0 is a plain paged
+        SUFFIX after ``prefix_len`` cached tokens, ``caches`` the cache
+        manager's arrays (``PagedKVCache.arrays``: the two pools, and a
+        recurrent model's two state arrays). ``embed -> layers -> final
+        norm -> head``, the layers the model's own: they write the suffix
+        K/V into the slot's pages (and the states after ``length - 1``
+        into the slot's state rows). prefix_len == 0 is a plain paged
         prefill."""
-        cfg = self.cfg
-        params = self._dequant(qparams)
-        dt = cfg.dtype
-        ln = gpt_mod._layer_norm
-        ps = self.ecfg.page_size
+        m = self.model
         T = tokens.shape[1]
-        n_pages = T // ps
         positions = prefix_len + jnp.arange(T)
-        x = _embed_rows(qparams, tokens, positions[None], dt)  # [1, T, D]
-        suffix_pages = jax.lax.dynamic_slice(
-            table_row, (prefix_len // ps,), (n_pages,))
-
-        def body(h, layer_p, l, kp, vp):
-            h1 = ln(h, layer_p["ln1_scale"], layer_p["ln1_bias"])
-            qkv = jnp.einsum("btd,dcnh->btcnh", h1,
-                             layer_p["w_qkv"].astype(dt))
-            qkv = qkv + layer_p["b_qkv"].astype(dt)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            nh, hd = k.shape[2], k.shape[3]
-            kp = paged_page_write(
-                kp, k[0].reshape(n_pages, ps, nh, hd), suffix_pages, l)
-            vp = paged_page_write(
-                vp, v[0].reshape(n_pages, ps, nh, hd), suffix_pages, l)
-            k_all = paged_gather(kp, table_row[None], l)  # [1, S, nh, hd]
-            v_all = paged_gather(vp, table_row[None], l)
-            a = paged_prefill_attention(q, k_all, v_all, prefix_len)
-            return self._block_tail(h, a, layer_p, dt, ln, "bt"), kp, vp
-
-        x, kp, vp = _layers_over_pools(body, x, kp, vp, params["blocks"])
+        x = m.embed(qparams, tokens, positions[None])          # [1, T, D]
+        x, caches = m.prefill_layers(qparams, x, caches, _model.ctx(
+            length=length, prefix_len=prefix_len, table_row=table_row,
+            slot=slot, page_size=self.ecfg.page_size))
         h_last = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0,
                                               keepdims=False)
-        h_last = ln(h_last, params["ln_f_scale"], params["ln_f_bias"])
-        logits = jnp.einsum("d,dv->v", h_last,
-                            params["lm_head"].astype(dt))
-        logits = logits.astype(jnp.float32)
+        logits = m.logits(qparams, h_last)
         tok = samp.sample_token(logits, temp, top_k, top_p, seed,
                                 prefix_len + length - 1)
-        return kp, vp, logits, tok
+        return caches, logits, tok
 
     def _decode_fn(self, qparams, ck, cv, tokens, positions, actives,
                    temps, top_ks, top_ps, seeds):
@@ -478,7 +460,7 @@ class DecodeEngine:
         params = self._dequant(qparams)
         dt = cfg.dtype
         fused = self.ecfg.fused_decode
-        ln = self._decode_ln()
+        ln = _model.decode_ln(fused)
         x = _embed_rows(qparams, tokens, positions, dt)
 
         def body(h, xs):
@@ -496,7 +478,7 @@ class DecodeEngine:
                 ck_l = cache_update(ck_l, k, positions, active=actives)
                 cv_l = cache_update(cv_l, v, positions, active=actives)
                 a = decode_attention(q, ck_l, cv_l, positions + 1)
-            h = self._block_tail(h, a, layer_p, dt, ln, "b")
+            h = _block_tail(h, a, layer_p, dt, ln, "b")
             return h, (ck_l, cv_l)
 
         x, (ck, cv) = jax.lax.scan(body, x,
@@ -514,63 +496,24 @@ class DecodeEngine:
                                  positions)
         return ck, cv, logits, toks
 
-    def _decode_fn_paged(self, qparams, kp, vp, tokens, positions,
-                         tables, temps, top_ks, top_ps, seeds):
-        """Paged twin of :meth:`_decode_fn`: per-slot page tables
-        [B, max_pages] route the one-row write (a scatter on the carried
-        pool) and the attention read through the shared pool. Lanes whose
-        table row is all-zero write into the scratch page. The read has
-        two lowerings of one algorithm (:attr:`kv_path`): the Pallas
-        kernel that fetches only the live pages, or gather + masked
-        softmax over the padded view."""
-        cfg = self.cfg
-        params = self._dequant(qparams)
-        dt = cfg.dtype
-        fused = self.ecfg.fused_decode
-        ln = self._decode_ln()
-        ps = self.ecfg.page_size
-        x = _embed_rows(qparams, tokens, positions, dt)
-        if self.kv_path == "pallas_paged":
-            def write_and_attend(q, k, v, kp, vp, l):
-                return _pk.fused_paged_decode_attention(
-                    q, kp, vp, k, v, tables, positions, layer=l)
-        else:
-            phys = jnp.take_along_axis(
-                tables, (positions // ps)[:, None], axis=1)[:, 0]
-            rows = positions % ps
-
-            def write_and_attend(q, k, v, kp, vp, l):
-                kp = paged_cache_update(kp, k, phys, rows, l)
-                vp = paged_cache_update(vp, v, phys, rows, l)
-                a = decode_attention(q, paged_gather(kp, tables, l),
-                                     paged_gather(vp, tables, l),
-                                     positions + 1)
-                return a, kp, vp
-
-        def body(h, layer_p, l, kp, vp):
-            h1 = ln(h, layer_p["ln1_scale"], layer_p["ln1_bias"])
-            qkv = jnp.einsum("bd,dcnh->bcnh", h1,
-                             layer_p["w_qkv"].astype(dt))
-            qkv = qkv + layer_p["b_qkv"].astype(dt)
-            # dead lanes' all-zero tables land the write on the scratch
-            # page, which no live slot reads
-            a, kp, vp = write_and_attend(qkv[:, 0], qkv[:, 1], qkv[:, 2],
-                                         kp, vp, l)
-            return self._block_tail(h, a, layer_p, dt, ln, "b"), kp, vp
-
-        x, kp, vp = _layers_over_pools(body, x, kp, vp, params["blocks"])
-        if fused:
-            logits = _pk.fused_logits_head(
-                x, params["ln_f_scale"], params["ln_f_bias"],
-                params["lm_head"].astype(dt))
-        else:
-            x = ln(x, params["ln_f_scale"], params["ln_f_bias"])
-            logits = jnp.einsum("bd,dv->bv", x,
-                                params["lm_head"].astype(dt))
-        logits = logits.astype(jnp.float32)
+    def _decode_fn_paged(self, qparams, caches, tokens, positions,
+                         tables, actives, temps, top_ks, top_ps, seeds):
+        """Paged twin of :meth:`_decode_fn`, the layers the model's own
+        (``serving/model.py``). Per-slot page tables [B, max_pages] route
+        the one-row write and the attention read through the shared pool;
+        lanes that do not ride have an all-zero table row (their write
+        lands on the scratch page) and ``actives`` 0 (a recurrent model
+        leaves their state as it is)."""
+        m = self.model
+        x = m.embed(qparams, tokens, positions)
+        x, caches = m.decode_layers(qparams, x, caches, _model.ctx(
+            positions=positions, tables=tables, actives=actives,
+            page_size=self.ecfg.page_size, kv_path=self.kv_path,
+            fused=self.ecfg.fused_decode))
+        logits = m.logits(qparams, x, fused=self.ecfg.fused_decode)
         toks = samp.sample_batch(logits, temps, top_ks, top_ps, seeds,
                                  positions)
-        return kp, vp, logits, toks
+        return caches, logits, toks
 
     def _verify_fn(self, qparams, ck, cv, tokens, starts, actives,
                    temps, top_ks, top_ps, seeds):
@@ -598,7 +541,7 @@ class DecodeEngine:
             cv_l = window_cache_update(cv_l, v, starts,
                                        active=actives)
             a = window_attention(q, ck_l, cv_l, starts)
-            h = self._block_tail(h, a, layer_p, dt, ln, "bw")
+            h = _block_tail(h, a, layer_p, dt, ln, "bw")
             return h, (ck_l, cv_l)
 
         x, (ck, cv) = jax.lax.scan(body, x, (params["blocks"], ck, cv))
@@ -640,7 +583,7 @@ class DecodeEngine:
                 phys.reshape(-1), rows.reshape(-1), l)
             a = window_attention(q, paged_gather(kp, tables, l),
                                  paged_gather(vp, tables, l), starts)
-            return self._block_tail(h, a, layer_p, dt, ln, "bw"), kp, vp
+            return _block_tail(h, a, layer_p, dt, ln, "bw"), kp, vp
 
         x, kp, vp = _layers_over_pools(body, x, kp, vp, params["blocks"])
         x = ln(x, params["ln_f_scale"], params["ln_f_bias"])
@@ -667,6 +610,12 @@ class DecodeEngine:
         other input/output replicates. None/None off-mesh."""
         if self._mesh is None:
             return None, None
+        if isinstance(example_args[1], tuple):      # the paged pair
+            pools = (self._cache_sh, self._cache_sh)
+            ins = [self._param_sh, pools]
+            ins += [self._repl_sh] * (len(example_args) - 2)
+            outs = [pools] + [self._repl_sh] * (n_outputs - 1)
+            return tuple(ins), tuple(outs)
         ins = [self._param_sh, self._cache_sh, self._cache_sh]
         ins += [self._repl_sh] * (len(example_args) - 3)
         outs = [self._cache_sh, self._cache_sh]
@@ -726,24 +675,34 @@ class DecodeEngine:
         return (np.zeros((B,), np.float32), np.zeros((B,), np.int32),
                 np.ones((B,), np.float32), np.zeros((B,), np.int32))
 
+    # The paged pair takes the manager's arrays as ONE argument (a tuple:
+    # the pools, and a recurrent model's state arrays), donated whole; the
+    # slab and verify programs take the two slabs as arguments 1 and 2.
+    def _donated(self, fn) -> Tuple[int, ...]:
+        paged_pair = (self._prefill_fn_paged, self._decode_fn_paged)
+        return (1,) if fn in paged_pair else (1, 2)
+
+    def _prefill_program(self, bucket: int):
+        """(fn, example args) of one prefill rung, as _decode_program."""
+        tokens = np.zeros((1, bucket), np.int32)
+        if self.paged:
+            M = self.cache.max_pages_per_slot
+            return self._prefill_fn_paged, (
+                self.qparams, self.cache.arrays(), tokens, np.int32(1),
+                np.int32(0), np.zeros((M,), np.int32), np.int32(0),
+                *self._samp_scalar_examples())
+        return self._prefill_fn, (
+            self.qparams, self.cache.k, self.cache.v, tokens, np.int32(1),
+            np.int32(0), *self._samp_scalar_examples())
+
     def _prefill_exec(self, bucket: int):
         name = f"prefill_b{bucket}"
         exe = self._exec.get(name)
         if exe is None:
-            if self.paged:
-                M = self.cache.max_pages_per_slot
-                example = (self.qparams, self.cache.k, self.cache.v,
-                           np.zeros((1, bucket), np.int32), np.int32(1),
-                           np.int32(0), np.zeros((M,), np.int32),
-                           *self._samp_scalar_examples())
-                exe = self._compile(name, self._prefill_fn_paged, example,
-                                    donate_argnums=(1, 2))
-            else:
-                example = (self.qparams, self.cache.k, self.cache.v,
-                           np.zeros((1, bucket), np.int32), np.int32(1),
-                           np.int32(0), *self._samp_scalar_examples())
-                exe = self._compile(name, self._prefill_fn, example,
-                                    donate_argnums=(1, 2))
+            fn, example = self._prefill_program(bucket)
+            exe = self._compile(name, fn, example,
+                                donate_argnums=self._donated(fn),
+                                n_outputs=3 if self.paged else 4)
             self._exec[name] = exe
         return exe
 
@@ -756,8 +715,9 @@ class DecodeEngine:
         if self.paged:
             M = self.cache.max_pages_per_slot
             return self._decode_fn_paged, (
-                self.qparams, self.cache.k, self.cache.v, zeros_b, zeros_b,
-                np.zeros((B, M), np.int32), *self._samp_batch_examples())
+                self.qparams, self.cache.arrays(), zeros_b, zeros_b,
+                np.zeros((B, M), np.int32), zeros_b,
+                *self._samp_batch_examples())
         return self._decode_fn, (
             self.qparams, self.cache.k, self.cache.v, zeros_b, zeros_b,
             zeros_b, *self._samp_batch_examples())
@@ -767,9 +727,20 @@ class DecodeEngine:
         if exe is None:
             fn, example = self._decode_program()
             exe = self._compile("decode", fn, example,
-                                donate_argnums=(1, 2))
+                                donate_argnums=self._donated(fn),
+                                n_outputs=3 if self.paged else 4)
             self._exec["decode"] = exe
         return exe
+
+    def _call(self, exe, *args):
+        """One prefill or decode call on the live caches: ``(caches,
+        rest)`` back, the caches for ``self.cache.set_arrays`` once the
+        call is known to have run."""
+        if self.paged:
+            out = exe(self.qparams, self.cache.arrays(), *args)
+            return out[0], out[1:]
+        out = exe(self.qparams, self.cache.k, self.cache.v, *args)
+        return out[:2], out[2:]
 
     def _verify_exec(self):
         W = self.ecfg.verify_window
@@ -809,50 +780,34 @@ class DecodeEngine:
 
         ensure_compile_cache()
         timings: Dict[str, float] = {}
-        B = self.ecfg.max_batch
-        zeros_b = np.zeros((B,), np.int32)
 
-        def _warm_call(label, exe, *args):
+        def _warm_call(label, exe, example):
+            # the example arguments the executable was compiled from, on
+            # the live caches: all-zero tables and ``actives``, so a warm
+            # call writes the scratch page and slot 0's dead state alone
             t0 = time.perf_counter()
-            out = exe(self.qparams, self.cache.k, self.cache.v, *args)
-            jax.block_until_ready(out[2])
-            self.cache.k, self.cache.v = out[0], out[1]
+            caches, rest = self._call(exe, *example[2 if self.paged else 3:])
+            jax.block_until_ready(rest[0])
+            self.cache.set_arrays(caches)
             timings[label] = (time.perf_counter() - t0) * 1e3
 
-        dec = self._decode_exec()
-        if self.paged:
-            M = self.cache.max_pages_per_slot
-            _warm_call("decode", dec, zeros_b, zeros_b,
-                       np.zeros((B, M), np.int32),
-                       *self._samp_batch_examples())
-        else:
-            _warm_call("decode", dec, zeros_b, zeros_b, zeros_b,
-                       *self._samp_batch_examples())
+        _warm_call("decode", self._decode_exec(), self._decode_program()[1])
         for bucket in self.buckets:
-            exe = self._prefill_exec(bucket)
-            if self.paged:
-                M = self.cache.max_pages_per_slot
-                _warm_call(f"prefill_b{bucket}", exe,
-                           np.zeros((1, bucket), np.int32), np.int32(1),
-                           np.int32(0), np.zeros((M,), np.int32),
-                           *self._samp_scalar_examples())
-            else:
-                _warm_call(f"prefill_b{bucket}", exe,
-                           np.zeros((1, bucket), np.int32), np.int32(1),
-                           np.int32(0), *self._samp_scalar_examples())
+            _warm_call(f"prefill_b{bucket}", self._prefill_exec(bucket),
+                       self._prefill_program(bucket)[1])
         if self.ecfg.verify_window >= 2:
-            W = self.ecfg.verify_window
+            W, B = self.ecfg.verify_window, self.ecfg.max_batch
+            zeros_b = np.zeros((B,), np.int32)
             ver = self._verify_exec()
-            if self.paged:
-                M = self.cache.max_pages_per_slot
-                _warm_call(f"verify_w{W}", ver,
-                           np.zeros((B, W), np.int32), zeros_b,
-                           np.zeros((B, M), np.int32),
-                           *self._samp_batch_examples())
-            else:
-                _warm_call(f"verify_w{W}", ver,
-                           np.zeros((B, W), np.int32), zeros_b, zeros_b,
-                           *self._samp_batch_examples())
+            t0 = time.perf_counter()
+            lanes = (np.zeros((B, self.cache.max_pages_per_slot), np.int32)
+                     if self.paged else zeros_b)
+            out = ver(self.qparams, self.cache.k, self.cache.v,
+                      np.zeros((B, W), np.int32), zeros_b, lanes,
+                      *self._samp_batch_examples())
+            jax.block_until_ready(out[2])
+            self.cache.k, self.cache.v = out[0], out[1]
+            timings[f"verify_w{W}"] = (time.perf_counter() - t0) * 1e3
         # transfer-path gather/scatter (KV handoff + prefix store): one
         # compiled shape each — warmed here so a disagg handoff's first
         # export/adopt never pays a mid-request compile (~100ms)
@@ -945,7 +900,11 @@ class DecodeEngine:
         # a real open span, under the scheduler's per-request context
         # (the admit path wraps this call in the request's trace); its
         # four phases are its children
-        attrs = {"prompt_len": n, "step": self.sched_step}
+        # scan_tokens: what a recurrent model's scan has to process (the
+        # prompt: such a model is never given a prefix); 0 where no layer
+        # scans
+        attrs = {"prompt_len": n, "step": self.sched_step,
+                 "scan_tokens": n if self.model.recurrent else 0}
         with _spans.span("serve/prefill", attrs=attrs):
             if self.paged:
                 return self._start_paged(tokens, n, sp_scalars, attrs)
@@ -956,11 +915,11 @@ class DecodeEngine:
                 padded = np.zeros((1, bucket), np.int32)
                 padded[0, :n] = np.asarray(tokens, np.int32)
                 attrs.update(bucket=bucket, prefix_len=0, slot=slot)
-            ck, cv, logits, tok = self._run_prefill(
+            caches, logits, tok = self._run_prefill(
                 exe, bucket, slot, n, padded, np.int32(n), np.int32(slot),
                 *sp_scalars)
             with _spans.span("prefill/publish"):
-                self.cache.k, self.cache.v = ck, cv
+                self.cache.set_arrays(caches)
             return slot, logits, tok
 
     def _run_prefill(self, exe, bucket: int, slot: int, n_tokens: int,
@@ -971,8 +930,7 @@ class DecodeEngine:
         t0 = time.perf_counter_ns()
         try:
             with _spans.span("prefill/run"):
-                k, v, logits, tok = exe(
-                    self.qparams, self.cache.k, self.cache.v, *args)
+                caches, (logits, tok) = self._call(exe, *args)
                 tok = int(tok)
             with _spans.span("prefill/fetch_logits"):
                 logits = np.asarray(logits)
@@ -982,7 +940,7 @@ class DecodeEngine:
             raise
         smetrics.m_prefill_ms.observe((time.perf_counter_ns() - t0) / 1e6)
         smetrics.m_prefill_tokens.inc(n_tokens)
-        return k, v, logits, tok
+        return caches, logits, tok
 
     def _start_paged(self, tokens, n: int, sp_scalars, attrs):
         with _spans.span("prefill/prep"):
@@ -999,11 +957,11 @@ class DecodeEngine:
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :len(suffix)] = np.asarray(suffix, np.int32)
             attrs.update(bucket=bucket, prefix_len=prefix_len, slot=slot)
-        kp, vp, logits, tok = self._run_prefill(
+        caches, logits, tok = self._run_prefill(
             exe, bucket, slot, len(suffix), padded, np.int32(len(suffix)),
-            np.int32(prefix_len), table_row, *sp_scalars)
+            np.int32(prefix_len), table_row, np.int32(slot), *sp_scalars)
         with _spans.span("prefill/publish"):
-            self.cache.k, self.cache.v = kp, vp
+            self.cache.set_arrays(caches)
             if self.prefix is not None:
                 added = self.prefix.insert(tokens, table_row)
                 if added and self.prefix_store is not None:
@@ -1034,7 +992,8 @@ class DecodeEngine:
             # serve/prefill beside the head's, the decode calls under it
             with _spans.span("serve/prefill", attrs={
                     "prompt_len": n, "replayed": n - len(head),
-                    "slot": slot, "step": self.sched_step}):
+                    "slot": slot, "step": self.sched_step,
+                    "scan_tokens": 0}):
                 for i in range(len(head), n - 1):
                     self.decode_step_sampled({slot: int(tokens[i])}, None)
                 out = self.decode_step_sampled(
@@ -1063,6 +1022,11 @@ class DecodeEngine:
             return 0
         return sum(self.cache.pages_for(self.cache.length(s) + 1)
                    for s in slots)
+
+    def state_bytes(self, slots) -> int:
+        """Bytes of recurrent state a decode tick over ``slots`` reads and
+        writes back (0 for a model without recurrent layers)."""
+        return self.cache.state_bytes_per_slot * len(slots)
 
     def _decode_feed(self, slot_tokens: Dict[int, int]):
         B = self.ecfg.max_batch
@@ -1123,16 +1087,14 @@ class DecodeEngine:
                         raise PagePoolFullError(
                             f"slot {slot}: no free page for position "
                             f"{self.cache.length(slot)}")
-                lanes = self._masked_tables(slot_tokens)
-            else:
-                lanes = np.zeros((self.ecfg.max_batch,), np.int32)
-                for slot in slot_tokens:
-                    lanes[slot] = 1
+            actives = np.zeros((self.ecfg.max_batch,), np.int32)
+            actives[list(slot_tokens)] = 1
+            lanes = ((self._masked_tables(slot_tokens), actives)
+                     if self.paged else (actives,))
         try:
             with _spans.span("decode/run"):
-                ck, cv, logits, toks = exe(
-                    self.qparams, self.cache.k, self.cache.v, tokens,
-                    positions, lanes, *sp)
+                caches, (logits, toks) = self._call(
+                    exe, tokens, positions, *lanes, *sp)
                 toks = np.asarray(toks)
             with _spans.span("decode/fetch_logits"):
                 logits = np.asarray(logits)
@@ -1141,7 +1103,7 @@ class DecodeEngine:
             raise
         smetrics.m_decode_ms.observe((time.perf_counter_ns() - t0) / 1e6)
         with _spans.span("decode/commit"):
-            self.cache.k, self.cache.v = ck, cv
+            self.cache.set_arrays(caches)
             out: Dict[int, Tuple[int, np.ndarray]] = {}
             for slot in slot_tokens:
                 self.cache.set_length(slot, self.cache.length(slot) + 1)
@@ -1256,8 +1218,7 @@ class DecodeEngine:
             raise RuntimeError("reference params were dropped")
         toks = np.asarray(tokens, np.int32)[None]
         return np.asarray(
-            gpt_mod.forward(self._ref_params, toks, self.cfg)[0],
-            np.float32)
+            self.model.forward(self._ref_params, toks)[0], np.float32)
 
     def drop_reference_params(self) -> None:
         self._ref_params = None

@@ -97,6 +97,13 @@ class KVCache:
     def nbytes(self) -> int:
         return int(self.k.size + self.v.size) * jnp.dtype(self.dtype).itemsize
 
+    state_bytes_per_slot = 0         # a slab holds keys and values only
+
+    def set_arrays(self, arrays) -> None:
+        """Commit what a compiled program handed back (the engine's one
+        way to swap the cache arrays, as on :class:`PagedKVCache`)."""
+        self.k, self.v = arrays
+
     # -- slot bookkeeping --------------------------------------------------
     def alloc(self, length: int = 0) -> int:
         """Claim a free slot (lowest index first — deterministic tests);

@@ -88,6 +88,14 @@ m_page_occupancy = _REG.gauge(
 m_page_fragmentation = _REG.gauge(
     "paddle_serve_page_pool_fragmentation",
     "Internal page waste: 1 - used rows / allocated rows")
+# recurrent state beside the pages (hybrid models, serving/paged_kv.py):
+# what the live slots hold, and how often a slot's state was born anew
+m_state_bytes = _REG.gauge(
+    "paddle_serve_state_bytes",
+    "Recurrent state (conv + scan) held by live decode slots, bytes")
+m_state_resets = _REG.counter(
+    "paddle_serve_state_resets_total",
+    "Slot allocations that start a recurrent state from nothing")
 # speculative decoding (serving/spec_decode.py): the acceptance histogram
 # IS the speedup meter — mean accepted/window vs the draft+verify cost
 m_spec_accepted = _REG.histogram(

@@ -1,0 +1,232 @@
+"""What the decode engine asks of a model: a *model description*.
+
+``DecodeEngine``'s paged prefill and decode programs are ``embed -> layers
+-> final norm -> head`` with the caches handed in and out as the loop's
+carry; everything between embedding and logits is the model's. A
+description is any object with:
+
+- ``cfg`` (its ``dtype`` is the compute dtype), ``vocab_size``,
+  ``max_positions`` (``None`` where no positional table bounds ``max_seq``);
+- ``kv_geometry``: ``(layers, heads, head_dim)`` of the page pools, the
+  attention layers alone;
+- ``recurrent`` and ``state_geometry``: whether slots carry recurrent
+  state beside their pages, and its sizes (``PagedKVCache``'s ``state``);
+- ``paged_kernel``: whether ``decode_layers`` can read the pools through
+  the page-table kernel (``kv_path`` ``pallas_paged``) where Mosaic takes
+  the page shape, or always gathers;
+- ``hold(params, weight_dtype, chunk)``: the serving storage of a float32
+  parameter tree;
+- ``embed(qparams, tokens, positions)``;
+- ``prefill_layers(qparams, x [1, T, D], caches, ctx)`` with ``ctx``:
+  ``length``, ``prefix_len``, ``table_row``, ``slot``, ``page_size``;
+- ``decode_layers(qparams, x [B, D], caches, ctx)`` with ``ctx``:
+  ``positions``, ``tables``, ``actives``, ``page_size``, ``kv_path``,
+  ``fused``; both return ``(x, caches)``, the caches a tuple ``(k pool, v
+  pool[, conv, ssm])`` updated in place;
+- ``logits(qparams, h, fused=False)``: final norm and head, float32;
+- ``forward(params, tokens [1, T])``: the plain full forward pass (the
+  engine's parity surface).
+
+Two descriptions exist: :class:`GPTServing` here (``models/gpt.py``'s
+block) and ``models/jamba.py:JambaServing``. The slab and verify programs
+of the engine are still written for the GPT block (ROADMAP D2) and use
+the block helpers below directly.
+"""
+from __future__ import annotations
+
+import types
+
+import jax
+import jax.numpy as jnp
+
+from ..models import gpt as gpt_mod
+from ..ops import pallas_kernels as _pk
+from ..ops.decode_attention import (decode_attention, paged_cache_update,
+                                    paged_gather, paged_page_write,
+                                    paged_prefill_attention)
+from .quant import QuantizedLeaf, dequantize_params, quantize_params
+
+__all__ = ["describe", "GPTServing", "embed_rows", "layers_over_pools",
+           "block_tail"]
+
+
+def embed_rows(qparams, tokens, positions, dt):
+    """``wte[tokens] + wpe[positions]`` as ``dt``, summed in float32. The
+    rows are gathered from the tables as they are stored and widened
+    after: widening first has XLA write the whole float32 table (412 MB
+    at 50257 x 2048) on every call before it gathers a few rows of it."""
+    def rows(table, idx):
+        if isinstance(table, QuantizedLeaf):     # int8: chunked, flat
+            return dequantize_params(table)[idx]
+        return table[idx].astype(jnp.float32)
+
+    return (rows(qparams["wte"], tokens)
+            + rows(qparams["wpe"], positions)).astype(dt)
+
+
+def layers_over_pools(body, x, kp, vp, blocks):
+    """Run ``body(h, layer_p, l, kp, vp) -> (h, kp, vp)`` over the stacked
+    ``blocks`` with both KV pools as the loop's CARRY, in their stored
+    ``[L, P, page, nh, hd]`` layout, and the layer index ``l`` a loop
+    variable. A scan's ``xs``/``ys`` would slice a layer out of each pool
+    and re-stack it into a new buffer every iteration; a carry is updated
+    in place, so the donated pools alias the outputs and a program
+    touches only the rows and pages it indexes at ``[l, page, row]``."""
+    def step(carry, xs):
+        layer_p, l = xs
+        return body(carry[0], layer_p, l, carry[1], carry[2]), None
+
+    layers = jnp.arange(kp.shape[0], dtype=jnp.int32)
+    (x, kp, vp), _ = jax.lax.scan(step, (x, kp, vp), (blocks, layers))
+    return x, kp, vp
+
+
+def block_tail(h, a, layer_p, dt, ln, bt: str):
+    """Shared post-attention half of a GPT block: projection, residual,
+    MLP. ``bt`` is the einsum batch prefix ("b" for decode rows,
+    "bt"/"bw" for prefill/verify)."""
+    o = jnp.einsum(f"{bt}nh,nhd->{bt}d", a, layer_p["w_proj"].astype(dt))
+    h = h + o + layer_p["b_proj"].astype(dt)
+    h2 = ln(h, layer_p["ln2_scale"], layer_p["ln2_bias"])
+    f = jnp.einsum(f"{bt}d,df->{bt}f", h2, layer_p["w_fc"].astype(dt))
+    f = jax.nn.gelu(f + layer_p["b_fc"].astype(dt), approximate=True)
+    o2 = jnp.einsum(f"{bt}f,fd->{bt}d", f, layer_p["w_out"].astype(dt))
+    return h + o2 + layer_p["b_out"].astype(dt)
+
+
+def decode_ln(fused: bool):
+    """The decode tick's layernorm: the fused Pallas block kernel under
+    ``EngineConfig.fused_decode``, else the XLA reference."""
+    if fused:
+        return lambda x, scale, bias: _pk.fused_ln(x, scale, bias, eps=1e-5)
+    return gpt_mod._layer_norm
+
+
+class GPTServing:
+    """``models/gpt.py``'s block (LayerNorm, learned positions, equal
+    heads, tanh-GELU MLP, untied head) over the paged pools."""
+    recurrent = False
+    state_geometry = None
+    paged_kernel = True
+
+    def __init__(self, cfg: gpt_mod.GPTConfig):
+        self.cfg = cfg
+        self.vocab_size = cfg.vocab_size
+        self.max_positions = cfg.max_seq_len
+        self.kv_geometry = (cfg.num_layers, cfg.num_heads, cfg.head_dim)
+
+    def hold(self, params, weight_dtype: str, chunk: int):
+        return quantize_params(params, weight_dtype, chunk)
+
+    def embed(self, qparams, tokens, positions):
+        return embed_rows(qparams, tokens, positions, self.cfg.dtype)
+
+    def logits(self, qparams, h, fused=False):
+        dt = self.cfg.dtype
+        scale, bias, head = (dequantize_params(qparams[k]) for k in
+                             ("ln_f_scale", "ln_f_bias", "lm_head"))
+        if fused:
+            logits = _pk.fused_logits_head(h, scale, bias, head.astype(dt))
+        else:
+            h = decode_ln(False)(h, scale, bias)
+            logits = jnp.einsum("...d,dv->...v", h, head.astype(dt))
+        return logits.astype(jnp.float32)
+
+    def forward(self, params, tokens):
+        return gpt_mod.forward(params, tokens, self.cfg)
+
+    def prefill_layers(self, qparams, x, caches, ctx):
+        """tokens ``[1, T]`` are the SUFFIX after ``prefix_len`` cached
+        tokens: suffix K/V scatter into the slot's own pages, attention
+        runs over the gathered full view (cached prefix + suffix)."""
+        dt = self.cfg.dtype
+        ln = gpt_mod._layer_norm
+        ps = ctx.page_size
+        n_pages = x.shape[1] // ps
+        suffix_pages = jax.lax.dynamic_slice(
+            ctx.table_row, (ctx.prefix_len // ps,), (n_pages,))
+
+        def body(h, layer_p, l, kp, vp):
+            h1 = ln(h, layer_p["ln1_scale"], layer_p["ln1_bias"])
+            qkv = jnp.einsum("btd,dcnh->btcnh", h1,
+                             layer_p["w_qkv"].astype(dt))
+            qkv = qkv + layer_p["b_qkv"].astype(dt)
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            nh, hd = k.shape[2], k.shape[3]
+            kp = paged_page_write(
+                kp, k[0].reshape(n_pages, ps, nh, hd), suffix_pages, l)
+            vp = paged_page_write(
+                vp, v[0].reshape(n_pages, ps, nh, hd), suffix_pages, l)
+            k_all = paged_gather(kp, ctx.table_row[None], l)
+            v_all = paged_gather(vp, ctx.table_row[None], l)
+            a = paged_prefill_attention(q, k_all, v_all, ctx.prefix_len)
+            return block_tail(h, a, layer_p, dt, ln, "bt"), kp, vp
+
+        x, kp, vp = layers_over_pools(
+            body, x, caches[0], caches[1],
+            dequantize_params(qparams["blocks"]))
+        return x, (kp, vp)
+
+    def decode_layers(self, qparams, x, caches, ctx):
+        """Per-slot page tables ``[B, max_pages]`` route the one-row write
+        (a scatter on the carried pool) and the attention read through the
+        shared pool. Lanes whose table row is all-zero write into the
+        scratch page. The read has two lowerings of one algorithm
+        (``ctx.kv_path``): the Pallas kernel that fetches only the live
+        pages, or gather + masked softmax over the padded view."""
+        dt = self.cfg.dtype
+        ln = decode_ln(ctx.fused)
+        ps, tables, positions = ctx.page_size, ctx.tables, ctx.positions
+        if ctx.kv_path == "pallas_paged":
+            def write_and_attend(q, k, v, kp, vp, l):
+                return _pk.fused_paged_decode_attention(
+                    q, kp, vp, k, v, tables, positions, layer=l)
+        else:
+            phys = jnp.take_along_axis(
+                tables, (positions // ps)[:, None], axis=1)[:, 0]
+            rows = positions % ps
+
+            def write_and_attend(q, k, v, kp, vp, l):
+                kp = paged_cache_update(kp, k, phys, rows, l)
+                vp = paged_cache_update(vp, v, phys, rows, l)
+                a = decode_attention(q, paged_gather(kp, tables, l),
+                                     paged_gather(vp, tables, l),
+                                     positions + 1)
+                return a, kp, vp
+
+        def body(h, layer_p, l, kp, vp):
+            h1 = ln(h, layer_p["ln1_scale"], layer_p["ln1_bias"])
+            qkv = jnp.einsum("bd,dcnh->bcnh", h1,
+                             layer_p["w_qkv"].astype(dt))
+            qkv = qkv + layer_p["b_qkv"].astype(dt)
+            # dead lanes' all-zero tables land the write on the scratch
+            # page, which no live slot reads
+            a, kp, vp = write_and_attend(qkv[:, 0], qkv[:, 1], qkv[:, 2],
+                                         kp, vp, l)
+            return block_tail(h, a, layer_p, dt, ln, "b"), kp, vp
+
+        x, kp, vp = layers_over_pools(
+            body, x, caches[0], caches[1],
+            dequantize_params(qparams["blocks"]))
+        return x, (kp, vp)
+
+
+def describe(cfg):
+    """A model description from what ``DecodeEngine`` was given: one as it
+    is, a config of a known family through its description."""
+    if hasattr(cfg, "prefill_layers"):
+        return cfg
+    if isinstance(cfg, gpt_mod.GPTConfig):
+        return GPTServing(cfg)
+    from ..models import jamba as jamba_mod
+
+    if isinstance(cfg, jamba_mod.JambaConfig):
+        return jamba_mod.JambaServing(cfg)
+    raise TypeError(
+        f"DecodeEngine: no model description for {type(cfg).__name__}; "
+        "pass an object with the surface serving/model.py lists")
+
+
+def ctx(**kw):
+    """The per-call context a program hands its model's layers."""
+    return types.SimpleNamespace(**kw)

@@ -35,6 +35,18 @@ executable. A shared system prompt therefore prefills ONCE per engine,
 metered by ``paddle_serve_prefix_cache_total{hit|miss}``. Entries are
 LRU; pool pressure reclaims cache-held pages before any allocation
 fails.
+
+**Recurrent state** (hybrid models, docs/serving.md "Hybrid models"): a
+model whose layers are mostly state-space mixers gives the manager a
+``state`` geometry, and a second kind of cache lives beside the pages: a
+fixed-size row per slot and recurrent layer, ``conv [Lm, slots, (d_conv -
+1) * d_inner]`` in the cache's dtype and ``ssm [Lm, slots, d_state,
+d_inner]`` float32, allocated once, carried through the compiled programs
+in place like the pools. A slot's row is born with the slot (the prefill
+program writes it from an empty history, never from what the row held), is
+advanced by the ticks the slot rides, and is dead at ``free``; the pool is
+then built for the attention layers alone. The one object answers
+``can_admit``, ``alloc``, ``free``, ``length`` and ``nbytes`` for both.
 """
 from __future__ import annotations
 
@@ -104,7 +116,8 @@ class PagedKVCache:
 
     def __init__(self, num_layers: int, max_slots: int, max_seq: int,
                  num_heads: int, head_dim: int, dtype: Any = jnp.float32,
-                 page_size: int = 8, num_pages: int = 0):
+                 page_size: int = 8, num_pages: int = 0,
+                 state: Optional[Dict[str, int]] = None):
         if max_slots < 1 or max_seq < 1:
             raise ValueError("max_slots and max_seq must be >= 1")
         if page_size < 1 or max_seq % page_size:
@@ -128,6 +141,20 @@ class PagedKVCache:
                  self.num_heads, self.head_dim)
         self.k = jnp.zeros(shape, dtype)
         self.v = jnp.zeros(shape, dtype)
+        # per-slot recurrent state: {"layers", "conv_width", "d_state",
+        # "d_inner"} from the model, None for an attention-only model
+        self.conv = self.ssm = None
+        self.state_bytes_per_slot = 0
+        self.state_resets = 0
+        if state is not None:
+            lm = int(state["layers"])
+            self.conv = jnp.zeros(
+                (lm, self.max_slots, int(state["conv_width"])), dtype)
+            self.ssm = jnp.zeros(
+                (lm, self.max_slots, int(state["d_state"]),
+                 int(state["d_inner"])), jnp.float32)
+            self.state_bytes_per_slot = (
+                self.conv.nbytes + self.ssm.nbytes) // self.max_slots
         self._tables = np.zeros((self.max_slots, self.max_pages_per_slot),
                                 np.int32)           # 0 = scratch/unmapped
         self._slots = [_SlotState() for _ in range(self.max_slots)]
@@ -140,7 +167,38 @@ class PagedKVCache:
     # -- geometry ----------------------------------------------------------
     @property
     def nbytes(self) -> int:
-        return int(self.k.size + self.v.size) * jnp.dtype(self.dtype).itemsize
+        """Pools and recurrent state together."""
+        return (int(self.k.size + self.v.size)
+                * jnp.dtype(self.dtype).itemsize
+                + self.state_bytes_per_slot * self.max_slots)
+
+    @property
+    def recurrent(self) -> bool:
+        return self.state_bytes_per_slot > 0
+
+    def arrays(self) -> tuple:
+        """What the compiled programs carry: ``(k, v)``, and the two state
+        arrays behind them where there are any."""
+        if self.recurrent:
+            return (self.k, self.v, self.conv, self.ssm)
+        return (self.k, self.v)
+
+    def set_arrays(self, arrays) -> None:
+        self.k, self.v = arrays[0], arrays[1]
+        if self.recurrent:
+            self.conv, self.ssm = arrays[2], arrays[3]
+
+    def live_state_bytes(self) -> int:
+        return self.state_bytes_per_slot * (
+            self.max_slots - len(self._free_slots))
+
+    def _note_state(self, born: bool = False) -> None:
+        if not self.recurrent:
+            return
+        if born:
+            self.state_resets += 1
+            smetrics.m_state_resets.inc()
+        smetrics.m_state_bytes.set(self.live_state_bytes())
 
     def pages_for(self, n_tokens: int) -> int:
         """Pages needed to back ``n_tokens`` cache rows."""
@@ -231,6 +289,7 @@ class PagedKVCache:
         row[:n_prefix] = prefix_pages
         row[n_prefix:st.mapped] = own
         self._note_pool_metrics()
+        self._note_state(born=True)
         return slot
 
     def ensure_capacity(self, slot: int, upto_len: int) -> bool:
@@ -267,6 +326,7 @@ class PagedKVCache:
         st.mapped = 0
         self._free_slots.append(slot)
         self._free_slots.sort()
+        self._note_state()
 
     def set_length(self, slot: int, length: int) -> None:
         st = self._slots[slot]
@@ -368,6 +428,10 @@ class PagedKVCache:
         Raises :class:`CacheFullError` when no slot is free (the caller
         still owns the pages and must deref them)."""
         pages = [int(p) for p in pages]
+        if self.recurrent:
+            raise ValueError(
+                "adopt_slot: pages carry keys and values only; a slot's "
+                "recurrent state has no hand-off (kv_transfer)")
         if length > self.max_seq:
             raise ValueError(
                 f"sequence length {length} exceeds max_seq {self.max_seq}")

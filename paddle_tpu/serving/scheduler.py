@@ -694,13 +694,18 @@ class Scheduler:
         # finds its ticks by step (first_step..last_step), not the other
         # way round
         eng = self.engine
+        state_bytes = eng.state_bytes(feed)
         with _spans.span("serve/decode_tick", attrs={
                 "step": self.steps, "batch": len(feed),
                 "riders": [r.id for r in self._active.values()],
                 "cached_tokens": sum(eng.cache.length(s) for s in feed),
                 # how the tick reads the cache, and how many pages of it
                 "kv_path": eng.kv_path,
-                "live_pages": eng.live_pages(feed)}):
+                "live_pages": eng.live_pages(feed),
+                # riders whose recurrent state the tick advances, and the
+                # bytes of it they hold (0 where no layer is recurrent)
+                "state_slots": len(feed) if state_bytes else 0,
+                "state_bytes": state_bytes}):
             out = eng.generate_step(feed, params)
         attrs = {"emitted": 0, "finished": 0}
         with _spans.span("serve/emit", attrs=attrs):

@@ -621,6 +621,12 @@ class FrontDoor:
             out["buckets"] = list(self.scheduler.engine.buckets)
             out["weight_dtype"] = self.scheduler.engine.ecfg.weight_dtype
             out["kv_path"] = getattr(self.scheduler.engine, "kv_path", None)
+            cache = self.scheduler.engine.cache
+            if getattr(cache, "state_bytes_per_slot", 0):
+                # a hybrid model: recurrent state the live slots hold, and
+                # how many slots' states have been born
+                out["state_bytes"] = cache.live_state_bytes()
+                out["state_resets"] = cache.state_resets
             if self.loop is not None:
                 out["loop_alive"] = self.loop.alive
                 out["loop_faults"] = self.loop.faults

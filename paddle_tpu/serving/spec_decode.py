@@ -83,6 +83,13 @@ class SpecDecodeEngine:
     allocators by construction."""
 
     def __init__(self, target: DecodeEngine, draft: DecodeEngine):
+        for eng in (target, draft):
+            if eng.model.recurrent:
+                raise ValueError(
+                    f"{type(eng.model).__name__} has recurrent layers: the "
+                    "speculative wrapper (SpecDecodeEngine) cannot carry "
+                    "recurrent state (a rejected draft token cannot be "
+                    "taken back out of it)")
         W = target.ecfg.verify_window
         if W < 2:
             raise ValueError(
@@ -141,6 +148,9 @@ class SpecDecodeEngine:
 
     def live_pages(self, slots) -> int:
         return self.target.live_pages(slots)
+
+    def state_bytes(self, slots) -> int:
+        return self.target.state_bytes(slots)
 
     @property
     def poisoned(self):

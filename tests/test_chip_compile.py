@@ -304,13 +304,15 @@ def _engine(num_layers, **ecfg):
 
 def _lower_donated(fn, example, sharding):
     """``fn`` lowered for the described chip from its example arguments'
-    shapes, the cache arguments (1 and 2) donated as the engine does."""
+    shapes, the cache arguments donated as the engine does (the slabs at
+    1 and 2; the paged pair's one tuple of arrays at 1)."""
     args = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype
                                        if not hasattr(a, "dtype")
                                        else a.dtype, sharding=sharding),
         example)
-    return jax.jit(fn, donate_argnums=(1, 2)).lower(*args)
+    donated = (1,) if isinstance(example[1], tuple) else (1, 2)
+    return jax.jit(fn, donate_argnums=donated).lower(*args)
 
 
 @pytest.mark.parametrize("kv_layout", ["slab", "paged"])
@@ -353,6 +355,49 @@ def test_paged_engine_gathers_where_mosaic_refuses_the_page(one_chip):
                  ((4, 4, 16), BF16), ((2, 17, 8, 4, 16), BF16),
                  ((2, 17, 8, 4, 16), BF16), ((), jnp.int32),
                  ((4, 4), jnp.int32), ((4,), jnp.int32))
+
+
+@pytest.mark.parametrize("T", [256, 2048])
+def test_selective_scan_fwd(one_chip, T):
+    """The prefill scan at the cell's widths (d_inner 5120, d_state 16):
+    blocks of 64 tokens by the whole width, the state in VMEM."""
+    from paddle_tpu.ops import selective_scan as SS
+
+    DI, N = 5120, 16
+    _compile(SS.selective_scan, one_chip,
+             ((T, DI), BF16), ((T, DI), F32), ((N, DI), F32), ((T, N), BF16),
+             ((T, N), BF16), ((DI,), F32), ((T, DI), BF16), ((), jnp.int32))
+
+
+def test_jamba_decode_tick_two_layer_cut(one_chip):
+    """The hybrid model's decode tick at the cell's widths and 64 slots,
+    cut to one Mamba and one attention layer: both state arrays and both
+    pools are carried in place (aliased whole), and the tick's temporaries
+    stay under one layer's scan state."""
+    from paddle_tpu import serving
+    from paddle_tpu.models import jamba as J
+
+    cfg = J.JambaConfig(num_hidden_layers=2, attn_layer_period=2,
+                        attn_layer_offset=1)
+    shapes = J.leaf_shapes(cfg)
+    params = jax.tree_util.tree_map(      # calloc'd: never touched
+        lambda s: np.zeros(s, np.float32), shapes,
+        is_leaf=lambda s: isinstance(s, tuple))
+    eng = serving.DecodeEngine(params, cfg, serving.EngineConfig(
+        max_batch=64, max_seq=CELL_S, kv_layout="paged", page_size=PAGE,
+        weight_dtype="bf16", prefix_cache=False,
+        prefill_buckets=(256, 2048)))
+    assert eng.kv_path == "xla_gather"        # 1 KV head: no page shape
+    compiled = _lower_donated(*eng._decode_program(), one_chip).compile()
+    mem = compiled.memory_analysis()
+    caches = sum(a.size * a.dtype.itemsize for a in eng.cache.arrays())
+    assert caches == eng.cache.nbytes
+    assert mem.alias_size_in_bytes >= caches
+    assert mem.temp_size_in_bytes < eng.cache.ssm.nbytes
+    # and a prefill rung goes through Mosaic (the scan kernel)
+    lowered = _lower_donated(*eng._prefill_program(256), one_chip)
+    assert "selective_scan_fwd" in lowered.as_text()
+    lowered.compile()
 
 
 _POOL_SIZED = ("copy", "convert", "dynamic-slice", "dynamic-update-slice")
@@ -423,12 +468,7 @@ def test_paged_programs_touch_only_live_pages(one_chip, program):
     if program == "decode":
         fn, example = eng._decode_program()
     else:
-        bucket = int(program.split("_b")[1])
-        fn, example = eng._prefill_fn_paged, (
-            eng.qparams, eng.cache.k, eng.cache.v,
-            np.zeros((1, bucket), np.int32), np.int32(1), np.int32(0),
-            np.zeros((eng.cache.max_pages_per_slot,), np.int32),
-            *eng._samp_scalar_examples())
+        fn, example = eng._prefill_program(int(program.split("_b")[1]))
     compiled = _lower_donated(fn, example, one_chip).compile()
     pool = eng.cache.k.size * eng.cache.k.dtype.itemsize
     mem = compiled.memory_analysis()

@@ -493,18 +493,14 @@ def test_recompile_negative_control_new_paths(tiny_model, layout_kw):
     eng._warm = True
     before = _recompile_total()
     if eng.paged:
-        M = eng.cache.max_pages_per_slot
-        example = (eng.qparams, eng.cache.k, eng.cache.v,
-                   np.zeros((1, 16), np.int32), np.int32(1), np.int32(0),
-                   np.zeros((M,), np.int32),
-                   *eng._samp_scalar_examples())
-        fn = eng._prefill_fn_paged
+        fn, example = eng._prefill_program(16)
     else:
         example = (eng.qparams, eng.cache.k, eng.cache.v,
                    np.zeros((1, 12), np.int32), np.int32(1), np.int32(0),
                    *eng._samp_scalar_examples())
         fn = eng._prefill_fn
-    eng._compile("prefill_b8", fn, example, donate_argnums=(1, 2))
+    eng._compile("prefill_b8", fn, example,
+                 donate_argnums=eng._donated(fn))
     assert _recompile_total() - before == 1
     assert eng.steady_state_recompiles == 1
 
@@ -706,12 +702,14 @@ def _paged_program(eng, program, rng):
         return eng._decode_fn_paged, (
             rng.integers(0, V, (B,)).astype(np.int32),
             np.asarray([7, 0, 16, 0], np.int32), tables,
+            np.asarray([1, 0, 1, 0], np.int32),
             *eng._samp_batch_examples())
     if program == "prefill":
         # a 8-token suffix behind a cached 8-token prefix, 5 valid
         return eng._prefill_fn_paged, (
             rng.integers(0, V, (1, 8)).astype(np.int32), np.int32(5),
-            np.int32(8), tables[2], *eng._samp_scalar_examples())
+            np.int32(8), tables[2], np.int32(2),
+            *eng._samp_scalar_examples())
     W = eng.ecfg.verify_window
     return eng._verify_fn_paged, (
         rng.integers(0, V, (B, W)).astype(np.int32),
@@ -725,15 +723,26 @@ def test_carried_pools_match_xs_ys_scan(tiny_model, monkeypatch, program):
     number: each paged program over the carried pools returns the pools,
     logits and tokens that the scan over per-layer slices returned."""
     from paddle_tpu.serving import engine as engine_mod
+    from paddle_tpu.serving import model as model_mod
 
     eng = make_engine(tiny_model, kv_layout="paged", page_size=8,
                       verify_window=3)
     assert eng.kv_path == "xla_gather"
     fn, args = _paged_program(eng, program, np.random.default_rng(5))
     kp, vp = _seeded_pools(eng, 6)
-    new = jax.jit(fn)(eng.qparams, kp, vp, *args)
+
+    def run():
+        # the paged prefill and decode programs take the pools as one
+        # argument and hand them back as one; the verify program as two
+        if program == "verify":
+            return jax.jit(fn)(eng.qparams, kp, vp, *args)
+        pools, logits, toks = jax.jit(fn)(eng.qparams, (kp, vp), *args)
+        return (*pools, logits, toks)
+
+    new = run()
     monkeypatch.setattr(engine_mod, "_layers_over_pools", _xs_ys_layers)
-    old = jax.jit(fn)(eng.qparams, kp, vp, *args)
+    monkeypatch.setattr(model_mod, "layers_over_pools", _xs_ys_layers)
+    old = run()
     for got, want in zip(new, old):
         np.testing.assert_array_equal(np.asarray(got, np.float32),
                                       np.asarray(want, np.float32))
