@@ -25,7 +25,10 @@ module assembles them into the serving shape:
   in a Pallas kernel (``kv_path``).
 - **Sampling inside the executables** (``serving/sampling.py``):
   per-slot temperature/top-k/top-p/seed ride as batch inputs — changing
-  them never changes a shape. ``temperature=0`` is bit-exact greedy.
+  them never changes a shape. ``temperature=0`` is bit-exact greedy,
+  and a call pays only for the dearest sampler path its batch asks for
+  (chosen inside the executable; counted and stamped by
+  ``_note_sampler``).
 - **Tensor-parallel lowering** (``EngineConfig(sharding="tp", tp=N)``):
   attention/MLP weights and the KV head axis shard over an N-chip mesh
   through PR 12's plan machinery (``sharding/plan.py`` suffix
@@ -79,6 +82,16 @@ __all__ = ["EngineConfig", "DecodeEngine", "PromptTooLongError",
 
 class PromptTooLongError(ValueError):
     """Prompt exceeds the largest prefill bucket."""
+
+
+def _note_sampler(program: str, temps, top_ks, top_ps) -> str:
+    """Count one call of ``program`` (decode | prefill | verify) under the
+    sampler path its executable will take for these host-side parameters,
+    and return the path's name (the ``sampler`` attribute of the call's
+    ``run`` span)."""
+    path = samp.path_name(temps, top_ks, top_ps)
+    smetrics.m_sampler_path.labels(path, program).inc()
+    return path
 
 
 def default_bucket_ladder(max_seq: int, smallest: int = 16) -> Tuple[int, ...]:
@@ -916,21 +929,23 @@ class DecodeEngine:
                 padded[0, :n] = np.asarray(tokens, np.int32)
                 attrs.update(bucket=bucket, prefix_len=0, slot=slot)
             caches, logits, tok = self._run_prefill(
-                exe, bucket, slot, n, padded, np.int32(n), np.int32(slot),
-                *sp_scalars)
+                exe, bucket, slot, n, sp_scalars, padded, np.int32(n),
+                np.int32(slot))
             with _spans.span("prefill/publish"):
                 self.cache.set_arrays(caches)
             return slot, logits, tok
 
     def _run_prefill(self, exe, bucket: int, slot: int, n_tokens: int,
-                     *args):
-        """The prefill executable's call: ``prefill/run`` until the
-        sampled token is on the host (that waits for the program), then
+                     sp_scalars, *args):
+        """The prefill executable's call (``args``, then the request's
+        four sampling scalars): ``prefill/run`` until the sampled token
+        is on the host (that waits for the program), then
         ``prefill/fetch_logits``, the transfer of the logits alone."""
         t0 = time.perf_counter_ns()
+        sampler = _note_sampler("prefill", *sp_scalars[:3])
         try:
-            with _spans.span("prefill/run"):
-                caches, (logits, tok) = self._call(exe, *args)
+            with _spans.span("prefill/run", attrs={"sampler": sampler}):
+                caches, (logits, tok) = self._call(exe, *args, *sp_scalars)
                 tok = int(tok)
             with _spans.span("prefill/fetch_logits"):
                 logits = np.asarray(logits)
@@ -958,8 +973,9 @@ class DecodeEngine:
             padded[0, :len(suffix)] = np.asarray(suffix, np.int32)
             attrs.update(bucket=bucket, prefix_len=prefix_len, slot=slot)
         caches, logits, tok = self._run_prefill(
-            exe, bucket, slot, len(suffix), padded, np.int32(len(suffix)),
-            np.int32(prefix_len), table_row, np.int32(slot), *sp_scalars)
+            exe, bucket, slot, len(suffix), sp_scalars, padded,
+            np.int32(len(suffix)), np.int32(prefix_len), table_row,
+            np.int32(slot))
         with _spans.span("prefill/publish"):
             self.cache.set_arrays(caches)
             if self.prefix is not None:
@@ -1091,8 +1107,9 @@ class DecodeEngine:
             actives[list(slot_tokens)] = 1
             lanes = ((self._masked_tables(slot_tokens), actives)
                      if self.paged else (actives,))
+            sampler = _note_sampler("decode", *sp[:3])
         try:
-            with _spans.span("decode/run"):
+            with _spans.span("decode/run", attrs={"sampler": sampler}):
                 caches, (logits, toks) = self._call(
                     exe, tokens, positions, *lanes, *sp)
                 toks = np.asarray(toks)
@@ -1152,6 +1169,7 @@ class DecodeEngine:
             tokens[slot] = np.asarray(win, np.int32)
             starts[slot] = self.cache.length(slot)
         sp = samp.batch_arrays(params_by_slot or {}, B)
+        _note_sampler("verify", *sp[:3])
         exe = self._verify_exec()
         t0 = time.perf_counter_ns()
         try:

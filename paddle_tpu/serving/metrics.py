@@ -18,7 +18,7 @@ __all__ = [
     "m_spec_windows", "m_preemptions", "m_hol_admits",
     "m_shed", "m_replica_restarts", "m_failover", "m_prefix_store",
     "m_kv_transfer_bytes", "m_kv_transfer_ms", "m_pool_prefix",
-    "m_disagg_fallback", "request_code",
+    "m_disagg_fallback", "m_sampler_path", "request_code",
 ]
 
 _REG = _obs.default_registry()
@@ -88,6 +88,15 @@ m_page_occupancy = _REG.gauge(
 m_page_fragmentation = _REG.gauge(
     "paddle_serve_page_pool_fragmentation",
     "Internal page waste: 1 - used rows / allocated rows")
+# which of the sampler's three paths an engine call took inside its
+# executable (serving/sampling.py: the host computes it from the same
+# predicate before the call), so an operator sees what share of ticks
+# pays the vocabulary sort
+m_sampler_path = _REG.counter(
+    "paddle_serve_sampler_path_total",
+    "Engine calls by the sampler path their batch took "
+    "(greedy|temperature|filtered) and program (decode|prefill|verify)",
+    ("path", "program"))
 # recurrent state beside the pages (hybrid models, serving/paged_kv.py):
 # what the live slots hold, and how often a slot's state was born anew
 m_state_bytes = _REG.gauge(

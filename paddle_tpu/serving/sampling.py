@@ -3,7 +3,7 @@
 Temperature / top-k / top-p live INSIDE the compiled decode (and prefill
 and verify) functions: per-slot parameters arrive as plain ``[max_batch]``
 batch inputs and per-slot PRNG keys derive from a per-request integer
-seed folded with the token position — so a request changing its sampling
+seed and the token position — so a request changing its sampling
 knobs, or two requests with different knobs sharing a decode batch, never
 changes a shape and never triggers a recompile (the zero-recompile
 contract extends to sampling by construction).
@@ -17,9 +17,28 @@ Semantics per slot:
   masked by top-k (keep the k highest-logit tokens; ``k <= 0`` disables)
   and nucleus top-p (keep the smallest set of tokens whose probability
   mass reaches ``p``; ``p >= 1`` disables), then sampled with
-  ``jax.random.categorical`` under a key
-  ``fold_in(PRNGKey(seed), position)`` — deterministic per
-  (seed, position), independent across slots and steps.
+  ``jax.random.categorical`` under the key ``(position, seed)`` —
+  deterministic per (seed, position), independent across slots and steps.
+
+What a call costs follows what its batch asks for. The rows of one call
+run in lock step, so the work is chosen once for the whole batch, at run
+time, by a ``lax.switch`` inside the one executable (:func:`sampler_path`
+is the predicate, the same function on the host's numpy vectors and on
+the traced ones):
+
+- ``greedy`` — no row has ``temperature > 0``: the argmax and nothing
+  else (no divide, no sort, no softmax, no PRNG);
+- ``temperature`` — some row samples, none of those filters: one
+  ``categorical`` over ``logits / temperature``, no sort;
+- ``filtered`` — some sampling row has a top-k or a top-p: the
+  vocabulary sort and softmax of :func:`_masked_logits` for every row (one
+  filtered rider makes its batch pay the dearest path).
+
+The tokens are the same whichever path runs: an unfiltered row's mask is
+the row itself, and a greedy row never read the draw. The engine counts
+its calls by path (``paddle_serve_sampler_path_total{path,program}``) and
+stamps the path on its ``decode/run`` and ``prefill/run`` spans
+(``sampler``).
 """
 from __future__ import annotations
 
@@ -31,7 +50,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-__all__ = ["SamplingParams", "GREEDY", "sample_token", "sample_batch",
+__all__ = ["SamplingParams", "GREEDY", "PATHS", "sampler_path",
+           "path_name", "sample", "sample_token", "sample_batch",
            "sample_window", "batch_arrays", "adjusted_probs_np"]
 
 
@@ -88,40 +108,88 @@ def _masked_logits(logits, temp, top_k, top_p):
     return jnp.where(scaled >= thresh, scaled, -jnp.inf)
 
 
-def sample_token(logits, temp, top_k, top_p, seed, position):
-    """One token from one [V] logits row (jit-traceable; scalars traced).
+# the sampler's paths in the order of :func:`sampler_path`'s index, each
+# dearer than the one before it
+PATHS = ("greedy", "temperature", "filtered")
 
-    Greedy lane (temp <= 0) short-circuits to argmax — no PRNG consumed,
-    bitwise what the host-side ``np.argmax`` used to produce. The PRNG
+
+def sampler_path(temps, top_ks, top_ps):
+    """Index into :data:`PATHS` of the cheapest path that serves every
+    row: 0 when no row samples, 1 when some do and none of those filters,
+    2 when a sampling row carries a top-k or a top-p. Operators and
+    ``any`` alone, so the host's numpy vectors (or scalars) and the
+    executable's traced ones go through the same lines."""
+    sampling = temps > 0
+    filtered = sampling & ((top_ks > 0) | (top_ps < 1))
+    return sampling.any() * 1 + filtered.any() * 1
+
+
+def path_name(temps, top_ks, top_ps) -> str:
+    """Host side: the name of the path the executable will take for these
+    (numpy) sampling parameters."""
+    return PATHS[int(sampler_path(temps, top_ks, top_ps))]
+
+
+def _draw(scaled, seed, position):
+    """One categorical draw from one [V] row of scaled logits. The PRNG
     key is the raw pair ``(position, seed)`` — deterministic per
     (seed, position), independent across slots and steps, one threefry
     application per draw (a fold_in chain would compile two more)."""
+    key = jnp.stack([position.astype(jnp.uint32), seed.astype(jnp.uint32)])
+    return jax.random.categorical(key, scaled).astype(jnp.int32)
+
+
+def _draw_plain(logits, temp, top_k, top_p, seed, position):
+    # what _masked_logits returns when neither filter is on: its
+    # threshold is then the row's minimum, so the mask keeps every entry
+    return _draw(logits / jnp.maximum(temp, 1e-6), seed, position)
+
+
+def _draw_filtered(logits, temp, top_k, top_p, seed, position):
+    return _draw(_masked_logits(logits, temp, top_k, top_p), seed, position)
+
+
+def sample(logits, temps, top_ks, top_ps, seeds, positions):
+    """[..., V] logits, per-row positions [...], and sampling parameters
+    over the leading batch axes -> [...] int32 tokens (jit-traceable).
+    Holds the one predicate: the path is picked outside every ``vmap``
+    (under one a ``switch`` turns back into a select that computes every
+    side). A greedy batch runs the argmax alone — no PRNG consumed,
+    bitwise what the host-side ``np.argmax`` used to produce.
+
+    The engine's three programs call it by the shape they hand over:
+    ``sample_token`` (prefill: one ``[V]`` row, scalar knobs, so a scalar
+    ``switch`` on the request's own), ``sample_batch`` (decode:
+    ``[B, V]`` beside ``[B]`` knobs and positions) and ``sample_window``
+    (speculative verify: ``[B, W, V]`` beside ``[B]`` knobs and ``[B, W]``
+    positions, every window position under its own key off the slot's
+    seed)."""
     logits = logits.astype(jnp.float32)
-    greedy_tok = jnp.argmax(logits).astype(jnp.int32)
-    key = jnp.stack([position.astype(jnp.uint32),
-                     seed.astype(jnp.uint32)])
-    sampled = jax.random.categorical(
-        key, _masked_logits(logits, temp, top_k, top_p)).astype(jnp.int32)
-    return jnp.where(temp <= 0.0, greedy_tok, sampled)
+    batch = logits.shape[:-1]
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def rows(a):
+        a = jnp.asarray(a)
+        return jnp.broadcast_to(
+            a.reshape(a.shape + (1,) * (len(batch) - a.ndim)), batch)
+
+    temps, top_ks, top_ps, seeds = map(rows, (temps, top_ks, top_ps, seeds))
+
+    def drawn(draw):
+        for _ in batch:
+            draw = jax.vmap(draw)
+
+        def branch():
+            sampled = draw(logits, temps, top_ks, top_ps, seeds, positions)
+            return jnp.where(temps <= 0.0, greedy, sampled)
+        return branch
+
+    return jax.lax.switch(
+        sampler_path(temps, top_ks, top_ps),
+        (lambda: greedy, drawn(_draw_plain), drawn(_draw_filtered)))
 
 
-def sample_batch(logits, temps, top_ks, top_ps, seeds, positions):
-    """[B, V] logits + [B] per-slot params -> [B] int32 tokens."""
-    return jax.vmap(sample_token)(logits, temps, top_ks, top_ps, seeds,
-                                  positions)
-
-
-def sample_window(logits, temps, top_ks, top_ps, seeds, positions):
-    """[B, W, V] logits + [B] params + [B, W] positions -> [B, W] tokens
-    (the speculative-verify window: every window position gets its own
-    position-folded key off the slot's seed)."""
-
-    def per_slot(lg, t, k, p, s, pos):
-        return jax.vmap(
-            lambda l, q: sample_token(l, t, k, p, s, q))(lg, pos)
-
-    return jax.vmap(per_slot)(logits, temps, top_ks, top_ps, seeds,
-                              positions)
+sample_token = sample_batch = sample_window = sample
 
 
 def adjusted_probs_np(logits: np.ndarray, sp: SamplingParams

@@ -369,11 +369,9 @@ def test_selective_scan_fwd(one_chip, T):
              ((T, N), BF16), ((DI,), F32), ((T, DI), BF16), ((), jnp.int32))
 
 
-def test_jamba_decode_tick_two_layer_cut(one_chip):
-    """The hybrid model's decode tick at the cell's widths and 64 slots,
-    cut to one Mamba and one attention layer: both state arrays and both
-    pools are carried in place (aliased whole), and the tick's temporaries
-    stay under one layer's scan state."""
+def _jamba_cut_engine():
+    """The hybrid model at the cell's widths and 64 slots, cut to one
+    Mamba and one attention layer, over calloc'd weights."""
     from paddle_tpu import serving
     from paddle_tpu.models import jamba as J
 
@@ -383,12 +381,37 @@ def test_jamba_decode_tick_two_layer_cut(one_chip):
     params = jax.tree_util.tree_map(      # calloc'd: never touched
         lambda s: np.zeros(s, np.float32), shapes,
         is_leaf=lambda s: isinstance(s, tuple))
-    eng = serving.DecodeEngine(params, cfg, serving.EngineConfig(
+    return serving.DecodeEngine(params, cfg, serving.EngineConfig(
         max_batch=64, max_seq=CELL_S, kv_layout="paged", page_size=PAGE,
         weight_dtype="bf16", prefix_cache=False,
         prefill_buckets=(256, 2048)))
+
+
+_TICKS = {}
+
+
+def _compiled_tick(cell, sharding):
+    """(engine, its decode tick compiled for the described chip) of the
+    GPT serving cell at depth 2 or of the two-layer cut of the hybrid
+    cell: a minute or more each, so compiled once a process for the
+    tests that read it. Called from inside a test (the autouse fixture
+    has to be in force), never from a fixture of wider scope."""
+    if cell not in _TICKS:
+        eng = (_jamba_cut_engine() if cell == "jamba_cut" else
+               _engine(CELL_L, max_seq=CELL_S, max_batch=CELL_B,
+                       kv_layout="paged", num_pages=CELL_PAGES))
+        _TICKS[cell] = eng, _lower_donated(
+            *eng._decode_program(), sharding).compile()
+    return _TICKS[cell]
+
+
+def test_jamba_decode_tick_two_layer_cut(one_chip):
+    """The hybrid model's decode tick at the cell's widths and 64 slots,
+    cut to one Mamba and one attention layer: both state arrays and both
+    pools are carried in place (aliased whole), and the tick's temporaries
+    stay under one layer's scan state."""
+    eng, compiled = _compiled_tick("jamba_cut", one_chip)
     assert eng.kv_path == "xla_gather"        # 1 KV head: no page shape
-    compiled = _lower_donated(*eng._decode_program(), one_chip).compile()
     mem = compiled.memory_analysis()
     caches = sum(a.size * a.dtype.itemsize for a in eng.cache.arrays())
     assert caches == eng.cache.nbytes
@@ -411,28 +434,46 @@ def _elements(shape_text):
                default=0)
 
 
+_LINE = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\((.*)$")
+_CALLED = re.compile(
+    r"\b(?:calls|to_apply|body|condition|branch_computations|"
+    r"true_computation|false_computation)=\{?(%[\w.-]+(?:, %[\w.-]+)*)")
+
+
+def _callees(rest):
+    """Computations an instruction's attributes name, in order."""
+    return [c.lstrip("%") for group in _CALLED.findall(rest)
+            for c in group.split(", ")]
+
+
+def _computations(hlo):
+    """A compiled module's text as {computation: [(name, result type,
+    opcode, rest of the line)]}, and the entry computation's name."""
+    bodies, current, entry = {}, None, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%(\S+) \(.*\) -> .* \{$", line)
+        if head:
+            current = head.group(2)
+            bodies[current] = []
+            if head.group(1):
+                entry = current
+            continue
+        m = _LINE.match(line)
+        if m and current is not None:
+            bodies[current].append(m.groups())
+    return bodies, entry
+
+
 def _pool_sized_moves(hlo, at_least):
     """Instructions of the compiled module that materialise an array of
     ``at_least`` elements or more by a copy, a convert, a dynamic-slice or
     a dynamic-update-slice: such an instruction on its own, or a fusion
     whose result is that large and whose body holds one that large (the
     body of a fusion with a small result materialises nothing)."""
-    line_re = re.compile(
-        r"^\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\((.*)$")
-    calls_re = re.compile(r"calls=%([\w.-]+)")
-    bodies, current, fused = {}, None, set()
-    for line in hlo.splitlines():
-        head = re.match(r"^(?:ENTRY )?%(\S+) \(.*\) -> .* \{$", line)
-        if head:
-            current = head.group(1)
-            bodies[current] = []
-            continue
-        m = line_re.match(line)
-        if m and current is not None:
-            name, shape, op, rest = m.groups()
-            bodies[current].append((name, shape, op, rest))
-            if op == "fusion":
-                fused.add(calls_re.search(rest).group(1))
+    bodies, _entry = _computations(hlo)
+    fused = {_callees(rest)[0]
+             for instrs in bodies.values()
+             for _n, _sh, op, rest in instrs if op == "fusion"}
 
     def big(body):
         return [f"{n} = {sh} {op}" for n, sh, op, _ in bodies.get(body, ())
@@ -445,7 +486,7 @@ def _pool_sized_moves(hlo, at_least):
         found += big(body)
         for name, shape, op, rest in instrs:
             if op == "fusion" and _elements(shape) >= at_least:
-                inner = big(calls_re.search(rest).group(1))
+                inner = big(_callees(rest)[0])
                 found += [f"{name} = {shape} fusion of {i}" for i in inner]
     return found
 
@@ -463,13 +504,11 @@ def test_paged_programs_touch_only_live_pages(one_chip, program):
     smaller than one pool (a scan's xs/ys held a second copy of both),
     and nothing of a layer's pool size is copied, converted, sliced out
     or written back."""
-    eng = _engine(CELL_L, max_seq=CELL_S, max_batch=CELL_B,
-                  kv_layout="paged", num_pages=CELL_PAGES)
-    if program == "decode":
-        fn, example = eng._decode_program()
-    else:
-        fn, example = eng._prefill_program(int(program.split("_b")[1]))
-    compiled = _lower_donated(fn, example, one_chip).compile()
+    eng, compiled = _compiled_tick("gpt_cell", one_chip)
+    if program != "decode":
+        compiled = _lower_donated(
+            *eng._prefill_program(int(program.split("_b")[1])),
+            one_chip).compile()
     pool = eng.cache.k.size * eng.cache.k.dtype.itemsize
     mem = compiled.memory_analysis()
     print(f"{program}: arguments {mem.argument_size_in_bytes / 2**20:.0f} "
@@ -481,3 +520,56 @@ def test_paged_programs_touch_only_live_pages(one_chip, program):
     moves = _pool_sized_moves(compiled.as_text(),
                               at_least=eng.cache.k[0].size)
     assert not moves, "\n".join(moves)
+
+
+def _reached(bodies, roots, through_conditionals):
+    """Computations reached from ``roots`` along the instructions' calls;
+    with ``through_conditionals`` false a ``conditional``'s branches are
+    not entered."""
+    seen, todo = set(), list(roots)
+    while todo:
+        body = todo.pop()
+        if body in seen:
+            continue
+        seen.add(body)
+        for _name, _shape, op, rest in bodies[body]:
+            if op != "conditional" or through_conditionals:
+                todo += _callees(rest)
+    return seen
+
+
+@pytest.mark.parametrize("cell", ["gpt_cell", "jamba_cut"])
+def test_decode_tick_sorts_the_vocabulary_under_a_conditional(one_chip,
+                                                              cell):
+    """The sampler's work is a branch of the tick, not a part of it
+    (serving/sampling.py): on the chip's own compile of the GPT cell's
+    tick (16 slots) and of the hybrid cell's (64 slots, vocabulary
+    65,536) every sort of ``[slots, vocabulary]`` lies in a computation
+    that only a ``conditional`` reaches (XLA may flatten a cond into a
+    select that computes every side: then the sort is back in every
+    tick), and the branch a greedy batch takes holds neither a sort nor
+    an exponential of that size."""
+    eng, compiled = _compiled_tick(cell, one_chip)
+    rows = eng.ecfg.max_batch * eng.cfg.vocab_size
+    bodies, entry = _computations(compiled.as_text())
+
+    def dear(body, ops):
+        return [f"{body}: {n} = {sh} {op}" for n, sh, op, _ in bodies[body]
+                if op in ops and _elements(sh) >= rows]
+
+    sorts = [body for body in bodies if dear(body, ("sort",))]
+    assert sorts, "no sort of the vocabulary anywhere: is the filtered " \
+                  "path still in the executable?"
+    always = _reached(bodies, [entry], through_conditionals=False)
+    assert not set(sorts) & always, sorts
+    switches = [
+        _callees(rest) for body in always
+        for _n, _sh, op, rest in bodies[body] if op == "conditional"
+        and set(sorts) & _reached(bodies, _callees(rest), True)]
+    (branches,) = switches            # the one switch that holds the sort
+    assert len(branches) == 3         # greedy, temperature, filtered
+    greedy, temperature, filtered = (
+        _reached(bodies, [b], True) for b in branches)
+    for body in greedy:
+        assert not dear(body, ("sort", "exponential"))
+    assert not set(sorts) & temperature and set(sorts) <= filtered
