@@ -381,8 +381,7 @@ def main():
     else:
         timed("train", phase_train, cfg, 16, 1024, 5, args.seed, devs[0])
         timed("serve", phase_serve, cfg,
-              dict(max_seq=1024, max_batch=8, kv_layout="paged",
-                   weight_dtype="bf16"),
+              dict(max_seq=1024, max_batch=8, weight_dtype="bf16"),
               (300, 700, 64, 128, 411, 650), 256, 32, 32, args.seed,
               devs[0])
         timed("fluid", phase_fluid, 128, 224, 3, devs[0])

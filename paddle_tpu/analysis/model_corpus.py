@@ -221,7 +221,7 @@ def build_serving_decode() -> ModelProgram:
     """The serving decode program shape: one token per slot over a static
     [max_batch] layout plus a fixed-shape cache feed that is shifted
     ring-buffer style and fetched back — the IR-level model of the
-    donate-in/donate-out KV slabs. Donation + recompile_risk are the
+    donate-in/donate-out KV pools. Donation + recompile_risk are the
     checkers this program exists for: fixed shapes end to end, no
     persistable writes, the updated cache is an explicit fetch."""
     def b(fluid):
@@ -232,7 +232,7 @@ def build_serving_decode() -> ModelProgram:
                                   append_batch_size=False)
         emb = fluid.layers.embedding(tok, size=[V, D],
                                      param_attr=fluid.ParamAttr("srv_wte2"))
-        # ring shift: drop the oldest cache row, append this token's slab
+        # ring shift: drop the oldest cache row, append this token's row
         tail = fluid.layers.slice(cache, axes=[1], starts=[1], ends=[S])
         new_cache = fluid.layers.concat([tail, emb], axis=1)
         pooled = fluid.layers.reduce_mean(new_cache, dim=1)    # [B, D]
@@ -269,7 +269,7 @@ def build_serving_prefill_tp2() -> ModelProgram:
 def build_serving_decode_tp2() -> ModelProgram:
     """The serving decode shape with the KV-HEAD SPLIT the tp engine
     runs: the cache feed is [B, S, nh, hd] annotated ``tp`` on the head
-    dim (exactly how the engine shards its slab/pool at dim 3), the
+    dim (exactly how the engine shards its pool at dim 3), the
     up-projection is column-split, the logits head row-split. The
     sharding checker must see the head split ride through the ring
     shift (slice+concat) and the pooled reduction with zero errors."""
@@ -286,7 +286,7 @@ def build_serving_decode_tp2() -> ModelProgram:
         h = fluid.layers.fc(emb, D, num_flatten_dims=2)     # column-par
         hr = fluid.layers.reshape(h, [B, 1, NH, HD])
         # ring shift on the head-split cache: drop the oldest row,
-        # append this token's head-split slab
+        # append this token's head-split row
         tail = fluid.layers.slice(cache, axes=[1], starts=[1], ends=[S])
         new_cache = fluid.layers.concat([tail, hr], axis=1)
         pooled = fluid.layers.reduce_mean(new_cache, dim=1)  # [B,NH,HD]

@@ -194,8 +194,7 @@ def _collect_comm(doc: Dict[str, Any], metrics, ctx) -> None:
 
 
 def _lane_key(lane: Dict[str, Any]) -> str:
-    parts = [str(lane.get("weight_dtype", "?")),
-             str(lane.get("kv_layout", "?"))]
+    parts = [str(lane.get("weight_dtype", "?"))]
     if lane.get("sharding"):
         parts.append(f"tp{lane.get('tp')}")
     if lane.get("spec"):

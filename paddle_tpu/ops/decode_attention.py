@@ -20,44 +20,11 @@ from __future__ import annotations
 import math
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
-__all__ = ["decode_attention", "cache_update", "prefill_attention",
-           "paged_gather", "paged_cache_update", "paged_page_write",
-           "paged_prefill_attention", "window_attention",
-           "window_cache_update"]
-
-
-def cache_update(cache, new, positions, active=None):
-    """Write one new per-sequence row into the cache at ``positions``.
-
-    cache:     [B, S, nh, hd]  (one layer's K or V slab, slot-major)
-    new:       [B, nh, hd]     (this step's projection per sequence)
-    positions: [B] int32       (write index per slot; traced, not static)
-    active:    [B] bool-ish    (optional write mask: inactive lanes keep
-                                the row that was already there — a LIVE
-                                slot riding a partial batch as a masked
-                                lane must not have its row 0 clobbered)
-
-    Returns the updated cache. A per-slot ``dynamic_update_slice`` under
-    ``vmap`` lowers to one scatter — fixed shapes, so donation makes it an
-    in-place HBM write on TPU.
-    """
-    if active is None:
-
-        def upd(c, n, p):
-            return jax.lax.dynamic_update_slice(c, n[None], (p, 0, 0))
-
-        return jax.vmap(upd)(cache, new.astype(cache.dtype), positions)
-
-    def upd_masked(c, n, p, a):
-        cur = jax.lax.dynamic_slice(c, (p, 0, 0), (1,) + c.shape[1:])
-        val = jnp.where(a != 0, n[None].astype(c.dtype), cur)
-        return jax.lax.dynamic_update_slice(c, val, (p, 0, 0))
-
-    return jax.vmap(upd_masked)(cache, new.astype(cache.dtype),
-                                positions, active)
+__all__ = ["decode_attention", "prefill_attention", "paged_gather",
+           "paged_cache_update", "paged_page_write",
+           "paged_prefill_attention", "window_attention"]
 
 
 def decode_attention(q, k_cache, v_cache, lengths,
@@ -69,7 +36,8 @@ def decode_attention(q, k_cache, v_cache, lengths,
     v_cache:  [B, S, nh, hd]
     lengths:  [B] int32       — valid prefix length per slot, INCLUDING the
                                 current token (callers run
-                                :func:`cache_update` first)
+                                :func:`paged_cache_update` first and
+                                hand in the :func:`paged_gather` view)
 
     Returns [B, nh, hd]. Scores are computed in f32 regardless of the
     cache dtype (softmax stability at bf16 caches), positions >= length are
@@ -220,34 +188,6 @@ def paged_prefill_attention(q, k_all, v_all, prefix_len,
     probs = e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
     out = jnp.einsum("bnqk,bknh->bqnh", probs, v_all.astype(jnp.float32))
     return out.astype(q.dtype)
-
-
-def window_cache_update(cache, new, starts, active=None):
-    """Write a W-token window per sequence into the slab cache.
-
-    cache:  [B, S, nh, hd]
-    new:    [B, W, nh, hd]   (the speculative-verify window's K or V)
-    starts: [B] int32        (first write position per slot)
-    active: [B] bool-ish     (optional write mask, as in
-                              :func:`cache_update`)
-
-    The window is contiguous, so one per-slot ``dynamic_update_slice``
-    under vmap covers it (the W=1 case reduces to :func:`cache_update`)."""
-    if active is None:
-
-        def upd(c, n, s):
-            return jax.lax.dynamic_update_slice(c, n, (s, 0, 0))
-
-        return jax.vmap(upd)(cache, new.astype(cache.dtype), starts)
-
-    def upd_masked(c, n, s, a):
-        cur = jax.lax.dynamic_slice(
-            c, (s, 0, 0), (n.shape[0],) + c.shape[1:])
-        val = jnp.where(a != 0, n.astype(c.dtype), cur)
-        return jax.lax.dynamic_update_slice(c, val, (s, 0, 0))
-
-    return jax.vmap(upd_masked)(cache, new.astype(cache.dtype), starts,
-                                active)
 
 
 def window_attention(q, k_cache, v_cache, starts,

@@ -1341,26 +1341,25 @@ def use_opt_megakernel(override=None) -> bool:
 # ---------------------------------------------------------------------------
 #
 # ATTRIBUTION_DECODE.json ranks the decode tick's residue: per layer, the
-# cache row scatter (cache_update / paged_cache_update), the paged-view
-# gather, and the masked one-token softmax each lower as separate
-# fusions with their own HBM round trips over the [B, S, nh, hd] slabs.
-# These kernels collapse a decode tick to one launch per layer (slab:
-# write-guarded row update + masked attention; paged: attention read
-# through the page table, the row write a scatter before it) plus one
-# launch for the final layernorm + LM-head projection. The slab kernel
-# and the logits head sit behind EngineConfig(fused_decode=True); the
-# paged kernel is what a paged engine runs on a TPU (engine.kv_path).
+# cache row scatter (paged_cache_update), the paged-view gather, and the
+# masked one-token softmax each lower as separate fusions with their own
+# HBM round trips over the gathered [B, S, nh, hd] views. These kernels
+# collapse a decode tick to one launch per layer (attention read through
+# the page table, the row write a scatter before it) plus one launch for
+# the final layernorm + LM-head projection. The logits head sits behind
+# EngineConfig(fused_decode=True); the paged kernel is what the engine
+# runs on a TPU (engine.kv_path).
 
 
 # Block shapes the chip's compiler takes (tests/test_chip_compile.py): the
 # caches are [.., rows, nh, hd], so a block keeps ALL heads — its last two
 # dims are then the array's own, which Mosaic accepts at any nh/hd — and
-# the row axis is cut into chunks: along the grid's last, sequential axis
-# (slab) or by the kernel's own copies of the live pages (paged), with
-# flash-decoding's running max / sum / accumulator in VMEM scratch. One
-# query row per head makes the matmuls M=1, so scores and the weighted
-# sum run on the VPU as broadcast multiply + reduce in exact f32, heads
-# on sublanes and hd on lanes — no in-kernel transpose of the head axis.
+# the row axis is cut into chunks by the kernel's own copies of the live
+# pages, with flash-decoding's running max / sum / accumulator in VMEM
+# scratch. One query row per head makes the matmuls M=1, so scores and
+# the weighted sum run on the VPU as broadcast multiply + reduce in exact
+# f32, heads on sublanes and hd on lanes — no in-kernel transpose of the
+# head axis.
 
 
 def _fold_rows(qf, kf, vf, pos, c, rows, m_scr, l_scr, acc_scr, *,
@@ -1391,24 +1390,6 @@ def _fold_rows(qf, kf, vf, pos, c, rows, m_scr, l_scr, acc_scr, *,
     m_scr[...] = m_new
 
 
-def _decode_chunk(q_ref, k_ref, v_ref, nk, nv, pos, sub, c, chunk,
-                  m_scr, l_scr, acc_scr, *, sm_scale):
-    """Fold cache rows [c*chunk, (c+1)*chunk) of one slab slot into the
-    running softmax. ``sub`` (scalar bool) substitutes row ``pos`` with
-    the new token's (nk, nv) — already rounded through the cache dtype, so
-    attention sees exactly the row value that lands in the cache."""
-    @pl.when(c * chunk <= pos)        # later chunks are fully masked
-    def _fold():
-        kf = k_ref[...].astype(jnp.float32)              # (chunk, nh, hd)
-        vf = v_ref[...].astype(jnp.float32)
-        rows = c * chunk + jax.lax.broadcasted_iota(jnp.int32, kf.shape, 0)
-        sel = jnp.logical_and(rows == pos, sub)
-        kf = jnp.where(sel, nk[None], kf)
-        vf = jnp.where(sel, nv[None], vf)
-        _fold_rows(q_ref[...].astype(jnp.float32), kf, vf, pos, c, chunk,
-                   m_scr, l_scr, acc_scr, sm_scale=sm_scale)
-
-
 def _decode_finish(o_ref, l_scr, acc_scr):
     o_ref[...] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
                   ).astype(o_ref.dtype)
@@ -1418,86 +1399,6 @@ def _decode_scratch(nh, hd):
     return [pltpu.VMEM((nh, 1), jnp.float32),
             pltpu.VMEM((nh, 1), jnp.float32),
             pltpu.VMEM((nh, hd), jnp.float32)]
-
-
-def _decode_slab_kernel(pos_ref, act_ref, q_ref, k_ref, v_ref, nk_ref,
-                        nv_ref, o_ref, ko_ref, vo_ref, m_scr, l_scr,
-                        acc_scr, *, sm_scale, chunk, num_chunks):
-    b = pl.program_id(0)
-    c = pl.program_id(1)
-    pos = pos_ref[b]
-    act = act_ref[b] != 0
-    nk = nk_ref[...].astype(k_ref.dtype)                 # (nh, hd)
-    nv = nv_ref[...].astype(v_ref.dtype)
-
-    @pl.when(c == pos // chunk)
-    def _write_row():
-        # write-guard: inactive lanes keep the row that was already there
-        # (cache_update's masked-lane semantics). The (1, nh, hd) out block
-        # sits at row pos for every c and is flushed when b advances.
-        r = pos - c * chunk
-        ko_ref[...] = jnp.where(act, nk[None], k_ref[pl.ds(r, 1)])
-        vo_ref[...] = jnp.where(act, nv[None], v_ref[pl.ds(r, 1)])
-
-    _decode_chunk(q_ref, k_ref, v_ref, nk.astype(jnp.float32),
-                  nv.astype(jnp.float32), pos, act, c, chunk,
-                  m_scr, l_scr, acc_scr, sm_scale=sm_scale)
-
-    @pl.when(c == num_chunks - 1)
-    def _finish():
-        _decode_finish(o_ref, l_scr, acc_scr)
-
-
-def fused_decode_attention(q, k_cache, v_cache, new_k, new_v, positions,
-                           active=None, sm_scale=None):
-    """One-launch slab decode tick: write-guarded cache row update +
-    masked one-token attention — replaces cache_update (x2) +
-    decode_attention per layer when ``EngineConfig.fused_decode``.
-
-    q/new_k/new_v: [B, nh, hd]; k_cache/v_cache: [B, S, nh, hd];
-    positions: [B] int32 in [0, S) (write row; attention covers
-    positions+1 rows — the engine's lengths); active: [B] optional write
-    mask — inactive lanes keep their cached row (the masked-lane
-    no-write guard).
-
-    Returns (out [B, nh, hd], k_cache', v_cache'); the caches are
-    aliased in place — only row positions[b] of slot b is touched.
-    """
-    B, S, nh, hd = k_cache.shape
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    if active is None:
-        active = jnp.ones((B,), jnp.int32)
-    _count_launch("decode_slab")
-    chunk = next((c for c in (256, 128, 64, 32, 16, 8) if S % c == 0), S)
-    nc = S // chunk
-    row3 = pl.BlockSpec((None, nh, hd), lambda b, c, p, a: (b, 0, 0))
-    slab = pl.BlockSpec((None, chunk, nh, hd),
-                        lambda b, c, p, a: (b, c, 0, 0))
-    row4 = pl.BlockSpec((None, 1, nh, hd),
-                        lambda b, c, p, a: (b, p[b], 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(B, nc),
-        in_specs=[row3, slab, slab, row3, row3],
-        out_specs=[row3, row4, row4],
-        scratch_shapes=_decode_scratch(nh, hd))
-    with jax.named_scope("fused_decode_attention"):
-        o, kc, vc = pl.pallas_call(
-            functools.partial(_decode_slab_kernel, sm_scale=sm_scale,
-                              chunk=chunk, num_chunks=nc),
-            grid_spec=grid_spec,
-            out_shape=[
-                jax.ShapeDtypeStruct((B, nh, hd), q.dtype),
-                jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
-                jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype),
-            ],
-            input_output_aliases={3: 1, 4: 2},
-            compiler_params=_CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
-            interpret=_interpret(),
-        )(positions.astype(jnp.int32), active.astype(jnp.int32),
-          q, k_cache, v_cache, new_k, new_v)
-    return o, kc, vc
 
 
 # the paged kernel reads the pool where it lies: the whole

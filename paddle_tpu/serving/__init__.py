@@ -2,7 +2,7 @@
 
 The production-inference half of the north star: AOT-compiled prefill
 (shape-bucketed ladder) and decode (static ``[max_batch]`` slot batch)
-executables over a preallocated, donated KV cache; continuous / in-flight
+executables over a preallocated, donated paged KV cache; continuous / in-flight
 batching at token boundaries; int8/bf16 serving weights through the
 comm_opt chunk-scaled quantizer; an HTTP front door with admission
 control, deadlines, backpressure and graceful drain. Steady state is
@@ -29,8 +29,8 @@ from .engine import (  # noqa: F401
     PromptTooLongError,
     default_bucket_ladder,
 )
-from .kv_cache import CacheFullError, KVCache  # noqa: F401
 from .paged_kv import (  # noqa: F401
+    CacheFullError,
     PagedKVCache,
     PagePoolFullError,
     PrefixCache,
@@ -73,7 +73,7 @@ from .disagg import (  # noqa: F401
 
 __all__ = [
     "DecodeEngine", "EngineConfig", "PromptTooLongError",
-    "default_bucket_ladder", "KVCache", "CacheFullError",
+    "default_bucket_ladder", "CacheFullError",
     "PagedKVCache", "PrefixCache", "PagePoolFullError",
     "SamplingParams", "GREEDY", "SpecDecodeEngine", "SpecStats",
     "quantize_params", "dequantize_params", "logit_error_stats",

@@ -132,8 +132,7 @@ class LocalReplica:
         self.name = name or f"{self.role}-{id(engine) & 0xffff:x}"
         self.scheduler = Scheduler(engine, scfg)
         self.prefix_index = prefix_index
-        if (prefix_index is not None and getattr(engine, "paged", False)
-                and engine.prefix is not None
+        if (prefix_index is not None and engine.prefix is not None
                 and engine.prefix_store is None):
             engine.prefix_store = prefix_index.binding(self.role)
         self.loop = EngineLoop(self.scheduler).start()

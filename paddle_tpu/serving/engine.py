@@ -1,5 +1,5 @@
 """TPU-native decode engine: AOT prefill/decode executables over a
-preallocated KV cache (docs/serving.md).
+preallocated paged KV cache (docs/serving.md).
 
 The training side already proved the ingredients — PR 1's cached dispatch,
 PR 4's explicit ``lower()+compile()`` AOT executables and recompile
@@ -14,12 +14,11 @@ module assembles them into the serving shape:
   state, never a shape. After :meth:`DecodeEngine.warmup`, steady-state
   serving performs zero compiles; ``paddle_recompiles_total`` is the
   guardrail.
-- **KV cache as carried state.** Slab layout
-  (``[L, max_batch, max_seq, nh, hd]``, PR 9) or the paged layout
+- **KV cache as carried state.** One layout, the paged one
   (``serving/paged_kv.py``: a ``[L, num_pages, page_size, nh, hd]`` pool
-  + per-slot page tables fed as device arrays, prefix-cache capable).
-  Both are threaded through every executable with buffer donation on TPU.
-  The paged programs carry both pools through the layer loop in place
+  + per-slot page tables fed as device arrays, prefix-cache capable),
+  threaded through every executable with buffer donation on TPU. The
+  programs carry both pools through the layer loop in place
   (``_layers_over_pools``) and touch only the rows and pages they index;
   on a TPU the decode tick reads the live pages through the page table
   in a Pallas kernel (``kv_path``).
@@ -41,11 +40,11 @@ module assembles them into the serving shape:
   take f32/bf16.)
 
 The engine is single-threaded by contract: exactly one scheduler loop
-calls it (serving/scheduler.py). The paged prefill and decode programs
-take their layers from a *model description* (``serving/model.py``: the
-GPT block, or ``models/jamba.py``'s hybrid of Mamba and attention layers
-with per-slot recurrent state); the slab and verify programs are still
-written for the GPT block (ROADMAP D2).
+calls it (serving/scheduler.py). The prefill and decode programs take
+their layers from a *model description* (``serving/model.py``: the GPT
+block, or ``models/jamba.py``'s hybrid of Mamba and attention layers
+with per-slot recurrent state); the verify program is still written for
+the GPT block (ROADMAP D2).
 """
 from __future__ import annotations
 
@@ -62,14 +61,11 @@ from ..models import gpt as gpt_mod
 from ..observability import program_report as _prep
 from ..observability import spans as _spans
 from ..ops import pallas_kernels as _pk
-from ..ops.decode_attention import (cache_update, decode_attention,
-                                    paged_cache_update, paged_gather,
-                                    prefill_attention, window_attention,
-                                    window_cache_update)
+from ..ops.decode_attention import (paged_cache_update, paged_gather,
+                                    window_attention)
 from . import metrics as smetrics
 from . import model as _model
 from . import sampling as samp
-from .kv_cache import KVCache
 from .model import (block_tail as _block_tail, embed_rows as _embed_rows,
                     layers_over_pools as _layers_over_pools)
 from .paged_kv import PagedKVCache, PagePoolFullError, PrefixCache
@@ -118,11 +114,15 @@ class EngineConfig:
     quant_chunk: int = 256           # int8 scale granularity
     cache_dtype: Any = None          # None -> the model's compute dtype
     eos_id: Optional[int] = None     # greedy decode stops on this token
-    # -- KV layout (docs/serving.md "Paged KV") -------------------------
-    kv_layout: str = "slab"          # "slab" | "paged"
+    # -- paged KV (docs/serving.md "Paged KV") --------------------------
+    # One value, "paged": the field is still here only because the
+    # benchmark's configurations and its harness test pass it whole into
+    # EngineConfig (ROADMAP D13); anything else is a ValueError.
+    kv_layout: str = "paged"
     page_size: int = 16              # tokens per page (divides buckets)
-    num_pages: int = 0               # 0 -> slab-parity pool (+1 scratch)
-    prefix_cache: bool = True        # token-hash prefix cache (paged only)
+    num_pages: int = 0               # 0 -> max_batch * max_seq / page_size
+                                     #      pages (+1 scratch)
+    prefix_cache: bool = True        # token-hash prefix cache
     prefix_cache_pages: int = 0      # 0 -> bounded by the pool itself
     # -- tensor parallelism over PR 12's sharding layer -----------------
     sharding: Optional[str] = None   # None | "tp"
@@ -136,17 +136,15 @@ class EngineConfig:
     # -- fused decode step (ops/pallas_kernels.py, docs/kernels.md) -----
     # Pallas launches in place of the decode tick's small-fusion residue
     # ranked by ATTRIBUTION_DECODE.json: fused_ln for the tick's
-    # layernorms, one launch for the final layernorm + LM-head
-    # projection, and on a slab one launch a layer for the write-guarded
-    # cache-row write + masked one-token attention. Opt-in:
-    # interpret-mode Pallas is slower than XLA off-TPU and no cell has
-    # measured these on the chip. NOT a switch of the paged attention:
-    # a paged engine on a TPU reads its cache through the page-table
-    # kernel either way (``DecodeEngine.kv_path``, decided from platform
-    # and layout); off the TPU this flag also asks for that kernel, in
-    # interpret mode, which is how the CPU lane drives it. Masked-lane /
-    # scratch-page write-guard semantics are preserved
-    # (tests/test_pallas_fused.py).
+    # layernorms and one launch for the final layernorm + LM-head
+    # projection. Opt-in: interpret-mode Pallas is slower than XLA
+    # off-TPU and no cell has measured these on the chip. NOT a switch
+    # of the paged attention: an engine on a TPU reads its cache through
+    # the page-table kernel either way (``DecodeEngine.kv_path``,
+    # decided from platform and mesh); off the TPU this flag also asks
+    # for that kernel, in interpret mode, which is how the CPU lane
+    # drives it. Masked-lane / scratch-page write-guard semantics are
+    # preserved (tests/test_pallas_fused.py).
     fused_decode: bool = False
 
     def resolved_buckets(self) -> Tuple[int, ...]:
@@ -177,16 +175,14 @@ class DecodeEngine:
         if self.model.recurrent:
             self._refuse_what_cannot_carry_state(ecfg)
         self.buckets = ecfg.resolved_buckets()
-        self.paged = ecfg.kv_layout == "paged"
-        if ecfg.kv_layout not in ("slab", "paged"):
+        if ecfg.kv_layout != "paged":
             raise ValueError(f"kv_layout {ecfg.kv_layout!r}: "
-                             "expected 'slab' or 'paged'")
-        if self.paged:
-            bad = [b for b in self.buckets if b % ecfg.page_size]
-            if bad:
-                raise ValueError(
-                    f"paged engine: prefill buckets {bad} are not "
-                    f"multiples of page_size {ecfg.page_size}")
+                             "expected 'paged'")
+        bad = [b for b in self.buckets if b % ecfg.page_size]
+        if bad:
+            raise ValueError(
+                f"paged engine: prefill buckets {bad} are not "
+                f"multiples of page_size {ecfg.page_size}")
         if ecfg.role not in ("prefill", "decode", "colocated"):
             raise ValueError(f"role {ecfg.role!r}: expected 'prefill', "
                              "'decode' or 'colocated'")
@@ -209,37 +205,28 @@ class DecodeEngine:
         self.weight_nbytes = quantized_nbytes(self.qparams)
         cache_dtype = ecfg.cache_dtype or cfg.dtype
         kv_layers, kv_heads, kv_head_dim = self.model.kv_geometry
-        if self.paged:
-            # one manager for both kinds of cache: pages for the attention
-            # layers, a state row a slot for the recurrent ones
-            self.cache = PagedKVCache(
-                kv_layers, ecfg.max_batch, ecfg.max_seq,
-                kv_heads, kv_head_dim, dtype=cache_dtype,
-                page_size=ecfg.page_size, num_pages=ecfg.num_pages,
-                state=self.model.state_geometry)
-            self.prefix = (PrefixCache(self.cache,
-                                       ecfg.prefix_cache_pages)
-                           if ecfg.prefix_cache else None)
-            if self.prefix is not None:
-                self.cache.reclaimer = self.prefix.reclaim
-        else:
-            self.cache = KVCache(kv_layers, ecfg.max_batch,
-                                 ecfg.max_seq, kv_heads, kv_head_dim,
-                                 dtype=cache_dtype)
-            self.prefix = None
+        # one manager for both kinds of cache: pages for the attention
+        # layers, a state row a slot for the recurrent ones
+        self.cache = PagedKVCache(
+            kv_layers, ecfg.max_batch, ecfg.max_seq,
+            kv_heads, kv_head_dim, dtype=cache_dtype,
+            page_size=ecfg.page_size, num_pages=ecfg.num_pages,
+            state=self.model.state_geometry)
+        self.prefix = (PrefixCache(self.cache, ecfg.prefix_cache_pages)
+                       if ecfg.prefix_cache else None)
+        # pool pressure reclaims the pages only the prefix cache holds
+        self.cache.prefix_cache = self.prefix
         if self._cache_sh is not None:
             self.cache.k = jax.device_put(self.cache.k, self._cache_sh)
             self.cache.v = jax.device_put(self.cache.v, self._cache_sh)
-        # how the decode tick reads the cache (docs/serving.md): a paged
-        # engine on a TPU reads the live pages through the page table in
-        # a Pallas kernel, where Mosaic takes its page shape; under a
-        # mesh (a Pallas call there needs shard_map) and off the TPU
+        # how the decode tick reads the cache (docs/serving.md): on a TPU
+        # it reads the live pages through the page table in a Pallas
+        # kernel, where Mosaic takes its page shape; under a mesh (a
+        # Pallas call there needs shard_map) and off the TPU
         # (interpret-mode Pallas is slower than XLA) it gathers the
         # padded view. Off the TPU fused_decode asks for the kernel all
         # the same: the CPU lane's way to drive it.
-        if not self.paged:
-            self.kv_path = "slab"
-        elif self._mesh is not None:
+        if self._mesh is not None:
             self.kv_path = "xla_gather"
         elif self.model.paged_kernel and (
                 _pk.paged_decode_tiles(kv_heads, kv_head_dim)
@@ -259,7 +246,7 @@ class DecodeEngine:
         # are proposals, not served output
         self.meter_tokens = True
         # set when an executable fails AFTER its cache buffers were donated
-        # (the slabs are invalidated by donation, so no later call can be
+        # (the pools are invalidated by donation, so no later call can be
         # trusted) — every serving entrypoint refuses from then on
         self.poisoned: Optional[str] = None
         # Scheduler.steps of the step now running, stamped by the
@@ -281,10 +268,6 @@ class DecodeEngine:
                 f"{type(self.model).__name__} has recurrent layers: "
                 f"{mechanism} cannot carry recurrent state ({why})")
 
-        if ecfg.kv_layout != "paged":
-            refuse("the slab KV layout (kv_layout='slab')",
-                   "per-slot state lives in the paged manager; use "
-                   "kv_layout='paged'")
         if ecfg.prefix_cache:
             refuse("the prefix cache (prefix_cache=True)",
                    "a cached page holds keys and values, not the state at "
@@ -316,9 +299,8 @@ class DecodeEngine:
         store's committed prefix records into the pool + prefix cache
         NOW (call before :meth:`warmup`), and persist every later
         publish through it. Returns how many records were restored."""
-        if not self.paged or self.prefix is None:
-            raise ValueError("prefix store needs kv_layout='paged' with "
-                             "prefix_cache enabled")
+        if self.prefix is None:
+            raise ValueError("prefix store needs prefix_cache enabled")
         self.prefix_store = store
         return store.restore_into(self)
 
@@ -378,8 +360,7 @@ class DecodeEngine:
         specs, self.tp_derived = complete_pytree_specs(
             qparams, ann, {"tp": tp})
         self._param_sh = named_sharding_tree(specs, self._mesh)
-        # slab [L, B, S, nh, hd] and pool [L, P, page, nh, hd] both carry
-        # the KV head axis at dim 3 — one spec serves either layout
+        # the pool [L, P, page, nh, hd] carries the KV head axis at dim 3
         self._cache_sh = NamedSharding(
             self._mesh, P(None, None, None, "tp", None))
         self._repl_sh = NamedSharding(self._mesh, P())
@@ -389,49 +370,6 @@ class DecodeEngine:
     # ------------------------------------------------------------------
     def _dequant(self, qparams):
         return dequantize_params(qparams)
-
-    def _prefill_fn(self, qparams, ck, cv, tokens, length, slot,
-                    temp, top_k, top_p, seed):
-        """tokens [1, T] int32, length/slot + sampling scalars ->
-        (ck, cv, logits[V], token).
-
-        Runs the full causal forward over the padded bucket, writes the
-        per-layer K/V for positions [0, T) into the cache at ``slot``
-        (padding rows land too, but the length mask keeps decode from ever
-        reading them), and returns the logits of the LAST VALID position
-        plus the token sampled from them — the first generated token comes
-        straight out of prefill."""
-        cfg = self.cfg
-        params = self._dequant(qparams)
-        dt = cfg.dtype
-        ln = gpt_mod._layer_norm
-        x = gpt_mod.embed(params, tokens, cfg)          # [1, T, D]
-
-        def body(h, layer_p):
-            h1 = ln(h, layer_p["ln1_scale"], layer_p["ln1_bias"])
-            qkv = jnp.einsum("btd,dcnh->btcnh", h1,
-                             layer_p["w_qkv"].astype(dt))
-            qkv = qkv + layer_p["b_qkv"].astype(dt)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            a = prefill_attention(q, k, v)
-            h = _block_tail(h, a, layer_p, dt, ln, "bt")
-            return h, (k, v)
-
-        x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
-        # ks: [L, 1, T, nh, hd] -> cache slab write at (slot, 0..T)
-        ck = jax.lax.dynamic_update_slice(
-            ck, ks.astype(ck.dtype), (0, slot, 0, 0, 0))
-        cv = jax.lax.dynamic_update_slice(
-            cv, vs.astype(cv.dtype), (0, slot, 0, 0, 0))
-        h_last = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0,
-                                              keepdims=False)      # [D]
-        h_last = ln(h_last, params["ln_f_scale"], params["ln_f_bias"])
-        logits = jnp.einsum("d,dv->v", h_last,
-                            params["lm_head"].astype(dt))
-        logits = logits.astype(jnp.float32)
-        tok = samp.sample_token(logits, temp, top_k, top_p, seed,
-                                length - 1)
-        return ck, cv, logits, tok
 
     def _prefill_fn_paged(self, qparams, caches, tokens, length,
                           prefix_len, table_row, slot, temp, top_k, top_p,
@@ -458,62 +396,13 @@ class DecodeEngine:
                                 prefix_len + length - 1)
         return caches, logits, tok
 
-    def _decode_fn(self, qparams, ck, cv, tokens, positions, actives,
-                   temps, top_ks, top_ps, seeds):
-        """tokens/positions/actives/sampling [max_batch] -> (ck, cv,
-        logits[B, V], tokens[B]).
-
-        One token per slot: write this step's K/V at ``positions``, attend
-        over each slot's valid prefix (positions+1), emit next-token
-        logits plus the per-slot sampled (or argmax) next token. Lanes
-        with ``actives == 0`` ride along shape-stable but write NOTHING —
-        a live slot excluded from a partial feed (the spec draft's
-        catch-up rounds) keeps every cached row intact."""
-        cfg = self.cfg
-        params = self._dequant(qparams)
-        dt = cfg.dtype
-        fused = self.ecfg.fused_decode
-        ln = _model.decode_ln(fused)
-        x = _embed_rows(qparams, tokens, positions, dt)
-
-        def body(h, xs):
-            layer_p, ck_l, cv_l = xs
-            h1 = ln(h, layer_p["ln1_scale"], layer_p["ln1_bias"])
-            qkv = jnp.einsum("bd,dcnh->bcnh", h1,
-                             layer_p["w_qkv"].astype(dt))
-            qkv = qkv + layer_p["b_qkv"].astype(dt)
-            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]      # [B, nh, hd]
-            if fused:
-                # one launch: write-guarded row update + masked attention
-                a, ck_l, cv_l = _pk.fused_decode_attention(
-                    q, ck_l, cv_l, k, v, positions, active=actives)
-            else:
-                ck_l = cache_update(ck_l, k, positions, active=actives)
-                cv_l = cache_update(cv_l, v, positions, active=actives)
-                a = decode_attention(q, ck_l, cv_l, positions + 1)
-            h = _block_tail(h, a, layer_p, dt, ln, "b")
-            return h, (ck_l, cv_l)
-
-        x, (ck, cv) = jax.lax.scan(body, x,
-                                   (params["blocks"], ck, cv))
-        if fused:
-            logits = _pk.fused_logits_head(
-                x, params["ln_f_scale"], params["ln_f_bias"],
-                params["lm_head"].astype(dt))
-        else:
-            x = ln(x, params["ln_f_scale"], params["ln_f_bias"])
-            logits = jnp.einsum("bd,dv->bv", x,
-                                params["lm_head"].astype(dt))
-        logits = logits.astype(jnp.float32)
-        toks = samp.sample_batch(logits, temps, top_ks, top_ps, seeds,
-                                 positions)
-        return ck, cv, logits, toks
-
     def _decode_fn_paged(self, qparams, caches, tokens, positions,
                          tables, actives, temps, top_ks, top_ps, seeds):
-        """Paged twin of :meth:`_decode_fn`, the layers the model's own
-        (``serving/model.py``). Per-slot page tables [B, max_pages] route
-        the one-row write and the attention read through the shared pool;
+        """tokens/positions/actives/sampling [max_batch] -> (caches,
+        logits[B, V], tokens[B]): one token per slot, the layers the
+        model's own (``serving/model.py``). Per-slot page tables
+        [B, max_pages] route the one-row write and the attention read
+        through the shared pool;
         lanes that do not ride have an all-zero table row (their write
         lands on the scratch page) and ``actives`` 0 (a recurrent model
         leaves their state as it is)."""
@@ -527,44 +416,6 @@ class DecodeEngine:
         toks = samp.sample_batch(logits, temps, top_ks, top_ps, seeds,
                                  positions)
         return caches, logits, toks
-
-    def _verify_fn(self, qparams, ck, cv, tokens, starts, actives,
-                   temps, top_ks, top_ps, seeds):
-        """Speculative-verify window: tokens [B, W] written at positions
-        ``starts + w``, causal window attention over the cache, logits
-        AND per-position sampled target tokens for every window slot in
-        one batched call (docs/serving.md "Speculative decoding")."""
-        cfg = self.cfg
-        params = self._dequant(qparams)
-        dt = cfg.dtype
-        ln = gpt_mod._layer_norm
-        W = tokens.shape[1]
-        positions = starts[:, None] + jnp.arange(W)      # [B, W]
-        x = _embed_rows(qparams, tokens, positions, dt)
-
-        def body(h, xs):
-            layer_p, ck_l, cv_l = xs
-            h1 = ln(h, layer_p["ln1_scale"], layer_p["ln1_bias"])
-            qkv = jnp.einsum("bwd,dcnh->bwcnh", h1,
-                             layer_p["w_qkv"].astype(dt))
-            qkv = qkv + layer_p["b_qkv"].astype(dt)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            ck_l = window_cache_update(ck_l, k, starts,
-                                       active=actives)
-            cv_l = window_cache_update(cv_l, v, starts,
-                                       active=actives)
-            a = window_attention(q, ck_l, cv_l, starts)
-            h = _block_tail(h, a, layer_p, dt, ln, "bw")
-            return h, (ck_l, cv_l)
-
-        x, (ck, cv) = jax.lax.scan(body, x, (params["blocks"], ck, cv))
-        x = ln(x, params["ln_f_scale"], params["ln_f_bias"])
-        logits = jnp.einsum("bwd,dv->bwv", x,
-                            params["lm_head"].astype(dt))
-        logits = logits.astype(jnp.float32)
-        toks = samp.sample_window(logits, temps, top_ks, top_ps, seeds,
-                                  positions)
-        return ck, cv, logits, toks
 
     def _verify_fn_paged(self, qparams, kp, vp, tokens, starts, tables,
                          temps, top_ks, top_ps, seeds):
@@ -617,27 +468,23 @@ class DecodeEngine:
                      str(jnp.result_type(a))) for i, a in enumerate(leaves)]
         return _prep.make_sig(feed_sig, fetch_names=())
 
-    def _shardings_for(self, example_args, n_outputs: int):
+    def _shardings_for(self, example_args):
         """(in_shardings, out_shardings) pytrees for the tp mesh: params
-        take the plan shardings, cache slabs the KV-head split, every
-        other input/output replicates. None/None off-mesh."""
+        take the plan shardings, the pools the KV-head split, every
+        other input and the two outputs behind the caches (logits,
+        tokens) replicate. None/None off-mesh."""
         if self._mesh is None:
             return None, None
-        if isinstance(example_args[1], tuple):      # the paged pair
-            pools = (self._cache_sh, self._cache_sh)
-            ins = [self._param_sh, pools]
-            ins += [self._repl_sh] * (len(example_args) - 2)
-            outs = [pools] + [self._repl_sh] * (n_outputs - 1)
-            return tuple(ins), tuple(outs)
-        ins = [self._param_sh, self._cache_sh, self._cache_sh]
-        ins += [self._repl_sh] * (len(example_args) - 3)
-        outs = [self._cache_sh, self._cache_sh]
-        outs += [self._repl_sh] * (n_outputs - 2)
-        return tuple(ins), tuple(outs)
+        if isinstance(example_args[1], tuple):      # prefill and decode
+            caches = [(self._cache_sh, self._cache_sh)]
+        else:                   # verify: the pools as arguments 1 and 2
+            caches = [self._cache_sh, self._cache_sh]
+        rest = len(example_args) - 1 - len(caches)
+        return (tuple([self._param_sh] + caches + [self._repl_sh] * rest),
+                tuple(caches + [self._repl_sh] * 2))
 
     def _compile(self, name: str, fn, example_args,
-                 donate_argnums: Tuple[int, ...],
-                 n_outputs: int = 4) -> Any:
+                 donate_argnums: Tuple[int, ...]) -> Any:
         from ..parallel import health as _health
 
         sig = self._make_sig(example_args)
@@ -651,7 +498,7 @@ class DecodeEngine:
                 self.steady_state_recompiles += 1
         hist.append(sig)
         del hist[:-8]
-        in_sh, out_sh = self._shardings_for(example_args, n_outputs)
+        in_sh, out_sh = self._shardings_for(example_args)
         jit_kw: Dict[str, Any] = dict(
             donate_argnums=donate_argnums if self._donate else ())
         if in_sh is not None:
@@ -672,7 +519,6 @@ class DecodeEngine:
                 "max_seq": self.ecfg.max_seq,
                 "weight_dtype": self.ecfg.weight_dtype,
                 "cache_dtype": str(jnp.dtype(self.cache.dtype).name),
-                "kv_layout": self.ecfg.kv_layout,
                 "sharding": self.ecfg.sharding or "none",
                 "tp": self.ecfg.tp,
                 "buckets": list(self.buckets),
@@ -688,34 +534,24 @@ class DecodeEngine:
         return (np.zeros((B,), np.float32), np.zeros((B,), np.int32),
                 np.ones((B,), np.float32), np.zeros((B,), np.int32))
 
-    # The paged pair takes the manager's arrays as ONE argument (a tuple:
-    # the pools, and a recurrent model's state arrays), donated whole; the
-    # slab and verify programs take the two slabs as arguments 1 and 2.
-    def _donated(self, fn) -> Tuple[int, ...]:
-        paged_pair = (self._prefill_fn_paged, self._decode_fn_paged)
-        return (1,) if fn in paged_pair else (1, 2)
-
+    # Prefill and decode take the manager's arrays as ONE argument (a
+    # tuple: the pools, and a recurrent model's state arrays), donated
+    # whole; the verify program takes the two pools as arguments 1 and 2.
     def _prefill_program(self, bucket: int):
         """(fn, example args) of one prefill rung, as _decode_program."""
-        tokens = np.zeros((1, bucket), np.int32)
-        if self.paged:
-            M = self.cache.max_pages_per_slot
-            return self._prefill_fn_paged, (
-                self.qparams, self.cache.arrays(), tokens, np.int32(1),
-                np.int32(0), np.zeros((M,), np.int32), np.int32(0),
-                *self._samp_scalar_examples())
-        return self._prefill_fn, (
-            self.qparams, self.cache.k, self.cache.v, tokens, np.int32(1),
-            np.int32(0), *self._samp_scalar_examples())
+        M = self.cache.max_pages_per_slot
+        return self._prefill_fn_paged, (
+            self.qparams, self.cache.arrays(),
+            np.zeros((1, bucket), np.int32), np.int32(1),
+            np.int32(0), np.zeros((M,), np.int32), np.int32(0),
+            *self._samp_scalar_examples())
 
     def _prefill_exec(self, bucket: int):
         name = f"prefill_b{bucket}"
         exe = self._exec.get(name)
         if exe is None:
             fn, example = self._prefill_program(bucket)
-            exe = self._compile(name, fn, example,
-                                donate_argnums=self._donated(fn),
-                                n_outputs=3 if self.paged else 4)
+            exe = self._compile(name, fn, example, donate_argnums=(1,))
             self._exec[name] = exe
         return exe
 
@@ -725,23 +561,17 @@ class DecodeEngine:
         described chip from the example's shapes."""
         B = self.ecfg.max_batch
         zeros_b = np.zeros((B,), np.int32)
-        if self.paged:
-            M = self.cache.max_pages_per_slot
-            return self._decode_fn_paged, (
-                self.qparams, self.cache.arrays(), zeros_b, zeros_b,
-                np.zeros((B, M), np.int32), zeros_b,
-                *self._samp_batch_examples())
-        return self._decode_fn, (
-            self.qparams, self.cache.k, self.cache.v, zeros_b, zeros_b,
-            zeros_b, *self._samp_batch_examples())
+        M = self.cache.max_pages_per_slot
+        return self._decode_fn_paged, (
+            self.qparams, self.cache.arrays(), zeros_b, zeros_b,
+            np.zeros((B, M), np.int32), zeros_b,
+            *self._samp_batch_examples())
 
     def _decode_exec(self):
         exe = self._exec.get("decode")
         if exe is None:
             fn, example = self._decode_program()
-            exe = self._compile("decode", fn, example,
-                                donate_argnums=self._donated(fn),
-                                n_outputs=3 if self.paged else 4)
+            exe = self._compile("decode", fn, example, donate_argnums=(1,))
             self._exec["decode"] = exe
         return exe
 
@@ -749,11 +579,8 @@ class DecodeEngine:
         """One prefill or decode call on the live caches: ``(caches,
         rest)`` back, the caches for ``self.cache.set_arrays`` once the
         call is known to have run."""
-        if self.paged:
-            out = exe(self.qparams, self.cache.arrays(), *args)
-            return out[0], out[1:]
-        out = exe(self.qparams, self.cache.k, self.cache.v, *args)
-        return out[:2], out[2:]
+        out = exe(self.qparams, self.cache.arrays(), *args)
+        return out[0], out[1:]
 
     def _verify_exec(self):
         W = self.ecfg.verify_window
@@ -763,23 +590,14 @@ class DecodeEngine:
         exe = self._exec.get(name)
         if exe is None:
             B = self.ecfg.max_batch
-            if self.paged:
-                M = self.cache.max_pages_per_slot
-                example = (self.qparams, self.cache.k, self.cache.v,
-                           np.zeros((B, W), np.int32),
-                           np.zeros((B,), np.int32),
-                           np.zeros((B, M), np.int32),
-                           *self._samp_batch_examples())
-                exe = self._compile(name, self._verify_fn_paged, example,
-                                    donate_argnums=(1, 2))
-            else:
-                example = (self.qparams, self.cache.k, self.cache.v,
-                           np.zeros((B, W), np.int32),
-                           np.zeros((B,), np.int32),
-                           np.zeros((B,), np.int32),
-                           *self._samp_batch_examples())
-                exe = self._compile(name, self._verify_fn, example,
-                                    donate_argnums=(1, 2))
+            M = self.cache.max_pages_per_slot
+            example = (self.qparams, self.cache.k, self.cache.v,
+                       np.zeros((B, W), np.int32),
+                       np.zeros((B,), np.int32),
+                       np.zeros((B, M), np.int32),
+                       *self._samp_batch_examples())
+            exe = self._compile(name, self._verify_fn_paged, example,
+                                donate_argnums=(1, 2))
             self._exec[name] = exe
         return exe
 
@@ -799,7 +617,7 @@ class DecodeEngine:
             # the live caches: all-zero tables and ``actives``, so a warm
             # call writes the scratch page and slot 0's dead state alone
             t0 = time.perf_counter()
-            caches, rest = self._call(exe, *example[2 if self.paged else 3:])
+            caches, rest = self._call(exe, *example[2:])
             jax.block_until_ready(rest[0])
             self.cache.set_arrays(caches)
             timings[label] = (time.perf_counter() - t0) * 1e3
@@ -813,10 +631,9 @@ class DecodeEngine:
             zeros_b = np.zeros((B,), np.int32)
             ver = self._verify_exec()
             t0 = time.perf_counter()
-            lanes = (np.zeros((B, self.cache.max_pages_per_slot), np.int32)
-                     if self.paged else zeros_b)
+            tables = np.zeros((B, self.cache.max_pages_per_slot), np.int32)
             out = ver(self.qparams, self.cache.k, self.cache.v,
-                      np.zeros((B, W), np.int32), zeros_b, lanes,
+                      np.zeros((B, W), np.int32), zeros_b, tables,
                       *self._samp_batch_examples())
             jax.block_until_ready(out[2])
             self.cache.k, self.cache.v = out[0], out[1]
@@ -825,14 +642,8 @@ class DecodeEngine:
         # compiled shape each — warmed here so a disagg handoff's first
         # export/adopt never pays a mid-request compile (~100ms)
         t0 = time.perf_counter()
-        if self.paged:
-            k0, v0 = self.cache.read_pages([0])
-            self.cache.write_pages([0], k0, v0)
-        else:
-            from .kv_transfer import DEFAULT_CHUNK_ROWS
-            n = min(DEFAULT_CHUNK_ROWS, self.ecfg.max_seq)
-            k0, v0 = self.cache.read_rows(0, 0, n)
-            self.cache.write_rows(0, 0, k0, v0)
+        k0, v0 = self.cache.read_pages([0])
+        self.cache.write_pages([0], k0, v0)
         timings["kv_transfer"] = (time.perf_counter() - t0) * 1e3
         self._warm = True
         return timings
@@ -846,14 +657,14 @@ class DecodeEngine:
 
     def _poison_on_donation_failure(self, name: str, exc: Exception) -> None:
         """An executable compiled with donate_argnums died mid-call: the
-        cache slabs it was handed are donation-invalidated, so cache.k/v
+        cache pools it was handed are donation-invalidated, so cache.k/v
         can no longer be trusted. Mark the engine fatally poisoned rather
         than let later calls read freed buffers. (Without donation — CPU —
-        the slabs are untouched and the engine stays usable.)"""
+        the pools are untouched and the engine stays usable.)"""
         if self._donate and self.poisoned is None:
             self.poisoned = (
                 f"{name} failed after cache-buffer donation "
-                f"({type(exc).__name__}: {exc}); KV slabs invalidated — "
+                f"({type(exc).__name__}: {exc}); KV pools invalidated — "
                 f"rebuild the engine")
 
     def bucket_for(self, n: int) -> int:
@@ -867,11 +678,9 @@ class DecodeEngine:
     def can_admit(self, prompt_len: int) -> bool:
         """Would a prompt admit RIGHT NOW (slot + page budget)? The
         scheduler's head-of-line check — never raises."""
-        if self.paged:
-            # conservative: require the full prompt's pages (a prefix hit
-            # only makes admission cheaper; reclaimable cache pages count)
-            return self.cache.can_admit(prompt_len)
-        return self.cache.free_slot_count() > 0
+        # conservative: require the full prompt's pages (a prefix hit
+        # only makes admission cheaper; reclaimable cache pages count)
+        return self.cache.can_admit(prompt_len)
 
     def _trim_prefix(self, n: int, prefix_len: int,
                      prefix_pages: Tuple[int, ...]):
@@ -919,20 +728,34 @@ class DecodeEngine:
         attrs = {"prompt_len": n, "step": self.sched_step,
                  "scan_tokens": n if self.model.recurrent else 0}
         with _spans.span("serve/prefill", attrs=attrs):
-            if self.paged:
-                return self._start_paged(tokens, n, sp_scalars, attrs)
             with _spans.span("prefill/prep"):
-                bucket = self.bucket_for(n)
+                prefix_len, prefix_pages = 0, ()
+                if self.prefix is not None:
+                    prefix_len, prefix_pages = self.prefix.lookup(tokens)
+                    prefix_len, prefix_pages = self._trim_prefix(
+                        n, prefix_len, tuple(prefix_pages))
+                suffix = list(tokens[prefix_len:])
+                bucket = self.bucket_for(len(suffix))
                 exe = self._prefill_exec(bucket)
-                slot = self.cache.alloc(length=n)
+                slot = self.cache.alloc(length=n, prefix_pages=prefix_pages)
+                table_row = self.cache.table_row(slot)
                 padded = np.zeros((1, bucket), np.int32)
-                padded[0, :n] = np.asarray(tokens, np.int32)
-                attrs.update(bucket=bucket, prefix_len=0, slot=slot)
+                padded[0, :len(suffix)] = np.asarray(suffix, np.int32)
+                attrs.update(bucket=bucket, prefix_len=prefix_len, slot=slot)
             caches, logits, tok = self._run_prefill(
-                exe, bucket, slot, n, sp_scalars, padded, np.int32(n),
+                exe, bucket, slot, len(suffix), sp_scalars, padded,
+                np.int32(len(suffix)), np.int32(prefix_len), table_row,
                 np.int32(slot))
             with _spans.span("prefill/publish"):
                 self.cache.set_arrays(caches)
+                if self.prefix is not None:
+                    added = self.prefix.insert(tokens, table_row)
+                    if added and self.prefix_store is not None:
+                        # persist at publish time: the pages just written are
+                        # the ones a recycled replica restores (async,
+                        # CRC-committed)
+                        self.prefix_store.maybe_publish(tokens, table_row,
+                                                        self.cache)
             return slot, logits, tok
 
     def _run_prefill(self, exe, bucket: int, slot: int, n_tokens: int,
@@ -956,37 +779,6 @@ class DecodeEngine:
         smetrics.m_prefill_ms.observe((time.perf_counter_ns() - t0) / 1e6)
         smetrics.m_prefill_tokens.inc(n_tokens)
         return caches, logits, tok
-
-    def _start_paged(self, tokens, n: int, sp_scalars, attrs):
-        with _spans.span("prefill/prep"):
-            prefix_len, prefix_pages = 0, ()
-            if self.prefix is not None:
-                prefix_len, prefix_pages = self.prefix.lookup(tokens)
-                prefix_len, prefix_pages = self._trim_prefix(
-                    n, prefix_len, tuple(prefix_pages))
-            suffix = list(tokens[prefix_len:])
-            bucket = self.bucket_for(len(suffix))
-            exe = self._prefill_exec(bucket)
-            slot = self.cache.alloc(length=n, prefix_pages=prefix_pages)
-            table_row = self.cache.table_row(slot)
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :len(suffix)] = np.asarray(suffix, np.int32)
-            attrs.update(bucket=bucket, prefix_len=prefix_len, slot=slot)
-        caches, logits, tok = self._run_prefill(
-            exe, bucket, slot, len(suffix), sp_scalars, padded,
-            np.int32(len(suffix)), np.int32(prefix_len), table_row,
-            np.int32(slot))
-        with _spans.span("prefill/publish"):
-            self.cache.set_arrays(caches)
-            if self.prefix is not None:
-                added = self.prefix.insert(tokens, table_row)
-                if added and self.prefix_store is not None:
-                    # persist at publish time: the pages just written are
-                    # the ones a recycled replica restores (async,
-                    # CRC-committed)
-                    self.prefix_store.maybe_publish(tokens, table_row,
-                                                    self.cache)
-        return slot, logits, tok
 
     def resume_sequence_sampled(
             self, tokens: Sequence[int], params: SamplingParams
@@ -1022,20 +814,15 @@ class DecodeEngine:
         return slot, logits, tok
 
     def ensure_decode_capacity(self, slot: int, extra: int = 1) -> bool:
-        """Make the next ``extra`` token positions of ``slot`` writable.
-        Paged: maps pages on demand (False = pool dry even after
-        prefix-cache reclaim — the scheduler preempts). Slab: always
-        True (the slab IS the capacity; headroom is checked separately)."""
-        if not self.paged:
-            return True
+        """Make the next ``extra`` token positions of ``slot`` writable:
+        maps pages on demand (False = pool dry even after prefix-cache
+        reclaim — the scheduler preempts)."""
         return self.cache.ensure_capacity(
             slot, self.cache.length(slot) + extra)
 
     def live_pages(self, slots) -> int:
         """Pages a decode tick over ``slots`` reads: those holding each
-        slot's rows up to the one the tick writes (0 on a slab)."""
-        if not self.paged:
-            return 0
+        slot's rows up to the one the tick writes."""
         return sum(self.cache.pages_for(self.cache.length(s) + 1)
                    for s in slots)
 
@@ -1097,21 +884,19 @@ class DecodeEngine:
                                    self.ecfg.max_batch)
             exe = self._decode_exec()
             t0 = time.perf_counter_ns()
-            if self.paged:
-                for slot in slot_tokens:
-                    if not self.ensure_decode_capacity(slot):
-                        raise PagePoolFullError(
-                            f"slot {slot}: no free page for position "
-                            f"{self.cache.length(slot)}")
+            for slot in slot_tokens:
+                if not self.ensure_decode_capacity(slot):
+                    raise PagePoolFullError(
+                        f"slot {slot}: no free page for position "
+                        f"{self.cache.length(slot)}")
             actives = np.zeros((self.ecfg.max_batch,), np.int32)
             actives[list(slot_tokens)] = 1
-            lanes = ((self._masked_tables(slot_tokens), actives)
-                     if self.paged else (actives,))
+            tables = self._masked_tables(slot_tokens)
             sampler = _note_sampler("decode", *sp[:3])
         try:
             with _spans.span("decode/run", attrs={"sampler": sampler}):
                 caches, (logits, toks) = self._call(
-                    exe, tokens, positions, *lanes, *sp)
+                    exe, tokens, positions, tables, actives, *sp)
                 toks = np.asarray(toks)
             with _spans.span("decode/fetch_logits"):
                 logits = np.asarray(logits)
@@ -1173,23 +958,15 @@ class DecodeEngine:
         exe = self._verify_exec()
         t0 = time.perf_counter_ns()
         try:
-            if self.paged:
-                for slot in windows:
-                    if not self.ensure_decode_capacity(slot, extra=W):
-                        raise PagePoolFullError(
-                            f"slot {slot}: no free pages for a {W}-token "
-                            "verify window")
-                tables = self._masked_tables(windows)
-                ck, cv, logits, toks = exe(
-                    self.qparams, self.cache.k, self.cache.v, tokens,
-                    starts, tables, *sp)
-            else:
-                actives = np.zeros((B,), np.int32)
-                for slot in windows:
-                    actives[slot] = 1
-                ck, cv, logits, toks = exe(
-                    self.qparams, self.cache.k, self.cache.v, tokens,
-                    starts, actives, *sp)
+            for slot in windows:
+                if not self.ensure_decode_capacity(slot, extra=W):
+                    raise PagePoolFullError(
+                        f"slot {slot}: no free pages for a {W}-token "
+                        "verify window")
+            tables = self._masked_tables(windows)
+            ck, cv, logits, toks = exe(
+                self.qparams, self.cache.k, self.cache.v, tokens,
+                starts, tables, *sp)
             logits = np.asarray(logits)
             toks = np.asarray(toks)
         except PagePoolFullError:

@@ -3,12 +3,12 @@
 
 Disaggregated serving migrates a request from its PREFILL replica to a
 DECODE replica at the first-token boundary. What actually moves is the
-request's KV cache state: the live pages of its page table (paged
-engines) or its valid slab rows (slab engines). This module is that
-path — the prefix store's record discipline (content CRC per payload,
-explicit COMMIT marker, config fingerprint) over an in-memory channel
-instead of disk: either a handoff dict passed within one process, or a
-length-prefixed frame stream over a TCP socket between replicas
+request's KV cache state: the live pages of its page table. This
+module is that path — the prefix store's record discipline (content
+CRC per payload, explicit COMMIT marker, config fingerprint) over an
+in-memory channel instead of disk: either a handoff dict passed within
+one process, or a length-prefixed frame stream over a TCP socket
+between replicas
 (:class:`KVTransferServer` / :func:`send_handoff`).
 
 Layout redistribution rides the same path. A tp=2 prefill replica holds
@@ -43,21 +43,20 @@ import numpy as np
 
 from ..observability import spans as _spans
 from . import metrics as smetrics
+from .paged_kv import CacheFullError
 
 __all__ = [
     "CacheConfigMismatch", "TransferStats", "cache_fingerprint",
     "fingerprint_mismatch", "export_slot", "adopt_into_engine",
     "adopt_prefix", "export_prefix", "iter_frames", "KVTransferServer",
     "send_handoff", "last_stats", "handoff_to_jsonable",
-    "handoff_from_jsonable",
-    "DEFAULT_CHUNK_PAGES", "DEFAULT_CHUNK_ROWS",
+    "handoff_from_jsonable", "DEFAULT_CHUNK_PAGES",
 ]
 
 # chunk sizes for the staged transfer: small enough that the transient
 # canonical-layout footprint is pages, not pools; large enough that the
 # per-chunk host round trip amortizes
 DEFAULT_CHUNK_PAGES = 4
-DEFAULT_CHUNK_ROWS = 64
 
 _transfer_ids = itertools.count(1)
 
@@ -70,18 +69,19 @@ class CacheConfigMismatch(RuntimeError):
 
 def cache_fingerprint(cache) -> Dict[str, Any]:
     """The geometry that determines the shape of transferred KV bytes.
-    Two caches with equal fingerprints can exchange pages/rows byte-for
-    byte; anything else must be refused up front."""
-    fp = {
-        "layout": "paged" if hasattr(cache, "page_size") else "slab",
+    Two caches with equal fingerprints can exchange pages byte for
+    byte; anything else must be refused up front. ``layout`` has one
+    value here; it stays on the wire because an older prefix-store file
+    or peer may still send another, and that has to be refused
+    (ROADMAP D14)."""
+    return {
+        "layout": "paged",
         "num_layers": int(cache.num_layers),
         "num_heads": int(cache.num_heads),
         "head_dim": int(cache.head_dim),
         "dtype": str(np.dtype(cache.dtype).name),
+        "page_size": int(cache.page_size),
     }
-    if fp["layout"] == "paged":
-        fp["page_size"] = int(cache.page_size)
-    return fp
 
 
 def fingerprint_mismatch(expected: Dict[str, Any],
@@ -203,8 +203,7 @@ def _wire_bytes(handoff: Dict[str, Any]) -> int:
 # ----------------------------------------------------------------------
 def export_slot(engine, slot: int,
                 tokens: Optional[Sequence[int]] = None,
-                chunk_pages: int = DEFAULT_CHUNK_PAGES,
-                chunk_rows: int = DEFAULT_CHUNK_ROWS) -> Dict[str, Any]:
+                chunk_pages: int = DEFAULT_CHUNK_PAGES) -> Dict[str, Any]:
     """Serialize a live slot's KV state into a handoff dict: config
     fingerprint + chunked, per-shard, CRC-stamped frames + COMMIT flag.
     The slot stays live — the caller frees it after the handoff is
@@ -218,39 +217,23 @@ def export_slot(engine, slot: int,
     itemsize = np.dtype(cache.dtype).itemsize
     nshards = _shard_count(engine)
     chunks: List[Dict[str, Any]] = []
-    if fp["layout"] == "paged":
-        n_pages = cache.pages_for(length)
-        row = cache.table_row(slot)
-        pages = [int(p) for p in row[:n_pages]]
-        unit = (cache.num_layers * cache.page_size * cache.num_heads
-                * cache.head_dim * itemsize)
-        stats = TransferStats(2 * chunk_pages * unit, cache.nbytes)
-        for ci, i in enumerate(range(0, len(pages), chunk_pages)):
-            group = pages[i:i + chunk_pages]
-            k_np, v_np = cache.read_pages(group)
-            nbytes = k_np.nbytes + v_np.nbytes
-            stats.note_alloc(nbytes)
-            shards = (_split_frames(k_np, "k", 3, nshards)
-                      + _split_frames(v_np, "v", 3, nshards))
-            del k_np, v_np
-            stats.note_free(nbytes)
-            chunks.append({"index": ci, "n": len(group),
-                           "shards": shards})
-    else:
-        unit = (cache.num_layers * cache.num_heads * cache.head_dim
-                * itemsize)
-        stats = TransferStats(2 * chunk_rows * unit, cache.nbytes)
-        for ci, start in enumerate(range(0, length, chunk_rows)):
-            n = min(chunk_rows, length - start)
-            k_np, v_np = cache.read_rows(slot, start, n)
-            nbytes = k_np.nbytes + v_np.nbytes
-            stats.note_alloc(nbytes)
-            shards = (_split_frames(k_np, "k", 2, nshards)
-                      + _split_frames(v_np, "v", 2, nshards))
-            del k_np, v_np
-            stats.note_free(nbytes)
-            chunks.append({"index": ci, "start": start, "n": n,
-                           "shards": shards})
+    n_pages = cache.pages_for(length)
+    row = cache.table_row(slot)
+    pages = [int(p) for p in row[:n_pages]]
+    unit = (cache.num_layers * cache.page_size * cache.num_heads
+            * cache.head_dim * itemsize)
+    stats = TransferStats(2 * chunk_pages * unit, cache.nbytes)
+    for ci, i in enumerate(range(0, len(pages), chunk_pages)):
+        group = pages[i:i + chunk_pages]
+        k_np, v_np = cache.read_pages(group)
+        nbytes = k_np.nbytes + v_np.nbytes
+        stats.note_alloc(nbytes)
+        shards = (_split_frames(k_np, "k", 3, nshards)
+                  + _split_frames(v_np, "v", 3, nshards))
+        del k_np, v_np
+        stats.note_free(nbytes)
+        chunks.append({"index": ci, "n": len(group),
+                       "shards": shards})
     handoff = {
         "version": 1,
         "transfer_id": f"t{next(_transfer_ids)}-{id(engine) & 0xffff:x}",
@@ -276,11 +259,11 @@ def adopt_into_engine(engine, handoff: Dict[str, Any]) -> int:
     """Materialize a handoff into the receiving engine's cache and
     return the slot it now lives in. Fingerprints are checked FIRST
     (:class:`CacheConfigMismatch` on any differing field); chunks are
-    merged shard-by-shard and written page-/row-wise so the canonical
+    merged shard-by-shard and written page-wise so the canonical
     layout only ever exists chunk-sized."""
     cache = engine.cache
-    fp_local = cache_fingerprint(cache)
-    diffs = fingerprint_mismatch(fp_local, handoff["fingerprint"])
+    diffs = fingerprint_mismatch(cache_fingerprint(cache),
+                                 handoff["fingerprint"])
     if diffs:
         raise CacheConfigMismatch(
             "KV handoff rejected — cache config mismatch: "
@@ -293,7 +276,6 @@ def adopt_into_engine(engine, handoff: Dict[str, Any]) -> int:
         # backlog the scheduler retries adoption every tick, and doing
         # the full transfer work just to hit CacheFullError in
         # adopt_slot taxes every decode gap (~2ms a tick)
-        from .kv_cache import CacheFullError
         raise CacheFullError(
             f"no free decode slot for handoff "
             f"{handoff.get('transfer_id')!r}")
@@ -302,44 +284,28 @@ def adopt_into_engine(engine, handoff: Dict[str, Any]) -> int:
     max_chunk = max((int(ch["n"]) for ch in handoff["chunks"]),
                     default=1)
     itemsize = np.dtype(cache.dtype).itemsize
-    if fp_local["layout"] == "paged":
-        unit = (cache.num_layers * cache.page_size * cache.num_heads
-                * cache.head_dim * itemsize)
-        stats = TransferStats(2 * max_chunk * unit, cache.nbytes)
-        pages = cache.claim_pages(cache.pages_for(length))
-        try:
-            written = 0
-            for ch in sorted(handoff["chunks"],
-                             key=lambda c: c["index"]):
-                k_np, v_np = _assemble_chunk(ch, 3, stats)
-                cache.write_pages(pages[written:written + int(ch["n"])],
-                                  k_np, v_np)
-                stats.note_free(k_np.nbytes + v_np.nbytes)
-                written += int(ch["n"])
-                del k_np, v_np
-            if written != len(pages):
-                raise ValueError(
-                    f"handoff covered {written} page(s), table needs "
-                    f"{len(pages)}")
-            slot = cache.adopt_slot(length, pages)
-        except Exception:
-            cache.deref_pages(pages)
-            raise
-    else:
-        unit = (cache.num_layers * cache.num_heads * cache.head_dim
-                * itemsize)
-        stats = TransferStats(2 * max_chunk * unit, cache.nbytes)
-        slot = cache.alloc(length)
-        try:
-            for ch in sorted(handoff["chunks"],
-                             key=lambda c: c["index"]):
-                k_np, v_np = _assemble_chunk(ch, 2, stats)
-                cache.write_rows(slot, int(ch["start"]), k_np, v_np)
-                stats.note_free(k_np.nbytes + v_np.nbytes)
-                del k_np, v_np
-        except Exception:
-            cache.free(slot)
-            raise
+    unit = (cache.num_layers * cache.page_size * cache.num_heads
+            * cache.head_dim * itemsize)
+    stats = TransferStats(2 * max_chunk * unit, cache.nbytes)
+    pages = cache.claim_pages(cache.pages_for(length))
+    try:
+        written = 0
+        for ch in sorted(handoff["chunks"],
+                         key=lambda c: c["index"]):
+            k_np, v_np = _assemble_chunk(ch, 3, stats)
+            cache.write_pages(pages[written:written + int(ch["n"])],
+                              k_np, v_np)
+            stats.note_free(k_np.nbytes + v_np.nbytes)
+            written += int(ch["n"])
+            del k_np, v_np
+        if written != len(pages):
+            raise ValueError(
+                f"handoff covered {written} page(s), table needs "
+                f"{len(pages)}")
+        slot = cache.adopt_slot(length, pages)
+    except Exception:
+        cache.deref_pages(pages)
+        raise
     stats.total_bytes = _wire_bytes(handoff)
     stats.elapsed_ms = (time.perf_counter_ns() - t0) / 1e6
     _note_stats("adopt", stats)
@@ -402,11 +368,10 @@ def adopt_prefix(engine, blob: Dict[str, Any]) -> int:
     ``tokens`` cover exactly its page-aligned length) into the local
     pool + prefix cache, so the next prefill of those tokens hits
     locally. Returns prefix-cache entries registered (0 when the
-    prefix is already cached). Paged engines with a prefix cache only."""
+    prefix is already cached). Engines with a prefix cache only."""
     cache = engine.cache
-    if not getattr(engine, "paged", False) or engine.prefix is None:
-        raise ValueError("prefix adoption needs kv_layout='paged' with "
-                         "prefix_cache enabled")
+    if engine.prefix is None:
+        raise ValueError("prefix adoption needs prefix_cache enabled")
     diffs = fingerprint_mismatch(cache_fingerprint(cache),
                                  blob["fingerprint"])
     if diffs:

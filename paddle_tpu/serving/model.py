@@ -28,9 +28,9 @@ description is any object with:
   engine's parity surface).
 
 Two descriptions exist: :class:`GPTServing` here (``models/gpt.py``'s
-block) and ``models/jamba.py:JambaServing``. The slab and verify programs
-of the engine are still written for the GPT block (ROADMAP D2) and use
-the block helpers below directly.
+block) and ``models/jamba.py:JambaServing``. The engine's verify program
+is still written for the GPT block (ROADMAP D2) and uses the block
+helpers below directly.
 """
 from __future__ import annotations
 

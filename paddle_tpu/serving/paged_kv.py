@@ -1,16 +1,15 @@
 """Block-granular paged KV cache + token-hash prefix cache (ISSUE 13,
 docs/serving.md).
 
-PR 9's :class:`~paddle_tpu.serving.kv_cache.KVCache` gives every slot a
-private ``[max_seq]`` slab — 8 slots x 1024 positions of HBM even when
-seven of them hold 12-token chats. This module replaces the slab with a
-**page pool**: one preallocated ``[L, num_pages, page_size, nh, hd]``
-K/V pair, fixed-size pages handed out from a host-side free list, and a
-per-slot **page table** (``[max_pages_per_slot]`` int32 of physical page
-ids) that rides into the decode/prefill executables as a plain device
-array — so long-context and short-chat traffic share HBM at page
-granularity and no shape ever changes (the zero-recompile contract is
-untouched).
+A private ``[max_seq]`` run of rows for every slot would hold 8 slots x
+1024 positions of HBM even when seven of them hold 12-token chats. This
+module is the engine's one cache, a **page pool**: one preallocated
+``[L, num_pages, page_size, nh, hd]`` K/V pair, fixed-size pages handed
+out from a host-side free list, and a per-slot **page table**
+(``[max_pages_per_slot]`` int32 of physical page ids) that rides into the
+decode/prefill executables as a plain device array — so long-context
+and short-chat traffic share HBM at page granularity and no shape ever
+changes (the zero-recompile contract is untouched).
 
 Layout rules:
 
@@ -61,10 +60,9 @@ import jax
 import jax.numpy as jnp
 
 from . import metrics as smetrics
-from .kv_cache import CacheFullError
 
 __all__ = ["PagedKVCache", "PrefixCache", "PagePoolFullError",
-           "TRANSFER_PAGE_BUCKET"]
+           "CacheFullError", "TRANSFER_PAGE_BUCKET"]
 
 # Gather/scatter width bucket for the host transfer path
 # (:meth:`PagedKVCache.read_pages` / ``write_pages``). Page groups are
@@ -90,6 +88,10 @@ def _scatter_pages_exec(k, v, idx, k_pages, v_pages):
     return k.at[:, idx].set(k_pages), v.at[:, idx].set(v_pages)
 
 
+class CacheFullError(RuntimeError):
+    """All slots are occupied (the scheduler should queue, not crash)."""
+
+
 class PagePoolFullError(RuntimeError):
     """No free page available (after prefix-cache reclaim) — the
     scheduler should defer admission or preempt, not crash."""
@@ -101,18 +103,20 @@ class _SlotState:
     length: int = 0          # valid prefix length (tokens written)
     prefix_len: int = 0      # leading tokens backed by shared pages
     mapped: int = 0          # logical pages currently mapped
-    generation: int = 0
+    generation: int = 0      # bumped on every alloc — reuse visible to tests
 
 
 class PagedKVCache:
-    """Page-pool allocator + the two pooled cache slabs.
+    """Page-pool allocator + the two pooled cache arrays.
 
-    Drop-in for the slab :class:`KVCache` from the engine's point of view
-    (``k``/``v`` device values swapped wholesale per call; ``alloc`` /
-    ``free`` / ``length`` / ``headroom`` / ``lengths_vector`` keep their
-    contracts) plus the paged surface: per-slot page tables, page-budget
-    queries for the scheduler, and refcounts shared with the prefix
-    cache."""
+    The device arrays are pure values (``k``/``v`` swapped wholesale per
+    call: donated in, fresh handle out); what this class owns is the HOST
+    truth the scheduler plans against: which slots are live, how long
+    each slot's valid prefix is (``alloc`` / ``free`` / ``length`` /
+    ``headroom`` / ``lengths_vector``), the per-slot page tables,
+    page-budget queries, and refcounts shared with the prefix cache.
+    Slot state never reaches the compiled functions, so join/evict at
+    token boundaries is a host-side bookkeeping edit, not a recompile."""
 
     def __init__(self, num_layers: int, max_slots: int, max_seq: int,
                  num_heads: int, head_dim: int, dtype: Any = jnp.float32,
@@ -131,8 +135,8 @@ class PagedKVCache:
         self.dtype = dtype
         self.page_size = int(page_size)
         self.max_pages_per_slot = self.max_seq // self.page_size
-        # default pool = slab parity (+1 scratch page): same worst case,
-        # but pages only bind to slots as sequences actually grow
+        # default pool = every slot at max_seq (+1 scratch page): the
+        # worst case, but pages only bind to slots as sequences grow
         self.num_pages = int(num_pages) or (
             self.max_slots * self.max_pages_per_slot + 1)
         if self.num_pages < 2:
@@ -162,7 +166,9 @@ class PagedKVCache:
         self._ref = np.zeros((self.num_pages,), np.int64)
         self._ref[0] = 1                             # scratch: pinned
         self._free_pages: List[int] = list(range(1, self.num_pages))
-        self.reclaimer = None    # set by the engine: fn(n_pages) -> freed
+        # the PrefixCache over this pool, set by the engine: pool
+        # pressure reclaims the pages only it still holds
+        self.prefix_cache: Optional["PrefixCache"] = None
 
     # -- geometry ----------------------------------------------------------
     @property
@@ -209,8 +215,8 @@ class PagedKVCache:
         return len(self._free_pages)
 
     def _take_pages(self, n: int) -> List[int]:
-        if n > len(self._free_pages) and self.reclaimer is not None:
-            self.reclaimer(n - len(self._free_pages))
+        if n > len(self._free_pages) and self.prefix_cache is not None:
+            self.prefix_cache.reclaim(n - len(self._free_pages))
         if n > len(self._free_pages):
             raise PagePoolFullError(
                 f"need {n} free page(s), have {len(self._free_pages)} "
@@ -240,14 +246,14 @@ class PagedKVCache:
     # -- slot bookkeeping --------------------------------------------------
     def can_admit(self, prompt_len: int, prefix_len: int = 0) -> bool:
         """Would a prompt of ``prompt_len`` (with ``prefix_len`` tokens
-        already cache-backed) fit right now? Counts reclaimable
-        prefix-cache pages via the reclaimer's dry-run hook when set."""
+        already cache-backed) fit right now? Pages that only the prefix
+        cache still holds count as free: ``_take_pages`` reclaims them."""
         if not self._free_slots:
             return False
         need = self.pages_for(prompt_len) - prefix_len // self.page_size
         avail = len(self._free_pages)
-        if self.reclaimer is not None:
-            avail += getattr(self.reclaimer, "reclaimable", lambda: 0)()
+        if self.prefix_cache is not None:
+            avail += self.prefix_cache.reclaimable()
         return need <= avail
 
     def alloc(self, length: int = 0,
@@ -269,7 +275,7 @@ class PagedKVCache:
             raise ValueError("prefix pages cover more than the sequence")
         n_own = self.pages_for(length) - n_prefix
         # pin the shared prefix FIRST: _take_pages may trigger the
-        # prefix-cache reclaimer, which must not be able to free (and
+        # prefix cache's reclaim, which must not be able to free (and
         # recycle) the very pages this slot is about to attach
         self.ref_pages(prefix_pages)
         try:
@@ -374,7 +380,7 @@ class PagedKVCache:
         """Take ``n`` pages off the free list with ONE reference each —
         the prefix cache's reference when the pages are adopted as a
         restored cache entry. Raises :class:`PagePoolFullError` (after
-        the reclaimer hook) when the pool cannot cover it."""
+        the prefix cache's reclaim) when the pool cannot cover it."""
         return self._take_pages(int(n))
 
     def read_pages(self, pages: Sequence[int]
@@ -499,8 +505,8 @@ class PrefixCache:
     Entries are page-aligned prompt prefixes; the cache holds ONE ref on
     every page of every entry (slots using the pages hold their own).
     ``capacity_pages`` bounds distinct cache-held pages; LRU entries are
-    dropped on overflow and under pool pressure (:meth:`reclaim` — wired
-    as the pool's ``reclaimer`` by the engine)."""
+    dropped on overflow and under pool pressure (:meth:`reclaim`: the
+    engine sets this cache as the pool's ``prefix_cache``)."""
 
     def __init__(self, pool: PagedKVCache, capacity_pages: int = 0):
         self.pool = pool

@@ -1,7 +1,7 @@
 """Persistent prefix-cache store: published pages survive engine restarts
 (ISSUE 15, ROADMAP 2(c), docs/serving.md "Resilience").
 
-The paged engine's :class:`~paddle_tpu.serving.paged_kv.PrefixCache`
+The engine's :class:`~paddle_tpu.serving.paged_kv.PrefixCache`
 makes a shared system prompt prefill ONCE — per engine *incarnation*.
 A crash (or a gang recycle) used to throw the warmed pages away, so a
 restarted replica re-paid every shared-prefix prefill. This module
@@ -131,8 +131,7 @@ class PrefixStore:
         records without a fingerprint fall back to the old silent
         shape-tail skip."""
         if engine.prefix is None:
-            raise ValueError("prefix store needs a paged engine with "
-                             "prefix_cache enabled")
+            raise ValueError("prefix store needs prefix_cache enabled")
         pool, cache = engine.cache, engine.prefix
         fp_local = cache_fingerprint(pool)
         expect = (pool.num_layers, pool.page_size, pool.num_heads,

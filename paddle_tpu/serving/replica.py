@@ -43,7 +43,7 @@ import sys
 import threading
 import time
 
-#: Exit code for a poisoned engine (donation invalidated the KV slabs —
+#: Exit code for a poisoned engine (donation invalidated the KV pools —
 #: engine.py). Distinct from health.HANG_EXIT_CODE (43): the gang maps
 #: 44 -> ``paddle_serve_replica_restarts_total{cause="poisoned"}``.
 POISONED_EXIT_CODE = 44

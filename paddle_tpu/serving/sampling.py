@@ -11,7 +11,7 @@ contract extends to sampling by construction).
 Semantics per slot:
 
 - ``temperature <= 0`` — greedy argmax, bit-identical to the pre-sampling
-  engine (the parity bars and the slab/paged token-match tests key off
+  engine (the parity bars and the reference token-match tests key off
   this lane);
 - ``temperature > 0`` — logits are divided by the temperature, then
   masked by top-k (keep the k highest-logit tokens; ``k <= 0`` disables)

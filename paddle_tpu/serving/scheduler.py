@@ -53,8 +53,7 @@ from ..observability import goodput as _goodput
 from ..observability import spans as _spans
 from . import metrics as smetrics
 from .engine import DecodeEngine, PromptTooLongError
-from .kv_cache import CacheFullError
-from .paged_kv import PagePoolFullError
+from .paged_kv import CacheFullError, PagePoolFullError
 from .sampling import GREEDY, SamplingParams
 
 __all__ = ["Request", "Scheduler", "SchedulerConfig", "QueueFullError"]

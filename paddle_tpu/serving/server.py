@@ -73,7 +73,7 @@ class EngineLoop:
     keeps ticking.
 
     A POISONED engine is different: no later step can ever succeed
-    (donated KV slabs are invalid — engine.py), so instead of 500ing
+    (donated KV pools are invalid — engine.py), so instead of 500ing
     every request forever the loop fails fast — it aborts everything
     with ``refuse_new`` (late submits get a clean error), records
     ``poison_reason``, invokes ``on_poison`` (a supervised replica exits
@@ -635,7 +635,7 @@ class FrontDoor:
                 if not self.loop.alive and not self._draining:
                     out["status"] = "degraded"
             # a poisoned engine outranks everything: donation invalidated
-            # its KV slabs, no request will ever succeed again — the gang
+            # its KV pools, no request will ever succeed again — the gang
             # supervisor recycles the replica on this status
             poisoned = getattr(self.scheduler.engine, "poisoned", None)
             if self.loop is not None and self.loop.poison_reason:

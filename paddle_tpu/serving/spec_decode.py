@@ -139,10 +139,6 @@ class SpecDecodeEngine:
         return self.target.buckets
 
     @property
-    def paged(self):
-        return self.target.paged
-
-    @property
     def kv_path(self):
         return self.target.kv_path
 

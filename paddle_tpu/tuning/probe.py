@@ -335,11 +335,9 @@ def _build_probe_engine(params, cfg, cand: Candidate,
         prefill_buckets=tuple(cand.get("buckets", (geom.max_seq // 2,))),
         weight_dtype=cand.get("weight_dtype", "f32"),
         fused_decode=bool(cand.get("fused_decode", False)),
+        page_size=geom.page_size,
+        num_pages=int(cand.get("num_pages", 0)),
         role=role)
-    if cand.get("kv_layout") == "paged":
-        kw.update(kv_layout="paged", page_size=geom.page_size)
-        if cand.get("num_pages", 0):
-            kw["num_pages"] = int(cand.get("num_pages"))
     if cand.get("sharding", "none") == "tp":
         kw.update(sharding="tp", tp=int(cand.get("tp", 2)))
     k = int(cand.get("spec", 0))

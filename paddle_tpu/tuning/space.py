@@ -7,16 +7,17 @@ Two spaces, one grammar:
   strategy + collective wire dtype + bucket cap, the fused flat-buffer
   optimizer, fused layernorm, and the CE vocab chunk.
 * **serve** — the static serving geometry ``EngineConfig`` bakes into
-  executable shapes: the prefill-bucket ladder, ``max_batch``, KV layout
-  + page-pool size, the fused decode step, the spec-decode window, the
+  executable shapes: the prefill-bucket ladder, ``max_batch``, the
+  page-pool size, the fused decode step, the spec-decode window, the
   weight dtype, tp sharding, and the disagg prefill:decode ratio with a
   per-role decode-batch multiplier (ROADMAP 2(c)).
 
 A :class:`Candidate` is an immutable, canonically-keyed knob assignment.
 Enumeration runs every cross-product combo through ``normalize`` (drop
-meaningless distinctions — a psum config has no bucket cap, a slab engine
-no page pool) and then the validity predicates, which REUSE the refusal
-logic the runtime already enforces (int8+tp head-sharding, fused_opt on
+meaningless distinctions — a psum config has no bucket cap, a colocated
+lane no decode-batch multiplier) and then the validity predicates, which
+REUSE the refusal logic the runtime already enforces (int8+tp
+head-sharding, fused_opt on
 multi-device psum meshes, error-feedback's quantized-dtype requirement,
 dp=1 comm levers) so an invalid candidate is refused here, with a logged
 reason, instead of crashing a probe.
@@ -179,7 +180,6 @@ def serve_axes(ctx: SpaceContext, *,
         "buckets": tuple(tuple(int(b) for b in lad)
                          for lad in bucket_ladders),
         "max_batch": tuple(int(b) for b in max_batches),
-        "kv_layout": ("slab", "paged"),
         "num_pages": tuple(int(p) for p in page_pools),
         "fused_decode": (False, True),
         "spec": tuple(int(s) for s in specs),
@@ -192,14 +192,8 @@ def serve_axes(ctx: SpaceContext, *,
 
 def normalize_serve(knobs: Dict[str, Any], ctx: SpaceContext):
     k = dict(knobs)
-    if k.get("kv_layout") != "paged":
-        k["num_pages"] = 0
     if k.get("disagg", "off") == "off":
         k["disagg_decode_batch"] = 1
-    else:
-        # the disagg router migrates KV between replicas page-wise
-        # (serving/disagg.py) — a disagg candidate is a paged candidate
-        k["kv_layout"] = "paged"
     if k.get("sharding", "none") == "none":
         k["tp"] = 1
     else:
@@ -227,29 +221,26 @@ def validate_serve(knobs: Dict[str, Any], ctx: SpaceContext):
             return "invalid:disagg_spec_unsupported"
         if knobs.get("sharding") == "tp":
             return "invalid:disagg_tp_unsupported"
-        if knobs.get("kv_layout") != "paged":
-            return "invalid:disagg_needs_paged"
-    if knobs.get("kv_layout") == "paged":
-        buckets = knobs.get("buckets", ())
-        if any(b % ctx.page_size for b in buckets):
-            return "invalid:bucket_page_align"
-        pool = knobs.get("num_pages", 0)
-        if pool and pool < knobs.get("max_batch", ctx.max_batch) * max(
-                1, min(buckets or (ctx.page_size,)) // ctx.page_size):
-            return "invalid:page_pool_too_small"
+    buckets = knobs.get("buckets", ())
+    if any(b % ctx.page_size for b in buckets):
+        return "invalid:bucket_page_align"
+    pool = knobs.get("num_pages", 0)
+    if pool and pool < knobs.get("max_batch", ctx.max_batch) * max(
+            1, min(buckets or (ctx.page_size,)) // ctx.page_size):
+        return "invalid:page_pool_too_small"
     if any(b > ctx.max_seq for b in knobs.get("buckets", ())):
         return "invalid:bucket_gt_max_seq"
     return None
 
 
 def serve_incumbent(ctx: SpaceContext) -> Candidate:
-    """Committed serving defaults: slab, f32, no fused decode, no spec,
+    """Committed serving defaults: f32, no fused decode, no spec,
     colocated — the EngineConfig dataclass defaults at the lane's
     geometry."""
     return Candidate.make("serve", **normalize_serve({
         "buckets": (max(ctx.page_size, ctx.max_seq // 4),
                     ctx.max_seq // 2),
-        "max_batch": ctx.max_batch, "kv_layout": "slab", "num_pages": 0,
+        "max_batch": ctx.max_batch, "num_pages": 0,
         "fused_decode": False, "spec": 0, "weight_dtype": "f32",
         "sharding": "none", "disagg": "off", "disagg_decode_batch": 1,
     }, ctx))

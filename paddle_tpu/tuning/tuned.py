@@ -240,7 +240,7 @@ def resolve_train_step_kwargs(doc: Dict[str, Any], pcfg,
 def engine_kwargs(doc: Dict[str, Any], *, page_size: int = 8
                   ) -> Dict[str, Any]:
     """Serving-engine side of the serve winner: kwargs for
-    ``EngineConfig`` (geometry + dtype + layout + fused decode +
+    ``EngineConfig`` (geometry + dtype + page pool + fused decode +
     sharding; the spec/disagg lane shape comes from
     :func:`serve_lane_kwargs`)."""
     cfg = _space_config(doc, "serve")
@@ -251,11 +251,9 @@ def engine_kwargs(doc: Dict[str, Any], *, page_size: int = 8
         out["prefill_buckets"] = tuple(int(b) for b in cfg["buckets"])
     if cfg.get("max_batch"):
         out["max_batch"] = int(cfg["max_batch"])
-    if cfg.get("kv_layout") == "paged":
-        out["kv_layout"] = "paged"
-        out["page_size"] = int(page_size)
-        if cfg.get("num_pages"):
-            out["num_pages"] = int(cfg["num_pages"])
+    out["page_size"] = int(page_size)
+    if cfg.get("num_pages"):
+        out["num_pages"] = int(cfg["num_pages"])
     if cfg.get("fused_decode"):
         out["fused_decode"] = True
     if cfg.get("weight_dtype") and cfg["weight_dtype"] != "f32":

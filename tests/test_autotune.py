@@ -122,15 +122,52 @@ def test_serve_space_predicates():
         assert not (c.get("weight_dtype") == "int8" and
                     c.get("sharding") == "tp")
         assert not (c.get("spec", 0) and c.get("fused_decode"))
-        # normalize: disagg candidates are paged candidates
-        if c.get("disagg", "off") != "off":
-            assert c.get("kv_layout") == "paged"
+        # normalize: a colocated lane has no decode-batch multiplier
+        if c.get("disagg", "off") == "off":
+            assert c.get("disagg_decode_batch") == 1
+
+
+def test_serve_space_has_one_layout_and_committed_tuned_still_loads():
+    """The serve space's axes are these nine and no layout among them;
+    the committed TUNED.json, written when there was one, still loads,
+    and what the appliers take from it builds an engine (a knob the
+    code no longer has is not read)."""
+    import dataclasses
+
+    from paddle_tpu import serving
+    from paddle_tpu.models import gpt as G
+
+    ctx = SpaceContext(dp=1, n_devices=8, platform="cpu", vocab_size=256,
+                       max_seq=64, max_batch=8, page_size=8)
+    assert set(serve_axes(ctx)) == {
+        "buckets", "max_batch", "num_pages", "fused_decode", "spec",
+        "weight_dtype", "sharding", "disagg", "disagg_decode_batch"}
+    inc = serve_incumbent(ctx)
+    assert set(inc.as_dict()) == set(serve_axes(ctx)) | {"tp"}
+    assert validate_serve(inc.as_dict(), ctx) is None
+    _, refused = enumerate_space("serve", serve_axes(ctx), ctx)
+    assert "invalid:disagg_needs_paged" not in {r for _, r in refused}
+
+    doc = tuned.load(os.path.join(REPO, "TUNED.json"))
+    ek = tuned.engine_kwargs(doc, page_size=8)
+    geometry = {"prefill_buckets", "max_batch", "page_size", "num_pages",
+                "fused_decode", "weight_dtype", "sharding", "tp"}
+    assert ek and set(ek) <= geometry
+    assert geometry <= {f.name
+                        for f in dataclasses.fields(serving.EngineConfig)}
+    assert set(tuned.serve_lane_kwargs(doc)) == {
+        "spec", "disagg", "disagg_decode_batch"}
+    cfg = G.GPT_TINY.scaled(num_layers=1, max_seq_len=64)
+    eng = serving.DecodeEngine(
+        G.init_params(jax.random.PRNGKey(0), cfg), cfg,
+        serving.EngineConfig(max_seq=64, **ek))
+    assert eng.buckets == tuple(ek["prefill_buckets"])
 
 
 def test_serve_disagg_ratio_bounds():
     ctx = SpaceContext(n_devices=8, max_seq=64, page_size=8)
-    base = dict(buckets=(16, 32), max_batch=4, kv_layout="paged",
-                num_pages=0, fused_decode=False, spec=0,
+    base = dict(buckets=(16, 32), max_batch=4, num_pages=0,
+                fused_decode=False, spec=0,
                 weight_dtype="f32", sharding="none",
                 disagg_decode_batch=1)
     assert validate_serve(dict(base, disagg="1:2"), ctx) is None
@@ -141,8 +178,8 @@ def test_serve_disagg_ratio_bounds():
 
 def test_serve_paged_geometry_predicates():
     ctx = SpaceContext(n_devices=1, max_seq=64, max_batch=8, page_size=8)
-    base = dict(max_batch=8, kv_layout="paged", num_pages=0,
-                fused_decode=False, spec=0, weight_dtype="f32",
+    base = dict(max_batch=8, num_pages=0, fused_decode=False, spec=0,
+                weight_dtype="f32",
                 sharding="none", disagg="off", disagg_decode_batch=1)
     assert validate_serve(dict(base, buckets=(12, 32)), ctx) \
         == "invalid:bucket_page_align"
@@ -158,8 +195,8 @@ def test_serve_paged_geometry_predicates():
 
 def test_tp_needs_devices():
     ctx = SpaceContext(n_devices=1, max_seq=64, page_size=8)
-    knobs = dict(buckets=(16,), max_batch=4, kv_layout="slab",
-                 num_pages=0, fused_decode=False, spec=0,
+    knobs = dict(buckets=(16,), max_batch=4, num_pages=0,
+                 fused_decode=False, spec=0,
                  weight_dtype="f32", sharding="tp", tp=2, disagg="off",
                  disagg_decode_batch=1)
     assert validate_serve(knobs, ctx) == "invalid:tp_needs_devices"
@@ -276,7 +313,7 @@ def test_static_train_vchunk_and_hbm_budget():
 
 def test_static_serve_hand_math():
     inc = Candidate.make("serve", buckets=(16, 32), max_batch=8,
-                         kv_layout="slab", num_pages=0, fused_decode=False,
+                         num_pages=0, fused_decode=False,
                          spec=0, weight_dtype="f32", sharding="none",
                          disagg="off", disagg_decode_batch=1, tp=1)
     base = BaseStats(flops=1e9, bytes_accessed=8e8, peak_hbm_bytes=2e9,
@@ -295,12 +332,11 @@ def test_static_serve_hand_math():
     assert predict_serve(inc.replace(spec=3), base, hw).ms \
         == pytest.approx(8.0 / 2.5)
     # disagg 1:2 with decode-batch x2: ms * (1+2)/max(2*2,1)
-    dis = inc.replace(disagg="1:2", disagg_decode_batch=2,
-                      kv_layout="paged")
+    dis = inc.replace(disagg="1:2", disagg_decode_batch=2)
     assert predict_serve(dis, base, hw).ms == pytest.approx(8.0 * 3 / 4)
     # page pool counts against the budget: 100 pages * 1e6 B on a 2e9
     # cap -> 2.1e9 > 1.9e9
-    pool = inc.replace(kv_layout="paged", num_pages=100)
+    pool = inc.replace(num_pages=100)
     est = predict_serve(pool, base,
                         HwModel(1e12, 1e11, hbm_capacity_bytes=2e9,
                                 on_acc=True), kv_page_bytes=1e6)
@@ -609,7 +645,7 @@ def _scripted_tunes():
                          max_batch=4, page_size=8, vocab_size=64)
     s_inc = serve_incumbent(s_ctx)
     s_win = Candidate.make("serve", buckets=(8, 16), max_batch=4,
-                           kv_layout="paged", num_pages=16,
+                           num_pages=16,
                            fused_decode=False, spec=2, weight_dtype="int8",
                            sharding="none", tp=1, disagg="off",
                            disagg_decode_batch=1, error_feedback=False)
@@ -692,7 +728,7 @@ def test_tuned_appliers_respect_caller_and_mesh(tmp_path):
 
     ek = tuned.engine_kwargs(doc, page_size=8)
     assert ek == {"prefill_buckets": (8, 16), "max_batch": 4,
-                  "kv_layout": "paged", "page_size": 8, "num_pages": 16,
+                  "page_size": 8, "num_pages": 16,
                   "weight_dtype": "int8"}
     assert tuned.serve_lane_kwargs(doc) == {"spec": 2, "disagg": "off",
                                             "disagg_decode_batch": 1}

@@ -140,12 +140,6 @@ def test_fused_ln_dropout_fwd_bwd(one_chip):
 ROW = ((SERVE_B, NH, HD), BF16)
 
 
-def test_fused_decode_slab(one_chip):
-    cache = ((SERVE_B, SERVE_S, NH, HD), BF16)
-    _compile(PK.fused_decode_attention, one_chip, ROW, cache, cache, ROW,
-             ROW, ((SERVE_B,), jnp.int32), ((SERVE_B,), jnp.int32))
-
-
 def test_fused_decode_paged(one_chip):
     m = SERVE_S // PAGE
     pool = ((1 + SERVE_B * m, PAGE, NH, HD), BF16)
@@ -304,30 +298,29 @@ def _engine(num_layers, **ecfg):
 
 def _lower_donated(fn, example, sharding):
     """``fn`` lowered for the described chip from its example arguments'
-    shapes, the cache arguments donated as the engine does (the slabs at
-    1 and 2; the paged pair's one tuple of arrays at 1)."""
+    shapes, the cache arguments donated as the engine does (the prefill
+    and decode programs' one tuple of arrays at 1)."""
     args = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype
                                        if not hasattr(a, "dtype")
                                        else a.dtype, sharding=sharding),
         example)
-    donated = (1,) if isinstance(example[1], tuple) else (1, 2)
-    return jax.jit(fn, donate_argnums=donated).lower(*args)
+    return jax.jit(fn, donate_argnums=(1,)).lower(*args)
 
 
-@pytest.mark.parametrize("kv_layout", ["slab", "paged"])
 @pytest.mark.parametrize("fused", [False, True], ids=["xla", "fused"])
-def test_gpt_wide_decode_tick(one_chip, kv_layout, fused):
+def test_gpt_wide_decode_tick(one_chip, fused):
     """The serving engine's decode tick at gpt_wide widths (depth 1),
-    default and fused_decode paths. A paged engine reads its cache
-    through the Pallas kernel on a TPU whatever fused_decode says."""
+    default and fused_decode paths. The engine reads its cache through
+    the Pallas kernel on a TPU whatever fused_decode says."""
     eng = _engine(1, max_seq=SERVE_S, max_batch=SERVE_B,
-                  kv_layout=kv_layout, fused_decode=fused)
-    assert eng.kv_path == ("pallas_paged" if kv_layout == "paged"
-                           else "slab")
+                  fused_decode=fused)
+    assert eng.kv_path == "pallas_paged"
     lowered = _lower_donated(*eng._decode_program(), one_chip)
-    assert ("tpu_custom_call" in lowered.as_text()) == (
-        fused or kv_layout == "paged")
+    text = lowered.as_text()
+    assert "paged_decode_attention" in text and "tpu_custom_call" in text
+    # fused_decode adds its own launches (layernorms, the logits head)
+    assert text.count("tpu_custom_call") > (2 if fused else 0)
     lowered.compile()
 
 
@@ -343,7 +336,7 @@ def test_paged_engine_gathers_where_mosaic_refuses_the_page(one_chip):
     assert PK.paged_decode_tiles(NH, HD)
     eng = serving.DecodeEngine(
         G.init_params(jax.random.PRNGKey(0), cfg), cfg,
-        serving.EngineConfig(max_batch=4, max_seq=32, kv_layout="paged",
+        serving.EngineConfig(max_batch=4, max_seq=32,
                              prefill_buckets=(8, 16), page_size=8,
                              weight_dtype="bf16"))
     assert eng.kv_path == "xla_gather"
@@ -382,7 +375,7 @@ def _jamba_cut_engine():
         lambda s: np.zeros(s, np.float32), shapes,
         is_leaf=lambda s: isinstance(s, tuple))
     return serving.DecodeEngine(params, cfg, serving.EngineConfig(
-        max_batch=64, max_seq=CELL_S, kv_layout="paged", page_size=PAGE,
+        max_batch=64, max_seq=CELL_S, page_size=PAGE,
         weight_dtype="bf16", prefix_cache=False,
         prefill_buckets=(256, 2048)))
 
@@ -399,7 +392,7 @@ def _compiled_tick(cell, sharding):
     if cell not in _TICKS:
         eng = (_jamba_cut_engine() if cell == "jamba_cut" else
                _engine(CELL_L, max_seq=CELL_S, max_batch=CELL_B,
-                       kv_layout="paged", num_pages=CELL_PAGES))
+                       num_pages=CELL_PAGES))
         _TICKS[cell] = eng, _lower_donated(
             *eng._decode_program(), sharding).compile()
     return _TICKS[cell]

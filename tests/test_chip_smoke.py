@@ -26,7 +26,7 @@ def test_phase_train_rehearsal():
 
 
 def test_phase_serve_rehearsal():
-    CS.phase_serve(TINY, dict(max_seq=64, max_batch=4, kv_layout="paged",
+    CS.phase_serve(TINY, dict(max_seq=64, max_batch=4,
                               weight_dtype="bf16"),
                    (20, 40, 8, 12, 33, 50), 16, 8, 8, 0, jax.devices()[0])
 

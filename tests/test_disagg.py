@@ -1,6 +1,6 @@
 """ISSUE 17 disaggregated-serving coverage (docs/serving.md
 "Disaggregation"): KV handoff wire format (CRC + jsonable + socket
-channels), colocated-vs-disagg greedy parity on both cache layouts,
+channels), colocated-vs-disagg greedy parity,
 the degrade-never-drop fallback matrix, the pool-level prefix index,
 the tp=2 -> tp=1 page-wise redistribution (page-exact, bounded
 transient residency), and the subprocess gang's mid-transfer kill with
@@ -23,6 +23,8 @@ from paddle_tpu.serving import kv_transfer as kvt
 from paddle_tpu.serving.disagg import (DisaggRouter, LocalReplica,
                                        SharedPrefixIndex)
 
+from serving_helpers import greedy_engine as _greedy
+
 
 @pytest.fixture(scope="module")
 def tiny_model():
@@ -36,17 +38,8 @@ def make_engine(tiny_model, **kw):
     kw.setdefault("max_batch", 4)
     kw.setdefault("max_seq", 32)
     kw.setdefault("prefill_buckets", (8, 16))
+    kw.setdefault("page_size", 8)
     return serving.DecodeEngine(params, cfg, serving.EngineConfig(**kw))
-
-
-def _greedy(engine, prompt, n):
-    slot, logits = engine.start_sequence(prompt)
-    toks = [int(np.argmax(logits))]
-    for _ in range(n - 1):
-        out = engine.decode_step({slot: toks[-1]})
-        toks.append(int(np.argmax(out[slot])))
-    engine.free_sequence(slot)
-    return toks
 
 
 def _f32(a):
@@ -95,10 +88,8 @@ def test_kv_socket_channel_roundtrip(tiny_model):
     """The frame-stream socket channel (prefill replica -> decode
     replica's KVTransferServer) delivers a committed handoff exactly
     once; the adopted KV decodes identically to the source."""
-    src = make_engine(tiny_model, kv_layout="paged", page_size=8,
-                      role="prefill")
-    dst = make_engine(tiny_model, kv_layout="paged", page_size=8,
-                      role="decode")
+    src = make_engine(tiny_model, role="prefill")
+    dst = make_engine(tiny_model, role="decode")
     server = kvt.KVTransferServer().start()
     try:
         prompt = [2, 7, 1, 8, 2, 8, 1, 8, 2, 8]
@@ -125,6 +116,25 @@ def test_kv_socket_channel_roundtrip(tiny_model):
         server.close()
 
 
+def test_handoff_of_another_layout_is_refused(tiny_model):
+    """Input from outside the process: a hand-off whose fingerprint names
+    a layout this engine does not have (an older peer's ``slab``) is
+    refused field by field before a page is claimed."""
+    src = make_engine(tiny_model, role="prefill")
+    dst = make_engine(tiny_model, role="decode")
+    assert src.cache_fingerprint()["layout"] == "paged"
+    slot, _ = src.start_sequence([3, 1, 4, 1, 5, 9])
+    handoff = src.export_request_kv(slot)
+    handoff["fingerprint"] = dict(handoff["fingerprint"], layout="slab")
+    pages_before = dst.cache.free_page_count()
+    with pytest.raises(kvt.CacheConfigMismatch,
+                       match="layout: expected 'paged', got 'slab'"):
+        dst.adopt_request_kv(handoff)
+    assert dst.cache.free_page_count() == pages_before
+    assert dst.cache.free_slot_count() == dst.ecfg.max_batch
+    src.free_sequence(slot)
+
+
 # ---------------------------------------------------------------------------
 # router parity + fallback matrix (in-process replicas)
 # ---------------------------------------------------------------------------
@@ -134,19 +144,13 @@ def _stop_all(replicas):
         r.stop()
 
 
-@pytest.mark.parametrize("layout_kw", [
-    pytest.param({}, id="slab"),
-    pytest.param({"kv_layout": "paged", "page_size": 8}, id="paged"),
-])
-def test_disagg_router_greedy_parity(tiny_model, layout_kw):
+def test_disagg_router_greedy_parity(tiny_model):
     """Phase-split serving is a pure routing change: the disagg router
     (prefill replica -> KV migration -> decode replica) must emit the
-    colocated engine's exact greedy tokens on both cache layouts."""
-    colo = make_engine(tiny_model, **layout_kw)
-    reps = [LocalReplica(make_engine(tiny_model, role="prefill",
-                                     **layout_kw)),
-            LocalReplica(make_engine(tiny_model, role="decode",
-                                     **layout_kw))]
+    colocated engine's exact greedy tokens."""
+    colo = make_engine(tiny_model)
+    reps = [LocalReplica(make_engine(tiny_model, role="prefill")),
+            LocalReplica(make_engine(tiny_model, role="decode"))]
     router = DisaggRouter(reps)
     rng = np.random.RandomState(17)
     try:
@@ -226,13 +230,12 @@ def test_shared_prefix_index_cross_replica_hit(tiny_model):
     """The pool-level prefix index: a system prompt prefilled on the
     prefill replica is published gang-wide; the next request's fetch
     hits it (per-phase counters move) and the tokens stay exact."""
-    layout_kw = {"kv_layout": "paged", "page_size": 8}
-    colo = make_engine(tiny_model, **layout_kw)
+    colo = make_engine(tiny_model)
     index = SharedPrefixIndex()
-    reps = [LocalReplica(make_engine(tiny_model, role="prefill",
-                                     **layout_kw), prefix_index=index),
-            LocalReplica(make_engine(tiny_model, role="decode",
-                                     **layout_kw), prefix_index=index)]
+    reps = [LocalReplica(make_engine(tiny_model, role="prefill"),
+                         prefix_index=index),
+            LocalReplica(make_engine(tiny_model, role="decode"),
+                         prefix_index=index)]
     router = DisaggRouter(reps, prefix_index=index)
     system_prompt = [7] * 10 + [3, 5]          # 12 tokens -> 1 full page
     try:
@@ -263,10 +266,8 @@ def test_tp2_to_tp1_handoff_page_exact_bounded_residency(tiny_model):
     BIT-exact against the source's canonical pages, and the transient
     canonical footprint never exceeds the per-chunk budget (let alone
     both layouts at once) — arXiv:2112.01075's discipline."""
-    src = make_engine(tiny_model, kv_layout="paged", page_size=8,
-                      sharding="tp", tp=2, role="prefill")
-    dst = make_engine(tiny_model, kv_layout="paged", page_size=8,
-                      role="decode")
+    src = make_engine(tiny_model, sharding="tp", tp=2, role="prefill")
+    dst = make_engine(tiny_model, role="decode")
     prompt = list(range(2, 14))                # 12 tokens -> 2 pages
     slot, logits = src.start_sequence(prompt)
     n_pages = src.cache.pages_for(len(prompt))

@@ -381,7 +381,8 @@ def tiny_serving():
     params = gpt_model.init_params(jrandom.PRNGKey(0), cfg)
     engine = pserving.DecodeEngine(
         params, cfg, pserving.EngineConfig(max_batch=2, max_seq=16,
-                                           prefill_buckets=(4, 8)))
+                                           prefill_buckets=(4, 8),
+                                           page_size=4))
     engine.warmup()
     return pserving, engine, cfg
 

@@ -56,7 +56,7 @@ _WEIGHTS = []
 
 
 def _engine(cell, **kw):
-    ecfg = dict(max_batch=4, max_seq=64, kv_layout="paged", page_size=8,
+    ecfg = dict(max_batch=4, max_seq=64, page_size=8,
                 prefix_cache=False, prefill_buckets=(8, 16, 32))
     ecfg.update(kw)
     if not _WEIGHTS:                 # one draw serves every engine here
@@ -188,7 +188,6 @@ def test_scheduler_batches_a_hybrid_model_and_the_spans_say_so(cell):
 
 
 REFUSALS = [
-    (dict(kv_layout="slab"), "slab"),
     (dict(prefix_cache=True), "prefix cache"),
     (dict(verify_window=3), "verify window"),
     (dict(sharding="tp", tp=2), "tensor-parallel"),
@@ -201,7 +200,7 @@ REFUSALS = [
                          ids=[m for _, m in REFUSALS])
 def test_what_cannot_carry_recurrent_state_is_refused_by_name(
         cell, kw, mechanism):
-    ecfg = dict(kv_layout="paged", prefix_cache=False)
+    ecfg = dict(prefix_cache=False)
     ecfg.update(kw)
     with pytest.raises(ValueError, match="recurrent") as e:
         serving.DecodeEngine({}, _cfg(cell), serving.EngineConfig(**ecfg))
@@ -221,5 +220,4 @@ def test_speculative_wrapper_and_kv_transfer_refuse_a_hybrid_engine(engine):
     engine.free_sequence(slot)
     # the default engine (prefix_cache=True) is refused, not quietly fixed
     with pytest.raises(ValueError, match="prefix_cache=False"):
-        serving.DecodeEngine({}, J.JAMBA_TINY, serving.EngineConfig(
-            kv_layout="paged"))
+        serving.DecodeEngine({}, J.JAMBA_TINY, serving.EngineConfig())

@@ -14,9 +14,11 @@ from paddle_tpu.models import gpt
 from paddle_tpu.observability import metrics as om
 from paddle_tpu.serving import metrics as sm
 from paddle_tpu.serving import sampling as samp
-from paddle_tpu.serving.kv_cache import CacheFullError
 from paddle_tpu.serving.paged_kv import (PagedKVCache, PagePoolFullError,
                                          PrefixCache)
+
+from serving_helpers import greedy_engine as _greedy
+from serving_helpers import greedy_reference as _reference
 
 
 @pytest.fixture(scope="module")
@@ -31,19 +33,13 @@ def make_engine(tiny_model, **kw):
     kw.setdefault("max_batch", 4)
     kw.setdefault("max_seq", 32)
     kw.setdefault("prefill_buckets", (8, 16))
+    kw.setdefault("page_size", 8)
     return serving.DecodeEngine(params, cfg, serving.EngineConfig(**kw))
 
 
 @pytest.fixture(scope="module")
-def slab_eng(tiny_model):
-    eng = make_engine(tiny_model)
-    eng.warmup()
-    return eng
-
-
-@pytest.fixture(scope="module")
 def paged_eng(tiny_model):
-    eng = make_engine(tiny_model, kv_layout="paged", page_size=8)
+    eng = make_engine(tiny_model)
     eng.warmup()
     return eng
 
@@ -52,16 +48,6 @@ def _recompile_total():
     snap = om.default_registry().snapshot()
     return sum(s["value"] for s in
                snap.get("paddle_recompiles_total", {}).get("series", []))
-
-
-def _greedy(engine, prompt, n):
-    slot, logits = engine.start_sequence(prompt)
-    toks = [int(np.argmax(logits))]
-    for _ in range(n - 1):
-        out = engine.decode_step({slot: toks[-1]})
-        toks.append(int(np.argmax(out[slot])))
-    engine.free_sequence(slot)
-    return toks
 
 
 # ---------------------------------------------------------------------------
@@ -129,20 +115,123 @@ def test_prefix_cache_lookup_insert_reclaim():
     assert cache.lookup(toks)[0] == 0         # entries really gone
 
 
+def test_can_admit_counts_what_the_prefix_cache_would_give_back():
+    """Pages that only the prefix cache still holds are free for
+    admission: ``_take_pages`` reclaims them on demand, so ``can_admit``
+    has to count them (it once asked the bound method ``reclaim`` for an
+    attribute of the cache and read 0). 1 free page + 15 reclaimable
+    admits a prompt of 16 pages; pages a live slot shares do not count."""
+    pool = PagedKVCache(num_layers=1, max_slots=3, max_seq=64,
+                        num_heads=1, head_dim=2, page_size=4,
+                        num_pages=17)                # 16 usable
+    cache = PrefixCache(pool)
+    pool.prefix_cache = cache
+    toks = list(range(61))
+    s = pool.alloc(length=61)                        # all 16 pages
+    assert cache.insert(toks, pool.table_row(s)) == 15
+    assert not pool.can_admit(4)                     # every page is held
+    pool.free(s)                                     # the partial page
+    assert pool.free_page_count() == 1 and cache.reclaimable() == 15
+    assert pool.can_admit(64) and pool.can_admit(4)
+    assert not pool.can_admit(65)                     # 17 pages: never
+    # a slot attached to 2 of the cached pages pins them
+    shared = [int(p) for p in cache.lookup(toks[:9])[1]]
+    s2 = pool.alloc(length=9, prefix_pages=shared)   # + the free page
+    assert pool.free_page_count() == 0 and cache.reclaimable() == 13
+    assert pool.can_admit(52) and not pool.can_admit(53)
+    # and admission really gets what can_admit promised
+    s3 = pool.alloc(length=52)
+    assert cache.reclaimable() == 0 and pool.free_page_count() == 0
+    pool.free(s2)
+    pool.free(s3)
+    # without a prefix cache the free list alone counts
+    bare = PagedKVCache(num_layers=1, max_slots=2, max_seq=16, num_heads=1,
+                        head_dim=2, page_size=4, num_pages=4)
+    bare.alloc(length=8)
+    assert bare.can_admit(4) and not bare.can_admit(8)
+
+
+def test_alloc_reclaims_from_the_prefix_cache_but_not_what_it_attaches():
+    """Pool pressure inside ``alloc``: the pool asks its prefix cache to
+    give pages back, and the prefix pages the new slot attaches are
+    pinned first, so the reclaim that drops their entries cannot free
+    (and recycle) them."""
+    pool = PagedKVCache(num_layers=1, max_slots=2, max_seq=32, num_heads=1,
+                        head_dim=2, page_size=4, num_pages=9)   # 8 usable
+    cache = PrefixCache(pool)
+    pool.prefix_cache = cache
+    toks = list(range(16))
+    s = pool.alloc(length=16)
+    assert cache.insert(toks, pool.table_row(s)) == 4
+    pool.free(s)
+    assert pool.free_page_count() == 4 and cache.reclaimable() == 4
+    plen, shared = cache.lookup(toks[:9])
+    assert plen == 8
+    assert pool.can_admit(32, prefix_len=plen)       # 6 own pages of 4 + 4
+    s2 = pool.alloc(length=32, prefix_pages=shared)  # has to reclaim 2
+    row = pool.table_row(s2)
+    assert [int(p) for p in row[:2]] == [int(p) for p in shared]
+    assert len(set(int(p) for p in row)) == 8 and 0 not in row
+    # the entry the lookup freshened outlived the reclaim (LRU went
+    # first): its two pages are the cache's and the slot's, the rest the
+    # slot's alone
+    assert [int(pool._ref[int(p)]) for p in row] == [2, 2, 1, 1, 1, 1, 1, 1]
+    assert len(cache) == 1 and pool.free_page_count() == 0
+    pool.free(s2)
+    assert pool.free_page_count() == 6 and cache.reclaimable() == 2
+
+
+def test_default_engine_drains_a_queue_of_fresh_prompts(tiny_model):
+    """PERF.md section 7's reproduction, now a passing test: 4 slots, 16
+    usable pages, the default prefix cache over the whole pool
+    (``prefix_cache_pages`` 0), eight fresh prompts of three full pages.
+    Finished requests leave their pages with the prefix cache, so the
+    free list alone never again covers a prompt (the parent stopped at
+    three queued requests, 1 free page, 15 reclaimable, for good); the
+    queued ones are admitted all the same, by reclaiming."""
+    cfg, _ = tiny_model
+    eng = make_engine(tiny_model, page_size=4, num_pages=17)
+    assert eng.prefix is not None and eng.ecfg.prefix_cache_pages == 0
+    eng.warmup()
+    sched = serving.Scheduler(eng, serving.SchedulerConfig(
+        default_timeout_s=120.0))
+    rng = np.random.RandomState(41)
+    prompts = [rng.randint(0, cfg.vocab_size, size=12).tolist()
+               for _ in range(8)]
+    reqs = [sched.submit(p, max_new_tokens=4) for p in prompts]
+    reclaimed_for = 0
+    for _ in range(60):
+        if not sched.pending():
+            break
+        free = eng.cache.free_page_count()
+        queued = [r for r in reqs if r.state == "queued"]
+        sched.step()
+        admitted = [r for r in queued if r.state != "queued"]
+        if len(admitted) * 3 > free:
+            reclaimed_for += len(admitted)   # the free list did not cover
+    assert [r.state for r in reqs] == ["done"] * 8, \
+        [(r.state, r.error) for r in reqs]
+    assert reclaimed_for >= 3
+    assert sched.preemptions == 0
+    for p, r in zip(prompts, reqs):
+        assert r.tokens == _reference(eng, p, 4)
+
+
 # ---------------------------------------------------------------------------
-# paged engine parity (the acceptance bar: bit-match at f32)
+# engine parity (the acceptance bar: at f32 the engine's greedy tokens are
+# those of the cache-free full forward, ``reference_logits``)
 # ---------------------------------------------------------------------------
 
-def test_paged_tokens_bitmatch_slab(tiny_model, slab_eng, paged_eng):
+def test_paged_tokens_match_reference(tiny_model, paged_eng):
     cfg, _ = tiny_model
     rng = np.random.RandomState(7)
     for plen in (3, 9, 15):
         prompt = rng.randint(0, cfg.vocab_size, size=plen).tolist()
         assert _greedy(paged_eng, prompt, 8) == \
-            _greedy(slab_eng, prompt, 8)
+            _reference(paged_eng, prompt, 8)
 
 
-def test_paged_interleaved_slots_isolated(tiny_model, slab_eng, paged_eng):
+def test_paged_interleaved_slots_isolated(tiny_model, paged_eng):
     cfg, _ = tiny_model
     rng = np.random.RandomState(8)
     p_a = rng.randint(0, cfg.vocab_size, size=5).tolist()
@@ -156,11 +245,11 @@ def test_paged_interleaved_slots_isolated(tiny_model, slab_eng, paged_eng):
         tb.append(int(np.argmax(out[sb])))
     paged_eng.free_sequence(sa)
     paged_eng.free_sequence(sb)
-    assert ta == _greedy(slab_eng, p_a, 6)
-    assert tb == _greedy(slab_eng, p_b, 6)
+    assert ta == _reference(paged_eng, p_a, 6)
+    assert tb == _reference(paged_eng, p_b, 6)
 
 
-def test_prefix_cache_prefills_once(tiny_model, slab_eng, paged_eng):
+def test_prefix_cache_prefills_once(tiny_model, paged_eng):
     """The headline satellite: a repeated system prompt attaches its
     cached pages and prefills only the suffix — with identical logits,
     and every page refcount unwinding cleanly."""
@@ -179,8 +268,8 @@ def test_prefix_cache_prefills_once(tiny_model, slab_eng, paged_eng):
     t1, t2 = int(np.argmax(l1)), int(np.argmax(l2))
     o = eng.decode_step({s1: t1, s2: t2})
     assert int(np.argmax(o[s1])) == int(np.argmax(o[s2]))
-    # and matches the slab engine exactly
-    ref = _greedy(slab_eng, prompt, 2)
+    # and matches the reference exactly
+    ref = _reference(eng, prompt, 2)
     assert [t1, int(np.argmax(o[s1]))] == ref
     eng.free_sequence(s1)
     eng.free_sequence(s2)
@@ -190,15 +279,14 @@ def test_prefix_cache_prefills_once(tiny_model, slab_eng, paged_eng):
 
 
 @pytest.mark.slow
-def test_prefix_cache_off_still_correct(tiny_model, slab_eng):
+def test_prefix_cache_off_still_correct(tiny_model):
     """(slow: own engine warmup; the prefix-cache-ON paths are the
     tier-1-gated ones.)"""
-    eng = make_engine(tiny_model, kv_layout="paged", page_size=8,
-                      prefix_cache=False)
+    eng = make_engine(tiny_model, prefix_cache=False)
     eng.warmup()
     assert eng.prefix is None
     prompt = list(range(30, 42))
-    assert _greedy(eng, prompt, 5) == _greedy(slab_eng, prompt, 5)
+    assert _greedy(eng, prompt, 5) == _reference(eng, prompt, 5)
 
 
 # ---------------------------------------------------------------------------
@@ -214,7 +302,7 @@ def test_scheduler_hol_bypass_and_starvation_bound(tiny_model):
     # engine admits shorts while the long one cannot fit
     eng = serving.DecodeEngine(params, cfg, serving.EngineConfig(
         max_batch=2, max_seq=32, prefill_buckets=(8, 16),
-        kv_layout="paged", page_size=8, num_pages=6, prefix_cache=False))
+        page_size=8, num_pages=6, prefix_cache=False))
     eng.warmup()
     sched = serving.Scheduler(eng, serving.SchedulerConfig(
         hol_starvation_limit=100))
@@ -256,14 +344,14 @@ def test_scheduler_hol_bypass_and_starvation_bound(tiny_model):
     assert blocker.state == "active" and blocker2.state == "active"
 
 
-def test_scheduler_page_pool_preemption_recompute(tiny_model, slab_eng):
+def test_scheduler_page_pool_preemption_recompute(tiny_model):
     """Pool dry mid-generation: the youngest request is requeued
     (recompute) and both requests still produce exactly the greedy
     reference stream."""
     cfg, params = tiny_model
     eng = serving.DecodeEngine(params, cfg, serving.EngineConfig(
         max_batch=2, max_seq=32, prefill_buckets=(8,),
-        kv_layout="paged", page_size=4, num_pages=7, prefix_cache=False))
+        page_size=4, num_pages=7, prefix_cache=False))
     eng.warmup()
     sched = serving.Scheduler(eng, serving.SchedulerConfig(
         default_timeout_s=120.0))
@@ -276,89 +364,90 @@ def test_scheduler_page_pool_preemption_recompute(tiny_model, slab_eng):
         sched.step()
     assert ra.state == "done" and rb.state == "done"
     assert sched.preemptions >= 1
-    assert ra.tokens == _greedy(slab_eng, pa, 12)
-    assert rb.tokens == _greedy(slab_eng, pb, 12)
+    assert ra.tokens == _reference(eng, pa, 12)
+    assert rb.tokens == _reference(eng, pb, 12)
 
 
-def test_partial_feed_does_not_clobber_live_slots(tiny_model, slab_eng):
+def test_partial_feed_does_not_clobber_live_slots(tiny_model, paged_eng):
     """Regression: a LIVE slot excluded from a decode call rides as a
-    masked lane — its write must be suppressed (actives mask), not land
-    in its row 0. The spec draft's catch-up rounds feed exactly such
-    partial batches."""
+    masked lane — its write must land on the scratch page (its table row
+    is zeroed for the call), not in its own first page. The spec draft's
+    catch-up rounds feed exactly such partial batches."""
     cfg, _ = tiny_model
+    eng = paged_eng
     rng = np.random.RandomState(23)
     pa = rng.randint(0, cfg.vocab_size, size=4).tolist()
     pb = rng.randint(0, cfg.vocab_size, size=6).tolist()
-    sa, la = slab_eng.start_sequence(pa)
-    sb, lb = slab_eng.start_sequence(pb)
+    sa, la = eng.start_sequence(pa)
+    sb, lb = eng.start_sequence(pb)
     ta = [int(np.argmax(la))]
     for _ in range(4):                      # b sits live but unfed
-        ta.append(int(np.argmax(slab_eng.decode_step({sa: ta[-1]})[sa])))
+        ta.append(int(np.argmax(eng.decode_step({sa: ta[-1]})[sa])))
     tb = [int(np.argmax(lb))]
     for _ in range(4):
-        tb.append(int(np.argmax(slab_eng.decode_step({sb: tb[-1]})[sb])))
-    slab_eng.free_sequence(sa)
-    slab_eng.free_sequence(sb)
-    assert ta == _greedy(slab_eng, pa, 5)
-    assert tb == _greedy(slab_eng, pb, 5)   # row 0 survived the idle ride
+        tb.append(int(np.argmax(eng.decode_step({sb: tb[-1]})[sb])))
+    eng.free_sequence(sa)
+    eng.free_sequence(sb)
+    assert ta == _reference(eng, pa, 5)
+    assert tb == _reference(eng, pb, 5)     # row 0 survived the idle ride
 
 
 # ---------------------------------------------------------------------------
 # sampling
 # ---------------------------------------------------------------------------
 
-def test_sampling_greedy_lane_is_exact(tiny_model, slab_eng):
+def test_sampling_greedy_lane_is_exact(tiny_model, paged_eng):
     """temperature=0 through the sampled API == host argmax (the whole
     pre-sampling engine behavior)."""
     prompt = [3, 1, 4]
-    slot, logits, tok = slab_eng.start_sequence_sampled(
+    slot, logits, tok = paged_eng.start_sequence_sampled(
         prompt, serving.GREEDY)
     assert tok == int(np.argmax(logits))
-    out = slab_eng.decode_step_sampled({slot: tok}, None)
+    out = paged_eng.decode_step_sampled({slot: tok}, None)
     tok2, lg2 = out[slot]
     assert tok2 == int(np.argmax(lg2))
-    slab_eng.free_sequence(slot)
+    paged_eng.free_sequence(slot)
 
 
-def test_sampling_topk1_and_determinism(tiny_model, slab_eng):
+def test_sampling_topk1_and_determinism(tiny_model, paged_eng):
     prompt = [8, 6, 7]
     sp_k1 = serving.SamplingParams(temperature=1.0, top_k=1, seed=5)
-    slot, logits, tok = slab_eng.start_sequence_sampled(prompt, sp_k1)
+    slot, logits, tok = paged_eng.start_sequence_sampled(prompt, sp_k1)
     assert tok == int(np.argmax(logits))      # top_k=1 collapses to greedy
-    slab_eng.free_sequence(slot)
+    paged_eng.free_sequence(slot)
 
     sp = serving.SamplingParams(temperature=1.2, top_k=5, top_p=0.9,
                                 seed=123)
 
     def run():
-        slot, _l, t = slab_eng.start_sequence_sampled(prompt, sp)
+        slot, _l, t = paged_eng.start_sequence_sampled(prompt, sp)
         toks = [t]
         for _ in range(6):
-            out = slab_eng.decode_step_sampled({slot: toks[-1]}, {slot: sp})
+            out = paged_eng.decode_step_sampled({slot: toks[-1]}, {slot: sp})
             toks.append(out[slot][0])
-        slab_eng.free_sequence(slot)
+        paged_eng.free_sequence(slot)
         return toks
 
     first = run()
     assert first == run()                      # same seed -> same stream
     sp2 = serving.SamplingParams(temperature=1.2, top_k=5, top_p=0.9,
                                  seed=124)
-    slot, _l, t = slab_eng.start_sequence_sampled(prompt, sp2)
-    slab_eng.free_sequence(slot)               # different seed compiles 0
+    slot, _l, t = paged_eng.start_sequence_sampled(prompt, sp2)
+    paged_eng.free_sequence(slot)               # different seed compiles 0
 
 
-def test_sampling_respects_topk_support(tiny_model, slab_eng):
+def test_sampling_respects_topk_support(tiny_model, paged_eng):
     sp = serving.SamplingParams(temperature=1.5, top_k=3, seed=77)
-    slot, logits, tok = slab_eng.start_sequence_sampled([2, 7, 1], sp)
+    slot, logits, tok = paged_eng.start_sequence_sampled([2, 7, 1], sp)
     support = set(np.argsort(logits)[-3:].tolist())
     assert tok in support
     toks = [tok]
     for _ in range(8):
-        out = slab_eng.decode_step_sampled({slot: toks[-1]}, {slot: sp})
+        out = paged_eng.decode_step_sampled({slot: toks[-1]}, {slot: sp})
         t2, lg = out[slot]
         assert t2 in set(np.argsort(lg)[-3:].tolist())
         toks.append(t2)
-    slab_eng.free_sequence(slot)
+    paged_eng.free_sequence(slot)
 
 
 def test_adjusted_probs_np_matches_support():
@@ -406,22 +495,22 @@ def tp_eng(tiny_model):
     return eng
 
 
-def test_tp2_logits_match_single_chip(tiny_model, slab_eng, tp_eng):
+def test_tp2_logits_match_single_chip(tiny_model, paged_eng, tp_eng):
     cfg, _ = tiny_model
     rng = np.random.RandomState(9)
     prompt = rng.randint(0, cfg.vocab_size, size=7).tolist()
     st, lt = tp_eng.start_sequence(prompt)
-    sr, lr = slab_eng.start_sequence(prompt)
+    sr, lr = paged_eng.start_sequence(prompt)
     np.testing.assert_allclose(lt, lr, rtol=1e-4, atol=1e-4)
     a, b = int(np.argmax(lt)), int(np.argmax(lr))
     for _ in range(6):
         oa = tp_eng.decode_step({st: a})
-        ob = slab_eng.decode_step({sr: b})
+        ob = paged_eng.decode_step({sr: b})
         np.testing.assert_allclose(oa[st], ob[sr], rtol=1e-4, atol=1e-4)
         a, b = int(np.argmax(oa[st])), int(np.argmax(ob[sr]))
         assert a == b
     tp_eng.free_sequence(st)
-    slab_eng.free_sequence(sr)
+    paged_eng.free_sequence(sr)
 
 
 def test_tp2_zero_recompile_steady_state(tiny_model, tp_eng):
@@ -457,12 +546,12 @@ def test_tp_rejects_int8_and_bad_sizes(tiny_model):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("layout_kw", [
-    {"kv_layout": "paged", "page_size": 8, "prefill_buckets": (8,)},
+    {"prefill_buckets": (8,)},
     {"sharding": "tp", "tp": 2, "prefill_buckets": (8,)},
-])
+], ids=["one_chip", "tp2"])
 def test_poisoned_after_donation_failure_new_paths(tiny_model, layout_kw):
-    """The PR 9 donation-poisoning guard must cover the paged and tp
-    executables too."""
+    """The donation-poisoning guard covers the one-chip and the tp
+    executables."""
     eng = make_engine(tiny_model, **layout_kw)
     eng.warmup()
 
@@ -481,26 +570,19 @@ def test_poisoned_after_donation_failure_new_paths(tiny_model, layout_kw):
 
 
 @pytest.mark.parametrize("layout_kw", [
-    {"kv_layout": "paged", "page_size": 8, "prefill_buckets": (8,)},
+    {"prefill_buckets": (8,)},
     {"sharding": "tp", "tp": 2, "prefill_buckets": (8,)},
-])
+], ids=["one_chip", "tp2"])
 def test_recompile_negative_control_new_paths(tiny_model, layout_kw):
     """A same-name rebuild under a drifted signature must tick the
-    explainer + the engine's steady-state counter on the paged and tp
-    paths exactly like the slab path."""
+    explainer + the engine's steady-state counter, on one chip and
+    under the tp mesh."""
     eng = make_engine(tiny_model, **layout_kw)
     eng._prefill_exec(8)
     eng._warm = True
     before = _recompile_total()
-    if eng.paged:
-        fn, example = eng._prefill_program(16)
-    else:
-        example = (eng.qparams, eng.cache.k, eng.cache.v,
-                   np.zeros((1, 12), np.int32), np.int32(1), np.int32(0),
-                   *eng._samp_scalar_examples())
-        fn = eng._prefill_fn
-    eng._compile("prefill_b8", fn, example,
-                 donate_argnums=eng._donated(fn))
+    fn, example = eng._prefill_program(16)
+    eng._compile("prefill_b8", fn, example, donate_argnums=(1,))
     assert _recompile_total() - before == 1
     assert eng.steady_state_recompiles == 1
 
@@ -514,6 +596,7 @@ def make_spec(tiny_model, k=3, draft_layers=1, same_params=False, **kw):
     kw.setdefault("max_batch", 4)
     kw.setdefault("max_seq", 32)
     kw.setdefault("prefill_buckets", (8, 16))
+    kw.setdefault("page_size", 8)
     target = serving.DecodeEngine(params, cfg, serving.EngineConfig(
         verify_window=k + 1, **kw))
     if same_params:
@@ -545,13 +628,13 @@ def spec_self_eng(tiny_model):
     return spec
 
 
-def test_spec_greedy_exact(tiny_model, slab_eng, spec_eng):
+def test_spec_greedy_exact(tiny_model, spec_eng):
     cfg, _ = tiny_model
     spec = spec_eng
     rng = np.random.RandomState(13)
     for plen in (3, 8):
         prompt = rng.randint(0, cfg.vocab_size, size=plen).tolist()
-        want = _greedy(slab_eng, prompt, 12)
+        want = _reference(spec.target, prompt, 12)
         slot, _l, tok = spec.start_sequence_sampled(prompt, serving.GREEDY)
         got = [tok]
         while len(got) < 12:
@@ -578,7 +661,7 @@ def test_spec_self_draft_accepts_everything(tiny_model, spec_self_eng):
     assert spec.stats.tokens_per_window == 3.0
 
 
-def test_spec_interleaved_slots(tiny_model, slab_eng, spec_eng):
+def test_spec_interleaved_slots(tiny_model, spec_eng):
     cfg, _ = tiny_model
     spec = spec_eng
     rng = np.random.RandomState(17)
@@ -595,8 +678,8 @@ def test_spec_interleaved_slots(tiny_model, slab_eng, spec_eng):
     spec.free_sequence(sa)
     spec.free_sequence(sb)
     n = min(len(ta), len(tb), 8)
-    assert ta[:n] == _greedy(slab_eng, p_a, n)
-    assert tb[:n] == _greedy(slab_eng, p_b, n)
+    assert ta[:n] == _reference(spec.target, p_a, n)
+    assert tb[:n] == _reference(spec.target, p_b, n)
 
 
 def test_spec_sampled_rejection_math(tiny_model, spec_self_eng):
@@ -615,7 +698,7 @@ def test_spec_sampled_rejection_math(tiny_model, spec_self_eng):
     assert spec.stats.accepted - acc0 == spec.stats.proposed - prop0 > 0
 
 
-def test_spec_scheduler_end_to_end(tiny_model, slab_eng, spec_eng):
+def test_spec_scheduler_end_to_end(tiny_model, spec_eng):
     """Spec engine behind the full scheduler: requests complete, emitted
     streams equal the target-only greedy reference, zero recompiles."""
     cfg, _ = tiny_model
@@ -631,7 +714,7 @@ def test_spec_scheduler_end_to_end(tiny_model, slab_eng, spec_eng):
         sched.step()
     assert all(r.state == "done" for r in reqs)
     for p, r in zip(prompts, reqs):
-        assert r.tokens == _greedy(slab_eng, p, len(r.tokens))
+        assert r.tokens == _reference(spec.target, p, len(r.tokens))
         assert len(r.tokens) == 7
     assert _recompile_total() - before == 0
     assert spec.steady_state_recompiles == 0
@@ -639,26 +722,6 @@ def test_spec_scheduler_end_to_end(tiny_model, slab_eng, spec_eng):
     snap = om.default_registry().snapshot()
     hist = snap["paddle_serve_spec_accepted_tokens"]["series"][0]
     assert hist["count"] >= spec.stats.windows > 0
-
-
-@pytest.mark.slow
-def test_spec_paged_target(tiny_model, slab_eng):
-    """Spec decode over a PAGED target+draft — the verify window's
-    scatter path. (slow: its own two-engine warmup; the slab verify
-    path + the paged decode/prefill paths are tier-1-covered above,
-    and serve_bench's spec lane runs on every bench refresh.)"""
-    cfg, _ = tiny_model
-    spec = make_spec(tiny_model, k=2, kv_layout="paged", page_size=8)
-    spec.warmup()
-    prompt = [9, 4, 2, 6]
-    want = _greedy(slab_eng, prompt, 9)
-    slot, _l, tok = spec.start_sequence_sampled(prompt, serving.GREEDY)
-    got = [tok]
-    while len(got) < 9:
-        out = spec.generate_step({slot: got[-1]}, {slot: serving.GREEDY})
-        got.extend(out[slot])
-    spec.free_sequence(slot)
-    assert got[:9] == want
 
 
 # ---------------------------------------------------------------------------
@@ -725,8 +788,7 @@ def test_carried_pools_match_xs_ys_scan(tiny_model, monkeypatch, program):
     from paddle_tpu.serving import engine as engine_mod
     from paddle_tpu.serving import model as model_mod
 
-    eng = make_engine(tiny_model, kv_layout="paged", page_size=8,
-                      verify_window=3)
+    eng = make_engine(tiny_model, verify_window=3)
     assert eng.kv_path == "xla_gather"
     fn, args = _paged_program(eng, program, np.random.default_rng(5))
     kp, vp = _seeded_pools(eng, 6)
@@ -757,6 +819,66 @@ def test_carried_pools_match_xs_ys_scan(tiny_model, monkeypatch, program):
     assert set(changed.tolist()) <= allowed, changed
 
 
+def test_serve_bench_engine_parity_lane_holds_the_reference(tiny_model):
+    """tools/serve_bench.py's acceptance lane, whose oracle was a second
+    cached engine: the engine's greedy tokens against ``reference_logits``
+    and the tp=2 engine against the one-chip one."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "serve_bench.py")
+    spec = importlib.util.spec_from_file_location("serve_bench", path)
+    sb = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(sb)
+    cfg, params = tiny_model
+    out = sb.engine_parity_lane(
+        params, cfg, dict(max_batch=2, max_seq=32, prefill_buckets=(8,),
+                          page_size=sb.PAGE_SIZE), seed=3, n_tokens=8)
+    assert out["tokens"] == 8 and out["tokens_match_reference"] is True
+    assert out["tp2_tokens_match"] is True
+    assert out["tp2_max_logit_diff"] < 1e-4
+
+
+@pytest.mark.parametrize("kv_path", ["xla_gather", "pallas_paged"])
+def test_dead_lane_leaves_every_page_but_scratch(tiny_model, kv_path):
+    """The paged heir of the masked-lane regression: a lane that does
+    not ride (all-zero table row, ``actives`` 0) writes the scratch page
+    and nothing else, in both lowerings of the tick; a live lane beside
+    it writes its one row."""
+    eng = make_engine(tiny_model)
+    eng.kv_path = kv_path                    # before anything compiles
+    B, M = eng.ecfg.max_batch, eng.cache.max_pages_per_slot
+    ps, V = eng.ecfg.page_size, eng.cfg.vocab_size
+    rng = np.random.default_rng(11)
+    tables = np.zeros((B, M), np.int32)
+    tables[2] = 1 + M + np.arange(M)         # the one rider
+    args = (rng.integers(0, V, (B,)).astype(np.int32),
+            np.asarray([0, 0, 11, 0], np.int32), tables,
+            np.asarray([0, 0, 1, 0], np.int32),
+            *eng._samp_batch_examples())
+    kp, vp = _seeded_pools(eng, 12)
+    (kp2, vp2), _logits, _toks = jax.jit(eng._decode_fn_paged)(
+        eng.qparams, (kp, vp), *args)
+    for before, after in ((kp, kp2), (vp, vp2)):
+        before = np.asarray(before, np.float32)
+        after = np.asarray(after, np.float32)
+        differs = before != after                # [L, P, page, nh, hd]
+        pages = np.flatnonzero(differs.any(axis=(0, 2, 3, 4)))
+        assert set(pages.tolist()) == {0, 1 + M + 11 // ps}
+        # on the rider's page, row 11 % page_size alone
+        rows = np.flatnonzero(
+            differs[:, 1 + M + 11 // ps].any(axis=(0, 2, 3)))
+        assert rows.tolist() == [11 % ps]
+    # all lanes dead: the scratch page alone
+    dead = (args[0], np.zeros((B,), np.int32), np.zeros((B, M), np.int32),
+            np.zeros((B,), np.int32), *args[4:])
+    (kp3, _vp3), _l, _t = jax.jit(eng._decode_fn_paged)(
+        eng.qparams, (kp, vp), *dead)
+    np.testing.assert_array_equal(np.asarray(kp3, np.float32)[:, 1:],
+                                  np.asarray(kp, np.float32)[:, 1:])
+
+
 def test_kernel_and_gather_ticks_agree_over_a_run(tiny_model):
     """The two lowerings of the paged decode tick (the Pallas kernel, in
     interpret mode here, and gather + masked softmax) through a
@@ -764,8 +886,8 @@ def test_kernel_and_gather_ticks_agree_over_a_run(tiny_model):
     pools to float rounding. The test steers the engine's choice (the CPU
     lane would take the gather), as tests/test_chip_compile.py steers the
     backend question."""
-    gather = make_engine(tiny_model, kv_layout="paged", page_size=8)
-    kernel = make_engine(tiny_model, kv_layout="paged", page_size=8)
+    gather = make_engine(tiny_model)
+    kernel = make_engine(tiny_model)
     kernel.kv_path = "pallas_paged"          # before anything compiles
     assert gather.kv_path == "xla_gather"
     rng = np.random.RandomState(3)
@@ -806,7 +928,7 @@ def test_tick_record_names_kv_path_and_live_pages(tiny_model):
     from paddle_tpu.observability import spans
     from paddle_tpu.serving.server import FrontDoor
 
-    eng = make_engine(tiny_model, kv_layout="paged", page_size=8)
+    eng = make_engine(tiny_model)
     eng.warmup()
     sched = serving.Scheduler(eng, serving.SchedulerConfig())
     tracer = spans.default_tracer()
@@ -822,7 +944,6 @@ def test_tick_record_names_kv_path_and_live_pages(tiny_model):
     assert {t["attrs"]["kv_path"] for t in ticks} == {"xla_gather"}
     # 3 and 9 prompt tokens (+ the first generated): 1 page and 2 pages
     assert ticks[0]["attrs"]["live_pages"] == 3
-    assert make_engine(tiny_model).kv_path == "slab"
     front = FrontDoor(scheduler=sched, port=0)
     try:
         assert front.health()["kv_path"] == "xla_gather"

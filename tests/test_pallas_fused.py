@@ -7,9 +7,10 @@
   unfused expressions, fluid engine parity under
   ``FLAGS_fuse_optimizer_pallas``, flat-moment bit-parity + checkpoint
   resume, and the ``make_train_step(fused_opt_pallas=...)`` lever;
-* the one-launch decode step — slab/paged parity against the unfused
-  update-then-attend pipeline, the masked-lane no-write regression, and
-  greedy-token EXACTNESS through a real ``fused_decode=True`` engine.
+* the one-launch decode step — the page-table kernel's parity against
+  the unfused update-then-gather-then-attend pipeline (dead lanes write
+  the scratch page alone: tests/test_paged_serving.py), and greedy-token
+  EXACTNESS through a real ``fused_decode=True`` engine.
 
 Parity methodology: the references are JITTED. The production unfused
 paths (fluid executor programs, the parallelize train step, the serving
@@ -487,64 +488,6 @@ def test_train_step_fused_opt_pallas_bitwise():
 
 @pytest.mark.parametrize("cdt", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-def test_fused_decode_slab_parity(cdt):
-    rng = np.random.default_rng(0)
-    B, S, nh, hd = 4, 32, 2, 64
-    kc = jnp.asarray(rng.standard_normal((B, S, nh, hd)), cdt)
-    vc = jnp.asarray(rng.standard_normal((B, S, nh, hd)), cdt)
-    q = jnp.asarray(rng.standard_normal((B, nh, hd)), jnp.float32)
-    nk = jnp.asarray(rng.standard_normal((B, nh, hd)), jnp.float32)
-    nv = jnp.asarray(rng.standard_normal((B, nh, hd)), jnp.float32)
-    positions = jnp.asarray([3, 5, 0, 7], jnp.int32)
-    active = jnp.asarray([1, 1, 0, 1], jnp.int32)
-
-    @jax.jit
-    def ref(q, kc, vc, nk, nv):
-        kc2 = DA.cache_update(kc, nk, positions, active)
-        vc2 = DA.cache_update(vc, nv, positions, active)
-        lengths = jnp.where(active != 0, positions + 1, 0)
-        return DA.decode_attention(q, kc2, vc2, lengths), kc2, vc2
-
-    out, kc2, vc2 = PK.fused_decode_attention(q, kc, vc, nk, nv,
-                                              positions, active=active)
-    r_out, r_kc, r_vc = ref(q, kc, vc, nk, nv)
-    # caches: bitwise everywhere, including the masked lane (no-write)
-    np.testing.assert_array_equal(np.asarray(kc2, jnp.float32),
-                                  np.asarray(r_kc, jnp.float32))
-    np.testing.assert_array_equal(np.asarray(vc2, jnp.float32),
-                                  np.asarray(r_vc, jnp.float32))
-    live = np.asarray(active) != 0
-    np.testing.assert_allclose(np.asarray(out)[live],
-                               np.asarray(r_out)[live], atol=2e-6,
-                               rtol=2e-6)
-
-
-def test_fused_decode_masked_lane_no_write():
-    """Regression: a dead lane's cache slab must come back bit-identical
-    — the unfused cache_update masked-lane guard, preserved in-kernel."""
-    rng = np.random.default_rng(1)
-    B, S, nh, hd = 3, 16, 2, 64
-    kc = jnp.asarray(rng.standard_normal((B, S, nh, hd)), jnp.float32)
-    vc = jnp.asarray(rng.standard_normal((B, S, nh, hd)), jnp.float32)
-    q = jnp.asarray(rng.standard_normal((B, nh, hd)), jnp.float32)
-    nk = jnp.full((B, nh, hd), 123.0, jnp.float32)
-    nv = jnp.full((B, nh, hd), 456.0, jnp.float32)
-    positions = jnp.asarray([2, 0, 9], jnp.int32)
-    active = jnp.asarray([1, 0, 0], jnp.int32)
-    _, kc2, vc2 = PK.fused_decode_attention(q, kc, vc, nk, nv, positions,
-                                            active=active)
-    for dead in (1, 2):
-        np.testing.assert_array_equal(np.asarray(kc2)[dead],
-                                      np.asarray(kc)[dead])
-        np.testing.assert_array_equal(np.asarray(vc2)[dead],
-                                      np.asarray(vc)[dead])
-    # and the live lane's row DID land
-    np.testing.assert_array_equal(np.asarray(kc2)[0, 2],
-                                  np.full((nh, hd), 123.0, np.float32))
-
-
-@pytest.mark.parametrize("cdt", [jnp.float32, jnp.bfloat16],
-                         ids=["f32", "bf16"])
 def test_fused_paged_decode_parity(cdt):
     """Disjoint page tables (the only layout the engine's allocator ever
     produces for live slots — pages are owned exclusively; only the
@@ -677,24 +620,24 @@ def _greedy(engine, prompt, n):
     return toks
 
 
-@pytest.mark.parametrize("kv_layout", ["slab", "paged"])
-def test_engine_greedy_tokens_exact_fused_decode(kv_layout):
-    """EngineConfig(fused_decode=True) must emit the EXACT same greedy
-    tokens as the unfused engine — both layouts, multiple prompts."""
+def test_engine_greedy_tokens_exact_fused_decode():
+    """EngineConfig(fused_decode=True) (fused layernorms and head, and
+    off the TPU the page-table kernel in interpret mode) must emit the
+    EXACT same greedy tokens as the unfused engine, multiple prompts."""
     from paddle_tpu import serving
     from paddle_tpu.models import gpt
 
     cfg = gpt.GPT_TINY.scaled(num_layers=2, max_seq_len=64)
     params = gpt.init_params(jax.random.PRNGKey(7), cfg)
-    ekw = dict(max_batch=4, max_seq=32, prefill_buckets=(8, 16))
-    if kv_layout == "paged":
-        ekw.update(kv_layout="paged", page_size=8)
+    ekw = dict(max_batch=4, max_seq=32, prefill_buckets=(8, 16),
+               page_size=8)
     base = serving.DecodeEngine(params, cfg, serving.EngineConfig(**ekw))
     fused = serving.DecodeEngine(
         params, cfg, serving.EngineConfig(fused_decode=True, **ekw))
     rng = np.random.RandomState(0)
     prompts = [rng.randint(0, cfg.vocab_size, size=int(n)).tolist()
                for n in (3, 6, 11)]
+    assert (base.kv_path, fused.kv_path) == ("xla_gather", "pallas_paged")
     for prompt in prompts:
         want = _greedy(base, prompt, 12)
         got = _greedy(fused, prompt, 12)
@@ -712,7 +655,7 @@ def test_fused_decode_engine_partial_batch_isolation():
     cfg = gpt.GPT_TINY.scaled(num_layers=2, max_seq_len=64)
     params = gpt.init_params(jax.random.PRNGKey(3), cfg)
     ekw = dict(max_batch=4, max_seq=32, prefill_buckets=(8, 16),
-               fused_decode=True)
+               page_size=8, fused_decode=True)
     eng = serving.DecodeEngine(params, cfg, serving.EngineConfig(**ekw))
     ref_eng = serving.DecodeEngine(params, cfg,
                                    serving.EngineConfig(**ekw))

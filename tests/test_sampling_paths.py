@@ -259,7 +259,7 @@ def paged_eng():
     params = gpt.init_params(jax.random.PRNGKey(0), cfg)
     eng = serving.DecodeEngine(params, cfg, serving.EngineConfig(
         max_batch=4, max_seq=32, prefill_buckets=(8, 16),
-        kv_layout="paged", page_size=8, verify_window=3))
+        page_size=8, verify_window=3))
     eng.warmup()
     return eng
 

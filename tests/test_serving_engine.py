@@ -1,4 +1,4 @@
-"""Serving-stack tier-1 coverage (ISSUE 9, docs/serving.md): KV-cache slot
+"""Serving-stack tier-1 coverage (ISSUE 9, docs/serving.md): cache slot
 reuse, bucket-ladder prefill, decode-vs-reference logit parity (f32 and
 int8 weights), zero-recompile steady state, continuous-batching scheduler
 semantics (join/evict/ordering/deadline), and the HTTP front door's
@@ -22,7 +22,10 @@ from paddle_tpu import serving
 from paddle_tpu.models import gpt
 from paddle_tpu.observability import metrics as om
 from paddle_tpu.serving import quant as squant
-from paddle_tpu.serving.kv_cache import CacheFullError, KVCache
+from paddle_tpu.serving.paged_kv import CacheFullError, PagedKVCache
+
+from serving_helpers import greedy_engine as _greedy_engine
+from serving_helpers import greedy_reference as _greedy_reference
 
 
 @pytest.fixture(scope="module")
@@ -37,6 +40,7 @@ def make_engine(tiny_model, **kw):
     kw.setdefault("max_batch", 4)
     kw.setdefault("max_seq", 32)
     kw.setdefault("prefill_buckets", (8, 16))
+    kw.setdefault("page_size", 8)
     return serving.DecodeEngine(params, cfg, serving.EngineConfig(**kw))
 
 
@@ -46,61 +50,54 @@ def _recompile_total():
                snap.get("paddle_recompiles_total", {}).get("series", []))
 
 
-def _greedy_reference(engine, prompt, n):
-    """Greedy tokens from the full-forward f32 reference."""
-    seq = list(prompt)
-    out = []
-    for _ in range(n):
-        tok = int(np.argmax(engine.reference_logits(seq)[-1]))
-        out.append(tok)
-        seq.append(tok)
-    return out
-
-
-def _greedy_engine(engine, prompt, n):
-    slot, logits = engine.start_sequence(prompt)
-    toks = [int(np.argmax(logits))]
-    for _ in range(n - 1):
-        out = engine.decode_step({slot: toks[-1]})
-        toks.append(int(np.argmax(out[slot])))
-    engine.free_sequence(slot)
-    return toks
-
-
 # ---------------------------------------------------------------------------
 # KV cache
 # ---------------------------------------------------------------------------
 
-def test_kv_cache_slot_alloc_free_reuse():
-    c = KVCache(num_layers=2, max_slots=3, max_seq=8, num_heads=2,
-                head_dim=4)
+def test_cache_slot_alloc_free_generation():
+    """The host truth the scheduler plans against: lowest free slot
+    first, ``CacheFullError`` beyond the slots, and a freed slot's
+    storage reused under a bumped generation counter."""
+    c = PagedKVCache(num_layers=2, max_slots=3, max_seq=8, num_heads=2,
+                     head_dim=4, page_size=4)
     s0, s1, s2 = c.alloc(2), c.alloc(5), c.alloc(1)
     assert (s0, s1, s2) == (0, 1, 2)
     assert c.occupancy == 1.0 and c.free_slot_count() == 0
     with pytest.raises(CacheFullError):
         c.alloc()
     gen1 = c.generation(s1)
+    pages1 = [int(p) for p in c.table_row(s1)[:2]]
     c.free(s1)
     assert c.free_slot_count() == 1 and not c.is_live(s1)
-    assert c.length(s1) == 0
-    # lowest free slot is reused, with a bumped generation
+    assert c.length(s1) == 0 and not c.table_row(s1).any()
+    # lowest free slot is reused, with a bumped generation, on the pages
+    # the free gave back
     again = c.alloc(3)
     assert again == s1 and c.generation(again) == gen1 + 1
+    assert int(c.table_row(again)[0]) == pages1[0]
     assert c.lengths_vector().tolist() == [2, 3, 1]
     assert c.headroom(s0) == 6
 
 
-def test_kv_cache_guards():
-    c = KVCache(num_layers=1, max_slots=2, max_seq=4, num_heads=1,
-                head_dim=2)
+def test_cache_host_guards():
+    c = PagedKVCache(num_layers=1, max_slots=2, max_seq=4, num_heads=1,
+                     head_dim=2, page_size=2)
+    free0 = c.free_page_count()
     with pytest.raises(ValueError):
         c.alloc(length=5)                    # beyond max_seq
+    assert c.free_slot_count() == 2 and c.free_page_count() == free0
     s = c.alloc(1)
     with pytest.raises(ValueError):
-        c.set_length(s, 9)
+        c.set_length(s, 9)                   # beyond max_seq
+    with pytest.raises(ValueError):
+        c.set_length(s, 3)                   # beyond the mapped pages
     c.free(s)
     with pytest.raises(ValueError):
         c.free(s)                            # double free
+    assert c.free_page_count() == free0      # and it freed nothing twice
+    with pytest.raises(ValueError):          # page_size must divide max_seq
+        PagedKVCache(num_layers=1, max_slots=1, max_seq=6, num_heads=1,
+                     head_dim=2, page_size=4)
 
 
 # ---------------------------------------------------------------------------
@@ -127,15 +124,46 @@ def test_engine_config_validation(tiny_model):
     with pytest.raises(ValueError):          # engine beyond wpe table
         serving.DecodeEngine(params, cfg, serving.EngineConfig(
             max_seq=4096))
+    with pytest.raises(ValueError, match="page_size"):   # rung of 8, page 16
+        serving.DecodeEngine(params, cfg, serving.EngineConfig(
+            max_seq=32, prefill_buckets=(8, 16)))
+
+
+def test_default_engine_is_paged_and_one_layout(tiny_model):
+    """``EngineConfig()`` is the paged engine with its prefix cache; the
+    field ``kv_layout`` accepts its one value (the benchmark's
+    configurations still pass it) and nothing else."""
+    cfg, params = tiny_model
+    eng = serving.DecodeEngine(params, cfg, serving.EngineConfig(max_seq=64))
+    assert isinstance(eng.cache, PagedKVCache)
+    assert eng.cache.page_size == 16 and eng.buckets == (16, 32, 64)
+    assert eng.cache.num_pages == 8 * (64 // 16) + 1
+    assert eng.prefix is not None and eng.cache.prefix_cache is eng.prefix
+    assert eng.kv_path == "xla_gather"       # off the TPU
+    same = serving.DecodeEngine(params, cfg, serving.EngineConfig(
+        max_seq=64, kv_layout="paged"))
+    assert same.cache_fingerprint() == eng.cache_fingerprint()
+    with pytest.raises(ValueError, match="expected 'paged'"):
+        serving.DecodeEngine(params, cfg, serving.EngineConfig(
+            max_seq=64, kv_layout="slab"))
 
 
 # ---------------------------------------------------------------------------
 # decode vs reference parity
 # ---------------------------------------------------------------------------
 
-def test_decode_matches_reference_f32(tiny_model):
+@pytest.mark.parametrize("page_size", [8, 16])
+@pytest.mark.parametrize("kv_path", ["xla_gather", "pallas_paged"])
+def test_decode_matches_reference_f32(tiny_model, kv_path, page_size):
+    """Both lowerings of the tick (the gather, and the page-table kernel
+    in interpret mode: the test steers the engine's choice before
+    anything compiles) at two page sizes, against the cache-free full
+    forward."""
     cfg, _ = tiny_model
-    eng = make_engine(tiny_model)
+    eng = make_engine(tiny_model, page_size=page_size,
+                      prefill_buckets=(16,))
+    assert eng.kv_path == "xla_gather"
+    eng.kv_path = kv_path
     eng.warmup()
     rng = np.random.RandomState(0)
     prompt = rng.randint(0, cfg.vocab_size, size=6).tolist()
@@ -253,16 +281,14 @@ def test_engine_recompile_is_explained(tiny_model):
     executable under a new signature must tick paddle_recompiles_total
     through the PR 4 explainer and its own steady-state counter."""
     eng = make_engine(tiny_model)
-    eng._prefill_exec(8)
+    eng._decode_exec()
     eng._warm = True
     before = _recompile_total()
-    # same program name, drifted prompt shape — the exact failure the
-    # steady-state contract forbids
-    example = (eng.qparams, eng.cache.k, eng.cache.v,
-               np.zeros((1, 12), np.int32), np.int32(1), np.int32(0),
-               *eng._samp_scalar_examples())
-    eng._compile("prefill_b8", eng._prefill_fn, example,
-                 donate_argnums=(1, 2))
+    # same program name, drifted signature — the exact failure the
+    # steady-state contract forbids (the prefill rungs' twin of this
+    # control is tests/test_paged_serving.py's)
+    fn, example = eng._prefill_program(16)
+    eng._compile("decode", fn, example, donate_argnums=(1,))
     assert _recompile_total() - before == 1
     assert eng.steady_state_recompiles == 1
 
@@ -331,7 +357,8 @@ def test_scheduler_eos_stop(tiny_model):
     prompt = [7, 11, 13]
     ref = _greedy_reference(probe, prompt, 3)
     eng = serving.DecodeEngine(params, cfg, serving.EngineConfig(
-        max_batch=2, max_seq=32, prefill_buckets=(8,), eos_id=ref[1]))
+        max_batch=2, max_seq=32, prefill_buckets=(8,), page_size=8,
+        eos_id=ref[1]))
     sched = serving.Scheduler(eng)
     req = sched.submit(prompt, max_new_tokens=50)
     while sched.pending():
@@ -392,8 +419,8 @@ def test_engine_loop_survives_step_fault(tiny_model):
 
 def test_engine_poisoned_after_donation_failure(tiny_model):
     """Regression: an executable failure AFTER buffer donation leaves the
-    cache slabs invalidated — the engine must refuse further work instead
-    of reading donated buffers. Without donation (CPU) the slabs survive
+    cache pools invalidated — the engine must refuse further work instead
+    of reading donated buffers. Without donation (CPU) the pools survive
     and the engine stays usable."""
     eng = make_engine(tiny_model)
     eng.warmup()

@@ -76,7 +76,8 @@ def tiny_engine_factory():
     params = gpt.init_params(jax.random.PRNGKey(3), cfg)
 
     def make(**ekw):
-        kw = dict(max_batch=2, max_seq=32, prefill_buckets=(8, 16))
+        kw = dict(max_batch=2, max_seq=32, prefill_buckets=(8, 16),
+                  page_size=8)
         kw.update(ekw)
         e = serving.DecodeEngine(params, cfg, serving.EngineConfig(**kw))
         return e
@@ -300,7 +301,7 @@ def test_prefix_store_warm_restart_in_process(tmp_path,
 
     system_prompt = [7] * 8 + [3, 5, 2, 9]     # 12 tokens = 1 full page
     store_a = serving.PrefixStore(str(tmp_path / "store"))
-    eng_a = tiny_engine_factory(kv_layout="paged", page_size=8)
+    eng_a = tiny_engine_factory()
     assert eng_a.attach_prefix_store(store_a) == 0
     eng_a.warmup()
     sched_a = serving.Scheduler(eng_a)
@@ -320,7 +321,7 @@ def test_prefix_store_warm_restart_in_process(tmp_path,
 
     # "restart": a brand-new engine over the same store directory
     store_b = serving.PrefixStore(str(tmp_path / "store"))
-    eng_b = tiny_engine_factory(kv_layout="paged", page_size=8)
+    eng_b = tiny_engine_factory()
     assert eng_b.attach_prefix_store(store_b) == 1
     assert store_b.restored == 1
     eng_b.warmup()
@@ -334,6 +335,26 @@ def test_prefix_store_warm_restart_in_process(tmp_path,
     assert rc.tokens == ra.tokens
 
 
+@pytest.mark.parametrize("entry", ["attach_prefix_store", "adopt_prefix"])
+def test_prefix_store_and_adoption_need_the_prefix_cache(
+        tmp_path, tiny_engine_factory, entry):
+    """The two ways pages come into an engine from outside (a restored
+    store, a gang-shared prefix record) need the prefix cache and say so;
+    they no longer ask which layout the engine has."""
+    from paddle_tpu import serving
+    from paddle_tpu.serving import kv_transfer as kvt
+
+    eng = tiny_engine_factory(prefix_cache=False)
+    assert eng.prefix is None and eng.cache.prefix_cache is None
+    with pytest.raises(ValueError, match="needs prefix_cache enabled"):
+        if entry == "attach_prefix_store":
+            eng.attach_prefix_store(
+                serving.PrefixStore(str(tmp_path / "store")))
+        else:
+            kvt.adopt_prefix(eng, {"fingerprint": eng.cache_fingerprint()})
+    assert eng.prefix_store is None
+
+
 def test_prefix_store_rejects_mismatched_geometry(tmp_path,
                                                   tiny_engine_factory):
     """A record written for a different cache config is REFUSED with a
@@ -345,7 +366,7 @@ def test_prefix_store_rejects_mismatched_geometry(tmp_path,
     from paddle_tpu import serving
 
     store = serving.PrefixStore(str(tmp_path / "store"))
-    eng = tiny_engine_factory(kv_layout="paged", page_size=8)
+    eng = tiny_engine_factory()
     eng.attach_prefix_store(store)
     eng.warmup()
     sched = serving.Scheduler(eng)
@@ -357,7 +378,7 @@ def test_prefix_store_rejects_mismatched_geometry(tmp_path,
 
     # different page_size -> fingerprint mismatch names the field
     store2 = serving.PrefixStore(str(tmp_path / "store"))
-    eng2 = tiny_engine_factory(kv_layout="paged", page_size=16,
+    eng2 = tiny_engine_factory(page_size=16,
                                prefill_buckets=(16, 32))
     with pytest.raises(serving.CacheConfigMismatch) as ei:
         eng2.attach_prefix_store(store2)
@@ -387,7 +408,7 @@ def test_prefix_store_skips_legacy_record_shape_drift(
                         lambda cache: None)
     monkeypatch.setattr("paddle_tpu.serving.prefix_store"
                         ".cache_fingerprint", lambda cache: None)
-    eng = tiny_engine_factory(kv_layout="paged", page_size=8)
+    eng = tiny_engine_factory()
     eng.attach_prefix_store(store)
     eng.warmup()
     sched = serving.Scheduler(eng)
@@ -399,7 +420,7 @@ def test_prefix_store_skips_legacy_record_shape_drift(
     monkeypatch.undo()
 
     store2 = serving.PrefixStore(str(tmp_path / "store"))
-    eng2 = tiny_engine_factory(kv_layout="paged", page_size=16,
+    eng2 = tiny_engine_factory(page_size=16,
                                prefill_buckets=(16, 32))
     assert eng2.attach_prefix_store(store2) == 0
     assert store2.restore_skipped == 1

@@ -1,6 +1,6 @@
 """Real-chip lane: the PR 16 kernels whose blocks PR 22 re-laid for the
-chip's compiler (fused_ln backward partials, the slab and paged decode
-kernels), compiled by Mosaic at gpt_wide widths and checked against the
+chip's compiler (fused_ln backward partials, the paged decode
+kernel), compiled by Mosaic at gpt_wide widths and checked against the
 unfused XLA expressions on the same chip. Interpret-mode parity lives in
 tests/test_pallas_fused.py; the described-chip compiles in
 tests/test_chip_compile.py prove they compile, this proves what they
@@ -69,39 +69,6 @@ def test_fused_ln_fwd_bwd_matches_xla_on_tpu():
         np.testing.assert_allclose(a, b, atol=tol, rtol=1e-4, err_msg=name)
         worst = max(worst, float(np.abs(a - b).max()))
     _record("fused_ln_fwd_bwd_max_abs_err", worst)
-
-
-@pytest.mark.parametrize("cdt", [jnp.bfloat16, jnp.float32],
-                         ids=["bf16", "f32"])
-def test_fused_decode_slab_matches_xla_on_tpu(cdt):
-    rng = np.random.default_rng(1)
-    kc = jnp.asarray(rng.standard_normal((B, S, NH, HD)), cdt)
-    vc = jnp.asarray(rng.standard_normal((B, S, NH, HD)), cdt)
-    q, nk, nv = (jnp.asarray(rng.standard_normal((B, NH, HD)), cdt)
-                 for _ in range(3))
-    positions = jnp.asarray([0, 5, 255, 256, 700, 1023, 17, 512], jnp.int32)
-    active = jnp.asarray([1, 1, 1, 1, 1, 1, 0, 1], jnp.int32)
-
-    @jax.jit
-    def ref(q, kc, vc, nk, nv):
-        kc2 = DA.cache_update(kc, nk, positions, active)
-        vc2 = DA.cache_update(vc, nv, positions, active)
-        return DA.decode_attention(q, kc2, vc2, positions + 1), kc2, vc2
-
-    fused = jax.jit(lambda q, kc, vc, nk, nv: PK.fused_decode_attention(
-        q, kc, vc, nk, nv, positions, active=active))
-    assert "tpu_custom_call" in fused.lower(q, kc, vc, nk, nv).as_text()
-    out, kc2, vc2 = fused(q, kc, vc, nk, nv)
-    r_out, r_kc, r_vc = ref(q, kc, vc, nk, nv)
-    np.testing.assert_array_equal(np.asarray(kc2, np.float32),
-                                  np.asarray(r_kc, np.float32))
-    np.testing.assert_array_equal(np.asarray(vc2, np.float32),
-                                  np.asarray(r_vc, np.float32))
-    live = np.asarray(active) != 0
-    tol = 2e-2 if cdt == jnp.bfloat16 else 2e-3   # XLA's f32 dot is bf16-pass
-    np.testing.assert_allclose(np.asarray(out, np.float32)[live],
-                               np.asarray(r_out, np.float32)[live],
-                               atol=tol, rtol=tol)
 
 
 @pytest.mark.parametrize("layers", [None, 3], ids=["one_layer", "pool5d"])

@@ -540,7 +540,8 @@ def _run_check_inner(out_dir: str) -> dict:
     sparams = gpt_model.init_params(jrandom.PRNGKey(7), scfg)
     sengine = pserving.DecodeEngine(
         sparams, scfg, pserving.EngineConfig(
-            max_batch=4, max_seq=32, prefill_buckets=(8, 16)))
+            max_batch=4, max_seq=32, prefill_buckets=(8, 16),
+            page_size=8))
     sengine.warmup()
     ssched = pserving.Scheduler(sengine)
     sfront = pserving.FrontDoor(scheduler=ssched, max_queue=32).start()
@@ -637,7 +638,7 @@ def _run_check_inner(out_dir: str) -> dict:
     pengine = pserving.DecodeEngine(
         sparams, scfg, pserving.EngineConfig(
             max_batch=4, max_seq=32, prefill_buckets=(8, 16),
-            kv_layout="paged", page_size=8))
+            page_size=8))
     pengine.warmup()
     psched = pserving.Scheduler(pengine)
     recompiles_before = _recompile_total()
@@ -687,7 +688,7 @@ def _run_check_inner(out_dir: str) -> dict:
     cengine = pserving.DecodeEngine(
         sparams, scfg, pserving.EngineConfig(
             max_batch=4, max_seq=32, prefill_buckets=(8, 16),
-            kv_layout="paged", page_size=8))
+            page_size=8))
     assert cengine.attach_prefix_store(cstore) == 0
     cengine.warmup()
     csched = pserving.Scheduler(cengine)
@@ -704,7 +705,7 @@ def _run_check_inner(out_dir: str) -> dict:
     dengine = pserving.DecodeEngine(
         sparams, scfg, pserving.EngineConfig(
             max_batch=4, max_seq=32, prefill_buckets=(8, 16),
-            kv_layout="paged", page_size=8))
+            page_size=8))
     restored = dengine.attach_prefix_store(dstore)
     assert restored == 1, restored
     ps_after = _prefix_store_ops()
@@ -757,10 +758,11 @@ def _run_check_inner(out_dir: str) -> dict:
     starget = pserving.DecodeEngine(
         sparams, scfg, pserving.EngineConfig(
             max_batch=2, max_seq=32, prefill_buckets=(8,),
-            verify_window=3))
+            page_size=8, verify_window=3))
     sdraft = pserving.DecodeEngine(
         sparams, scfg, pserving.EngineConfig(
-            max_batch=2, max_seq=32, prefill_buckets=(8,)))
+            max_batch=2, max_seq=32, prefill_buckets=(8,),
+            page_size=8))
     sspec = pserving.SpecDecodeEngine(starget, sdraft)
     sspec.warmup()
     recompiles_before = _recompile_total()
@@ -840,11 +842,12 @@ def _run_check_inner(out_dir: str) -> dict:
     fengine = pserving.DecodeEngine(
         sparams, scfg, pserving.EngineConfig(
             max_batch=4, max_seq=32, prefill_buckets=(8, 16),
-            fused_decode=True))
+            page_size=8, fused_decode=True))
     fengine.warmup()
     mk_warm = _mk_counts()
-    assert mk_warm.get("decode_slab", 0) > mk_train.get("decode_slab", 0), \
-        "fused-decode warmup traced no decode_slab megakernel launch"
+    assert mk_warm.get("decode_paged", 0) \
+        > mk_train.get("decode_paged", 0), \
+        "fused-decode warmup traced no decode_paged megakernel launch"
     assert mk_warm.get("decode_logits_head", 0) \
         > mk_train.get("decode_logits_head", 0), \
         "fused-decode warmup traced no decode_logits_head launch"
@@ -900,8 +903,7 @@ def _run_check_inner(out_dir: str) -> dict:
         step_flops=arep.get("flops"),
         step_bytes=arep.get("bytes_accessed"),
         programs=[arep] if arep else None,
-        config={"mode": "decode", "weight_dtype": "f32",
-                "kv_layout": "slab"},
+        config={"mode": "decode", "weight_dtype": "f32"},
         generated_by="tools/metrics_check.py")
     # the schema gate proper: raises naming the offending field
     ATT.validate(attr_doc, require_residue=True)
@@ -916,8 +918,8 @@ def _run_check_inner(out_dir: str) -> dict:
 
     # --- disagg KV-transfer gate (ISSUE 17, docs/serving.md
     # "Disaggregation"): the transfer counters must move ONLY on disagg
-    # runs. Everything above was plain colocated serving — slab smoke,
-    # paged prefix-cache smoke, warm restart, spec decode, fused decode
+    # runs. Everything above was plain colocated serving — HTTP smoke,
+    # prefix-cache smoke, warm restart, spec decode, fused decode
     # — so the counters must be EXACTLY where they started; then one
     # in-process export/adopt exchange must move them by the exact
     # stats-reported byte totals, under the chunk-residency budget
@@ -1266,8 +1268,8 @@ def _run_check_inner(out_dir: str) -> dict:
     # fused-decode serve above left per-kernel trace-time samples
     assert 'paddle_megakernel_launches_total{kernel="opt_sgd"}' \
         in prom_text, "opt_sgd megakernel sample missing from exposition"
-    assert 'paddle_megakernel_launches_total{kernel="decode_slab"}' \
-        in prom_text, "decode_slab megakernel sample missing"
+    assert 'paddle_megakernel_launches_total{kernel="decode_paged"}' \
+        in prom_text, "decode_paged megakernel sample missing"
     # autotune families (docs/autotune.md): the smoke tune above left
     # exactly-counted probe/prune samples
     for name in ("paddle_autotune_probes_total",

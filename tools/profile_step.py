@@ -283,7 +283,7 @@ def train_profile(spec_str: str, trace_dir: str, steps: int = 6,
 def serve_profile(trace_dir: str, ticks: int = 16, attr_out: str = None,
                   d: int = 64, layers: int = 4, nh: int = 4, ff: int = 128,
                   vocab: int = 256, max_batch: int = 4, max_seq: int = 64,
-                  weight_dtype: str = "f32", kv_layout: str = "slab",
+                  weight_dtype: str = "f32",
                   fused_decode: bool = False, role: str = "colocated",
                   tuned: str = None):
     """Profile a warmed DecodeEngine decode tick: fill every slot, trace
@@ -301,15 +301,13 @@ def serve_profile(trace_dir: str, ticks: int = 16, attr_out: str = None,
     dev = jax.devices()[0]
     tuned_doc = _load_tuned(tuned, "serve")
     if tuned_doc is not None:
-        # dtype/layout/fused-decode only where the flags stayed default
+        # dtype/fused-decode only where the flags stayed default
         from paddle_tpu.tuning import tuned as tuned_mod
 
         scfg = (tuned_doc.get("spaces") or {}).get("serve", {}).get(
             "config") or {}
         if weight_dtype == "f32" and scfg.get("weight_dtype"):
             weight_dtype = scfg["weight_dtype"]
-        if kv_layout == "slab" and scfg.get("kv_layout"):
-            kv_layout = scfg["kv_layout"]
         if not fused_decode and scfg.get("fused_decode"):
             fused_decode = True
     cfg = gpt.GPTConfig(vocab_size=vocab, max_seq_len=max(max_seq, 64),
@@ -318,11 +316,9 @@ def serve_profile(trace_dir: str, ticks: int = 16, attr_out: str = None,
     params = gpt.init_params(jax.random.PRNGKey(0), cfg)
     ekw = dict(max_batch=max_batch, max_seq=max_seq,
                prefill_buckets=(8, 16), weight_dtype=weight_dtype,
-               fused_decode=fused_decode, role=role)
-    if kv_layout == "paged":
-        ekw.update(kv_layout="paged", page_size=8)
-        if tuned_doc is not None and scfg.get("num_pages"):
-            ekw["num_pages"] = int(scfg["num_pages"])
+               fused_decode=fused_decode, role=role, page_size=8)
+    if tuned_doc is not None and scfg.get("num_pages"):
+        ekw["num_pages"] = int(scfg["num_pages"])
     engine = serving.DecodeEngine(params, cfg,
                                   serving.EngineConfig(**ekw))
     print("[profile --serve] warmup (AOT prefill ladder + decode)",
@@ -362,7 +358,7 @@ def serve_profile(trace_dir: str, ticks: int = 16, attr_out: str = None,
                        if r.get("program") == "serve/decode"), {})
     config = {
         "mode": "decode", "weight_dtype": weight_dtype,
-        "kv_layout": kv_layout, "max_batch": max_batch,
+        "max_batch": max_batch,
         "max_seq": max_seq, "d_model": d, "layers": layers,
         "fused_decode": fused_decode,
         # disagg stamp (ISSUE 17): phase-split captures must be
@@ -378,7 +374,7 @@ def serve_profile(trace_dir: str, ticks: int = 16, attr_out: str = None,
         trace_dir, steps=ticks, wall_ms_per_step=wall_ms,
         hlo_texts=hlo_texts, device=dev, mode="decode",
         spec=f"serve:d={d},L={layers},b={max_batch},"
-             f"{weight_dtype},{kv_layout}"
+             f"{weight_dtype}"
              + (",fused" if fused_decode else "")
              + (f",{role}" if role != "colocated" else ""),
         step_flops=decode_rep.get("flops"),
@@ -467,7 +463,6 @@ def main():
         serve_profile(trace_dir, ticks=int(_flag("--ticks", 16, int)),
                       attr_out=attr_out,
                       weight_dtype=_flag("--weight-dtype", "f32"),
-                      kv_layout=_flag("--kv-layout", "slab"),
                       max_batch=int(_flag("--max-batch", 4, int)),
                       fused_decode="--fused-decode" in sys.argv,
                       role=role, tuned=tuned)

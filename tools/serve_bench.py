@@ -4,8 +4,8 @@ continuous-batching scheduler -> SERVE_BENCH.json (docs/serving.md).
 
 Open-loop on purpose: arrivals follow a Poisson process at each target
 rate regardless of completions (the closed-loop trap understates tail
-latency under overload). Per lane — a (weight_dtype, kv_layout, sharding,
-sampling, spec-decode) config x arrival rate — the bench reports:
+latency under overload). Per lane — a (weight_dtype, sharding, sampling,
+spec-decode) config x arrival rate — the bench reports:
 
   * TTFT p50/p99 ms (submit -> first token, queueing included)
   * per-output-token latency (TPOT) p50/p99 ms
@@ -46,6 +46,9 @@ if "host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
                                + " --xla_force_host_platform_device_count=8")
 
 import numpy as np  # noqa: E402
+
+# tokens per KV page at this bench's tiny geometry (buckets of 16 and 32)
+PAGE_SIZE = 8
 
 
 def _pct(vals, q):
@@ -113,10 +116,10 @@ def parity_lane(params, cfg, ecfg_kw, seed: int, eval_len: int):
     return out
 
 
-def paged_parity_lane(params, cfg, ecfg_kw, seed: int, n_tokens: int):
-    """The ISSUE 13 acceptance bar: paged + greedy decode tokens
-    bit-match the slab engine at f32, and the tp=2 decode logits match
-    single-chip."""
+def engine_parity_lane(params, cfg, ecfg_kw, seed: int, n_tokens: int):
+    """The engine's acceptance bar: at f32 its greedy tokens are those of
+    greedy decoding through ``reference_logits`` (the cache-free full
+    forward), and the tp=2 decode logits match single-chip."""
     import jax
 
     from paddle_tpu import serving
@@ -134,39 +137,37 @@ def paged_parity_lane(params, cfg, ecfg_kw, seed: int, n_tokens: int):
         engine.free_sequence(slot)
         return toks, first_logits
 
-    slab = serving.DecodeEngine(
-        params, cfg, serving.EngineConfig(**ecfg_kw))
-    slab.warmup()
-    slab_toks, slab_logits = greedy(slab)
-    paged = serving.DecodeEngine(params, cfg, serving.EngineConfig(
-        kv_layout="paged", page_size=8, **ecfg_kw))
-    paged.warmup()
-    paged_toks, _ = greedy(paged)
+    one = serving.DecodeEngine(params, cfg,
+                               serving.EngineConfig(**ecfg_kw))
+    one.warmup()
+    toks, logits = greedy(one)
+    ref, stream = [], list(prompt)
+    for _ in range(n_tokens):
+        ref.append(int(np.argmax(one.reference_logits(stream)[-1])))
+        stream.append(ref[-1])
     out = {"tokens": int(n_tokens),
-           "paged_tokens_match_slab": paged_toks == slab_toks}
+           "tokens_match_reference": toks == ref}
     if jax.device_count() >= 2:
         tp = serving.DecodeEngine(params, cfg, serving.EngineConfig(
             sharding="tp", tp=2, **ecfg_kw))
         tp.warmup()
         tp_toks, tp_logits = greedy(tp)
-        out["tp2_tokens_match"] = tp_toks == slab_toks
+        out["tp2_tokens_match"] = tp_toks == toks
         out["tp2_max_logit_diff"] = float(
-            np.max(np.abs(tp_logits - slab_logits)))
+            np.max(np.abs(tp_logits - logits)))
     return out
 
 
 def build_engine(params, cfg, ecfg_kw, lane):
-    """One engine per lane config dict: {weight_dtype, kv_layout,
+    """One engine per lane config dict: {weight_dtype, num_pages,
     sharding, spec(k or 0)} (+ the shared geometry)."""
     from paddle_tpu import serving
     from paddle_tpu.models import gpt
 
     kw = dict(ecfg_kw)
     kw["weight_dtype"] = lane.get("weight_dtype", "f32")
-    if lane.get("kv_layout") == "paged":
-        kw.update(kv_layout="paged", page_size=lane.get("page_size", 8))
-        if lane.get("num_pages"):
-            kw["num_pages"] = int(lane["num_pages"])
+    if lane.get("num_pages"):
+        kw["num_pages"] = int(lane["num_pages"])
     if lane.get("fused_decode"):
         kw["fused_decode"] = True
     if lane.get("sharding") == "tp":
@@ -351,7 +352,7 @@ def _family_total(name):
 
 
 def disagg_lane(params, cfg, ecfg_kw, rate_rps: float, n_requests: int,
-                max_new_tokens: int, seed: int, page_size: int = 8):
+                max_new_tokens: int, seed: int):
     """Disaggregated-vs-colocated A/B at EQUAL chips (ISSUE 17).
 
     Same mixed long/short Poisson trace against two 2-engine
@@ -382,8 +383,7 @@ def disagg_lane(params, cfg, ecfg_kw, rate_rps: float, n_requests: int,
 
     def make(role, max_batch):
         e = serving.DecodeEngine(params, cfg, serving.EngineConfig(
-            max_batch=max_batch, kv_layout="paged",
-            page_size=page_size, role=role, **kw))
+            max_batch=max_batch, role=role, **kw))
         e.warmup()
         return e
 
@@ -546,7 +546,6 @@ def main(argv=None):
     ap.add_argument("--max-new-tokens", type=int, default=16)
     ap.add_argument("--prompt-len-max", type=int, default=16)
     ap.add_argument("--weight-dtypes", default="f32,int8")
-    ap.add_argument("--layouts", default="slab,paged")
     ap.add_argument("--tp", type=int, default=2,
                     help="tp size for the tensor-parallel lane (0 skips)")
     ap.add_argument("--spec-k", type=int, default=3,
@@ -626,7 +625,8 @@ def main(argv=None):
     params = gpt.init_params(jax.random.PRNGKey(args.seed), cfg)
     ecfg_kw = dict(
         max_batch=args.max_batch, max_seq=args.max_seq,
-        prefill_buckets=tuple(int(b) for b in args.buckets.split(",")))
+        prefill_buckets=tuple(int(b) for b in args.buckets.split(",")),
+        page_size=PAGE_SIZE)
 
     backend = jax.default_backend()
     result = {
@@ -652,32 +652,27 @@ def main(argv=None):
           flush=True)
     result["quant_parity"] = parity_lane(
         params, cfg, ecfg_kw, args.seed + 1, args.eval_len)
-    print("[serve_bench] paged/tp parity lane...", flush=True)
-    result["engine_parity"] = paged_parity_lane(
+    print("[serve_bench] reference/tp parity lane...", flush=True)
+    result["engine_parity"] = engine_parity_lane(
         params, cfg, ecfg_kw, args.seed + 1, max(args.eval_len // 2, 8))
 
-    # lane matrix: dtype x layout open-loop rates, plus one lane each for
+    # lane matrix: open-loop rates per dtype, plus one lane each for
     # tp, sampled, and spec-decode configs
-    lane_cfgs = []
-    for wd in args.weight_dtypes.split(","):
-        for layout in args.layouts.split(","):
-            lane_cfgs.append({"weight_dtype": wd.strip(),
-                              "kv_layout": layout.strip()})
+    lane_cfgs = [{"weight_dtype": wd.strip()}
+                 for wd in args.weight_dtypes.split(",")]
     if args.tp and jax.device_count() >= args.tp:
-        lane_cfgs.append({"weight_dtype": "f32", "kv_layout": "slab",
+        lane_cfgs.append({"weight_dtype": "f32",
                           "sharding": "tp", "tp": args.tp})
-    lane_cfgs.append({"weight_dtype": "f32", "kv_layout": "paged",
+    lane_cfgs.append({"weight_dtype": "f32",
                       "sampling": {"temperature": 0.8, "top_p": 0.9}})
     if args.spec_k:
-        lane_cfgs.append({"weight_dtype": "f32", "kv_layout": "slab",
-                          "spec": args.spec_k})
+        lane_cfgs.append({"weight_dtype": "f32", "spec": args.spec_k})
     if tuned_doc is not None:
-        # one lane at the tuner's full serve winner (dtype + layout +
-        # page pool + fused decode + sharding + spec window)
+        # one lane at the tuner's full serve winner (dtype + page pool +
+        # fused decode + sharding + spec window)
         scfg = (tuned_doc.get("spaces") or {}).get("serve", {}).get(
             "config") or {}
-        tuned_lane = {"weight_dtype": scfg.get("weight_dtype", "f32"),
-                      "kv_layout": scfg.get("kv_layout", "slab")}
+        tuned_lane = {"weight_dtype": scfg.get("weight_dtype", "f32")}
         if scfg.get("num_pages"):
             tuned_lane["num_pages"] = int(scfg["num_pages"])
         if scfg.get("fused_decode"):
@@ -705,11 +700,9 @@ def main(argv=None):
 
     # closed-loop capacity: per (chip count, dtype, spec on/off)
     cap_ladder = [float(r) for r in args.capacity_rates.split(",")]
-    cap_cfgs = [{"weight_dtype": "f32", "kv_layout": "paged"},
-                {"weight_dtype": "int8", "kv_layout": "paged"}]
+    cap_cfgs = [{"weight_dtype": "f32"}, {"weight_dtype": "int8"}]
     if args.spec_k:
-        cap_cfgs.append({"weight_dtype": "f32", "kv_layout": "slab",
-                         "spec": args.spec_k})
+        cap_cfgs.append({"weight_dtype": "f32", "spec": args.spec_k})
     capacity = []
     for lane in cap_cfgs:
         desc = ",".join(f"{k}={v}" for k, v in lane.items())
@@ -737,7 +730,7 @@ def main(argv=None):
     result["int8_pass"] = bool(result["quant_parity"]["int8"]["pass"])
     ep = result["engine_parity"]
     result["engine_parity_pass"] = bool(
-        ep["paged_tokens_match_slab"]
+        ep["tokens_match_reference"]
         and ep.get("tp2_tokens_match", True))
 
     with open(args.out, "w") as f:

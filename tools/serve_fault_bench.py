@@ -76,7 +76,7 @@ def _log(msg):
 MODEL = {"d_model": 32, "num_layers": 1, "num_heads": 2, "d_ff": 64,
          "vocab_size": 128, "max_seq_len": 64, "seed": 5}
 ENGINE = {"max_batch": 4, "max_seq": 32, "prefill_buckets": [8, 16],
-          "kv_layout": "paged", "page_size": 8}
+          "page_size": 8}
 
 
 def _worker_config(**over):
@@ -412,7 +412,7 @@ def scenario_overload_storm(ref_params_cfg):
     # the PREEMPTION path, not the cache's elasticity
     engine = serving.DecodeEngine(params, cfg, serving.EngineConfig(
         max_batch=4, max_seq=32, prefill_buckets=(8, 16),
-        kv_layout="paged", page_size=8, num_pages=10,
+        page_size=8, num_pages=10,
         prefix_cache=False))
     engine.warmup()
     # the queue is deep on purpose: pressure must land on the PAGE POOL
