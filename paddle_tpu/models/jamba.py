@@ -353,10 +353,10 @@ class JambaServing:
             "conv_width": (cfg.mamba_d_conv - 1) * cfg.d_inner,
             "d_state": cfg.mamba_d_state, "d_inner": cfg.d_inner}
 
-    def hold(self, params, weight_dtype: str, chunk: int):
+    def hold(self, params, weight_dtype: str, chunk: int, sharded=False):
         """The serving storage: matrices in ``weight_dtype``, the leaves of
-        ``F32_LEAVES`` float32. (int8 is refused where the engine is
-        built.)"""
+        ``F32_LEAVES`` float32, every leaf in its stored shape. (int8 and
+        a ``sharded`` engine are refused where the engine is built.)"""
         held = {"f32": jnp.float32, "bf16": jnp.bfloat16}[weight_dtype]
 
         def one(path, x):

@@ -67,9 +67,10 @@ from . import metrics as smetrics
 from . import model as _model
 from . import sampling as samp
 from .model import (block_tail as _block_tail, embed_rows as _embed_rows,
-                    layers_over_pools as _layers_over_pools)
+                    layers_over_pools as _layers_over_pools,
+                    qkv_heads as _qkv_heads)
 from .paged_kv import PagedKVCache, PagePoolFullError, PrefixCache
-from .quant import dequantize_params, quantized_nbytes
+from .quant import QuantizedLeaf, dequantize_params, quantized_nbytes
 from .sampling import GREEDY, SamplingParams
 
 __all__ = ["EngineConfig", "DecodeEngine", "PromptTooLongError",
@@ -88,6 +89,20 @@ def _note_sampler(program: str, temps, top_ks, top_ps) -> str:
     path = samp.path_name(temps, top_ks, top_ps)
     smetrics.m_sampler_path.labels(path, program).inc()
     return path
+
+
+def _held_shapes(params, qparams) -> Dict[str, Tuple[int, ...]]:
+    """``{"blocks/w_qkv": (L, d, 3·nh·hd), ..}``: the leaves of the serving
+    storage whose shape is not the stored leaf's (a quantized leaf's shape
+    is the one it dequantizes to)."""
+    from ..sharding.plan import _path_str
+
+    held = jax.tree_util.tree_flatten_with_path(
+        qparams, is_leaf=lambda x: isinstance(x, QuantizedLeaf))[0]
+    stored = jax.tree_util.tree_leaves(params)
+    return {_path_str(path): tuple(q.shape)
+            for (path, q), p in zip(held, stored)
+            if tuple(q.shape) != tuple(np.shape(p))}
 
 
 def default_bucket_ladder(max_seq: int, smallest: int = 16) -> Tuple[int, ...]:
@@ -197,12 +212,19 @@ class DecodeEngine:
         if ecfg.sharding not in (None, "tp"):
             raise ValueError(f"sharding {ecfg.sharding!r}: expected None "
                              "or 'tp'")
+        # the serving storage is the model's own: it may hold a leaf in
+        # the layout its programs contract (GPTServing.hold: w_qkv). A
+        # sharded engine asks for the stored shapes, which its plan names.
         qparams = self.model.hold(params, ecfg.weight_dtype,
-                                  ecfg.quant_chunk)
+                                  ecfg.quant_chunk,
+                                  sharded=ecfg.sharding is not None)
         if ecfg.sharding == "tp":
             self._init_tp(qparams)
         self.qparams = jax.device_put(qparams, self._param_sh)
         self.weight_nbytes = quantized_nbytes(self.qparams)
+        # leaf path -> shape, for the leaves held in another shape than
+        # they are stored (/health shows it)
+        self.held_shapes = _held_shapes(params, qparams)
         cache_dtype = ecfg.cache_dtype or cfg.dtype
         kv_layers, kv_heads, kv_head_dim = self.model.kv_geometry
         # one manager for both kinds of cache: pages for the attention
@@ -434,10 +456,7 @@ class DecodeEngine:
 
         def body(h, layer_p, l, kp, vp):
             h1 = ln(h, layer_p["ln1_scale"], layer_p["ln1_bias"])
-            qkv = jnp.einsum("bwd,dcnh->bwcnh", h1,
-                             layer_p["w_qkv"].astype(dt))
-            qkv = qkv + layer_p["b_qkv"].astype(dt)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            q, k, v = _qkv_heads(h1, layer_p, cfg)
             nh, hd = k.shape[2], k.shape[3]
             kp = paged_cache_update(
                 kp, k.reshape(B * W, nh, hd),

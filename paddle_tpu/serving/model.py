@@ -14,8 +14,10 @@ description is any object with:
 - ``paged_kernel``: whether ``decode_layers`` can read the pools through
   the page-table kernel (``kv_path`` ``pallas_paged``) where Mosaic takes
   the page shape, or always gathers;
-- ``hold(params, weight_dtype, chunk)``: the serving storage of a float32
-  parameter tree;
+- ``hold(params, weight_dtype, chunk, sharded=False)``: the serving
+  storage of a float32 parameter tree, a leaf re-laid where the programs
+  contract it better so (``sharded``: the engine lays the tree over a mesh
+  by the stored layout's plan, so every leaf keeps its stored shape);
 - ``embed(qparams, tokens, positions)``;
 - ``prefill_layers(qparams, x [1, T, D], caches, ctx)`` with ``ctx``:
   ``length``, ``prefix_len``, ``table_row``, ``slot``, ``page_size``;
@@ -47,7 +49,7 @@ from ..ops.decode_attention import (decode_attention, paged_cache_update,
 from .quant import QuantizedLeaf, dequantize_params, quantize_params
 
 __all__ = ["describe", "GPTServing", "embed_rows", "layers_over_pools",
-           "block_tail"]
+           "qkv_heads", "block_tail"]
 
 
 def embed_rows(qparams, tokens, positions, dt):
@@ -79,6 +81,30 @@ def layers_over_pools(body, x, kp, vp, blocks):
     layers = jnp.arange(kp.shape[0], dtype=jnp.int32)
     (x, kp, vp), _ = jax.lax.scan(step, (x, kp, vp), (blocks, layers))
     return x, kp, vp
+
+
+def qkv_heads(h1, layer_p, cfg):
+    """``q, k, v [..., nh, hd]`` of the normed rows ``h1 [..., d]``: the
+    pre-attention product of a GPT block, for decode rows, a prefill rung
+    and the verify window alike. It follows the weight's own shape. Held
+    as ``[d, 3·nh·hd]`` with its bias ``[3·nh·hd]``
+    (:meth:`GPTServing.hold`) it is a plain ``[rows, d] x [d, n]``
+    product, the contracted axis and the output axis the two the TPU
+    tiles; the bias is added and q, k and v are cut out of the flat
+    result BEFORE anything is reshaped to heads: a reshape straight after
+    the product is folded back into it by XLA, which then wants the weight
+    re-laid again (tests/test_chip_compile.py holds the compiled programs
+    to it). Stored as ``[d, 3, nh, hd]`` (the tensor-parallel engine,
+    whose plan shards the head axis) it is contracted as ``models/gpt.py``
+    contracts it."""
+    dt = cfg.dtype
+    w, b = layer_p["w_qkv"].astype(dt), layer_p["b_qkv"].astype(dt)
+    if w.ndim == 2:
+        flat = jnp.einsum("...d,dn->...n", h1, w) + b
+        heads = (*h1.shape[:-1], cfg.num_heads, cfg.head_dim)
+        return tuple(x.reshape(heads) for x in jnp.split(flat, 3, axis=-1))
+    qkv = jnp.einsum("...d,dcnh->...cnh", h1, w) + b
+    return qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :]
 
 
 def block_tail(h, a, layer_p, dt, ln, bt: str):
@@ -115,7 +141,25 @@ class GPTServing:
         self.max_positions = cfg.max_seq_len
         self.kv_geometry = (cfg.num_layers, cfg.num_heads, cfg.head_dim)
 
-    def hold(self, params, weight_dtype: str, chunk: int):
+    def hold(self, params, weight_dtype: str, chunk: int, sharded=False):
+        """The serving storage: ``quantize_params`` of the stored tree
+        with ``w_qkv [L, d, 3, nh, hd]`` held as ``[L, d, 3·nh·hd]`` and
+        ``b_qkv`` as ``[L, 3·nh·hd]``, the layout :func:`qkv_heads`
+        contracts without a copy. Stored, the two axes the TPU tiles are
+        ``(nh, hd)`` and every decode tick re-laid all layers' weight to
+        get ``d`` into a tile (1.83 ms of an 8.3 ms tick at 24 x 2048 x
+        6144, PERF.md section 6, PR 32). The reshapes are row-major: no
+        element moves, so the leaves' bytes and the int8 quantiser's flat
+        chunks and scales are the stored layout's. A ``sharded`` engine
+        keeps the stored layout: its plan
+        (``sharding/plan.py:gpt_annotations``) splits the head axis, which
+        the flat axis ``3·nh·hd`` no longer shows."""
+        if not sharded:
+            blocks = dict(params["blocks"])
+            for leaf, lead in (("w_qkv", 2), ("b_qkv", 1)):
+                x = blocks[leaf]
+                blocks[leaf] = x.reshape(*x.shape[:lead], -1)
+            params = {**params, "blocks": blocks}
         return quantize_params(params, weight_dtype, chunk)
 
     def embed(self, qparams, tokens, positions):
@@ -148,10 +192,7 @@ class GPTServing:
 
         def body(h, layer_p, l, kp, vp):
             h1 = ln(h, layer_p["ln1_scale"], layer_p["ln1_bias"])
-            qkv = jnp.einsum("btd,dcnh->btcnh", h1,
-                             layer_p["w_qkv"].astype(dt))
-            qkv = qkv + layer_p["b_qkv"].astype(dt)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            q, k, v = qkv_heads(h1, layer_p, self.cfg)
             nh, hd = k.shape[2], k.shape[3]
             kp = paged_page_write(
                 kp, k[0].reshape(n_pages, ps, nh, hd), suffix_pages, l)
@@ -196,13 +237,10 @@ class GPTServing:
 
         def body(h, layer_p, l, kp, vp):
             h1 = ln(h, layer_p["ln1_scale"], layer_p["ln1_bias"])
-            qkv = jnp.einsum("bd,dcnh->bcnh", h1,
-                             layer_p["w_qkv"].astype(dt))
-            qkv = qkv + layer_p["b_qkv"].astype(dt)
+            q, k, v = qkv_heads(h1, layer_p, self.cfg)
             # dead lanes' all-zero tables land the write on the scratch
             # page, which no live slot reads
-            a, kp, vp = write_and_attend(qkv[:, 0], qkv[:, 1], qkv[:, 2],
-                                         kp, vp, l)
+            a, kp, vp = write_and_attend(q, k, v, kp, vp, l)
             return block_tail(h, a, layer_p, dt, ln, "b"), kp, vp
 
         x, kp, vp = layers_over_pools(

@@ -621,6 +621,9 @@ class FrontDoor:
             out["buckets"] = list(self.scheduler.engine.buckets)
             out["weight_dtype"] = self.scheduler.engine.ecfg.weight_dtype
             out["kv_path"] = getattr(self.scheduler.engine, "kv_path", None)
+            out["held_shapes"] = {
+                k: list(v) for k, v in getattr(
+                    self.scheduler.engine, "held_shapes", {}).items()}
             cache = self.scheduler.engine.cache
             if getattr(cache, "state_bytes_per_slot", 0):
                 # a hybrid model: recurrent state the live slots hold, and

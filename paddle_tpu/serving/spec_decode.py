@@ -142,6 +142,10 @@ class SpecDecodeEngine:
     def kv_path(self):
         return self.target.kv_path
 
+    @property
+    def held_shapes(self):
+        return self.target.held_shapes
+
     def live_pages(self, slots) -> int:
         return self.target.live_pages(slots)
 

@@ -398,6 +398,20 @@ def _compiled_tick(cell, sharding):
     return _TICKS[cell]
 
 
+def _compiled_gpt_program(program, sharding):
+    """(the GPT cell's engine, its ``decode`` tick or ``prefill_b<rung>``
+    program compiled for the described chip), each compiled once a
+    process as the ticks are."""
+    eng, tick = _compiled_tick("gpt_cell", sharding)
+    if program == "decode":
+        return eng, tick
+    if program not in _TICKS:
+        _TICKS[program] = _lower_donated(
+            *eng._prefill_program(int(program.split("_b")[1])),
+            sharding).compile()
+    return eng, _TICKS[program]
+
+
 def test_jamba_decode_tick_two_layer_cut(one_chip):
     """The hybrid model's decode tick at the cell's widths and 64 slots,
     cut to one Mamba and one attention layer: both state arrays and both
@@ -497,11 +511,7 @@ def test_paged_programs_touch_only_live_pages(one_chip, program):
     smaller than one pool (a scan's xs/ys held a second copy of both),
     and nothing of a layer's pool size is copied, converted, sliced out
     or written back."""
-    eng, compiled = _compiled_tick("gpt_cell", one_chip)
-    if program != "decode":
-        compiled = _lower_donated(
-            *eng._prefill_program(int(program.split("_b")[1])),
-            one_chip).compile()
+    eng, compiled = _compiled_gpt_program(program, one_chip)
     pool = eng.cache.k.size * eng.cache.k.dtype.itemsize
     mem = compiled.memory_analysis()
     print(f"{program}: arguments {mem.argument_size_in_bytes / 2**20:.0f} "
@@ -512,6 +522,43 @@ def test_paged_programs_touch_only_live_pages(one_chip, program):
     assert mem.temp_size_in_bytes < pool
     moves = _pool_sized_moves(compiled.as_text(),
                               at_least=eng.cache.k[0].size)
+    assert not moves, "\n".join(moves)
+
+
+def _weight_sized_relayouts(hlo, at_least):
+    """``copy`` and ``transpose`` instructions of the compiled module whose
+    result has ``at_least`` elements or more, wherever they stand (in the
+    entry, a loop's body or a fusion's), with the tiling they write."""
+    bodies, _entry = _computations(hlo)
+    return [f"{name} = {shape} {op}"
+            for instrs in bodies.values() for name, shape, op, _ in instrs
+            if op in ("copy", "transpose") and _elements(shape) >= at_least]
+
+
+@pytest.mark.parametrize("program", [
+    "decode", "prefill_b16", "prefill_b512",
+    pytest.param("prefill_b2048", marks=pytest.mark.xfail(
+        strict=True, reason="the 2048 rung alone still copies w_proj "
+        "[L, nh, hd, d] into the tiling {2,3,1,0} (hd minor), as the "
+        "parent's did beside its copy of w_qkv: 0.6 ms of a 60 ms rung "
+        "by its bytes (PERF.md section 7)"))])
+def test_serving_programs_relay_no_weight(one_chip, program):
+    """The engine holds ``w_qkv`` in the layout its programs contract
+    (``GPTServing.hold``: ``[L, d, 3·nh·hd]``, where the stored
+    ``[L, d, 3, nh, hd]`` put the tiles on ``(nh, hd)``): the serving
+    cell's decode tick and its prefill rungs, compiled for the described
+    chip, hold no ``copy`` or ``transpose`` the size of the smallest
+    stacked block matrix (``w_proj``, a third of ``w_qkv``) or larger
+    (compiled by hand, PR 32: nor do the rungs 32 to 1024). At
+    24 layers such a copy of ``w_qkv`` was 1.83 ms of every 8.3 ms tick
+    (PERF.md section 6, PR 32). XLA decides the tiling from the product's
+    spelling (``serving/model.py:qkv_heads``): a reshape to heads straight
+    after the product brings the copy back, in the tiling ``{1,2,0}``."""
+    eng, compiled = _compiled_gpt_program(program, one_chip)
+    held = eng.held_shapes["blocks/w_qkv"]
+    assert held == (CELL_L, D, 3 * NH * HD)
+    moves = _weight_sized_relayouts(compiled.as_text(),
+                                    at_least=CELL_L * D * NH * HD)
     assert not moves, "\n".join(moves)
 
 

@@ -510,6 +510,10 @@ def test_front_door_generate_and_metrics(front, tiny_model):
         f"http://127.0.0.1:{front.port}/health", timeout=10).read())
     assert health["status"] == "ok"
     assert health["max_batch"] == 4 and health["buckets"] == [8, 16]
+    # the leaves the engine holds in another shape than they are stored
+    assert health["held_shapes"] == {
+        "blocks/w_qkv": [cfg.num_layers, cfg.d_model, 3 * cfg.d_model],
+        "blocks/b_qkv": [cfg.num_layers, 3 * cfg.d_model]}
 
 
 def test_front_door_client_errors(front):
