@@ -346,8 +346,9 @@ class JambaServing:
     def __init__(self, cfg: JambaConfig):
         self.cfg = cfg
         self.vocab_size = cfg.vocab_size
-        self.kv_geometry = (len(cfg.attention_layers),
-                            cfg.num_key_value_heads, cfg.head_dim)
+        self.cache_pools = {
+            "layers": len(cfg.attention_layers),
+            "rows": ((cfg.num_key_value_heads, cfg.head_dim),) * 2}
         self.state_geometry = {
             "layers": cfg.num_mamba_layers,
             "conv_width": (cfg.mamba_d_conv - 1) * cfg.d_inner,

@@ -24,7 +24,8 @@ import jax.numpy as jnp
 
 __all__ = ["decode_attention", "prefill_attention", "paged_gather",
            "paged_cache_update", "paged_page_write",
-           "paged_prefill_attention", "window_attention"]
+           "paged_prefill_attention", "window_attention",
+           "latent_decode_attention"]
 
 
 def decode_attention(q, k_cache, v_cache, lengths,
@@ -71,6 +72,26 @@ def decode_attention(q, k_cache, v_cache, lengths,
     out = jnp.einsum("bns,bsnh->bnh", probs,
                      v_cache.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+def latent_decode_attention(q_lat, rows, lengths, rank: int,
+                            sm_scale: float):
+    """One-token absorbed latent attention over a gathered view: the
+    lowering of ``pallas_kernels.mla_paged_decode_attention`` off the TPU,
+    and its parity reference.
+
+    q_lat ``[B, H, rank + rope]``; rows ``[B, S, rank + rope]`` (one shared
+    row a token: ``[c_kv | k_rope]``, only ``[:lengths[b]]`` valid, the
+    current token included); returns ``[B, H, rank]``: the probabilities
+    times the rows' first ``rank`` values. Float32 inside; an empty slot
+    gives zeros. In the terms of :func:`_grouped_attention`: one key/value
+    head serving a group of ``H`` queries, its value a slice of its key."""
+    valid = jnp.arange(rows.shape[1])[None, :] < lengths[:, None]
+    shared = rows[:, :, None]                            # [B, S, 1, W]
+    out = _grouped_attention(q_lat[:, None, None], shared,
+                             shared[..., :rank],
+                             valid[:, None, None, None], sm_scale)
+    return out[:, 0, 0]
 
 
 def _grouped_attention(q, k, v, mask, sm_scale):

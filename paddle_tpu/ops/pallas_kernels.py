@@ -1563,6 +1563,165 @@ def fused_paged_decode_attention(q, k_pool, v_pool, new_k, new_v, tables,
     return out, k_pool, v_pool
 
 
+# Latent (MLA) decode attention in absorbed form. The cache holds ONE row a
+# token and layer, ``[c_kv | k_rope | 0..]`` (``rank + rope`` values, 512 +
+# 64, and zeros up to whole lanes: Mosaic refuses a page copy whose minor
+# dimension is not whole 128-lane tiles, and the TPU stores a 576-wide
+# bfloat16 row in 640 lanes whatever its shape says), shared by all heads;
+# a head's query is ``[q_nope W_uk | q_rope | 0..]`` of the same width, its
+# score the plain product with the row, and its output the
+# probabilities times the row's first ``rank`` values (``W_uv`` is applied
+# outside). That is 64 query heads against one key/value head whose value
+# is a slice of its key: M = heads, so scores and the weighted sum run on
+# the MXU. The pool ``[L, P, page, rank + rope]`` stays in HBM, one grid
+# step a slot (its query and output blocks are pipelined by Pallas), and
+# the page copies of ``_decode_paged_kernel``, double-buffered across chunks
+# AND grid steps: the parity of the flat work-item count lives in SMEM.
+_MLA_CHUNK_ROWS = 512
+
+
+def mla_decode_tiles(page_size: int, dtype) -> bool:
+    """Whether Mosaic takes the latent kernel's page copies: a page lands
+    in VMEM as ``(page, width)`` rows on sublanes, and a chunk of pages is
+    read as one ``(pages * page, width)`` matrix, which needs a page to be
+    whole sublane tiles (16 rows of bfloat16, 8 of float32); the row's
+    width is whole lanes by the model's choice of ``cache_width``."""
+    return page_size % (32 // jnp.dtype(dtype).itemsize) == 0
+
+
+def _mla_decode_kernel(layer_ref, tbl_ref, pos_ref, q_ref, pool_hbm, o_ref,
+                       buf, sems, w_smem, m_scr, l_scr, acc_scr, *,
+                       sm_scale, page, pages_per_chunk, batch, rank):
+    G = pages_per_chunk
+    layer = layer_ref[0]
+    b = pl.program_id(0)
+
+    def live_pages(s):               # pages holding rows [0, pos[s]]
+        return pos_ref[s] // page + 1
+
+    def copies(s, c, slot, go):
+        n = live_pages(s)
+        for i in range(G):
+            pg = c * G + i
+
+            @pl.when(pg < n)
+            def _():
+                cp = pltpu.make_async_copy(
+                    pool_hbm.at[layer, tbl_ref[s, pg]], buf.at[slot, i],
+                    sems.at[slot])
+                if go:
+                    cp.start()
+                else:
+                    cp.wait()
+
+    @pl.when(b == 0)
+    def _first():
+        w_smem[0] = 0
+        copies(0, 0, 0, True)
+
+    pos = pos_ref[b]
+    nc = (live_pages(b) + G - 1) // G
+    R = G * page
+    q = q_ref[0]                                         # (H, rank + rope)
+
+    def chunk(c, w):
+        slot = w % 2
+        last = c + 1 == nc
+        nb = jnp.where(last, b + 1, b)
+        nxt = jnp.where(last, 0, c + 1)
+
+        @pl.when(nb < batch)
+        def _prefetch():
+            copies(nb, nxt, 1 - slot, True)
+
+        copies(b, c, slot, False)
+
+        @pl.when(c == 0)
+        def _init():
+            m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
+            l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+            acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+        rows = buf[slot].reshape(R, buf.shape[-1])
+        # rows past the slot's length may be VMEM no copy has written:
+        # masked in the scores AND zeroed where they are values
+        live = c * R + jax.lax.broadcasted_iota(
+            jnp.int32, (R, 1), 0) < pos + 1
+        rows = jnp.where(live, rows, 0).astype(rows.dtype)
+        ckv = rows[:, :rank]
+        s = jax.lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale   # (H, R)
+        valid = c * R + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1) < pos + 1
+        s = jnp.where(valid, s, -jnp.inf)
+        m_prev = m_scr[...]                              # (H, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        alpha = jnp.exp(m_prev - m_safe)
+        e = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(e, axis=1, keepdims=True)
+        acc_scr[...] = alpha * acc_scr[...] + jnp.dot(
+            e.astype(rows.dtype), ckv, preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
+        return w + 1
+
+    w_smem[0] = jax.lax.fori_loop(0, nc, chunk, w_smem[0])
+    o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+                ).astype(o_ref.dtype)
+
+
+def mla_paged_decode_attention(q_lat, pool, new_rows, tables, positions,
+                               layer, rank: int, sm_scale: float):
+    """The latent decode step of one layer: the riders' new rows written
+    through the page table (a scatter on the carried pool), then one-token
+    absorbed attention over each slot's LIVE pages, read where they lie.
+
+    q_lat ``[B, H, W]`` (``[q_nope W_uk | q_rope | 0..]``, ``W`` whole
+    lanes); pool ``[L, P, page, W]``; new_rows ``[B, W]`` (``[c_kv | k_rope
+    | 0..]`` of this step's token); tables ``[B, M]`` int32 (all-zero rows
+    = idle lanes writing the scratch page); positions ``[B]`` int32 in
+    ``[0, M * page)``; layer: int32 scalar (traced). Returns ``(out [B, H,
+    rank] in q_lat's dtype: softmax(q . row) . row[:rank], pool')``."""
+    from .decode_attention import paged_cache_update
+
+    B, M = tables.shape
+    page, width = pool.shape[2], pool.shape[3]
+    H = q_lat.shape[1]
+    phys = jnp.take_along_axis(
+        tables, (positions // page)[:, None], axis=1)[:, 0]
+    pool = paged_cache_update(pool, new_rows, phys, positions % page,
+                              layer=layer)
+    _count_launch("mla_paged_decode")
+    G = max(1, min(M, _MLA_CHUNK_ROWS // page))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(B,),
+        in_specs=[pl.BlockSpec((1, H, width), lambda b, *_: (b, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, H, rank), lambda b, *_: (b, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, G, page, width), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SMEM((1,), jnp.int32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, rank), jnp.float32)])
+    with jax.named_scope("mla_paged_decode_attention"):
+        out = pl.pallas_call(
+            functools.partial(_mla_decode_kernel, sm_scale=sm_scale,
+                              page=page, pages_per_chunk=G, batch=B,
+                              rank=rank),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((B, H, rank), q_lat.dtype),
+            compiler_params=_CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=_interpret(),
+            name="mla_paged_decode",
+        )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+          tables.astype(jnp.int32), positions.astype(jnp.int32),
+          q_lat.astype(pool.dtype), pool)
+    return out, pool
+
+
 def _logits_head_kernel(x_ref, scale_ref, bias_ref, w_ref, o_ref, *, eps):
     x32 = x_ref[...].astype(jnp.float32)
     mu = jnp.mean(x32, axis=1, keepdims=True)

@@ -42,9 +42,11 @@ module assembles them into the serving shape:
 The engine is single-threaded by contract: exactly one scheduler loop
 calls it (serving/scheduler.py). The prefill and decode programs take
 their layers from a *model description* (``serving/model.py``: the GPT
-block, or ``models/jamba.py``'s hybrid of Mamba and attention layers
-with per-slot recurrent state); the verify program is still written for
-the GPT block (ROADMAP D2).
+block, ``models/jamba.py``'s hybrid of Mamba and attention layers
+with per-slot recurrent state, or ``models/kimi_k2.py``'s latent attention
+over one pool of latent rows with sparse experts, whose per-layer load
+comes back with the logits); the verify program is still written for the
+GPT block (ROADMAP D2).
 """
 from __future__ import annotations
 
@@ -187,8 +189,7 @@ class DecodeEngine:
                 f"EngineConfig.max_seq {ecfg.max_seq} exceeds the model's "
                 f"positional table {self.model.max_positions}")
         self.ecfg = ecfg
-        if self.model.recurrent:
-            self._refuse_what_cannot_carry_state(ecfg)
+        self._refuse_what_cannot_carry_state(ecfg)
         self.buckets = ecfg.resolved_buckets()
         if ecfg.kv_layout != "paged":
             raise ValueError(f"kv_layout {ecfg.kv_layout!r}: "
@@ -226,14 +227,15 @@ class DecodeEngine:
         # they are stored (/health shows it)
         self.held_shapes = _held_shapes(params, qparams)
         cache_dtype = ecfg.cache_dtype or cfg.dtype
-        kv_layers, kv_heads, kv_head_dim = self.model.kv_geometry
-        # one manager for both kinds of cache: pages for the attention
-        # layers, a state row a slot for the recurrent ones
+        pools = self.model.cache_pools
+        # one manager for every kind of cache: pages for the attention
+        # layers (keys and values of every head, or a latent model's one
+        # row a token), a state row a slot for the recurrent ones
         self.cache = PagedKVCache(
-            kv_layers, ecfg.max_batch, ecfg.max_seq,
-            kv_heads, kv_head_dim, dtype=cache_dtype,
-            page_size=ecfg.page_size, num_pages=ecfg.num_pages,
-            state=self.model.state_geometry)
+            pools["layers"], ecfg.max_batch, ecfg.max_seq,
+            dtype=cache_dtype, page_size=ecfg.page_size,
+            num_pages=ecfg.num_pages, state=self.model.state_geometry,
+            rows=pools["rows"])
         self.prefix = (PrefixCache(self.cache, ecfg.prefix_cache_pages)
                        if ecfg.prefix_cache else None)
         # pool pressure reclaims the pages only the prefix cache holds
@@ -251,7 +253,7 @@ class DecodeEngine:
         if self._mesh is not None:
             self.kv_path = "xla_gather"
         elif self.model.paged_kernel and (
-                _pk.paged_decode_tiles(kv_heads, kv_head_dim)
+                self.model.kernel_takes_pages(ecfg.page_size, cache_dtype)
                 if _pk._on_tpu() else ecfg.fused_decode):
             self.kv_path = "pallas_paged"
         else:
@@ -278,43 +280,75 @@ class DecodeEngine:
         # published pages survive restarts — attach_prefix_store()
         self.prefix_store = None
         self._tokens_window: List[Tuple[float, int]] = []  # (t, n) samples
+        # what the last prefill / decode call's expert layers reported
+        # (``_note_experts``); None for a model without experts
+        self.last_expert_load: Optional[Dict[str, int]] = None
+
+    def _beside_plain_pages(self):
+        """``(what the model has, what a mechanism would have to carry)``
+        for a model whose slots hold more or other than pages of keys and
+        values, else None."""
+        if self.model.recurrent:
+            return "recurrent layers", "recurrent state"
+        if getattr(self.model, "latent", False):
+            return "a latent cache", "latent rows"
+        return None
 
     def _refuse_what_cannot_carry_state(self, ecfg: EngineConfig) -> None:
-        """The one place the rule is stated: a model with recurrent layers
+        """The one place the rule is stated. A model with recurrent layers
         keeps, beside its pages, a state a slot that is advanced token by
         token and cannot be cut at a page boundary, rolled back, exported
-        or split over chips by anything built so far. Each mechanism that
-        would have to carry it is refused by name."""
-        def refuse(mechanism, why):
+        or split over chips by anything built so far. A model with latent
+        attention keeps one compressed row a token in ONE pool, and the
+        mechanisms below are written for pages of keys and values of equal
+        heads. Each mechanism that would have to carry either is refused by
+        name."""
+        beside = self._beside_plain_pages()
+        if beside is None:
+            return
+        has, carry = beside
+        recurrent = self.model.recurrent
+
+        def refuse(mechanism, why, why_latent=None):
+            if not recurrent and why_latent:
+                why = why_latent
             raise ValueError(
-                f"{type(self.model).__name__} has recurrent layers: "
-                f"{mechanism} cannot carry recurrent state ({why})")
+                f"{type(self.model).__name__} has {has}: {mechanism} cannot "
+                f"carry {carry} ({why})")
 
         if ecfg.prefix_cache:
             refuse("the prefix cache (prefix_cache=True)",
                    "a cached page holds keys and values, not the state at "
-                   "its boundary; pass prefix_cache=False")
+                   "its boundary; pass prefix_cache=False",
+                   "no continuation prefill attends a rung over cached "
+                   "latent rows; pass prefix_cache=False")
         if ecfg.verify_window:
             refuse("the verify window (verify_window > 0, speculative "
                    "decoding)", "a rejected draft token cannot be taken "
-                   "back out of the state")
+                   "back out of the state",
+                   "the verify program is the GPT block's, over a key and "
+                   "a value pool")
         if ecfg.sharding is not None:
             refuse("the tensor-parallel engine (sharding='tp')",
-                   "no plan shards the scan's channels")
+                   "no plan shards the scan's channels",
+                   "no plan shards the latent projections or the experts, "
+                   "and the shared row has no head axis to split")
         if ecfg.weight_dtype not in ("bf16", "f32"):
             refuse(f"weight_dtype {ecfg.weight_dtype!r} (int8)",
                    "the quantiser's flat chunks are dequantised by the "
                    "GPT block's programs alone; use 'bf16' or 'f32'")
         if ecfg.role != "colocated":
             refuse(f"role {ecfg.role!r} (phase disaggregation, "
-                   "kv_transfer)", "a hand-off ships pages only")
+                   "kv_transfer)", "a hand-off ships pages only",
+                   "a hand-off ships pages of keys and values")
 
     def _refuse_kv_transfer(self) -> None:
-        if self.model.recurrent:
+        beside = self._beside_plain_pages()
+        if beside is not None:
             raise ValueError(
-                f"{type(self.model).__name__} has recurrent layers: "
+                f"{type(self.model).__name__} has {beside[0]}: "
                 "kv_transfer (export_request_kv / adopt_request_kv) ships "
-                "pages of keys and values and cannot carry recurrent state")
+                f"pages of keys and values and cannot carry {beside[1]}")
 
     def attach_prefix_store(self, store) -> int:
         """Arm warm restart (docs/serving.md "Resilience"): restore the
@@ -408,7 +442,7 @@ class DecodeEngine:
         T = tokens.shape[1]
         positions = prefix_len + jnp.arange(T)
         x = m.embed(qparams, tokens, positions[None])          # [1, T, D]
-        x, caches = m.prefill_layers(qparams, x, caches, _model.ctx(
+        x, caches, *report = m.prefill_layers(qparams, x, caches, _model.ctx(
             length=length, prefix_len=prefix_len, table_row=table_row,
             slot=slot, page_size=self.ecfg.page_size))
         h_last = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0,
@@ -416,7 +450,7 @@ class DecodeEngine:
         logits = m.logits(qparams, h_last)
         tok = samp.sample_token(logits, temp, top_k, top_p, seed,
                                 prefix_len + length - 1)
-        return caches, logits, tok
+        return (caches, logits, tok, *report)
 
     def _decode_fn_paged(self, qparams, caches, tokens, positions,
                          tables, actives, temps, top_ks, top_ps, seeds):
@@ -430,14 +464,16 @@ class DecodeEngine:
         leaves their state as it is)."""
         m = self.model
         x = m.embed(qparams, tokens, positions)
-        x, caches = m.decode_layers(qparams, x, caches, _model.ctx(
+        x, caches, *report = m.decode_layers(qparams, x, caches, _model.ctx(
             positions=positions, tables=tables, actives=actives,
             page_size=self.ecfg.page_size, kv_path=self.kv_path,
             fused=self.ecfg.fused_decode))
         logits = m.logits(qparams, x, fused=self.ecfg.fused_decode)
         toks = samp.sample_batch(logits, temps, top_ks, top_ps, seeds,
                                  positions)
-        return caches, logits, toks
+        # a model with experts hands its layers' report out with the
+        # logits: one small int32 array, no second program
+        return (caches, logits, toks, *report)
 
     def _verify_fn_paged(self, qparams, kp, vp, tokens, starts, tables,
                          temps, top_ks, top_ps, seeds):
@@ -661,9 +697,10 @@ class DecodeEngine:
         # compiled shape each — warmed here so a disagg handoff's first
         # export/adopt never pays a mid-request compile (~100ms)
         t0 = time.perf_counter()
-        k0, v0 = self.cache.read_pages([0])
-        self.cache.write_pages([0], k0, v0)
-        timings["kv_transfer"] = (time.perf_counter() - t0) * 1e3
+        if self.cache.keys_and_values:
+            k0, v0 = self.cache.read_pages([0])
+            self.cache.write_pages([0], k0, v0)
+            timings["kv_transfer"] = (time.perf_counter() - t0) * 1e3
         self._warm = True
         return timings
 
@@ -765,6 +802,10 @@ class DecodeEngine:
                 exe, bucket, slot, len(suffix), sp_scalars, padded,
                 np.int32(len(suffix)), np.int32(prefix_len), table_row,
                 np.int32(slot))
+            if self.last_expert_load is not None:
+                attrs.update(
+                    expert_tokens=self.last_expert_load["expert_tokens"],
+                    experts_hit=self.last_expert_load["experts_hit"])
             with _spans.span("prefill/publish"):
                 self.cache.set_arrays(caches)
                 if self.prefix is not None:
@@ -787,10 +828,12 @@ class DecodeEngine:
         sampler = _note_sampler("prefill", *sp_scalars[:3])
         try:
             with _spans.span("prefill/run", attrs={"sampler": sampler}):
-                caches, (logits, tok) = self._call(exe, *args, *sp_scalars)
+                caches, (logits, tok, *report) = self._call(
+                    exe, *args, *sp_scalars)
                 tok = int(tok)
             with _spans.span("prefill/fetch_logits"):
                 logits = np.asarray(logits)
+            self._note_experts(report, n_tokens)
         except Exception as e:
             self._poison_on_donation_failure(f"prefill_b{bucket}", e)
             self.cache.free(slot)
@@ -844,6 +887,38 @@ class DecodeEngine:
         slot's rows up to the one the tick writes."""
         return sum(self.cache.pages_for(self.cache.length(s) + 1)
                    for s in slots)
+
+    @property
+    def latent_token_bytes(self) -> int:
+        """Bytes of latent rows a cached token holds, all layers (the
+        values, not the lanes a stored row is padded to): what a decode
+        tick reads of it; 0 for a model whose pages hold keys and values."""
+        if not getattr(self.model, "latent", False):
+            return 0
+        return (self.model.cfg.latent_width * self.cache.num_layers
+                * jnp.dtype(self.cache.dtype).itemsize)
+
+    def _note_experts(self, report, n_tokens: int) -> None:
+        """Read the expert layers' report of the call that just ran
+        (``[expert layers, G + 1]`` int32: tokens on each held expert, and
+        last the held pairs that reached none) into ``last_expert_load``
+        and the ``moe_*`` counters. ``n_tokens``: the tokens that were
+        routed (a rung's valid ones, a tick's riders)."""
+        if not report:
+            return
+        report = np.asarray(report[0])
+        counts, dropped = report[:, :-1], int(report[:, -1].sum())
+        here = int(counts.sum())
+        routed = n_tokens * self.model.cfg.num_experts_per_tok \
+            * counts.shape[0]
+        load = {"expert_tokens": here,
+                "experts_hit": int(np.count_nonzero(counts)),
+                "expert_load_max": int(counts.max())}
+        self.last_expert_load = load
+        smetrics.m_moe_routed.labels("here").inc(here)
+        smetrics.m_moe_routed.labels("elsewhere").inc(routed - here)
+        smetrics.m_moe_load_max.set(load["expert_load_max"])
+        smetrics.m_moe_dropped.inc(dropped)
 
     def state_bytes(self, slots) -> int:
         """Bytes of recurrent state a decode tick over ``slots`` reads and
@@ -914,11 +989,12 @@ class DecodeEngine:
             sampler = _note_sampler("decode", *sp[:3])
         try:
             with _spans.span("decode/run", attrs={"sampler": sampler}):
-                caches, (logits, toks) = self._call(
+                caches, (logits, toks, *report) = self._call(
                     exe, tokens, positions, tables, actives, *sp)
                 toks = np.asarray(toks)
             with _spans.span("decode/fetch_logits"):
                 logits = np.asarray(logits)
+            self._note_experts(report, len(slot_tokens))
         except Exception as e:
             self._poison_on_donation_failure("decode", e)
             raise

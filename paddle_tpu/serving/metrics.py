@@ -18,7 +18,8 @@ __all__ = [
     "m_spec_windows", "m_preemptions", "m_hol_admits",
     "m_shed", "m_replica_restarts", "m_failover", "m_prefix_store",
     "m_kv_transfer_bytes", "m_kv_transfer_ms", "m_pool_prefix",
-    "m_disagg_fallback", "m_sampler_path", "request_code",
+    "m_disagg_fallback", "m_sampler_path", "m_moe_routed",
+    "m_moe_load_max", "m_moe_dropped", "request_code",
 ]
 
 _REG = _obs.default_registry()
@@ -105,6 +106,21 @@ m_state_bytes = _REG.gauge(
 m_state_resets = _REG.counter(
     "paddle_serve_state_resets_total",
     "Slot allocations that start a recurrent state from nothing")
+# sparse experts on one chip of an expert-parallel group (ops/moe.py,
+# models/kimi_k2.py): where the (token, choice) pairs of the expert layers
+# went, how unevenly the held experts were loaded in the last call, and
+# the held pairs that reached no expert: 0 by construction (no capacity),
+# counted so that it is seen and not assumed
+m_moe_routed = _REG.counter(
+    "moe_routed_tokens_total",
+    "Routed (token, choice) pairs of the expert layers, by whether the "
+    "chosen expert is held here or on another chip", ("where",))
+m_moe_load_max = _REG.gauge(
+    "moe_expert_load_max",
+    "Most tokens on one held expert of one layer in the last engine call")
+m_moe_dropped = _REG.counter(
+    "moe_dropped_tokens_total",
+    "Pairs routed to a held expert that no expert computed (always 0)")
 # speculative decoding (serving/spec_decode.py): the acceptance histogram
 # IS the speedup meter — mean accepted/window vs the draft+verify cost
 m_spec_accepted = _REG.histogram(

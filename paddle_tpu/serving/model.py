@@ -7,13 +7,17 @@ description is any object with:
 
 - ``cfg`` (its ``dtype`` is the compute dtype), ``vocab_size``,
   ``max_positions`` (``None`` where no positional table bounds ``max_seq``);
-- ``kv_geometry``: ``(layers, heads, head_dim)`` of the page pools, the
-  attention layers alone;
+- ``cache_pools``: ``{"layers": n, "rows": (shape, ...)}``: the page pools
+  it asks of ``PagedKVCache``, one ``[layers, pages, page, *shape]`` a
+  row shape, the attention layers alone: ``((heads, head_dim),) * 2`` for
+  keys and values, ``((width,),)`` for one pool of latent rows (then
+  ``latent`` is true);
 - ``recurrent`` and ``state_geometry``: whether slots carry recurrent
   state beside their pages, and its sizes (``PagedKVCache``'s ``state``);
 - ``paged_kernel``: whether ``decode_layers`` can read the pools through
-  the page-table kernel (``kv_path`` ``pallas_paged``) where Mosaic takes
-  the page shape, or always gathers;
+  a page-table kernel (``kv_path`` ``pallas_paged``), or always gathers;
+  and where it can, ``kernel_takes_pages(page_size, cache_dtype)``:
+  whether Mosaic takes this engine's page shape;
 - ``hold(params, weight_dtype, chunk, sharded=False)``: the serving
   storage of a float32 parameter tree, a leaf re-laid where the programs
   contract it better so (``sharded``: the engine lays the tree over a mesh
@@ -24,13 +28,16 @@ description is any object with:
 - ``decode_layers(qparams, x [B, D], caches, ctx)`` with ``ctx``:
   ``positions``, ``tables``, ``actives``, ``page_size``, ``kv_path``,
   ``fused``; both return ``(x, caches)``, the caches a tuple ``(k pool, v
-  pool[, conv, ssm])`` updated in place;
+  pool[, conv, ssm])`` (or ``(latent pool,)``) updated in place, and a
+  model with experts a third value, its layers' report (one small int32
+  array that the engine hands out with the logits);
 - ``logits(qparams, h, fused=False)``: final norm and head, float32;
 - ``forward(params, tokens [1, T])``: the plain full forward pass (the
   engine's parity surface).
 
-Two descriptions exist: :class:`GPTServing` here (``models/gpt.py``'s
-block) and ``models/jamba.py:JambaServing``. The engine's verify program
+Three descriptions exist: :class:`GPTServing` here (``models/gpt.py``'s
+block), ``models/jamba.py:JambaServing`` and
+``models/kimi_k2.py:KimiK2Serving``. The engine's verify program
 is still written for the GPT block (ROADMAP D2) and uses the block
 helpers below directly.
 """
@@ -139,7 +146,11 @@ class GPTServing:
         self.cfg = cfg
         self.vocab_size = cfg.vocab_size
         self.max_positions = cfg.max_seq_len
-        self.kv_geometry = (cfg.num_layers, cfg.num_heads, cfg.head_dim)
+        self.cache_pools = {"layers": cfg.num_layers,
+                            "rows": ((cfg.num_heads, cfg.head_dim),) * 2}
+
+    def kernel_takes_pages(self, page_size, cache_dtype) -> bool:
+        return _pk.paged_decode_tiles(self.cfg.num_heads, self.cfg.head_dim)
 
     def hold(self, params, weight_dtype: str, chunk: int, sharded=False):
         """The serving storage: ``quantize_params`` of the stored tree
@@ -260,6 +271,10 @@ def describe(cfg):
 
     if isinstance(cfg, jamba_mod.JambaConfig):
         return jamba_mod.JambaServing(cfg)
+    from ..models import kimi_k2 as kimi_mod
+
+    if isinstance(cfg, kimi_mod.KimiK2Config):
+        return kimi_mod.KimiK2Serving(cfg)
     raise TypeError(
         f"DecodeEngine: no model description for {type(cfg).__name__}; "
         "pass an object with the surface serving/model.py lists")

@@ -46,6 +46,14 @@ program writes it from an empty history, never from what the row held), is
 advanced by the ticks the slot rides, and is dead at ``free``; the pool is
 then built for the attention layers alone. The one object answers
 ``can_admit``, ``alloc``, ``free``, ``length`` and ``nbytes`` for both.
+
+**Latent rows** (docs/serving.md "Latent attention"): a model whose
+attention caches one compressed row a token, shared by all heads, asks for
+``rows=((width,),)``: ONE pool ``[L, num_pages, page_size, width]`` in place
+of the key and value pair. Pages, tables, refcounts and the allocator are
+the same; what a page holds is the model's business. The page-content I/O
+(``read_pages``/``write_pages``/``adopt_slot``: prefix store, KV hand-off)
+is written for the pair and refuses a manager with another set of pools.
 """
 from __future__ import annotations
 
@@ -119,9 +127,11 @@ class PagedKVCache:
     token boundaries is a host-side bookkeeping edit, not a recompile."""
 
     def __init__(self, num_layers: int, max_slots: int, max_seq: int,
-                 num_heads: int, head_dim: int, dtype: Any = jnp.float32,
+                 num_heads: int = 0, head_dim: int = 0,
+                 dtype: Any = jnp.float32,
                  page_size: int = 8, num_pages: int = 0,
-                 state: Optional[Dict[str, int]] = None):
+                 state: Optional[Dict[str, int]] = None,
+                 rows: Optional[Sequence[Tuple[int, ...]]] = None):
         if max_slots < 1 or max_seq < 1:
             raise ValueError("max_slots and max_seq must be >= 1")
         if page_size < 1 or max_seq % page_size:
@@ -130,6 +140,8 @@ class PagedKVCache:
         self.num_layers = int(num_layers)
         self.max_slots = int(max_slots)
         self.max_seq = int(max_seq)
+        if rows is not None and len(rows[0]) == 2:
+            num_heads, head_dim = rows[0]
         self.num_heads = int(num_heads)
         self.head_dim = int(head_dim)
         self.dtype = dtype
@@ -141,10 +153,13 @@ class PagedKVCache:
             self.max_slots * self.max_pages_per_slot + 1)
         if self.num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is scratch)")
-        shape = (self.num_layers, self.num_pages, self.page_size,
-                 self.num_heads, self.head_dim)
-        self.k = jnp.zeros(shape, dtype)
-        self.v = jnp.zeros(shape, dtype)
+        # what a token's row holds in each pool: keys and values of every
+        # head unless the model says otherwise (``rows``)
+        self.rows = tuple(tuple(int(n) for n in r) for r in (
+            rows or ((self.num_heads, self.head_dim),) * 2))
+        self.pools = [jnp.zeros((self.num_layers, self.num_pages,
+                                 self.page_size) + r, dtype)
+                      for r in self.rows]
         # per-slot recurrent state: {"layers", "conv_width", "d_state",
         # "d_inner"} from the model, None for an attention-only model
         self.conv = self.ssm = None
@@ -172,9 +187,31 @@ class PagedKVCache:
 
     # -- geometry ----------------------------------------------------------
     @property
+    def keys_and_values(self) -> bool:
+        """Whether the pools are the key and value pair the page-content
+        I/O (and the engine's verify program) is written for."""
+        return len(self.pools) == 2
+
+    @property
+    def k(self):
+        return self.pools[0]
+
+    @k.setter
+    def k(self, value):
+        self.pools[0] = value
+
+    @property
+    def v(self):
+        return self.pools[1]
+
+    @v.setter
+    def v(self, value):
+        self.pools[1] = value
+
+    @property
     def nbytes(self) -> int:
         """Pools and recurrent state together."""
-        return (int(self.k.size + self.v.size)
+        return (sum(int(p.size) for p in self.pools)
                 * jnp.dtype(self.dtype).itemsize
                 + self.state_bytes_per_slot * self.max_slots)
 
@@ -183,16 +220,18 @@ class PagedKVCache:
         return self.state_bytes_per_slot > 0
 
     def arrays(self) -> tuple:
-        """What the compiled programs carry: ``(k, v)``, and the two state
-        arrays behind them where there are any."""
+        """What the compiled programs carry: the pools (``(k, v)``, or a
+        latent model's one), and the two state arrays behind them where
+        there are any."""
         if self.recurrent:
-            return (self.k, self.v, self.conv, self.ssm)
-        return (self.k, self.v)
+            return (*self.pools, self.conv, self.ssm)
+        return tuple(self.pools)
 
     def set_arrays(self, arrays) -> None:
-        self.k, self.v = arrays[0], arrays[1]
+        n = len(self.pools)
+        self.pools = list(arrays[:n])
         if self.recurrent:
-            self.conv, self.ssm = arrays[2], arrays[3]
+            self.conv, self.ssm = arrays[n], arrays[n + 1]
 
     def live_state_bytes(self) -> int:
         return self.state_bytes_per_slot * (
@@ -383,11 +422,19 @@ class PagedKVCache:
         the prefix cache's reclaim) when the pool cannot cover it."""
         return self._take_pages(int(n))
 
+    def _keys_and_values_only(self, what: str) -> None:
+        if not self.keys_and_values:
+            raise ValueError(
+                f"{what}: page contents move as a key and value pair; this "
+                f"manager's pools hold rows of {self.rows} (latent rows "
+                "have no prefix store and no hand-off)")
+
     def read_pages(self, pages: Sequence[int]
                    ) -> Tuple[np.ndarray, np.ndarray]:
         """Host copies of the K/V contents of ``pages``:
         ``([L, n, page_size, nh, hd] k, same v)`` — what the prefix
         store persists at publish time."""
+        self._keys_and_values_only("read_pages")
         idx = np.asarray(list(pages), np.int32)
         n = idx.size
         pad = -n % TRANSFER_PAGE_BUCKET
@@ -405,6 +452,7 @@ class PagedKVCache:
         """Write restored K/V contents into ``pages`` (boot-time only:
         the arrays are replaced wholesale, which is exactly how the
         engine treats them between executable calls)."""
+        self._keys_and_values_only("write_pages")
         idx = np.asarray(list(pages), np.int32)
         n = idx.size
         pad = -n % TRANSFER_PAGE_BUCKET
@@ -434,6 +482,7 @@ class PagedKVCache:
         Raises :class:`CacheFullError` when no slot is free (the caller
         still owns the pages and must deref them)."""
         pages = [int(p) for p in pages]
+        self._keys_and_values_only("adopt_slot")
         if self.recurrent:
             raise ValueError(
                 "adopt_slot: pages carry keys and values only; a slot's "

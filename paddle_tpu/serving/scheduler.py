@@ -694,18 +694,31 @@ class Scheduler:
         # way round
         eng = self.engine
         state_bytes = eng.state_bytes(feed)
-        with _spans.span("serve/decode_tick", attrs={
+        cached = sum(eng.cache.length(s) for s in feed)
+        attrs = {
                 "step": self.steps, "batch": len(feed),
                 "riders": [r.id for r in self._active.values()],
-                "cached_tokens": sum(eng.cache.length(s) for s in feed),
+                "cached_tokens": cached,
                 # how the tick reads the cache, and how many pages of it
                 "kv_path": eng.kv_path,
                 "live_pages": eng.live_pages(feed),
                 # riders whose recurrent state the tick advances, and the
                 # bytes of it they hold (0 where no layer is recurrent)
                 "state_slots": len(feed) if state_bytes else 0,
-                "state_bytes": state_bytes}):
+                "state_bytes": state_bytes}
+        if eng.latent_token_bytes:
+            # the latent rows of the riders' cached tokens, all layers
+            attrs["latent_bytes"] = cached * eng.latent_token_bytes
+        with _spans.span("serve/decode_tick", attrs=attrs):
             out = eng.generate_step(feed, params)
+            # what the tick's expert layers reported, off the device with
+            # its logits: routings that fell on held experts, held experts
+            # with a token (summed over layers), the fullest one's load
+            load = getattr(eng, "last_expert_load", None)
+            if load is not None:
+                attrs.update(
+                    {k: load[k] for k in ("expert_tokens", "experts_hit",
+                                          "expert_load_max")})
         attrs = {"emitted": 0, "finished": 0}
         with _spans.span("serve/emit", attrs=attrs):
             self._emit(out, attrs)

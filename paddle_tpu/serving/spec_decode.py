@@ -153,6 +153,10 @@ class SpecDecodeEngine:
         return self.target.state_bytes(slots)
 
     @property
+    def latent_token_bytes(self) -> int:
+        return self.target.latent_token_bytes
+
+    @property
     def poisoned(self):
         return self.target.poisoned or self.draft.poisoned
 

@@ -613,3 +613,99 @@ def test_decode_tick_sorts_the_vocabulary_under_a_conditional(one_chip,
     for body in greedy:
         assert not dear(body, ("sort", "exponential"))
     assert not set(sorts) & temperature and set(sorts) <= filtered
+
+
+# ---------------------------------------------------------------------------
+# sparse experts and a latent cache (models/kimi_k2.py, ops/moe.py)
+# ---------------------------------------------------------------------------
+
+KIMI_YARN = {"type": "yarn", "factor": 64.0, "beta_fast": 32.0,
+             "beta_slow": 1.0, "mscale": 1.0, "mscale_all_dim": 1.0,
+             "original_max_position_embeddings": 4096}
+
+
+def _kimi_cut_program(program, sharding):
+    """(config, the cell's decode tick or a prefill rung compiled for the
+    described chip) of the expert-parallel cell at its published widths,
+    128 slots of 3072 tokens, 12 held experts of 384, an eighth of the
+    vocabulary, cut to layer 0 (dense) and two expert layers (so that the
+    expert layers' loop is real). Compiled from SHAPES alone: the engine's
+    pure functions on an engine that was never built (its weights would be
+    4 GB of host memory that no compile reads)."""
+    from paddle_tpu import serving
+    from paddle_tpu.models import kimi_k2 as KK
+    from paddle_tpu.serving import engine as E
+
+    B, S = 128, 3072
+    cfg = KK.KimiK2Config(vocab_size=20480, num_hidden_layers=3,
+                          experts_held=12, rope_scaling=KIMI_YARN)
+    key = ("kimi", program)
+    if key not in _TICKS:
+        eng = object.__new__(E.DecodeEngine)
+        eng.model, eng.cfg = KK.KimiK2Serving(cfg), cfg
+        eng.ecfg = serving.EngineConfig(
+            max_batch=B, max_seq=S, page_size=PAGE, weight_dtype="bf16",
+            prefix_cache=False)
+        eng.kv_path = "pallas_paged"
+        assert eng.model.kernel_takes_pages(PAGE, BF16)
+
+        def arg(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+        stored = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s, F32), KK.leaf_shapes(cfg),
+            is_leaf=lambda s: isinstance(s, tuple))
+        held = jax.tree_util.tree_map(
+            lambda a: arg(a.shape, a.dtype),
+            jax.eval_shape(lambda p: KK.hold(p, cfg, "bf16"), stored))
+        pool = (arg((3, B * S // PAGE + 1, PAGE, cfg.cache_width), BF16),)
+        i32 = jnp.int32
+        if program == "decode":
+            fn, rest = eng._decode_fn_paged, (
+                arg((B,), i32), arg((B,), i32), arg((B, S // PAGE), i32),
+                arg((B,), i32), arg((B,), F32), arg((B,), i32),
+                arg((B,), F32), arg((B,), i32))
+        else:
+            T = int(program.split("_b")[1])
+            fn, rest = eng._prefill_fn_paged, (
+                arg((1, T), i32), arg((), i32), arg((), i32),
+                arg((S // PAGE,), i32), arg((), i32), arg((), F32),
+                arg((), i32), arg((), F32), arg((), i32))
+        _TICKS[key] = jax.jit(fn, donate_argnums=(1,)).lower(
+            held, pool, *rest).compile()
+    return cfg, _TICKS[key]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_b512"])
+def test_kimi_programs_copy_no_expert_leaf(one_chip, program):
+    """The expert-parallel cell's tick and a prefill rung on the chip's
+    own compile: both kernels are there by name (``moe_grouped_matmul``
+    takes the stacked experts with the layer as a prefetched scalar,
+    ``mla_paged_decode`` the pool where it lies), the donated pool is
+    aliased whole, and nothing the size of ONE expert's smallest leaf
+    (``w_down [F, D]``, 14.7 M elements: the held leaves are 12 and 24
+    times that a layer) is copied, transposed, converted or sliced out:
+    the experts are held as the grouped product contracts them
+    (``KimiK2Serving.hold``). The tick never materialises the padded ``[128,
+    3072, 640]`` view of the pool either (the gather lowering's): its
+    temporaries stay under a tenth of one layer's pool."""
+    cfg, compiled = _kimi_cut_program(program, one_chip)
+    hlo = compiled.as_text()
+    assert re.search(r"%moe_grouped_matmul[\w.]* = ", hlo)
+    assert bool(re.search(r"%mla_paged_decode[\w.]* = ", hlo)) == (
+        program == "decode")
+    one_expert_leaf = cfg.moe_intermediate_size * cfg.hidden_size
+    moves = (_weight_sized_relayouts(hlo, at_least=one_expert_leaf)
+             + _pool_sized_moves(hlo, at_least=one_expert_leaf))
+    # a rung's own activations are that large ([T, H, 192] queries);
+    # weights and pool are told from them by their leading sizes
+    weights = [m for m in moves if re.search(
+        r"\[(?:\d+,)*(?:12,7168,4096|12,2048,7168|7168,4096|2048,7168|"
+        r"24577,16,640|128,3072,640|393216,640)\]", m)]
+    assert not weights, "\n".join(weights)
+    mem = compiled.memory_analysis()
+    layer_pool = (128 * 3072 + PAGE) * cfg.cache_width * 2
+    assert mem.alias_size_in_bytes >= 3 * layer_pool
+    if program == "decode":
+        assert not moves, "\n".join(moves)
+        assert mem.temp_size_in_bytes < layer_pool // 10
