@@ -107,6 +107,21 @@ def _held_shapes(params, qparams) -> Dict[str, Tuple[int, ...]]:
             if tuple(q.shape) != tuple(np.shape(p))}
 
 
+@dataclasses.dataclass
+class _Tick:
+    """A decode call that has been dispatched and not yet collected."""
+    feed: Dict[int, int]         # slot -> input token, the riders
+    # the call's cache outputs; None once they are the manager's arrays
+    # (a tick dispatched ahead swaps them in at dispatch: its inputs were
+    # donated to it, and whatever runs next must take its outputs)
+    caches: Optional[tuple]
+    logits: Any
+    toks: Any
+    report: list
+    sampler: str
+    t0: int                      # perf_counter_ns at dispatch
+
+
 def default_bucket_ladder(max_seq: int, smallest: int = 16) -> Tuple[int, ...]:
     """Powers of two from ``smallest`` up to ``max_seq`` (inclusive as the
     last rung). Each rung is one AOT-compiled prefill executable — the
@@ -283,6 +298,15 @@ class DecodeEngine:
         # what the last prefill / decode call's expert layers reported
         # (``_note_experts``); None for a model without experts
         self.last_expert_load: Optional[Dict[str, int]] = None
+        # early dispatch (docs/serving.md "The tick's anatomy"): the hook
+        # a Scheduler installs round its tick, asked for the next tick as
+        # soon as a tick's sampled tokens are on the host: {slot: token
+        # sampled} -> ({slot: input token}, {slot: SamplingParams}) or
+        # None. A call from anyone else (a test, a resumed request's
+        # replay, the speculative wrapper) finds none and never runs ahead.
+        self.next_tick = None
+        # the tick dispatched ahead of its decode_step_sampled call
+        self._ahead: Optional[_Tick] = None
 
     def _beside_plain_pages(self):
         """``(what the model has, what a mechanism would have to carry)``
@@ -711,13 +735,16 @@ class DecodeEngine:
         if self.poisoned is not None:
             raise RuntimeError(f"engine poisoned: {self.poisoned}")
 
-    def _poison_on_donation_failure(self, name: str, exc: Exception) -> None:
+    def _poison_on_donation_failure(self, name: str, exc: Exception,
+                                    swapped: bool = False) -> None:
         """An executable compiled with donate_argnums died mid-call: the
         cache pools it was handed are donation-invalidated, so cache.k/v
         can no longer be trusted. Mark the engine fatally poisoned rather
         than let later calls read freed buffers. (Without donation — CPU —
-        the pools are untouched and the engine stays usable.)"""
-        if self._donate and self.poisoned is None:
+        the pools are untouched and the engine stays usable, unless the
+        call's outputs were ``swapped`` in before it failed: a tick
+        dispatched ahead.)"""
+        if (self._donate or swapped) and self.poisoned is None:
             self.poisoned = (
                 f"{name} failed after cache-buffer donation "
                 f"({type(exc).__name__}: {exc}); KV pools invalidated — "
@@ -964,49 +991,143 @@ class DecodeEngine:
     ) -> Dict[int, Tuple[int, np.ndarray]]:
         """One decode step with per-slot sampling: {slot: input_token} ->
         {slot: (next_token, logits[V])}. Slots not in the map ride as
-        masked lanes — same shapes, same executable, zero recompiles."""
+        masked lanes — same shapes, same executable, zero recompiles.
+
+        Under a scheduler (``next_tick``) the call may find its tick
+        already in flight, dispatched by the call before it, and may
+        dispatch the next one before it returns (docs/serving.md "The
+        tick's anatomy"); what it returns is the same either way."""
         if not slot_tokens:
             return {}
         self._check_poisoned()
-        # four phases, each a span under the scheduler's serve/decode_tick:
+        # phases, each a span under the scheduler's serve/decode_tick:
         # feed (host arrays), run (the call until the sampled tokens are
         # on the host: the small array first, it waits for the program),
-        # fetch_logits (the transfer alone), commit (host bookkeeping)
-        with _spans.span("decode/feed"):
-            tokens, positions = self._decode_feed(slot_tokens)
-            sp = samp.batch_arrays(params_by_slot or {},
-                                   self.ecfg.max_batch)
-            exe = self._decode_exec()
-            t0 = time.perf_counter_ns()
-            for slot in slot_tokens:
-                if not self.ensure_decode_capacity(slot):
-                    raise PagePoolFullError(
-                        f"slot {slot}: no free page for position "
-                        f"{self.cache.length(slot)}")
-            actives = np.zeros((self.ecfg.max_batch,), np.int32)
-            actives[list(slot_tokens)] = 1
-            tables = self._masked_tables(slot_tokens)
-            sampler = _note_sampler("decode", *sp[:3])
+        # plan (a scheduler's next tick, dispatched while this one's
+        # logits are still on the device), fetch_logits (the transfer
+        # alone), commit (host bookkeeping). A tick found in flight has
+        # had its feed and its call under its predecessor's plan.
+        tick = self._claim_ahead(slot_tokens)
+        was_ahead = tick is not None
+        if was_ahead:
+            sampler = tick.sampler
+        else:
+            with _spans.span("decode/feed"):
+                args, sampler = self._tick_args(slot_tokens, params_by_slot)
         try:
             with _spans.span("decode/run", attrs={"sampler": sampler}):
-                caches, (logits, toks, *report) = self._call(
-                    exe, tokens, positions, tables, actives, *sp)
-                toks = np.asarray(toks)
+                if tick is None:
+                    tick = self._launch(slot_tokens, args, sampler)
+                toks = np.asarray(tick.toks)
+                sampled = {slot: int(toks[slot]) for slot in slot_tokens}
+        except Exception as e:
+            self._poison_on_donation_failure("decode", e, swapped=was_ahead)
+            raise
+        plans = self.next_tick is not None and self._ahead is None
+        if plans:
+            with _spans.span("decode/plan"):
+                self._advance(tick, slot_tokens)
+                nxt = self.next_tick(sampled)
+                if nxt is not None:
+                    self.dispatch_ahead(*nxt)
+        try:
             with _spans.span("decode/fetch_logits"):
-                logits = np.asarray(logits)
-            self._note_experts(report, len(slot_tokens))
+                logits = np.asarray(tick.logits)
+            self._note_experts(tick.report, len(slot_tokens))
+        except Exception as e:
+            self._poison_on_donation_failure("decode", e, swapped=was_ahead)
+            raise
+        smetrics.m_decode_ms.observe(
+            (time.perf_counter_ns() - tick.t0) / 1e6)
+        with _spans.span("decode/commit"):
+            if not plans:
+                self._advance(tick, slot_tokens)
+            out = {slot: (tok, logits[slot])
+                   for slot, tok in sampled.items()}
+            self.note_tokens(len(slot_tokens))
+        return out
+
+    def _tick_args(self, slot_tokens: Dict[int, int], params_by_slot):
+        """The decode call's host arrays for these riders, their next
+        rows' pages mapped: ``(arguments behind the caches, sampler
+        path)``."""
+        tokens, positions = self._decode_feed(slot_tokens)
+        sp = samp.batch_arrays(params_by_slot or {}, self.ecfg.max_batch)
+        for slot in slot_tokens:
+            if not self.ensure_decode_capacity(slot):
+                raise PagePoolFullError(
+                    f"slot {slot}: no free page for position "
+                    f"{self.cache.length(slot)}")
+        actives = np.zeros((self.ecfg.max_batch,), np.int32)
+        actives[list(slot_tokens)] = 1
+        tables = self._masked_tables(slot_tokens)
+        sampler = _note_sampler("decode", *sp[:3])
+        return (tokens, positions, tables, actives, *sp), sampler
+
+    def _launch(self, slot_tokens: Dict[int, int], args, sampler: str,
+                ahead: bool = False) -> _Tick:
+        """Dispatch the decode executable; nothing here waits for it."""
+        t0 = time.perf_counter_ns()
+        caches, (logits, toks, *report) = self._call(
+            self._decode_exec(), *args)
+        if ahead:
+            self.cache.set_arrays(caches)
+            caches = None
+        return _Tick(dict(slot_tokens), caches, logits, toks, report,
+                     sampler, t0)
+
+    def _advance(self, tick: _Tick, slots) -> None:
+        """A collected tick's rows become part of its riders' sequences."""
+        if tick.caches is not None:
+            self.cache.set_arrays(tick.caches)
+            tick.caches = None
+        for slot in slots:
+            self.cache.set_length(slot, self.cache.length(slot) + 1)
+
+    def dispatch_ahead(self, feed: Dict[int, int], params_by_slot) -> None:
+        """Dispatch the tick for ``feed`` now; the next
+        ``decode_step_sampled`` call for these riders collects it."""
+        if self._ahead is not None:
+            raise RuntimeError(f"a tick is in flight for {self._ahead.feed}")
+        args, sampler = self._tick_args(feed, params_by_slot)
+        try:
+            self._ahead = self._launch(feed, args, sampler, ahead=True)
         except Exception as e:
             self._poison_on_donation_failure("decode", e)
             raise
-        smetrics.m_decode_ms.observe((time.perf_counter_ns() - t0) / 1e6)
-        with _spans.span("decode/commit"):
-            self.cache.set_arrays(caches)
-            out: Dict[int, Tuple[int, np.ndarray]] = {}
-            for slot in slot_tokens:
-                self.cache.set_length(slot, self.cache.length(slot) + 1)
-                out[slot] = (int(toks[slot]), logits[slot])
-            self.note_tokens(len(slot_tokens))
-        return out
+
+    def _claim_ahead(self, slot_tokens: Dict[int, int]) -> Optional[_Tick]:
+        """The tick in flight, if this call is the one that collects it:
+        its riders are the call's, less those freed since dispatch, whose
+        lanes are dropped. A call for other slots altogether (the replay
+        of a request resumed while it is in flight) runs beside it."""
+        tick = self._ahead
+        if tick is None or not tick.feed.keys() & slot_tokens.keys():
+            return None
+        if any(tick.feed.get(slot) != tok
+               for slot, tok in slot_tokens.items()):
+            raise RuntimeError(
+                f"a tick is in flight for {tick.feed}, not for "
+                f"{slot_tokens}")
+        self._ahead = None
+        gone = len(tick.feed) - len(slot_tokens)
+        if gone:
+            smetrics.m_early_dispatch.labels("dropped_lanes").inc(gone)
+        return tick
+
+    @property
+    def ahead_feed(self) -> Optional[Dict[int, int]]:
+        """{slot: input token} of the tick in flight, or None."""
+        return None if self._ahead is None else self._ahead.feed
+
+    def drop_ahead(self) -> None:
+        """Forget the tick in flight: its riders are gone (an abort). The
+        program runs to its end on the device; its caches are the
+        manager's already and its tokens and logits are let go."""
+        tick, self._ahead = self._ahead, None
+        if tick is not None:
+            smetrics.m_early_dispatch.labels("dropped_lanes").inc(
+                len(tick.feed))
 
     def generate_step(
             self, slot_tokens: Dict[int, int],

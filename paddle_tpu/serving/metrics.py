@@ -18,7 +18,8 @@ __all__ = [
     "m_spec_windows", "m_preemptions", "m_hol_admits",
     "m_shed", "m_replica_restarts", "m_failover", "m_prefix_store",
     "m_kv_transfer_bytes", "m_kv_transfer_ms", "m_pool_prefix",
-    "m_disagg_fallback", "m_sampler_path", "m_moe_routed",
+    "m_disagg_fallback", "m_sampler_path", "m_early_dispatch",
+    "m_moe_routed",
     "m_moe_load_max", "m_moe_dropped", "request_code",
 ]
 
@@ -98,6 +99,17 @@ m_sampler_path = _REG.counter(
     "Engine calls by the sampler path their batch took "
     "(greedy|temperature|filtered) and program (decode|prefill|verify)",
     ("path", "program"))
+# early dispatch (serving/scheduler.py:_plan_next, docs/serving.md "The
+# tick's anatomy"): once a tick's tokens are on the host, was the next
+# tick dispatched at once (``ahead``), or held for the next step to decide
+# (``held_admission`` | ``held_capacity`` | ``held_idle`` |
+# ``held_engine``); ``dropped_lanes`` counts lanes of ticks in flight
+# whose rider was gone at collection
+m_early_dispatch = _REG.counter(
+    "paddle_serve_early_dispatch_total",
+    "Decode ticks by whether their successor was dispatched before their "
+    "logits were fetched (ahead) or held and why; and lanes dropped",
+    ("outcome",))
 # recurrent state beside the pages (hybrid models, serving/paged_kv.py):
 # what the live slots hold, and how often a slot's state was born anew
 m_state_bytes = _REG.gauge(

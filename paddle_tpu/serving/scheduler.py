@@ -24,6 +24,16 @@ generated tokens folded into the prompt (recompute-style resume; with
 the prefix cache warm, the recompute is usually a suffix prefill) — and
 retries. ``paddle_serve_preemptions_total{reason}`` meters it.
 
+Early dispatch (docs/serving.md "The tick's anatomy"): a plain engine asks
+the scheduler for the next tick (:meth:`Scheduler._plan_next`) as soon as a
+tick's sampled tokens are on the host, and dispatches it before it fetches
+the logits; ``_emit``, the evictions, the step's close and the next step's
+opening then run while the device works. (A tick the step fed itself, the
+first or one beside a prefill, hands its tokens out first and has its
+successor planned after the emit.) The scheduler answers "none", and
+the next step proceeds as it always did, whenever that step could decide
+something the tick would pre-empt: an admission, a preemption, the end.
+
 Threading contract: ``submit``/``cancel`` may be called from any thread
 (the HTTP front door's handler pool); ``step``/``drain`` run on exactly
 one loop thread. Request completion is signaled through a per-request
@@ -187,6 +197,13 @@ class Scheduler:
         # TTFT/TPOT children resolved once: phase is structural (TTFT
         # ends prefill, TPOT is decode cadence), role is this engine's
         self.role = getattr(engine, "role", "colocated")
+        # early dispatch: only the plain engine runs a tick ahead (the
+        # speculative wrapper emits a window a step, and a target compiled
+        # with a verify window is driven by one)
+        self._plain = (isinstance(engine, DecodeEngine)
+                       and not engine.ecfg.verify_window)
+        self.early_dispatch: Dict[str, int] = {}   # outcome -> ticks
+        self._settling = False
         self._ttft_hist = smetrics.m_ttft_ms.labels("prefill", self.role)
         self._tpot_hist = smetrics.m_tpot_ms.labels("decode", self.role)
 
@@ -328,8 +345,9 @@ class Scheduler:
         with _spans.span("serve/step", trace=self.loop_trace, attrs=attrs):
             now = time.monotonic()
             self._expire_queued(now)
-            ingested = self._ingest_handoffs(now)
-            admitted = self._admit(now)
+            # settling collects the tick in flight and starts nothing
+            ingested = 0 if self._settling else self._ingest_handoffs(now)
+            admitted = 0 if self._settling else self._admit(now)
             decoded = self._decode(now)
             self.steps += 1
             occ = self.engine.cache.occupancy
@@ -397,7 +415,21 @@ class Scheduler:
                 if idle:
                     return True
                 self.step()
+            self.settle()
         return False
+
+    def settle(self) -> bool:
+        """Collect the tick in flight, if there is one: hand its tokens
+        out and dispatch no other (loop thread only: what a loop does as
+        it stops). Returns whether there was one."""
+        if not self._plain or self.engine.ahead_feed is None:
+            return False
+        self._settling = True
+        try:
+            self.step()
+        finally:
+            self._settling = False
+        return True
 
     def abort_all(self, reason: str, refuse_new: bool = False) -> int:
         """Fail every queued and active request (the loop's fault path —
@@ -419,6 +451,8 @@ class Scheduler:
             queued += list(self._pending_handoffs)
             self._pending_handoffs.clear()
             smetrics.m_queue_depth.set(0)
+        if self._plain:
+            self.engine.drop_ahead()   # its riders go with everyone else
         n = 0
         for slot in list(self._active):
             self._evict(slot, FAILED, reason)
@@ -502,25 +536,34 @@ class Scheduler:
             self._queue = keep
             smetrics.m_queue_depth.set(len(self._queue))
 
-    def _pop_admissible(self) -> Optional[Request]:
-        """FIFO pop with bounded head-of-line bypass: the first request
-        whose prompt fits the current slot/page budget. A head bypassed
-        past the starvation limit pins the queue until it fits."""
-        with self._lock:
-            if not self._queue:
-                return None
-            head = self._queue[0]
-            for i, req in enumerate(self._queue):
-                if i > 0 and head.hol_skips >= self.cfg.hol_starvation_limit:
-                    return None       # head pinned: wait for its budget
-                if self.engine.can_admit(len(req.gen_prompt())):
-                    del self._queue[i]
-                    smetrics.m_queue_depth.set(len(self._queue))
-                    if i > 0:
-                        head.hol_skips += 1
-                        smetrics.m_hol_admits.inc()
-                    return req
+    def _first_admissible(self) -> Optional[int]:
+        """Place in the queue of the request admission would take next
+        (under ``self._lock``): the first whose prompt fits the current
+        slot/page budget, none past a head bypassed to the starvation
+        limit, which pins the queue until it fits."""
+        if not self._queue:
             return None
+        head = self._queue[0]
+        for i, req in enumerate(self._queue):
+            if i > 0 and head.hol_skips >= self.cfg.hol_starvation_limit:
+                return None           # head pinned: wait for its budget
+            if self.engine.can_admit(len(req.gen_prompt())):
+                return i
+        return None
+
+    def _pop_admissible(self) -> Optional[Request]:
+        """FIFO pop with bounded head-of-line bypass."""
+        with self._lock:
+            i = self._first_admissible()
+            if i is None:
+                return None
+            head, req = self._queue[0], self._queue[i]
+            del self._queue[i]
+            smetrics.m_queue_depth.set(len(self._queue))
+            if i > 0:
+                head.hol_skips += 1
+                smetrics.m_hol_admits.inc()
+            return req
 
     def _admit(self, now: float) -> int:
         """Prefill queued requests into free slots — FIFO with the
@@ -627,8 +670,7 @@ class Scheduler:
             admitted += 1
             if self._should_finish(req, last):
                 self._evict(slot, DONE)
-            elif self.engine.cache.headroom(slot) < getattr(
-                    self.engine, "min_headroom", 1):
+            elif self._out_of_room(slot):
                 # prompt filled the slot to (near) max_seq: the prefill
                 # logits already produced the one token that fits, and
                 # the next generation step could not run — finish here
@@ -680,24 +722,34 @@ class Scheduler:
             if req.deadline <= now:
                 self._evict(slot, EXPIRED,
                             "deadline exceeded mid-generation")
-        if not self._active:
-            return False
-        self._ensure_step_capacity()
-        if not self._active:
-            return False
-        feed = {slot: self._next_token[slot] for slot in self._active}
-        params = {slot: self._active[slot].sampling
-                  for slot in self._active}
+        eng = self.engine
+        # the tick the step before dispatched ahead: this step collects
+        # it for those of its riders that are still here (one expired just
+        # now has its lane dropped); a request prefilled since rides from
+        # the next tick on
+        ahead = eng.ahead_feed if self._plain else None
+        if ahead is not None:
+            feed = {s: t for s, t in ahead.items() if s in self._active}
+            if not feed:
+                eng.drop_ahead()
+                ahead = None
+        if ahead is None:
+            if not self._active:
+                return False
+            self._ensure_step_capacity()
+            if not self._active:
+                return False
+            feed = {slot: self._next_token[slot] for slot in self._active}
+        params = {slot: self._active[slot].sampling for slot in feed}
         # ONE record a tick on the loop's trace: the whole batch shares
         # one executable call, so the tick names its riders and a request
         # finds its ticks by step (first_step..last_step), not the other
         # way round
-        eng = self.engine
         state_bytes = eng.state_bytes(feed)
         cached = sum(eng.cache.length(s) for s in feed)
         attrs = {
                 "step": self.steps, "batch": len(feed),
-                "riders": [r.id for r in self._active.values()],
+                "riders": [self._active[s].id for s in feed],
                 "cached_tokens": cached,
                 # how the tick reads the cache, and how many pages of it
                 "kv_path": eng.kv_path,
@@ -705,12 +757,27 @@ class Scheduler:
                 # riders whose recurrent state the tick advances, and the
                 # bytes of it they hold (0 where no layer is recurrent)
                 "state_slots": len(feed) if state_bytes else 0,
-                "state_bytes": state_bytes}
+                "state_bytes": state_bytes,
+                # dispatched by the tick before it, ahead of this step
+                "ahead": ahead is not None}
         if eng.latent_token_bytes:
             # the latent rows of the riders' cached tokens, all layers
             attrs["latent_bytes"] = cached * eng.latent_token_bytes
+        # a tick found in flight plans its successor inside the call, as
+        # soon as its tokens are on the host; a tick this step fed itself
+        # (the first, one after a prefill or a held decision) has its
+        # riders' longest gap behind it and hands its tokens out first:
+        # its successor is planned after the emit, below
+        if ahead is not None:
+            eng.next_tick = self._plan_next      # for this call alone
+        elif not self._plain:
+            self._count("held_engine")
         with _spans.span("serve/decode_tick", attrs=attrs):
-            out = eng.generate_step(feed, params)
+            try:
+                out = eng.generate_step(feed, params)
+            finally:
+                if ahead is not None:
+                    eng.next_tick = None
             # what the tick's expert layers reported, off the device with
             # its logits: routings that fell on held experts, held experts
             # with a token (summed over layers), the fullest one's load
@@ -722,7 +789,76 @@ class Scheduler:
         attrs = {"emitted": 0, "finished": 0}
         with _spans.span("serve/emit", attrs=attrs):
             self._emit(out, attrs)
+        if self._plain and ahead is None:
+            with _spans.span("decode/plan"):
+                plan = self._plan_next({})
+                if plan is not None:
+                    eng.dispatch_ahead(*plan)
         return True
+
+    # ------------------------------------------------------------------
+    # early dispatch: the engine's question, asked inside a tick
+    # ------------------------------------------------------------------
+    def _count(self, outcome: str) -> None:
+        self.early_dispatch[outcome] = self.early_dispatch.get(outcome, 0) + 1
+        smetrics.m_early_dispatch.labels(outcome).inc()
+
+    def early_dispatch_share(self) -> Optional[float]:
+        """Share of decode ticks whose successor was dispatched ahead."""
+        ticks = sum(self.early_dispatch.values())
+        return self.early_dispatch.get("ahead", 0) / ticks if ticks else None
+
+    def _plan_next(self, sampled: Dict[int, int]):
+        """The engine's hook (``DecodeEngine.next_tick``): the tokens a
+        tick sampled are on the host, its rows committed, nothing else of
+        it done. Answer with the next tick, ``({slot: input token}, {slot:
+        sampling})``, for the engine to dispatch now, or None to leave it
+        to the next step. Asked with no tokens after a step's own tick
+        has been emitted: every rider then feeds its ``_next_token``."""
+        outcome, plan = self._next_tick(sampled)
+        self._count(outcome)
+        return plan
+
+    def _next_tick(self, sampled: Dict[int, int]):
+        eng = self.engine
+        if (self._draining or self._settling or self._refusing is not None
+                or eng.poisoned is not None):
+            return "held_idle", None
+        now = time.monotonic()
+        feed: Dict[int, int] = {}
+        stops = False
+        for slot, req in self._active.items():
+            tok = sampled.get(slot)
+            if tok is None:
+                # prefilled while the tick was in flight: its first token
+                # is the one to feed
+                tok = self._next_token[slot]
+            elif (self._should_finish(req, tok, len(req.tokens) + 1)
+                  or self._out_of_room(slot)):
+                stops = True          # _emit evicts it, after the dispatch
+                continue
+            if req.deadline <= now:
+                stops = True          # the next step expires it
+                continue
+            feed[slot] = tok
+        if not feed:
+            return "held_idle", None
+        # a request the next step could admit must not find a tick queued
+        # in front of its prefill: hold when a slot is free, or is freed by
+        # this tick's stops, and the pool takes a waiting prompt
+        with self._lock:
+            if self._pending_handoffs or self._queue:
+                if (stops or (eng.cache.free_slot_count() > 0 and (
+                        self._pending_handoffs
+                        or self._first_admissible() is not None))):
+                    return "held_admission", None
+        # the next rows' pages; a dry pool is the next step's to settle
+        # (_ensure_step_capacity preempts, or fails a request)
+        for slot in feed:
+            if not eng.ensure_decode_capacity(slot):
+                return "held_capacity", None
+        return "ahead", (feed, {slot: self._active[slot].sampling
+                                for slot in feed})
 
     def _emit(self, out, counts) -> None:
         """Hand a tick's tokens to their requests; finish and evict."""
@@ -745,18 +881,27 @@ class Scheduler:
                     self._evict(slot, DONE)
                     finished = True
                     break
-            if not finished and self.engine.cache.headroom(slot) < getattr(
-                    self.engine, "min_headroom", 1):
+            if not finished and self._out_of_room(slot):
                 self._evict(slot, DONE, "max_seq reached",
                             reason="max_seq")
                 finished = True
             counts["finished"] += finished
 
-    def _should_finish(self, req: Request, last_token: int) -> bool:
+    def _should_finish(self, req: Request, last_token: int,
+                       n_tokens: Optional[int] = None) -> bool:
+        """Does ``last_token`` end the request (``n_tokens``: how many it
+        has with that one, where it is not yet in ``req.tokens``)?"""
         eos = self.engine.ecfg.eos_id
         if eos is not None and last_token == eos:
             return True
-        return len(req.tokens) >= req.max_new_tokens
+        if n_tokens is None:
+            n_tokens = len(req.tokens)
+        return n_tokens >= req.max_new_tokens
+
+    def _out_of_room(self, slot: int) -> bool:
+        """The slot is at (or too near) max_seq for one more step."""
+        return self.engine.cache.headroom(slot) < getattr(
+            self.engine, "min_headroom", 1)
 
     _EVICT_REASONS = {DONE: "done", EXPIRED: "deadline", FAILED: "failed"}
 

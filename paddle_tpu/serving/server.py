@@ -154,6 +154,12 @@ class EngineLoop:
                 self._wake.clear()
         if idle is not None:
             idle.__exit__(None, None, None)
+        try:
+            # a tick dispatched ahead is collected before the thread ends
+            self.scheduler.settle()
+        except Exception as e:
+            self.faults += 1
+            self.last_fault = f"{type(e).__name__}: {e}"
 
     def _check_poisoned(self) -> bool:
         """Fail-fast on a poisoned engine: abort + refuse, fire
@@ -621,6 +627,11 @@ class FrontDoor:
             out["buckets"] = list(self.scheduler.engine.buckets)
             out["weight_dtype"] = self.scheduler.engine.ecfg.weight_dtype
             out["kv_path"] = getattr(self.scheduler.engine, "kv_path", None)
+            # decode ticks by whether their successor was dispatched ahead
+            # of the step that collects it, or held and why; the share
+            out["early_dispatch"] = dict(
+                self.scheduler.early_dispatch,
+                ahead_share=self.scheduler.early_dispatch_share())
             out["held_shapes"] = {
                 k: list(v) for k, v in getattr(
                     self.scheduler.engine, "held_shapes", {}).items()}

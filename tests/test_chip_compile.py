@@ -381,35 +381,60 @@ def _jamba_cut_engine():
 
 
 _TICKS = {}
+_CELL_ENGINES = {}
+_LOWERED = {}
+
+
+def _cell_engine(cell):
+    """The GPT serving cell's engine at depth 2, or the two-layer cut of
+    the hybrid cell's: built once a process."""
+    if cell not in _CELL_ENGINES:
+        _CELL_ENGINES[cell] = (
+            _jamba_cut_engine() if cell == "jamba_cut" else
+            _engine(CELL_L, max_seq=CELL_S, max_batch=CELL_B,
+                    num_pages=CELL_PAGES))
+    return _CELL_ENGINES[cell]
+
+
+def _lowered(cell, program, sharding):
+    """``decode`` or ``prefill_b<rung>`` of a cell (``gpt_cell``,
+    ``jamba_cut``, ``kimi``) lowered for the described chip, once a
+    process. Called from inside a test (the autouse fixture has to be in
+    force), never from a fixture of wider scope."""
+    key = (cell, program)
+    if key not in _LOWERED:
+        if cell == "kimi":
+            _LOWERED[key] = _lower_kimi_cut(program, sharding)
+        else:
+            eng = _cell_engine(cell)
+            _LOWERED[key] = _lower_donated(
+                *(eng._decode_program() if program == "decode" else
+                  eng._prefill_program(int(program.split("_b")[1]))),
+                sharding)
+    return _LOWERED[key]
+
+
+def _compiled(cell, program, sharding):
+    """A cell's ``decode`` tick or ``prefill_b<rung>`` compiled for the
+    described chip: a minute or more each, so compiled once a process
+    for the tests that read it."""
+    key = (cell, program)
+    if key not in _TICKS:
+        _TICKS[key] = _lowered(cell, program, sharding).compile()
+    return _TICKS[key]
 
 
 def _compiled_tick(cell, sharding):
-    """(engine, its decode tick compiled for the described chip) of the
-    GPT serving cell at depth 2 or of the two-layer cut of the hybrid
-    cell: a minute or more each, so compiled once a process for the
-    tests that read it. Called from inside a test (the autouse fixture
-    has to be in force), never from a fixture of wider scope."""
-    if cell not in _TICKS:
-        eng = (_jamba_cut_engine() if cell == "jamba_cut" else
-               _engine(CELL_L, max_seq=CELL_S, max_batch=CELL_B,
-                       num_pages=CELL_PAGES))
-        _TICKS[cell] = eng, _lower_donated(
-            *eng._decode_program(), sharding).compile()
-    return _TICKS[cell]
+    """(engine, its compiled decode tick) of the GPT serving cell at
+    depth 2 or of the two-layer cut of the hybrid cell."""
+    return _cell_engine(cell), _compiled(cell, "decode", sharding)
 
 
 def _compiled_gpt_program(program, sharding):
-    """(the GPT cell's engine, its ``decode`` tick or ``prefill_b<rung>``
-    program compiled for the described chip), each compiled once a
-    process as the ticks are."""
-    eng, tick = _compiled_tick("gpt_cell", sharding)
-    if program == "decode":
-        return eng, tick
-    if program not in _TICKS:
-        _TICKS[program] = _lower_donated(
-            *eng._prefill_program(int(program.split("_b")[1])),
-            sharding).compile()
-    return eng, _TICKS[program]
+    """(the GPT cell's engine, its compiled ``decode`` tick or
+    ``prefill_b<rung>`` program)."""
+    return (_cell_engine("gpt_cell"),
+            _compiled("gpt_cell", program, sharding))
 
 
 def test_jamba_decode_tick_two_layer_cut(one_chip):
@@ -624,9 +649,22 @@ KIMI_YARN = {"type": "yarn", "factor": 64.0, "beta_fast": 32.0,
              "original_max_position_embeddings": 4096}
 
 
+def _kimi_cut_config():
+    from paddle_tpu.models import kimi_k2 as KK
+
+    return KK.KimiK2Config(vocab_size=20480, num_hidden_layers=3,
+                           experts_held=12, rope_scaling=KIMI_YARN)
+
+
 def _kimi_cut_program(program, sharding):
     """(config, the cell's decode tick or a prefill rung compiled for the
-    described chip) of the expert-parallel cell at its published widths,
+    described chip)."""
+    return _kimi_cut_config(), _compiled("kimi", program, sharding)
+
+
+def _lower_kimi_cut(program, sharding):
+    """The decode tick or a prefill rung, lowered for the described chip,
+    of the expert-parallel cell at its published widths,
     128 slots of 3072 tokens, 12 held experts of 384, an eighth of the
     vocabulary, cut to layer 0 (dense) and two expert layers (so that the
     expert layers' loop is real). Compiled from SHAPES alone: the engine's
@@ -637,43 +675,38 @@ def _kimi_cut_program(program, sharding):
     from paddle_tpu.serving import engine as E
 
     B, S = 128, 3072
-    cfg = KK.KimiK2Config(vocab_size=20480, num_hidden_layers=3,
-                          experts_held=12, rope_scaling=KIMI_YARN)
-    key = ("kimi", program)
-    if key not in _TICKS:
-        eng = object.__new__(E.DecodeEngine)
-        eng.model, eng.cfg = KK.KimiK2Serving(cfg), cfg
-        eng.ecfg = serving.EngineConfig(
-            max_batch=B, max_seq=S, page_size=PAGE, weight_dtype="bf16",
-            prefix_cache=False)
-        eng.kv_path = "pallas_paged"
-        assert eng.model.kernel_takes_pages(PAGE, BF16)
+    cfg = _kimi_cut_config()
+    eng = object.__new__(E.DecodeEngine)
+    eng.model, eng.cfg = KK.KimiK2Serving(cfg), cfg
+    eng.ecfg = serving.EngineConfig(
+        max_batch=B, max_seq=S, page_size=PAGE, weight_dtype="bf16",
+        prefix_cache=False)
+    eng.kv_path = "pallas_paged"
+    assert eng.model.kernel_takes_pages(PAGE, BF16)
 
-        def arg(shape, dtype):
-            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-        stored = jax.tree_util.tree_map(
-            lambda s: jax.ShapeDtypeStruct(s, F32), KK.leaf_shapes(cfg),
-            is_leaf=lambda s: isinstance(s, tuple))
-        held = jax.tree_util.tree_map(
-            lambda a: arg(a.shape, a.dtype),
-            jax.eval_shape(lambda p: KK.hold(p, cfg, "bf16"), stored))
-        pool = (arg((3, B * S // PAGE + 1, PAGE, cfg.cache_width), BF16),)
-        i32 = jnp.int32
-        if program == "decode":
-            fn, rest = eng._decode_fn_paged, (
-                arg((B,), i32), arg((B,), i32), arg((B, S // PAGE), i32),
-                arg((B,), i32), arg((B,), F32), arg((B,), i32),
-                arg((B,), F32), arg((B,), i32))
-        else:
-            T = int(program.split("_b")[1])
-            fn, rest = eng._prefill_fn_paged, (
-                arg((1, T), i32), arg((), i32), arg((), i32),
-                arg((S // PAGE,), i32), arg((), i32), arg((), F32),
-                arg((), i32), arg((), F32), arg((), i32))
-        _TICKS[key] = jax.jit(fn, donate_argnums=(1,)).lower(
-            held, pool, *rest).compile()
-    return cfg, _TICKS[key]
+    stored = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s, F32), KK.leaf_shapes(cfg),
+        is_leaf=lambda s: isinstance(s, tuple))
+    held = jax.tree_util.tree_map(
+        lambda a: arg(a.shape, a.dtype),
+        jax.eval_shape(lambda p: KK.hold(p, cfg, "bf16"), stored))
+    pool = (arg((3, B * S // PAGE + 1, PAGE, cfg.cache_width), BF16),)
+    i32 = jnp.int32
+    if program == "decode":
+        fn, rest = eng._decode_fn_paged, (
+            arg((B,), i32), arg((B,), i32), arg((B, S // PAGE), i32),
+            arg((B,), i32), arg((B,), F32), arg((B,), i32),
+            arg((B,), F32), arg((B,), i32))
+    else:
+        T = int(program.split("_b")[1])
+        fn, rest = eng._prefill_fn_paged, (
+            arg((1, T), i32), arg((), i32), arg((), i32),
+            arg((S // PAGE,), i32), arg((), i32), arg((), F32),
+            arg((), i32), arg((), F32), arg((), i32))
+    return jax.jit(fn, donate_argnums=(1,)).lower(held, pool, *rest)
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_b512"])
@@ -709,3 +742,74 @@ def test_kimi_programs_copy_no_expert_leaf(one_chip, program):
     if program == "decode":
         assert not moves, "\n".join(moves)
         assert mem.temp_size_in_bytes < layer_pool // 10
+
+
+# ---------------------------------------------------------------------------
+# the serving cells' programs, as text: a change to the host's side of the
+# tick (PR 34: early dispatch) must leave every one of them as it was
+# ---------------------------------------------------------------------------
+
+# sha256 of ``lowered.as_text()`` (StableHLO, no source locations) of each
+# cell's decode tick and of every prefill rung, lowered for the described
+# chip from the shapes above, the Mosaic kernels' bodies masked (serialized
+# MLIR that carries the checkout's path in its locations; the kernels have
+# tests of their own above). Read at PR 33's tree and the same at PR 34's.
+# A PR that changes a program on purpose reads the new digests off the
+# failure's message, puts them here and says in PERF.md which program
+# changed and why; one that meant to leave the device's work alone has not.
+PROGRAM_TEXT_SHA256 = {
+    "gpt_cell/decode":
+        "01f7f55ad04479f7c6dc53e6f6569ecbea13a40a6d1d8aff95166bc5aef33658",
+    "gpt_cell/prefill_b16":
+        "593234aa0a271fb142f123b13aa8bb3d3c74d6ee3d0e720e4493a687bdc667be",
+    "gpt_cell/prefill_b32":
+        "ec34154a3ca0b361a0afbbdfbdeeb0bd6d0237a1dc1a29a6dbe24d9a5156fc3a",
+    "gpt_cell/prefill_b64":
+        "7323b8529dfb8829747e1e06cc3f7aa6d817170b52481bef311b987ef742ac85",
+    "gpt_cell/prefill_b128":
+        "0c5d38f6633b8da14e7064e749bc57ace44d08141473b87d335885c37d001409",
+    "gpt_cell/prefill_b256":
+        "ffb1647ca4a672516a888ebd7b9f6d2f1b275db63b624f01c1aa07f1515aa6c1",
+    "gpt_cell/prefill_b512":
+        "403b2879711e2671843c2cfa331ad29b13b785bc685f2494681b8a6180da9c3e",
+    "gpt_cell/prefill_b1024":
+        "c64b2b2134f3b8f279f9e77e52032e2a0de2b0bac0e39badf4ec26f6b962463f",
+    "gpt_cell/prefill_b2048":
+        "dc39f1831268573051b969e9d6b04001aca9dffb413912340052eaeebaa6e7ff",
+    "jamba_cut/decode":
+        "a7bd90bf1b15a58fb8bcd784ca92a38d038cd1fbb271e50cc506bfce64f5c874",
+    "jamba_cut/prefill_b256":
+        "765bfeb613a9f29e4c21536b79246b660e47c1ece682f1b4a0ee6ef323ddeec0",
+    "jamba_cut/prefill_b2048":
+        "6c4346064d7e51b44def091e3c8ef4e160b304ee37389623122cfd2b0d719b6a",
+    "kimi/decode":
+        "4f8e56263e7f8154dd1f6d6eccac2cbb120e988efe66a9a30b3253fa485ba6bf",
+    "kimi/prefill_b512":
+        "a83f6981bf07fd7f8b7fff087feb600adbd4c04fd18b55f9419b06080794d73e",
+}
+
+
+def _program_names(cell):
+    if cell == "gpt_cell":
+        rungs = (16, 32, 64, 128, 256, 512, 1024, 2048)
+    elif cell == "jamba_cut":
+        rungs = (256, 2048)
+    else:
+        rungs = (512,)
+    return ["decode"] + [f"prefill_b{r}" for r in rungs]
+
+
+@pytest.mark.parametrize("cell", ["gpt_cell", "jamba_cut", "kimi"])
+def test_serving_programs_lower_to_the_text_they_had(one_chip, cell):
+    import hashlib
+
+    if cell != "kimi":
+        assert _program_names(cell)[1:] == [
+            f"prefill_b{b}" for b in _cell_engine(cell).buckets]
+    got = {f"{cell}/{program}": hashlib.sha256(re.sub(
+        r'\\22body\\22: \\22[A-Za-z0-9+/=]*\\22', "body",
+        _lowered(cell, program, one_chip).as_text()).encode()).hexdigest()
+        for program in _program_names(cell)}
+    want = {k: v for k, v in PROGRAM_TEXT_SHA256.items()
+            if k.startswith(cell + "/")}
+    assert got == want, f"the programs' digests now:\n{got!r}"

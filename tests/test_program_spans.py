@@ -171,8 +171,14 @@ def test_a_sink_hears_of_an_open_span_before_its_children_end():
 # ---------------------------------------------------------------------------
 
 STEP_CHILDREN = {"serve/admit", "serve/decode_tick", "serve/emit"}
+# a tick the step feeds itself, then one found in flight (dispatched ahead
+# by its predecessor's plan, its feed under that): under a scheduler the
+# second plans, and as a rule dispatches, its successor between its tokens
+# and its logits; the first's successor is planned after the step's emit
 TICK_PHASES = ["decode/feed", "decode/run", "decode/fetch_logits",
                "decode/commit"]
+AHEAD_PHASES = ["decode/run", "decode/plan", "decode/fetch_logits",
+                "decode/commit"]
 PREFILL_PHASES = ["prefill/prep", "prefill/run", "prefill/fetch_logits",
                   "prefill/publish"]
 
@@ -198,12 +204,16 @@ def test_span_tree_of_a_step_on_a_paged_engine(tracer, paged_engine,
     assert [s["attrs"]["step"] for s in steps] == list(range(sched.steps))
     assert all(s["parent"] is None for s in steps)
     assert {s["name"] for s in loop} == (
-        {"serve/step"} | STEP_CHILDREN | set(TICK_PHASES))
+        {"serve/step"} | STEP_CHILDREN | set(TICK_PHASES + AHEAD_PHASES))
     for s in loop:
         if s["name"] in STEP_CHILDREN:
             assert by_id[s["parent"]]["name"] == "serve/step"
         if s["name"] in TICK_PHASES:
             assert by_id[s["parent"]]["name"] == "serve/decode_tick"
+    # one plan a tick: after the first step's emit, then inside each tick
+    plans = [by_id[s["parent"]]["name"] for s in loop
+             if s["name"] == "decode/plan"]
+    assert plans == ["serve/step"] + ["serve/decode_tick"] * 3
     # the first step admitted both and ticked once
     first = steps[0]
     assert first["attrs"] == {"step": 0, "worked": True, "prefills": 2,
@@ -219,12 +229,15 @@ def test_span_tree_of_a_step_on_a_paged_engine(tracer, paged_engine,
                                  "cached_tokens": 5 + 3,
                                  "kv_path": "xla_gather", "live_pages": 2,
                                  # no layer of this model is recurrent
-                                 "state_slots": 0, "state_bytes": 0}
-    # a tick's four phases, in order, and they account for the tick
+                                 "state_slots": 0, "state_bytes": 0,
+                                 "ahead": False}
+    assert [t["attrs"]["ahead"] for t in ticks] == [False, True, True, True]
+    # a tick's phases, in order, and they account for the tick
     shares = []
     for t in ticks:
         kids = [s for s in ss if s["parent"] == t["span"]]
-        assert [k["name"] for k in kids] == TICK_PHASES
+        assert [k["name"] for k in kids] == (
+            AHEAD_PHASES if t["attrs"]["ahead"] else TICK_PHASES)
         assert all(k["start_ns"] >= t["start_ns"] for k in kids)
         shares.append(sum(k["dur_ns"] for k in kids) / t["dur_ns"])
     assert all(x <= 1.0 for x in shares)
@@ -277,17 +290,22 @@ def test_from_a_slow_request_to_the_ticks_it_rode(tracer, paged_engine):
     roots = {s["attrs"]["request_id"]: s for s in tracer.spans()
              if s["name"] == "serve/request"
              and not s["attrs"].get("open")}
-    for req, steps in ((long, [0, 1, 2, 3, 4]), (late, [2])):
+    # late was prefilled in step 2 beside the tick step 1 had dispatched
+    # ahead for long alone, and rides from step 3 on
+    assert (late.first_step, late.last_step) == (2, 3)
+    for req, steps in ((long, [0, 1, 2, 3, 4]), (late, [3])):
         root = roots[req.id]["attrs"]
         assert (root["first_step"], root["last_step"]) == (
-            steps[0], steps[-1]) == (req.first_step, req.last_step)
+            req.first_step, req.last_step)
+        assert (req.first_step <= steps[0]
+                and steps[-1] == req.last_step)
         rode = [t for t in tracer.attr_range(
             "serve/decode_tick", "step", root["first_step"],
             root["last_step"]) if req.id in t["attrs"]["riders"]]
         assert [t["attrs"]["step"] for t in rode] == steps
         assert len(rode) == len(req.tokens) - 1
     # the tick both rode says so
-    shared = tracer.attr_range("serve/decode_tick", "step", 2, 2)[0]
+    shared = tracer.attr_range("serve/decode_tick", "step", 3, 3)[0]
     assert shared["attrs"]["riders"] == [long.id, late.id]
     assert shared["attrs"]["batch"] == 2
     # a request that never ran has no steps to show
