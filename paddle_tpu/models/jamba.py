@@ -333,6 +333,19 @@ def _logits(params, h, cfg):
 # what the serving engine asks of a model (serving/model.py)
 # ---------------------------------------------------------------------------
 
+def hold_leaves(params, weight_dtype: str, f32_leaves):
+    """A parameter tree as an engine holds it: the leaves named in
+    ``f32_leaves`` float32, every other leaf in ``weight_dtype``
+    (``"f32"`` or ``"bf16"``), each in its stored shape."""
+    held = {"f32": jnp.float32, "bf16": jnp.bfloat16}[weight_dtype]
+
+    def one(path, x):
+        keep = path[-1].key in f32_leaves
+        return jnp.asarray(x, jnp.float32 if keep else held)
+
+    return jax.tree_util.tree_map_with_path(one, params)
+
+
 class JambaServing:
     """The model description ``DecodeEngine`` builds its paged prefill and
     decode programs from. The caches are ``(k pool, v pool, conv, ssm)``:
@@ -351,20 +364,14 @@ class JambaServing:
             "rows": ((cfg.num_key_value_heads, cfg.head_dim),) * 2}
         self.state_geometry = {
             "layers": cfg.num_mamba_layers,
-            "conv_width": (cfg.mamba_d_conv - 1) * cfg.d_inner,
-            "d_state": cfg.mamba_d_state, "d_inner": cfg.d_inner}
+            "conv": ((cfg.mamba_d_conv - 1) * cfg.d_inner,),
+            "ssm": (cfg.mamba_d_state, cfg.d_inner)}
 
     def hold(self, params, weight_dtype: str, chunk: int, sharded=False):
         """The serving storage: matrices in ``weight_dtype``, the leaves of
         ``F32_LEAVES`` float32, every leaf in its stored shape. (int8 and
         a ``sharded`` engine are refused where the engine is built.)"""
-        held = {"f32": jnp.float32, "bf16": jnp.bfloat16}[weight_dtype]
-
-        def one(path, x):
-            keep = path[-1].key in F32_LEAVES
-            return jnp.asarray(x, jnp.float32 if keep else held)
-
-        return jax.tree_util.tree_map_with_path(one, params)
+        return hold_leaves(params, weight_dtype, F32_LEAVES)
 
     def embed(self, qparams, tokens, positions):
         return qparams["embed"][tokens].astype(self.cfg.dtype)

@@ -719,9 +719,12 @@ class DecodeEngine:
             timings[f"verify_w{W}"] = (time.perf_counter() - t0) * 1e3
         # transfer-path gather/scatter (KV handoff + prefix store): one
         # compiled shape each — warmed here so a disagg handoff's first
-        # export/adopt never pays a mid-request compile (~100ms)
+        # export/adopt never pays a mid-request compile (~100ms). An
+        # engine that refuses the transfer (_refuse_kv_transfer) warms
+        # nothing: the scatter is not donated and would hold a second
+        # copy of both pools beside the first
         t0 = time.perf_counter()
-        if self.cache.keys_and_values:
+        if self._beside_plain_pages() is None:
             k0, v0 = self.cache.read_pages([0])
             self.cache.write_pages([0], k0, v0)
             timings["kv_transfer"] = (time.perf_counter() - t0) * 1e3
@@ -808,8 +811,12 @@ class DecodeEngine:
         # scan_tokens: what a recurrent model's scan has to process (the
         # prompt: such a model is never given a prefix); 0 where no layer
         # scans
+        # delta_chunks: the chunks a chunked recurrence has to process
+        # for the prompt (0 where the model has none)
+        chunks = getattr(self.model, "delta_chunks", None)
         attrs = {"prompt_len": n, "step": self.sched_step,
-                 "scan_tokens": n if self.model.recurrent else 0}
+                 "scan_tokens": n if self.model.recurrent else 0,
+                 "delta_chunks": chunks(n) if chunks else 0}
         with _spans.span("serve/prefill", attrs=attrs):
             with _spans.span("prefill/prep"):
                 prefix_len, prefix_pages = 0, ()
@@ -948,8 +955,10 @@ class DecodeEngine:
         smetrics.m_moe_dropped.inc(dropped)
 
     def state_bytes(self, slots) -> int:
-        """Bytes of recurrent state a decode tick over ``slots`` reads and
-        writes back (0 for a model without recurrent layers)."""
+        """Bytes the state rows of ``slots`` hold, every recurrent layer's
+        conv row and state together: what a decode tick over those riders
+        has to read and to write back, whatever the program moves (0 for a
+        model without recurrent layers)."""
         return self.cache.state_bytes_per_slot * len(slots)
 
     def _decode_feed(self, slot_tokens: Dict[int, int]):

@@ -13,7 +13,13 @@ description is any object with:
   keys and values, ``((width,),)`` for one pool of latent rows (then
   ``latent`` is true);
 - ``recurrent`` and ``state_geometry``: whether slots carry recurrent
-  state beside their pages, and its sizes (``PagedKVCache``'s ``state``);
+  state beside their pages, and the shapes of a slot's two state rows a
+  recurrent layer as the model states them, ``{"layers": n, "conv":
+  shape, "ssm": shape}`` (``PagedKVCache``'s ``state``: the conv's last
+  inputs in the cache's dtype, the recurrence's state float32, be it a
+  scan's ``[d_state, d_inner]`` or a delta rule's matrix a head);
+  optionally ``delta_chunks(tokens)``: the chunks a prompt costs a
+  chunked recurrence (``serve/prefill`` records it);
 - ``paged_kernel``: whether ``decode_layers`` can read the pools through
   a page-table kernel (``kv_path`` ``pallas_paged``), or always gathers;
   and where it can, ``kernel_takes_pages(page_size, cache_dtype)``:
@@ -35,9 +41,10 @@ description is any object with:
 - ``forward(params, tokens [1, T])``: the plain full forward pass (the
   engine's parity surface).
 
-Three descriptions exist: :class:`GPTServing` here (``models/gpt.py``'s
-block), ``models/jamba.py:JambaServing`` and
-``models/kimi_k2.py:KimiK2Serving``. The engine's verify program
+Four descriptions exist: :class:`GPTServing` here (``models/gpt.py``'s
+block), ``models/jamba.py:JambaServing``,
+``models/kimi_k2.py:KimiK2Serving`` and
+``models/olmo_hybrid.py:OlmoHybridServing``. The engine's verify program
 is still written for the GPT block (ROADMAP D2) and uses the block
 helpers below directly.
 """
@@ -275,6 +282,10 @@ def describe(cfg):
 
     if isinstance(cfg, kimi_mod.KimiK2Config):
         return kimi_mod.KimiK2Serving(cfg)
+    from ..models import olmo_hybrid as olmo_mod
+
+    if isinstance(cfg, olmo_mod.OlmoHybridConfig):
+        return olmo_mod.OlmoHybridServing(cfg)
     raise TypeError(
         f"DecodeEngine: no model description for {type(cfg).__name__}; "
         "pass an object with the surface serving/model.py lists")

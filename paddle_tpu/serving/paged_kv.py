@@ -36,12 +36,16 @@ LRU; pool pressure reclaims cache-held pages before any allocation
 fails.
 
 **Recurrent state** (hybrid models, docs/serving.md "Hybrid models"): a
-model whose layers are mostly state-space mixers gives the manager a
-``state`` geometry, and a second kind of cache lives beside the pages: a
-fixed-size row per slot and recurrent layer, ``conv [Lm, slots, (d_conv -
-1) * d_inner]`` in the cache's dtype and ``ssm [Lm, slots, d_state,
-d_inner]`` float32, allocated once, carried through the compiled programs
-in place like the pools. A slot's row is born with the slot (the prefill
+model whose layers are mostly recurrent mixers gives the manager a
+``state`` geometry, the shapes of a slot's two state rows as the model
+states them: ``{"layers": Lr, "conv": shape, "ssm": shape}``. A second
+kind of cache then lives beside the pages: a fixed-size row per slot and
+recurrent layer, ``conv [Lr, slots, *conv]`` in the cache's dtype (the
+last inputs of the layer's causal conv) and ``ssm [Lr, slots, *ssm]``
+float32 (the recurrence's state: Mamba's ``[d_state, d_inner]`` scan
+state, or a delta rule's matrix a head), allocated once, carried through
+the compiled programs in place like the pools. A slot's row is born with
+the slot (the prefill
 program writes it from an empty history, never from what the row held), is
 advanced by the ticks the slot rides, and is dead at ``free``; the pool is
 then built for the attention layers alone. The one object answers
@@ -130,7 +134,7 @@ class PagedKVCache:
                  num_heads: int = 0, head_dim: int = 0,
                  dtype: Any = jnp.float32,
                  page_size: int = 8, num_pages: int = 0,
-                 state: Optional[Dict[str, int]] = None,
+                 state: Optional[Dict[str, Any]] = None,
                  rows: Optional[Sequence[Tuple[int, ...]]] = None):
         if max_slots < 1 or max_seq < 1:
             raise ValueError("max_slots and max_seq must be >= 1")
@@ -160,18 +164,18 @@ class PagedKVCache:
         self.pools = [jnp.zeros((self.num_layers, self.num_pages,
                                  self.page_size) + r, dtype)
                       for r in self.rows]
-        # per-slot recurrent state: {"layers", "conv_width", "d_state",
-        # "d_inner"} from the model, None for an attention-only model
+        # per-slot recurrent state: {"layers", "conv": shape, "ssm":
+        # shape} from the model (a slot's two state rows as the model
+        # states them), None for an attention-only model
         self.conv = self.ssm = None
         self.state_bytes_per_slot = 0
         self.state_resets = 0
         if state is not None:
-            lm = int(state["layers"])
+            lead = (int(state["layers"]), self.max_slots)
             self.conv = jnp.zeros(
-                (lm, self.max_slots, int(state["conv_width"])), dtype)
+                lead + tuple(int(n) for n in state["conv"]), dtype)
             self.ssm = jnp.zeros(
-                (lm, self.max_slots, int(state["d_state"]),
-                 int(state["d_inner"])), jnp.float32)
+                lead + tuple(int(n) for n in state["ssm"]), jnp.float32)
             self.state_bytes_per_slot = (
                 self.conv.nbytes + self.ssm.nbytes) // self.max_slots
         self._tables = np.zeros((self.max_slots, self.max_pages_per_slot),

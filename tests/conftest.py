@@ -45,14 +45,19 @@ def pytest_configure(config):
 # Run last, they leave the xdist schedule of every other file as it was
 # before they existed: test_dgc_halfasync's two async trainer processes stop
 # converging when such a neighbour starves them (their margin is thin).
-_RUN_LAST = ("test_chip_compile.py", "test_chip_smoke.py")
+# (PR 35's two files of the delta-rule family likewise, behind the longest.)
+_RUN_LAST = ("test_chip_compile.py", "test_chip_smoke.py",
+             "test_olmo_hybrid.py", "test_benchmark_olmo_hybrid.py")
 
 
 def pytest_collection_modifyitems(config, items):
     if not _TPU_LANE:
         # stable and deterministic: every xdist worker collects this order
-        items.sort(key=lambda it: os.path.basename(str(it.fspath))
-                   in _RUN_LAST)
+        def place(it):
+            name = os.path.basename(str(it.fspath))
+            return _RUN_LAST.index(name) + 1 if name in _RUN_LAST else 0
+
+        items.sort(key=place)
         return
     # the TPU lane runs on the real (single-chip) backend: everything
     # outside tests/tpu assumes the 8-virtual-device CPU mesh — skip it

@@ -813,3 +813,166 @@ def test_serving_programs_lower_to_the_text_they_had(one_chip, cell):
     want = {k: v for k, v in PROGRAM_TEXT_SHA256.items()
             if k.startswith(cell + "/")}
     assert got == want, f"the programs' digests now:\n{got!r}"
+
+
+# ---------------------------------------------------------------------------
+# serve_olmo_hybrid_7b_l16_closed32 (benchmark/configs/
+# olmo-hybrid-7b-l16.json): 48 slots of 4608 tokens over 3585 pages, 30
+# heads of 128 in the full layers, 30 heads of 96 x 192 in the linear ones
+# ---------------------------------------------------------------------------
+
+GDN_H, GDN_DK, GDN_DV = 30, 96, 192
+OLMO_B, OLMO_S, OLMO_PAGES = 48, 4608, 3585
+
+
+@pytest.mark.parametrize("T", [256, 4096])
+def test_gated_delta_chunk_fwd(one_chip, T):
+    """The chunkwise delta rule at the cell's head sizes, its lowest and
+    its highest rung: blocks of 64 tokens of one head, the triangular
+    solve's static lane slices, the state in VMEM."""
+    from paddle_tpu.ops import gated_delta as GD
+
+    _compile(GD.gated_delta_chunked, one_chip,
+             ((T, GDN_H, GDN_DK), BF16), ((T, GDN_H, GDN_DK), BF16),
+             ((T, GDN_H, GDN_DV), BF16), ((T, GDN_H), F32),
+             ((T, GDN_H), F32), ((), jnp.int32))
+
+
+def test_gated_delta_update_rows(one_chip):
+    """The one-token delta rule over all 12 linear layers' state rows of
+    48 slots: the array stays in HBM and is aliased whole (no copy of it
+    is made), a rider's row of 15 x 96 x 384 float32 is what a DMA
+    moves."""
+    from paddle_tpu.ops import gated_delta as GD
+
+    assert GD.state_fold(GDN_H, GDN_DV) == 2
+    state = (12, OLMO_B, GDN_H // 2, GDN_DK, 2 * GDN_DV)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        (state, F32), ((OLMO_B, GDN_H, GDN_DK), BF16),
+        ((OLMO_B, GDN_H, GDN_DK), BF16), ((OLMO_B, GDN_H, GDN_DV), BF16),
+        ((OLMO_B, GDN_H), F32), ((OLMO_B, GDN_H), F32),
+        ((OLMO_B,), jnp.int32), ((), jnp.int32))]
+    lowered = jax.jit(
+        lambda S, q, k, v, a, b, slots, layer: GD.gated_delta_update(
+            S, q, k, v, a, b, slots, layer=layer),
+        donate_argnums=(0,)).lower(*args)
+    assert "gated_delta_update_rows" in lowered.as_text()
+    mem = lowered.compile().memory_analysis()
+    nbytes = int(np.prod(state)) * 4
+    assert mem.alias_size_in_bytes >= nbytes
+    assert mem.temp_size_in_bytes < nbytes // (12 * OLMO_B) * 4
+
+
+def test_thirty_heads_lie_in_pages_of_thirty_two_rows(one_chip):
+    """30 key/value heads are no page shape the paged decode kernel takes
+    (Mosaic: a copy's slice along the head axis "must be aligned to tiling
+    (8), but is 30"), so the model's pools hold 32 head rows a token, two
+    of them zeros, and the tick reads its pages through the kernel. In
+    HBM the two rows cost nothing: the TPU tiles a ``[.., 30, 128]``
+    bfloat16 array in 32 rows as it is. Whoever teaches
+    ``paged_decode_tiles`` another head count learns of this model
+    here."""
+    from paddle_tpu.models import olmo_hybrid as O
+
+    cfg = O.OlmoHybridConfig()
+    assert cfg.num_key_value_heads == 30 and cfg.kv_pool_heads == 32
+    assert not PK.paged_decode_tiles(30, HD)
+    assert O.OlmoHybridServing(cfg).kernel_takes_pages(PAGE, BF16)
+
+    def shapes(heads):
+        pool = ((4, OLMO_PAGES, PAGE, heads, HD), BF16)
+        return (((OLMO_B, heads, HD), BF16), pool, pool, ((), jnp.int32),
+                ((OLMO_B, OLMO_S // PAGE), jnp.int32),
+                ((OLMO_B,), jnp.int32))
+
+    _compile(PK.paged_decode_attention, one_chip, *shapes(32))
+    with pytest.raises(Exception, match=r"aligned to tiling \(8\), but "
+                       "is 30"):
+        _compile(PK.paged_decode_attention, one_chip, *shapes(30))
+
+
+def _lower_olmo_cut(program, sharding):
+    """The decode tick or a prefill rung, lowered for the described chip,
+    of the delta-rule cell at its published widths, 48 slots of 4608
+    tokens over 3585 pages, cut to one period of its pattern (three linear
+    layers, so that their loop is real, and one full layer). Compiled from
+    SHAPES alone: the engine's pure functions on an engine that was never
+    built (its weights would be 2.8 GB of host memory that no compile
+    reads)."""
+    from paddle_tpu import serving
+    from paddle_tpu.models import olmo_hybrid as O
+    from paddle_tpu.serving import engine as E
+
+    B, S = OLMO_B, OLMO_S
+    cfg = O.OlmoHybridConfig(num_hidden_layers=4, layer_types=O._PERIOD)
+    eng = object.__new__(E.DecodeEngine)
+    eng.model, eng.cfg = O.OlmoHybridServing(cfg), cfg
+    eng.ecfg = serving.EngineConfig(
+        max_batch=B, max_seq=S, page_size=PAGE, weight_dtype="bf16",
+        prefix_cache=False)
+    eng.kv_path = "pallas_paged"
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    stored = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s, F32), O.leaf_shapes(cfg),
+        is_leaf=lambda s: isinstance(s, tuple))
+    held = jax.tree_util.tree_map(
+        lambda a: arg(a.shape, a.dtype),
+        jax.eval_shape(lambda p: eng.model.hold(p, "bf16", 256), stored))
+    geometry = eng.model.state_geometry
+    pool = arg((1, OLMO_PAGES, PAGE, cfg.kv_pool_heads, HD), BF16)
+    caches = (pool, pool, arg((3, B) + geometry["conv"], BF16),
+              arg((3, B) + geometry["ssm"], F32))
+    i32 = jnp.int32
+    if program == "decode":
+        fn, rest = eng._decode_fn_paged, (
+            arg((B,), i32), arg((B,), i32), arg((B, S // PAGE), i32),
+            arg((B,), i32), arg((B,), F32), arg((B,), i32),
+            arg((B,), F32), arg((B,), i32))
+    else:
+        T = int(program.split("_b")[1])
+        fn, rest = eng._prefill_fn_paged, (
+            arg((1, T), i32), arg((), i32), arg((), i32),
+            arg((S // PAGE,), i32), arg((), i32), arg((), F32),
+            arg((), i32), arg((), F32), arg((), i32))
+    return (jax.jit(fn, donate_argnums=(1,)).lower(held, caches, *rest),
+            caches)
+
+
+def test_olmo_hybrid_tick_moves_the_riders_rows_and_relays_no_weight(
+        one_chip):
+    """The tick of one period at the cell's widths: pages read through the
+    page-table kernel, the state rows advanced in place by the update
+    kernel (every cache array aliased whole, the temporaries under one
+    slot's state of a layer times the slots: no copy of a layer's rows),
+    and no weight re-laid: applied on heads, the output gate had XLA copy
+    all layers' ``w_g`` on every tick (0.53 GB at 12 layers, described
+    compile, PR 35)."""
+    lowered, caches = _lower_olmo_cut("decode", one_chip)
+    text = lowered.as_text()
+    assert "paged_decode_attention" in text
+    assert "gated_delta_update_rows" in text
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    nbytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in caches)
+    assert mem.alias_size_in_bytes >= nbytes
+    layer_rows = OLMO_B * GDN_H * GDN_DK * GDN_DV * 4
+    assert mem.temp_size_in_bytes < layer_rows
+    moves = _weight_sized_relayouts(compiled.as_text(),
+                                    at_least=3 * 3840 * 3840)
+    assert not moves, "\n".join(moves)
+
+
+def test_olmo_hybrid_rung_goes_through_both_kernels(one_chip):
+    """A rung of 1408 tokens (the cycle's median prompt): the chunked
+    delta rule and the flash kernel (no ``[30, T, T]`` scores), and a
+    slot's state written into the carried arrays in place."""
+    lowered, caches = _lower_olmo_cut("prefill_b1408", one_chip)
+    text = lowered.as_text()
+    assert "gated_delta_chunk_fwd" in text and "flash_fwd" in text
+    mem = lowered.compile().memory_analysis()
+    nbytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in caches)
+    assert mem.alias_size_in_bytes >= nbytes
+    assert mem.temp_size_in_bytes < 30 * 1408 * 1408 * 4

@@ -262,7 +262,8 @@ def test_span_tree_of_a_step_on_a_paged_engine(tracer, paged_engine,
         pre = next(s for s in fam if s["name"] == "serve/prefill")
         assert pre["parent"] == req.root_span
         assert pre["attrs"] == {"prompt_len": len(req.prompt), "step": 0,
-                                "scan_tokens": 0, "bucket": 8,
+                                "scan_tokens": 0, "delta_chunks": 0,
+                                "bucket": 8,
                                 "prefix_len": 0, "slot": req.slot}
         kids = [s for s in fam if s["parent"] == pre["span"]]
         assert [k["name"] for k in kids] == PREFILL_PHASES
