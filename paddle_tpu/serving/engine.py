@@ -93,6 +93,58 @@ def _note_sampler(program: str, temps, top_ks, top_ps) -> str:
     return path
 
 
+# ---------------------------------------------------------------------------
+# the feed: an engine call's host arguments, ONE int32 array
+# ---------------------------------------------------------------------------
+# Every host array a call hands its executable is a transfer of its own,
+# 0.13 ms inside the call whatever its bytes (PERF.md section 6, PR 34), so
+# each program takes one array behind the caches and cuts it apart
+# (docs/serving.md "The tick's anatomy"). A row is
+#
+#     [ page-table row (M) | scalars | the sampler's four | token(s) ]
+#
+# a slot's in the decode tick's ``[max_batch, M + 7]`` and the verify
+# window's ``[max_batch, M + 6 + W]`` (scalars: position, active), the
+# request's in a prefill rung's ``[M + 7 + bucket]`` (scalars: length,
+# prefix_len, slot). The sampler's four are ``samp.batch_arrays``' block,
+# the floats as their bits.
+_POSITION, _ACTIVE = 0, 1            # a slot's scalars, behind its table row
+_SLOT_SCALARS = 2
+_RUNG_SCALARS = 3
+_SAMPLING = 4
+
+
+def slot_feed_shape(B: int, M: int, W: int = 1) -> Tuple[int, int]:
+    """Shape of a decode tick's feed, or with ``W`` a verify window's, for
+    ``B`` slots whose table rows name ``M`` pages."""
+    return B, M + _SLOT_SCALARS + _SAMPLING + W
+
+
+def rung_feed_len(M: int, bucket: int) -> int:
+    """Length of the feed of a prefill rung of ``bucket`` positions."""
+    return M + _RUNG_SCALARS + _SAMPLING + bucket
+
+
+def cut_slot_feed(feed, M: int):
+    """Inside the tick's and the verify window's program: ``feed`` ->
+    (tokens [B, W], positions [B], tables [B, M], actives [B], (temps,
+    top_ks, top_ps, seeds)), each with the dtype and the bits the host
+    wrote."""
+    at = M + _SLOT_SCALARS
+    return (feed[:, at + _SAMPLING:], feed[:, M + _POSITION], feed[:, :M],
+            feed[:, M + _ACTIVE],
+            samp.feed_columns(feed[:, at:at + _SAMPLING]))
+
+
+def cut_rung_feed(feed, M: int):
+    """Inside a prefill rung's program: ``feed`` -> (tokens [1, bucket],
+    length, prefix_len, table_row [M], slot, (temp, top_k, top_p,
+    seed))."""
+    at = M + _RUNG_SCALARS
+    return (feed[None, at + _SAMPLING:], feed[M], feed[M + 1], feed[:M],
+            feed[M + 2], samp.feed_columns(feed[at:at + _SAMPLING]))
+
+
 def _held_shapes(params, qparams) -> Dict[str, Tuple[int, ...]]:
     """``{"blocks/w_qkv": (L, d, 3·nh·hd), ..}``: the leaves of the serving
     storage whose shape is not the stored leaf's (a quantized leaf's shape
@@ -451,11 +503,11 @@ class DecodeEngine:
     def _dequant(self, qparams):
         return dequantize_params(qparams)
 
-    def _prefill_fn_paged(self, qparams, caches, tokens, length,
-                          prefix_len, table_row, slot, temp, top_k, top_p,
-                          seed):
-        """Paged (prefix-cache capable) prefill: tokens [1, T] is the
-        SUFFIX after ``prefix_len`` cached tokens, ``caches`` the cache
+    def _prefill_fn_paged(self, qparams, caches, feed):
+        """Paged (prefix-cache capable) prefill. ``feed``
+        (:func:`cut_rung_feed`): tokens [1, T], the SUFFIX after
+        ``prefix_len`` cached tokens, its ``length``, the slot and its
+        table row, the request's sampling scalars; ``caches`` the cache
         manager's arrays (``PagedKVCache.arrays``: the two pools, and a
         recurrent model's two state arrays). ``embed -> layers -> final
         norm -> head``, the layers the model's own: they write the suffix
@@ -463,6 +515,8 @@ class DecodeEngine:
         into the slot's state rows). prefix_len == 0 is a plain paged
         prefill."""
         m = self.model
+        tokens, length, prefix_len, table_row, slot, sp = cut_rung_feed(
+            feed, self.table_width)
         T = tokens.shape[1]
         positions = prefix_len + jnp.arange(T)
         x = m.embed(qparams, tokens, positions[None])          # [1, T, D]
@@ -472,13 +526,12 @@ class DecodeEngine:
         h_last = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0,
                                               keepdims=False)
         logits = m.logits(qparams, h_last)
-        tok = samp.sample_token(logits, temp, top_k, top_p, seed,
-                                prefix_len + length - 1)
+        tok = samp.sample_token(logits, *sp, prefix_len + length - 1)
         return (caches, logits, tok, *report)
 
-    def _decode_fn_paged(self, qparams, caches, tokens, positions,
-                         tables, actives, temps, top_ks, top_ps, seeds):
-        """tokens/positions/actives/sampling [max_batch] -> (caches,
+    def _decode_fn_paged(self, qparams, caches, feed):
+        """``feed`` (:func:`cut_slot_feed`: a token, a position, ``actives``
+        and the sampling knobs a slot, its page-table row) -> (caches,
         logits[B, V], tokens[B]): one token per slot, the layers the
         model's own (``serving/model.py``). Per-slot page tables
         [B, max_pages] route the one-row write and the attention read
@@ -487,27 +540,32 @@ class DecodeEngine:
         lands on the scratch page) and ``actives`` 0 (a recurrent model
         leaves their state as it is)."""
         m = self.model
-        x = m.embed(qparams, tokens, positions)
+        tokens, positions, tables, actives, sp = cut_slot_feed(
+            feed, self.table_width)
+        x = m.embed(qparams, tokens[:, 0], positions)
         x, caches, *report = m.decode_layers(qparams, x, caches, _model.ctx(
             positions=positions, tables=tables, actives=actives,
             page_size=self.ecfg.page_size, kv_path=self.kv_path,
             fused=self.ecfg.fused_decode))
         logits = m.logits(qparams, x, fused=self.ecfg.fused_decode)
-        toks = samp.sample_batch(logits, temps, top_ks, top_ps, seeds,
-                                 positions)
+        toks = samp.sample_batch(logits, *sp, positions)
         # a model with experts hands its layers' report out with the
         # logits: one small int32 array, no second program
         return (caches, logits, toks, *report)
 
-    def _verify_fn_paged(self, qparams, kp, vp, tokens, starts, tables,
-                         temps, top_ks, top_ps, seeds):
+    def _verify_fn_paged(self, qparams, kp, vp, feed):
         """Paged verify window: B*W rows scatter through the page tables,
-        attention reads the gathered per-slot views."""
+        attention reads the gathered per-slot views. ``feed`` is the
+        tick's with W tokens a slot and the window's start for a
+        position (``actives`` is not read: a lane that sits out has a
+        zero table row)."""
         cfg = self.cfg
         params = self._dequant(qparams)
         dt = cfg.dtype
         ln = gpt_mod._layer_norm
         ps = self.ecfg.page_size
+        tokens, starts, tables, _actives, sp = cut_slot_feed(
+            feed, self.table_width)
         B, W = tokens.shape
         positions = starts[:, None] + jnp.arange(W)      # [B, W]
         x = _embed_rows(qparams, tokens, positions, dt)
@@ -533,8 +591,7 @@ class DecodeEngine:
         logits = jnp.einsum("bwd,dv->bwv", x,
                             params["lm_head"].astype(dt))
         logits = logits.astype(jnp.float32)
-        toks = samp.sample_window(logits, temps, top_ks, top_ps, seeds,
-                                  positions)
+        toks = samp.sample_window(logits, *sp, positions)
         return kp, vp, logits, toks
 
     # ------------------------------------------------------------------
@@ -604,26 +661,50 @@ class DecodeEngine:
             }})
         return compiled
 
-    def _samp_scalar_examples(self):
-        return (np.float32(0.0), np.int32(0), np.float32(1.0),
-                np.int32(0))
+    # -- the feed's host side (layout: the head of this file) -------------
+    @property
+    def table_width(self) -> int:
+        """Pages a slot's table row names (the cache manager's
+        ``max_pages_per_slot``), from the configuration: a program is
+        traced for an engine that holds no cache too
+        (tests/test_chip_compile.py)."""
+        return self.ecfg.max_seq // self.ecfg.page_size
 
-    def _samp_batch_examples(self):
-        B = self.ecfg.max_batch
-        return (np.zeros((B,), np.float32), np.zeros((B,), np.int32),
-                np.ones((B,), np.float32), np.zeros((B,), np.int32))
+    def _slot_feed(self, width: int = 1, params_by_slot=None):
+        """A tick's feed, or with ``width`` W a verify window's, every
+        lane dead and the sampler's block written (greedy where
+        ``params_by_slot`` names no slot: what the programs are compiled
+        from and warmed with) -> (feed, ``samp.batch_arrays``' four
+        vectors, views of its columns)."""
+        B, M = self.ecfg.max_batch, self.table_width
+        feed = np.zeros(slot_feed_shape(B, M, width), np.int32)
+        at = M + _SLOT_SCALARS
+        return feed, samp.batch_arrays(params_by_slot or {}, B,
+                                       out=feed[:, at:at + _SAMPLING])
+
+    def _rung_feed(self, bucket: int, suffix, prefix_len: int, slot: int,
+                   table_row, params: SamplingParams):
+        """A prefill rung's feed -> (feed, the request's four sampling
+        values as one-element views of it)."""
+        M = self.table_width
+        at = M + _RUNG_SCALARS
+        feed = np.zeros((rung_feed_len(M, bucket),), np.int32)
+        if table_row is not None:
+            feed[:M] = table_row
+        feed[M:at] = (len(suffix), prefix_len, slot)
+        sp = samp.batch_arrays({0: params}, 1,
+                               out=feed[None, at:at + _SAMPLING])
+        feed[at + _SAMPLING:at + _SAMPLING + len(suffix)] = suffix
+        return feed, sp
 
     # Prefill and decode take the manager's arrays as ONE argument (a
     # tuple: the pools, and a recurrent model's state arrays), donated
     # whole; the verify program takes the two pools as arguments 1 and 2.
     def _prefill_program(self, bucket: int):
         """(fn, example args) of one prefill rung, as _decode_program."""
-        M = self.cache.max_pages_per_slot
+        feed, _sp = self._rung_feed(bucket, (0,), 0, 0, None, GREEDY)
         return self._prefill_fn_paged, (
-            self.qparams, self.cache.arrays(),
-            np.zeros((1, bucket), np.int32), np.int32(1),
-            np.int32(0), np.zeros((M,), np.int32), np.int32(0),
-            *self._samp_scalar_examples())
+            self.qparams, self.cache.arrays(), feed)
 
     def _prefill_exec(self, bucket: int):
         name = f"prefill_b{bucket}"
@@ -638,13 +719,8 @@ class DecodeEngine:
         """(fn, example args) of the decode tick — what _decode_exec
         compiles; tests/test_chip_compile.py lowers the same pair for a
         described chip from the example's shapes."""
-        B = self.ecfg.max_batch
-        zeros_b = np.zeros((B,), np.int32)
-        M = self.cache.max_pages_per_slot
         return self._decode_fn_paged, (
-            self.qparams, self.cache.arrays(), zeros_b, zeros_b,
-            np.zeros((B, M), np.int32), zeros_b,
-            *self._samp_batch_examples())
+            self.qparams, self.cache.arrays(), self._slot_feed()[0])
 
     def _decode_exec(self):
         exe = self._exec.get("decode")
@@ -654,11 +730,12 @@ class DecodeEngine:
             self._exec["decode"] = exe
         return exe
 
-    def _call(self, exe, *args):
-        """One prefill or decode call on the live caches: ``(caches,
-        rest)`` back, the caches for ``self.cache.set_arrays`` once the
-        call is known to have run."""
-        out = exe(self.qparams, self.cache.arrays(), *args)
+    def _call(self, exe, feed):
+        """One prefill or decode call on the live caches, its host
+        arguments the one ``feed``: ``(caches, rest)`` back, the caches
+        for ``self.cache.set_arrays`` once the call is known to have
+        run."""
+        out = exe(self.qparams, self.cache.arrays(), feed)
         return out[0], out[1:]
 
     def _verify_exec(self):
@@ -668,13 +745,8 @@ class DecodeEngine:
         name = f"verify_w{W}"
         exe = self._exec.get(name)
         if exe is None:
-            B = self.ecfg.max_batch
-            M = self.cache.max_pages_per_slot
             example = (self.qparams, self.cache.k, self.cache.v,
-                       np.zeros((B, W), np.int32),
-                       np.zeros((B,), np.int32),
-                       np.zeros((B, M), np.int32),
-                       *self._samp_batch_examples())
+                       self._slot_feed(W)[0])
             exe = self._compile(name, self._verify_fn_paged, example,
                                 donate_argnums=(1, 2))
             self._exec[name] = exe
@@ -696,7 +768,7 @@ class DecodeEngine:
             # the live caches: all-zero tables and ``actives``, so a warm
             # call writes the scratch page and slot 0's dead state alone
             t0 = time.perf_counter()
-            caches, rest = self._call(exe, *example[2:])
+            caches, rest = self._call(exe, example[2])
             jax.block_until_ready(rest[0])
             self.cache.set_arrays(caches)
             timings[label] = (time.perf_counter() - t0) * 1e3
@@ -706,14 +778,11 @@ class DecodeEngine:
             _warm_call(f"prefill_b{bucket}", self._prefill_exec(bucket),
                        self._prefill_program(bucket)[1])
         if self.ecfg.verify_window >= 2:
-            W, B = self.ecfg.verify_window, self.ecfg.max_batch
-            zeros_b = np.zeros((B,), np.int32)
+            W = self.ecfg.verify_window
             ver = self._verify_exec()
             t0 = time.perf_counter()
-            tables = np.zeros((B, self.cache.max_pages_per_slot), np.int32)
             out = ver(self.qparams, self.cache.k, self.cache.v,
-                      np.zeros((B, W), np.int32), zeros_b, tables,
-                      *self._samp_batch_examples())
+                      self._slot_feed(W)[0])
             jax.block_until_ready(out[2])
             self.cache.k, self.cache.v = out[0], out[1]
             timings[f"verify_w{W}"] = (time.perf_counter() - t0) * 1e3
@@ -802,9 +871,6 @@ class DecodeEngine:
         n = len(tokens)
         if n < 1:
             raise ValueError("empty prompt")
-        sp_scalars = (np.float32(params.temperature),
-                      np.int32(params.top_k), np.float32(params.top_p),
-                      np.int32(np.uint32(params.seed)))
         # a real open span, under the scheduler's per-request context
         # (the admit path wraps this call in the request's trace); its
         # four phases are its children
@@ -829,13 +895,11 @@ class DecodeEngine:
                 exe = self._prefill_exec(bucket)
                 slot = self.cache.alloc(length=n, prefix_pages=prefix_pages)
                 table_row = self.cache.table_row(slot)
-                padded = np.zeros((1, bucket), np.int32)
-                padded[0, :len(suffix)] = np.asarray(suffix, np.int32)
+                feed, sp = self._rung_feed(bucket, suffix, prefix_len, slot,
+                                           table_row, params)
                 attrs.update(bucket=bucket, prefix_len=prefix_len, slot=slot)
             caches, logits, tok = self._run_prefill(
-                exe, bucket, slot, len(suffix), sp_scalars, padded,
-                np.int32(len(suffix)), np.int32(prefix_len), table_row,
-                np.int32(slot))
+                exe, bucket, slot, len(suffix), sp, feed)
             if self.last_expert_load is not None:
                 attrs.update(
                     expert_tokens=self.last_expert_load["expert_tokens"],
@@ -853,17 +917,16 @@ class DecodeEngine:
             return slot, logits, tok
 
     def _run_prefill(self, exe, bucket: int, slot: int, n_tokens: int,
-                     sp_scalars, *args):
-        """The prefill executable's call (``args``, then the request's
-        four sampling scalars): ``prefill/run`` until the sampled token
-        is on the host (that waits for the program), then
+                     sp, feed):
+        """The prefill executable's call on ``feed`` (``sp``: the
+        request's sampling values on the host): ``prefill/run`` until the
+        sampled token is on the host (that waits for the program), then
         ``prefill/fetch_logits``, the transfer of the logits alone."""
         t0 = time.perf_counter_ns()
-        sampler = _note_sampler("prefill", *sp_scalars[:3])
+        sampler = _note_sampler("prefill", *sp[:3])
         try:
             with _spans.span("prefill/run", attrs={"sampler": sampler}):
-                caches, (logits, tok, *report) = self._call(
-                    exe, *args, *sp_scalars)
+                caches, (logits, tok, *report) = self._call(exe, feed)
                 tok = int(tok)
             with _spans.span("prefill/fetch_logits"):
                 logits = np.asarray(logits)
@@ -961,30 +1024,34 @@ class DecodeEngine:
         model without recurrent layers)."""
         return self.cache.state_bytes_per_slot * len(slots)
 
-    def _decode_feed(self, slot_tokens: Dict[int, int]):
-        B = self.ecfg.max_batch
-        tokens = np.zeros((B,), np.int32)
-        positions = np.zeros((B,), np.int32)
-        for slot, tok in slot_tokens.items():
-            if not self.cache.is_live(slot):
+    def _decode_feed(self, slot_tokens: Dict[int, int], params_by_slot):
+        """A tick's feed with its riders' tokens, positions and sampling
+        knobs in -> (feed, the sampler's four vectors)."""
+        is_live, length = self.cache.is_live, self.cache.length
+        max_seq = self.ecfg.max_seq
+        positions = []
+        for slot in slot_tokens:
+            if not is_live(slot):
                 raise ValueError(f"slot {slot} is not live")
-            if self.cache.headroom(slot) < 1:
-                raise ValueError(
-                    f"slot {slot} is at max_seq {self.ecfg.max_seq}")
-            tokens[slot] = tok
-            positions[slot] = self.cache.length(slot)
-        return tokens, positions
+            n = length(slot)
+            if n >= max_seq:                      # no headroom
+                raise ValueError(f"slot {slot} is at max_seq {max_seq}")
+            positions.append(n)
+        feed, sp = self._slot_feed(params_by_slot=params_by_slot)
+        riders = np.fromiter(slot_tokens, np.intp, len(slot_tokens))
+        feed[riders, self.table_width + _POSITION] = positions
+        feed[riders, -1] = list(slot_tokens.values())
+        return feed, sp
 
-    def _masked_tables(self, active_slots) -> np.ndarray:
-        """Page-table feed with non-participating lanes zeroed so their
-        writes land in the scratch page — a live slot absent from this
-        call keeps its pages untouched."""
-        tables = self.cache.tables()
-        active = set(active_slots)
-        for s in range(self.ecfg.max_batch):
-            if s not in active:
-                tables[s, :] = 0
-        return tables
+    def _masked_tables(self, active_slots, feed: np.ndarray) -> None:
+        """The riders' page-table rows and ``actives`` into ``feed``, by
+        one row index: a lane that does not ride keeps its zero row, so
+        its write lands in the scratch page — a live slot absent from
+        this call keeps its pages untouched."""
+        riders = np.fromiter(active_slots, np.intp, len(active_slots))
+        M = self.table_width
+        feed[riders, :M] = self.cache.table_rows(riders)
+        feed[riders, M + _ACTIVE] = 1
 
     def decode_step(self, slot_tokens: Dict[int, int]) -> Dict[int, np.ndarray]:
         """One greedy-compatible decode step for the given
@@ -1022,11 +1089,11 @@ class DecodeEngine:
             sampler = tick.sampler
         else:
             with _spans.span("decode/feed"):
-                args, sampler = self._tick_args(slot_tokens, params_by_slot)
+                feed, sampler = self._tick_args(slot_tokens, params_by_slot)
         try:
             with _spans.span("decode/run", attrs={"sampler": sampler}):
                 if tick is None:
-                    tick = self._launch(slot_tokens, args, sampler)
+                    tick = self._launch(slot_tokens, feed, sampler)
                 toks = np.asarray(tick.toks)
                 sampled = {slot: int(toks[slot]) for slot in slot_tokens}
         except Exception as e:
@@ -1057,28 +1124,24 @@ class DecodeEngine:
         return out
 
     def _tick_args(self, slot_tokens: Dict[int, int], params_by_slot):
-        """The decode call's host arrays for these riders, their next
-        rows' pages mapped: ``(arguments behind the caches, sampler
-        path)``."""
-        tokens, positions = self._decode_feed(slot_tokens)
-        sp = samp.batch_arrays(params_by_slot or {}, self.ecfg.max_batch)
+        """The decode call's feed for these riders, their next rows'
+        pages mapped: ``(feed, sampler path)``."""
+        feed, sp = self._decode_feed(slot_tokens, params_by_slot)
         for slot in slot_tokens:
             if not self.ensure_decode_capacity(slot):
                 raise PagePoolFullError(
                     f"slot {slot}: no free page for position "
                     f"{self.cache.length(slot)}")
-        actives = np.zeros((self.ecfg.max_batch,), np.int32)
-        actives[list(slot_tokens)] = 1
-        tables = self._masked_tables(slot_tokens)
+        self._masked_tables(slot_tokens, feed)
         sampler = _note_sampler("decode", *sp[:3])
-        return (tokens, positions, tables, actives, *sp), sampler
+        return feed, sampler
 
-    def _launch(self, slot_tokens: Dict[int, int], args, sampler: str,
+    def _launch(self, slot_tokens: Dict[int, int], feed, sampler: str,
                 ahead: bool = False) -> _Tick:
         """Dispatch the decode executable; nothing here waits for it."""
         t0 = time.perf_counter_ns()
         caches, (logits, toks, *report) = self._call(
-            self._decode_exec(), *args)
+            self._decode_exec(), feed)
         if ahead:
             self.cache.set_arrays(caches)
             caches = None
@@ -1098,9 +1161,9 @@ class DecodeEngine:
         ``decode_step_sampled`` call for these riders collects it."""
         if self._ahead is not None:
             raise RuntimeError(f"a tick is in flight for {self._ahead.feed}")
-        args, sampler = self._tick_args(feed, params_by_slot)
+        host_feed, sampler = self._tick_args(feed, params_by_slot)
         try:
-            self._ahead = self._launch(feed, args, sampler, ahead=True)
+            self._ahead = self._launch(feed, host_feed, sampler, ahead=True)
         except Exception as e:
             self._poison_on_donation_failure("decode", e)
             raise
@@ -1165,9 +1228,8 @@ class DecodeEngine:
         W = self.ecfg.verify_window
         if W < 2:
             raise RuntimeError("engine compiled without a verify window")
-        B = self.ecfg.max_batch
-        tokens = np.zeros((B, W), np.int32)
-        starts = np.zeros((B,), np.int32)
+        feed, sp = self._slot_feed(W, params_by_slot)
+        M = self.table_width
         for slot, win in windows.items():
             if len(win) != W:
                 raise ValueError(
@@ -1176,9 +1238,8 @@ class DecodeEngine:
                 raise ValueError(f"slot {slot} is not live")
             if self.cache.headroom(slot) < W:
                 raise ValueError(f"slot {slot}: headroom < window {W}")
-            tokens[slot] = np.asarray(win, np.int32)
-            starts[slot] = self.cache.length(slot)
-        sp = samp.batch_arrays(params_by_slot or {}, B)
+            feed[slot, -W:] = win
+            feed[slot, M + _POSITION] = self.cache.length(slot)
         _note_sampler("verify", *sp[:3])
         exe = self._verify_exec()
         t0 = time.perf_counter_ns()
@@ -1188,10 +1249,9 @@ class DecodeEngine:
                     raise PagePoolFullError(
                         f"slot {slot}: no free pages for a {W}-token "
                         "verify window")
-            tables = self._masked_tables(windows)
+            self._masked_tables(windows, feed)
             ck, cv, logits, toks = exe(
-                self.qparams, self.cache.k, self.cache.v, tokens,
-                starts, tables, *sp)
+                self.qparams, self.cache.k, self.cache.v, feed)
             logits = np.asarray(logits)
             toks = np.asarray(toks)
         except PagePoolFullError:

@@ -522,9 +522,10 @@ class PagedKVCache:
         """[max_pages_per_slot] int32 page table for one slot (copy)."""
         return self._tables[slot].copy()
 
-    def tables(self) -> np.ndarray:
-        """[max_slots, max_pages_per_slot] int32 — the decode feed."""
-        return self._tables.copy()
+    def table_rows(self, slots) -> np.ndarray:
+        """[len(slots), max_pages_per_slot] int32 page tables of ``slots``
+        (copy): the riders' rows of the decode feed."""
+        return self._tables[slots]
 
     # -- pool metrics ------------------------------------------------------
     def pool_occupancy(self) -> float:

@@ -52,7 +52,8 @@ import jax.numpy as jnp
 
 __all__ = ["SamplingParams", "GREEDY", "PATHS", "sampler_path",
            "path_name", "sample", "sample_token", "sample_batch",
-           "sample_window", "batch_arrays", "adjusted_probs_np"]
+           "sample_window", "batch_arrays", "feed_columns",
+           "adjusted_probs_np"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -224,18 +225,36 @@ def adjusted_probs_np(logits: np.ndarray, sp: SamplingParams
 
 
 def batch_arrays(params_by_slot: Dict[int, SamplingParams],
-                 max_batch: int) -> Tuple[np.ndarray, np.ndarray,
-                                          np.ndarray, np.ndarray]:
+                 max_batch: int, out: Optional[np.ndarray] = None
+                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
     """Host helper: {slot: SamplingParams} -> the four [max_batch] feed
     vectors (temps f32, top_ks i32, top_ps f32, seeds i32). Slots absent
-    from the map ride greedy."""
-    temps = np.zeros((max_batch,), np.float32)
-    top_ks = np.zeros((max_batch,), np.int32)
-    top_ps = np.ones((max_batch,), np.float32)
-    seeds = np.zeros((max_batch,), np.int32)
+    from the map ride greedy. The vectors are the columns of ``out``, an
+    int32 ``[max_batch, 4]`` block of an engine call's feed (the floats
+    as their bit patterns; :func:`feed_columns` is the program's reader),
+    or of a block of their own."""
+    if out is None:
+        out = np.empty((max_batch, 4), np.int32)
+    temps, top_ps = out[:, 0].view(np.float32), out[:, 2].view(np.float32)
+    top_ks, seeds = out[:, 1], out[:, 3]
+    temps[:] = 0.0
+    top_ks[:] = 0
+    top_ps[:] = 1.0
+    seeds[:] = 0
     for slot, sp in params_by_slot.items():
         temps[slot] = sp.temperature
         top_ks[slot] = sp.top_k
         top_ps[slot] = sp.top_p
         seeds[slot] = np.int32(np.uint32(sp.seed))
     return temps, top_ks, top_ps, seeds
+
+
+def feed_columns(block):
+    """Inside a program: the int32 ``[..., 4]`` block :func:`batch_arrays`
+    wrote -> (temps, top_ks, top_ps, seeds) with the dtypes and the bits
+    the host gave them."""
+    def f32(col):
+        return jax.lax.bitcast_convert_type(col, jnp.float32)
+
+    return (f32(block[..., 0]), block[..., 1], f32(block[..., 2]),
+            block[..., 3])

@@ -83,12 +83,24 @@ class EngineLoop:
 
     Every iteration stamps hang-watchdog progress (``serve/tick``), so a
     replica armed via the ``PADDLE_HEALTH_*`` env contract exits 43 when
-    the loop wedges — the same contract training workers follow."""
+    the loop wedges — the same contract training workers follow.
+
+    Beside the loop a second thread, the beat, keeps the host's side of
+    the transfers awake while requests are pending (``_beat``)."""
+
+    # The beat's period. An engine call hands over one host array (PR 38),
+    # and with ticks of 20 ms between prefills of 40-250 ms that is too
+    # few transfers to keep whatever waits under them from going to
+    # sleep: every wait for a transfer, in either direction, then takes
+    # 0.5-0.8 ms longer (PERF.md section 6, PR 38). A 64-byte transfer
+    # every 4 ms while the loop has work keeps it awake.
+    BEAT_S = 0.004
 
     def __init__(self, scheduler: Scheduler, idle_sleep_s: float = 0.002,
                  on_poison=None):
         self.scheduler = scheduler
         self.idle_sleep_s = idle_sleep_s
+        self.beats = 0
         self.faults = 0
         self.last_fault: Optional[str] = None
         self.on_poison = on_poison
@@ -96,11 +108,15 @@ class EngineLoop:
         self._stop = threading.Event()
         self._wake = threading.Event()
         self._thread: Optional[threading.Thread] = None
+        self._beat_thread: Optional[threading.Thread] = None
 
     def start(self) -> "EngineLoop":
         self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="serve-engine-loop")
         self._thread.start()
+        self._beat_thread = threading.Thread(target=self._beat, daemon=True,
+                                             name="serve-engine-beat")
+        self._beat_thread.start()
         return self
 
     @property
@@ -113,8 +129,21 @@ class EngineLoop:
     def stop(self, timeout: float = 5.0) -> None:
         self._stop.set()
         self._wake.set()
-        if self._thread:
-            self._thread.join(timeout=timeout)
+        for thread in (self._thread, self._beat_thread):
+            if thread:
+                thread.join(timeout=timeout)
+
+    def _beat(self) -> None:
+        """While requests are pending, one tiny host-to-device transfer
+        every ``BEAT_S``: nothing reads it, no program waits for it (it
+        is no argument of any), and an idle server sends none."""
+        import jax
+
+        word = np.zeros((16,), np.int32)
+        while not self._stop.wait(self.BEAT_S):
+            if self.scheduler.pending():
+                jax.device_put(word)
+                self.beats += 1
 
     def _run(self) -> None:
         from ..parallel import health as _health

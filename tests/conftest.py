@@ -45,9 +45,11 @@ def pytest_configure(config):
 # Run last, they leave the xdist schedule of every other file as it was
 # before they existed: test_dgc_halfasync's two async trainer processes stop
 # converging when such a neighbour starves them (their margin is thin).
-# (PR 35's two files of the delta-rule family likewise, behind the longest.)
+# (PR 35's two files of the delta-rule family likewise, behind the longest,
+# and PR 38's of the feed array.)
 _RUN_LAST = ("test_chip_compile.py", "test_chip_smoke.py",
-             "test_olmo_hybrid.py", "test_benchmark_olmo_hybrid.py")
+             "test_olmo_hybrid.py", "test_benchmark_olmo_hybrid.py",
+             "test_tick_feed.py")
 
 
 def pytest_collection_modifyitems(config, items):

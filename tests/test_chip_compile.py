@@ -396,22 +396,74 @@ def _cell_engine(cell):
     return _CELL_ENGINES[cell]
 
 
-def _lowered(cell, program, sharding):
+def _lowered(cell, program, sharding, uncut=False):
     """``decode`` or ``prefill_b<rung>`` of a cell (``gpt_cell``,
     ``jamba_cut``, ``kimi``) lowered for the described chip, once a
     process. Called from inside a test (the autouse fixture has to be in
-    force), never from a fixture of wider scope."""
-    key = (cell, program)
+    force), never from a fixture of wider scope. ``uncut``: the program
+    behind the feed's cut (:func:`_uncut`)."""
+    key = (cell, program) + (("uncut",) if uncut else ())
     if key not in _LOWERED:
         if cell == "kimi":
-            _LOWERED[key] = _lower_kimi_cut(program, sharding)
+            _LOWERED[key] = _lower_kimi_cut(program, sharding, uncut)
         else:
             eng = _cell_engine(cell)
-            _LOWERED[key] = _lower_donated(
-                *(eng._decode_program() if program == "decode" else
-                  eng._prefill_program(int(program.split("_b")[1]))),
-                sharding)
+            fn, (held, caches, feed) = (
+                eng._decode_program() if program == "decode" else
+                eng._prefill_program(int(program.split("_b")[1])))
+            if uncut:
+                feed = _uncut(feed, eng.table_width)
+            _LOWERED[key] = _lower_donated(fn, (held, caches, feed),
+                                           sharding)
     return _LOWERED[key]
+
+
+class _Column:
+    """Stands where a program reads ``tokens[:, 0]`` of the feed's token
+    columns: the [B] vector the tick took before there was a feed."""
+
+    def __init__(self, vector):
+        self.vector = vector
+
+    def __getitem__(self, at):
+        return self.vector
+
+
+def _uncut(feed, M):
+    """What PR 35's programs took where today's take ``feed``: the eight
+    arrays of a tick or the nine of a rung, as shapes, in the order of
+    their arguments, as ONE pytree. With :func:`_cuts_handed_through` in
+    force the engine's program functions lower from it to the program
+    behind the cut, and that is PR 35's program, text for text."""
+    i32, f32 = np.int32, np.float32
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    if len(feed.shape) == 2:
+        B = feed.shape[0]
+        return (arr((B,), i32), arr((B,), i32), arr((B, M), i32),
+                arr((B,), i32), (arr((B,), f32), arr((B,), i32),
+                                 arr((B,), f32), arr((B,), i32)))
+    from paddle_tpu.serving import engine as E
+
+    bucket = feed.shape[0] - E.rung_feed_len(M, 0)
+    return (arr((1, bucket), i32), arr((), i32), arr((), i32),
+            arr((M,), i32), arr((), i32),
+            (arr((), f32), arr((), i32), arr((), f32), arr((), i32)))
+
+
+@pytest.fixture
+def _cuts_handed_through(monkeypatch):
+    """The feed's two cuts return what they are given (:func:`_uncut`'s
+    arrays): the engine's program functions then trace what lies behind
+    the cut and nothing of the cut."""
+    from paddle_tpu.serving import engine as E
+
+    monkeypatch.setattr(E, "cut_rung_feed", lambda feed, M: feed)
+    monkeypatch.setattr(
+        E, "cut_slot_feed",
+        lambda feed, M: (_Column(feed[0]),) + tuple(feed[1:]))
 
 
 def _compiled(cell, program, sharding):
@@ -587,6 +639,45 @@ def test_serving_programs_relay_no_weight(one_chip, program):
     assert not moves, "\n".join(moves)
 
 
+# the GPT cell's programs with eight and nine host arrays (the parent of
+# the one feed array, PR 35's tree): their temporaries by this compile
+_TEMP_BEFORE_THE_FEED = {"decode": 4_300_288, "prefill_b512": 1_285_632}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_b512"])
+def test_the_one_feed_array_is_cut_apart_for_nothing(one_chip, program):
+    """A call's host arguments are one int32 array that the program cuts
+    apart (``serving/engine.py``, "the feed"): on the chip's own compile of
+    the GPT cell's tick and its 512 rung the entry takes ONE integer
+    parameter behind the weights and the two pools, the cut costs no
+    re-laid copy of it (the table row comes first so that its slice starts
+    at lane 0; the compiler may prefetch the array's few KB into fast
+    memory, a ``copy-start`` in the same tiling, which is no relayout),
+    and the program's temporaries are within 1 MB of what they were with
+    eight and nine arrays (described compile of the parent: 4.10 and 1.23
+    MiB). That no weight is re-laid either is
+    ``test_serving_programs_relay_no_weight``'s."""
+    eng, compiled = _compiled_gpt_program(program, one_chip)
+    _fn, example = (eng._decode_program() if program == "decode" else
+                    eng._prefill_program(512))
+    feed = example[-1]
+    assert len(example) == 3 and feed.dtype == np.int32
+    bodies, entry = _computations(compiled.as_text())
+    shape = "s32[%s]" % ",".join(map(str, feed.shape))
+    params = [sh for _n, sh, op, _r in bodies[entry] if op == "parameter"]
+    (integers,) = [sh for sh in params if sh.startswith("s32")]
+    assert integers.startswith(shape), params
+    assert not [sh for sh in params if sh.startswith("f32[]")]
+    relaid = [f"{n} = {sh} {op}" for instrs in bodies.values()
+              for n, sh, op, _r in instrs
+              if op in ("copy", "transpose") and sh.startswith(shape)]
+    assert not relaid, relaid
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"{program}: temporaries {temp} B, with eight or nine arrays "
+          f"{_TEMP_BEFORE_THE_FEED[program]} B")
+    assert abs(temp - _TEMP_BEFORE_THE_FEED[program]) < 2 ** 20
+
+
 def _reached(bodies, roots, through_conditionals):
     """Computations reached from ``roots`` along the instructions' calls;
     with ``through_conditionals`` false a ``conditional``'s branches are
@@ -662,7 +753,7 @@ def _kimi_cut_program(program, sharding):
     return _kimi_cut_config(), _compiled("kimi", program, sharding)
 
 
-def _lower_kimi_cut(program, sharding):
+def _lower_kimi_cut(program, sharding, uncut=False):
     """The decode tick or a prefill rung, lowered for the described chip,
     of the expert-parallel cell at its published widths,
     128 slots of 3072 tokens, 12 held experts of 384, an eighth of the
@@ -694,19 +785,18 @@ def _lower_kimi_cut(program, sharding):
         lambda a: arg(a.shape, a.dtype),
         jax.eval_shape(lambda p: KK.hold(p, cfg, "bf16"), stored))
     pool = (arg((3, B * S // PAGE + 1, PAGE, cfg.cache_width), BF16),)
-    i32 = jnp.int32
+    # the one feed array of a call (serving/engine.py, "the feed")
     if program == "decode":
-        fn, rest = eng._decode_fn_paged, (
-            arg((B,), i32), arg((B,), i32), arg((B, S // PAGE), i32),
-            arg((B,), i32), arg((B,), F32), arg((B,), i32),
-            arg((B,), F32), arg((B,), i32))
+        fn, feed = eng._decode_fn_paged, arg(
+            E.slot_feed_shape(B, S // PAGE), jnp.int32)
     else:
         T = int(program.split("_b")[1])
-        fn, rest = eng._prefill_fn_paged, (
-            arg((1, T), i32), arg((), i32), arg((), i32),
-            arg((S // PAGE,), i32), arg((), i32), arg((), F32),
-            arg((), i32), arg((), F32), arg((), i32))
-    return jax.jit(fn, donate_argnums=(1,)).lower(held, pool, *rest)
+        fn, feed = eng._prefill_fn_paged, arg(
+            (E.rung_feed_len(S // PAGE, T),), jnp.int32)
+    if uncut:
+        feed = jax.tree_util.tree_map(
+            lambda a: arg(a.shape, a.dtype), _uncut(feed, S // PAGE))
+    return jax.jit(fn, donate_argnums=(1,)).lower(held, pool, feed)
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_b512"])
@@ -753,11 +843,51 @@ def test_kimi_programs_copy_no_expert_leaf(one_chip, program):
 # cell's decode tick and of every prefill rung, lowered for the described
 # chip from the shapes above, the Mosaic kernels' bodies masked (serialized
 # MLIR that carries the checkout's path in its locations; the kernels have
-# tests of their own above). Read at PR 33's tree and the same at PR 34's.
+# tests of their own above). Read at PR 33's tree and the same at PR 34's;
+# read anew at PR 38's, which changed every program's signature on purpose
+# (one feed array behind the caches in place of eight or nine, cut apart
+# first thing: the layers' and the sampler's text is the parent's).
 # A PR that changes a program on purpose reads the new digests off the
 # failure's message, puts them here and says in PERF.md which program
 # changed and why; one that meant to leave the device's work alone has not.
 PROGRAM_TEXT_SHA256 = {
+    "gpt_cell/decode":
+        "df0e03ef4c1e22c55483477b115abf1ffc818a1e7675afd53caf42e8a742502a",
+    "gpt_cell/prefill_b16":
+        "b7f432de388010f27c07a84b78d888548ffc4f166bc43d346f8d85838dd91e41",
+    "gpt_cell/prefill_b32":
+        "b1892624bb3ffc153a523013188ffc7c1579cbe146937c8af19bb01a3f0f7b34",
+    "gpt_cell/prefill_b64":
+        "217a7aeb142eb883764a68b04021b968d29ff2a6fc7a96f8506cc0407af16e2a",
+    "gpt_cell/prefill_b128":
+        "52db89088d0d2c017873e16fcda764b7ba0562c0f77910b851db9b02d763918c",
+    "gpt_cell/prefill_b256":
+        "f35937a54e194730a4422644c355274400e3dc1405ea147bda51a65636bc8dc1",
+    "gpt_cell/prefill_b512":
+        "0eee918a93f0dc2c43afd74f37e4288c813b2cb6df3d7ea25455821d9ad5cd2a",
+    "gpt_cell/prefill_b1024":
+        "7cd7b7886d198e1870e4b921398ec1fffc93503a106fd457c297d9befdc8621b",
+    "gpt_cell/prefill_b2048":
+        "3d14bae8d80d3e38a63bfeba3cf8e036e5653e7df3890975a13e5066ddeb46b7",
+    "jamba_cut/decode":
+        "89820486ab01c4a292d3a3960cdfb639c76c5aaba51a7ec086b14adacb4c61c8",
+    "jamba_cut/prefill_b256":
+        "457166bf9e26c2b84b33ca299a15c06ad80b60b8f584eb1060153a33fbd5a3b7",
+    "jamba_cut/prefill_b2048":
+        "4236a86f1728454259195c681b48841cb57449fe7651ad4e8ed25460b3dc2c33",
+    "kimi/decode":
+        "581fb938140ac7db08b08c921d14aa26a684c3f4a935cef192eec095ba17499c",
+    "kimi/prefill_b512":
+        "abe8eea28687e68d7baa85e1ba7304e6e712b5fc6c0a4367049da51ae1e778e1",
+}
+
+
+# The same programs behind the feed's cut: PR 35's digests, of the programs
+# that took eight and nine host arrays. PR 38 packed those into the feed and
+# changed nothing else, and this holds it to that: what the layers, the head
+# and the sampler are traced from, and in what order, is PR 35's, text for
+# text. A PR that changes a program on purpose re-pins both tables.
+BEHIND_THE_CUT_SHA256 = {
     "gpt_cell/decode":
         "01f7f55ad04479f7c6dc53e6f6569ecbea13a40a6d1d8aff95166bc5aef33658",
     "gpt_cell/prefill_b16":
@@ -789,6 +919,14 @@ PROGRAM_TEXT_SHA256 = {
 }
 
 
+def _text_digest(lowered):
+    import hashlib
+
+    return hashlib.sha256(re.sub(
+        r'\\22body\\22: \\22[A-Za-z0-9+/=]*\\22', "body",
+        lowered.as_text()).encode()).hexdigest()
+
+
 def _program_names(cell):
     if cell == "gpt_cell":
         rungs = (16, 32, 64, 128, 256, 512, 1024, 2048)
@@ -801,18 +939,25 @@ def _program_names(cell):
 
 @pytest.mark.parametrize("cell", ["gpt_cell", "jamba_cut", "kimi"])
 def test_serving_programs_lower_to_the_text_they_had(one_chip, cell):
-    import hashlib
-
     if cell != "kimi":
         assert _program_names(cell)[1:] == [
             f"prefill_b{b}" for b in _cell_engine(cell).buckets]
-    got = {f"{cell}/{program}": hashlib.sha256(re.sub(
-        r'\\22body\\22: \\22[A-Za-z0-9+/=]*\\22', "body",
-        _lowered(cell, program, one_chip).as_text()).encode()).hexdigest()
-        for program in _program_names(cell)}
+    got = {f"{cell}/{program}": _text_digest(
+        _lowered(cell, program, one_chip)) for program in _program_names(cell)}
     want = {k: v for k, v in PROGRAM_TEXT_SHA256.items()
             if k.startswith(cell + "/")}
     assert got == want, f"the programs' digests now:\n{got!r}"
+
+
+@pytest.mark.parametrize("cell", ["gpt_cell", "jamba_cut", "kimi"])
+def test_serving_programs_behind_the_cut_are_what_they_were(
+        one_chip, cell, _cuts_handed_through):
+    got = {f"{cell}/{program}": _text_digest(
+        _lowered(cell, program, one_chip, uncut=True))
+        for program in _program_names(cell)}
+    want = {k: v for k, v in BEHIND_THE_CUT_SHA256.items()
+            if k.startswith(cell + "/")}
+    assert got == want, f"the digests behind the cut now:\n{got!r}"
 
 
 # ---------------------------------------------------------------------------
@@ -925,19 +1070,15 @@ def _lower_olmo_cut(program, sharding):
     pool = arg((1, OLMO_PAGES, PAGE, cfg.kv_pool_heads, HD), BF16)
     caches = (pool, pool, arg((3, B) + geometry["conv"], BF16),
               arg((3, B) + geometry["ssm"], F32))
-    i32 = jnp.int32
+    # the one feed array of a call (serving/engine.py, "the feed")
     if program == "decode":
-        fn, rest = eng._decode_fn_paged, (
-            arg((B,), i32), arg((B,), i32), arg((B, S // PAGE), i32),
-            arg((B,), i32), arg((B,), F32), arg((B,), i32),
-            arg((B,), F32), arg((B,), i32))
+        fn, feed = eng._decode_fn_paged, arg(
+            E.slot_feed_shape(B, S // PAGE), jnp.int32)
     else:
         T = int(program.split("_b")[1])
-        fn, rest = eng._prefill_fn_paged, (
-            arg((1, T), i32), arg((), i32), arg((), i32),
-            arg((S // PAGE,), i32), arg((), i32), arg((), F32),
-            arg((), i32), arg((), F32), arg((), i32))
-    return (jax.jit(fn, donate_argnums=(1,)).lower(held, caches, *rest),
+        fn, feed = eng._prefill_fn_paged, arg(
+            (E.rung_feed_len(S // PAGE, T),), jnp.int32)
+    return (jax.jit(fn, donate_argnums=(1,)).lower(held, caches, feed),
             caches)
 
 

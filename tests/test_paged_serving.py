@@ -18,7 +18,10 @@ from paddle_tpu.serving.paged_kv import (PagedKVCache, PagePoolFullError,
                                          PrefixCache)
 
 from serving_helpers import greedy_engine as _greedy
+from serving_helpers import greedy_knobs as _greedy_knobs
 from serving_helpers import greedy_reference as _reference
+from serving_helpers import pack_rung_feed as _pack_rung_feed
+from serving_helpers import pack_slot_feed as _pack_slot_feed
 
 
 @pytest.fixture(scope="module")
@@ -762,22 +765,17 @@ def _paged_program(eng, program, rng):
     tables[0] = 1 + np.arange(M)
     tables[2] = 1 + M + np.arange(M)
     if program == "decode":
-        return eng._decode_fn_paged, (
-            rng.integers(0, V, (B,)).astype(np.int32),
-            np.asarray([7, 0, 16, 0], np.int32), tables,
-            np.asarray([1, 0, 1, 0], np.int32),
-            *eng._samp_batch_examples())
+        return eng._decode_fn_paged, (_pack_slot_feed(
+            rng.integers(0, V, (B,)), [7, 0, 16, 0], tables, [1, 0, 1, 0],
+            *_greedy_knobs(B)),)
     if program == "prefill":
         # a 8-token suffix behind a cached 8-token prefix, 5 valid
-        return eng._prefill_fn_paged, (
-            rng.integers(0, V, (1, 8)).astype(np.int32), np.int32(5),
-            np.int32(8), tables[2], np.int32(2),
-            *eng._samp_scalar_examples())
-    W = eng.ecfg.verify_window
-    return eng._verify_fn_paged, (
-        rng.integers(0, V, (B, W)).astype(np.int32),
-        np.asarray([7, 0, 14, 0], np.int32), tables,
-        *eng._samp_batch_examples())
+        return eng._prefill_fn_paged, (_pack_rung_feed(
+            rng.integers(0, V, (1, 8)), 5, 8, tables[2], 2, 0.0, 0, 1.0,
+            0),)
+    return eng._verify_fn_paged, (_pack_slot_feed(
+        rng.integers(0, V, (B, eng.ecfg.verify_window)), [7, 0, 14, 0],
+        tables, [1, 0, 1, 0], *_greedy_knobs(B)),)
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill", "verify"])
@@ -853,13 +851,12 @@ def test_dead_lane_leaves_every_page_but_scratch(tiny_model, kv_path):
     rng = np.random.default_rng(11)
     tables = np.zeros((B, M), np.int32)
     tables[2] = 1 + M + np.arange(M)         # the one rider
-    args = (rng.integers(0, V, (B,)).astype(np.int32),
-            np.asarray([0, 0, 11, 0], np.int32), tables,
-            np.asarray([0, 0, 1, 0], np.int32),
-            *eng._samp_batch_examples())
+    tokens = rng.integers(0, V, (B,))
+    feed = _pack_slot_feed(tokens, [0, 0, 11, 0], tables, [0, 0, 1, 0],
+                           *_greedy_knobs(B))
     kp, vp = _seeded_pools(eng, 12)
     (kp2, vp2), _logits, _toks = jax.jit(eng._decode_fn_paged)(
-        eng.qparams, (kp, vp), *args)
+        eng.qparams, (kp, vp), feed)
     for before, after in ((kp, kp2), (vp, vp2)):
         before = np.asarray(before, np.float32)
         after = np.asarray(after, np.float32)
@@ -871,10 +868,10 @@ def test_dead_lane_leaves_every_page_but_scratch(tiny_model, kv_path):
             differs[:, 1 + M + 11 // ps].any(axis=(0, 2, 3)))
         assert rows.tolist() == [11 % ps]
     # all lanes dead: the scratch page alone
-    dead = (args[0], np.zeros((B,), np.int32), np.zeros((B, M), np.int32),
-            np.zeros((B,), np.int32), *args[4:])
+    dead = _pack_slot_feed(tokens, np.zeros((B,)), np.zeros((B, M)),
+                           np.zeros((B,)), *_greedy_knobs(B))
     (kp3, _vp3), _l, _t = jax.jit(eng._decode_fn_paged)(
-        eng.qparams, (kp, vp), *dead)
+        eng.qparams, (kp, vp), dead)
     np.testing.assert_array_equal(np.asarray(kp3, np.float32)[:, 1:],
                                   np.asarray(kp, np.float32)[:, 1:])
 
