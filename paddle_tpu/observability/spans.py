@@ -22,11 +22,13 @@ is one timed operation with identity:
   :func:`monotonic_to_ns` and never assumes the two clocks are one;
 - a span opened with :meth:`SpanTracer.span` is also a
   ``jax.profiler.TraceAnnotation`` called ``paddle/<name>`` while it is
-  open.  Without a profiler session that is a no-op inside jax; with one
-  (profiler.py, a benchmark's traced run, an operator's capture) the span
-  lies on the xplane's host plane, on the profiler's clock, beside the
-  device operations.  A process that has not loaded jax (the stub replica
-  worker) is never made to.
+  open, with one stat, ``span``: the id its record carries.  Without a
+  profiler session that is a no-op inside jax (the stat is not encoded);
+  with one (profiler.py, a benchmark's traced run, an operator's capture)
+  the span lies on the xplane's host plane, on the profiler's clock,
+  beside the device operations, and its id fetches the record, with its
+  attributes and its parent, from the ring or the JSONL sink.  A process
+  that has not loaded jax (the stub replica worker) is never made to.
 
 Cost model (the dispatch-overhead gate in tools/dispatch_bench.py holds
 tracing to <5% of the fast path): a disabled tracer is one global read;
@@ -211,7 +213,7 @@ class _OpenSpan:
     def __enter__(self):
         cls = _annotation_class()
         if cls is not None:
-            self.ann = cls(ANNOTATION_PREFIX + self.name)
+            self.ann = cls(ANNOTATION_PREFIX + self.name, span=self.span_id)
             self.ann.__enter__()
         else:
             self.ann = None
@@ -250,6 +252,7 @@ class _NullSpan:
     """Shared no-op handle returned while tracing is disabled."""
 
     __slots__ = ()
+    span_id = None
 
     def __enter__(self):
         return self

@@ -172,6 +172,7 @@ class _Tick:
     report: list
     sampler: str
     t0: int                      # perf_counter_ns at dispatch
+    call: Optional[int]          # its decode/call's span id, if traced
 
 
 def default_bucket_ladder(max_seq: int, smallest: int = 16) -> Tuple[int, ...]:
@@ -925,8 +926,12 @@ class DecodeEngine:
         t0 = time.perf_counter_ns()
         sampler = _note_sampler("prefill", *sp[:3])
         try:
-            with _spans.span("prefill/run", attrs={"sampler": sampler}):
-                caches, (logits, tok, *report) = self._call(exe, feed)
+            with _spans.span("prefill/run",
+                             attrs={"sampler": sampler}) as run:
+                with _spans.span("prefill/call", attrs={
+                        "exe": f"prefill_b{bucket}"}) as call:
+                    caches, (logits, tok, *report) = self._call(exe, feed)
+                run.set_attr("call", call.span_id)
                 tok = int(tok)
             with _spans.span("prefill/fetch_logits"):
                 logits = np.asarray(logits)
@@ -1081,8 +1086,10 @@ class DecodeEngine:
         # on the host: the small array first, it waits for the program),
         # plan (a scheduler's next tick, dispatched while this one's
         # logits are still on the device), fetch_logits (the transfer
-        # alone), commit (host bookkeeping). A tick found in flight has
-        # had its feed and its call under its predecessor's plan.
+        # alone), commit (host bookkeeping). The executable's call alone
+        # is decode/call (_launch): under this run for a tick fed here,
+        # under its predecessor's plan, with its feed, for a tick found
+        # in flight.
         tick = self._claim_ahead(slot_tokens)
         was_ahead = tick is not None
         if was_ahead:
@@ -1091,14 +1098,21 @@ class DecodeEngine:
             with _spans.span("decode/feed"):
                 feed, sampler = self._tick_args(slot_tokens, params_by_slot)
         try:
-            with _spans.span("decode/run", attrs={"sampler": sampler}):
+            with _spans.span("decode/run",
+                             attrs={"sampler": sampler}) as run:
                 if tick is None:
                     tick = self._launch(slot_tokens, feed, sampler)
+                # the link across two steps: a tick found in flight was
+                # called under the step before
+                run.set_attr("call", tick.call)
                 toks = np.asarray(tick.toks)
                 sampled = {slot: int(toks[slot]) for slot in slot_tokens}
         except Exception as e:
             self._poison_on_donation_failure("decode", e, swapped=was_ahead)
             raise
+        # dispatch to tokens on the host: the tick's round trip
+        smetrics.m_decode_ms.observe(
+            (time.perf_counter_ns() - tick.t0) / 1e6)
         plans = self.next_tick is not None and self._ahead is None
         if plans:
             with _spans.span("decode/plan"):
@@ -1113,8 +1127,6 @@ class DecodeEngine:
         except Exception as e:
             self._poison_on_donation_failure("decode", e, swapped=was_ahead)
             raise
-        smetrics.m_decode_ms.observe(
-            (time.perf_counter_ns() - tick.t0) / 1e6)
         with _spans.span("decode/commit"):
             if not plans:
                 self._advance(tick, slot_tokens)
@@ -1138,15 +1150,18 @@ class DecodeEngine:
 
     def _launch(self, slot_tokens: Dict[int, int], feed, sampler: str,
                 ahead: bool = False) -> _Tick:
-        """Dispatch the decode executable; nothing here waits for it."""
+        """Dispatch the decode executable; nothing here waits for it.
+        ``decode/call`` is the engine's boundary with the device: the
+        executable's call and only that."""
+        exe = self._decode_exec()
         t0 = time.perf_counter_ns()
-        caches, (logits, toks, *report) = self._call(
-            self._decode_exec(), feed)
+        with _spans.span("decode/call", attrs={"exe": "decode"}) as call:
+            caches, (logits, toks, *report) = self._call(exe, feed)
         if ahead:
             self.cache.set_arrays(caches)
             caches = None
         return _Tick(dict(slot_tokens), caches, logits, toks, report,
-                     sampler, t0)
+                     sampler, t0, call.span_id)
 
     def _advance(self, tick: _Tick, slots) -> None:
         """A collected tick's rows become part of its riders' sequences."""
@@ -1250,8 +1265,9 @@ class DecodeEngine:
                         f"slot {slot}: no free pages for a {W}-token "
                         "verify window")
             self._masked_tables(windows, feed)
-            ck, cv, logits, toks = exe(
-                self.qparams, self.cache.k, self.cache.v, feed)
+            with _spans.span("decode/call", attrs={"exe": f"verify_w{W}"}):
+                ck, cv, logits, toks = exe(
+                    self.qparams, self.cache.k, self.cache.v, feed)
             logits = np.asarray(logits)
             toks = np.asarray(toks)
         except PagePoolFullError:

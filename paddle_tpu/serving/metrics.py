@@ -62,7 +62,8 @@ m_prefill_ms = _REG.histogram(
     "Prefill executable wall time (bucket-padded prompt), ms")
 m_decode_ms = _REG.histogram(
     "paddle_serve_decode_step_ms",
-    "Decode executable wall time (one token across the batch), ms")
+    "Decode tick round trip (the call's dispatch to its sampled tokens "
+    "on the host, one token across the batch), ms")
 m_evictions = _REG.counter(
     "paddle_serve_slot_evictions_total",
     "Decode-slot evictions by reason", ("reason",))

@@ -204,6 +204,9 @@ class Scheduler:
                        and not engine.ecfg.verify_window)
         self.early_dispatch: Dict[str, int] = {}   # outcome -> ticks
         self._settling = False
+        # [first step, start (clock_ns), steps] of the stretch of steps
+        # that found work pending and could do none, while it lasts
+        self._stall: Optional[List[int]] = None
         self._ttft_hist = smetrics.m_ttft_ms.labels("prefill", self.role)
         self._tpot_hist = smetrics.m_tpot_ms.labels("decode", self.role)
 
@@ -339,6 +342,9 @@ class Scheduler:
     def step(self) -> bool:
         """One serving tick: evict -> admit -> decode. Returns True when
         any work happened (False = idle, the loop may sleep)."""
+        if self.stalled_step():
+            return False
+        self._end_stall()
         attrs = {"step": self.steps}
         # the engine's spans (serve/prefill) name the step they ran in
         self.engine.sched_step = self.steps
@@ -349,15 +355,53 @@ class Scheduler:
             ingested = 0 if self._settling else self._ingest_handoffs(now)
             admitted = 0 if self._settling else self._admit(now)
             decoded = self._decode(now)
-            self.steps += 1
-            occ = self.engine.cache.occupancy
-            self.occupancy_sum += occ
-            smetrics.m_occupancy.set(occ)
-            smetrics.m_active.set(len(self._active))
+            self._count_step()
             worked = bool(ingested or admitted or decoded)
             attrs.update(worked=worked, prefills=admitted,
                          active=len(self._active))
         return worked
+
+    def _count_step(self) -> None:
+        self.steps += 1
+        occ = self.engine.cache.occupancy
+        self.occupancy_sum += occ
+        smetrics.m_occupancy.set(occ)
+        smetrics.m_active.set(len(self._active))
+
+    def stalled_step(self) -> bool:
+        """Count a step that can do nothing, where this one is such:
+        requests are queued, and nothing rides, nothing is in flight, no
+        handoff waits, no queued request is overdue and none can be
+        admitted. True where it was; nothing else of the step is run
+        then. A whole stretch of such steps (500 a second from
+        ``EngineLoop``, for as long as a prompt waits for room that is
+        not freed) leaves ONE ``serve/step`` record when it ends, {step:
+        the first, steps: how many, worked: False}: it must not wash the
+        requests' records out of the ring."""
+        if (self._active or self._pending_handoffs
+                or (self._plain and self.engine.ahead_feed is not None)):
+            return False
+        now = time.monotonic()
+        with self._lock:
+            if (not self._queue
+                    or any(req.deadline <= now for req in self._queue)
+                    or (self.engine.cache.free_slot_count() > 0
+                        and self._first_admissible() is not None)):
+                return False
+        if self._stall is None:
+            self._stall = [self.steps, _spans.clock_ns(), 0]
+        self._stall[2] += 1
+        self._count_step()
+        return True
+
+    def _end_stall(self) -> None:
+        stall, self._stall = self._stall, None
+        if stall is not None:
+            first, t0, n = stall
+            _spans.record("serve/step", t0, _spans.clock_ns() - t0,
+                          trace=self.loop_trace,
+                          attrs={"step": first, "steps": n, "worked": False,
+                                 "prefills": 0, "active": 0})
 
     def _ingest_handoffs(self, now: float) -> int:
         """Adopt migrated requests' KV payloads into the cache — at the
@@ -422,6 +466,7 @@ class Scheduler:
         """Collect the tick in flight, if there is one: hand its tokens
         out and dispatch no other (loop thread only: what a loop does as
         it stops). Returns whether there was one."""
+        self._end_stall()
         if not self._plain or self.engine.ahead_feed is None:
             return False
         self._settling = True

@@ -155,11 +155,15 @@ class EngineLoop:
                 break
             worked = False
             if self.scheduler.pending():
-                if idle is not None:
-                    idle.__exit__(None, None, None)
-                    idle = None
                 try:
-                    worked = self.scheduler.step()
+                    # a step that can do nothing is counted and parked
+                    # under the idle span that is open: no record a
+                    # step, none a park
+                    if not self.scheduler.stalled_step():
+                        if idle is not None:
+                            idle.__exit__(None, None, None)
+                            idle = None
+                        worked = self.scheduler.step()
                 except Exception as e:
                     self.faults += 1
                     self.last_fault = f"{type(e).__name__}: {e}"

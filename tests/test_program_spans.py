@@ -112,8 +112,9 @@ def test_nothing_recorded_or_annotated_while_tracing_is_off(tracer,
     made = []
     real = spans._annotation_class()
     assert real is jax.profiler.TraceAnnotation
-    monkeypatch.setattr(spans, "_annotation",
-                        lambda name: made.append(name) or real(name))
+    monkeypatch.setattr(
+        spans, "_annotation",
+        lambda name, **kw: made.append((name, kw)) or real(name, **kw))
     spans.set_tracing_enabled(False)
     with spans.span("off") as sp:
         sp.set_attr("a", 1)
@@ -122,8 +123,10 @@ def test_nothing_recorded_or_annotated_while_tracing_is_off(tracer,
     spans.set_tracing_enabled(True)
     with spans.span("on"):
         pass
-    assert made == ["paddle/on"]
-    assert [s["name"] for s in tracer.spans()] == ["on"]
+    # the annotation carries the record's id, and nothing else
+    (on,) = tracer.spans()
+    assert on["name"] == "on"
+    assert made == [("paddle/on", {"span": on["span"]})]
 
 
 def test_tracing_loads_no_jax_into_a_process_that_has_none():
@@ -181,6 +184,11 @@ AHEAD_PHASES = ["decode/run", "decode/plan", "decode/fetch_logits",
                 "decode/commit"]
 PREFILL_PHASES = ["prefill/prep", "prefill/run", "prefill/fetch_logits",
                   "prefill/publish"]
+# the executable's call alone, the engine's boundary with the device: a
+# grandchild, under the run of a tick the step fed, under the plan that
+# dispatched a tick ahead, under a prefill's run
+CALLS = {"decode/call": {"decode/run", "decode/plan"},
+         "prefill/call": {"prefill/run"}}
 
 
 def test_span_tree_of_a_step_on_a_paged_engine(tracer, paged_engine,
@@ -204,8 +212,12 @@ def test_span_tree_of_a_step_on_a_paged_engine(tracer, paged_engine,
     assert [s["attrs"]["step"] for s in steps] == list(range(sched.steps))
     assert all(s["parent"] is None for s in steps)
     assert {s["name"] for s in loop} == (
-        {"serve/step"} | STEP_CHILDREN | set(TICK_PHASES + AHEAD_PHASES))
+        {"serve/step", "decode/call"} | STEP_CHILDREN
+        | set(TICK_PHASES + AHEAD_PHASES))
     for s in loop:
+        if s["name"] in CALLS:
+            assert by_id[s["parent"]]["name"] in CALLS[s["name"]]
+            assert not [k for k in ss if k["parent"] == s["span"]]
         if s["name"] in STEP_CHILDREN:
             assert by_id[s["parent"]]["name"] == "serve/step"
         if s["name"] in TICK_PHASES:
@@ -258,7 +270,7 @@ def test_span_tree_of_a_step_on_a_paged_engine(tracer, paged_engine,
         fam = tracer.trace_spans(req.trace_id)
         assert sorted({s["name"] for s in fam}) == sorted(
             ["serve/request", "serve/queue_wait", "serve/prefill",
-             "serve/evict"] + PREFILL_PHASES)
+             "serve/evict", "prefill/call"] + PREFILL_PHASES)
         pre = next(s for s in fam if s["name"] == "serve/prefill")
         assert pre["parent"] == req.root_span
         assert pre["attrs"] == {"prompt_len": len(req.prompt), "step": 0,
@@ -268,6 +280,12 @@ def test_span_tree_of_a_step_on_a_paged_engine(tracer, paged_engine,
         kids = [s for s in fam if s["parent"] == pre["span"]]
         assert [k["name"] for k in kids] == PREFILL_PHASES
         assert sum(k["dur_ns"] for k in kids) <= pre["dur_ns"]
+        # once a prefill, a leaf of its run, named by it
+        (call,) = [s for s in fam if s["name"] == "prefill/call"]
+        assert by_id[call["parent"]]["name"] == "prefill/run"
+        assert by_id[call["parent"]]["parent"] == pre["span"]
+        assert call["attrs"] == {"exe": "prefill_b8"}
+        assert by_id[call["parent"]]["attrs"]["call"] == call["span"]
         # inside the step's serve/admit in time, though on its own trace
         assert admit["start_ns"] <= pre["start_ns"] and (
             pre["start_ns"] + pre["dur_ns"]
@@ -277,6 +295,144 @@ def test_span_tree_of_a_step_on_a_paged_engine(tracer, paged_engine,
         assert evict["attrs"]["reason"] == "done"
     # at most 12 records a tick from the loop, prefills and requests aside
     assert len(loop) / len(ticks) <= 12
+
+
+def _tick_records(ss):
+    """(serve/decode_tick, its decode/run) pairs in step order, and the
+    decode/call records by id."""
+    calls = {s["span"]: s for s in ss if s["name"] == "decode/call"}
+    ticks = sorted((s for s in ss if s["name"] == "serve/decode_tick"),
+                   key=lambda s: s["attrs"]["step"])
+    runs = {s["parent"]: s for s in ss if s["name"] == "decode/run"}
+    return [(t, runs[t["span"]]) for t in ticks], calls
+
+
+def test_a_tick_remembers_the_call_that_dispatched_it(tracer, paged_engine):
+    sched, _ = _serve(paged_engine, [[1, 2, 3, 4, 5], [6, 7, 8]], max_new=5)
+    ss = tracer.spans()
+    by_id = {s["span"]: s for s in ss}
+    pairs, calls = _tick_records(ss)
+    # exactly one decode/call a dispatched tick: four collected, and the
+    # last tick's plan found its riders finishing and dispatched none
+    assert len(calls) == len(pairs) == 4
+    assert all(c["attrs"] == {"exe": "decode"} for c in calls.values())
+    named = [run["attrs"]["call"] for _, run in pairs]
+    assert sorted(named) == sorted(calls)
+    step_of = {}
+    for s in ss:
+        if s["name"] == "serve/step":
+            step_of[s["span"]] = s["attrs"]["step"]
+
+    def step(rec):
+        while rec["span"] not in step_of:
+            rec = by_id[rec["parent"]]
+        return step_of[rec["span"]]
+
+    for tick, run in pairs:
+        call = calls[run["attrs"]["call"]]
+        holder = by_id[call["parent"]]
+        if tick["attrs"]["ahead"]:
+            # called one step earlier, under that step's plan: the
+            # attribute is the link across the two steps
+            assert holder["name"] == "decode/plan"
+            assert step(call) == tick["attrs"]["step"] - 1
+            assert call["start_ns"] + call["dur_ns"] <= run["start_ns"]
+        else:
+            # a tick the step fed itself: the call is inside its own run
+            assert holder["span"] == run["span"]
+            assert run["start_ns"] <= call["start_ns"]
+        # the round trip, call's start to the tokens on the host, holds
+        # the call
+        assert (call["start_ns"] + call["dur_ns"]
+                <= run["start_ns"] + run["dur_ns"])
+    assert [t["attrs"]["ahead"] for t, _ in pairs] == [False, True, True,
+                                                       True]
+
+
+def test_a_dropped_ticks_call_is_named_by_no_run(tracer, paged_engine):
+    sched = serving.Scheduler(paged_engine)
+    req = sched.submit([1, 2, 3], max_new_tokens=8)
+    sched.step()                       # prefill, a tick, the next ahead
+    assert paged_engine.ahead_feed is not None
+    sched.abort_all("gone")            # drops the tick in flight
+    assert paged_engine.ahead_feed is None and req.state == "failed"
+    ss = tracer.spans()
+    calls = [s["span"] for s in ss if s["name"] == "decode/call"]
+    named = [s["attrs"]["call"] for s in ss if s["name"] == "decode/run"]
+    assert len(calls) == 2 and named == calls[:1]
+
+
+def test_the_replayed_resume_and_the_verify_window_call_under_their_own_parent(
+        tracer):
+    cfg = gpt.GPT_TINY.scaled(num_layers=1, max_seq_len=64)
+    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    eng = serving.DecodeEngine(params, cfg, serving.EngineConfig(
+        max_batch=2, max_seq=32, prefill_buckets=(8,), page_size=8,
+        verify_window=2))
+    # a stream longer than the ladder: the head prefills, the tail
+    # replays through the decode executable under a second serve/prefill
+    slot, _, _ = eng.resume_sequence_sampled(list(range(1, 11)),
+                                             serving.SamplingParams())
+    eng.verify_step({slot: [3, 4]})
+    ss = tracer.spans()
+    by_id = {s["span"]: s for s in ss}
+    got = [(s["name"], s["attrs"]["exe"]) for s in ss
+           if s["name"] in CALLS]
+    assert got == [("prefill/call", "prefill_b8"),
+                   ("decode/call", "decode"), ("decode/call", "decode"),
+                   ("decode/call", "verify_w2")]
+    replayed = [s for s in ss if s["name"] == "decode/call"
+                and s["attrs"]["exe"] == "decode"]
+    for call in replayed:
+        run = by_id[call["parent"]]
+        assert run["name"] == "decode/run"
+        assert run["attrs"]["call"] == call["span"]
+        assert by_id[run["parent"]]["attrs"]["replayed"] == 2
+
+
+def test_no_call_span_and_no_link_while_tracing_is_off(tracer, paged_engine):
+    spans.set_tracing_enabled(False)
+    sched, _ = _serve(paged_engine, [[1, 2, 3]], max_new=3)
+    assert not tracer.spans()
+    spans.set_tracing_enabled(True)
+    # a tick dispatched while tracing was off has no call to name
+    sched = serving.Scheduler(paged_engine)
+    sched.submit([1, 2, 3], max_new_tokens=4)
+    spans.set_tracing_enabled(False)
+    sched.step()
+    spans.set_tracing_enabled(True)
+    sched.step()
+    run = next(s for s in tracer.spans() if s["name"] == "decode/run")
+    assert run["attrs"]["call"] is None
+    for _ in range(8):
+        sched.step()
+
+
+def test_decode_step_ms_is_a_ticks_round_trip(tracer, paged_engine,
+                                              monkeypatch):
+    """``paddle_serve_decode_step_ms``: dispatch to tokens on the host,
+    observed where ``decode/run`` ends: not the logits' fetch, and for a
+    tick found in flight not the plan that follows."""
+    from paddle_tpu.serving import metrics as smetrics
+
+    seen = []
+    real = smetrics.m_decode_ms.observe
+
+    class _Probe:
+        @staticmethod
+        def observe(ms):
+            # where the ring stands when the histogram hears of a tick
+            seen.append((ms, [s["name"] for s in tracer.spans()][-1]))
+            real(ms)
+
+    monkeypatch.setattr(smetrics, "m_decode_ms", _Probe)
+    _serve(paged_engine, [[1, 2, 3]], max_new=4)
+    assert len(seen) == 3 and {last for _, last in seen} == {"decode/run"}
+    pairs, calls = _tick_records(tracer.spans())
+    for (ms, _), (_, run) in zip(seen, pairs):
+        call = calls[run["attrs"]["call"]]
+        trip = (run["start_ns"] + run["dur_ns"] - call["start_ns"]) / 1e6
+        assert trip <= ms < trip + 1.0
 
 
 def test_from_a_slow_request_to_the_ticks_it_rode(tracer, paged_engine):
@@ -370,6 +526,65 @@ def test_engine_loop_parks_under_one_idle_span(tracer, paged_engine):
                for s in steps)
 
 
+def test_a_loop_that_can_do_nothing_leaves_one_record_a_stretch(
+        tracer, paged_engine, monkeypatch):
+    sched = serving.Scheduler(paged_engine)
+    monkeypatch.setattr(paged_engine, "can_admit", lambda n: False)
+    req = sched.submit([1, 2, 3], max_new_tokens=2)
+    before = len(tracer.spans())
+    occ = paged_engine.cache.occupancy
+    assert [sched.step() for _ in range(1000)] == [False] * 1000
+    # counted as ever, and nothing in the ring while the stretch lasts
+    assert sched.steps == 1000 and sched.pending() == 1
+    assert sched.occupancy_sum == pytest.approx(1000 * occ)
+    assert len(tracer.spans()) == before
+    monkeypatch.undo()
+    for _ in range(8):
+        sched.step()
+    assert req.state == "done"
+    steps = [s for s in tracer.spans() if s["name"] == "serve/step"]
+    assert steps[0]["attrs"] == {"step": 0, "steps": 1000, "worked": False,
+                                 "prefills": 0, "active": 0}
+    assert steps[0]["trace"] == sched.loop_trace
+    assert steps[0]["parent"] is None
+    assert steps[1]["attrs"]["step"] == 1000 and steps[1]["attrs"]["worked"]
+    assert steps[0]["start_ns"] + steps[0]["dur_ns"] <= steps[1]["start_ns"]
+    # the ring grew by the stretch's one record and the request's own
+    assert sum(s["start_ns"] < steps[1]["start_ns"]
+               for s in tracer.spans()) - before <= 3
+    # a queued request that is overdue is the full step's to expire
+    late = sched.submit([1, 2], max_new_tokens=2, timeout_s=0.0)
+    monkeypatch.setattr(paged_engine, "can_admit", lambda n: False)
+    assert sched.stalled_step() is False
+    sched.step()
+    assert late.state == "expired"
+
+
+def test_engine_loop_keeps_one_idle_span_over_steps_that_can_do_nothing(
+        tracer, paged_engine, monkeypatch):
+    sched = serving.Scheduler(paged_engine)
+    monkeypatch.setattr(paged_engine, "can_admit", lambda n: False)
+    loop = EngineLoop(sched, idle_sleep_s=0.001).start()
+    try:
+        req = sched.submit([1, 2, 3], max_new_tokens=2)
+        loop.wake()
+        time.sleep(0.1)                  # some hundred steps, each parked
+        stalled = sched.steps
+        before = [s["name"] for s in tracer.spans()]
+        monkeypatch.undo()
+        assert req.wait(timeout=30) and req.state == "done"
+    finally:
+        loop.stop()
+    assert stalled >= 20
+    assert before.count("serve/loop_idle") <= 1 and "serve/step" not in before
+    ss = tracer.spans()
+    idle = [s for s in ss if s["name"] == "serve/loop_idle"]
+    assert len(idle) <= 3 and max(s["dur_ns"] for s in idle) >= 0.08e9
+    first = min((s for s in ss if s["name"] == "serve/step"),
+                key=lambda s: s["start_ns"])
+    assert first["attrs"]["steps"] >= 20 and not first["attrs"]["worked"]
+
+
 # ---------------------------------------------------------------------------
 # the train step
 # ---------------------------------------------------------------------------
@@ -429,7 +644,7 @@ def _host_events(trace_dir, prefix):
             for ev in line.events:
                 if ev.name.startswith(prefix):
                     out.append((ev.name, int(ev.start_ns),
-                                int(ev.duration_ns)))
+                                int(ev.duration_ns), dict(ev.stats)))
     return sorted(out, key=lambda e: e[1])
 
 
@@ -460,6 +675,17 @@ def test_a_profiler_capture_holds_the_spans_where_the_ring_has_them(
         got = [e for e in events if e[0] == "paddle/" + name]
         want = [s for s in ring if s["name"] == name]
         assert len(got) == len(want) >= 2
-        for (_, start, dur), rec in zip(got, want):
+        for (_, start, dur, _), rec in zip(got, want):
             assert abs(start - offset - rec["start_ns"]) < 1e6, name
             assert abs(dur - rec["dur_ns"]) < 1e6, name
+    # every annotation carries its record's id and nothing else, so the
+    # attributes, the parent and the trace are one lookup away; what the
+    # ring holds without an annotation was timed elsewhere
+    by_id = {s["span"]: s for s in ring}
+    assert all(set(stats) == {"span"} for _, _, _, stats in events)
+    assert len({stats["span"] for _, _, _, stats in events}) == len(events)
+    for name, _, _, stats in events:
+        assert "paddle/" + by_id[stats["span"]]["name"] == name
+    assert {"paddle/decode/call", "paddle/prefill/call"} <= names
+    bare = {s["name"] for s in ring} - {n[len("paddle/"):] for n in names}
+    assert bare == {"serve/request", "serve/queue_wait"}
