@@ -885,10 +885,15 @@ class DecodeEngine:
                  "scan_tokens": n if self.model.recurrent else 0,
                  "delta_chunks": chunks(n) if chunks else 0}
         with _spans.span("serve/prefill", attrs=attrs):
-            with _spans.span("prefill/prep"):
+            with _spans.span("prefill/prep") as prep:
                 prefix_len, prefix_pages = 0, ()
                 if self.prefix is not None:
-                    prefix_len, prefix_pages = self.prefix.lookup(tokens)
+                    # the prompt is hashed once: the publication below
+                    # takes the keys the lookup was made with
+                    keys = self.prefix.page_keys(tokens)
+                    prep.set_attr("prefix_pages_hashed", len(keys))
+                    prefix_len, prefix_pages = self.prefix.lookup(
+                        tokens, keys)
                     prefix_len, prefix_pages = self._trim_prefix(
                         n, prefix_len, tuple(prefix_pages))
                 suffix = list(tokens[prefix_len:])
@@ -905,10 +910,14 @@ class DecodeEngine:
                 attrs.update(
                     expert_tokens=self.last_expert_load["expert_tokens"],
                     experts_hit=self.last_expert_load["experts_hit"])
-            with _spans.span("prefill/publish"):
+            with _spans.span("prefill/publish") as publish:
                 self.cache.set_arrays(caches)
                 if self.prefix is not None:
-                    added = self.prefix.insert(tokens, table_row)
+                    evicted = self.prefix.evicted
+                    added = self.prefix.insert(tokens, table_row, keys)
+                    publish.set_attr("prefix_added", added)
+                    publish.set_attr("prefix_evicted",
+                                     self.prefix.evicted - evicted)
                     if added and self.prefix_store is not None:
                         # persist at publish time: the pages just written are
                         # the ones a recycled replica restores (async,

@@ -25,8 +25,9 @@ Layout rules:
   lands at positions ``>= prefix_len``, i.e. in pages it owns alone —
   no copy-on-write machinery needed.
 
-The **prefix cache** keys page-aligned token prefixes by content hash
-(exact token match verified — hashes only narrow the lookup): after a
+The **prefix cache** keys page-aligned token prefixes by a chained
+content hash, a page a link (:class:`PrefixCache` says what the key is
+and what each call costs): after a
 prompt prefill, its full pages are published under every page-boundary
 prefix; a later prompt sharing the prefix attaches those pages by
 refcount and prefills only its suffix through the continuation-prefill
@@ -553,6 +554,19 @@ class PagedKVCache:
         smetrics.m_page_fragmentation.set(self.fragmentation())
 
 
+class _Chain:
+    """The pages one publication mapped, shared by the entries it made:
+    entry ``j`` maps ``pages[:j]``, so together they hold
+    ``pages[:top]``, ``top`` the longest of them still in the cache."""
+
+    __slots__ = ("pages", "live", "top")
+
+    def __init__(self, pages: Tuple[int, ...]):
+        self.pages = pages
+        self.live: set = set()
+        self.top = 0
+
+
 class PrefixCache:
     """Token-hash keyed, refcounted, LRU prefix cache over a page pool.
 
@@ -560,63 +574,93 @@ class PrefixCache:
     every page of every entry (slots using the pages hold their own).
     ``capacity_pages`` bounds distinct cache-held pages; LRU entries are
     dropped on overflow and under pool pressure (:meth:`reclaim`: the
-    engine sets this cache as the pool's ``prefix_cache``)."""
+    engine sets this cache as the pool's ``prefix_cache``).
+
+    **The key** of the prefix that ends with page ``j`` is chained:
+    ``key_j = SHA-256(key_{j-1} || page j's tokens as int64)``,
+    ``key_0`` empty, so one pass over a prompt (:meth:`page_keys`) names
+    every one of its page boundaries. The digest IS the prefix: no entry
+    keeps tokens to compare, and a collision of two 256-bit digests is
+    not handled (it would hand one prompt another's pages), as in the
+    common serving stacks.
+
+    **An entry** is ``(chain, j)``: the :class:`_Chain` of the
+    publication that made it, whose one page tuple all its entries
+    share, and how many of those pages the entry maps. ``_held`` counts,
+    a page, the chains that hold it; the cache's pool reference on a
+    page lives while that count does.
+
+    **What a call costs**, in pages ``P`` of the prompt it is given,
+    whatever the cache holds: :meth:`page_keys` ``P`` hashes of a page;
+    :meth:`lookup`, :meth:`has`, :meth:`insert`, :meth:`adopt_nested`
+    the keys (unless handed them) and ``P`` probes; dropping an entry
+    O(1) and the pages its chain lets go of, each once a chain;
+    :meth:`held_page_count` O(1); :meth:`reclaimable` the held pages
+    (at most ``capacity_pages``)."""
 
     def __init__(self, pool: PagedKVCache, capacity_pages: int = 0):
         self.pool = pool
         self.capacity_pages = int(capacity_pages) or pool.num_pages
-        # insertion/use-ordered: key -> (tokens tuple, pages tuple)
-        self._entries: "OrderedDict[bytes, Tuple[Tuple[int, ...], Tuple[int, ...]]]" = OrderedDict()
+        # insertion/use-ordered: key -> (chain, pages of it mapped)
+        self._entries: "OrderedDict[bytes, Tuple[_Chain, int]]" = \
+            OrderedDict()
+        self._held: Dict[int, int] = {}     # page -> chains holding it
         self.hits = 0
         self.misses = 0
+        self.evicted = 0                    # entries dropped, ever
 
-    @staticmethod
-    def _key(tokens: Sequence[int]) -> bytes:
-        return hashlib.sha1(
-            np.asarray(tokens, np.int64).tobytes()).digest()
-
-    def _held_pages(self) -> set:
-        held = set()
-        for _, pages in self._entries.values():
-            held.update(pages)
-        return held
+    def page_keys(self, tokens: Sequence[int]) -> List[bytes]:
+        """The key of every page-boundary prefix of ``tokens``, shortest
+        first: ``keys[j - 1]`` names ``tokens[:j * page_size]``. What
+        :meth:`lookup` and :meth:`insert` take as ``keys``, so that a
+        prefill hashes its prompt once."""
+        ps = self.pool.page_size
+        full = len(tokens) // ps
+        buf = np.asarray(tokens[:full * ps], np.int64).tobytes()
+        step = 8 * ps
+        keys, key = [], b""
+        for i in range(0, len(buf), step):
+            key = hashlib.sha256(key + buf[i:i + step]).digest()
+            keys.append(key)
+        return keys
 
     def held_page_count(self) -> int:
-        return len(self._held_pages())
+        return len(self._held)
 
     def reclaimable(self) -> int:
         """Pages that a full reclaim could hand back to the pool (those
         only the cache still holds)."""
-        n = 0
-        for p in self._held_pages():
-            if self.pool._ref[p] == 1:
-                n += 1
-        return n
+        if not self._held:
+            return 0
+        return int(np.count_nonzero(self.pool._ref[list(self._held)] == 1))
 
     def has(self, tokens: Sequence[int]) -> bool:
         """Exact-entry probe WITHOUT metric counts or LRU freshening —
         the disagg prefix-index's "is it already local?" check."""
-        key = self._key(tuple(int(t) for t in tokens))
-        ent = self._entries.get(key)
-        return ent is not None and ent[0] == tuple(int(t) for t in tokens)
+        if not len(tokens) or len(tokens) % self.pool.page_size:
+            return False
+        return self.page_keys(tokens)[-1] in self._entries
 
-    def lookup(self, tokens: Sequence[int]
+    def lookup(self, tokens: Sequence[int],
+               keys: Optional[List[bytes]] = None
                ) -> Tuple[int, Tuple[int, ...]]:
         """Longest cached page-aligned prefix of ``tokens`` that still
         leaves at least one suffix token to prefill. Returns
         ``(prefix_len, pages)`` — (0, ()) on miss. Counts the
-        hit/miss metric and freshens LRU order on hit."""
+        hit/miss metric and freshens LRU order on hit. Probes from the
+        longest boundary down: LRU drops a prompt's short entries before
+        its long ones, and a long one is a hit without them."""
+        if keys is None:
+            keys = self.page_keys(tokens)
         ps = self.pool.page_size
-        max_j = (len(tokens) - 1) // ps
-        for j in range(max_j, 0, -1):
-            prefix = tuple(int(t) for t in tokens[:j * ps])
-            key = self._key(prefix)
+        for j in range((len(tokens) - 1) // ps, 0, -1):
+            key = keys[j - 1]
             ent = self._entries.get(key)
-            if ent is not None and ent[0] == prefix:
+            if ent is not None:
                 self._entries.move_to_end(key)
                 self.hits += 1
                 smetrics.m_prefix_cache.labels("hit").inc()
-                return j * ps, ent[1]
+                return j * ps, ent[0].pages[:j]
         self.misses += 1
         smetrics.m_prefix_cache.labels("miss").inc()
         return 0, ()
@@ -634,55 +678,78 @@ class PrefixCache:
         pages = tuple(int(p) for p in pages)
         if len(tokens) < len(pages) * ps:
             raise ValueError("adopted pages cover more than the tokens")
-        registered = 0
-        for j in range(1, len(pages) + 1):
-            prefix = tuple(int(t) for t in tokens[:j * ps])
-            key = self._key(prefix)
-            if key in self._entries:
-                continue
-            self._entries[key] = (prefix, pages[:j])
-            registered += 1
+        registered, _fresh = self._publish(
+            self.page_keys(tokens[:len(pages) * ps]), pages, touch=False)
         self._evict_over_capacity()
         return registered
 
-    def insert(self, tokens: Sequence[int], table_row: np.ndarray) -> int:
+    def insert(self, tokens: Sequence[int], table_row: np.ndarray,
+               keys: Optional[List[bytes]] = None) -> int:
         """Publish every page-boundary prefix of ``tokens`` whose pages
         are in ``table_row`` (the slot's mapping after prefill). Returns
         how many NEW entries were added. New pages get one cache ref."""
-        ps = self.pool.page_size
-        full = len(tokens) // ps
-        added = 0
-        newly_held = []
-        held = self._held_pages()
-        for j in range(1, full + 1):
-            prefix = tuple(int(t) for t in tokens[:j * ps])
-            key = self._key(prefix)
-            if key in self._entries:
-                self._entries.move_to_end(key)
-                continue
-            pages = tuple(int(p) for p in table_row[:j])
-            if any(p == 0 for p in pages):
-                break                      # unmapped — nothing cacheable
-            self._entries[key] = (prefix, pages)
-            added += 1
-            for p in pages:
-                if p not in held:
-                    held.add(p)
-                    newly_held.append(p)
-        if newly_held:
-            self.pool.ref_pages(newly_held)
+        if keys is None:
+            keys = self.page_keys(tokens)
+        added, fresh = self._publish(
+            keys, tuple(int(p) for p in table_row[:len(keys)]), touch=True)
+        if fresh:
+            self.pool.ref_pages(fresh)
         self._evict_over_capacity()
         return added
 
-    def _drop_entry(self, key: bytes) -> None:
-        _tokens, pages = self._entries.pop(key)
-        still_held = self._held_pages()
-        self.pool.deref_pages([p for p in pages if p not in still_held])
+    def _publish(self, keys: List[bytes], pages: Tuple[int, ...],
+                 touch: bool) -> Tuple[int, List[int]]:
+        """One chain over ``pages`` and an entry of it under every key
+        the cache lacks (a key it has is freshened if ``touch``), up to
+        the first unmapped page. Returns how many entries were made and
+        the pages no chain held before."""
+        chain = _Chain(pages)
+        mapped = pages.index(0) if 0 in pages else len(pages)
+        for j, key in enumerate(keys, 1):
+            if key in self._entries:
+                if touch:
+                    self._entries.move_to_end(key)
+                continue
+            if j > mapped:
+                break                      # unmapped — nothing cacheable
+            self._entries[key] = (chain, j)
+            chain.live.add(j)
+            chain.top = j
+        fresh = []
+        for p in pages[:chain.top]:
+            n = self._held.get(p, 0)
+            if not n:
+                fresh.append(p)
+            self._held[p] = n + 1
+        return len(chain.live), fresh
+
+    def _drop_oldest(self) -> None:
+        """Drop the least recently used entry; the pages its chain held
+        for it alone go back to the pool unless another chain holds
+        them."""
+        _key, (chain, j) = self._entries.popitem(last=False)
+        self.evicted += 1
+        chain.live.remove(j)
+        if j < chain.top:
+            return                         # a longer entry holds them all
+        top = j - 1
+        while top and top not in chain.live:
+            top -= 1
+        chain.top = top
+        gone = []
+        for p in chain.pages[top:j]:
+            n = self._held[p] - 1
+            if n:
+                self._held[p] = n
+            else:
+                del self._held[p]
+                gone.append(p)
+        if gone:
+            self.pool.deref_pages(gone)
 
     def _evict_over_capacity(self) -> None:
-        while (self._entries
-               and self.held_page_count() > self.capacity_pages):
-            self._drop_entry(next(iter(self._entries)))
+        while self._entries and len(self._held) > self.capacity_pages:
+            self._drop_oldest()
 
     def reclaim(self, n_pages: int) -> int:
         """Pool-pressure hook: drop LRU entries until ``n_pages`` pages
@@ -691,12 +758,12 @@ class PrefixCache:
         freed0 = self.pool.free_page_count()
         while (self._entries
                and self.pool.free_page_count() - freed0 < n_pages):
-            self._drop_entry(next(iter(self._entries)))
+            self._drop_oldest()
         return self.pool.free_page_count() - freed0
 
     def clear(self) -> None:
         while self._entries:
-            self._drop_entry(next(iter(self._entries)))
+            self._drop_oldest()
 
     def __len__(self) -> int:
         return len(self._entries)

@@ -161,7 +161,7 @@ class PrefixStore:
             n_pages = k_pages.shape[1]
             if (shape_tail != expect or k_pages.shape != v_pages.shape
                     or n_pages * pool.page_size != len(tokens)
-                    or cache._key(tokens) in cache._entries
+                    or cache.has(tokens)
                     or pool.free_page_count() <= n_pages):
                 # geometry drift / duplicate / pool too tight (leave at
                 # least one free page for live traffic) — skip cleanly
