@@ -353,7 +353,10 @@ class JambaServing:
     alone, ``conv [Lm, slots, (d_conv - 1) * Di]`` in the cache's dtype and
     ``ssm [Lm, slots, N, Di]`` float32."""
     recurrent = True
-    paged_kernel = False             # one key/value head: the tick gathers
+    # 20 query heads over one key/value head: a group of 20 is no whole
+    # sublane tile, so ``pallas_kernels.paged_decode_kernel`` (the one rule)
+    # names no kernel for it and the tick gathers
+    paged_kernel = False
     max_positions = None             # no positional table bounds max_seq
 
     def __init__(self, cfg: JambaConfig):

@@ -20,12 +20,14 @@ from __future__ import annotations
 import math
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 __all__ = ["decode_attention", "prefill_attention", "paged_gather",
            "paged_cache_update", "paged_page_write",
            "paged_prefill_attention", "window_attention",
-           "latent_decode_attention"]
+           "latent_decode_attention", "band_prefill_attention",
+           "sliding_decode_attention"]
 
 
 def decode_attention(q, k_cache, v_cache, lengths,
@@ -108,6 +110,76 @@ def _grouped_attention(q, k, v, mask, sm_scale):
     probs = e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
     out = jnp.einsum("bkgts,bskh->btkgh", probs, v.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+_BAND_QUERY_BLOCK = 256
+
+
+def band_prefill_attention(q, k, v, window: Optional[int] = None,
+                           sm_scale: Optional[float] = None):
+    """Causal self-attention of a rung inside a band, grouped heads: q
+    ``[T, nh, hd]``, k, v ``[T, kvh, hd]`` -> ``[T, nh, hd]``; query ``i``
+    sees keys ``i - window < j <= i`` (``window`` None: every ``j <= i``).
+    A block of queries at a time (``[kvh, g, block, T]`` float32 scores,
+    never ``[nh, T, T]``). The lowering of
+    ``pallas_kernels.band_flash_attention`` off the TPU, and its parity
+    reference."""
+    T, nh, hd = q.shape
+    kvh = k.shape[1]
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(hd)
+    blk = _BAND_QUERY_BLOCK if T % _BAND_QUERY_BLOCK == 0 else T
+    keys = jnp.arange(T)[None, :]
+
+    def one(i):
+        rows = i * blk + jnp.arange(blk)[:, None]
+        seen = keys <= rows
+        if window is not None:
+            seen &= rows - keys < window
+        qb = jax.lax.dynamic_slice_in_dim(q, i * blk, blk, 0)
+        return _grouped_attention(
+            qb.reshape(1, blk, kvh, nh // kvh, hd), k[None], v[None],
+            seen[None, None, None], sm_scale)[0]
+
+    out = jax.lax.map(one, jnp.arange(T // blk))
+    return out.reshape(T, nh, hd)
+
+
+def sliding_decode_attention(q, k_rows, v_rows, positions, kv_heads: int,
+                             page: int, window: Optional[int] = None,
+                             ring: bool = False,
+                             sm_scale: Optional[float] = None):
+    """One-token grouped-query attention over a gathered view of a page
+    group, with a lower bound: the lowering of
+    ``pallas_kernels.gqa_paged_decode_attention`` off the TPU, and its
+    parity reference.
+
+    q ``[B, H, hd]``; k_rows/v_rows ``[B, M * page, kv_heads * hd]``
+    (:func:`paged_gather` of the slot's table: every logical page in
+    order, or with ``ring`` a ring of ``M`` entries, logical page ``j`` at
+    entry ``j % M``), this tick's row already written; positions ``[B]``:
+    the row attends ``(position - window, position]`` (``window`` None:
+    ``[0, position]``). Returns ``[B, H, hd]``."""
+    B, H, hd = q.shape
+    S = k_rows.shape[1]
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(hd)
+    at = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
+    if ring:
+        # the row a ring entry holds now: the newest logical page at it
+        M = S // page
+        cur = (positions // page)[:, None]
+        entry = at // page
+        at = (cur - (cur - entry) % M) * page + at % page
+    seen = (at >= 0) & (at <= positions[:, None])
+    if window is not None:
+        seen &= at > positions[:, None] - window
+    out = _grouped_attention(
+        q.reshape(B, 1, kv_heads, H // kv_heads, hd),
+        k_rows.reshape(B, S, kv_heads, hd),
+        v_rows.reshape(B, S, kv_heads, hd),
+        seen[:, None, None, None], sm_scale)
+    return out.reshape(B, H, hd)
 
 
 def paged_gather(pool, tables, layer=None):
@@ -213,7 +285,10 @@ def paged_prefill_attention(q, k_all, v_all, prefix_len,
 
 def window_attention(q, k_cache, v_cache, starts,
                      sm_scale: Optional[float] = None):
-    """W-query attention over the cache (speculative-verify window).
+    """W-query attention over the cache (speculative-verify window: the
+    window of speculative decoding, NOT a sliding window of keys; those
+    paths are :func:`band_prefill_attention` and
+    :func:`sliding_decode_attention`).
 
     q:        [B, W, nh, hd]  — window queries; query w sits at global
                                position ``starts[b] + w``

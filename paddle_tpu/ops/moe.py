@@ -178,7 +178,8 @@ def _round_up(n: int, to: int) -> int:
 
 def expert_share(x, valid, experts, weights, w_gate_up, w_down, *,
                  first_expert: int, layer=None,
-                 use_pallas: Optional[bool] = None
+                 use_pallas: Optional[bool] = None,
+                 small_rows: Optional[int] = None
                  ) -> Tuple[jax.Array, jax.Array]:
     """The held experts' part of ``sum_k w_k E_k(x)``.
 
@@ -186,7 +187,10 @@ def expert_share(x, valid, experts, weights, w_gate_up, w_down, *,
     lanes take no part and are not counted); experts/weights ``[T, k]``
     from :func:`route`; w_gate_up ``[G, D, 2F]`` (gate then up) and w_down
     ``[G, F, D]`` of the held experts ``[first_expert, first_expert + G)``,
-    or both with a leading layer axis and ``layer``. Returns ``(y [T, D] in
+    or both with a leading layer axis and ``layer``; ``small_rows``: the
+    rows of the small buffer, ``T`` unless the caller expects more held
+    pairs than tokens (a chip that holds an eighth of the experts at eight
+    a token expects ``T``). Returns ``(y [T, D] in
     x's dtype, report [G + 1] int32)``: the tokens on each held expert, and
     last the held pairs that reached no expert (always 0)."""
     T, k = experts.shape
@@ -223,7 +227,7 @@ def expert_share(x, valid, experts, weights, w_gate_up, w_down, *,
     # the buffer is whole row tiles, or one tile of everything
     full = T * k
     tile = ROW_TILE if full % ROW_TILE == 0 else full
-    small = min(full, _round_up(max(T, tile), tile))
+    small = min(full, _round_up(max(small_rows or T, tile), tile))
     if small == full:
         return run(full)
     return jax.lax.cond(jnp.sum(counts) <= small,

@@ -448,8 +448,14 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(q, k, v, causal: bool = True,
                     sm_scale: Optional[float] = None,
                     block_q: int = 512, block_k: int = 512,
-                    bias=None):
+                    bias=None, window: Optional[int] = None):
     """FlashAttention-2 on TPU (Pallas). q,k,v: [B, T, nh, hd] -> [B, T, nh, hd].
+
+    Grouped-query heads (k, v ``[B, T, kvh, hd]``, ``kvh`` dividing ``nh``)
+    and a sliding ``window`` (query ``i`` sees keys ``i - window < j <=
+    i``) go to :func:`band_flash_attention`: forward only, causal, no
+    bias. A call with equal heads and no window is the kernel below,
+    untouched.
 
     Replaces the O(T^2)-memory XLA attention in models/gpt.py when
     ``GPTConfig.use_flash``; differentiable via hand-written Pallas backward.
@@ -467,6 +473,15 @@ def flash_attention(q, k, v, causal: bool = True,
     b, t, nh, hd = q.shape
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(hd)
+    if window is not None or k.shape[2] != nh:
+        if bias is not None or not causal:
+            raise ValueError("flash_attention: grouped heads and a window "
+                             "are causal and take no bias")
+        return band_flash_attention(
+            q.reshape(b, t, nh * hd), k.reshape(b, t, -1),
+            v.reshape(b, t, -1), nh, k.shape[2], window=window,
+            sm_scale=sm_scale, block_q=block_q, block_k=block_k
+        ).reshape(b, t, nh, hd)
 
     def to_bh(x):
         return x.transpose(0, 2, 1, 3).reshape(b * nh, x.shape[1], hd)
@@ -487,6 +502,137 @@ def flash_attention(q, k, v, causal: bool = True,
     o = _flash(to_bh(q), to_bh(k), to_bh(v), bias_bh, causal, sm_scale,
                block_q, block_k)
     return from_bh(o)
+
+
+# ---------------------------------------------------------------------------
+# causal attention inside a band, grouped heads (forward only: serving)
+# ---------------------------------------------------------------------------
+#
+# The rung of a model with sliding-window layers: query ``i`` sees keys ``i -
+# window < j <= i``. The arrays stay as the projections leave them, ``[B, T,
+# heads * hd]`` flat: a block is ``(block, hd)`` lanes of one head, so no
+# transposed copy of q, k, v or the output is made (half a gigabyte each at
+# 16,384 tokens x 128 heads). Query head ``h`` reads key/value head ``h //
+# (nh / kvh)``. The last grid axis walks only the key blocks that meet the
+# band of the query block: ``visits`` of them from the band's first, a
+# block index past the diagonal clamped onto it (no copy is made for a
+# repeated index) and its step skipped; a block on the band's edge is
+# masked.
+
+
+def band_blocks(t: int, block_q: int, block_k: int,
+                window: Optional[int]) -> int:
+    """Key blocks a query block visits at most."""
+    nk = t // block_k
+    if window is None:
+        return nk
+    return min(nk, (block_q + window - 2) // block_k + 2)
+
+
+def _band_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                 sm_scale, block_q, block_k, window, visits):
+    qi = pl.program_id(2)
+    kj = pl.program_id(3)
+    lo, hi = _band_range(qi, block_q, block_k, window)
+    ki = lo + kj
+
+    @pl.when(kj == 0)
+    def _init():
+        m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    @pl.when(ki <= hi)
+    def _compute():
+        s = jax.lax.dot_general(
+            q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale  # (bq, bk)
+        qpos = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        kpos = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        seen = qpos >= kpos
+        if window is not None:
+            seen &= qpos - kpos < window
+        s = jnp.where(seen, s, _NEG_INF)
+        m_prev = m_scr[...]                             # (bq, 128) replicated
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
+        alpha = jnp.exp(m_prev - m_next)
+        # a row none of whose keys is in this block keeps p at 0
+        p = jnp.where(seen, jnp.exp(s - _bcast_lanes(m_next, block_k)), 0.0)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1)[:, None]
+        m_scr[...] = m_next
+        hd = acc_scr.shape[-1]
+        acc_scr[...] = acc_scr[...] * _bcast_lanes(alpha, hd) + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[...],
+            preferred_element_type=jnp.float32)
+
+    @pl.when(kj == visits - 1)
+    def _finish():
+        hd = acc_scr.shape[-1]
+        l = l_scr[...]
+        l_inv = jnp.where(l == 0.0, 1.0, 1.0 / l)
+        o_ref[...] = (acc_scr[...] * _bcast_lanes(l_inv, hd)
+                      ).astype(o_ref.dtype)
+
+
+def _band_range(qi, block_q, block_k, window):
+    """(first, last) key block that meets the band of query block ``qi``."""
+    hi = (qi * block_q + block_q - 1) // block_k
+    if window is None:
+        return 0, hi
+    return jnp.maximum(qi * block_q - window + 1, 0) // block_k, hi
+
+
+def _band_block(t: int, cap: int) -> int:
+    """The largest block of at most ``cap`` rows that divides ``t``,
+    halving from ``cap``; ``t`` itself where none does."""
+    b = min(cap, t)
+    while b > 8 and t % b:
+        b //= 2
+    return b if t % b == 0 else t
+
+
+def band_flash_attention(q, k, v, num_heads: int, kv_heads: int,
+                         window: Optional[int] = None,
+                         sm_scale: Optional[float] = None,
+                         block_q: int = 512, block_k: int = 512):
+    """Causal attention with grouped heads inside a band. q ``[B, T,
+    num_heads * hd]``, k, v ``[B, T, kv_heads * hd]`` -> ``[B, T, num_heads
+    * hd]`` in q's dtype; ``window`` None is plain causal attention."""
+    b, t, width = q.shape
+    hd = width // num_heads
+    group = num_heads // kv_heads
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(hd)
+    block_q, block_k = _band_block(t, block_q), _band_block(t, block_k)
+    visits = band_blocks(t, block_q, block_k, window)
+
+    def kv_index(b_i, h, qi, kj):
+        lo, hi = _band_range(qi, block_q, block_k, window)
+        return b_i, jnp.minimum(lo + kj, hi), h // group
+
+    q_spec = pl.BlockSpec((None, block_q, hd),
+                          lambda b_i, h, qi, kj: (b_i, qi, h))
+    kv_spec = pl.BlockSpec((None, block_k, hd), kv_index)
+    _count_launch("window_flash")
+    with jax.named_scope("window_flash_attention"):
+        return pl.pallas_call(
+            functools.partial(_band_kernel, sm_scale=sm_scale,
+                              block_q=block_q, block_k=block_k,
+                              window=window, visits=visits),
+            grid=(b, num_heads, t // block_q, visits),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            scratch_shapes=[pltpu.VMEM((block_q, NUM_LANES), jnp.float32),
+                            pltpu.VMEM((block_q, NUM_LANES), jnp.float32),
+                            pltpu.VMEM((block_q, hd), jnp.float32)],
+            compiler_params=_CompilerParams(dimension_semantics=(
+                "parallel", "parallel", "parallel", "arbitrary")),
+            interpret=_interpret(),
+            name="window_flash_fwd",
+        )(q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -1412,13 +1558,38 @@ def _decode_scratch(nh, hd):
 _PAGED_CHUNK_BYTES = 1 << 20         # float32 bytes of K a chunk folds
 
 
+def paged_decode_kernel(num_heads: int, kv_heads: int,
+                        head_dim: int) -> Optional[str]:
+    """Which paged decode kernel reads a cache of ``kv_heads`` key/value
+    heads for ``num_heads`` query heads of ``head_dim``, by name, or None
+    where the tick gathers: the one statement of the rule, asked by every
+    model description before the engine chooses ``kv_path``.
+
+    * heads whose width is not whole lanes (64): none. A page's copy must
+      be whole tiles (tests/test_chip_compile.py).
+    * equal heads, in eights: :func:`paged_decode_attention`. A page lands
+      in VMEM as (page, nh, hd), heads on sublanes, one query row a head
+      on the VPU (12 heads, or the 30 a model may pad to 32, are refused).
+    * grouped heads, each key/value head serving a multiple of eight
+      query heads (128 over 8): :func:`gqa_paged_decode_attention`. A
+      group's query rows are whole sublane tiles of an MXU product; the
+      pool's rows are flat ``kv_heads * head_dim`` lanes.
+    * any other grouping (20 query heads over 1: a group that is no whole
+      tile) gathers."""
+    if head_dim % NUM_LANES or num_heads % max(kv_heads, 1):
+        return None
+    if kv_heads == num_heads:
+        return "paged_decode_attention" if num_heads % 8 == 0 else None
+    if (num_heads // kv_heads) % 8 == 0:
+        return "gqa_paged_decode_attention"
+    return None
+
+
 def paged_decode_tiles(num_heads: int, head_dim: int) -> bool:
-    """Whether Mosaic takes the paged kernel's page copies: a page lands
-    in VMEM as (page, nh, hd) with (nh, hd) on sublanes and lanes, and a
-    copy's slice must be whole tiles (tests/test_chip_compile.py; heads
-    of 64, or 12 of them, are refused). The engine asks before it
-    chooses the kernel; interpret mode takes any shape."""
-    return head_dim % NUM_LANES == 0 and num_heads % 8 == 0
+    """Whether Mosaic takes :func:`paged_decode_attention`'s page copies
+    for equal heads (:func:`paged_decode_kernel` has the rule). The engine
+    asks before it chooses the kernel; interpret mode takes any shape."""
+    return paged_decode_kernel(num_heads, num_heads, head_dim) is not None
 
 
 def _decode_paged_kernel(layer_ref, tbl_ref, pos_ref, q_ref, kp_hbm, vp_hbm,
@@ -1720,6 +1891,184 @@ def mla_paged_decode_attention(q_lat, pool, new_rows, tables, positions,
           tables.astype(jnp.int32), positions.astype(jnp.int32),
           q_lat.astype(pool.dtype), pool)
     return out, pool
+
+
+# Grouped-query decode attention over a page table, with a lower bound. The
+# pool ``[L, P, page, kvh * hd]`` holds a token's keys (or values) of all
+# ``kvh`` key/value heads flat in the lanes (a page is ``page`` sublane rows
+# of whole lanes: bytes held are bytes of values, where ``[.., page, 8,
+# 128]`` in bfloat16 would be stored in tiles of 16 rows, half of them
+# padding). Each key/value head serves ``g = H / kvh`` query heads: their
+# rows against a chunk of the head's keys are one ``[g, hd] x [hd, rows]``
+# product on the MXU. One grid step a slot; the live pages of ``(position -
+# window, position]`` (``window`` None: ``[0, position]``) are copied in as
+# in ``_mla_decode_kernel``, double-buffered across chunks AND grid steps.
+# ``ring``: the table is a ring, logical page ``j`` at entry ``j % ring``
+# (serving/paged_kv.py: a window group's table).
+_GQA_CHUNK_ROWS = 512
+
+
+def _gqa_decode_kernel(layer_ref, tbl_ref, pos_ref, q_ref, kp_hbm, vp_hbm,
+                       o_ref, kbuf, vbuf, sems, w_smem, m_scr, l_scr,
+                       acc_scr, *, sm_scale, page, pages_per_chunk, batch,
+                       kv_heads, window, ring):
+    G = pages_per_chunk
+    layer = layer_ref[0]
+    b = pl.program_id(0)
+    hd = q_ref.shape[-1]
+    group = q_ref.shape[1] // kv_heads
+
+    def first_page(s):               # the first page a row of the span is on
+        if window is None:
+            return 0
+        return jnp.maximum(pos_ref[s] - window + 1, 0) // page
+
+    def live_pages(s):               # pages holding the span's rows
+        return pos_ref[s] // page + 1 - first_page(s)
+
+    def copies(s, c, slot, go):
+        n, lo = live_pages(s), first_page(s)
+        for i in range(G):
+            pg = c * G + i
+
+            @pl.when(pg < n)
+            def _():
+                j = lo + pg
+                phys = tbl_ref[s, j % ring if ring else j]
+                for hbm, vmem, kv in ((kp_hbm, kbuf, 0), (vp_hbm, vbuf, 1)):
+                    cp = pltpu.make_async_copy(
+                        hbm.at[layer, phys], vmem.at[slot, i],
+                        sems.at[kv, slot])
+                    if go:
+                        cp.start()
+                    else:
+                        cp.wait()
+
+    @pl.when(b == 0)
+    def _first():
+        w_smem[0] = 0
+        copies(0, 0, 0, True)
+
+    pos = pos_ref[b]
+    lo_row = first_page(b) * page
+    nc = (live_pages(b) + G - 1) // G
+    R = G * page
+    floor = -1 if window is None else pos - window
+
+    def chunk(c, w):
+        slot = w % 2
+        last = c + 1 == nc
+        nb = jnp.where(last, b + 1, b)
+        nxt = jnp.where(last, 0, c + 1)
+
+        @pl.when(nb < batch)
+        def _prefetch():
+            copies(nb, nxt, 1 - slot, True)
+
+        copies(b, c, slot, False)
+
+        @pl.when(c == 0)
+        def _init():
+            m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
+            l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+            acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+        k = kbuf[slot].reshape(R, kbuf.shape[-1])
+        v = vbuf[slot].reshape(R, vbuf.shape[-1])
+        # rows past the slot's length may be VMEM no copy has written:
+        # masked in the scores AND zeroed where they are values
+        at = lo_row + c * R + jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+        v = jnp.where(at <= pos, v, 0).astype(v.dtype)
+        at = lo_row + c * R + jax.lax.broadcasted_iota(
+            jnp.int32, (group, R), 1)
+        valid = (at <= pos) & (at > floor)
+        for h in range(kv_heads):
+            rows = slice(h * group, (h + 1) * group)
+            lanes = slice(h * hd, (h + 1) * hd)
+            s = jax.lax.dot_general(
+                q_ref[0, rows, :], k[:, lanes], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale  # (group, R)
+            s = jnp.where(valid, s, -jnp.inf)
+            m_prev = m_scr[rows, :]                              # (group, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+            alpha = jnp.exp(m_prev - m_safe)
+            e = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
+            l_scr[rows, :] = alpha * l_scr[rows, :] + jnp.sum(
+                e, axis=1, keepdims=True)
+            acc_scr[rows, :] = alpha * acc_scr[rows, :] + jnp.dot(
+                e.astype(v.dtype), v[:, lanes],
+                preferred_element_type=jnp.float32)
+            m_scr[rows, :] = m_new
+        return w + 1
+
+    w_smem[0] = jax.lax.fori_loop(0, nc, chunk, w_smem[0])
+    o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+                ).astype(o_ref.dtype)
+
+
+def gqa_paged_decode_attention(q, k_pool, v_pool, new_k, new_v, tables,
+                               positions, layer, kv_heads: int,
+                               window: Optional[int] = None,
+                               ring: bool = False,
+                               sm_scale: Optional[float] = None):
+    """The grouped-query decode step of one layer: this tick's rows written
+    through the page table (a scatter on the carried pools), then one-token
+    attention over each slot's live pages of ``(position - window,
+    position]`` (``window`` None: ``[0, position]``), read where they lie.
+
+    q ``[B, H, hd]``, query head ``i`` served by key/value head ``i // (H /
+    kv_heads)``; k_pool/v_pool ``[L, P, page, kv_heads * hd]``; new_k/new_v
+    ``[B, kv_heads * hd]``; tables ``[B, M]`` int32 (all-zero rows = idle
+    lanes writing the scratch page), with ``ring`` a ring of ``M`` entries,
+    logical page ``j`` at ``j % M``; positions ``[B]`` int32; layer: int32
+    scalar (traced). Returns ``(out [B, H, hd] in q's dtype, k_pool',
+    v_pool')``."""
+    from .decode_attention import paged_cache_update
+
+    B, M = tables.shape
+    page, width = k_pool.shape[2], k_pool.shape[3]
+    H, hd = q.shape[1], q.shape[2]
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(hd)
+    logical = positions // page
+    phys = jnp.take_along_axis(
+        tables, (logical % M if ring else logical)[:, None], axis=1)[:, 0]
+    k_pool = paged_cache_update(k_pool, new_k, phys, positions % page,
+                                layer=layer)
+    v_pool = paged_cache_update(v_pool, new_v, phys, positions % page,
+                                layer=layer)
+    _count_launch("gqa_paged_decode")
+    G = max(1, min(M, _GQA_CHUNK_ROWS // page))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(B,),
+        in_specs=[pl.BlockSpec((1, H, hd), lambda b, *_: (b, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, H, hd), lambda b, *_: (b, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, G, page, width), k_pool.dtype),
+                        pltpu.VMEM((2, G, page, width), v_pool.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((1,), jnp.int32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, hd), jnp.float32)])
+    with jax.named_scope("gqa_paged_decode_attention"):
+        out = pl.pallas_call(
+            functools.partial(
+                _gqa_decode_kernel, sm_scale=sm_scale, page=page,
+                pages_per_chunk=G, batch=B, kv_heads=kv_heads,
+                window=window, ring=M if ring else 0),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
+            compiler_params=_CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=_interpret(),
+            name="gqa_paged_decode",
+        )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+          tables.astype(jnp.int32), positions.astype(jnp.int32),
+          q.astype(k_pool.dtype), k_pool, v_pool)
+    return out, k_pool, v_pool
 
 
 def _logits_head_kernel(x_ref, scale_ref, bias_ref, w_ref, o_ref, *, eps):

@@ -71,7 +71,8 @@ from . import sampling as samp
 from .model import (block_tail as _block_tail, embed_rows as _embed_rows,
                     layers_over_pools as _layers_over_pools,
                     qkv_heads as _qkv_heads)
-from .paged_kv import PagedKVCache, PagePoolFullError, PrefixCache
+from .paged_kv import (PagedKVCache, PagePoolFullError, PrefixCache,
+                       table_width as _table_width)
 from .quant import QuantizedLeaf, dequantize_params, quantized_nbytes
 from .sampling import GREEDY, SamplingParams
 
@@ -299,11 +300,14 @@ class DecodeEngine:
         # one manager for every kind of cache: pages for the attention
         # layers (keys and values of every head, or a latent model's one
         # row a token), a state row a slot for the recurrent ones
+        # (a model whose layers keep different spans names several page
+        # groups, each with its pools, free list and table a slot:
+        # docs/serving.md "Window and global layers")
         self.cache = PagedKVCache(
-            pools["layers"], ecfg.max_batch, ecfg.max_seq,
+            pools.get("layers", 0), ecfg.max_batch, ecfg.max_seq,
             dtype=cache_dtype, page_size=ecfg.page_size,
             num_pages=ecfg.num_pages, state=self.model.state_geometry,
-            rows=pools["rows"])
+            rows=pools.get("rows"), groups=pools.get("groups"))
         self.prefix = (PrefixCache(self.cache, ecfg.prefix_cache_pages)
                        if ecfg.prefix_cache else None)
         # pool pressure reclaims the pages only the prefix cache holds
@@ -369,10 +373,15 @@ class DecodeEngine:
             return "recurrent layers", "recurrent state"
         if getattr(self.model, "latent", False):
             return "a latent cache", "latent rows"
+        if len(self.model.cache_pools.get("groups", ())) > 1:
+            return "several page groups", "a table a group"
         return None
 
     def _refuse_what_cannot_carry_state(self, ecfg: EngineConfig) -> None:
-        """The one place the rule is stated. A model with recurrent layers
+        """The one place the rule is stated. A model whose layers keep
+        different spans of the context (window and global layers) has a
+        page table a group, and the mechanisms below name a slot's pages
+        through one. A model with recurrent layers
         keeps, beside its pages, a state a slot that is advanced token by
         token and cannot be cut at a page boundary, rolled back, exported
         or split over chips by anything built so far. A model with latent
@@ -385,9 +394,17 @@ class DecodeEngine:
             return
         has, carry = beside
         recurrent = self.model.recurrent
+        grouped = len(self.model.cache_pools.get("groups", ())) > 1
 
-        def refuse(mechanism, why, why_latent=None):
-            if not recurrent and why_latent:
+        def refuse(mechanism, why, why_latent=None, pages=True):
+            if grouped and pages:
+                # window and global layers under one manager: whatever
+                # names a slot's pages names ONE table of them
+                why = ("it is written for one page table a slot, every "
+                       "layer holding every page"
+                       + ("; pass prefix_cache=False"
+                          if "prefix_cache" in mechanism else ""))
+            elif not recurrent and why_latent:
                 why = why_latent
             raise ValueError(
                 f"{type(self.model).__name__} has {has}: {mechanism} cannot "
@@ -413,7 +430,8 @@ class DecodeEngine:
         if ecfg.weight_dtype not in ("bf16", "f32"):
             refuse(f"weight_dtype {ecfg.weight_dtype!r} (int8)",
                    "the quantiser's flat chunks are dequantised by the "
-                   "GPT block's programs alone; use 'bf16' or 'f32'")
+                   "GPT block's programs alone; use 'bf16' or 'f32'",
+                   pages=False)
         if ecfg.role != "colocated":
             refuse(f"role {ecfg.role!r} (phase disaggregation, "
                    "kv_transfer)", "a hand-off ships pages only",
@@ -425,7 +443,8 @@ class DecodeEngine:
             raise ValueError(
                 f"{type(self.model).__name__} has {beside[0]}: "
                 "kv_transfer (export_request_kv / adopt_request_kv) ships "
-                f"pages of keys and values and cannot carry {beside[1]}")
+                "pages of keys and values of one table and cannot carry "
+                f"{beside[1]}")
 
     def attach_prefix_store(self, store) -> int:
         """Arm warm restart (docs/serving.md "Resilience"): restore the
@@ -523,7 +542,8 @@ class DecodeEngine:
         x = m.embed(qparams, tokens, positions[None])          # [1, T, D]
         x, caches, *report = m.prefill_layers(qparams, x, caches, _model.ctx(
             length=length, prefix_len=prefix_len, table_row=table_row,
-            slot=slot, page_size=self.ecfg.page_size))
+            slot=slot, page_size=self.ecfg.page_size,
+            table_widths=self.table_widths))
         h_last = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0,
                                               keepdims=False)
         logits = m.logits(qparams, h_last)
@@ -547,7 +567,7 @@ class DecodeEngine:
         x, caches, *report = m.decode_layers(qparams, x, caches, _model.ctx(
             positions=positions, tables=tables, actives=actives,
             page_size=self.ecfg.page_size, kv_path=self.kv_path,
-            fused=self.ecfg.fused_decode))
+            fused=self.ecfg.fused_decode, table_widths=self.table_widths))
         logits = m.logits(qparams, x, fused=self.ecfg.fused_decode)
         toks = samp.sample_batch(logits, *sp, positions)
         # a model with experts hands its layers' report out with the
@@ -666,10 +686,18 @@ class DecodeEngine:
     @property
     def table_width(self) -> int:
         """Pages a slot's table row names (the cache manager's
-        ``max_pages_per_slot``), from the configuration: a program is
-        traced for an engine that holds no cache too
-        (tests/test_chip_compile.py)."""
-        return self.ecfg.max_seq // self.ecfg.page_size
+        ``max_pages_per_slot``; with several page groups their rows side
+        by side), from the configuration: a program is traced for an
+        engine that holds no cache too (tests/test_chip_compile.py)."""
+        return sum(self.table_widths)
+
+    @property
+    def table_widths(self) -> Tuple[int, ...]:
+        """Entries of a slot's table row a page group, in the groups'
+        order (``paged_kv.table_width``)."""
+        groups = self.model.cache_pools.get("groups") or ({"window": None},)
+        return tuple(_table_width(g.get("window"), self.ecfg.max_seq,
+                                  self.ecfg.page_size) for g in groups)
 
     def _slot_feed(self, width: int = 1, params_by_slot=None):
         """A tick's feed, or with ``width`` W a verify window's, every
@@ -904,6 +932,10 @@ class DecodeEngine:
                 feed, sp = self._rung_feed(bucket, suffix, prefix_len, slot,
                                            table_row, params)
                 attrs.update(bucket=bucket, prefix_len=prefix_len, slot=slot)
+                if len(self.cache.groups) > 1:
+                    # pages the prompt took, a page group
+                    attrs.update({f"pages_{name}": n for name, n in
+                                  self.cache.pages_held(slot).items()})
             caches, logits, tok = self._run_prefill(
                 exe, bucket, slot, len(suffix), sp, feed)
             if self.last_expert_load is not None:

@@ -14,7 +14,8 @@ __all__ = [
     "m_ttft_ms", "m_tpot_ms", "m_tokens", "m_tokens_per_s",
     "m_prefill_ms", "m_decode_ms", "m_evictions", "m_queue_wait_ms",
     "m_prefix_cache", "m_prefill_tokens", "m_page_occupancy",
-    "m_page_fragmentation", "m_spec_accepted", "m_spec_proposed",
+    "m_page_fragmentation", "m_kv_pages", "m_window_released",
+    "m_spec_accepted", "m_spec_proposed",
     "m_spec_windows", "m_preemptions", "m_hol_admits",
     "m_shed", "m_replica_restarts", "m_failover", "m_prefix_store",
     "m_kv_transfer_bytes", "m_kv_transfer_ms", "m_pool_prefix",
@@ -91,6 +92,15 @@ m_page_occupancy = _REG.gauge(
 m_page_fragmentation = _REG.gauge(
     "paddle_serve_page_pool_fragmentation",
     "Internal page waste: 1 - used rows / allocated rows")
+# page groups (serving/paged_kv.py, docs/serving.md "Window and global
+# layers"): pages held in each group of a manager that has several, and
+# the pages a window group gave back while their slot was still decoding
+m_kv_pages = _REG.gauge(
+    "paddle_serve_kv_pages",
+    "KV pages held, by page group (full | window)", ("group",))
+m_window_released = _REG.counter(
+    "paddle_serve_window_pages_released_total",
+    "Pages a window group gave back because they left a live slot's window")
 # which of the sampler's three paths an engine call took inside its
 # executable (serving/sampling.py: the host computes it from the same
 # predicate before the call), so an operator sees what share of ticks

@@ -11,7 +11,13 @@ description is any object with:
   it asks of ``PagedKVCache``, one ``[layers, pages, page, *shape]`` a
   row shape, the attention layers alone: ``((heads, head_dim),) * 2`` for
   keys and values, ``((width,),)`` for one pool of latent rows (then
-  ``latent`` is true);
+  ``latent`` is true); or, where its layers keep different spans of the
+  context, ``{"groups": [{"name", "layers", "rows", "window"}, ...]}``:
+  several page groups under the one manager, each with its pools, free
+  list and a table a slot, a group with a ``window`` bounded by it
+  (``serving/paged_kv.py``); the programs are then handed the groups'
+  table rows side by side and ``ctx.table_widths`` says where each
+  starts;
 - ``recurrent`` and ``state_geometry``: whether slots carry recurrent
   state beside their pages, and the shapes of a slot's two state rows a
   recurrent layer as the model states them, ``{"layers": n, "conv":
@@ -30,10 +36,11 @@ description is any object with:
   by the stored layout's plan, so every leaf keeps its stored shape);
 - ``embed(qparams, tokens, positions)``;
 - ``prefill_layers(qparams, x [1, T, D], caches, ctx)`` with ``ctx``:
-  ``length``, ``prefix_len``, ``table_row``, ``slot``, ``page_size``;
+  ``length``, ``prefix_len``, ``table_row``, ``slot``, ``page_size``,
+  ``table_widths``;
 - ``decode_layers(qparams, x [B, D], caches, ctx)`` with ``ctx``:
   ``positions``, ``tables``, ``actives``, ``page_size``, ``kv_path``,
-  ``fused``; both return ``(x, caches)``, the caches a tuple ``(k pool, v
+  ``fused``, ``table_widths``; both return ``(x, caches)``, the caches a tuple ``(k pool, v
   pool[, conv, ssm])`` (or ``(latent pool,)``) updated in place, and a
   model with experts a third value, its layers' report (one small int32
   array that the engine hands out with the logits);
@@ -41,10 +48,12 @@ description is any object with:
 - ``forward(params, tokens [1, T])``: the plain full forward pass (the
   engine's parity surface).
 
-Four descriptions exist: :class:`GPTServing` here (``models/gpt.py``'s
+Five descriptions exist: :class:`GPTServing` here (``models/gpt.py``'s
 block), ``models/jamba.py:JambaServing``,
-``models/kimi_k2.py:KimiK2Serving`` and
-``models/olmo_hybrid.py:OlmoHybridServing``. The engine's verify program
+``models/kimi_k2.py:KimiK2Serving``,
+``models/olmo_hybrid.py:OlmoHybridServing`` and
+``models/cohere2_moe.py:Cohere2MoeServing`` (window and global layers: two
+page groups). The engine's verify program
 is still written for the GPT block (ROADMAP D2) and uses the block
 helpers below directly.
 """
@@ -157,7 +166,9 @@ class GPTServing:
                             "rows": ((cfg.num_heads, cfg.head_dim),) * 2}
 
     def kernel_takes_pages(self, page_size, cache_dtype) -> bool:
-        return _pk.paged_decode_tiles(self.cfg.num_heads, self.cfg.head_dim)
+        c = self.cfg
+        return _pk.paged_decode_kernel(
+            c.num_heads, c.num_heads, c.head_dim) == "paged_decode_attention"
 
     def hold(self, params, weight_dtype: str, chunk: int, sharded=False):
         """The serving storage: ``quantize_params`` of the stored tree
@@ -286,6 +297,10 @@ def describe(cfg):
 
     if isinstance(cfg, olmo_mod.OlmoHybridConfig):
         return olmo_mod.OlmoHybridServing(cfg)
+    from ..models import cohere2_moe as cohere_mod
+
+    if isinstance(cfg, cohere_mod.Cohere2MoeConfig):
+        return cohere_mod.Cohere2MoeServing(cfg)
     raise TypeError(
         f"DecodeEngine: no model description for {type(cfg).__name__}; "
         "pass an object with the surface serving/model.py lists")

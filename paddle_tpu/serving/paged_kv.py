@@ -59,6 +59,23 @@ of the key and value pair. Pages, tables, refcounts and the allocator are
 the same; what a page holds is the model's business. The page-content I/O
 (``read_pages``/``write_pages``/``adopt_slot``: prefix store, KV hand-off)
 is written for the pair and refuses a manager with another set of pools.
+
+**Page groups** (docs/serving.md "Window and global layers"): a model whose
+layers do not all keep the same rows asks for several *groups*, each
+``{"name", "layers", "rows", "window"}``: a pool set, a free list, the
+refcounts and one table a slot of its own (:class:`_PageGroup`), all under
+this one manager. A group with a ``window`` of ``W`` tokens serves layers
+that attend the last ``W`` positions only: its table is a **ring** of
+``ceil(W / page) + 1`` entries (logical page ``j`` at entry ``j % ring``),
+the page that left the window is given back to the group's free list when
+the page that takes its entry is mapped (``ensure_capacity`` at a page
+boundary, while the rider decodes), and a prompt longer than the window
+takes pages for its last ``ring`` logical pages alone. Such a slot never
+holds more than ``W + 2`` pages of tokens in that group. A slot's logical
+pages are mapped in every group at once; ``can_admit``, ``alloc``,
+``free``, ``ensure_capacity``, ``nbytes`` and the page metrics count every
+group. The first group is the one the prefix cache and the page-content
+I/O see; a manager with more than one refuses both.
 """
 from __future__ import annotations
 
@@ -101,6 +118,55 @@ def _scatter_pages_exec(k, v, idx, k_pages, v_pages):
     return k.at[:, idx].set(k_pages), v.at[:, idx].set(v_pages)
 
 
+def table_width(window: Optional[int], max_seq: int, page_size: int) -> int:
+    """Entries of a slot's table row in a group: every logical page of
+    ``max_seq``, or the ring of a window group, ``ceil(window / page) + 1``
+    (a window of ``W`` positions ending anywhere touches at most that many
+    pages). What the engine sizes the feed with, with no cache built."""
+    pages = max_seq // page_size
+    if window is None:
+        return pages
+    return min(pages, -(-int(window) // page_size) + 1)
+
+
+class _PageGroup:
+    """One page-backed cache of the manager: the pools of the layers that
+    keep the same rows over the same span, with a free list, refcounts and a
+    table a slot of its own. ``window`` None: a slot's table names every
+    logical page; else the table is a ring (module docstring)."""
+
+    def __init__(self, name: str, layers: int,
+                 rows: Sequence[Tuple[int, ...]], window: Optional[int],
+                 num_pages: int, max_slots: int, max_seq: int,
+                 page_size: int, dtype: Any):
+        self.name, self.layers = str(name), int(layers)
+        self.window = None if window is None else int(window)
+        self.rows = tuple(tuple(int(n) for n in r) for r in rows)
+        self.width = table_width(window, max_seq, page_size)
+        # default pool = every slot at its fullest (+1 scratch page)
+        self.num_pages = int(num_pages) or max_slots * self.width + 1
+        if self.num_pages < 2:
+            raise ValueError("num_pages must be >= 2 (page 0 is scratch)")
+        self.pools = [jnp.zeros((self.layers, self.num_pages, page_size) + r,
+                                dtype) for r in self.rows]
+        self.tables = np.zeros((max_slots, self.width), np.int32)
+        self.ref = np.zeros((self.num_pages,), np.int64)
+        self.ref[0] = 1                              # scratch: pinned
+        self.free_pages: List[int] = list(range(1, self.num_pages))
+        self.released = 0      # pages given back while their slot lived
+
+    def first_held(self, n_pages: int) -> int:
+        """The first logical page a slot of ``n_pages`` logical pages
+        still holds."""
+        return 0 if self.window is None else max(0, n_pages - self.width)
+
+    def entry(self, j: int) -> int:
+        return j if self.window is None else j % self.width
+
+    def held_pages(self) -> int:
+        return self.num_pages - 1 - len(self.free_pages)
+
+
 class CacheFullError(RuntimeError):
     """All slots are occupied (the scheduler should queue, not crash)."""
 
@@ -136,13 +202,13 @@ class PagedKVCache:
                  dtype: Any = jnp.float32,
                  page_size: int = 8, num_pages: int = 0,
                  state: Optional[Dict[str, Any]] = None,
-                 rows: Optional[Sequence[Tuple[int, ...]]] = None):
+                 rows: Optional[Sequence[Tuple[int, ...]]] = None,
+                 groups: Optional[Sequence[Dict[str, Any]]] = None):
         if max_slots < 1 or max_seq < 1:
             raise ValueError("max_slots and max_seq must be >= 1")
         if page_size < 1 or max_seq % page_size:
             raise ValueError(
                 f"page_size {page_size} must divide max_seq {max_seq}")
-        self.num_layers = int(num_layers)
         self.max_slots = int(max_slots)
         self.max_seq = int(max_seq)
         if rows is not None and len(rows[0]) == 2:
@@ -152,19 +218,19 @@ class PagedKVCache:
         self.dtype = dtype
         self.page_size = int(page_size)
         self.max_pages_per_slot = self.max_seq // self.page_size
-        # default pool = every slot at max_seq (+1 scratch page): the
-        # worst case, but pages only bind to slots as sequences grow
-        self.num_pages = int(num_pages) or (
-            self.max_slots * self.max_pages_per_slot + 1)
-        if self.num_pages < 2:
-            raise ValueError("num_pages must be >= 2 (page 0 is scratch)")
         # what a token's row holds in each pool: keys and values of every
-        # head unless the model says otherwise (``rows``)
-        self.rows = tuple(tuple(int(n) for n in r) for r in (
-            rows or ((self.num_heads, self.head_dim),) * 2))
-        self.pools = [jnp.zeros((self.num_layers, self.num_pages,
-                                 self.page_size) + r, dtype)
-                      for r in self.rows]
+        # head unless the model says otherwise (``rows``); one group over
+        # all the layers unless it names several (``groups``: each
+        # {"name", "layers", "rows", "window"[, "num_pages"]}; the
+        # manager's ``num_pages`` sizes the first)
+        if groups is None:
+            groups = [{"name": "full", "layers": num_layers, "window": None,
+                       "rows": rows or ((self.num_heads, self.head_dim),) * 2}]
+        self.groups = [
+            _PageGroup(g["name"], g["layers"], g["rows"], g.get("window"),
+                       g.get("num_pages", 0) or (0 if i else num_pages),
+                       self.max_slots, self.max_seq, self.page_size, dtype)
+            for i, g in enumerate(groups)]
         # per-slot recurrent state: {"layers", "conv": shape, "ssm":
         # shape} from the model (a slot's two state rows as the model
         # states them), None for an attention-only model
@@ -179,23 +245,39 @@ class PagedKVCache:
                 lead + tuple(int(n) for n in state["ssm"]), jnp.float32)
             self.state_bytes_per_slot = (
                 self.conv.nbytes + self.ssm.nbytes) // self.max_slots
-        self._tables = np.zeros((self.max_slots, self.max_pages_per_slot),
-                                np.int32)           # 0 = scratch/unmapped
         self._slots = [_SlotState() for _ in range(self.max_slots)]
         self._free_slots: List[int] = list(range(self.max_slots))
-        self._ref = np.zeros((self.num_pages,), np.int64)
-        self._ref[0] = 1                             # scratch: pinned
-        self._free_pages: List[int] = list(range(1, self.num_pages))
         # the PrefixCache over this pool, set by the engine: pool
         # pressure reclaims the pages only it still holds
         self.prefix_cache: Optional["PrefixCache"] = None
 
     # -- geometry ----------------------------------------------------------
+    # The first group is the one every mechanism built before the groups
+    # sees (prefix cache, page-content I/O, the verify program): its
+    # pools, table, refcounts and free list under the names they had.
+    @property
+    def pools(self):
+        return self.groups[0].pools
+
+    @pools.setter
+    def pools(self, value):
+        self.groups[0].pools = value
+
+    num_layers = property(lambda self: self.groups[0].layers)
+    num_pages = property(lambda self: self.groups[0].num_pages)
+    rows = property(lambda self: self.groups[0].rows)
+    _tables = property(lambda self: self.groups[0].tables)
+    _ref = property(lambda self: self.groups[0].ref)
+    _free_pages = property(lambda self: self.groups[0].free_pages)
+
     @property
     def keys_and_values(self) -> bool:
         """Whether the pools are the key and value pair the page-content
         I/O (and the engine's verify program) is written for."""
-        return len(self.pools) == 2
+        return len(self.groups) == 1 and len(self.pools) == 2
+
+    def group(self, name: str) -> Optional[_PageGroup]:
+        return next((g for g in self.groups if g.name == name), None)
 
     @property
     def k(self):
@@ -215,8 +297,8 @@ class PagedKVCache:
 
     @property
     def nbytes(self) -> int:
-        """Pools and recurrent state together."""
-        return (sum(int(p.size) for p in self.pools)
+        """Every group's pools and the recurrent state together."""
+        return (sum(int(p.size) for g in self.groups for p in g.pools)
                 * jnp.dtype(self.dtype).itemsize
                 + self.state_bytes_per_slot * self.max_slots)
 
@@ -226,15 +308,18 @@ class PagedKVCache:
 
     def arrays(self) -> tuple:
         """What the compiled programs carry: the pools (``(k, v)``, or a
-        latent model's one), and the two state arrays behind them where
-        there are any."""
+        latent model's one), group after group, and the two state arrays
+        behind them where there are any."""
+        pools = tuple(p for g in self.groups for p in g.pools)
         if self.recurrent:
-            return (*self.pools, self.conv, self.ssm)
-        return tuple(self.pools)
+            return (*pools, self.conv, self.ssm)
+        return pools
 
     def set_arrays(self, arrays) -> None:
-        n = len(self.pools)
-        self.pools = list(arrays[:n])
+        n = 0
+        for g in self.groups:
+            g.pools = list(arrays[n:n + len(g.pools)])
+            n += len(g.pools)
         if self.recurrent:
             self.conv, self.ssm = arrays[n], arrays[n + 1]
 
@@ -258,17 +343,31 @@ class PagedKVCache:
     def free_page_count(self) -> int:
         return len(self._free_pages)
 
-    def _take_pages(self, n: int) -> List[int]:
-        if n > len(self._free_pages) and self.prefix_cache is not None:
-            self.prefix_cache.reclaim(n - len(self._free_pages))
-        if n > len(self._free_pages):
+    def _available(self, g: _PageGroup) -> int:
+        """Pages ``_take_pages`` could hand out of ``g`` right now: its
+        free list, and for the first group what only the prefix cache
+        still holds (``_take_pages`` reclaims it)."""
+        avail = len(g.free_pages)
+        if g is self.groups[0] and self.prefix_cache is not None:
+            avail += self.prefix_cache.reclaimable()
+        return avail
+
+    def _take_pages(self, n: int, g: Optional[_PageGroup] = None
+                    ) -> List[int]:
+        g = g or self.groups[0]
+        free = g.free_pages
+        if (n > len(free) and g is self.groups[0]
+                and self.prefix_cache is not None):
+            self.prefix_cache.reclaim(n - len(free))
+        if n > len(free):
             raise PagePoolFullError(
-                f"need {n} free page(s), have {len(self._free_pages)} "
-                f"of {self.num_pages}")
-        out = [self._free_pages.pop(0) for _ in range(n)]
+                f"need {n} free page(s), have {len(free)} "
+                f"of {g.num_pages}"
+                + (f" in group {g.name!r}" if len(self.groups) > 1 else ""))
+        out = [free.pop(0) for _ in range(n)]
         for p in out:
-            assert self._ref[p] == 0, f"free page {p} had refs"
-            self._ref[p] = 1
+            assert g.ref[p] == 0, f"free page {p} had refs"
+            g.ref[p] = 1
         return out
 
     def ref_pages(self, pages: Sequence[int]) -> None:
@@ -276,37 +375,70 @@ class PagedKVCache:
             assert p != 0 and self._ref[p] > 0, f"ref on dead page {p}"
             self._ref[p] += 1
 
-    def deref_pages(self, pages: Sequence[int]) -> None:
+    def deref_pages(self, pages: Sequence[int],
+                    g: Optional[_PageGroup] = None) -> None:
+        g = g or self.groups[0]
         for p in pages:
             if p == 0:
                 continue
-            assert self._ref[p] > 0, f"double free of page {p}"
-            self._ref[p] -= 1
-            if self._ref[p] == 0:
-                self._free_pages.append(p)
-        self._free_pages.sort()
+            assert g.ref[p] > 0, f"double free of page {p}"
+            g.ref[p] -= 1
+            if g.ref[p] == 0:
+                g.free_pages.append(p)
+        g.free_pages.sort()
         self._note_pool_metrics()
+
+    def _map_pages(self, slot: int, first: int, upto: int) -> None:
+        """Map the logical pages ``[first, upto)`` of ``slot`` in every
+        group, all or none (:class:`PagePoolFullError` where a group
+        cannot). A window group maps those it will still hold (the last
+        ``ring``), each into its ring entry, and gives back the page that
+        entry held: it left the window when this one began."""
+        plans = []
+        for g in self.groups:
+            lo = max(first, g.first_held(upto))
+            row = g.tables[slot]
+            # a ring entry's old page goes back before the new one is
+            # taken, so a full group can still turn
+            leaving = ([int(row[g.entry(j)]) for j in range(lo, upto)
+                        if row[g.entry(j)]] if g.window is not None else [])
+            if upto - lo > self._available(g) + len(leaving):
+                raise PagePoolFullError(
+                    f"need {upto - lo} free page(s), have "
+                    f"{len(g.free_pages)} of {g.num_pages}"
+                    + (f" in group {g.name!r}"
+                       if len(self.groups) > 1 else ""))
+            plans.append((g, lo, leaving))
+        for g, lo, leaving in plans:
+            row = g.tables[slot]
+            if leaving:
+                self.deref_pages(leaving, g)
+                g.released += len(leaving)
+                smetrics.m_window_released.inc(len(leaving))
+            pages = self._take_pages(upto - lo, g)
+            for j, page in zip(range(lo, upto), pages):
+                row[g.entry(j)] = page
 
     # -- slot bookkeeping --------------------------------------------------
     def can_admit(self, prompt_len: int, prefix_len: int = 0) -> bool:
         """Would a prompt of ``prompt_len`` (with ``prefix_len`` tokens
-        already cache-backed) fit right now? Pages that only the prefix
-        cache still holds count as free: ``_take_pages`` reclaims them."""
+        already cache-backed) fit right now, in every group? Pages that
+        only the prefix cache still holds count as free: ``_take_pages``
+        reclaims them."""
         if not self._free_slots:
             return False
-        need = self.pages_for(prompt_len) - prefix_len // self.page_size
-        avail = len(self._free_pages)
-        if self.prefix_cache is not None:
-            avail += self.prefix_cache.reclaimable()
-        return need <= avail
+        n, n_prefix = self.pages_for(prompt_len), prefix_len // self.page_size
+        return all(n - max(n_prefix, g.first_held(n)) <= self._available(g)
+                   for g in self.groups)
 
     def alloc(self, length: int = 0,
               prefix_pages: Sequence[int] = ()) -> int:
         """Claim a slot; attach ``prefix_pages`` (shared, refcounted) and
-        map fresh pages so every position ``< length`` is backed.
+        map fresh pages so every position ``< length`` is backed (in a
+        window group: every position the window still reaches).
 
         Raises :class:`CacheFullError` when no slot is free and
-        :class:`PagePoolFullError` when the pool is dry (the slot is NOT
+        :class:`PagePoolFullError` when a pool is dry (the slot is NOT
         claimed in that case)."""
         if not self._free_slots:
             raise CacheFullError(
@@ -317,49 +449,58 @@ class PagedKVCache:
         n_prefix = len(prefix_pages)
         if n_prefix * self.page_size > length:
             raise ValueError("prefix pages cover more than the sequence")
-        n_own = self.pages_for(length) - n_prefix
+        if n_prefix and len(self.groups) > 1:
+            raise ValueError("shared prefix pages belong to one group; "
+                             f"this manager has {len(self.groups)}")
         # pin the shared prefix FIRST: _take_pages may trigger the
         # prefix cache's reclaim, which must not be able to free (and
         # recycle) the very pages this slot is about to attach
         self.ref_pages(prefix_pages)
+        slot = self._free_slots[0]
+        for g in self.groups:
+            g.tables[slot][:] = 0
+        self._tables[slot][:n_prefix] = prefix_pages
+        n = self.pages_for(length)
         try:
-            own = self._take_pages(n_own)    # may raise PagePoolFullError
+            self._map_pages(slot, n_prefix, n)
         except PagePoolFullError:
+            # (pages are taken only once every group has enough, so this
+            # undoes a reclaim that fell short of what it promised)
+            for g in self.groups:
+                own = g.tables[slot][n_prefix if g is self.groups[0] else 0:]
+                self.deref_pages([int(p) for p in own if p], g)
+                g.tables[slot][:] = 0
             self.deref_pages(prefix_pages)
             raise
-        slot = self._free_slots.pop(0)
+        self._free_slots.pop(0)
         st = self._slots[slot]
         st.live = True
         st.length = int(length)
         st.prefix_len = n_prefix * self.page_size
-        st.mapped = n_prefix + n_own
+        st.mapped = n
         st.generation += 1
-        row = self._tables[slot]
-        row[:] = 0
-        row[:n_prefix] = prefix_pages
-        row[n_prefix:st.mapped] = own
         self._note_pool_metrics()
         self._note_state(born=True)
         return slot
 
     def ensure_capacity(self, slot: int, upto_len: int) -> bool:
-        """Map pages so positions ``< upto_len`` are write-backed.
-        Returns False (mapping nothing) when the pool cannot cover it —
-        the scheduler's cue to preempt."""
+        """Map pages so positions ``< upto_len`` are write-backed, in
+        every group (a window group gives back the page that left the
+        window). Returns False (mapping nothing) when a pool cannot cover
+        it — the scheduler's cue to preempt."""
         st = self._slots[slot]
         if not st.live:
             raise ValueError(f"slot {slot} is not live")
         if upto_len > self.max_seq:
             return False
-        need = self.pages_for(upto_len) - st.mapped
-        if need <= 0:
+        n = self.pages_for(upto_len)
+        if n <= st.mapped:
             return True
         try:
-            pages = self._take_pages(need)
+            self._map_pages(slot, st.mapped, n)
         except PagePoolFullError:
             return False
-        self._tables[slot][st.mapped:st.mapped + need] = pages
-        st.mapped += need
+        st.mapped = n
         self._note_pool_metrics()
         return True
 
@@ -367,9 +508,10 @@ class PagedKVCache:
         st = self._slots[slot]
         if not st.live:
             raise ValueError(f"slot {slot} is not live")
-        row = self._tables[slot]
-        self.deref_pages([int(p) for p in row[:st.mapped]])
-        row[:] = 0
+        for g in self.groups:
+            row = g.tables[slot]
+            self.deref_pages([int(p) for p in row if p], g)
+            row[:] = 0
         st.live = False
         st.length = 0
         st.prefix_len = 0
@@ -520,19 +662,60 @@ class PagedKVCache:
 
     # -- executable feeds --------------------------------------------------
     def table_row(self, slot: int) -> np.ndarray:
-        """[max_pages_per_slot] int32 page table for one slot (copy)."""
-        return self._tables[slot].copy()
+        """[max_pages_per_slot] int32 page table for one slot (copy); a
+        manager of several groups hands their rows side by side, group
+        after group (``table_widths``)."""
+        if len(self.groups) == 1:
+            return self._tables[slot].copy()
+        return np.concatenate([g.tables[slot] for g in self.groups])
 
     def table_rows(self, slots) -> np.ndarray:
         """[len(slots), max_pages_per_slot] int32 page tables of ``slots``
         (copy): the riders' rows of the decode feed."""
-        return self._tables[slots]
+        if len(self.groups) == 1:
+            return self._tables[slots]
+        return np.concatenate([g.tables[slots] for g in self.groups], axis=1)
+
+    @property
+    def table_widths(self) -> Tuple[int, ...]:
+        return tuple(g.width for g in self.groups)
+
+    # -- what the groups hold ------------------------------------------------
+    def live_rows(self, slots, extra: int = 0) -> Dict[str, int]:
+        """{group: cache rows a layer that ``slots`` have live in it}, each
+        slot's length plus ``extra`` (the row a tick is about to write), a
+        window group's clipped to its window: what a decode tick over those
+        riders reads, a layer of the group."""
+        out = {}
+        for g in self.groups:
+            lens = [self._slots[s].length + extra for s in slots]
+            out[g.name] = int(sum(
+                n if g.window is None else min(n, g.window) for n in lens))
+        return out
+
+    def pages_held(self, slot: int) -> Dict[str, int]:
+        """{group: pages ``slot`` holds in it}."""
+        return {g.name: int(np.count_nonzero(g.tables[slot]))
+                for g in self.groups}
+
+    def held_over_one_table(self) -> Optional[float]:
+        """Pages x layers the live slots hold over what ONE table for all
+        the layers would make them hold (every layer every mapped page);
+        None with no slot live. 1.0 for a manager of one group."""
+        live = [i for i, s in enumerate(self._slots) if s.live]
+        one = sum(self._slots[i].mapped for i in live) * sum(
+            g.layers for g in self.groups)
+        if not one:
+            return None
+        return sum(int(np.count_nonzero(g.tables[live])) * g.layers
+                   for g in self.groups) / one
 
     # -- pool metrics ------------------------------------------------------
     def pool_occupancy(self) -> float:
-        """Allocated pages / allocatable pages (scratch excluded)."""
-        total = self.num_pages - 1
-        return (total - len(self._free_pages)) / total
+        """Allocated pages / allocatable pages (scratch excluded), every
+        group's together."""
+        total = sum(g.num_pages - 1 for g in self.groups)
+        return sum(g.held_pages() for g in self.groups) / total
 
     def fragmentation(self) -> float:
         """Internal waste: 1 - used_rows / allocated_rows (0 when every
@@ -545,6 +728,14 @@ class PagedKVCache:
         allocated_rows = (mapped + max(cache_held, 0)) * self.page_size
         used_rows = sum(s.length for s in self._slots if s.live) + \
             max(cache_held, 0) * self.page_size
+        # a further group's slots hold their last pages alone: rows from
+        # their first held page up to their length, of the pages held
+        for g in self.groups[1:]:
+            for st in self._slots:
+                if st.live:
+                    first = g.first_held(st.mapped)
+                    allocated_rows += (st.mapped - first) * self.page_size
+                    used_rows += st.length - first * self.page_size
         if allocated_rows <= 0:
             return 0.0
         return 1.0 - used_rows / allocated_rows
@@ -552,6 +743,9 @@ class PagedKVCache:
     def _note_pool_metrics(self) -> None:
         smetrics.m_page_occupancy.set(self.pool_occupancy())
         smetrics.m_page_fragmentation.set(self.fragmentation())
+        if len(self.groups) > 1:
+            for g in self.groups:
+                smetrics.m_kv_pages.labels(g.name).set(g.held_pages())
 
 
 class _Chain:

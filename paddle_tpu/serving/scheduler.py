@@ -808,6 +808,14 @@ class Scheduler:
         if eng.latent_token_bytes:
             # the latent rows of the riders' cached tokens, all layers
             attrs["latent_bytes"] = cached * eng.latent_token_bytes
+        if len(eng.cache.groups) > 1:
+            # window and global layers: the rows a layer of each page
+            # group reads for these riders (the row this tick writes among
+            # them, a window group's clipped to its window), and the pages
+            # x layers the live slots hold over what one table would
+            attrs.update({f"rows_{name}": n for name, n in
+                          eng.cache.live_rows(feed, extra=1).items()})
+            attrs["held_over_one_table"] = eng.cache.held_over_one_table()
         # a tick found in flight plans its successor inside the call, as
         # soon as its tokens are on the host; a tick this step fed itself
         # (the first, one after a prefill or a held decision) has its

@@ -1117,3 +1117,156 @@ def test_olmo_hybrid_rung_goes_through_both_kernels(one_chip):
     nbytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in caches)
     assert mem.alias_size_in_bytes >= nbytes
     assert mem.temp_size_in_bytes < 30 * 1408 * 1408 * 4
+
+
+# ---------------------------------------------------------------------------
+# window and global layers over two page groups (PR 41): the cohere2_moe
+# cell's kernels, tick and rungs at published widths, from shapes alone
+# ---------------------------------------------------------------------------
+
+COHERE_B, COHERE_S, COHERE_PAGE, COHERE_PAGES = 32, 17408, 64, 5001
+
+
+def _cohere_cut_config():
+    from paddle_tpu.models import cohere2_moe as CM
+
+    return CM.Cohere2MoeConfig(
+        vocab_size=32768, num_hidden_layers=4,
+        layer_types=(CM.SLIDING,) * 3 + (CM.FULL,), experts_held=16)
+
+
+def _lower_cohere_cut(program, sharding):
+    """The decode tick or a prefill rung of ``command-a-plus-ep8-l4`` as the
+    cell runs it (one period of the layer pattern, 16 held experts of 128,
+    an eighth of the vocabulary, 32 slots of 17,408 tokens in pages of 64),
+    lowered for the described chip from SHAPES alone (its weights would be
+    9.5 GB of host memory that no compile reads)."""
+    from paddle_tpu import serving
+    from paddle_tpu.models import cohere2_moe as CM
+    from paddle_tpu.serving import engine as E
+    from paddle_tpu.serving.paged_kv import table_width
+
+    B, S, PG = COHERE_B, COHERE_S, COHERE_PAGE
+    cfg = _cohere_cut_config()
+    eng = object.__new__(E.DecodeEngine)
+    eng.model, eng.cfg = CM.Cohere2MoeServing(cfg), cfg
+    eng.ecfg = serving.EngineConfig(
+        max_batch=B, max_seq=S, page_size=PG, num_pages=COHERE_PAGES,
+        weight_dtype="bf16", prefix_cache=False)
+    eng.kv_path = "pallas_paged"
+    assert eng.model.kernel_takes_pages(PG, BF16)
+    assert eng.table_widths == (S // PG, 4096 // PG + 1)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    stored = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s, F32), CM.leaf_shapes(cfg),
+        is_leaf=lambda s: isinstance(s, tuple))
+    held = jax.tree_util.tree_map(
+        lambda a: arg(a.shape, a.dtype),
+        jax.eval_shape(lambda p: CM.hold(p, cfg, "bf16"), stored))
+    ring = table_width(4096, S, PG)
+    pools = tuple(
+        arg((layers, pages, PG, cfg.kv_width), BF16)
+        for layers, pages in ((1, COHERE_PAGES), (3, B * ring + 1))
+        for _ in range(2))
+    if program == "decode":
+        fn, feed = eng._decode_fn_paged, arg(
+            E.slot_feed_shape(B, eng.table_width), jnp.int32)
+    else:
+        T = int(program.split("_b")[1])
+        fn, feed = eng._prefill_fn_paged, arg(
+            (E.rung_feed_len(eng.table_width, T),), jnp.int32)
+    return jax.jit(fn, donate_argnums=(1,)).lower(held, pools, feed)
+
+
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
+def test_gqa_paged_decode_attention(one_chip, window):
+    """Mosaic takes the grouped-query paged decode kernel at the cell's
+    shapes: 128 query heads over 8 key/value heads of 128, pages of 64 rows
+    of 1,024 lanes, a full table of 272 entries and a ring of 65."""
+    B, H, KVH, PG = COHERE_B, 128, 8, COHERE_PAGE
+    M = COHERE_S // PG if window is None else 4096 // PG + 1
+
+    def fn(q, kp, vp, nk, nv, tables, positions, layer):
+        return PK.gqa_paged_decode_attention(
+            q, kp, vp, nk, nv, tables, positions, layer, KVH,
+            window=window, ring=window is not None)
+
+    pool = ((3, 2081, PG, KVH * HD), BF16)
+    row = ((B, KVH * HD), BF16)
+    compiled = _compile(fn, one_chip, ((B, H, HD), BF16), pool, pool, row,
+                        row, ((B, M), jnp.int32), ((B,), jnp.int32),
+                        ((), jnp.int32))
+    assert re.search(r"%gqa_paged_decode[\w.]* = ", compiled.as_text())
+
+
+@pytest.mark.parametrize("window", [None, 4096], ids=["causal", "band"])
+@pytest.mark.parametrize("T", [2048, 16384])
+def test_band_flash_attention(one_chip, T, window):
+    """Mosaic takes the grouped, windowed flash kernel on the flat ``[1, T,
+    heads x 128]`` arrays of the lowest and the top rung; no transposed
+    copy of q, k, v or the output is made (no temporaries at all)."""
+    compiled = _compile(
+        lambda q, k, v: PK.band_flash_attention(q, k, v, 128, 8,
+                                                window=window),
+        one_chip, ((1, T, 128 * HD), BF16), ((1, T, 8 * HD), BF16),
+        ((1, T, 8 * HD), BF16))
+    assert re.search(r"%window_flash_fwd[\w.]* = ", compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+def test_gpt_flash_call_is_the_kernel_it_was(one_chip):
+    """``flash_attention`` with equal heads and no window lowers to the
+    kernel it lowered to before it learned of either (``flash_fwd`` on
+    ``[BH, T, hd]``, a three-axis grid): the training cell's path."""
+    text = jax.jit(lambda q, k, v: PK.flash_attention(q, k, v)).lower(
+        *[jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+          for s, d in (QKV, QKV, QKV)]).as_text()
+    assert "flash_fwd" in text and "window_flash_fwd" not in text
+
+
+_COHERE_RESIDENT = 15.75 * 2 ** 30
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_b2048",
+                                     "prefill_b16384"])
+def test_cohere_programs_fit_and_relay_no_weight(one_chip, program):
+    """The window-and-global cell's tick, lowest and top rung on the chip's
+    own compile: the kernels are there by name (``gqa_paged_decode`` in the
+    tick, ``window_flash_fwd`` in a rung, ``moe_grouped_matmul`` in both),
+    both page groups' donated pools are aliased whole, nothing the size of
+    one expert's smallest leaf (``w_down [F, D]``, 16.8 M elements) is
+    copied, transposed, converted or sliced out of a weight or a pool, and
+    arguments plus temporaries stay under the chip's 15.75 GiB."""
+    cfg = _cohere_cut_config()
+    compiled = _lower_cohere_cut(program, one_chip).compile()
+    hlo = compiled.as_text()
+    assert re.search(r"%moe_grouped_matmul[\w.]* = ", hlo)
+    assert bool(re.search(r"%gqa_paged_decode[\w.]* = ", hlo)) == (
+        program == "decode")
+    assert bool(re.search(r"%window_flash_fwd[\w.]* = ", hlo)) == (
+        program != "decode")
+    one_expert_leaf = cfg.intermediate_size * cfg.hidden_size
+    moves = (_weight_sized_relayouts(hlo, at_least=one_expert_leaf)
+             + _pool_sized_moves(hlo, at_least=one_expert_leaf))
+    # a rung's own activations are that large; weights and pools are told
+    # from them by their leading sizes (``w_o [16384, 4096]`` and the
+    # table ``[32768, 4096]`` are the shapes of the experts' full-size row
+    # buffers of the 2048 and the 16384 rung too, so those two are held by
+    # the tick alone, below)
+    weights = [m for m in moves if re.search(
+        r"\[(?:\d+,)*(?:16,4096,8192|16,4096,4096|4096,18432|4096,32768|"
+        r"4096,8192|5001,64,1024|2081,64,1024)\]", m)]
+    assert not weights, "\n".join(weights)
+    mem = compiled.memory_analysis()
+    pools = 2 * (COHERE_PAGES + 3 * 2081) * COHERE_PAGE * cfg.kv_width * 2
+    assert mem.alias_size_in_bytes >= pools
+    resident = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    print(f"{program}: arguments {mem.argument_size_in_bytes / 2**30:.2f} "
+          f"GiB, temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB")
+    assert resident < _COHERE_RESIDENT, resident
+    if program == "decode":
+        assert not moves, "\n".join(moves)
+        assert mem.temp_size_in_bytes < 256 << 20
