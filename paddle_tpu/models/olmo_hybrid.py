@@ -107,13 +107,6 @@ class OlmoHybridConfig:
         return (2 * self.linear_key_head_dim
                 + self.linear_value_head_dim) * self.linear_num_value_heads
 
-    @property
-    def kv_pool_heads(self) -> int:
-        """Head rows a token holds in the key and the value pool: the
-        heads, up to whole sublane tiles (``paged_decode_tiles`` takes
-        heads in eights; 30 heads lie in 32 rows, two of them zeros)."""
-        return -(-self.num_key_value_heads // 8) * 8
-
     def segments(self) -> List[Tuple[str, int, int]]:
         """The layers in order, as ``("linear", first, count)`` runs of the
         stacked linear layers and ``("full", index, 1)``."""
@@ -322,15 +315,6 @@ def _sequence_attention(q, k, v):
     return prefill_attention(q[None], k[None], v[None])[0]
 
 
-def _pool_rows(x, cfg):
-    """``[..., nh, hd]`` -> ``[..., kv_pool_heads, hd]``: zero head rows
-    up to whole sublane tiles."""
-    pad = cfg.kv_pool_heads - x.shape[-2]
-    if not pad:
-        return x
-    return jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, pad), (0, 0)])
-
-
 def _mix(x, out, p, cfg):
     return x + rms_norm(out, p["norm_mix"], cfg.rms_norm_eps)
 
@@ -389,8 +373,9 @@ def forward(params, tokens, cfg: OlmoHybridConfig):
 class OlmoHybridServing:
     """The model description ``DecodeEngine`` builds its paged prefill and
     decode programs from. The caches are ``(k pool, v pool, conv, ssm)``:
-    pools ``[Lf, pages, page, kv_pool_heads, hd]`` for the full layers
-    alone, ``conv [Ll, slots, (taps - 1) * Cc]`` in the cache's dtype and
+    pools ``[Lf, pages, page, nh * hd]`` for the full layers alone (a
+    token's heads flat in the lanes: 30 heads are 3,840 lanes, no padded
+    row), ``conv [Ll, slots, (taps - 1) * Cc]`` in the cache's dtype and
     ``ssm [Ll, slots, H / f, dk, f * dv]`` float32 (the matrix states,
     ``f`` heads folded into whole lane tiles)."""
     recurrent = True
@@ -405,14 +390,14 @@ class OlmoHybridServing:
         f = _gd.state_fold(H, dv)
         self.cache_pools = {
             "layers": len(cfg.full_layers),
-            "rows": ((cfg.kv_pool_heads, cfg.head_dim),) * 2}
+            "rows": ((cfg.num_key_value_heads * cfg.head_dim,),) * 2}
         self.state_geometry = {
             "layers": cfg.num_linear_layers,
             "conv": ((cfg.linear_conv_kernel_dim - 1) * cfg.conv_channels,),
             "ssm": (H // f, dk, f * dv)}
 
     def kernel_takes_pages(self, page_size, cache_dtype) -> bool:
-        return _pk.paged_decode_tiles(self.cfg.kv_pool_heads,
+        return _pk.paged_decode_tiles(self.cfg.num_key_value_heads,
                                       self.cfg.head_dim)
 
     def delta_chunks(self, tokens: int) -> int:
@@ -457,11 +442,9 @@ class OlmoHybridServing:
         def full_layer(h, p, a, caches):
             kp, vp, conv, ssm = caches
             q, k, v = _qkv(h, p, cfg)
-            rows = (T // ps, ps, cfg.kv_pool_heads, cfg.head_dim)
-            kp = paged_page_write(kp, _pool_rows(k, cfg).reshape(rows),
-                                  pages, a)
-            vp = paged_page_write(vp, _pool_rows(v, cfg).reshape(rows),
-                                  pages, a)
+            rows = (T // ps, ps) + kp.shape[3:]
+            kp = paged_page_write(kp, k.reshape(rows), pages, a)
+            vp = paged_page_write(vp, v.reshape(rows), pages, a)
             att = _sequence_attention(q, k, v)
             return (_mlp(_mix(h, _attn_out(att, p, cfg), p, cfg), p, cfg),
                     (kp, vp, conv, ssm))
@@ -478,7 +461,7 @@ class OlmoHybridServing:
         pages through the page table (``kv_path`` ``pallas_paged``) or
         gather them."""
         cfg = self.cfg
-        ps, nh = ctx.page_size, cfg.num_attention_heads
+        ps = ctx.page_size
         phys = jnp.take_along_axis(
             ctx.tables, (ctx.positions // ps)[:, None], axis=1)[:, 0]
         rows = ctx.positions % ps
@@ -496,18 +479,20 @@ class OlmoHybridServing:
 
         def full_layer(h, p, a, caches):
             kp, vp, conv, ssm = caches
-            q, k, v = (_pool_rows(t, cfg) for t in _qkv(h, p, cfg))
+            q, k, v = _qkv(h, p, cfg)
             if ctx.kv_path == "pallas_paged":
                 att, kp, vp = _pk.fused_paged_decode_attention(
                     q, kp, vp, k, v, ctx.tables, ctx.positions, layer=a)
             else:
-                kp = paged_cache_update(kp, k, phys, rows, a)
-                vp = paged_cache_update(vp, v, phys, rows, a)
-                att = decode_attention(q, paged_gather(kp, ctx.tables, a),
-                                       paged_gather(vp, ctx.tables, a),
-                                       ctx.positions + 1)
-            return (_mlp(_mix(h, _attn_out(att[:, :nh], p, cfg), p, cfg),
-                         p, cfg), (kp, vp, conv, ssm))
+                row = (k.shape[0],) + kp.shape[3:]
+                kp = paged_cache_update(kp, k.reshape(row), phys, rows, a)
+                vp = paged_cache_update(vp, v.reshape(row), phys, rows, a)
+                att = decode_attention(
+                    q, paged_gather(kp, ctx.tables, a, k.shape[1:]),
+                    paged_gather(vp, ctx.tables, a, k.shape[1:]),
+                    ctx.positions + 1)
+            return (_mlp(_mix(h, _attn_out(att, p, cfg), p, cfg), p, cfg),
+                    (kp, vp, conv, ssm))
 
         return _over_layers(cfg, qparams, x, caches, linear_layer,
                             full_layer)

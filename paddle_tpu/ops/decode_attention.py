@@ -182,13 +182,16 @@ def sliding_decode_attention(q, k_rows, v_rows, positions, kv_heads: int,
     return out.reshape(B, H, hd)
 
 
-def paged_gather(pool, tables, layer=None):
+def paged_gather(pool, tables, layer=None, heads=None):
     """Materialize per-slot contiguous cache views from a paged pool.
 
     pool:   [P, page, nh, hd]  (one layer's K or V page pool), or with
             ``layer`` the engine's whole [L, P, page, nh, hd] pool: the
             gather then indexes (layer, page) at once and no layer is
-            sliced out of the pool first
+            sliced out of the pool first. A pool of flat rows
+            ``[.., page, nh * hd]`` (what the page-table kernels read)
+            comes back as heads with ``heads=(nh, hd)``: a reshape of the
+            gathered temporary, never of the pool
     tables: [B, M] int32       (physical page per logical page per slot;
                                 unmapped entries point at the reserved
                                 scratch page — positions there are always
@@ -205,7 +208,8 @@ def paged_gather(pool, tables, layer=None):
     reference."""
     B, M = tables.shape
     g = pool[tables] if layer is None else pool[layer, tables]
-    return g.reshape((B, M * g.shape[2]) + g.shape[3:])
+    return g.reshape((B, M * g.shape[2])
+                     + (g.shape[3:] if heads is None else tuple(heads)))
 
 
 def _never_one_index(new, *index):
@@ -259,7 +263,8 @@ def paged_prefill_attention(q, k_all, v_all, prefix_len,
                                   ``prefix_len + i``
     k_all/v_all:[1, S, nh, hd]  — the slot's full gathered view (cached
                                   prefix rows + this call's suffix rows
-                                  already scattered in)
+                                  already scattered in), or the view of
+                                  a pool of flat rows, [1, S, nh * hd]
     prefix_len: scalar int32    — tokens already cached ahead of the
                                   suffix (page-aligned by the allocator)
 
@@ -269,8 +274,20 @@ def paged_prefill_attention(q, k_all, v_all, prefix_len,
     of the same positions agrees to float rounding."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    T, S = q.shape[1], k_all.shape[1]
-    scores = jnp.einsum("bqnh,bknh->bnqk", q.astype(jnp.float32),
+    (B, T, nh, hd), S = q.shape, k_all.shape[1]
+    flat = k_all.ndim == 3
+    if flat:
+        # a view of flat rows [B, S, nh * hd]: transposed, its major axis
+        # splits into heads for nothing (``[nh * hd, S]`` is ``[nh, hd,
+        # S]`` tile for tile), one pass over the view as the head rows'
+        # heads-major copy was; ``[S, nh, hd]`` would be re-tiled first and
+        # then laid out heads-major, two passes, and the heads' lane slices
+        # stacked are 2 x 16 small programs a layer (described compile, PR
+        # 42)
+        k_all, v_all = (jnp.swapaxes(x, 1, 2).reshape(B, nh, hd, S)
+                        for x in (k_all, v_all))
+    scores = jnp.einsum("bqnh,bnhk->bnqk" if flat else "bqnh,bknh->bnqk",
+                        q.astype(jnp.float32),
                         k_all.astype(jnp.float32)) * sm_scale
     mask = (jnp.arange(S)[None, :]
             <= prefix_len + jnp.arange(T)[:, None])[None, None]
@@ -279,7 +296,8 @@ def paged_prefill_attention(q, k_all, v_all, prefix_len,
     m = jnp.where(jnp.isfinite(m), m, 0.0)
     e = jnp.where(mask, jnp.exp(scores - m), 0.0)
     probs = e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
-    out = jnp.einsum("bnqk,bknh->bqnh", probs, v_all.astype(jnp.float32))
+    out = jnp.einsum("bnqk,bnhk->bqnh" if flat else "bnqk,bknh->bqnh",
+                     probs, v_all.astype(jnp.float32))
     return out.astype(q.dtype)
 
 

@@ -1497,89 +1497,61 @@ def use_opt_megakernel(override=None) -> bool:
 # runs on a TPU (engine.kv_path).
 
 
-# Block shapes the chip's compiler takes (tests/test_chip_compile.py): the
-# caches are [.., rows, nh, hd], so a block keeps ALL heads — its last two
-# dims are then the array's own, which Mosaic accepts at any nh/hd — and
-# the row axis is cut into chunks by the kernel's own copies of the live
-# pages, with flash-decoding's running max / sum / accumulator in VMEM
-# scratch. One query row per head makes the matmuls M=1, so scores and
-# the weighted sum run on the VPU as broadcast multiply + reduce in exact
-# f32, heads on sublanes and hd on lanes — no in-kernel transpose of the
-# head axis.
+# The decode tick reads its paged cache where it lies. A pool is
+# ``[L, P, page, kvh * hd]``: a token's keys (or values) of all ``kvh``
+# key/value heads flat in the lanes, a head a whole lane tile, so a page is
+# ``page`` sublane rows of whole lanes and a head's keys are a free lane
+# slice of it. The whole pool stays in HBM (``pl.ANY``); the layer index,
+# the page tables and the positions are scalar prefetch, and the kernel
+# copies just the pages that hold rows of a slot's span into VMEM, a chunk
+# of pages at a time, double-buffered across chunks AND slots. The work of
+# a tick is then the live pages' and nothing else's: no grid step, copy or
+# product exists for a page no token lives on. Each key/value head's query
+# rows against a chunk of its keys are one product on the MXU, and the
+# probabilities against its values another, all heads' products of a chunk
+# side by side (``_gqa_decode_kernel``: the fold runs within 2 % of its own
+# page copies, which reach three quarters of the HBM rate at pages of 64
+# KB and more at larger ones; ``_mla_decode_kernel`` is the same walk over
+# one shared latent row a token).
+_PAGED_CHUNK_BYTES = 1 << 20         # bytes of K (and of V) a chunk holds
+_QUERY_TILE = 8                      # query rows an equal head is given
 
 
-def _fold_rows(qf, kf, vf, pos, c, rows, m_scr, l_scr, acc_scr, *,
-               sm_scale):
-    """Fold rows [c*rows, (c+1)*rows) of one slot into the running
-    softmax. ``qf`` (nh, hd), ``kf``/``vf`` (rows, nh, hd), all float32;
-    the guards are those of ops/decode_attention.py. Rows past ``pos``
-    are masked in K AND in V: the tail of a paged chunk may be VMEM that
-    no copy has written."""
-    @pl.when(c == 0)
-    def _init():
-        m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
-        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
-        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
-
-    s = jnp.sum(qf[None] * kf, axis=2, keepdims=True) * sm_scale
-    valid = c * rows + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 0) < pos + 1                 # (rows, nh, 1)
-    s = jnp.where(valid, s, -jnp.inf)
-    m_prev = m_scr[...]                                  # (nh, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
-    m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-    alpha = jnp.exp(m_prev - m_safe)
-    e = jnp.where(valid, jnp.exp(s - m_safe[None]), 0.0)
-    vf = jnp.where(valid, vf, 0.0)
-    l_scr[...] = alpha * l_scr[...] + jnp.sum(e, axis=0)
-    acc_scr[...] = alpha * acc_scr[...] + jnp.sum(e * vf, axis=0)
-    m_scr[...] = m_new
-
-
-def _decode_finish(o_ref, l_scr, acc_scr):
-    o_ref[...] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-                  ).astype(o_ref.dtype)
-
-
-def _decode_scratch(nh, hd):
-    return [pltpu.VMEM((nh, 1), jnp.float32),
-            pltpu.VMEM((nh, 1), jnp.float32),
-            pltpu.VMEM((nh, hd), jnp.float32)]
-
-
-# the paged kernel reads the pool where it lies: the whole
-# ``[L, P, page, nh, hd]`` pool stays in HBM (``pl.ANY``), the layer
-# index, the page tables and the positions are scalar prefetch, and the
-# kernel copies just the pages below each slot's length into VMEM, ``G``
-# pages a chunk, double-buffered across chunks AND slots (one flat
-# sequence of work items: while chunk w folds, chunk w+1 is in flight).
-# The work of a tick is then the live pages' and nothing else's: no grid
-# step, copy or convert exists for a page no token lives on.
-_PAGED_CHUNK_BYTES = 1 << 20         # float32 bytes of K a chunk folds
+def _chunk_pages(max_pages: int, page: int, width: int, dtype) -> int:
+    """Pages a chunk of the paged kernels holds: a megabyte of keys (512
+    rows of 1,024 bfloat16 lanes, 256 of 2,048, 128 of 3,840), so that the
+    two double-buffered pools take 4 MB of VMEM at any row width. A K or V
+    tile of a product is 128 rows whatever the chunk, and a product works
+    on whole chunks where the copies are by live pages. Half and twice
+    the bytes read the same at 2,048 lanes (211.4, 211.4, 213.1 us a layer
+    at 128, 256, 512 rows), four times slower (221.5: my chip run, PR
+    42)."""
+    row = width * jnp.dtype(dtype).itemsize
+    return max(1, min(max_pages, _PAGED_CHUNK_BYTES // (page * row)))
 
 
 def paged_decode_kernel(num_heads: int, kv_heads: int,
                         head_dim: int) -> Optional[str]:
-    """Which paged decode kernel reads a cache of ``kv_heads`` key/value
-    heads for ``num_heads`` query heads of ``head_dim``, by name, or None
-    where the tick gathers: the one statement of the rule, asked by every
-    model description before the engine chooses ``kv_path``.
+    """Which entry to the paged decode kernel reads a cache of ``kv_heads``
+    key/value heads for ``num_heads`` query heads of ``head_dim``, by name,
+    or None where the tick gathers: the one statement of the rule, asked by
+    every model description before the engine chooses ``kv_path``.
 
-    * heads whose width is not whole lanes (64): none. A page's copy must
-      be whole tiles (tests/test_chip_compile.py).
-    * equal heads, in eights: :func:`paged_decode_attention`. A page lands
-      in VMEM as (page, nh, hd), heads on sublanes, one query row a head
-      on the VPU (12 heads, or the 30 a model may pad to 32, are refused).
+    * heads whose width is not whole lanes (64): none. A head is a lane
+      slice of a page's rows, and a page's copy must be whole tiles
+      (tests/test_chip_compile.py).
+    * equal heads, ANY count (16, or 30: 3,840 lanes):
+      :func:`paged_decode_attention`, the kernel at a group of one: each
+      head's one query row fills a sublane tile of its own.
     * grouped heads, each key/value head serving a multiple of eight
       query heads (128 over 8): :func:`gqa_paged_decode_attention`. A
-      group's query rows are whole sublane tiles of an MXU product; the
-      pool's rows are flat ``kv_heads * head_dim`` lanes.
+      group's query rows are whole sublane tiles.
     * any other grouping (20 query heads over 1: a group that is no whole
-      tile) gathers."""
+      tile, and no entry pads one) gathers."""
     if head_dim % NUM_LANES or num_heads % max(kv_heads, 1):
         return None
     if kv_heads == num_heads:
-        return "paged_decode_attention" if num_heads % 8 == 0 else None
+        return "paged_decode_attention"
     if (num_heads // kv_heads) % 8 == 0:
         return "gqa_paged_decode_attention"
     return None
@@ -1590,148 +1562,6 @@ def paged_decode_tiles(num_heads: int, head_dim: int) -> bool:
     for equal heads (:func:`paged_decode_kernel` has the rule). The engine
     asks before it chooses the kernel; interpret mode takes any shape."""
     return paged_decode_kernel(num_heads, num_heads, head_dim) is not None
-
-
-def _decode_paged_kernel(layer_ref, tbl_ref, pos_ref, q_ref, kp_hbm, vp_hbm,
-                         o_ref, kbuf, vbuf, sems, m_scr, l_scr, acc_scr,
-                         *, sm_scale, page, pages_per_chunk, batch):
-    G = pages_per_chunk
-    layer = layer_ref[0]
-
-    def live_pages(b):               # pages holding rows [0, pos[b]]
-        return pos_ref[b] // page + 1
-
-    def copies(b, c, buf, go):
-        """Start (``go``) or wait for the copies of chunk ``c`` of slot
-        ``b`` into buffer ``buf``: one per live page, K and V."""
-        n = live_pages(b)
-        for i in range(G):
-            pg = c * G + i
-
-            @pl.when(pg < n)
-            def _():
-                phys = tbl_ref[b, pg]
-                for hbm, vmem, kv in ((kp_hbm, kbuf, 0), (vp_hbm, vbuf, 1)):
-                    cp = pltpu.make_async_copy(
-                        hbm.at[layer, phys], vmem.at[buf, i],
-                        sems.at[kv, buf])
-                    if go:
-                        cp.start()
-                    else:
-                        cp.wait()
-
-    copies(0, 0, 0, True)
-
-    def slot(b, w):
-        pos = pos_ref[b]
-        nc = (live_pages(b) + G - 1) // G
-
-        def chunk(c, w):
-            buf = w % 2
-            # the next work item: this slot's next chunk, or the next
-            # slot's first
-            last = c + 1 == nc
-            nb = jnp.where(last, b + 1, b)
-            nxt = jnp.where(last, 0, c + 1)
-
-            @pl.when(nb < batch)
-            def _prefetch():
-                copies(nb, nxt, 1 - buf, True)
-
-            copies(b, c, buf, False)
-            R = G * page
-            k = kbuf[buf].reshape((R,) + kbuf.shape[3:])
-            v = vbuf[buf].reshape((R,) + vbuf.shape[3:])
-            _fold_rows(q_ref[b].astype(jnp.float32),
-                       k.astype(jnp.float32), v.astype(jnp.float32), pos,
-                       c, R, m_scr, l_scr, acc_scr, sm_scale=sm_scale)
-            return w + 1
-
-        w = jax.lax.fori_loop(0, nc, chunk, w)
-        _decode_finish(o_ref.at[b], l_scr, acc_scr)
-        return w
-
-    jax.lax.fori_loop(0, batch, slot, 0)
-
-
-def paged_decode_attention(q, k_pool, v_pool, layer, tables, positions,
-                           sm_scale=None):
-    """One-token attention read through the page table: the pool is not
-    sliced, gathered or converted outside the kernel.
-
-    q [B, nh, hd]; k_pool/v_pool [L, P, page, nh, hd] (the engine's stored
-    layout, this step's rows already written); layer: int32 scalar
-    (traced: the layer loop's variable); tables [B, M] int32; positions
-    [B] int32 in [0, M*page): slot b attends rows [0, positions[b]] of
-    its pages ``tables[b, :positions[b]//page + 1]`` and touches no
-    other. A dead lane (all-zero table, position 0) reads one row of the
-    scratch page. Returns [B, nh, hd] in q's dtype."""
-    B, M = tables.shape
-    L, P, page, nh, hd = k_pool.shape
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    _count_launch("decode_paged")
-    # pages a chunk: 128 rows at 16 heads of 128, fewer rows for more
-    # heads, so that a chunk's float32 K (and V, and their product) stays
-    # a megabyte of VMEM
-    G = max(1, min(M, _PAGED_CHUNK_BYTES // (4 * nh * hd * page)))
-    rows_spec = pl.BlockSpec((B, nh, hd), lambda i, *_: (0, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3, grid=(1,),
-        in_specs=[rows_spec,
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=rows_spec,
-        scratch_shapes=[pltpu.VMEM((2, G, page, nh, hd), k_pool.dtype),
-                        pltpu.VMEM((2, G, page, nh, hd), v_pool.dtype),
-                        pltpu.SemaphoreType.DMA((2, 2))]
-        + _decode_scratch(nh, hd))
-    with jax.named_scope("paged_decode_attention"):
-        return pl.pallas_call(
-            functools.partial(_decode_paged_kernel, sm_scale=sm_scale,
-                              page=page, pages_per_chunk=G, batch=B),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, nh, hd), q.dtype),
-            compiler_params=_CompilerParams(
-                dimension_semantics=("arbitrary",)),
-            interpret=_interpret(),
-            name="paged_decode_attention",
-        )(jnp.reshape(layer, (1,)).astype(jnp.int32),
-          tables.astype(jnp.int32), positions.astype(jnp.int32),
-          q, k_pool, v_pool)
-
-
-def fused_paged_decode_attention(q, k_pool, v_pool, new_k, new_v, tables,
-                                 positions, layer=None, sm_scale=None):
-    """The paged decode step of one layer: row write through the page
-    table + :func:`paged_decode_attention` (subsumes paged_cache_update +
-    paged_gather + decode_attention).
-
-    q/new_k/new_v [B, nh, hd]; k_pool/v_pool [L, P, page, nh, hd] with
-    ``layer`` (the engine's carried pools), or one layer's
-    [P, page, nh, hd] without; tables [B, M] int32 (all-zero rows = dead
-    lanes writing the scratch page); positions [B] int32 in [0, M*page).
-
-    Returns (out [B, nh, hd], k_pool', v_pool'): the row write is a
-    scatter of B rows on the pool, in place under donation or as a
-    loop's carry; the kernel only reads.
-    """
-    from .decode_attention import paged_cache_update
-
-    one_layer = layer is None
-    if one_layer:
-        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
-    page = k_pool.shape[2]
-    phys = jnp.take_along_axis(
-        tables, (positions // page)[:, None], axis=1)[:, 0]
-    rows = positions % page
-    k_pool = paged_cache_update(k_pool, new_k, phys, rows, layer=layer)
-    v_pool = paged_cache_update(v_pool, new_v, phys, rows, layer=layer)
-    out = paged_decode_attention(q, k_pool, v_pool, layer, tables,
-                                 positions, sm_scale=sm_scale)
-    if one_layer:
-        k_pool, v_pool = k_pool[0], v_pool[0]
-    return out, k_pool, v_pool
 
 
 # Latent (MLA) decode attention in absorbed form. The cache holds ONE row a
@@ -1746,7 +1576,7 @@ def fused_paged_decode_attention(q, k_pool, v_pool, new_k, new_v, tables,
 # is a slice of its key: M = heads, so scores and the weighted sum run on
 # the MXU. The pool ``[L, P, page, rank + rope]`` stays in HBM, one grid
 # step a slot (its query and output blocks are pipelined by Pallas), and
-# the page copies of ``_decode_paged_kernel``, double-buffered across chunks
+# the page copies of ``_gqa_decode_kernel``, double-buffered across chunks
 # AND grid steps: the parity of the flat work-item count lives in SMEM.
 _MLA_CHUNK_ROWS = 512
 
@@ -1893,25 +1723,30 @@ def mla_paged_decode_attention(q_lat, pool, new_rows, tables, positions,
     return out, pool
 
 
-# Grouped-query decode attention over a page table, with a lower bound. The
-# pool ``[L, P, page, kvh * hd]`` holds a token's keys (or values) of all
-# ``kvh`` key/value heads flat in the lanes (a page is ``page`` sublane rows
-# of whole lanes: bytes held are bytes of values, where ``[.., page, 8,
-# 128]`` in bfloat16 would be stored in tiles of 16 rows, half of them
-# padding). Each key/value head serves ``g = H / kvh`` query heads: their
-# rows against a chunk of the head's keys are one ``[g, hd] x [hd, rows]``
+# Decode attention over a page table, grouped heads, with a lower bound.
+# Each key/value head serves ``g = H / kvh`` query heads: their rows
+# against a chunk of the head's keys are one ``[g, hd] x [hd, rows]``
 # product on the MXU. One grid step a slot; the live pages of ``(position -
 # window, position]`` (``window`` None: ``[0, position]``) are copied in as
 # in ``_mla_decode_kernel``, double-buffered across chunks AND grid steps.
 # ``ring``: the table is a ring, logical page ``j`` at entry ``j % ring``
-# (serving/paged_kv.py: a window group's table).
-_GQA_CHUNK_ROWS = 512
+# (serving/paged_kv.py: a window group's table). Only the chunks that hold
+# an end of the span are masked: the last (rows past ``position`` may be
+# VMEM no copy has written) and, under a window, the first.
+#
+# ``split`` (equal heads, :func:`paged_decode_attention`): every row of a
+# head's tile is a copy of its one query row, so all rows carry the same
+# probabilities ``e``. Rounded to a pool narrower than float32 they would
+# lose 2^-9 each; row 0 takes ``e_hi = round(e)`` and the rows behind it
+# the remainder ``e_lo = round(e - e_hi)`` through the SAME product with
+# the same loaded V tile, and the two are summed at the end: probabilities
+# to 2^-17, at no tile load.
 
 
 def _gqa_decode_kernel(layer_ref, tbl_ref, pos_ref, q_ref, kp_hbm, vp_hbm,
                        o_ref, kbuf, vbuf, sems, w_smem, m_scr, l_scr,
                        acc_scr, *, sm_scale, page, pages_per_chunk, batch,
-                       kv_heads, window, ring):
+                       kv_heads, window, ring, split):
     G = pages_per_chunk
     layer = layer_ref[0]
     b = pl.program_id(0)
@@ -1955,6 +1790,55 @@ def _gqa_decode_kernel(layer_ref, tbl_ref, pos_ref, q_ref, kp_hbm, vp_hbm,
     R = G * page
     floor = -1 if window is None else pos - window
 
+    def fold(c, slot, masked):
+        """Chunk ``c`` into the running softmax; ``masked``: the chunk may
+        hold rows outside the span. Three passes, each over ALL heads:
+        the score products, one softmax step on ``(H, R)``, the value
+        products. Head after head, every head's chain of product,
+        reduction, exponential and product waits for itself (0.12 us a
+        head and chunk, twice the copies' time at 16 heads and 256 rows:
+        my chip run, PR 42); side by side the products follow each other
+        through the MXU."""
+        k = kbuf[slot].reshape(R, kbuf.shape[-1])
+        v = vbuf[slot].reshape(R, vbuf.shape[-1])
+        H = kv_heads * group
+        heads = [(slice(h * group, (h + 1) * group),
+                  slice(h * hd, (h + 1) * hd)) for h in range(kv_heads)]
+        s = jnp.concatenate([
+            jax.lax.dot_general(
+                q_ref[0, rows, :], k[:, lanes], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            for rows, lanes in heads], axis=0) * sm_scale       # (H, R)
+        m_prev = m_scr[...]                                     # (H, 1)
+        if masked:
+            # masked in the scores AND zeroed where they are values
+            at = lo_row + c * R + jax.lax.broadcasted_iota(
+                jnp.int32, (R, 1), 0)
+            v = jnp.where(at <= pos, v, 0).astype(v.dtype)
+            at = lo_row + c * R + jax.lax.broadcasted_iota(
+                jnp.int32, (H, R), 1)
+            valid = (at <= pos) & (at > floor)
+            s = jnp.where(valid, s, -jnp.inf)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        if masked:
+            m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+            e = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
+        else:
+            m_safe = m_new
+            e = jnp.exp(s - m_safe)
+        alpha = jnp.exp(m_prev - m_safe)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(e, axis=1, keepdims=True)
+        p = e.astype(v.dtype)
+        if split:
+            behind = jax.lax.broadcasted_iota(
+                jnp.int32, (H, R), 0) % group > 0
+            p = jnp.where(behind, (e - p.astype(jnp.float32)
+                                   ).astype(v.dtype), p)
+        acc_scr[...] = alpha * acc_scr[...] + jnp.concatenate([
+            jnp.dot(p[rows], v[:, lanes], preferred_element_type=jnp.float32)
+            for rows, lanes in heads], axis=0)                  # (H, hd)
+        m_scr[...] = m_new
+
     def chunk(c, w):
         slot = w % 2
         last = c + 1 == nc
@@ -1973,38 +1857,73 @@ def _gqa_decode_kernel(layer_ref, tbl_ref, pos_ref, q_ref, kp_hbm, vp_hbm,
             l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
             acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-        k = kbuf[slot].reshape(R, kbuf.shape[-1])
-        v = vbuf[slot].reshape(R, vbuf.shape[-1])
-        # rows past the slot's length may be VMEM no copy has written:
-        # masked in the scores AND zeroed where they are values
-        at = lo_row + c * R + jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
-        v = jnp.where(at <= pos, v, 0).astype(v.dtype)
-        at = lo_row + c * R + jax.lax.broadcasted_iota(
-            jnp.int32, (group, R), 1)
-        valid = (at <= pos) & (at > floor)
-        for h in range(kv_heads):
-            rows = slice(h * group, (h + 1) * group)
-            lanes = slice(h * hd, (h + 1) * hd)
-            s = jax.lax.dot_general(
-                q_ref[0, rows, :], k[:, lanes], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale  # (group, R)
-            s = jnp.where(valid, s, -jnp.inf)
-            m_prev = m_scr[rows, :]                              # (group, 1)
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-            alpha = jnp.exp(m_prev - m_safe)
-            e = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
-            l_scr[rows, :] = alpha * l_scr[rows, :] + jnp.sum(
-                e, axis=1, keepdims=True)
-            acc_scr[rows, :] = alpha * acc_scr[rows, :] + jnp.dot(
-                e.astype(v.dtype), v[:, lanes],
-                preferred_element_type=jnp.float32)
-            m_scr[rows, :] = m_new
+        ends = last if window is None else last | (c == 0)
+        pl.when(ends)(lambda: fold(c, slot, True))
+        pl.when(jnp.logical_not(ends))(lambda: fold(c, slot, False))
         return w + 1
 
     w_smem[0] = jax.lax.fori_loop(0, nc, chunk, w_smem[0])
-    o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-                ).astype(o_ref.dtype)
+    acc = acc_scr[...]
+    if split:                        # row 0 of a tile: e_hi's sum + e_lo's
+        acc = acc + pltpu.roll(acc, acc.shape[0] - 1, 0)
+    o_ref[0] = (acc / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+
+
+def _paged_attention_call(name, q, k_pool, v_pool, layer, tables, positions,
+                          *, kv_heads, window=None, ring=False,
+                          sm_scale=None, split=False):
+    """The one builder of the kernel's call: q ``[B, H, hd]`` (``H /
+    kv_heads`` rows a key/value head, whole sublane tiles) against the
+    pools ``[L, P, page, kv_heads * hd]``, this tick's rows already
+    written; ``name`` is the device operation's."""
+    B, M = tables.shape
+    page, width = k_pool.shape[2], k_pool.shape[3]
+    H, hd = q.shape[1], q.shape[2]
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(hd)
+    G = _chunk_pages(M, page, width, k_pool.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(B,),
+        in_specs=[pl.BlockSpec((1, H, hd), lambda b, *_: (b, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, H, hd), lambda b, *_: (b, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, G, page, width), k_pool.dtype),
+                        pltpu.VMEM((2, G, page, width), v_pool.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((1,), jnp.int32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, hd), jnp.float32)])
+    return pl.pallas_call(
+        functools.partial(
+            _gqa_decode_kernel, sm_scale=sm_scale, page=page,
+            pages_per_chunk=G, batch=B, kv_heads=kv_heads,
+            window=window, ring=M if ring else 0, split=split),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
+        compiler_params=_CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(),
+        name=name,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      tables.astype(jnp.int32), positions.astype(jnp.int32),
+      q.astype(k_pool.dtype), k_pool, v_pool)
+
+
+def _write_rows(k_pool, v_pool, new_k, new_v, tables, positions, layer,
+                ring=False):
+    """This tick's rows ``new_k``/``new_v [B, kv_heads * hd]`` through the
+    page table: a scatter of B rows on each carried pool."""
+    from .decode_attention import paged_cache_update
+
+    M, page = tables.shape[1], k_pool.shape[2]
+    logical = positions // page
+    phys = jnp.take_along_axis(
+        tables, (logical % M if ring else logical)[:, None], axis=1)[:, 0]
+    return tuple(paged_cache_update(pool, new, phys, positions % page,
+                                    layer=layer)
+                 for pool, new in ((k_pool, new_k), (v_pool, new_v)))
 
 
 def gqa_paged_decode_attention(q, k_pool, v_pool, new_k, new_v, tables,
@@ -2024,50 +1943,72 @@ def gqa_paged_decode_attention(q, k_pool, v_pool, new_k, new_v, tables,
     logical page ``j`` at ``j % M``; positions ``[B]`` int32; layer: int32
     scalar (traced). Returns ``(out [B, H, hd] in q's dtype, k_pool',
     v_pool')``."""
-    from .decode_attention import paged_cache_update
-
-    B, M = tables.shape
-    page, width = k_pool.shape[2], k_pool.shape[3]
-    H, hd = q.shape[1], q.shape[2]
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(hd)
-    logical = positions // page
-    phys = jnp.take_along_axis(
-        tables, (logical % M if ring else logical)[:, None], axis=1)[:, 0]
-    k_pool = paged_cache_update(k_pool, new_k, phys, positions % page,
-                                layer=layer)
-    v_pool = paged_cache_update(v_pool, new_v, phys, positions % page,
-                                layer=layer)
+    k_pool, v_pool = _write_rows(k_pool, v_pool, new_k, new_v, tables,
+                                 positions, layer, ring)
     _count_launch("gqa_paged_decode")
-    G = max(1, min(M, _GQA_CHUNK_ROWS // page))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3, grid=(B,),
-        in_specs=[pl.BlockSpec((1, H, hd), lambda b, *_: (b, 0, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((1, H, hd), lambda b, *_: (b, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((2, G, page, width), k_pool.dtype),
-                        pltpu.VMEM((2, G, page, width), v_pool.dtype),
-                        pltpu.SemaphoreType.DMA((2, 2)),
-                        pltpu.SMEM((1,), jnp.int32),
-                        pltpu.VMEM((H, 1), jnp.float32),
-                        pltpu.VMEM((H, 1), jnp.float32),
-                        pltpu.VMEM((H, hd), jnp.float32)])
     with jax.named_scope("gqa_paged_decode_attention"):
-        out = pl.pallas_call(
-            functools.partial(
-                _gqa_decode_kernel, sm_scale=sm_scale, page=page,
-                pages_per_chunk=G, batch=B, kv_heads=kv_heads,
-                window=window, ring=M if ring else 0),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
-            compiler_params=_CompilerParams(
-                dimension_semantics=("arbitrary",)),
-            interpret=_interpret(),
-            name="gqa_paged_decode",
-        )(jnp.reshape(layer, (1,)).astype(jnp.int32),
-          tables.astype(jnp.int32), positions.astype(jnp.int32),
-          q.astype(k_pool.dtype), k_pool, v_pool)
+        out = _paged_attention_call(
+            "gqa_paged_decode", q, k_pool, v_pool, layer, tables, positions,
+            kv_heads=kv_heads, window=window, ring=ring, sm_scale=sm_scale)
+    return out, k_pool, v_pool
+
+
+def paged_decode_attention(q, k_pool, v_pool, layer, tables, positions,
+                           sm_scale=None):
+    """One-token attention of equal heads read through the page table: the
+    kernel at a group of one. The pool is not sliced, gathered or converted
+    outside it.
+
+    q [B, nh, hd]; k_pool/v_pool [L, P, page, nh * hd] (the engine's stored
+    layout, this step's rows already written); layer: int32 scalar
+    (traced: the layer loop's variable); tables [B, M] int32; positions
+    [B] int32 in [0, M*page): slot b attends rows [0, positions[b]] of
+    its pages ``tables[b, :positions[b]//page + 1]`` and touches no
+    other. A dead lane (all-zero table, position 0) reads one row of the
+    scratch page. Returns [B, nh, hd] in q's dtype.
+
+    A head's one query row is laid out as a whole sublane tile (its
+    copies: the MXU takes tiles, and the scores of a tile are one float32
+    vreg a 128 keys), and the copies carry what a narrow pool's rounding
+    would drop of the probabilities (``_gqa_decode_kernel``, ``split``)."""
+    B, nh, hd = q.shape
+    _count_launch("decode_paged")
+    rows = jnp.broadcast_to(q[:, :, None], (B, nh, _QUERY_TILE, hd))
+    with jax.named_scope("paged_decode_attention"):
+        out = _paged_attention_call(
+            "paged_decode_attention", rows.reshape(B, nh * _QUERY_TILE, hd),
+            k_pool, v_pool, layer, tables, positions, kv_heads=nh,
+            sm_scale=sm_scale,
+            split=jnp.dtype(k_pool.dtype).itemsize < 4)
+    return out.reshape(B, nh, _QUERY_TILE, hd)[:, :, 0]
+
+
+def fused_paged_decode_attention(q, k_pool, v_pool, new_k, new_v, tables,
+                                 positions, layer=None, sm_scale=None):
+    """The paged decode step of one layer of equal heads: row write through
+    the page table + :func:`paged_decode_attention` (subsumes
+    paged_cache_update + paged_gather + decode_attention).
+
+    q/new_k/new_v [B, nh, hd]; k_pool/v_pool [L, P, page, nh * hd] with
+    ``layer`` (the engine's carried pools), or one layer's
+    [P, page, nh * hd] without; tables [B, M] int32 (all-zero rows = dead
+    lanes writing the scratch page); positions [B] int32 in [0, M*page).
+
+    Returns (out [B, nh, hd], k_pool', v_pool'): the row write is a
+    scatter of B rows on the pool, in place under donation or as a
+    loop's carry; the kernel only reads.
+    """
+    one_layer = layer is None
+    if one_layer:
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
+    B = q.shape[0]
+    k_pool, v_pool = _write_rows(
+        k_pool, v_pool, new_k.reshape(B, -1), new_v.reshape(B, -1), tables,
+        positions, layer)
+    out = paged_decode_attention(q, k_pool, v_pool, layer, tables,
+                                 positions, sm_scale=sm_scale)
+    if one_layer:
+        k_pool, v_pool = k_pool[0], v_pool[0]
     return out, k_pool, v_pool
 
 

@@ -278,6 +278,7 @@ class DecodeEngine:
         self._mesh = None
         self._param_sh = None
         self._cache_sh = None
+        self._pool_rows = None       # the model's own, unless the mesh's
         self._repl_sh = None
         if ecfg.sharding not in (None, "tp"):
             raise ValueError(f"sharding {ecfg.sharding!r}: expected None "
@@ -303,11 +304,14 @@ class DecodeEngine:
         # (a model whose layers keep different spans names several page
         # groups, each with its pools, free list and table a slot:
         # docs/serving.md "Window and global layers")
+        num_heads, head_dim = pools.get("heads", (0, 0))
         self.cache = PagedKVCache(
             pools.get("layers", 0), ecfg.max_batch, ecfg.max_seq,
+            num_heads=num_heads, head_dim=head_dim,
             dtype=cache_dtype, page_size=ecfg.page_size,
             num_pages=ecfg.num_pages, state=self.model.state_geometry,
-            rows=pools.get("rows"), groups=pools.get("groups"))
+            rows=self._pool_rows or pools.get("rows"),
+            groups=pools.get("groups"))
         self.prefix = (PrefixCache(self.cache, ecfg.prefix_cache_pages)
                        if ecfg.prefix_cache else None)
         # pool pressure reclaims the pages only the prefix cache holds
@@ -512,7 +516,10 @@ class DecodeEngine:
         specs, self.tp_derived = complete_pytree_specs(
             qparams, ann, {"tp": tp})
         self._param_sh = named_sharding_tree(specs, self._mesh)
-        # the pool [L, P, page, nh, hd] carries the KV head axis at dim 3
+        # the plan splits the KV head axis, which flat rows no longer
+        # show: a sharded engine's pools are [L, P, page, nh, hd], the
+        # head axis at dim 3, and its tick gathers
+        self._pool_rows = ((self.cfg.num_heads, self.cfg.head_dim),) * 2
         self._cache_sh = NamedSharding(
             self._mesh, P(None, None, None, "tp", None))
         self._repl_sh = NamedSharding(self._mesh, P())
@@ -596,15 +603,14 @@ class DecodeEngine:
         def body(h, layer_p, l, kp, vp):
             h1 = ln(h, layer_p["ln1_scale"], layer_p["ln1_bias"])
             q, k, v = _qkv_heads(h1, layer_p, cfg)
-            nh, hd = k.shape[2], k.shape[3]
+            row = (B * W,) + kp.shape[3:]
             kp = paged_cache_update(
-                kp, k.reshape(B * W, nh, hd),
-                phys.reshape(-1), rows.reshape(-1), l)
+                kp, k.reshape(row), phys.reshape(-1), rows.reshape(-1), l)
             vp = paged_cache_update(
-                vp, v.reshape(B * W, nh, hd),
-                phys.reshape(-1), rows.reshape(-1), l)
-            a = window_attention(q, paged_gather(kp, tables, l),
-                                 paged_gather(vp, tables, l), starts)
+                vp, v.reshape(row), phys.reshape(-1), rows.reshape(-1), l)
+            a = window_attention(q, paged_gather(kp, tables, l, k.shape[2:]),
+                                 paged_gather(vp, tables, l, k.shape[2:]),
+                                 starts)
             return _block_tail(h, a, layer_p, dt, ln, "bw"), kp, vp
 
         x, kp, vp = _layers_over_pools(body, x, kp, vp, params["blocks"])

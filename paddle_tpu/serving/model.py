@@ -9,10 +9,13 @@ description is any object with:
   ``max_positions`` (``None`` where no positional table bounds ``max_seq``);
 - ``cache_pools``: ``{"layers": n, "rows": (shape, ...)}``: the page pools
   it asks of ``PagedKVCache``, one ``[layers, pages, page, *shape]`` a
-  row shape, the attention layers alone: ``((heads, head_dim),) * 2`` for
-  keys and values, ``((width,),)`` for one pool of latent rows (then
-  ``latent`` is true); or, where its layers keep different spans of the
-  context, ``{"groups": [{"name", "layers", "rows", "window"}, ...]}``:
+  row shape, the attention layers alone: ``((heads * head_dim,),) * 2``
+  for keys and values (a token's heads flat in the lanes, what the
+  page-table kernel reads; with ``"heads": (heads, head_dim)`` where page
+  contents travel as heads: prefix store, KV hand-off), ``((width,),)``
+  for one pool of latent rows (then ``latent`` is true); or, where its
+  layers keep different spans of the context,
+  ``{"groups": [{"name", "layers", "rows", "window"}, ...]}``:
   several page groups under the one manager, each with its pools, free
   list and a table a slot, a group with a ``window`` bounded by it
   (``serving/paged_kv.py``); the programs are then handed the groups'
@@ -92,7 +95,7 @@ def embed_rows(qparams, tokens, positions, dt):
 def layers_over_pools(body, x, kp, vp, blocks):
     """Run ``body(h, layer_p, l, kp, vp) -> (h, kp, vp)`` over the stacked
     ``blocks`` with both KV pools as the loop's CARRY, in their stored
-    ``[L, P, page, nh, hd]`` layout, and the layer index ``l`` a loop
+    ``[L, P, page, nh * hd]`` layout, and the layer index ``l`` a loop
     variable. A scan's ``xs``/``ys`` would slice a layer out of each pool
     and re-stack it into a new buffer every iteration; a carry is updated
     in place, so the donated pools alias the outputs and a program
@@ -162,8 +165,16 @@ class GPTServing:
         self.cfg = cfg
         self.vocab_size = cfg.vocab_size
         self.max_positions = cfg.max_seq_len
+        # a token's keys (and values) of all heads flat in the lanes, a
+        # head a lane tile: what the page-table kernel reads
+        # (ops/pallas_kernels.py). The bodies below follow the pool's own
+        # row shape: a tensor-parallel engine, whose plan splits the head
+        # axis, keeps ``(nh, hd)`` rows (serving/engine.py:_init_tp).
+        # ``heads``: what a row is where page contents travel (the prefix
+        # store, the KV hand-off).
         self.cache_pools = {"layers": cfg.num_layers,
-                            "rows": ((cfg.num_heads, cfg.head_dim),) * 2}
+                            "heads": (cfg.num_heads, cfg.head_dim),
+                            "rows": ((cfg.num_heads * cfg.head_dim,),) * 2}
 
     def kernel_takes_pages(self, page_size, cache_dtype) -> bool:
         c = self.cfg
@@ -222,14 +233,12 @@ class GPTServing:
         def body(h, layer_p, l, kp, vp):
             h1 = ln(h, layer_p["ln1_scale"], layer_p["ln1_bias"])
             q, k, v = qkv_heads(h1, layer_p, self.cfg)
-            nh, hd = k.shape[2], k.shape[3]
-            kp = paged_page_write(
-                kp, k[0].reshape(n_pages, ps, nh, hd), suffix_pages, l)
-            vp = paged_page_write(
-                vp, v[0].reshape(n_pages, ps, nh, hd), suffix_pages, l)
-            k_all = paged_gather(kp, ctx.table_row[None], l)
-            v_all = paged_gather(vp, ctx.table_row[None], l)
-            a = paged_prefill_attention(q, k_all, v_all, ctx.prefix_len)
+            pages = (n_pages, ps) + kp.shape[3:]
+            kp = paged_page_write(kp, k[0].reshape(pages), suffix_pages, l)
+            vp = paged_page_write(vp, v[0].reshape(pages), suffix_pages, l)
+            a = paged_prefill_attention(
+                q, paged_gather(kp, ctx.table_row[None], l),
+                paged_gather(vp, ctx.table_row[None], l), ctx.prefix_len)
             return block_tail(h, a, layer_p, dt, ln, "bt"), kp, vp
 
         x, kp, vp = layers_over_pools(
@@ -257,11 +266,12 @@ class GPTServing:
             rows = positions % ps
 
             def write_and_attend(q, k, v, kp, vp, l):
-                kp = paged_cache_update(kp, k, phys, rows, l)
-                vp = paged_cache_update(vp, v, phys, rows, l)
-                a = decode_attention(q, paged_gather(kp, tables, l),
-                                     paged_gather(vp, tables, l),
-                                     positions + 1)
+                row = (k.shape[0],) + kp.shape[3:]
+                kp = paged_cache_update(kp, k.reshape(row), phys, rows, l)
+                vp = paged_cache_update(vp, v.reshape(row), phys, rows, l)
+                a = decode_attention(
+                    q, paged_gather(kp, tables, l, k.shape[1:]),
+                    paged_gather(vp, tables, l, k.shape[1:]), positions + 1)
                 return a, kp, vp
 
         def body(h, layer_p, l, kp, vp):

@@ -4,7 +4,9 @@ docs/serving.md).
 A private ``[max_seq]`` run of rows for every slot would hold 8 slots x
 1024 positions of HBM even when seven of them hold 12-token chats. This
 module is the engine's one cache, a **page pool**: one preallocated
-``[L, num_pages, page_size, nh, hd]`` K/V pair, fixed-size pages handed
+``[L, num_pages, page_size, *row]`` K/V pair (a token's row: ``nh * hd``
+flat lanes as the models here ask, ``(nh, hd)`` by default and under a
+mesh), fixed-size pages handed
 out from a host-side free list, and a per-slot **page table**
 (``[max_pages_per_slot]`` int32 of physical page ids) that rides into the
 decode/prefill executables as a plain device array — so long-context
@@ -569,6 +571,14 @@ class PagedKVCache:
         the prefix cache's reclaim) when the pool cannot cover it."""
         return self._take_pages(int(n))
 
+    def _wire_row(self) -> Tuple[int, ...]:
+        """A token's row of one layer as page contents travel: ``(nh,
+        hd)`` where the manager knows the heads, else the pool's own."""
+        row = self.rows[0]
+        if self.num_heads * self.head_dim == int(np.prod(row)):
+            return (self.num_heads, self.head_dim)
+        return row
+
     def _keys_and_values_only(self, what: str) -> None:
         if not self.keys_and_values:
             raise ValueError(
@@ -590,8 +600,11 @@ class PagedKVCache:
             # bucket shares one compiled shape (zero-recompile contract)
             idx = np.concatenate([idx, np.zeros(pad, np.int32)])
         k, v = _gather_pages_exec(self.k, self.v, idx)
-        k = np.asarray(k)
-        v = np.asarray(v)
+        # on the wire a row is (nh, hd) whatever the pool's rows are (flat
+        # lanes here, the head axis under a mesh): a reshape on the host
+        wire = k.shape[:3] + self._wire_row()
+        k = np.asarray(k).reshape(wire)
+        v = np.asarray(v).reshape(wire)
         return (k[:, :n], v[:, :n]) if pad else (k, v)
 
     def write_pages(self, pages: Sequence[int], k_pages: np.ndarray,
@@ -605,6 +618,8 @@ class PagedKVCache:
         pad = -n % TRANSFER_PAGE_BUCKET
         k_pages = np.asarray(k_pages)
         v_pages = np.asarray(v_pages)
+        k_pages, v_pages = (a.reshape(a.shape[:3] + r)
+                            for a, r in zip((k_pages, v_pages), self.rows))
         if pad:
             # pad the scatter with writes to the scratch page (whose
             # contents are garbage by contract) so every group in a

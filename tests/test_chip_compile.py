@@ -142,16 +142,16 @@ ROW = ((SERVE_B, NH, HD), BF16)
 
 def test_fused_decode_paged(one_chip):
     m = SERVE_S // PAGE
-    pool = ((1 + SERVE_B * m, PAGE, NH, HD), BF16)
+    pool = ((1 + SERVE_B * m, PAGE, NH * HD), BF16)
     _compile(PK.fused_paged_decode_attention, one_chip, ROW, pool, pool,
              ROW, ROW, ((SERVE_B, m), jnp.int32), ((SERVE_B,), jnp.int32))
 
 
 def test_paged_decode_attention_layer_indexed(one_chip):
     """The kernel of the paged tick at the serving cell's widths: the
-    whole [L, P, page, nh, hd] pool in HBM, layer index, page tables and
+    whole [L, P, page, nh * hd] pool in HBM, layer index, page tables and
     positions as scalar prefetch."""
-    pool = ((CELL_L, CELL_PAGES, PAGE, NH, HD), BF16)
+    pool = ((CELL_L, CELL_PAGES, PAGE, NH * HD), BF16)
     row = ((CELL_B, NH, HD), BF16)
     _compile(PK.paged_decode_attention, one_chip, row, pool, pool,
              ((), jnp.int32), ((CELL_B, CELL_S // PAGE), jnp.int32),
@@ -325,9 +325,10 @@ def test_gpt_wide_decode_tick(one_chip, fused):
 
 
 def test_paged_engine_gathers_where_mosaic_refuses_the_page(one_chip):
-    """Heads of 16 (GPT_TINY): Mosaic refuses the kernel's page copies
-    (a slice must be whole (8, 128) tiles), the engine sees it from the
-    shapes (``paged_decode_tiles``) and its tick gathers instead."""
+    """Heads of 16 (GPT_TINY): a head is no whole lane tile of a page's
+    rows, so Mosaic refuses the kernel's slice of one; the engine sees it
+    from the shapes (``paged_decode_tiles``) and its tick gathers
+    instead."""
     from paddle_tpu import serving
     from paddle_tpu.models import gpt as G
 
@@ -345,8 +346,8 @@ def test_paged_engine_gathers_where_mosaic_refuses_the_page(one_chip):
     lowered.compile()
     with pytest.raises(Exception, match="aligned to tiling"):
         _compile(PK.paged_decode_attention, one_chip,
-                 ((4, 4, 16), BF16), ((2, 17, 8, 4, 16), BF16),
-                 ((2, 17, 8, 4, 16), BF16), ((), jnp.int32),
+                 ((4, 4, 16), BF16), ((2, 17, 8, 4 * 16), BF16),
+                 ((2, 17, 8, 4 * 16), BF16), ((), jnp.int32),
                  ((4, 4), jnp.int32), ((4,), jnp.int32))
 
 
@@ -847,28 +848,34 @@ def test_kimi_programs_copy_no_expert_leaf(one_chip, program):
 # read anew at PR 38's, which changed every program's signature on purpose
 # (one feed array behind the caches in place of eight or nine, cut apart
 # first thing: the layers' and the sampler's text is the parent's).
+# Read anew for ``gpt_cell/*`` at PR 42's, in both tables: the pools' rows
+# are flat (``bf16[2,1793,16,2048]``), the tick's custom call is the grouped
+# kernel at a group of one, a rung writes pages without a reshape to heads
+# and attends the transposed flat view; ``jamba_cut/*`` and ``kimi/*`` are
+# PR 38's still, which is the proof that those cells' programs are the
+# parent's.
 # A PR that changes a program on purpose reads the new digests off the
 # failure's message, puts them here and says in PERF.md which program
 # changed and why; one that meant to leave the device's work alone has not.
 PROGRAM_TEXT_SHA256 = {
     "gpt_cell/decode":
-        "df0e03ef4c1e22c55483477b115abf1ffc818a1e7675afd53caf42e8a742502a",
+        "3ee90c509bff70535104c388223face1456a21d13323c4f4201f894c3beb84ef",
     "gpt_cell/prefill_b16":
-        "b7f432de388010f27c07a84b78d888548ffc4f166bc43d346f8d85838dd91e41",
+        "408ef5622e62ff4832805c68cfadc6e82b96d142fcea5b00b6765cf60c171063",
     "gpt_cell/prefill_b32":
-        "b1892624bb3ffc153a523013188ffc7c1579cbe146937c8af19bb01a3f0f7b34",
+        "4e32b12c09151de0af2335b7fa73cf27469c682d355b14e400f9aa338062ff05",
     "gpt_cell/prefill_b64":
-        "217a7aeb142eb883764a68b04021b968d29ff2a6fc7a96f8506cc0407af16e2a",
+        "7e962ea13598348c182b4959b39f1b4f7fb50aacc213f689480361237cb1525f",
     "gpt_cell/prefill_b128":
-        "52db89088d0d2c017873e16fcda764b7ba0562c0f77910b851db9b02d763918c",
+        "8d00e9bab9137fc7f5003ca8d5b0bf3b55334cc4b4ce924f5dc12492bb7c5f45",
     "gpt_cell/prefill_b256":
-        "f35937a54e194730a4422644c355274400e3dc1405ea147bda51a65636bc8dc1",
+        "0893180a845729f4e19f517d7a770656874d9c7c8aa023d1223e931ef2fd7fda",
     "gpt_cell/prefill_b512":
-        "0eee918a93f0dc2c43afd74f37e4288c813b2cb6df3d7ea25455821d9ad5cd2a",
+        "7ade07ef7e8086f04e88dfeee17eaee842995a49f0faab45d4a87c8d9a735a65",
     "gpt_cell/prefill_b1024":
-        "7cd7b7886d198e1870e4b921398ec1fffc93503a106fd457c297d9befdc8621b",
+        "547883a9b66441ec745b6c23f1a45c15d117cf6994ad9552c7c015dcc37a6e48",
     "gpt_cell/prefill_b2048":
-        "3d14bae8d80d3e38a63bfeba3cf8e036e5653e7df3890975a13e5066ddeb46b7",
+        "0b4c8f8ef2de232b8d11a719009a2f1827186eefad472b8860b7dc6fe4ba0bb7",
     "jamba_cut/decode":
         "89820486ab01c4a292d3a3960cdfb639c76c5aaba51a7ec086b14adacb4c61c8",
     "jamba_cut/prefill_b256":
@@ -889,23 +896,23 @@ PROGRAM_TEXT_SHA256 = {
 # text. A PR that changes a program on purpose re-pins both tables.
 BEHIND_THE_CUT_SHA256 = {
     "gpt_cell/decode":
-        "01f7f55ad04479f7c6dc53e6f6569ecbea13a40a6d1d8aff95166bc5aef33658",
+        "1c149076c8ec3571dd878631f40bda7f9d6e2bd7c521a1afd8fb9ff8ad512f62",
     "gpt_cell/prefill_b16":
-        "593234aa0a271fb142f123b13aa8bb3d3c74d6ee3d0e720e4493a687bdc667be",
+        "a1d81af7e79f6fbc6b59b809784c2f4579588d2f34941dbbbd05b96f7c1eff76",
     "gpt_cell/prefill_b32":
-        "ec34154a3ca0b361a0afbbdfbdeeb0bd6d0237a1dc1a29a6dbe24d9a5156fc3a",
+        "44fe4d4b13ed08b00c99202e4e8ecb7461621445e6b2530e8169a7e6c99ab96c",
     "gpt_cell/prefill_b64":
-        "7323b8529dfb8829747e1e06cc3f7aa6d817170b52481bef311b987ef742ac85",
+        "68a3ea564ca2ae09d29e8ee7537e736406f9141653fed8ba3b17da051375873b",
     "gpt_cell/prefill_b128":
-        "0c5d38f6633b8da14e7064e749bc57ace44d08141473b87d335885c37d001409",
+        "22b7069a2320d3b2304af935f44d6273155cc2445685c17728edd29c3ee0e753",
     "gpt_cell/prefill_b256":
-        "ffb1647ca4a672516a888ebd7b9f6d2f1b275db63b624f01c1aa07f1515aa6c1",
+        "e6d8d19a3c2022a1d3baf606e4d0296dc88e9be400bcedf5d9fbeaee13e1a7f6",
     "gpt_cell/prefill_b512":
-        "403b2879711e2671843c2cfa331ad29b13b785bc685f2494681b8a6180da9c3e",
+        "358969e7b8b084f99e0a276a200f42de00d6348fe990fe3bfeec4f37466f4d56",
     "gpt_cell/prefill_b1024":
-        "c64b2b2134f3b8f279f9e77e52032e2a0de2b0bac0e39badf4ec26f6b962463f",
+        "55402336a2b328a3d58ef943b63f2aa0ba03d788b59f617ba279218a972ca742",
     "gpt_cell/prefill_b2048":
-        "dc39f1831268573051b969e9d6b04001aca9dffb413912340052eaeebaa6e7ff",
+        "54c5311a652ee06a12e49083e71ce56ecbe38f076bf18a34eaaff00cbccdc202",
     "jamba_cut/decode":
         "a7bd90bf1b15a58fb8bcd784ca92a38d038cd1fbb271e50cc506bfce64f5c874",
     "jamba_cut/prefill_b256":
@@ -1008,32 +1015,26 @@ def test_gated_delta_update_rows(one_chip):
     assert mem.temp_size_in_bytes < nbytes // (12 * OLMO_B) * 4
 
 
-def test_thirty_heads_lie_in_pages_of_thirty_two_rows(one_chip):
-    """30 key/value heads are no page shape the paged decode kernel takes
-    (Mosaic: a copy's slice along the head axis "must be aligned to tiling
-    (8), but is 30"), so the model's pools hold 32 head rows a token, two
-    of them zeros, and the tick reads its pages through the kernel. In
-    HBM the two rows cost nothing: the TPU tiles a ``[.., 30, 128]``
-    bfloat16 array in 32 rows as it is. Whoever teaches
-    ``paged_decode_tiles`` another head count learns of this model
-    here."""
+def test_thirty_heads_lie_flat_in_3840_lanes(one_chip):
+    """30 key/value heads of 128 are 30 lane tiles of a page's rows: the
+    model's pools hold a token's heads flat, 3,840 lanes and no padded
+    row (as head rows, 30 had to be padded to 32: Mosaic copies a page's
+    head axis in eights), and the page-table kernel takes them as it
+    takes any count of equal heads of whole lanes. At a megabyte a chunk
+    such a row gives chunks of 128 rows, eight pages."""
     from paddle_tpu.models import olmo_hybrid as O
 
     cfg = O.OlmoHybridConfig()
-    assert cfg.num_key_value_heads == 30 and cfg.kv_pool_heads == 32
-    assert not PK.paged_decode_tiles(30, HD)
-    assert O.OlmoHybridServing(cfg).kernel_takes_pages(PAGE, BF16)
-
-    def shapes(heads):
-        pool = ((4, OLMO_PAGES, PAGE, heads, HD), BF16)
-        return (((OLMO_B, heads, HD), BF16), pool, pool, ((), jnp.int32),
-                ((OLMO_B, OLMO_S // PAGE), jnp.int32),
-                ((OLMO_B,), jnp.int32))
-
-    _compile(PK.paged_decode_attention, one_chip, *shapes(32))
-    with pytest.raises(Exception, match=r"aligned to tiling \(8\), but "
-                       "is 30"):
-        _compile(PK.paged_decode_attention, one_chip, *shapes(30))
+    model = O.OlmoHybridServing(cfg)
+    assert cfg.num_key_value_heads == 30
+    assert model.cache_pools["rows"] == ((30 * HD,),) * 2
+    assert PK.paged_decode_tiles(30, HD)
+    assert model.kernel_takes_pages(PAGE, BF16)
+    assert PK._chunk_pages(OLMO_S // PAGE, PAGE, 30 * HD, BF16) == 8
+    pool = ((4, OLMO_PAGES, PAGE, 30 * HD), BF16)
+    _compile(PK.paged_decode_attention, one_chip,
+             ((OLMO_B, 30, HD), BF16), pool, pool, ((), jnp.int32),
+             ((OLMO_B, OLMO_S // PAGE), jnp.int32), ((OLMO_B,), jnp.int32))
 
 
 def _lower_olmo_cut(program, sharding):
@@ -1067,7 +1068,8 @@ def _lower_olmo_cut(program, sharding):
         lambda a: arg(a.shape, a.dtype),
         jax.eval_shape(lambda p: eng.model.hold(p, "bf16", 256), stored))
     geometry = eng.model.state_geometry
-    pool = arg((1, OLMO_PAGES, PAGE, cfg.kv_pool_heads, HD), BF16)
+    pool = arg((1, OLMO_PAGES, PAGE) + eng.model.cache_pools["rows"][0],
+               BF16)
     caches = (pool, pool, arg((3, B) + geometry["conv"], BF16),
               arg((3, B) + geometry["ssm"], F32))
     # the one feed array of a call (serving/engine.py, "the feed")

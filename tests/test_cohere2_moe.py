@@ -185,10 +185,11 @@ def test_gqa_paged_decode_attention_against_the_gathered_view(window, ring):
 
 @pytest.mark.parametrize("heads, kv_heads, head_dim, kernel", [
     (16, 16, 128, "paged_decode_attention"),      # the GPT cells
-    (32, 32, 128, "paged_decode_attention"),      # 30 heads padded to 32
+    (30, 30, 128, "paged_decode_attention"),      # the delta-rule hybrid
+    (12, 12, 128, "paged_decode_attention"),      # equal heads: any count
     (128, 8, 128, "gqa_paged_decode_attention"),  # this model
     (20, 1, 128, None),                           # the Mamba hybrid
-    (12, 12, 128, None), (30, 30, 128, None), (16, 16, 64, None),
+    (16, 16, 64, None),                           # a head is half a tile
     (16, 4, 128, None)])                          # a group of 4: no tile
 def test_one_rule_says_which_heads_take_which_decode_kernel(
         heads, kv_heads, head_dim, kernel):

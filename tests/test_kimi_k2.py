@@ -367,13 +367,14 @@ def test_the_shares_add_up_to_the_uncut_layer():
 
 
 def test_gpt_and_jamba_pools_are_what_they_were():
-    """Shape and dtype of the arrays the two older families carry."""
+    """Shape and dtype of the arrays the two older families carry (the
+    GPT pools' rows flat since PR 42: a token's heads side by side)."""
     g = serving.DecodeEngine(
         G.init_params(jax.random.PRNGKey(0), G.GPT_TINY), G.GPT_TINY,
         serving.EngineConfig(max_batch=2, max_seq=32, page_size=8))
     nh, hd = G.GPT_TINY.num_heads, G.GPT_TINY.head_dim
     assert [(a.shape, a.dtype) for a in g.cache.arrays()] == [
-        ((G.GPT_TINY.num_layers, 9, 8, nh, hd), jnp.float32)] * 2
+        ((G.GPT_TINY.num_layers, 9, 8, nh * hd), jnp.float32)] * 2
     assert g.cache.k is g.cache.arrays()[0]
     assert g.cache.v is g.cache.arrays()[1] and g.cache.keys_and_values
     assert g.latent_token_bytes == 0

@@ -298,8 +298,8 @@ def test_state_geometry_is_the_models_rows_and_bytes(cell, engine):
         cell.config, conv_bytes=4)
     pools = sum(int(p.size) * 4 for p in cache.pools)
     assert cache.nbytes == pools + 4 * per_slot
-    # the pool holds the heads in whole sublane tiles: 4 heads lie in 8
-    assert cache.pools[0].shape[-2:] == (8, s["hd"])
+    # the pool holds a token's heads flat in the lanes, none padded
+    assert cache.pools[0].shape[-1] == s["KVH"] * s["hd"]
     assert engine.state_bytes([0, 2]) == 2 * per_slot
 
 
@@ -386,7 +386,8 @@ def test_init_params_are_the_leaf_shapes_and_run():
     assert cfg.segments() == [("linear", 0, 3), ("full", 0, 1)]
     assert O.OlmoHybridConfig().segments()[:3] == [
         ("linear", 0, 3), ("full", 0, 1), ("linear", 3, 3)]
-    assert O.OlmoHybridConfig().kv_pool_heads == 32
+    assert O.OlmoHybridServing(O.OlmoHybridConfig()).cache_pools["rows"] \
+        == ((30 * 128,),) * 2            # flat: no padded head row
     logits = O.forward(params, jnp.arange(10, dtype=jnp.int32), cfg)
     assert logits.shape == (10, cfg.vocab_size)
     assert np.isfinite(np.asarray(logits)).all()

@@ -808,8 +808,8 @@ def test_carried_pools_match_xs_ys_scan(tiny_model, monkeypatch, program):
                                       np.asarray(want, np.float32))
     # the program wrote the rows it was given and no others
     changed = np.flatnonzero(
-        (np.asarray(new[0], np.float32)
-         != np.asarray(kp, np.float32)).any(axis=(0, 2, 3, 4)))
+        (np.asarray(new[0], np.float32)           # [L, P, page, nh * hd]
+         != np.asarray(kp, np.float32)).any(axis=(0, 2, 3)))
     M = eng.cache.max_pages_per_slot
     allowed = {"decode": {0, 1 + 0, 1 + M + 2},
                "prefill": {1 + M + 1},
@@ -860,12 +860,12 @@ def test_dead_lane_leaves_every_page_but_scratch(tiny_model, kv_path):
     for before, after in ((kp, kp2), (vp, vp2)):
         before = np.asarray(before, np.float32)
         after = np.asarray(after, np.float32)
-        differs = before != after                # [L, P, page, nh, hd]
-        pages = np.flatnonzero(differs.any(axis=(0, 2, 3, 4)))
+        differs = before != after                # [L, P, page, nh * hd]
+        pages = np.flatnonzero(differs.any(axis=(0, 2, 3)))
         assert set(pages.tolist()) == {0, 1 + M + 11 // ps}
         # on the rider's page, row 11 % page_size alone
         rows = np.flatnonzero(
-            differs[:, 1 + M + 11 // ps].any(axis=(0, 2, 3)))
+            differs[:, 1 + M + 11 // ps].any(axis=(0, 2)))
         assert rows.tolist() == [11 % ps]
     # all lanes dead: the scratch page alone
     dead = _pack_slot_feed(tokens, np.zeros((B,)), np.zeros((B, M)),

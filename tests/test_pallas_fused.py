@@ -486,18 +486,32 @@ def test_train_step_fused_opt_pallas_bitwise():
 # ---------------------------------------------------------------------------
 
 
+# bfloat16 pools: the kernel rounds the query to the pool's dtype (the
+# tests hand it one that is bfloat16 already) and carries the
+# probabilities as e_hi + e_lo, 2^-17 of a probability; against the
+# float32 softmax over the same bfloat16 keys and values that is 2^-17 x
+# max|v| (N(0, 1) draws: under 4.5) = 3.4e-5 at worst, 5e-6 as measured.
+# Rounding the probabilities to bfloat16 would miss it by 2^8.
+PAGED_TOL = {jnp.float32: 3e-6, jnp.bfloat16: 3.4e-5}
+
+
+def _as_pool_dtype(x, cdt):
+    return jnp.asarray(x, cdt).astype(jnp.float32)
+
+
 @pytest.mark.parametrize("cdt", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 def test_fused_paged_decode_parity(cdt):
     """Disjoint page tables (the only layout the engine's allocator ever
     produces for live slots — pages are owned exclusively; only the
-    never-read-back scratch page 0 is shared by dead lanes)."""
+    never-read-back scratch page 0 is shared by dead lanes). Flat rows:
+    a token's heads side by side in the lanes."""
     rng = np.random.default_rng(2)
     B, M, page, nh, hd = 3, 4, 8, 2, 64
     P = 1 + B * M                            # page 0 = scratch
-    kp = jnp.asarray(rng.standard_normal((P, page, nh, hd)), cdt)
-    vp = jnp.asarray(rng.standard_normal((P, page, nh, hd)), cdt)
-    q = jnp.asarray(rng.standard_normal((B, nh, hd)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((P, page, nh * hd)), cdt)
+    vp = jnp.asarray(rng.standard_normal((P, page, nh * hd)), cdt)
+    q = _as_pool_dtype(rng.standard_normal((B, nh, hd)), cdt)
     nk = jnp.asarray(rng.standard_normal((B, nh, hd)), jnp.float32)
     nv = jnp.asarray(rng.standard_normal((B, nh, hd)), jnp.float32)
     # slot b owns pages [1 + b*M, 1 + (b+1)*M) — disjoint by construction
@@ -509,10 +523,10 @@ def test_fused_paged_decode_parity(cdt):
     def ref(q, kp, vp, nk, nv):
         phys = tables[jnp.arange(B), positions // page]
         rows = positions % page
-        kp2 = DA.paged_cache_update(kp, nk, phys, rows)
-        vp2 = DA.paged_cache_update(vp, nv, phys, rows)
-        gk = DA.paged_gather(kp2, tables)
-        gv = DA.paged_gather(vp2, tables)
+        kp2 = DA.paged_cache_update(kp, nk.reshape(B, -1), phys, rows)
+        vp2 = DA.paged_cache_update(vp, nv.reshape(B, -1), phys, rows)
+        gk = DA.paged_gather(kp2, tables, heads=(nh, hd))
+        gv = DA.paged_gather(vp2, tables, heads=(nh, hd))
         return DA.decode_attention(q, gk, gv, positions + 1), kp2, vp2
 
     out, kp2, vp2 = PK.fused_paged_decode_attention(
@@ -522,26 +536,29 @@ def test_fused_paged_decode_parity(cdt):
                                   np.asarray(r_kp, jnp.float32))
     np.testing.assert_array_equal(np.asarray(vp2, jnp.float32),
                                   np.asarray(r_vp, jnp.float32))
+    tol = {jnp.float32: 2e-6, jnp.bfloat16: PAGED_TOL[cdt]}[cdt]
     np.testing.assert_allclose(np.asarray(out), np.asarray(r_out),
-                               atol=2e-6, rtol=2e-6)
+                               atol=tol, rtol=tol)
 
 
 @pytest.mark.parametrize("cdt", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-def test_paged_decode_layer_indexed_parity(cdt):
+def test_paged_decode_layer_indexed_parity(cdt, monkeypatch):
     """The layer-indexed kernel over the engine's whole
-    [L, P, page, nh, hd] pool against paged_gather + decode_attention, at
+    [L, P, page, nh * hd] pool against paged_gather + decode_attention, at
     every layer, with ragged lengths: a slot of one token, a slot ending
-    on a page edge, one a row past it, a full 2048-token slot, and dead
-    lanes (all-zero tables, position 0). The pools come back bit for bit
-    the reference's: the written rows and nothing else."""
+    on a page edge, one a row past it, a full 2048-token slot (eight
+    chunks at the megabyte a chunk shrunk to 64 KB), and dead lanes
+    (all-zero tables, position 0). The pools come back bit for bit the
+    reference's: the written rows and nothing else."""
+    monkeypatch.setattr(PK, "_PAGED_CHUNK_BYTES", 1 << 17)
     rng = np.random.default_rng(4)
     L, B, M, page, nh, hd = 3, 6, 128, 16, 2, 64
     own = [1, 2, 3, M]                       # pages each live slot owns
     P = 1 + sum(own)                         # page 0 = scratch
-    kp = jnp.asarray(rng.standard_normal((L, P, page, nh, hd)), cdt)
-    vp = jnp.asarray(rng.standard_normal((L, P, page, nh, hd)), cdt)
-    q = jnp.asarray(rng.standard_normal((B, nh, hd)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((L, P, page, nh * hd)), cdt)
+    vp = jnp.asarray(rng.standard_normal((L, P, page, nh * hd)), cdt)
+    q = _as_pool_dtype(rng.standard_normal((B, nh, hd)), cdt)
     nk = jnp.asarray(rng.standard_normal((B, nh, hd)), jnp.float32)
     nv = jnp.asarray(rng.standard_normal((B, nh, hd)), jnp.float32)
     tables = np.zeros((B, M), np.int32)      # slots 1 and 4: dead lanes
@@ -558,10 +575,12 @@ def test_paged_decode_layer_indexed_parity(cdt):
     def ref(q, kp, vp, nk, nv, layer):
         phys = tables[jnp.arange(B), positions // page]
         rows = positions % page
-        kp2 = DA.paged_cache_update(kp, nk, phys, rows, layer=layer)
-        vp2 = DA.paged_cache_update(vp, nv, phys, rows, layer=layer)
-        gk = DA.paged_gather(kp2, tables, layer=layer)
-        gv = DA.paged_gather(vp2, tables, layer=layer)
+        kp2 = DA.paged_cache_update(kp, nk.reshape(B, -1), phys, rows,
+                                    layer=layer)
+        vp2 = DA.paged_cache_update(vp, nv.reshape(B, -1), phys, rows,
+                                    layer=layer)
+        gk = DA.paged_gather(kp2, tables, layer=layer, heads=(nh, hd))
+        gv = DA.paged_gather(vp2, tables, layer=layer, heads=(nh, hd))
         return (DA.decode_attention(q, gk, gv, positions + 1), kp2, vp2,
                 phys, rows)
 
@@ -574,7 +593,7 @@ def test_paged_decode_layer_indexed_parity(cdt):
                                             jnp.int32(layer))
         np.testing.assert_allclose(np.asarray(out)[live],
                                    np.asarray(r_out)[live],
-                                   atol=3e-6, rtol=3e-6)
+                                   atol=PAGED_TOL[cdt], rtol=PAGED_TOL[cdt])
         for got, want, before in ((kp2, r_kp, kp), (vp2, r_vp, vp)):
             got, want, before = (np.asarray(a, np.float32)
                                  for a in (got, want, before))
@@ -584,6 +603,81 @@ def test_paged_decode_layer_indexed_parity(cdt):
             mask[:, 0] = False
             mask[layer, np.asarray(phys), np.asarray(rows)] = False
             np.testing.assert_array_equal(got[mask], before[mask])
+
+
+# where a slot may end, in pages of 16 rows and chunks of two pages
+ENDS = {"one_token": 0, "page_last_row": 47, "chunk_last_row": 63,
+        "row_past_a_chunk": 64, "mid_page": 70}
+
+
+@pytest.mark.parametrize("end", sorted(ENDS))
+@pytest.mark.parametrize("cdt", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("nh", [16, 30])
+def test_paged_decode_masks_the_last_chunk_alone(nh, cdt, end, monkeypatch):
+    """Equal heads at any count, 16 or 30 lane tiles of 128, through the
+    grouped kernel at a group of one. Only the chunk that holds a slot's
+    position is masked, so everything the mask has to hide is planted as
+    NaN: the rows of the slot's last page past its position, and the tail
+    of its last chunk, which no copy writes (a slot of NaN rides in front
+    of it and leaves both VMEM buffers full of them). A dead lane rides
+    between the two."""
+    page, M, hd, L = 16, 8, 128, 2
+    pos = ENDS[end]
+    # two pages a chunk: 32 rows
+    monkeypatch.setattr(PK, "_PAGED_CHUNK_BYTES",
+                        2 * page * nh * hd * jnp.dtype(cdt).itemsize)
+    rng = np.random.default_rng(nh + pos)
+    B, P = 3, 1 + 2 * M
+    tables = np.zeros((B, M), np.int32)
+    tables[0] = 1 + np.arange(M)             # the slot of NaN
+    tables[2] = 1 + M + np.arange(M)
+    positions = jnp.asarray([M * page - 1, 0, pos], jnp.int32)
+    clean = rng.standard_normal((2, L, P, page, nh * hd)).astype(np.float32)
+    poisoned = clean.copy()
+    poisoned[:, :, 1:1 + M] = np.nan
+    flat = poisoned[:, :, 1 + M:].reshape(2, L, M * page, nh * hd)
+    flat[:, :, pos + 1:] = np.nan            # a view: written in place
+    q = _as_pool_dtype(rng.standard_normal((B, nh, hd)), cdt)
+    tables = jnp.asarray(tables)
+    for layer in range(L):
+        got = PK.paged_decode_attention(
+            q, jnp.asarray(poisoned[0], cdt), jnp.asarray(poisoned[1], cdt),
+            jnp.int32(layer), tables, positions)
+        want = DA.decode_attention(
+            q, *(DA.paged_gather(jnp.asarray(x, cdt), tables, layer,
+                                 heads=(nh, hd)) for x in clean),
+            positions + 1)
+        assert got.shape == (B, nh, hd)
+        np.testing.assert_allclose(np.asarray(got)[2], np.asarray(want)[2],
+                                   atol=PAGED_TOL[cdt], rtol=PAGED_TOL[cdt])
+
+
+def test_paged_decode_probabilities_keep_float32():
+    """What ``split`` is for: over bfloat16 pools the probabilities go
+    into the product with the values as e_hi + e_lo and hold PAGED_TOL;
+    rounded to bfloat16 alone they miss it 25 times over and more."""
+    rng = np.random.default_rng(9)
+    B, M, page, nh, hd, tile = 2, 8, 16, 16, 128, 16
+    kp, vp = (jnp.asarray(rng.standard_normal((1, 1 + B * M, page, nh * hd)),
+                          jnp.bfloat16) for _ in range(2))
+    q = _as_pool_dtype(rng.standard_normal((B, nh, hd)), jnp.bfloat16)
+    tables = jnp.asarray(1 + np.arange(B * M).reshape(B, M), jnp.int32)
+    positions = jnp.asarray([M * page - 1, 40], jnp.int32)
+    want = DA.decode_attention(
+        q, DA.paged_gather(kp, tables, 0, heads=(nh, hd)),
+        DA.paged_gather(vp, tables, 0, heads=(nh, hd)), positions + 1)
+    rows = jnp.broadcast_to(q[:, :, None], (B, nh, tile, hd))
+    err = {}
+    for split in (True, False):
+        got = PK._paged_attention_call(
+            "paged_decode_attention", rows.reshape(B, nh * tile, hd), kp, vp,
+            jnp.int32(0), tables, positions, kv_heads=nh, split=split)
+        err[split] = float(np.abs(
+            np.asarray(got).reshape(B, nh, tile, hd)[:, :, 0]
+            - np.asarray(want)).max())
+    tol = PAGED_TOL[jnp.bfloat16]
+    assert err[True] < tol and err[False] > 25 * tol, err
 
 
 def test_fused_logits_head_parity():

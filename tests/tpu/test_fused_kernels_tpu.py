@@ -76,16 +76,16 @@ def test_fused_ln_fwd_bwd_matches_xla_on_tpu():
                          ids=["bf16", "f32"])
 def test_fused_decode_paged_matches_xla_on_tpu(cdt, layers):
     """The paged decode step against the unfused XLA expressions, over one
-    layer's [P, page, nh, hd] pool and over the engine's whole
-    [L, P, page, nh, hd] pool with a traced layer index (the tick's
+    layer's [P, page, nh * hd] pool and over the engine's whole
+    [L, P, page, nh * hd] pool with a traced layer index (the tick's
     carried pools). Ragged lengths; slot 6 is a dead lane (all-zero
     table, position 0: it writes the scratch page)."""
     rng = np.random.default_rng(2)
     M = S // PAGE
     P = 1 + B * M                            # page 0 = scratch
     lead = () if layers is None else (layers,)
-    kp = jnp.asarray(rng.standard_normal(lead + (P, PAGE, NH, HD)), cdt)
-    vp = jnp.asarray(rng.standard_normal(lead + (P, PAGE, NH, HD)), cdt)
+    kp = jnp.asarray(rng.standard_normal(lead + (P, PAGE, NH * HD)), cdt)
+    vp = jnp.asarray(rng.standard_normal(lead + (P, PAGE, NH * HD)), cdt)
     q, nk, nv = (jnp.asarray(rng.standard_normal((B, NH, HD)), cdt)
                  for _ in range(3))
     perm = rng.permutation(np.arange(1, P)).reshape(B, M)   # disjoint
@@ -99,10 +99,12 @@ def test_fused_decode_paged_matches_xla_on_tpu(cdt, layers):
     def ref(q, kp, vp, nk, nv, layer):
         phys = tables[jnp.arange(B), positions // PAGE]
         rows = positions % PAGE
-        kp2 = DA.paged_cache_update(kp, nk, phys, rows, layer=layer)
-        vp2 = DA.paged_cache_update(vp, nv, phys, rows, layer=layer)
-        gk = DA.paged_gather(kp2, tables, layer=layer)
-        gv = DA.paged_gather(vp2, tables, layer=layer)
+        kp2 = DA.paged_cache_update(kp, nk.reshape(B, -1), phys, rows,
+                                    layer=layer)
+        vp2 = DA.paged_cache_update(vp, nv.reshape(B, -1), phys, rows,
+                                    layer=layer)
+        gk = DA.paged_gather(kp2, tables, layer=layer, heads=(NH, HD))
+        gv = DA.paged_gather(vp2, tables, layer=layer, heads=(NH, HD))
         return DA.decode_attention(q, gk, gv, positions + 1), kp2, vp2
 
     fused = jax.jit(lambda q, kp, vp, nk, nv, layer:
