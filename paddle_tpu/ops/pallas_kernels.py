@@ -1544,8 +1544,10 @@ def paged_decode_kernel(num_heads: int, kv_heads: int,
       :func:`paged_decode_attention`, the kernel at a group of one: each
       head's one query row fills a sublane tile of its own.
     * grouped heads, each key/value head serving a multiple of eight
-      query heads (128 over 8): :func:`gqa_paged_decode_attention`. A
-      group's query rows are whole sublane tiles.
+      query heads (128 over 8; 64 over 8, a group of EIGHT, compiled for
+      the v5e and run on it since PR 44: half a packed bfloat16 tile a
+      group, which Mosaic takes as it is): :func:`gqa_paged_decode_attention`.
+      A group's query rows are whole sublane tiles.
     * any other grouping (20 query heads over 1: a group that is no whole
       tile, and no entry pads one) gathers."""
     if head_dim % NUM_LANES or num_heads % max(kv_heads, 1):

@@ -51,12 +51,14 @@ description is any object with:
 - ``forward(params, tokens [1, T])``: the plain full forward pass (the
   engine's parity surface).
 
-Five descriptions exist: :class:`GPTServing` here (``models/gpt.py``'s
+Six descriptions exist: :class:`GPTServing` here (``models/gpt.py``'s
 block), ``models/jamba.py:JambaServing``,
 ``models/kimi_k2.py:KimiK2Serving``,
-``models/olmo_hybrid.py:OlmoHybridServing`` and
+``models/olmo_hybrid.py:OlmoHybridServing``,
 ``models/cohere2_moe.py:Cohere2MoeServing`` (window and global layers: two
-page groups). The engine's verify program
+page groups) and ``models/solar_open2.py:SolarOpen2Serving`` (the first
+that is ``recurrent`` AND has experts: caches ``(k pool, v pool, conv,
+ssm)`` and the report together). The engine's verify program
 is still written for the GPT block (ROADMAP D2) and uses the block
 helpers below directly.
 """
@@ -311,6 +313,10 @@ def describe(cfg):
 
     if isinstance(cfg, cohere_mod.Cohere2MoeConfig):
         return cohere_mod.Cohere2MoeServing(cfg)
+    from ..models import solar_open2 as solar_mod
+
+    if isinstance(cfg, solar_mod.SolarOpen2Config):
+        return solar_mod.SolarOpen2Serving(cfg)
     raise TypeError(
         f"DecodeEngine: no model description for {type(cfg).__name__}; "
         "pass an object with the surface serving/model.py lists")

@@ -46,10 +46,11 @@ def pytest_configure(config):
 # before they existed: test_dgc_halfasync's two async trainer processes stop
 # converging when such a neighbour starves them (their margin is thin).
 # (PR 35's two files of the delta-rule family likewise, behind the longest,
-# and PR 38's of the feed array.)
+# PR 38's of the feed array, and PR 44's two of the KDA-and-experts family.)
 _RUN_LAST = ("test_chip_compile.py", "test_chip_smoke.py",
              "test_olmo_hybrid.py", "test_benchmark_olmo_hybrid.py",
-             "test_tick_feed.py")
+             "test_tick_feed.py", "test_solar_open2.py",
+             "test_benchmark_solar_open2.py")
 
 
 def pytest_collection_modifyitems(config, items):

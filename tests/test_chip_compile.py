@@ -1272,3 +1272,187 @@ def test_cohere_programs_fit_and_relay_no_weight(one_chip, program):
     if program == "decode":
         assert not moves, "\n".join(moves)
         assert mem.temp_size_in_bytes < 256 << 20
+
+
+# ---------------------------------------------------------------------------
+# serve_solar_open2_ep8_closed64 (benchmark/configs/solar-open2-ep8-l4.json):
+# 80 slots of 11,264 tokens over 14,081 pages of 64, 64 query heads over 8
+# key/value heads of 128 in the GQA layer (a group of EIGHT), 64 heads of
+# 128 x 128 with a gate a channel in the KDA layers, 40 held experts of 1280
+# ---------------------------------------------------------------------------
+
+KDA_H, KDA_D = 64, 128
+SOLAR_B, SOLAR_S, SOLAR_PAGE, SOLAR_PAGES = 80, 11264, 64, 14081
+
+
+@pytest.mark.parametrize("T", [256, 8192])
+def test_kda_chunk_fwd(one_chip, T):
+    """The chunkwise delta rule with a gate a channel at the cell's head
+    sizes, its lowest and its top rung: blocks of 64 tokens of one head,
+    the sub-blocks' selects on full tiles, rows read back from VMEM one by
+    one, the state in VMEM."""
+    from paddle_tpu.ops import gated_delta as GD
+
+    compiled = _compile(
+        GD.kda_chunked, one_chip, ((T, KDA_H, KDA_D), BF16),
+        ((T, KDA_H, KDA_D), BF16), ((T, KDA_H, KDA_D), BF16),
+        ((T, KDA_H, KDA_D), F32), ((T, KDA_H), F32), ((), jnp.int32))
+    assert re.search(r"%kda_chunk_fwd[\w.]* = ", compiled.as_text())
+
+
+def test_kda_update_rows(one_chip):
+    """The one-token delta rule with a gate a channel over the three KDA
+    layers' state rows of 80 slots: the array stays in HBM and is aliased
+    whole, a rider's row of 64 x 128 x 128 float32 (4.19 MB) is what a DMA
+    moves, and the riders' inputs come eight a grid step (all 80 at once
+    with the two row buffers pass the kernel's 48 MB)."""
+    from paddle_tpu.ops import gated_delta as GD
+
+    assert GD.state_fold(KDA_H, KDA_D) == 1
+    assert GD._rider_block(SOLAR_B, KDA_H, KDA_D, KDA_D,
+                           2 * KDA_H * KDA_D * KDA_D * 4, True) == 8
+    # the delta-rule cell's 48 riders still come in one step
+    assert GD._rider_block(OLMO_B, GDN_H, GDN_DK, GDN_DV,
+                           2 * 15 * GDN_DK * 2 * GDN_DV * 4, False) is None
+    state = (3, SOLAR_B, KDA_H, KDA_D, KDA_D)
+    head = ((SOLAR_B, KDA_H, KDA_D), BF16)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        (state, F32), head, head, head, ((SOLAR_B, KDA_H, KDA_D), F32),
+        ((SOLAR_B, KDA_H), F32), ((SOLAR_B,), jnp.int32), ((), jnp.int32))]
+    lowered = jax.jit(
+        lambda S, q, k, v, a, b, slots, layer: GD.kda_update(
+            S, q, k, v, a, b, slots, layer=layer),
+        donate_argnums=(0,)).lower(*args)
+    assert "kda_update_rows" in lowered.as_text()
+    mem = lowered.compile().memory_analysis()
+    nbytes = int(np.prod(state)) * 4
+    assert mem.alias_size_in_bytes >= nbytes
+    assert mem.temp_size_in_bytes < nbytes // (3 * SOLAR_B) * 8
+
+
+def test_gqa_paged_decode_attention_at_a_group_of_eight(one_chip):
+    """Mosaic takes the grouped-query paged decode kernel at 64 query heads
+    over 8 key/value heads of 128: a group of EIGHT query rows, half a
+    packed bfloat16 tile; pages of 64 rows of 1,024 lanes, a table of 176
+    entries."""
+    B, H, KVH, PG = SOLAR_B, 64, 8, SOLAR_PAGE
+    assert PK.paged_decode_kernel(H, KVH, HD) == "gqa_paged_decode_attention"
+
+    def fn(q, kp, vp, nk, nv, tables, positions, layer):
+        return PK.gqa_paged_decode_attention(
+            q, kp, vp, nk, nv, tables, positions, layer, KVH)
+
+    pool = ((1, SOLAR_PAGES, PG, KVH * HD), BF16)
+    row = ((B, KVH * HD), BF16)
+    compiled = _compile(fn, one_chip, ((B, H, HD), BF16), pool, pool, row,
+                        row, ((B, SOLAR_S // PG), jnp.int32),
+                        ((B,), jnp.int32), ((), jnp.int32))
+    assert re.search(r"%gqa_paged_decode[\w.]* = ", compiled.as_text())
+
+
+@pytest.mark.parametrize("T", [256, 1408, 8192])
+def test_band_flash_attention_at_a_group_of_eight(one_chip, T):
+    """The grouped flash kernel on the flat ``[1, T, heads x 128]`` arrays
+    of the lowest, the median and the top rung at 64 over 8; no transposed
+    copy is made."""
+    compiled = _compile(
+        lambda q, k, v: PK.band_flash_attention(q, k, v, 64, 8),
+        one_chip, ((1, T, 64 * HD), BF16), ((1, T, 8 * HD), BF16),
+        ((1, T, 8 * HD), BF16))
+    assert re.search(r"%window_flash_fwd[\w.]* = ", compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+def _solar_config():
+    from paddle_tpu.models import solar_open2 as SO
+
+    return SO.SolarOpen2Config(vocab_size=24576, num_hidden_layers=4,
+                               gqa_layers=(0,), experts_held=40)
+
+
+def _lower_solar(program, sharding):
+    """The decode tick or a prefill rung of ``solar-open2-ep8-l4`` as the
+    cell runs it (one period: a GQA layer and three KDA layers, 40 held
+    experts of 320, an eighth of the vocabulary, 80 slots of 11,264 tokens
+    in pages of 64), lowered for the described chip from SHAPES alone (its
+    weights would be 6.6 GB of host memory that no compile reads)."""
+    from paddle_tpu import serving
+    from paddle_tpu.models import solar_open2 as SO
+    from paddle_tpu.serving import engine as E
+
+    B, S, PG = SOLAR_B, SOLAR_S, SOLAR_PAGE
+    cfg = _solar_config()
+    eng = object.__new__(E.DecodeEngine)
+    eng.model, eng.cfg = SO.SolarOpen2Serving(cfg), cfg
+    eng.ecfg = serving.EngineConfig(
+        max_batch=B, max_seq=S, page_size=PG, num_pages=SOLAR_PAGES,
+        weight_dtype="bf16", prefix_cache=False)
+    eng.kv_path = "pallas_paged"
+    assert eng.model.kernel_takes_pages(PG, BF16)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    stored = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s, F32), SO.leaf_shapes(cfg),
+        is_leaf=lambda s: isinstance(s, tuple))
+    held = jax.tree_util.tree_map(
+        lambda a: arg(a.shape, a.dtype),
+        jax.eval_shape(lambda p: SO.hold(p, cfg, "bf16"), stored))
+    geometry = eng.model.state_geometry
+    pool = arg((1, SOLAR_PAGES, PG, cfg.kv_width), BF16)
+    caches = (pool, pool, arg((3, B) + geometry["conv"], BF16),
+              arg((3, B) + geometry["ssm"], F32))
+    if program == "decode":
+        fn, feed = eng._decode_fn_paged, arg(
+            E.slot_feed_shape(B, S // PG), jnp.int32)
+    else:
+        T = int(program.split("_b")[1])
+        fn, feed = eng._prefill_fn_paged, arg(
+            (E.rung_feed_len(S // PG, T),), jnp.int32)
+    return (jax.jit(fn, donate_argnums=(1,)).lower(held, caches, feed),
+            caches)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_b8192"])
+def test_solar_programs_fit_move_the_riders_rows_and_relay_no_weight(
+        one_chip, program):
+    """The KDA-and-experts cell's tick and top rung on the chip's own
+    compile (the 1408 rung, the cycle's median, compiled to 0.26 GiB of
+    temporaries by hand: a minute of this file, which the suite's limit
+    does not have to spare): the kernels are there by name
+    (``kda_update_rows`` and ``gqa_paged_decode`` in the tick,
+    ``kda_chunk_fwd`` and ``window_flash_fwd`` in a rung,
+    ``moe_grouped_matmul`` in both), every
+    donated cache array (pools, conv rows, matrix states) is aliased whole,
+    nothing the size of one expert's smallest leaf (``w_down [F, D]``, 5.2 M
+    elements) is copied, transposed, converted or sliced out of a weight in
+    the tick, its temporaries stay under one layer's riders' states, and
+    arguments plus temporaries stay under the chip's 15.75 GiB."""
+    cfg = _solar_config()
+    lowered, caches = _lower_solar(program, one_chip)
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    tick = program == "decode"
+    assert re.search(r"%moe_grouped_matmul[\w.]* = ", hlo)
+    for name in ("kda_update_rows", "gqa_paged_decode"):
+        assert bool(re.search(rf"%{name}[\w.]* = ", hlo)) == tick, name
+    for name in ("kda_chunk_fwd", "window_flash_fwd"):
+        assert bool(re.search(rf"%{name}[\w.]* = ", hlo)) == (not tick), name
+    mem = compiled.memory_analysis()
+    nbytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in caches)
+    assert mem.alias_size_in_bytes >= nbytes
+    resident = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    print(f"{program}: arguments {mem.argument_size_in_bytes / 2**30:.2f} "
+          f"GiB, temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB")
+    assert resident < _COHERE_RESIDENT, resident
+    if tick:
+        one_expert_leaf = cfg.moe_intermediate_size * cfg.hidden_size
+        moves = (_weight_sized_relayouts(hlo, at_least=one_expert_leaf)
+                 + _pool_sized_moves(hlo, at_least=one_expert_leaf))
+        # a layer's conv rows of all 80 lanes (5.9 M values, written in
+        # place every tick: the riders' new taps) are that large too
+        moves = [m for m in moves if "80,73728]" not in m]
+        assert not moves, "\n".join(moves)
+        layer_rows = SOLAR_B * KDA_H * KDA_D * KDA_D * 4
+        assert mem.temp_size_in_bytes < layer_rows
