@@ -24,9 +24,11 @@ Two forms, as every mixer of the serving engine has:
       O = diag(exp(g)) Q St0 + (exp(g[i] - g[j]) (q[i] . k[j]))_{j <= i} U
       St = exp(g[C]) St0 + (diag(exp(g[C] - g)) K)^T U
 
-  The unit triangular system is solved in float32 by forward substitution
-  whatever the inputs' type; the other products take the inputs' type with
-  float32 sums; the state is float32 and is carried across chunks.
+  The unit triangular system is solved in float32 whatever the inputs' type
+  (the kernel inverts ``I + A`` in diagonal blocks of 32, ``_unit_lower_
+  inverse``; the XLA form calls ``solve_triangular``); the other products
+  take the inputs' type with float32 sums; the state is float32 and is
+  carried across chunks.
   Positions at or past ``length`` get ``log alpha = 0`` and ``beta = 0``:
   they leave the state as it is. On a TPU it is the Pallas kernel
   ``gated_delta_chunk_fwd`` (grid heads x chunks, the state in VMEM); off
@@ -62,7 +64,7 @@ a channel decays fast across a chunk (``exp(r - G[j])`` at 64 tokens of
 a block of rows ``I`` against the tokens before it takes ``r = G`` at the
 block's first row (both exponents are then at most 0), and inside a
 diagonal block the differences ``G[i] - G[j]`` are taken pair by pair on
-the VPU, where they too are at most 0. The rest (the forward substitution,
+the VPU, where they too are at most 0. The rest (the triangle's inverse,
 ``U``, ``O`` and the state's step with ``exp(G)`` a factor of ``k`` and
 ``q`` a channel) is the scalar form's. On a TPU the Pallas kernel
 ``kda_chunk_fwd``; off it the same algebra in ``jax.numpy``.
@@ -198,25 +200,61 @@ def _column(row, n):
                    axis=1, keepdims=True)
 
 
-def _unit_lower_inverse(at, t_scr):
-    """``T = (I + A)^-1`` of a unit lower triangular system by forward
-    substitution, float32 on the VPU, inside a kernel. ``at[j, i] = A[i,
-    j]`` (the column of A a row of T needs lies down the sublanes); row i
-    is ``e_i - A[i, :i] T[:i]``. ``t_scr`` ``[C, C]`` float32 scratch."""
+_INVERSE_BLOCK = 32
+
+
+def _unit_lower_inverse(at):
+    """``T = (I + A)^-1`` of a unit lower triangular system, float32,
+    inside a kernel; ``at[j, i] = A[i, j]``, so ``I + at`` is upper
+    triangular and ``T`` its inverse transposed. Blocked: the diagonal
+    blocks of ``b`` rows (32 where that divides a larger ``C``, else the
+    one block of ``C``) are inverted side by side by backward
+    substitution, block ``k`` in rows and columns ``[k b, (k + 1) b)`` of
+    one ``[C, C]`` tile: step ``j`` of ``b - 1`` takes from the rows above
+    ``j`` of every block's stripe (rounded up to 8 sublanes) their entry
+    of column ``j`` times the stripe's row ``j``, which is final by then.
+    The entry is read as a masked sum along the lanes: a sliced column or
+    a lane gather is slower on the chip (docs/kernels.md). The stripe's
+    other columns take the same steps, so they end as ``N = D M`` (``D``
+    the blocks' inverses, ``M`` the part of ``at`` outside the blocks),
+    nilpotent block by block, and ``(I + N)^-1 D = (I + N^2)(I + N^4) ..
+    (I - N) D`` is products on the MXU: one for two blocks."""
+    f32 = jnp.float32
     C = at.shape[0]
-    eye = (jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
-           == jax.lax.broadcasted_iota(jnp.int32, (C, C), 1))
-    t_scr[...] = eye.astype(jnp.float32)
-    for i in range(1, C):
-        r8 = -(-i // 8) * 8
-        row = jnp.sum(at[:r8, i:i + 1] * t_scr[:r8, :], axis=0,
-                      keepdims=True)
-        t_scr[i:i + 1, :] = t_scr[i:i + 1, :] - row
-    return t_scr[...]
+    b = _INVERSE_BLOCK if C > _INVERSE_BLOCK and C % _INVERSE_BLOCK == 0 \
+        else C
+    nb = C // b
+    rows = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    same = rows // b == cols // b
+    x = jnp.where(same, (rows == cols).astype(f32), at)
+    stripes = [x[k * b:(k + 1) * b] for k in range(nb)]
+    for j in range(b - 1, 0, -1):
+        h = min(-(-j // 8) * 8, b)
+        # an iota of its own: Mosaic aborts on ``cols[:h]`` compared
+        lane = jax.lax.broadcasted_iota(jnp.int32, (h, C), 1)
+        for k, s in enumerate(stripes):
+            coef = jnp.sum(jnp.where(lane == k * b + j,
+                                     at[k * b:k * b + h], 0.0),
+                           axis=1, keepdims=True)
+            top = s[:h] - coef * s[j:j + 1]
+            stripes[k] = top if h == b else jnp.concatenate(
+                [top, s[h:]], axis=0)
+    x = jnp.concatenate(stripes, axis=0)
+    if nb > 1:
+        d = jnp.where(same, x, 0.0)
+        n = x - d
+        x = d - _dot(n, d, precision=_HI)
+        power = 2
+        while power < nb:
+            n = _dot(n, n, precision=_HI)
+            x = x + _dot(n, x, precision=_HI)
+            power *= 2
+    return x.T
 
 
 def _chunk_kernel(len_ref, q_ref, k_ref, v_ref, gb_ref, o_ref, s_ref,
-                  st_scr, t_scr, *, chunk):
+                  st_scr, *, chunk):
     f32 = jnp.float32
     C = chunk
     c = pl.program_id(1)
@@ -248,7 +286,7 @@ def _chunk_kernel(len_ref, q_ref, k_ref, v_ref, gb_ref, o_ref, s_ref,
         upper = rows < cols
         at = jnp.where(upper, b_row * jnp.exp(
             jnp.where(upper, g_row - g_col, 0.0)) * dot(k, k, _NT), 0.0)
-        tm = _unit_lower_inverse(at, t_scr)
+        tm = _unit_lower_inverse(at)
         st = st_scr[...]
         sm = st.astype(mm)
         u0 = dot(tm, b_col * vf, precision=_HI)
@@ -290,8 +328,7 @@ def _chunked_pallas(q, k, v, g, beta, length, chunk):
                   pl.BlockSpec((1, 1, 2, C), lambda h, c, *_: (h, c, 0, 0))],
         out_specs=[pl.BlockSpec((1, C, dv), block),
                    pl.BlockSpec((1, dk, dv), lambda h, c, *_: (h, 0, 0))],
-        scratch_shapes=[pltpu.VMEM((dk, dv), f32),
-                        pltpu.VMEM((C, C), f32)])
+        scratch_shapes=[pltpu.VMEM((dk, dv), f32)])
     with jax.named_scope("gated_delta_chunk"):
         o, St = pl.pallas_call(
             functools.partial(_chunk_kernel, chunk=C),
@@ -416,7 +453,7 @@ def _kda_chunked_xla(q, k, v, g, beta, chunk):
 
 
 def _kda_chunk_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, b_ref, o_ref,
-                      s_ref, st_scr, t_scr, qf_scr, kf_scr, *, chunk, sub):
+                      s_ref, st_scr, qf_scr, kf_scr, *, chunk, sub):
     f32 = jnp.float32
     C = chunk
     c = pl.program_id(1)
@@ -489,7 +526,7 @@ def _kda_chunk_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, b_ref, o_ref,
             m_diag.append(m_tile)
         at = (at + jnp.concatenate(at_diag, axis=0)) * b_row
         m = m + jnp.concatenate(m_diag, axis=0)
-        tm = _unit_lower_inverse(at, t_scr)
+        tm = _unit_lower_inverse(at)
         st = st_scr[...]
         sm = st.astype(mm)
         eg = jnp.exp(G)
@@ -527,7 +564,6 @@ def _kda_chunked_pallas(q, k, v, g, beta, length, chunk):
         out_specs=[pl.BlockSpec((1, C, dv), block),
                    pl.BlockSpec((1, dk, dv), lambda h, c, *_: (h, 0, 0))],
         scratch_shapes=[pltpu.VMEM((dk, dv), f32),
-                        pltpu.VMEM((C, C), f32),
                         pltpu.VMEM((C, dk), f32),
                         pltpu.VMEM((C, dk), f32)])
     with jax.named_scope("kda_chunk"):

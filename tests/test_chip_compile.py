@@ -977,17 +977,30 @@ GDN_H, GDN_DK, GDN_DV = 30, 96, 192
 OLMO_B, OLMO_S, OLMO_PAGES = 48, 4608, 3585
 
 
-@pytest.mark.parametrize("T", [256, 4096])
+# temp_size_in_bytes of the chunk kernel's program (the transposes and the
+# running sums around the call) at the lowest rung, the median first
+# token's and the top one, as PR 44's tree compiled them: the blocked
+# inverse holds its tile in values and drops the [C, C] scratch, so none
+# may grow
+GDN_CHUNK_TEMP = {256: 0, 1408: 0, 4096: 63_301_632}
+
+
+@pytest.mark.parametrize("T", sorted(GDN_CHUNK_TEMP))
 def test_gated_delta_chunk_fwd(one_chip, T):
-    """The chunkwise delta rule at the cell's head sizes, its lowest and
-    its highest rung: blocks of 64 tokens of one head, the triangular
-    solve's static lane slices, the state in VMEM."""
+    """The chunkwise delta rule at the cell's head sizes, its lowest rung,
+    the 1408 of a median first token and its highest: blocks of 64 tokens
+    of one head, the triangle inverted in two blocks of 32 (masked lane
+    sums, static slices of 8 to 32 rows, one product) and transposed, the
+    state in VMEM."""
     from paddle_tpu.ops import gated_delta as GD
 
-    _compile(GD.gated_delta_chunked, one_chip,
-             ((T, GDN_H, GDN_DK), BF16), ((T, GDN_H, GDN_DK), BF16),
-             ((T, GDN_H, GDN_DV), BF16), ((T, GDN_H), F32),
-             ((T, GDN_H), F32), ((), jnp.int32))
+    compiled = _compile(
+        GD.gated_delta_chunked, one_chip,
+        ((T, GDN_H, GDN_DK), BF16), ((T, GDN_H, GDN_DK), BF16),
+        ((T, GDN_H, GDN_DV), BF16), ((T, GDN_H), F32),
+        ((T, GDN_H), F32), ((), jnp.int32))
+    assert re.search(r"%gated_delta_chunk_fwd[\w.]* = ", compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes <= GDN_CHUNK_TEMP[T]
 
 
 def test_gated_delta_update_rows(one_chip):
@@ -1282,15 +1295,17 @@ def test_cohere_programs_fit_and_relay_no_weight(one_chip, program):
 # ---------------------------------------------------------------------------
 
 KDA_H, KDA_D = 64, 128
+KDA_CHUNK_TEMP = {256: 0, 1408: 47_266_304, 8192: 671_249_920}  # as GDN's
 SOLAR_B, SOLAR_S, SOLAR_PAGE, SOLAR_PAGES = 80, 11264, 64, 14081
 
 
-@pytest.mark.parametrize("T", [256, 8192])
+@pytest.mark.parametrize("T", sorted(KDA_CHUNK_TEMP))
 def test_kda_chunk_fwd(one_chip, T):
     """The chunkwise delta rule with a gate a channel at the cell's head
-    sizes, its lowest and its top rung: blocks of 64 tokens of one head,
-    the sub-blocks' selects on full tiles, rows read back from VMEM one by
-    one, the state in VMEM."""
+    sizes, its lowest rung, the 1408 of a median first token and its top
+    rung: blocks of 64 tokens of one head, the sub-blocks' selects on full
+    tiles, rows read back from VMEM one by one, the blocked inverse, the
+    state in VMEM."""
     from paddle_tpu.ops import gated_delta as GD
 
     compiled = _compile(
@@ -1298,6 +1313,7 @@ def test_kda_chunk_fwd(one_chip, T):
         ((T, KDA_H, KDA_D), BF16), ((T, KDA_H, KDA_D), BF16),
         ((T, KDA_H, KDA_D), F32), ((T, KDA_H), F32), ((), jnp.int32))
     assert re.search(r"%kda_chunk_fwd[\w.]* = ", compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes <= KDA_CHUNK_TEMP[T]
 
 
 def test_kda_update_rows(one_chip):
