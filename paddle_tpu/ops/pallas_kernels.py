@@ -1580,6 +1580,25 @@ def paged_decode_tiles(num_heads: int, head_dim: int) -> bool:
 # step a slot (its query and output blocks are pipelined by Pallas), and
 # the page copies of ``_gqa_decode_kernel``, double-buffered across chunks
 # AND grid steps: the parity of the flat work-item count lives in SMEM.
+#
+# A page is 20 KB at the deployment's pages of 16 rows, some 57 a rider
+# and layer, and the kernel is bound by its own instruction stream, not by
+# HBM: the copies' time and the fold's add. Mosaic turns a ``pl.when``
+# around ONE copy into a predicated copy, which costs its whole issue live
+# or not, and checks every copy's bounds twice (12 of the 22 bundles a
+# page's start took). So a chunk whose pages are all live is started in
+# straight-line code off the flat table and awaited ONCE, the span's last
+# chunk goes in pieces of 2^k pages (a real branch and one wait a piece),
+# and no copy is checked. Every chunk folds under the span's masks: a
+# second, unmasked fold for the chunks before the last read the same time
+# a launch at the cell's spans, under two chunks a rider, and is not kept
+# (PERF.md section 6, PR 50). The program's SIZE is budgeted too: every process that serves the model traces and lowers the
+# kernel in Python at start-up, compile cache warm or not, some 5 ms an
+# equation of its jaxpr on the benchmark's host (PERF.md section 7, PR 50),
+# which is what chunks of 1,024 rows, a fold a count of live row groups and
+# a second site of starts cost PR 49 (13.4 s of set-up for 6 % of the
+# kernel's time). tests/test_chip_compile.py holds the starts and products
+# to what is here.
 _MLA_CHUNK_ROWS = 512
 
 
@@ -1595,39 +1614,78 @@ def mla_decode_tiles(page_size: int, dtype) -> bool:
 def _mla_decode_kernel(layer_ref, tbl_ref, pos_ref, q_ref, pool_hbm, o_ref,
                        buf, sems, w_smem, m_scr, l_scr, acc_scr, *,
                        sm_scale, page, pages_per_chunk, batch, rank):
-    G = pages_per_chunk
+    G, M = pages_per_chunk, tbl_ref.shape[0] // batch    # the table is flat
     layer = layer_ref[0]
     b = pl.program_id(0)
+    R = G * page
 
     def live_pages(s):               # pages holding rows [0, pos[s]]
         return pos_ref[s] // page + 1
 
+    def pages(s, c, slot, at, n, go):
+        """``n`` pages of chunk ``c`` of slot ``s`` from the chunk's page
+        ``at`` on: ``n`` copies started one behind the other with no guard
+        between them, or ONE wait for the bytes of all ``n`` (a wait
+        counts its descriptor's bytes on the semaphore, whoever sent
+        them)."""
+        if not go:
+            dst = buf.at[slot, pl.ds(at, n)]
+            pltpu.make_async_copy(dst, dst, sems.at[slot]).wait()
+            return
+        for i in range(n):
+            pltpu.make_async_copy(
+                pool_hbm.at[layer, tbl_ref[s * M + c * G + at + i]],
+                buf.at[slot, at + i], sems.at[slot]).start()
+
     def copies(s, c, slot, go):
-        n = live_pages(s)
-        for i in range(G):
-            pg = c * G + i
+        """Chunk ``c`` of slot ``s``, started or awaited: whole where all
+        its pages are live, else its live pages in pieces of 2^k pages,
+        the larger first, one guard a piece. No copy exists for a page no
+        token lives on."""
+        left = live_pages(s) - c * G
+        pl.when(left >= G)(lambda: pages(s, c, slot, 0, G, go))
 
-            @pl.when(pg < n)
-            def _():
-                cp = pltpu.make_async_copy(
-                    pool_hbm.at[layer, tbl_ref[s, pg]], buf.at[slot, i],
-                    sems.at[slot])
-                if go:
-                    cp.start()
-                else:
-                    cp.wait()
-
-    @pl.when(b == 0)
-    def _first():
-        w_smem[0] = 0
-        copies(0, 0, 0, True)
+        @pl.when(left < G)
+        def _tail():
+            n = 1 << max(G - 1, 1).bit_length() - 1
+            while n:
+                pl.when(left & n != 0)(functools.partial(
+                    pages, s, c, slot, left & -(2 * n), n, go))
+                n //= 2
 
     pos = pos_ref[b]
     nc = (live_pages(b) + G - 1) // G
-    R = G * page
     q = q_ref[0]                                         # (H, rank + rope)
 
+    def fold(c, slot):
+        """Chunk ``c`` into the running softmax. Rows past ``pos`` may be
+        VMEM no copy has written: masked in the scores AND zeroed where
+        they are values."""
+        rows = buf[slot].reshape(R, buf.shape[-1])
+        live = c * R + jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0) <= pos
+        rows = jnp.where(live, rows, 0).astype(rows.dtype)
+        s = jax.lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale   # (H, R)
+        m_prev = m_scr[...]                              # (H, 1)
+        valid = c * R + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1) <= pos
+        s = jnp.where(valid, s, -jnp.inf)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        e = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
+        alpha = jnp.exp(m_prev - m_safe)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(e, axis=1, keepdims=True)
+        acc_scr[...] = alpha * acc_scr[...] + jnp.dot(
+            e.astype(rows.dtype), rows[:, :rank],
+            preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
+
     def chunk(c, w):
+        """One pass: start the work item behind ``(b, c)`` into the other
+        buffer, then await and fold ``(b, c)``. The launch's first item is
+        started by a pass of its own, ``c == -1`` at ``b == 0``, that folds
+        nothing: the copies' starts are in the program ONCE."""
         slot = w % 2
         last = c + 1 == nc
         nb = jnp.where(last, b + 1, b)
@@ -1637,39 +1695,24 @@ def _mla_decode_kernel(layer_ref, tbl_ref, pos_ref, q_ref, pool_hbm, o_ref,
         def _prefetch():
             copies(nb, nxt, 1 - slot, True)
 
-        copies(b, c, slot, False)
+        @pl.when(c >= 0)
+        def _work():
+            copies(b, c, slot, False)
 
-        @pl.when(c == 0)
-        def _init():
-            m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
-            l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
-            acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+            @pl.when(c == 0)
+            def _init():
+                m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
+                l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+                acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-        rows = buf[slot].reshape(R, buf.shape[-1])
-        # rows past the slot's length may be VMEM no copy has written:
-        # masked in the scores AND zeroed where they are values
-        live = c * R + jax.lax.broadcasted_iota(
-            jnp.int32, (R, 1), 0) < pos + 1
-        rows = jnp.where(live, rows, 0).astype(rows.dtype)
-        ckv = rows[:, :rank]
-        s = jax.lax.dot_general(
-            q, rows, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale   # (H, R)
-        valid = c * R + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1) < pos + 1
-        s = jnp.where(valid, s, -jnp.inf)
-        m_prev = m_scr[...]                              # (H, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        alpha = jnp.exp(m_prev - m_safe)
-        e = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(e, axis=1, keepdims=True)
-        acc_scr[...] = alpha * acc_scr[...] + jnp.dot(
-            e.astype(rows.dtype), ckv, preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+            fold(c, slot)
+
         return w + 1
 
-    w_smem[0] = jax.lax.fori_loop(0, nc, chunk, w_smem[0])
+    first = b == 0
+    w_smem[0] = jax.lax.fori_loop(
+        jnp.where(first, -1, 0), nc, chunk,
+        jnp.where(first, 0, w_smem[0]))
     o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
                 ).astype(o_ref.dtype)
 
@@ -1715,12 +1758,17 @@ def mla_paged_decode_attention(q_lat, pool, new_rows, tables, positions,
                               rank=rank),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((B, H, rank), q_lat.dtype),
+            # no check of a copy's bounds (a fifth of the kernel's time): a
+            # page index is an entry of the cache manager's table, in
+            # [0, P) (tests/test_kimi_k2.py holds it to that), a buffer
+            # index a Python ``range``
             compiler_params=_CompilerParams(
-                dimension_semantics=("arbitrary",)),
+                dimension_semantics=("arbitrary",),
+                disable_bounds_checks=True),
             interpret=_interpret(),
             name="mla_paged_decode",
         )(jnp.reshape(layer, (1,)).astype(jnp.int32),
-          tables.astype(jnp.int32), positions.astype(jnp.int32),
+          tables.reshape(-1).astype(jnp.int32), positions.astype(jnp.int32),
           q_lat.astype(pool.dtype), pool)
     return out, pool
 

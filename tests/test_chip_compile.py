@@ -800,6 +800,78 @@ def _lower_kimi_cut(program, sharding, uncut=False):
     return jax.jit(fn, donate_argnums=(1,)).lower(held, pool, feed)
 
 
+# What ``_mla_decode_kernel`` may hold, counted in the kernel's jaxpr at the
+# cell's shapes (each ``pl.when`` and loop body once): copies started, and
+# products on the MXU (two a fold body). PR 50 ships 63 starts (32 of a
+# whole chunk and 16 + 8 + 4 + 2 + 1 of a tail, at ONE site) and ONE fold
+# body of 512 rows. A larger program is paid for by EVERY process that
+# serves the model, warm compile cache or not: PR 49's kernel (254 starts at
+# two sites, five fold bodies of up to 1,024 rows) was 6 % faster end to end
+# and cost 13.4 s more ``setup_s`` in the expert-parallel cell, which
+# refused it (ledger, PR 49; the table of variant, program size, first call
+# and warm set-up is PERF.md section 6, PR 50). Raise these only with that
+# table's columns measured anew. (Two products: an unmasked second fold
+# body read the same time a launch in the cell and is not shipped.)
+MLA_KERNEL_DMA_STARTS = 63
+MLA_KERNEL_DMA_WAITS = 6
+MLA_KERNEL_DOTS = 2
+
+
+def _kernel_primitive_counts(fn, *args):
+    """{primitive: count} over the jaxpr of the one ``pallas_call`` that
+    ``fn`` traces to, the bodies of its conditionals and loops once each."""
+    from collections import Counter
+
+    def subjaxprs(eqn):
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield inner
+
+    def walk(jaxpr, count):
+        for eqn in jaxpr.eqns:
+            count[eqn.primitive.name] += 1
+            for inner in subjaxprs(eqn):
+                walk(inner, count)
+        return count
+
+    def kernels(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["jaxpr"]
+            else:
+                for inner in subjaxprs(eqn):
+                    yield from kernels(inner)
+
+    (kernel,) = kernels(jax.make_jaxpr(fn)(*args).jaxpr)
+    return walk(kernel, Counter())
+
+
+def test_mla_paged_decode_attention(one_chip):
+    """Mosaic takes the latent decode kernel at the expert-parallel cell's
+    shapes (128 slots, 64 heads, rows of 640 lanes of which 512 are
+    values, pages of 16 rows, a table of 192 entries), its copies
+    unchecked; and the kernel's program is no larger than PR 50 shipped
+    it."""
+    B, H, W, RANK, M = 128, 64, 640, 512, 192
+
+    def fn(q, pool, rows, tables, positions, layer):
+        return PK.mla_paged_decode_attention(
+            q, pool, rows, tables, positions, layer, RANK, 0.1447)
+
+    shapes = (((B, H, W), BF16), ((3, B * M + 1, PAGE, W), BF16),
+              ((B, W), BF16), ((B, M), jnp.int32), ((B,), jnp.int32),
+              ((), jnp.int32))
+    compiled = _compile(fn, one_chip, *shapes)
+    assert re.search(r"%mla_paged_decode[\w.]* = ", compiled.as_text())
+    count = _kernel_primitive_counts(
+        fn, *[jax.ShapeDtypeStruct(s, d) for s, d in shapes])
+    assert count["dma_start"] <= MLA_KERNEL_DMA_STARTS, count["dma_start"]
+    assert count["dot_general"] <= MLA_KERNEL_DOTS, count["dot_general"]
+    assert count["dma_wait"] <= MLA_KERNEL_DMA_WAITS, count["dma_wait"]
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill_b512"])
 def test_kimi_programs_copy_no_expert_leaf(one_chip, program):
     """The expert-parallel cell's tick and a prefill rung on the chip's
@@ -854,6 +926,10 @@ def test_kimi_programs_copy_no_expert_leaf(one_chip, program):
 # and attends the transposed flat view; ``jamba_cut/*`` and ``kimi/*`` are
 # PR 38's still, which is the proof that those cells' programs are the
 # parent's.
+# Read anew for ``kimi/decode`` at PR 50's, in both tables: the latent
+# kernel takes its page table flat (a reshape of the feed's columns before
+# the call, ``s32[24576]`` where ``s32[128,192]`` was) and its custom call
+# says ``disable_bounds_checks``; ``kimi/prefill_b512`` is PR 38's still.
 # A PR that changes a program on purpose reads the new digests off the
 # failure's message, puts them here and says in PERF.md which program
 # changed and why; one that meant to leave the device's work alone has not.
@@ -883,7 +959,7 @@ PROGRAM_TEXT_SHA256 = {
     "jamba_cut/prefill_b2048":
         "4236a86f1728454259195c681b48841cb57449fe7651ad4e8ed25460b3dc2c33",
     "kimi/decode":
-        "581fb938140ac7db08b08c921d14aa26a684c3f4a935cef192eec095ba17499c",
+        "6c84ea7d634ae5574ae38a702d86fcc3c189f557c609fd17c94aa6eb6a8fc6cd",
     "kimi/prefill_b512":
         "abe8eea28687e68d7baa85e1ba7304e6e712b5fc6c0a4367049da51ae1e778e1",
 }
@@ -920,7 +996,7 @@ BEHIND_THE_CUT_SHA256 = {
     "jamba_cut/prefill_b2048":
         "6c4346064d7e51b44def091e3c8ef4e160b304ee37389623122cfd2b0d719b6a",
     "kimi/decode":
-        "4f8e56263e7f8154dd1f6d6eccac2cbb120e988efe66a9a30b3253fa485ba6bf",
+        "522f60c4ac8926985a3609a4761425cce7bc7cbc153148f4a0b6d10daffd97b2",
     "kimi/prefill_b512":
         "a83f6981bf07fd7f8b7fff087feb600adbd4c04fd18b55f9419b06080794d73e",
 }

@@ -13,6 +13,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -214,27 +215,87 @@ def test_absorbed_attention_is_the_expanded_one():
     np.testing.assert_allclose(absorbed, expanded, rtol=1e-4, atol=1e-5)
 
 
-def test_both_lowerings_of_the_latent_decode_agree():
+# (id, page, chunk rows, table entries, positions a lane; None: the lane
+# idles). A chunk holds ``G = chunk rows / page`` pages; a rider at position
+# ``p`` has ``p // page + 1`` live pages: whole chunks of ``G`` and a tail
+# of ``left`` pages, which the kernel starts and awaits in pieces of 2^k
+# pages, one a set bit of ``left``.
+LATENT_DECODE_CASES = [
+    # the one case this test was before PR 50: a table of one chunk
+    ("positions_19_and_8_and_an_idle_lane", 8, 512, 4, [19, 8, None]),
+    # the span ends on a chunk's last row (no tail at all), and one row on
+    ("ends_on_a_chunks_last_row", 8, 32, 12, [31, 63, None]),
+    ("one_row_into_the_next_chunk", 8, 32, 12, [32, None, 64]),
+    ("on_a_pages_first_and_last_row", 8, 32, 12, [8, 15, 40, 47]),
+    ("in_the_first_page", 8, 32, 12, [0, 3, 7]),
+    ("every_page_of_the_table_live", 8, 32, 12, [95, None, 95]),
+    ("one_and_three_whole_chunks_then_a_tail", 8, 32, 16, [37, 100, 127]),
+    # G = 8: tails of 1..7 pages, every piece (4, 2, 1) alone and together
+    ("tails_of_1_2_3_4_pages", 8, 64, 16, [64, 75, 87, 31]),
+    ("tails_of_5_6_7_pages", 8, 64, 16, [39, 111, 119, 55]),
+    ("idle_lanes_first_last_and_between", 8, 32, 12,
+     [None, 19, None, None, 70, None]),
+    ("one_lane", 8, 32, 12, [45]),
+    ("one_idle_lane", 8, 32, 12, [None]),
+    ("a_page_a_chunk", 8, 8, 8, [0, 7, 8, None, 63]),
+    ("pages_of_16_four_a_chunk", 16, 64, 8, [15, 16, 63, 64, 127, None]),
+    ("pages_of_16_a_table_smaller_than_a_chunk", 16, 512, 6, [95, 17]),
+]
+
+
+@pytest.mark.parametrize(
+    "page, chunk_rows, M, positions",
+    [c[1:] for c in LATENT_DECODE_CASES],
+    ids=[c[0] for c in LATENT_DECODE_CASES])
+def test_both_lowerings_of_the_latent_decode_agree(monkeypatch, page,
+                                                   chunk_rows, M, positions):
+    """``mla_paged_decode_attention`` (interpret mode: the same code the
+    chip runs) against ``latent_decode_attention`` over ``paged_gather``,
+    every lane: an idle one reads the scratch page's first row. Rows past a
+    rider's position in its last page are NaN in the kernel's pool: the
+    copies bring them in, and only the masks keep them out of the scores
+    and of the values. The interpreter performs a copy when its bytes are
+    AWAITED and leaves unwritten VMEM NaN, so a wait that counts too few
+    pages, or the wrong piece's, reads NaN here (plain interpret mode
+    completes a copy at its start and cannot tell)."""
+    monkeypatch.setattr(PK, "_MLA_CHUNK_ROWS", chunk_rows)
+    monkeypatch.setattr(PK, "_interpret", lambda: pltpu.InterpretParams(
+        dma_execution_mode="on_wait", uninitialized_memory="nan"))
     rng = np.random.default_rng(3)
-    L, P, page, W, R, B, H, M = 2, 9, 8, 128, 96, 3, 4, 4
-    pool = jnp.asarray(rng.normal(size=(L, P, page, W)), jnp.float32)
+    L, W, R, H, B = 2, 128, 96, 4, len(positions)
+    riders = [b for b, p in enumerate(positions) if p is not None]
+    pos = np.array([p or 0 for p in positions], np.int32)
+    need = [0 if p is None else p // page + 1 for p in positions]
+    P = sum(need) + 3
+    assert max(need, default=0) <= M
+    free = rng.permutation(np.arange(1, P)).tolist()
+    tables = np.zeros((B, M), np.int32)
+    for b in riders:
+        tables[b, :need[b]] = [free.pop() for _ in range(need[b])]
+    pool = rng.normal(size=(L, P, page, W)).astype(np.float32)
+    poisoned = pool.copy()
+    for b in riders:
+        poisoned[:, tables[b, need[b] - 1], pos[b] % page + 1:] = np.nan
     q = jnp.asarray(rng.normal(size=(B, H, W)), jnp.float32)
     new = jnp.asarray(rng.normal(size=(B, W)), jnp.float32)
-    tables = jnp.asarray([[1, 2, 3, 0], [4, 5, 0, 0], [0, 0, 0, 0]],
-                         jnp.int32)
-    positions = jnp.asarray([19, 8, 0], jnp.int32)   # the last lane idles
+    tables, pos = jnp.asarray(tables), jnp.asarray(pos)
     out, pool2 = PK.mla_paged_decode_attention(
-        q, pool, new, tables, positions, jnp.int32(1), R, 0.2)
-    # the row went where the table says
-    np.testing.assert_array_equal(np.asarray(pool2[1, 3, 3]),
-                                  np.asarray(new[0]))
-    np.testing.assert_array_equal(np.asarray(pool2[1, 5, 0]),
-                                  np.asarray(new[1]))
-    np.testing.assert_array_equal(np.asarray(pool2[0]), np.asarray(pool[0]))
-    want = latent_decode_attention(q, paged_gather(pool2, tables, 1),
-                                   positions + 1, R, 0.2)
+        q, jnp.asarray(poisoned), new, tables, pos, jnp.int32(1), R, 0.2)
+    # the rows went where the table says, in the one layer
+    for b in riders:
+        np.testing.assert_array_equal(
+            np.asarray(pool2[1, tables[b, need[b] - 1], pos[b] % page]),
+            np.asarray(new[b]))
+    np.testing.assert_array_equal(np.asarray(pool2[0]), poisoned[0])
+    clean = jnp.where(jnp.isnan(pool2), pool, pool2)
+    want = latent_decode_attention(q, paged_gather(clean, tables, 1),
+                                   pos + 1, R, 0.2)
+    assert np.isfinite(np.asarray(out)).all()
+    # float32 both sides; the kernel sums a span chunk by chunk under a
+    # running maximum, the reference in one pass: 1.8e-6 apart at most, on
+    # the spans of 128 rows (atol was 1e-6 when the longest span was 20)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                               rtol=1e-5, atol=1e-6)
+                               rtol=1e-5, atol=2e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -289,6 +350,67 @@ def test_prefill_then_decode_through_the_latent_pool(fused):
     assert smetrics.m_moe_dropped.value == dropped0 == 0
     assert smetrics.m_moe_routed.labels("elsewhere").value > \
         smetrics.m_moe_routed.labels("here").value > 0
+
+
+def test_the_latent_tick_is_handed_pages_of_its_pool_and_zeros():
+    """What ``mla_paged_decode_attention`` rests on since its page copies
+    go unchecked (``disable_bounds_checks``): every entry of every table
+    row a tick is fed names a page of the pool, ``[0, P)``; a lane that
+    does not ride has the all-zero row (the scratch page) and position 0;
+    a rider's live pages ``[0, position // page]`` are its own, none the
+    scratch page and none another rider's. Through admit, grow over page
+    ends, evict, and reuse of the slot and of its pages, in a pool too
+    small to hold every slot at full length."""
+    eng = _engine(num_pages=13)
+    P, page, M = eng.cache.arrays()[0].shape[1], 8, eng.table_width
+    assert (P, M) == (13, 8)
+    fed = []
+    tick_args = eng._tick_args
+
+    def recorded(slot_tokens, params_by_slot):
+        feed, sampler = tick_args(slot_tokens, params_by_slot)
+        fed.append((feed.copy(), sorted(slot_tokens)))
+        return feed, sampler
+
+    eng._tick_args = recorded
+    rng = np.random.default_rng(6)
+    streams = {}
+
+    def admit(n):
+        prompt = rng.integers(0, SHARE.vocab_size, n).tolist()
+        slot, _, tok = eng.start_sequence_sampled(
+            prompt, serving.sampling.GREEDY)
+        streams[slot] = tok
+
+    def tick(n=1):
+        for _ in range(n):
+            out = eng.decode_step_sampled(dict(streams), None)
+            for slot, (tok, _) in out.items():
+                streams[slot] = tok
+
+    admit(11), admit(19), admit(7)            # 2, 3 and 1 pages
+    tick(10)                                  # each grows over a page end
+    gone = min(streams)
+    eng.free_sequence(gone)                   # evict: its pages go back
+    del streams[gone]
+    tick(2)                                   # its lane idles among riders
+    admit(31)               # 4 of the 5 free pages: the slot AND pages reused
+    assert gone in streams
+    tick(1)
+    for slot in list(streams):
+        eng.free_sequence(slot)
+    assert len(fed) == 13
+    was = set(fed[9][0][gone, :M].tolist()) - {0}
+    assert len(was & set(fed[-1][0][gone, :M].tolist())) >= 2
+    for feed, riders in fed:
+        tables, positions = feed[:, :M], feed[:, M]
+        assert tables.min() >= 0 and tables.max() < P
+        idle = [s for s in range(feed.shape[0]) if s not in riders]
+        assert idle, "a lane idles in every tick of this test"
+        assert not tables[idle].any() and not positions[idle].any()
+        live = [tables[s, :positions[s] // page + 1] for s in riders]
+        held = np.concatenate(live)
+        assert (held > 0).all() and len(set(held.tolist())) == held.size
 
 
 def _family_config(cfg, **over):
