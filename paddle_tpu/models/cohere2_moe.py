@@ -57,6 +57,7 @@ from ..ops.decode_attention import (band_prefill_attention,
                                     paged_cache_update, paged_gather,
                                     paged_page_write,
                                     sliding_decode_attention)
+from .blocks import hold_leaves, over_ffn_chunks
 
 __all__ = ["Cohere2MoeConfig", "COHERE2_MOE_TINY", "leaf_shapes",
            "init_params", "hold", "forward", "Cohere2MoeServing"]
@@ -117,6 +118,11 @@ class Cohere2MoeConfig:
 
     def scaled(self, **kw) -> "Cohere2MoeConfig":
         return dataclasses.replace(self, **kw)
+
+    def serving_description(self) -> "Cohere2MoeServing":
+        """What ``DecodeEngine`` builds its programs from
+        (``serving/model.py``)."""
+        return Cohere2MoeServing(self)
 
 
 COHERE2_MOE_TINY = Cohere2MoeConfig(
@@ -214,27 +220,6 @@ def _qkv(u, p, positions, rotary: bool, cfg):
     return q, k, v
 
 
-# tokens of a rung whose experts run in one call, at most: the gathered
-# rows, the shared experts' hidden rows, the 0/1 matrix that sums the pairs
-# back and the share's full-size fallback (8 rows a token) are sized by it,
-# whatever the rung (1.8 GB of a 16,384 rung's temporaries at 4096, half
-# that at 2048; every chunk reads the layer's expert weights again, 2 GB in
-# 2.4 ms beside 5 ms of products)
-_FFN_ROWS = 2048
-
-
-def _ffn_chunk(T: int) -> int:
-    """The largest divisor of ``T`` that is whole row tiles (128) and at
-    most ``_FFN_ROWS``; ``T`` itself where it is no more than that, or has
-    no such divisor."""
-    if T <= _FFN_ROWS:
-        return T
-    for n in range(-(-T // _FFN_ROWS), T // 128 + 1):
-        if T % n == 0 and (T // n) % 128 == 0:
-            return T // n
-    return T
-
-
 def _ffn_rows(u, valid, stacked, l: int, cfg, use_pallas):
     """``(FFN(u) [N, D], report [G + 1] int32)`` of the rows ``u``. The
     layer's leaves are cut out of the stacked ones HERE, inside the loop
@@ -265,23 +250,13 @@ def _ffn_rows(u, valid, stacked, l: int, cfg, use_pallas):
 
 
 def _ffn(u, valid, held, l: int, cfg, use_pallas=None):
-    """The feed-forward half of layer ``l`` on ``u [T, D]``, ``_FFN_ROWS``
-    tokens at a time. Returns ``(ffn [T, D], report [G + 1] int32)``:
-    tokens on each held expert, and the held pairs that reached no expert
-    (0: nothing is dropped)."""
+    """The feed-forward half of layer ``l`` on ``u [T, D]``, a rung's
+    chunk of tokens at a time (``blocks.over_ffn_chunks``). Returns ``(ffn
+    [T, D], report [G + 1] int32)``."""
     stacked = held["layers"]
-    T, c = u.shape[0], _ffn_chunk(u.shape[0])
-    if c == T:
-        return _ffn_rows(u, valid, stacked, l, cfg, use_pallas)
-
-    def step(report, xs):
-        y, r = _ffn_rows(xs[0], xs[1], stacked, l, cfg, use_pallas)
-        return report + r, y
-
-    report, y = jax.lax.scan(
-        step, jnp.zeros((cfg.experts_held + 1,), jnp.int32),
-        (u.reshape(T // c, c, -1), valid.reshape(T // c, c)))
-    return y.reshape(T, -1), report
+    return over_ffn_chunks(
+        lambda rows, ok: _ffn_rows(rows, ok, stacked, l, cfg, use_pallas),
+        u, valid, cfg.experts_held)
 
 
 def _sequence(held, x, length, cfg, write_rows=None, use_pallas=None,
@@ -339,7 +314,6 @@ def hold(params, cfg: Cohere2MoeConfig, weight_dtype: str = "f32"):
     and ``shared_down [L, S F, D]``, whose output the layer divides by
     ``S``. The routed experts are stored as the grouped product contracts
     them and are held as they are."""
-    dt = {"f32": jnp.float32, "bf16": jnp.bfloat16}[weight_dtype]
     H, KVH, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                   cfg.head_dim)
     halves = _rope.halves_from_interleaved(hd)
@@ -361,13 +335,7 @@ def hold(params, cfg: Cohere2MoeConfig, weight_dtype: str = "f32"):
          side_by_side(a.pop("shared_up"))], axis=-1)
     down = a.pop("shared_down")              # [L, S, F, D] -> [L, S F, D]
     a["shared_down"] = down.reshape(L, -1, down.shape[-1])
-    tree = {**params, "layers": a}
-
-    def one(path, x):
-        keep = path[-1].key in F32_LEAVES
-        return jnp.asarray(x, jnp.float32 if keep else dt)
-
-    return jax.tree_util.tree_map_with_path(one, tree)
+    return hold_leaves({**params, "layers": a}, weight_dtype, F32_LEAVES)
 
 
 def forward(params, tokens, cfg: Cohere2MoeConfig):

@@ -84,6 +84,13 @@ class GPTConfig:
     def scaled(self, **kw) -> "GPTConfig":
         return dataclasses.replace(self, **kw)
 
+    def serving_description(self):
+        """What ``DecodeEngine`` builds its programs from
+        (``serving/model.py``). Imported here: training loads none of it."""
+        from .gpt_serving import GPTServing
+
+        return GPTServing(self)
+
 
 # 124M-ish config for single-chip benches; tiny config for tests/dryruns.
 GPT_SMALL = GPTConfig(vocab_size=50304, max_seq_len=1024, num_layers=12,

@@ -39,6 +39,8 @@ from ..ops import selective_scan as _ss
 from ..ops.decode_attention import (decode_attention, paged_cache_update,
                                     paged_gather, paged_page_write,
                                     prefill_attention)
+from .blocks import (gated_mlp, hold_leaves, layer_at, mlp_shapes,
+                     rms_norm)
 
 __all__ = ["JambaConfig", "JAMBA_TINY", "init_params", "forward",
            "JambaServing"]
@@ -100,6 +102,11 @@ class JambaConfig:
     def scaled(self, **kw) -> "JambaConfig":
         return dataclasses.replace(self, **kw)
 
+    def serving_description(self) -> "JambaServing":
+        """What ``DecodeEngine`` builds its programs from
+        (``serving/model.py``)."""
+        return JambaServing(self)
+
 
 JAMBA_TINY = JambaConfig(
     vocab_size=256, hidden_size=64, intermediate_size=128,
@@ -111,11 +118,6 @@ JAMBA_TINY = JambaConfig(
 # biases and the scan's own constants (5 k values a layer against 104 M)
 F32_LEAVES = ("norm_in", "norm_ff", "final_norm", "conv_b", "dt_norm",
               "b_norm", "c_norm", "dt_bias", "A_log", "D")
-
-
-def _mlp_shapes(n, D, F):
-    return {"norm_ff": (n, D), "gate": (n, D, F), "up": (n, D, F),
-            "down": (n, F, D)}
 
 
 def leaf_shapes(cfg: JambaConfig) -> Dict[str, Any]:
@@ -134,10 +136,10 @@ def leaf_shapes(cfg: JambaConfig) -> Dict[str, Any]:
              "b_norm": (Lm, N), "c_norm": (Lm, N),
              "dt_proj": (Lm, R, Di), "dt_bias": (Lm, Di),
              "A_log": (Lm, N, Di), "D": (Lm, Di),
-             "out_proj": (Lm, Di, D), **_mlp_shapes(Lm, D, F)}
+             "out_proj": (Lm, Di, D), **mlp_shapes(Lm, D, F)}
     attn = {"norm_in": (D,), "wq": (D, nh * hd), "wk": (D, kvh * hd),
             "wv": (D, kvh * hd), "wo": (nh * hd, D),
-            **{k: s[1:] for k, s in _mlp_shapes(1, D, F).items()}}
+            **{k: s[1:] for k, s in mlp_shapes(1, D, F).items()}}
     return {"embed": (cfg.vocab_size, D), "final_norm": (D,),
             "mamba": mamba,
             "attn": [dict(attn) for _ in cfg.attention_layers]}
@@ -181,19 +183,9 @@ def init_params(key, cfg: JambaConfig) -> Dict[str, Any]:
 # the pieces of a layer
 # ---------------------------------------------------------------------------
 
-def rms_norm(x, gain, eps):
-    xf = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-    return (xf * jax.lax.rsqrt(var + eps)
-            * gain.astype(jnp.float32)).astype(x.dtype)
-
-
 def _mlp(h, p, cfg):
-    dt = cfg.dtype
     u = rms_norm(h, p["norm_ff"], cfg.rms_norm_eps)
-    g = jnp.dot(u, p["gate"].astype(dt))
-    return h + jnp.dot(jax.nn.silu(g) * jnp.dot(u, p["up"].astype(dt)),
-                       p["down"].astype(dt))
+    return h + gated_mlp(u, p["gate"], p["up"], p["down"], cfg.dtype)
 
 
 def _scan_inputs(xs, p, cfg):
@@ -278,14 +270,6 @@ def _attn_out(a, p, cfg):
                    p["wo"].astype(cfg.dtype))
 
 
-def _layer_at(stacked, m):
-    """Layer ``m`` (traced) of the stacked Mamba leaves, sliced where it
-    is used: the loop's operand stays the whole stack, in place."""
-    return jax.tree_util.tree_map(
-        lambda a: jax.lax.dynamic_index_in_dim(a, m, 0, keepdims=False),
-        stacked)
-
-
 def _over_layers(cfg, params, x, carry, mamba_layer, attn_layer):
     """The layers in order: a ``fori_loop`` a run of Mamba layers with
     ``carry`` (the caches) carried in place, an attention layer between."""
@@ -295,7 +279,7 @@ def _over_layers(cfg, params, x, carry, mamba_layer, attn_layer):
             continue
 
         def body(m, xc):
-            return mamba_layer(xc[0], _layer_at(params["mamba"], m), m,
+            return mamba_layer(xc[0], layer_at(params["mamba"], m), m,
                                xc[1])
 
         x, carry = jax.lax.fori_loop(first, first + count, body, (x, carry))
@@ -332,19 +316,6 @@ def _logits(params, h, cfg):
 # ---------------------------------------------------------------------------
 # what the serving engine asks of a model (serving/model.py)
 # ---------------------------------------------------------------------------
-
-def hold_leaves(params, weight_dtype: str, f32_leaves):
-    """A parameter tree as an engine holds it: the leaves named in
-    ``f32_leaves`` float32, every other leaf in ``weight_dtype``
-    (``"f32"`` or ``"bf16"``), each in its stored shape."""
-    held = {"f32": jnp.float32, "bf16": jnp.bfloat16}[weight_dtype]
-
-    def one(path, x):
-        keep = path[-1].key in f32_leaves
-        return jnp.asarray(x, jnp.float32 if keep else held)
-
-    return jax.tree_util.tree_map_with_path(one, params)
-
 
 class JambaServing:
     """The model description ``DecodeEngine`` builds its paged prefill and

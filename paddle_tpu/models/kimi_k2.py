@@ -52,7 +52,8 @@ from ..ops import rope as _rope
 from ..ops.decode_attention import (latent_decode_attention,
                                     paged_cache_update, paged_gather,
                                     paged_page_write)
-from .jamba import rms_norm
+from .blocks import (gated_mlp, hold_leaves, layer_at, rms_logits,
+                     rms_norm)
 
 __all__ = ["KimiK2Config", "KIMI_K2_TINY", "leaf_shapes", "init_params",
            "forward", "KimiK2Serving"]
@@ -123,6 +124,11 @@ class KimiK2Config:
 
     def scaled(self, **kw) -> "KimiK2Config":
         return dataclasses.replace(self, **kw)
+
+    def serving_description(self) -> "KimiK2Serving":
+        """What ``DecodeEngine`` builds its programs from
+        (``serving/model.py``)."""
+        return KimiK2Serving(self)
 
 
 _TINY_YARN = {"type": "yarn", "factor": 4.0, "beta_fast": 32.0,
@@ -201,20 +207,6 @@ def init_params(key, cfg: KimiK2Config) -> Dict[str, Any]:
 # the pieces of a layer (on the HELD tree: KimiK2Serving.hold)
 # ---------------------------------------------------------------------------
 
-def _at(stacked, l):
-    """Layer ``l`` (static or traced) of stacked leaves, sliced where it
-    is used."""
-    return jax.tree_util.tree_map(
-        lambda a: jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False),
-        stacked)
-
-
-def _gated_mlp(u, gate, up, down, dt):
-    g = jnp.dot(u, gate.astype(dt))
-    return jnp.dot(jax.nn.silu(g) * jnp.dot(u, up.astype(dt)),
-                   down.astype(dt))
-
-
 def _latent_projections(u, p, positions, cfg):
     """u ``[N, D]`` (normed) at ``positions [N]`` -> ``(q_nope [N, H,
     nope], q_rope [N, H, rope] rotated, c_kv [N, rank] normed, k_rope [N,
@@ -292,21 +284,22 @@ def _ffn(h, valid, held, l, cfg, use_pallas=None):
     the held pairs that reached no expert (0: nothing is dropped)."""
     dt = cfg.dtype
     G = cfg.experts_held
-    u = rms_norm(h, _at(held["attn"]["norm_ff"], l), cfg.rms_norm_eps)
+    u = rms_norm(h, layer_at(held["attn"]["norm_ff"], l), cfg.rms_norm_eps)
     if isinstance(l, int) and l < cfg.first_k_dense_replace:
-        d = _at(held["dense"], l)
-        return (h + _gated_mlp(u, d["gate"], d["up"], d["down"], dt),
+        d = layer_at(held["dense"], l)
+        return (h + gated_mlp(u, d["gate"], d["up"], d["down"], dt),
                 jnp.zeros((G + 1,), jnp.int32))
     m = l - cfg.first_k_dense_replace
     e = held["moe"]
     experts, w = _moe.route(
-        u, _at(e["router"], m), _at(e["router_bias"], m),
+        u, layer_at(e["router"], m), layer_at(e["router_bias"], m),
         cfg.num_experts_per_tok, cfg.routed_scaling_factor)
     y, report = _moe.expert_share(
         u, valid, experts, w, e["w_gate_up"], e["w_down"],
         first_expert=cfg.first_expert, layer=m, use_pallas=use_pallas)
-    shared = _gated_mlp(u, _at(e["shared_gate"], m), _at(e["shared_up"], m),
-                        _at(e["shared_down"], m), dt)
+    shared = gated_mlp(u, layer_at(e["shared_gate"], m),
+                       layer_at(e["shared_up"], m),
+                       layer_at(e["shared_down"], m), dt)
     return h + y + shared, report
 
 
@@ -339,7 +332,8 @@ def _sequence(held, x, length, cfg, pool=None, write_rows=None,
     valid = positions < length
 
     def layer(h, l, pool):
-        out, rows = _attn_sequence(h, _at(held["attn"], l), positions, cfg)
+        out, rows = _attn_sequence(h, layer_at(held["attn"], l), positions,
+                                   cfg)
         if write_rows is not None:
             pool = write_rows(pool, rows, l)
         h, report = _ffn(h + out, valid, held, l, cfg, use_pallas)
@@ -349,9 +343,8 @@ def _sequence(held, x, length, cfg, pool=None, write_rows=None,
 
 
 def _logits(held, h, cfg):
-    h = rms_norm(h, held["final_norm"], cfg.rms_norm_eps)
-    return jnp.dot(h, held["head"].astype(cfg.dtype),
-                   preferred_element_type=jnp.float32)
+    return rms_logits(h, held["final_norm"], held["head"], cfg.rms_norm_eps,
+                      cfg.dtype)
 
 
 def hold(params, cfg: KimiK2Config, weight_dtype: str = "f32"):
@@ -364,7 +357,6 @@ def hold(params, cfg: KimiK2Config, weight_dtype: str = "f32"):
     ``w_uv [L, H, rank, v]``, the absorbed halves. Every expert leaf is
     stored in the layout the grouped product contracts and is held as it
     is: no tick and no rung copies a weight."""
-    dt = {"f32": jnp.float32, "bf16": jnp.bfloat16}[weight_dtype]
     H, dn, dr, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                      cfg.qk_rope_head_dim, cfg.v_head_dim)
     Rkv = cfg.kv_lora_rank
@@ -382,13 +374,7 @@ def hold(params, cfg: KimiK2Config, weight_dtype: str = "f32"):
     kvb = a.pop("w_kvb").reshape(L, Rkv, H, dn + dv)
     a["w_uk"] = jnp.transpose(kvb[..., :dn], (0, 2, 1, 3))
     a["w_uv"] = jnp.transpose(kvb[..., dn:], (0, 2, 1, 3))
-    tree = {**params, "attn": a}
-
-    def one(path, x):
-        keep = path[-1].key in F32_LEAVES
-        return jnp.asarray(x, jnp.float32 if keep else dt)
-
-    return jax.tree_util.tree_map_with_path(one, tree)
+    return hold_leaves({**params, "attn": a}, weight_dtype, F32_LEAVES)
 
 
 def forward(params, tokens, cfg: KimiK2Config):
@@ -481,7 +467,7 @@ class KimiK2Serving:
                 scale), pool
 
         def layer(h, l, pool):
-            p = _at(qparams["attn"], l)
+            p = layer_at(qparams["attn"], l)
             u = rms_norm(h, p["norm_in"], cfg.rms_norm_eps)
             q_nope, q_rope, ckv, k_rope = _latent_projections(
                 u, p, positions, cfg)

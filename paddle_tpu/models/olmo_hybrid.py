@@ -54,7 +54,8 @@ from ..ops import pallas_kernels as _pk
 from ..ops.decode_attention import (decode_attention, paged_cache_update,
                                     paged_gather, paged_page_write,
                                     prefill_attention)
-from .jamba import _layer_at, _mlp_shapes, hold_leaves, rms_norm
+from .blocks import (gated_mlp, hold_leaves, layer_at, mlp_shapes,
+                     rms_logits, rms_norm)
 
 __all__ = ["OlmoHybridConfig", "OLMO_HYBRID_TINY", "init_params", "forward",
            "OlmoHybridServing"]
@@ -127,6 +128,11 @@ class OlmoHybridConfig:
     def scaled(self, **kw) -> "OlmoHybridConfig":
         return dataclasses.replace(self, **kw)
 
+    def serving_description(self) -> "OlmoHybridServing":
+        """What ``DecodeEngine`` builds its programs from
+        (``serving/model.py``)."""
+        return OlmoHybridServing(self)
+
 
 OLMO_HYBRID_TINY = OlmoHybridConfig(
     vocab_size=256, hidden_size=64, intermediate_size=128,
@@ -156,11 +162,11 @@ def leaf_shapes(cfg: OlmoHybridConfig) -> Dict[str, Any]:
               "w_ab": (Ll, D, 2 * H), "A_log": (Ll, H), "dt_bias": (Ll, H),
               "w_g": (Ll, D, H * dv), "o_norm": (Ll, dv),
               "w_o": (Ll, H * dv, D), "norm_mix": (Ll, D),
-              **_mlp_shapes(Ll, D, F)}
+              **mlp_shapes(Ll, D, F)}
     full = {"wq": (D, nh * hd), "wk": (D, nh * hd), "wv": (D, nh * hd),
             "wo": (nh * hd, D), "q_norm": (nh * hd,), "k_norm": (nh * hd,),
             "norm_mix": (D,),
-            **{k: s[1:] for k, s in _mlp_shapes(1, D, F).items()}}
+            **{k: s[1:] for k, s in mlp_shapes(1, D, F).items()}}
     return {"embed": (cfg.vocab_size, D), "final_norm": (D,),
             "lm_head": (D, cfg.vocab_size), "linear": linear,
             "full": [dict(full) for _ in cfg.full_layers]}
@@ -202,10 +208,7 @@ def init_params(key, cfg: OlmoHybridConfig) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 def _mlp(h, p, cfg):
-    dt = cfg.dtype
-    g = jnp.dot(h, p["gate"].astype(dt))
-    y = jnp.dot(jax.nn.silu(g) * jnp.dot(h, p["up"].astype(dt)),
-                p["down"].astype(dt))
+    y = gated_mlp(h, p["gate"], p["up"], p["down"], cfg.dtype)
     return h + rms_norm(y, p["norm_ff"], cfg.rms_norm_eps)
 
 
@@ -333,7 +336,7 @@ def _over_layers(cfg, params, x, carry, linear_layer, full_layer):
             continue
 
         def body(m, xc):
-            return linear_layer(xc[0], _layer_at(params["linear"], m), m,
+            return linear_layer(xc[0], layer_at(params["linear"], m), m,
                                 xc[1])
 
         x, carry = jax.lax.fori_loop(first, first + count, body, (x, carry))
@@ -341,9 +344,8 @@ def _over_layers(cfg, params, x, carry, linear_layer, full_layer):
 
 
 def _logits(params, h, cfg):
-    h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-    return jnp.dot(h, params["lm_head"].astype(cfg.dtype),
-                   preferred_element_type=jnp.float32)
+    return rms_logits(h, params["final_norm"], params["lm_head"],
+                      cfg.rms_norm_eps, cfg.dtype)
 
 
 def forward(params, tokens, cfg: OlmoHybridConfig):

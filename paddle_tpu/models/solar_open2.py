@@ -65,8 +65,7 @@ from ..ops.decode_attention import (band_prefill_attention,
                                     paged_cache_update, paged_gather,
                                     paged_page_write,
                                     sliding_decode_attention)
-from .cohere2_moe import _ffn_chunk
-from .jamba import rms_norm
+from .blocks import hold_leaves, over_ffn_chunks, rms_logits, rms_norm
 
 __all__ = ["SolarOpen2Config", "SOLAR_OPEN2_TINY", "leaf_shapes",
            "init_params", "hold", "forward", "SolarOpen2Serving"]
@@ -157,6 +156,11 @@ class SolarOpen2Config:
 
     def scaled(self, **kw) -> "SolarOpen2Config":
         return dataclasses.replace(self, **kw)
+
+    def serving_description(self) -> "SolarOpen2Serving":
+        """What ``DecodeEngine`` builds its programs from
+        (``serving/model.py``)."""
+        return SolarOpen2Serving(self)
 
 
 SOLAR_OPEN2_TINY = SolarOpen2Config(
@@ -264,8 +268,6 @@ def hold(params, cfg: SolarOpen2Config, weight_dtype: str = "f32"):
     w_v | w_gate`` as ``w_qkvg [D, (2 H + 2 KVH) hd]``; the shared expert's
     gate and up as ``shared_gate_up [D, 2 Fs]``. The routed experts are
     stored as the grouped product contracts them and held as they are."""
-    dt = {"f32": jnp.float32, "bf16": jnp.bfloat16}[weight_dtype]
-
     def beside(a, *names):
         return jnp.concatenate([a.pop(n) for n in names], axis=-1)
 
@@ -282,12 +284,7 @@ def hold(params, cfg: SolarOpen2Config, weight_dtype: str = "f32"):
 
     tree = {**params, "layers": [layer(p, kind) for p, kind in
                                  zip(params["layers"], cfg.layer_types)]}
-
-    def one(path, x):
-        keep = path[-1].key in F32_LEAVES
-        return jnp.asarray(x, jnp.float32 if keep else dt)
-
-    return jax.tree_util.tree_map_with_path(one, tree)
+    return hold_leaves(tree, weight_dtype, F32_LEAVES)
 
 
 def _kda_inputs(conv, u, p, cfg):
@@ -418,22 +415,12 @@ def _ffn_rows(u, valid, p, cfg, use_pallas):
 
 
 def _ffn(u, valid, p, cfg, use_pallas=None):
-    """The experts' half of a layer on ``u [T, D]`` (normed), at most
-    ``cohere2_moe._FFN_ROWS`` tokens at a time. Returns ``(ffn [T, D],
-    report [G + 1] int32)``: tokens on each held expert, and the held pairs
-    that reached no expert (0: nothing is dropped)."""
-    T, c = u.shape[0], _ffn_chunk(u.shape[0])
-    if c == T:
-        return _ffn_rows(u, valid, p, cfg, use_pallas)
-
-    def step(report, xs):
-        y, r = _ffn_rows(xs[0], xs[1], p, cfg, use_pallas)
-        return report + r, y
-
-    report, y = jax.lax.scan(
-        step, jnp.zeros((cfg.experts_held + 1,), jnp.int32),
-        (u.reshape(T // c, c, -1), valid.reshape(T // c, c)))
-    return y.reshape(T, -1), report
+    """The experts' half of a layer on ``u [T, D]`` (normed), a rung's
+    chunk of tokens at a time (``blocks.over_ffn_chunks``). Returns ``(ffn
+    [T, D], report [G + 1] int32)``."""
+    return over_ffn_chunks(
+        lambda rows, ok: _ffn_rows(rows, ok, p, cfg, use_pallas),
+        u, valid, cfg.experts_held)
 
 
 def _sequence(held, x, length, cfg, write_rows=None, write_state=None,
@@ -480,9 +467,8 @@ def _sequence(held, x, length, cfg, write_rows=None, write_state=None,
 
 
 def _logits(held, h, cfg):
-    h = rms_norm(h, held["final_norm"], cfg.rms_norm_eps)
-    return jnp.dot(h, held["lm_head"].astype(cfg.dtype),
-                   preferred_element_type=jnp.float32)
+    return rms_logits(h, held["final_norm"], held["lm_head"],
+                      cfg.rms_norm_eps, cfg.dtype)
 
 
 def forward(params, tokens, cfg: SolarOpen2Config):

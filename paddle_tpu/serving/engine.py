@@ -19,7 +19,8 @@ module assembles them into the serving shape:
   + per-slot page tables fed as device arrays, prefix-cache capable),
   threaded through every executable with buffer donation on TPU. The
   programs carry both pools through the layer loop in place
-  (``_layers_over_pools``) and touch only the rows and pages they index;
+  (``models/gpt_serving.py:layers_over_pools``) and touch only the rows
+  and pages they index;
   on a TPU the decode tick reads the live pages through the page table
   in a Pallas kernel (``kv_path``).
 - **Sampling inside the executables** (``serving/sampling.py``):
@@ -40,13 +41,11 @@ module assembles them into the serving shape:
   take f32/bf16.)
 
 The engine is single-threaded by contract: exactly one scheduler loop
-calls it (serving/scheduler.py). The prefill and decode programs take
-their layers from a *model description* (``serving/model.py``: the GPT
-block, ``models/jamba.py``'s hybrid of Mamba and attention layers
-with per-slot recurrent state, or ``models/kimi_k2.py``'s latent attention
-over one pool of latent rows with sparse experts, whose per-layer load
-comes back with the logits); the verify program is still written for the
-GPT block (ROADMAP D2).
+calls it (serving/scheduler.py). Every program (prefill, decode, the
+verify window) is ``cut the feed -> embed -> layers -> logits -> sample``
+over ``(qparams, caches, feed)``, the layers those of a *model description*
+(``serving/model.py`` states the protocol; the descriptions live beside
+their models under ``models/``, and this package imports none of them).
 """
 from __future__ import annotations
 
@@ -59,21 +58,15 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..models import gpt as gpt_mod
 from ..observability import program_report as _prep
 from ..observability import spans as _spans
 from ..ops import pallas_kernels as _pk
-from ..ops.decode_attention import (paged_cache_update, paged_gather,
-                                    window_attention)
 from . import metrics as smetrics
 from . import model as _model
 from . import sampling as samp
-from .model import (block_tail as _block_tail, embed_rows as _embed_rows,
-                    layers_over_pools as _layers_over_pools,
-                    qkv_heads as _qkv_heads)
 from .paged_kv import (PagedKVCache, PagePoolFullError, PrefixCache,
                        table_width as _table_width)
-from .quant import QuantizedLeaf, dequantize_params, quantized_nbytes
+from .quant import QuantizedLeaf, quantized_nbytes
 from .sampling import GREEDY, SamplingParams
 
 __all__ = ["EngineConfig", "DecodeEngine", "PromptTooLongError",
@@ -248,8 +241,8 @@ class EngineConfig:
 
 class DecodeEngine:
     def __init__(self, params, cfg, ecfg: EngineConfig):
-        """``cfg``: a model description (``serving/model.py``) or the
-        config of a family that has one (``GPTConfig``, ``JambaConfig``)."""
+        """``cfg``: a model description (``serving/model.py``) or a config
+        that names one (``cfg.serving_description()``)."""
         self.model = _model.describe(cfg)
         cfg = self.cfg = self.model.cfg
         if (self.model.max_positions is not None
@@ -284,7 +277,7 @@ class DecodeEngine:
             raise ValueError(f"sharding {ecfg.sharding!r}: expected None "
                              "or 'tp'")
         # the serving storage is the model's own: it may hold a leaf in
-        # the layout its programs contract (GPTServing.hold: w_qkv). A
+        # the layout its programs contract (the GPT block's w_qkv). A
         # sharded engine asks for the stored shapes, which its plan names.
         qparams = self.model.hold(params, ecfg.weight_dtype,
                                   ecfg.quant_chunk,
@@ -392,9 +385,16 @@ class DecodeEngine:
         attention keeps one compressed row a token in ONE pool, and the
         mechanisms below are written for pages of keys and values of equal
         heads. Each mechanism that would have to carry either is refused by
-        name."""
+        name. The verify window's layers are the description's own
+        (``verify_layers``): one without them is refused the window."""
         beside = self._beside_plain_pages()
         if beside is None:
+            if ecfg.verify_window and not hasattr(self.model,
+                                                  "verify_layers"):
+                raise ValueError(
+                    f"{type(self.model).__name__} has no verify_layers: the "
+                    "verify window (verify_window > 0, speculative "
+                    "decoding) runs the description's own window layers")
             return
         has, carry = beside
         recurrent = self.model.recurrent
@@ -527,9 +527,6 @@ class DecodeEngine:
     # ------------------------------------------------------------------
     # pure functions (traced once per executable)
     # ------------------------------------------------------------------
-    def _dequant(self, qparams):
-        return dequantize_params(qparams)
-
     def _prefill_fn_paged(self, qparams, caches, feed):
         """Paged (prefix-cache capable) prefill. ``feed``
         (:func:`cut_rung_feed`): tokens [1, T], the SUFFIX after
@@ -581,45 +578,23 @@ class DecodeEngine:
         # logits: one small int32 array, no second program
         return (caches, logits, toks, *report)
 
-    def _verify_fn_paged(self, qparams, kp, vp, feed):
-        """Paged verify window: B*W rows scatter through the page tables,
-        attention reads the gathered per-slot views. ``feed`` is the
-        tick's with W tokens a slot and the window's start for a
-        position (``actives`` is not read: a lane that sits out has a
-        zero table row)."""
-        cfg = self.cfg
-        params = self._dequant(qparams)
-        dt = cfg.dtype
-        ln = gpt_mod._layer_norm
-        ps = self.ecfg.page_size
+    def _verify_fn_paged(self, qparams, caches, feed):
+        """The verify window: ``feed`` is the tick's with W tokens a slot
+        and the window's start for a position (``actives`` is not read: a
+        lane that sits out has a zero table row) -> (caches, logits[B, W,
+        V], tokens[B, W]), the layers the model's own ``verify_layers``:
+        B*W rows written through the page tables at ``start + w``."""
+        m = self.model
         tokens, starts, tables, _actives, sp = cut_slot_feed(
             feed, self.table_width)
-        B, W = tokens.shape
-        positions = starts[:, None] + jnp.arange(W)      # [B, W]
-        x = _embed_rows(qparams, tokens, positions, dt)
-        phys = jnp.take_along_axis(tables, positions // ps, axis=1)
-        rows = positions % ps
-
-        def body(h, layer_p, l, kp, vp):
-            h1 = ln(h, layer_p["ln1_scale"], layer_p["ln1_bias"])
-            q, k, v = _qkv_heads(h1, layer_p, cfg)
-            row = (B * W,) + kp.shape[3:]
-            kp = paged_cache_update(
-                kp, k.reshape(row), phys.reshape(-1), rows.reshape(-1), l)
-            vp = paged_cache_update(
-                vp, v.reshape(row), phys.reshape(-1), rows.reshape(-1), l)
-            a = window_attention(q, paged_gather(kp, tables, l, k.shape[2:]),
-                                 paged_gather(vp, tables, l, k.shape[2:]),
-                                 starts)
-            return _block_tail(h, a, layer_p, dt, ln, "bw"), kp, vp
-
-        x, kp, vp = _layers_over_pools(body, x, kp, vp, params["blocks"])
-        x = ln(x, params["ln_f_scale"], params["ln_f_bias"])
-        logits = jnp.einsum("bwd,dv->bwv", x,
-                            params["lm_head"].astype(dt))
-        logits = logits.astype(jnp.float32)
+        positions = starts[:, None] + jnp.arange(tokens.shape[1])  # [B, W]
+        x = m.embed(qparams, tokens, positions)
+        x, caches = m.verify_layers(qparams, x, caches, _model.ctx(
+            starts=starts, positions=positions, tables=tables,
+            page_size=self.ecfg.page_size))
+        logits = m.logits(qparams, x)
         toks = samp.sample_window(logits, *sp, positions)
-        return kp, vp, logits, toks
+        return caches, logits, toks
 
     # ------------------------------------------------------------------
     # AOT compilation (PR 4 discipline: explicit lower+compile, program
@@ -631,20 +606,16 @@ class DecodeEngine:
                      str(jnp.result_type(a))) for i, a in enumerate(leaves)]
         return _prep.make_sig(feed_sig, fetch_names=())
 
-    def _shardings_for(self, example_args):
+    def _shardings_for(self):
         """(in_shardings, out_shardings) pytrees for the tp mesh: params
-        take the plan shardings, the pools the KV-head split, every
-        other input and the two outputs behind the caches (logits,
-        tokens) replicate. None/None off-mesh."""
+        take the plan shardings, the pools the KV-head split, the feed
+        and the two outputs behind the caches (logits, tokens) replicate.
+        None/None off-mesh."""
         if self._mesh is None:
             return None, None
-        if isinstance(example_args[1], tuple):      # prefill and decode
-            caches = [(self._cache_sh, self._cache_sh)]
-        else:                   # verify: the pools as arguments 1 and 2
-            caches = [self._cache_sh, self._cache_sh]
-        rest = len(example_args) - 1 - len(caches)
-        return (tuple([self._param_sh] + caches + [self._repl_sh] * rest),
-                tuple(caches + [self._repl_sh] * 2))
+        caches = (self._cache_sh, self._cache_sh)
+        return ((self._param_sh, caches, self._repl_sh),
+                (caches, self._repl_sh, self._repl_sh))
 
     def _compile(self, name: str, fn, example_args,
                  donate_argnums: Tuple[int, ...]) -> Any:
@@ -661,7 +632,7 @@ class DecodeEngine:
                 self.steady_state_recompiles += 1
         hist.append(sig)
         del hist[:-8]
-        in_sh, out_sh = self._shardings_for(example_args)
+        in_sh, out_sh = self._shardings_for()
         jit_kw: Dict[str, Any] = dict(
             donate_argnums=donate_argnums if self._donate else ())
         if in_sh is not None:
@@ -732,9 +703,8 @@ class DecodeEngine:
         feed[at + _SAMPLING:at + _SAMPLING + len(suffix)] = suffix
         return feed, sp
 
-    # Prefill and decode take the manager's arrays as ONE argument (a
-    # tuple: the pools, and a recurrent model's state arrays), donated
-    # whole; the verify program takes the two pools as arguments 1 and 2.
+    # Every program takes the manager's arrays as ONE argument (a tuple:
+    # the pools, and a recurrent model's state arrays), donated whole.
     def _prefill_program(self, bucket: int):
         """(fn, example args) of one prefill rung, as _decode_program."""
         feed, _sp = self._rung_feed(bucket, (0,), 0, 0, None, GREEDY)
@@ -766,12 +736,18 @@ class DecodeEngine:
         return exe
 
     def _call(self, exe, feed):
-        """One prefill or decode call on the live caches, its host
+        """One prefill, decode or verify call on the live caches, its host
         arguments the one ``feed``: ``(caches, rest)`` back, the caches
         for ``self.cache.set_arrays`` once the call is known to have
         run."""
         out = exe(self.qparams, self.cache.arrays(), feed)
         return out[0], out[1:]
+
+    def _verify_program(self):
+        """(fn, example args) of the verify window, as _decode_program."""
+        return self._verify_fn_paged, (
+            self.qparams, self.cache.arrays(),
+            self._slot_feed(self.ecfg.verify_window)[0])
 
     def _verify_exec(self):
         W = self.ecfg.verify_window
@@ -780,10 +756,8 @@ class DecodeEngine:
         name = f"verify_w{W}"
         exe = self._exec.get(name)
         if exe is None:
-            example = (self.qparams, self.cache.k, self.cache.v,
-                       self._slot_feed(W)[0])
-            exe = self._compile(name, self._verify_fn_paged, example,
-                                donate_argnums=(1, 2))
+            fn, example = self._verify_program()
+            exe = self._compile(name, fn, example, donate_argnums=(1,))
             self._exec[name] = exe
         return exe
 
@@ -813,14 +787,8 @@ class DecodeEngine:
             _warm_call(f"prefill_b{bucket}", self._prefill_exec(bucket),
                        self._prefill_program(bucket)[1])
         if self.ecfg.verify_window >= 2:
-            W = self.ecfg.verify_window
-            ver = self._verify_exec()
-            t0 = time.perf_counter()
-            out = ver(self.qparams, self.cache.k, self.cache.v,
-                      self._slot_feed(W)[0])
-            jax.block_until_ready(out[2])
-            self.cache.k, self.cache.v = out[0], out[1]
-            timings[f"verify_w{W}"] = (time.perf_counter() - t0) * 1e3
+            _warm_call(f"verify_w{self.ecfg.verify_window}",
+                       self._verify_exec(), self._verify_program()[1])
         # transfer-path gather/scatter (KV handoff + prefix store): one
         # compiled shape each — warmed here so a disagg handoff's first
         # export/adopt never pays a mid-request compile (~100ms). An
@@ -1313,8 +1281,7 @@ class DecodeEngine:
                         "verify window")
             self._masked_tables(windows, feed)
             with _spans.span("decode/call", attrs={"exe": f"verify_w{W}"}):
-                ck, cv, logits, toks = exe(
-                    self.qparams, self.cache.k, self.cache.v, feed)
+                caches, (logits, toks) = self._call(exe, feed)
             logits = np.asarray(logits)
             toks = np.asarray(toks)
         except PagePoolFullError:
@@ -1324,7 +1291,7 @@ class DecodeEngine:
                 f"verify_w{W}", e)
             raise
         smetrics.m_decode_ms.observe((time.perf_counter_ns() - t0) / 1e6)
-        self.cache.k, self.cache.v = ck, cv
+        self.cache.set_arrays(caches)
         return {slot: (logits[slot], toks[slot]) for slot in windows}
 
     def commit_window(self, slot: int, n_accepted_rows: int) -> None:

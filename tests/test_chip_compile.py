@@ -636,7 +636,7 @@ def test_serving_programs_relay_no_weight(one_chip, program):
     (compiled by hand, PR 32: nor do the rungs 32 to 1024). At
     24 layers such a copy of ``w_qkv`` was 1.83 ms of every 8.3 ms tick
     (PERF.md section 6, PR 32). XLA decides the tiling from the product's
-    spelling (``serving/model.py:qkv_heads``): a reshape to heads straight
+    spelling (``models/gpt_serving.py:qkv_heads``): a reshape to heads straight
     after the product brings the copy back, in the tiling ``{1,2,0}``."""
     eng, compiled = _compiled_gpt_program(program, one_chip)
     held = eng.held_shapes["blocks/w_qkv"]

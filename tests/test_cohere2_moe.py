@@ -19,7 +19,7 @@ sys.path.insert(0, ROOT)
 
 from benchmark import harness  # noqa: E402
 from paddle_tpu import serving  # noqa: E402
-from paddle_tpu.models import cohere2_moe as C  # noqa: E402
+from paddle_tpu.models import blocks, cohere2_moe as C  # noqa: E402
 from paddle_tpu.ops import moe, rope  # noqa: E402
 from paddle_tpu.ops import pallas_kernels as PK  # noqa: E402
 from paddle_tpu.ops.decode_attention import (_grouped_attention,  # noqa
@@ -342,17 +342,17 @@ def test_held_tree_is_the_stored_one_relaid():
     u = jax.random.normal(jax.random.PRNGKey(3), (256, D), jnp.float32)
     valid = jnp.arange(256) < 200
     whole, r_whole = C._ffn_rows(u, valid, held["layers"], 1, cfg, False)
-    old = C._FFN_ROWS
+    old = blocks._FFN_ROWS
     try:
-        C._FFN_ROWS = 128
-        assert C._ffn_chunk(256) == 128 and C._ffn_chunk(384) == 128
+        blocks._FFN_ROWS = 128
+        assert blocks.ffn_chunk(256) == 128 and blocks.ffn_chunk(384) == 128
         parts, r_parts = C._ffn(u, valid, held, 1, cfg, False)
     finally:
-        C._FFN_ROWS = old
+        blocks._FFN_ROWS = old
     np.testing.assert_allclose(np.asarray(parts), np.asarray(whole),
                                atol=2e-6)
     np.testing.assert_array_equal(np.asarray(r_parts), np.asarray(r_whole))
-    assert C._ffn_chunk(16384) == 2048 and C._ffn_chunk(7168) == 1792
+    assert blocks.ffn_chunk(16384) == 2048 and blocks.ffn_chunk(7168) == 1792
 
 
 # ---------------------------------------------------------------------------

@@ -16,7 +16,7 @@ import jax
 from paddle_tpu import serving
 from paddle_tpu.models import gpt
 from paddle_tpu.serving import quant as squant
-from paddle_tpu.serving.model import GPTServing, qkv_heads
+from paddle_tpu.models.gpt_serving import GPTServing, qkv_heads
 
 from serving_helpers import greedy_engine, greedy_reference
 

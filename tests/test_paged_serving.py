@@ -783,8 +783,7 @@ def test_carried_pools_match_xs_ys_scan(tiny_model, monkeypatch, program):
     """Part 1 changes where the pools live in the layer loop, not a
     number: each paged program over the carried pools returns the pools,
     logits and tokens that the scan over per-layer slices returned."""
-    from paddle_tpu.serving import engine as engine_mod
-    from paddle_tpu.serving import model as model_mod
+    from paddle_tpu.models import gpt_serving
 
     eng = make_engine(tiny_model, verify_window=3)
     assert eng.kv_path == "xla_gather"
@@ -792,16 +791,13 @@ def test_carried_pools_match_xs_ys_scan(tiny_model, monkeypatch, program):
     kp, vp = _seeded_pools(eng, 6)
 
     def run():
-        # the paged prefill and decode programs take the pools as one
-        # argument and hand them back as one; the verify program as two
-        if program == "verify":
-            return jax.jit(fn)(eng.qparams, kp, vp, *args)
+        # every paged program takes the pools as one argument and hands
+        # them back as one
         pools, logits, toks = jax.jit(fn)(eng.qparams, (kp, vp), *args)
         return (*pools, logits, toks)
 
     new = run()
-    monkeypatch.setattr(engine_mod, "_layers_over_pools", _xs_ys_layers)
-    monkeypatch.setattr(model_mod, "layers_over_pools", _xs_ys_layers)
+    monkeypatch.setattr(gpt_serving, "layers_over_pools", _xs_ys_layers)
     old = run()
     for got, want in zip(new, old):
         np.testing.assert_array_equal(np.asarray(got, np.float32),

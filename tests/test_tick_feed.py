@@ -210,7 +210,7 @@ def test_the_verify_windows_feed_cuts_into_the_seven_arrays_it_replaced(
     monkeypatch.setitem(eng._exec, "verify_w3",
                         lambda *args: (handed.append(args), exe(*args))[1])
     eng.verify_step(windows, params)
-    (_qparams, _k, _v, feed), = handed
+    (_qparams, _caches, feed), = handed
     tokens, starts, tables, _actives, sp = _cut_slot(feed, eng.table_width)
     want_tokens = np.zeros((4, 3), np.int32)
     want_starts = np.zeros((4,), np.int32)
